@@ -1,0 +1,695 @@
+"""Does the system still start on the chip?
+
+    python3 chip_smoke.py [kernels] [bert] [gpt] [resnet] [multichip]
+
+Drives the main path once through the entry points a user calls, at the
+full width of the models the repo supports, with seeded random weights:
+
+* kernels   — every Pallas route (flash attention forward/backward, its
+              in-kernel dropout PRNG, the paged decode kernel at q_len 1
+              and 8) against its primitive oracle on the same inputs at
+              the models' own shapes, under written tolerances;
+* bert      — BERT-base pretraining, bs 32 x 512, bf16 AMP, through
+              ``Executor(TPUPlace()).run`` on one fixed batch;
+* gpt       — GPT-2-base (768 wide, 12 layers, 12 heads) served by
+              ``GenerativeEngine``: bucketed prefill, chunked prefill,
+              prefix-cache hit, plain decode chunks, speculative verify;
+* resnet    — ResNet-50 bs 128 bf16 training for a few steps;
+* multichip — on a host with >= 4 chips: the BERT step (depth cut to 2
+              layers, full width) through ``CompiledProgram
+              .with_data_parallel`` and ``compile_sharded_step`` on a
+              dp 2 x tp 2 mesh of the real devices, loss against the
+              single-chip step. Otherwise the leg says it did not run.
+
+No arguments runs every leg. One process; it needs the chip: without a TPU
+it names the devices JAX found and exits non-zero. Any failed check or
+raised leg makes the exit code non-zero and no result line is printed. On
+success the last line of stdout is the result line, one JSON object with
+exactly the keys ``ok`` and ``device`` (``platform``, ``kind``, ``count`` as
+JAX reports them); the legs run and ``"claim": null`` are on the ``summary``
+line above it. Times printed here (compile seconds per executable, plain-loop
+step wall time with the fetch as the sync) are set-up facts for choosing a
+measurement protocol, not metrics.
+"""
+from __future__ import annotations
+
+import gc
+import json
+import math
+import os
+import sys
+import time
+import traceback
+
+import numpy as np
+
+LEGS = ("kernels", "bert", "gpt", "resnet", "multichip")
+
+# written tolerances, set from the dtype a route COMPUTES in: operands
+# rounded to bf16 (eps 2^-8) on values of magnitude up to ~4 are good to
+# ~2e-2; a route whose dots run at full f32 precision on both sides is good
+# to 2e-3. flash_attention asks the MXU for 'highest' on f32 inputs; the
+# decode kernel leaves its f32 dots at the MXU default, which rounds the
+# operands to bf16 (first chip run, PR 21: 7.8e-3 on a one-key row, i.e.
+# exactly the bf16 rounding of v) — so it is held to the bf16 bound.
+BF16_ATOL = 2e-2
+F32_ATOL = 2e-3
+
+
+class SmokeFailure(Exception):
+    pass
+
+
+def check(ok: bool, what: str) -> None:
+    print(f"    [{'ok' if ok else 'FAIL'}] {what}", flush=True)
+    if not ok:
+        raise SmokeFailure(what)
+
+
+def say(leg: str, msg: str) -> None:
+    print(f"[{leg}] {msg}", flush=True)
+
+
+def hbm(leg: str) -> None:
+    import jax
+
+    stats = jax.devices()[0].memory_stats() or {}
+    if stats:
+        say(leg, f"HBM in use {stats.get('bytes_in_use', 0) / 2**30:.2f} GiB, "
+                 f"peak {stats.get('peak_bytes_in_use', 0) / 2**30:.2f} GiB "
+                 f"of {stats.get('bytes_limit', 0) / 2**30:.2f} GiB")
+
+
+class CompileLog:
+    """Per-executable compile seconds (monitor on_compile hook) and the
+    kernel route each program took (``kernel_route_total``)."""
+
+    def __init__(self):
+        from paddle_tpu import monitor
+
+        self._monitor = monitor
+        self.records = []
+        self._hook = monitor.add_hook(on_compile=self.records.append)
+        self.names = {}
+
+    def name(self, program, name: str) -> None:
+        self.names[int(program._serial)] = name
+
+    def report(self, leg: str) -> float:
+        total = 0.0
+        for rec in self.records:
+            secs = (rec.trace_lower_s or 0.0) + (rec.compile_s or 0.0)
+            total += secs
+            say(leg, f"compile {self.names.get(rec.program_serial, '?')} "
+                     f"({rec.path}): trace+lower "
+                     f"{rec.trace_lower_s or 0.0:.1f} s, xla "
+                     f"{rec.compile_s or 0.0:.1f} s")
+        say(leg, f"compile seconds, all executables: {total:.1f}")
+        self.records.clear()
+        return total
+
+    def routes(self, leg: str) -> dict:
+        out = {}
+        for serial, key, n in route_counts():
+            if serial in self.names:
+                out.setdefault(self.names[serial], {})[key] = n
+        for name in sorted(out):
+            say(leg, f"route {name}: {out[name]}")
+        return out
+
+    def close(self) -> None:
+        self._monitor.remove_hook(self._hook)
+
+
+def route_counts() -> list:
+    """``kernel_route_total`` as (program serial, "op:route", count)."""
+    from paddle_tpu import monitor
+
+    fam = monitor.get_registry().get("kernel_route_total")
+    return [(int(labels["program"]), f"{labels['op']}:{labels['route']}",
+             int(ctr.value))
+            for labels, ctr in (fam.children() if fam is not None else ())]
+
+
+def route_totals() -> dict:
+    """The same summed over programs: {"op:route": count}."""
+    out = {}
+    for _, key, n in route_counts():
+        out[key] = out.get(key, 0) + n
+    return out
+
+
+# ---------------------------------------------------------------------------
+# kernels: Pallas routes against their primitive oracles, on the chip
+# ---------------------------------------------------------------------------
+
+def leg_kernels() -> dict:
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.kernels import (decode_attention_reference,
+                                    flash_attention, flash_attention_decode)
+    from paddle_tpu.lowering import LowerCtx
+    from paddle_tpu.ops.fused_attention import _primitive_attention
+
+    leg = "kernels"
+    rng = np.random.RandomState(0)
+    ctx = LowerCtx(platform="tpu")
+
+    def maxerr(a, b):
+        return float(jnp.max(jnp.abs(a.astype(jnp.float32)
+                                     - b.astype(jnp.float32))))
+
+    def oracle(q, k, v, bias, causal):
+        # the primitive route in f32 at full precision: the truth both the
+        # kernel and the primitive-at-compute-dtype are measured against
+        f = jnp.float32
+        return _primitive_attention(ctx, q.astype(f), k.astype(f),
+                                    v.astype(f), bias, causal,
+                                    q.shape[-1] ** -0.5, 0.0, True)
+
+    # -- flash attention, BERT-base shape: bs 32 x 12 heads, S 512, D 64,
+    #    bf16, key-padding bias -- forward and all three gradients
+    B, H, S, D = 32, 12, 512, 64
+    q, k, v = (jnp.asarray(rng.randn(B * H, S, D), jnp.bfloat16)
+               for _ in range(3))
+    mask = np.ones((B, S), np.float32)
+    mask[::3, 400:] = 0.0                      # padded tails on some rows
+    bias = jnp.asarray((mask - 1.0) * 10000.0)
+    w = jnp.asarray(rng.randn(B * H, S, D), jnp.bfloat16)
+
+    def kernel_loss(q, k, v):
+        o = flash_attention(q, k, v, bias=bias, num_heads=H)
+        return jnp.sum(o.astype(jnp.float32) * w.astype(jnp.float32)), o
+
+    def oracle_loss(q, k, v):
+        o = oracle(q, k, v, bias, False)
+        return jnp.sum(o * w.astype(jnp.float32)), o
+
+    (_, o_k), g_k = jax.jit(jax.value_and_grad(
+        kernel_loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    (_, o_r), g_r = jax.jit(jax.value_and_grad(
+        oracle_loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    e = maxerr(o_k, o_r)
+    say(leg, f"flash fwd bf16 {B * H}x{S}x{D} + bias: max|err| {e:.2e}")
+    check(bool(jnp.isfinite(o_k.astype(jnp.float32)).all()) and e <= BF16_ATOL,
+          f"flash_attention forward agrees with the primitive oracle "
+          f"(<= {BF16_ATOL})")
+    for name, gk, gr in zip("qkv", g_k, g_r):
+        scale = float(jnp.max(jnp.abs(gr.astype(jnp.float32))))
+        e = maxerr(gk, gr)
+        say(leg, f"flash bwd d{name}: max|err| {e:.2e} "
+                 f"(max|grad| {scale:.2e})")
+        check(e <= BF16_ATOL * max(scale, 1.0),
+              f"flash_attention d{name} agrees with the primitive oracle "
+              f"(<= {BF16_ATOL} x max|grad|)")
+
+    # -- flash attention, GPT-2 prefill shape: 8 slots x 12 heads, S 512,
+    #    f32, causal + key-padding bias
+    Bg = 8
+    qf, kf, vf = (jnp.asarray(rng.randn(Bg * H, S, D), jnp.float32)
+                  for _ in range(3))
+    gmask = np.ones((Bg, S), np.float32)
+    gmask[1::2, 300:] = 0.0
+    gbias = jnp.asarray((gmask - 1.0) * 10000.0)
+    o_k = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, bias=gbias, causal=True, num_heads=H))(qf, kf, vf)
+    o_r = jax.jit(lambda q, k, v: oracle(q, k, v, gbias, True))(qf, kf, vf)
+    e = maxerr(o_k, o_r)
+    say(leg, f"flash fwd f32 causal {Bg * H}x{S}x{D} + bias: "
+             f"max|err| {e:.2e}")
+    check(e <= F32_ATOL, f"causal flash_attention (GPT prefill shape) "
+                         f"agrees with the primitive oracle (<= {F32_ATOL})")
+
+    # -- in-kernel dropout PRNG (no interpret mode: only a chip runs it).
+    #    q = k = 0 makes p uniform, so o = sum_j keep_ij v_j / ((1-r) S):
+    #    with v = 1 every row is the row's keep count over its expectation
+    rate = 0.1
+    zeros = jnp.zeros((B * H, S, D), jnp.float32)
+    ones = jnp.ones((B * H, S, D), jnp.float32)
+
+    @jax.jit
+    def drop(q, k, v, seed):
+        return flash_attention(q, k, v, dropout_rate=rate, seed=seed,
+                               num_heads=H)
+
+    o1 = drop(zeros, zeros, ones, 7)
+    rows = np.asarray(o1[:, :, 0], np.float64)
+    want_std = math.sqrt(rate / ((1.0 - rate) * S))
+    say(leg, f"dropout {rate}: row mean {rows.mean():.5f} (want 1), row "
+             f"std {rows.std():.5f} (want {want_std:.5f})")
+    check(abs(rows.mean() - 1.0) < 2e-3
+          and 0.7 * want_std < rows.std() < 1.3 * want_std,
+          "in-kernel dropout keeps 1-rate of the probabilities, "
+          "independently per element")
+    check(bool(jnp.array_equal(o1, drop(zeros, zeros, ones, 7)))
+          and not bool(jnp.array_equal(o1, drop(zeros, zeros, ones, 8))),
+          "dropout mask is a function of the seed (same seed -> same bits)")
+    # backward regenerates the SAME mask. o is linear in v, so with the
+    # mask fixed <dL/dv, v> == L exactly (dK/dV kernel); along a direction
+    # u, dL/dq . u must match a central difference of L (dQ kernel)
+    wv = jnp.asarray(rng.randn(B * H, S, D), jnp.float32)
+    qd, kd, vd = (jnp.asarray(0.5 * rng.randn(B * H, S, D), jnp.float32)
+                  for _ in range(3))
+
+    @jax.jit
+    def dloss(q, k, v):
+        return jnp.sum(drop(q, k, v, 11) * wv)
+
+    L, (dq, _dk, dv) = jax.jit(jax.value_and_grad(
+        dloss, argnums=(0, 1, 2)))(qd, kd, vd)
+    euler = float(jnp.sum(dv * vd))
+    say(leg, f"dropout bwd: L {float(L):.4f}, <dL/dv, v> {euler:.4f}")
+    check(abs(euler - float(L)) <= 1e-3 * max(abs(float(L)), 1.0),
+          "dK/dV kernel regenerates the forward dropout mask "
+          "(<dL/dv, v> == L)")
+    u = jnp.asarray(rng.randn(B * H, S, D), jnp.float32)
+    eps = 1e-2
+    fd = (float(dloss(qd + eps * u, kd, vd))
+          - float(dloss(qd - eps * u, kd, vd))) / (2 * eps)
+    an = float(jnp.sum(dq * u))
+    say(leg, f"dropout bwd: dL/dq.u analytic {an:.4f}, central "
+             f"difference {fd:.4f}")
+    check(abs(an - fd) <= 3e-2 * max(abs(fd), 1.0),
+          "dQ kernel regenerates the forward dropout mask "
+          "(directional derivative matches a central difference)")
+
+    # -- paged decode kernel, GPT-2 shape: 8 slots x 12 heads, cache 1024,
+    #    page 128, f32; q_len 1 (decode) and 8 (speculative verify)
+    S_max, page = 1024, 128
+    kc, vc = (jnp.asarray(rng.randn(Bg * H, S_max, D), jnp.float32)
+              for _ in range(2))
+    lengths = jnp.asarray([1, 127, 128, 129, 500, 777, 1000, 1016],
+                          jnp.int32)
+    for q_len in (1, 8):
+        qc = jnp.asarray(rng.randn(Bg * H, q_len, D), jnp.float32)
+        o_k = jax.jit(lambda q, k, v, l: flash_attention_decode(
+            q, k, v, l, num_heads=H, page_size=page))(qc, kc, vc, lengths)
+        o_r = jax.jit(lambda q, k, v, l: decode_attention_reference(
+            q, k, v, jnp.repeat(l, H), D ** -0.5))(qc, kc, vc, lengths)
+        e = maxerr(o_k, o_r)
+        say(leg, f"decode kernel f32 q_len {q_len} {Bg * H}x{S_max}x{D} "
+                 f"page {page}: max|err| {e:.2e}")
+        check(e <= BF16_ATOL,
+              f"flash_attention_decode q_len={q_len} agrees with "
+              f"decode_attention_reference (<= {BF16_ATOL}, bf16-rounded "
+              f"operands)")
+    hbm(leg)
+    return {}
+
+
+# ---------------------------------------------------------------------------
+# bert: the trainer
+# ---------------------------------------------------------------------------
+
+def leg_bert(cfg=None, batch: int = 32, seq_len: int = 512) -> dict:
+    import jax
+
+    import paddle_tpu as fluid
+    import paddle_tpu.unique_name as un
+    from paddle_tpu.models.bert import (BertConfig, build_bert_pretrain,
+                                        synthetic_pretrain_batch)
+
+    leg, steps = "bert", 12
+    log = CompileLog()
+    cfg = cfg or BertConfig.base()
+    with un.guard():
+        model = build_bert_pretrain(cfg, seq_len=seq_len, amp=True)
+    log.name(model["main"], "bert.main")
+    log.name(model["startup"], "bert.startup")
+    exe = fluid.Executor(fluid.TPUPlace())
+    scope = fluid.Scope()
+    feed = synthetic_pretrain_batch(cfg, batch, seq_len)
+    losses, walls = [], []
+    with fluid.scope_guard(scope):
+        exe.run(model["startup"])
+        for _ in range(steps):
+            t0 = time.perf_counter()
+            (lv,) = exe.run(model["main"], feed=feed,
+                            fetch_list=[model["loss"]])
+            walls.append(time.perf_counter() - t0)
+            losses.append(float(np.asarray(lv).reshape(-1)[0]))
+    say(leg, f"BERT-base bs {batch} x {seq_len} bf16, {cfg.num_layers} "
+             f"layers: losses {[round(l, 3) for l in losses]}")
+    say(leg, f"plain-loop step wall (host clock, fetch is the sync): first "
+             f"{walls[0]:.1f} s (compile), steady median "
+             f"{1e3 * float(np.median(walls[2:])):.1f} ms over "
+             f"{len(walls) - 2} steps")
+    log.report(leg)
+    routes = log.routes(leg)
+    log.close()
+    want0 = math.log(cfg.vocab_size) + math.log(2.0)
+    check(all(np.isfinite(losses)), "loss finite on every step")
+    check(abs(losses[0] - want0) < 0.5,
+          f"first loss {losses[0]:.3f} near ln(vocab) + ln 2 = {want0:.3f}")
+    check(losses[-1] < losses[0] - 0.1,
+          f"loss fell over {steps} Adam steps on one batch "
+          f"({losses[0]:.3f} -> {losses[-1]:.3f})")
+    check(set(routes.get("bert.main", {})) ==
+          {"fused_multihead_attention:pallas"},
+          "every attention op of the train step took the Pallas route")
+    tpu = exe.place.jax_device()
+    homes = {d for v in scope.vars.values() if isinstance(v, jax.Array)
+             for d in v.devices()}
+    check(homes == {tpu}, f"all {len(scope.vars)} persistables live on {tpu}")
+    hbm(leg)
+    return {"first_loss": losses[0]}
+
+
+# ---------------------------------------------------------------------------
+# gpt: the server
+# ---------------------------------------------------------------------------
+
+def leg_gpt(cfg=None, slots: int = 8, max_seq: int = 1024, page: int = 128,
+            buckets=(128, 512), spec_k: int = 8) -> dict:
+    import paddle_tpu as fluid
+    import paddle_tpu.unique_name as un
+    from paddle_tpu import serving
+    from paddle_tpu.models.gpt import GptConfig, build_gpt_generative
+
+    leg = "gpt"
+    log = CompileLog()
+    cfg = cfg or GptConfig.base()
+    with un.guard():
+        net = build_gpt_generative(cfg, batch_slots=slots, max_seq=max_seq,
+                                   page_size=page, prompt_buckets=buckets,
+                                   spec_k=spec_k)
+    log.name(net["startup"], "gpt.startup")
+    for b in buckets:
+        log.name(net["prefill"][b]["main"], f"gpt.prefill:{b}")
+    log.name(net["decode"]["main"], "gpt.decode")
+    log.name(net["chunk"]["main"], f"gpt.chunk:{net['prefill_chunk']}")
+    log.name(net["verify"]["main"], f"gpt.verify:{spec_k}")
+    exe = fluid.Executor(fluid.TPUPlace())
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe.run(net["startup"], scope=scope)
+
+    def engine(speculative: bool):
+        return serving.GenerativeEngine(
+            net, scope=scope, executor=exe,
+            config=serving.ServingConfig(max_batch=slots, queue_depth=64,
+                                         deadline_s=0),
+            gen_config=serving.GenerationConfig(decode_chunk=4,
+                                                speculative=speculative))
+
+    rng = np.random.RandomState(5)
+
+    def prompt(n):
+        return rng.randint(1, cfg.vocab_size, n).astype(np.int64)
+
+    def answered(what, out, max_new):
+        out = np.asarray(out)
+        check(out.shape == (max_new,) and int(out.min()) >= 0
+              and int(out.max()) < cfg.vocab_size,
+              f"{what}: {max_new} tokens, all inside the vocabulary")
+
+    say(leg, f"GPT-2-base {cfg.hidden_size} wide x {cfg.num_layers} layers "
+             f"x {cfg.num_heads} heads, {slots} slots x max_seq {max_seq}, "
+             f"page {page}, buckets {buckets}, spec_k {spec_k}")
+    eng = engine(False)
+    t0 = time.perf_counter()
+    n_exec = eng.warm_up()
+    say(leg, f"warm_up: {n_exec} executables in "
+             f"{time.perf_counter() - t0:.1f} s")
+    cold_compile = log.report(leg)
+    shared = prompt(3 * page)                  # three whole prefix pages
+    walls = {}
+    with eng:
+        def ask(what, p, max_new):
+            t0 = time.perf_counter()
+            out = eng.submit(p, max_new_tokens=max_new).result(
+                timeout=600)[0]
+            walls[what] = time.perf_counter() - t0
+            answered(what, out, max_new)
+
+        small, big = buckets
+        long = big + big // 2 - page // 2      # 5.5 pages at the defaults
+        ask(f"bucket {small} prefill (prompt {small - page // 4})",
+            prompt(small - page // 4), 12)
+        ask(f"bucket {big} prefill (prompt 3 pages + {page // 8}, "
+            f"publishes 3 pages)",
+            np.concatenate([shared, prompt(page // 8)]), 8)
+        ask(f"chunked prefill (prompt {long} > largest bucket)",
+            prompt(long), 8)
+        ask("prefix hit (same 3 pages + another suffix)",
+            np.concatenate([shared, prompt(page // 3)]), 8)
+        futs = [eng.submit(prompt(n), max_new_tokens=16)
+                for n in (small // 6, small // 3, small // 2)]
+        for i, f in enumerate(futs):
+            answered(f"concurrent stream {i}", f.result(timeout=600)[0], 16)
+    stats, acct = eng.generation_stats(), eng.accounting()
+    for what, dt in walls.items():
+        say(leg, f"request wall {dt:.2f} s — {what}")
+    say(leg, f"plain engine stats: {json.dumps(stats, default=str)}")
+    check(acct["exact"], "plain engine: accounting exact")
+    check(stats["decode_recompiles"] == 0, "plain engine: zero warm "
+                                           "recompiles")
+    check(stats["prefix_cache"]["hits"] >= 1
+          and stats["prefix_cache"]["pages_reused"] >= 3,
+          "prefix cache: the shared 3 pages were copied in, not re-prefilled")
+    check(stats["prefill_chunks"] >= -(-long // page) + 1,
+          "chunked prefill: the over-bucket prompt and the prefix-hit suffix "
+          "went through chunk slices")
+    check({f"prefill:{b}" for b in buckets} | {f"decode:{slots}",
+          f"chunk:{net['prefill_chunk']}"} <= set(stats["compiled_buckets"]),
+          "every plain-path executable was taken")
+
+    spec = engine(True)
+    spec.warm_up()
+    cold_compile += log.report(leg)
+    with spec:
+        # a prompt that repeats itself gives the n-gram drafter material
+        rep = np.tile(prompt(small // 5), 4)
+        t0 = time.perf_counter()
+        out = spec.submit(rep, max_new_tokens=32).result(timeout=600)[0]
+        say(leg, f"request wall {time.perf_counter() - t0:.2f} s — "
+                 f"speculative, prompt {len(rep)}, 32 new tokens")
+        answered("speculative stream", out, 32)
+    sstats = spec.generation_stats()
+    say(leg, f"speculative engine stats: "
+             f"{json.dumps(sstats['speculative'])}")
+    check(spec.accounting()["exact"], "speculative engine: accounting exact")
+    check(sstats["decode_recompiles"] == 0,
+          "speculative engine: zero warm recompiles")
+    check(sstats["speculative"]["chunks"] >= 1
+          and f"verify:{spec_k}" in sstats["compiled_buckets"],
+          "speculative pass dispatched verify chunks")
+    routes = log.routes(leg)
+    log.close()
+    want = {f"gpt.prefill:{b}": {"fused_multihead_attention:pallas"}
+            for b in buckets}
+    want["gpt.decode"] = {"fused_decode_attention:pallas"}
+    want[f"gpt.verify:{spec_k}"] = {"fused_decode_attention:pallas"}
+    # a 128-row chunk is past the decode kernel's 8-row tile: the chunk
+    # program rides the primitive path, and says so
+    want[f"gpt.chunk:{net['prefill_chunk']}"] = {
+        "fused_decode_attention:primitive"}
+    check({n: set(r) for n, r in routes.items()} == want,
+          "attention routes: prefill/decode/verify Pallas, chunk primitive")
+    hbm(leg)
+    return {"cold_compile_s": cold_compile}
+
+
+# ---------------------------------------------------------------------------
+# resnet
+# ---------------------------------------------------------------------------
+
+def leg_resnet() -> dict:
+    import paddle_tpu as fluid
+    import paddle_tpu.unique_name as un
+    from paddle_tpu.models.resnet import build_resnet
+
+    leg, batch, steps = "resnet", 128, 6
+    log = CompileLog()
+    with un.guard():
+        model = build_resnet(depth=50, class_num=1000, amp=True)
+    log.name(model["main"], "resnet50.main")
+    log.name(model["startup"], "resnet50.startup")
+    exe = fluid.Executor(fluid.TPUPlace())
+    scope = fluid.Scope()
+    rng = np.random.RandomState(0)
+    feed = {"img": rng.rand(batch, 3, 224, 224).astype(np.float32),
+            "label": rng.randint(0, 1000, (batch, 1)).astype(np.int64)}
+    losses, walls = [], []
+    with fluid.scope_guard(scope):
+        exe.run(model["startup"])
+        for _ in range(steps):
+            t0 = time.perf_counter()
+            (lv,) = exe.run(model["main"], feed=feed,
+                            fetch_list=[model["loss"]])
+            walls.append(time.perf_counter() - t0)
+            losses.append(float(np.asarray(lv).reshape(-1)[0]))
+    say(leg, f"ResNet-50 bs {batch} bf16: losses "
+             f"{[round(l, 3) for l in losses]}")
+    say(leg, f"plain-loop step wall (host clock, fetch is the sync, 77 MB "
+             f"host feed per step): first {walls[0]:.1f} s (compile), steady "
+             f"median {1e3 * float(np.median(walls[2:])):.1f} ms")
+    log.report(leg)
+    log.close()
+    check(all(np.isfinite(losses)), "loss finite on every step")
+    check(abs(losses[0] - math.log(1000.0)) < 1.5,
+          f"first loss {losses[0]:.3f} near ln(1000) = "
+          f"{math.log(1000.0):.3f}")
+    hbm(leg)
+    return {}
+
+
+# ---------------------------------------------------------------------------
+# multichip
+# ---------------------------------------------------------------------------
+
+def leg_multichip() -> dict:
+    import jax
+
+    import paddle_tpu as fluid
+    import paddle_tpu.unique_name as un
+    from __graft_entry__ import sharded_bert_step
+    from paddle_tpu.models.bert import (BertConfig, build_bert_pretrain,
+                                        synthetic_pretrain_batch)
+    from paddle_tpu.parallel.sharding import make_mesh
+
+    leg = "multichip"
+    devices = jax.devices()
+    if len(devices) < 4:
+        say(leg, f"NOT RUN: the multichip leg needs >= 4 devices, this host "
+                 f"has {len(devices)}")
+        return {"ran": False}
+    devices = devices[:4]
+    # full width, depth cut to 2 layers, dropout off: three compiles of the
+    # step fit the time limit, and the loss is a function of the weights
+    # and the batch alone, so the three paths must agree on it
+    cfg = BertConfig.base()
+    cfg.num_layers = 2
+    batch, seq_len, tol = 32, 512, 0.05
+    feed = synthetic_pretrain_batch(cfg, batch, seq_len)
+    routes_before = route_totals()
+
+    def build():
+        with un.guard():
+            return build_bert_pretrain(cfg, seq_len=seq_len, lr=1e-3,
+                                       amp=True, is_test=True)
+
+    def run(compiled_of):
+        model = build()
+        exe = fluid.Executor(fluid.TPUPlace())
+        scope = fluid.Scope()
+        with fluid.scope_guard(scope):
+            exe.run(model["startup"])
+            prog = compiled_of(model)
+            out = [float(np.asarray(exe.run(
+                prog, feed=feed, fetch_list=[model["loss"]])[0]).reshape(-1)[0])
+                for _ in range(2)]
+        return out, scope
+
+    single, _ = run(lambda m: m["main"])
+    say(leg, f"single chip, 2 steps: {single}")
+    dp_losses, dp_scope = run(
+        lambda m: fluid.CompiledProgram(m["main"]).with_data_parallel(
+            loss_name=m["loss"].name, places=devices))
+    say(leg, f"CompiledProgram.with_data_parallel over 4 chips: {dp_losses}")
+    w = dp_scope.find_var("word_embedding")
+    check({s.device for s in w.addressable_shards} == set(devices),
+          "data-parallel state sits on four distinct devices")
+    check(all(abs(a - b) <= tol for a, b in zip(dp_losses, single)),
+          f"data-parallel losses match the single-chip step (<= {tol})")
+
+    mesh = make_mesh({"dp": 2, "tp": 2}, devices)
+    tp_losses, state = sharded_bert_step(mesh, cfg, seq_len, batch, amp=True,
+                                         is_test=True, steps=2)
+    say(leg, f"compile_sharded_step on dp 2 x tp 2: {tp_losses}")
+    ffn = state["layer0_ffn1_w"]
+    shard_shapes = {tuple(s.data.shape) for s in ffn.addressable_shards}
+    check({s.device for s in ffn.addressable_shards} == set(devices)
+          and shard_shapes == {(cfg.hidden_size, cfg.intermediate_size // 2)},
+          "tensor-parallel FFN weight is split over tp on four distinct "
+          "devices")
+    check(all(abs(a - b) <= tol for a, b in zip(tp_losses, single)),
+          f"dp x tp losses match the single-chip step (<= {tol})")
+    routes = {k: n - routes_before.get(k, 0)
+              for k, n in route_totals().items()
+              if n > routes_before.get(k, 0)}
+    say(leg, f"routes taken in this leg: {routes}")
+    check(set(routes) == {"fused_multihead_attention:pallas"},
+          "on the meshes too every attention op took the Pallas route "
+          "(per shard, under shard_map)")
+    return {"ran": True}
+
+
+# ---------------------------------------------------------------------------
+
+def result_line(devices) -> str:
+    """The last line of stdout on success: exactly these keys, the device
+    as JAX reports it. Anything else the run has to say goes above it."""
+    return json.dumps({
+        "ok": True,
+        "device": {"platform": devices[0].platform,
+                   "kind": devices[0].device_kind, "count": len(devices)},
+    })
+
+
+def main(argv) -> int:
+    legs = list(argv) or list(LEGS)
+    unknown = [leg for leg in legs if leg not in LEGS]
+    if unknown:
+        print(f"chip_smoke: unknown leg(s) {unknown}; known: {LEGS}",
+              file=sys.stderr)
+        return 1
+
+    import jax
+    import jaxlib
+
+    from paddle_tpu.compile_cache import enable_compile_cache
+
+    cache_dir = enable_compile_cache()
+    devices = jax.devices()
+    dev = devices[0]
+    try:
+        from importlib.metadata import version
+
+        libtpu = version("libtpu")
+    except Exception:
+        libtpu = "not installed"
+    print(f"jax {jax.__version__}, jaxlib {jaxlib.__version__}, "
+          f"libtpu {libtpu}")
+    print(f"device: platform {dev.platform}, kind {dev.device_kind}, "
+          f"count {len(devices)}")
+    print(f"compile cache: {cache_dir} "
+          f"({len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0}"
+          f" entries at start)", flush=True)
+    if dev.platform != "tpu":
+        print(f"chip_smoke: needs a TPU, JAX found {devices} "
+              f"(JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r})",
+              file=sys.stderr)
+        return 1
+
+    failed, facts = [], {}
+    t_all = time.perf_counter()
+    for leg in legs:
+        print(f"== {leg}", flush=True)
+        t0 = time.perf_counter()
+        try:
+            facts[leg] = globals()[f"leg_{leg}"]()
+        except Exception:
+            # a failed leg fails the run (exit code below); the others
+            # still run so one chip call reports everything it can
+            traceback.print_exc()
+            failed.append(leg)
+        say(leg, f"leg wall {time.perf_counter() - t0:.1f} s")
+        gc.collect()
+    print(f"chip_smoke wall {time.perf_counter() - t_all:.1f} s, legs "
+          f"{legs}, failed {failed}", flush=True)
+    if failed:
+        print(f"chip_smoke: FAILED legs: {failed}", file=sys.stderr)
+        return 1
+    print("summary " + json.dumps({
+        "legs": legs,
+        "multichip_ran": bool(facts.get("multichip", {}).get("ran")),
+        "claim": None,
+    }))
+    print(result_line(devices), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))

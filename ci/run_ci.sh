@@ -5,7 +5,8 @@
 # fast: import check + CPU unit tests (8 virtual devices, what the repo's
 #       conftest configures)
 # full: fast + the multichip dry-run the round driver executes
-# tpu : the on-accelerator smoke suite (needs a real chip)
+# tpu : chip_smoke.py, then the on-accelerator smoke suite (needs a real
+#       chip; one process at a time owns it)
 set -euo pipefail
 cd "$(dirname "$0")/.."
 MODE="${1:-fast}"
@@ -319,13 +320,16 @@ echo "== unit tests (CPU, 8 virtual devices; FLAGS_check_program on via conftest
 python -m pytest tests/ -q -x
 
 if [ "$MODE" = "full" ]; then
-  echo "== multichip dry-run (8 virtual devices)"
+  echo "== multichip dry-run (8 VIRTUAL CPU devices)"
   JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
     python -c "import sys; sys.path.insert(0, '.'); \
                import __graft_entry__ as g; g.dryrun_multichip(8)"
 fi
 
 if [ "$MODE" = "tpu" ]; then
+  echo "== chip smoke (BERT-base training + GPT-2-base serving end to end,"
+  echo "   kernel routes against their oracles; fails without a TPU)"
+  python chip_smoke.py
   echo "== on-chip smoke suite"
   PADDLE_TPU_TESTS=1 python -m pytest tests/test_tpu_smoke.py -m tpu -q
 fi

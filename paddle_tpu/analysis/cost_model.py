@@ -38,9 +38,43 @@ from .liveness import _var_bytes
 
 __all__ = ["CostReport", "estimate_cost", "op_flops", "check_cost_model",
            "MATMUL_CLASS", "CommsReport", "estimate_comms",
-           "comms_compute_ratio"]
+           "comms_compute_ratio", "DevicePeak", "DEVICE_PEAKS",
+           "device_peak"]
 
 EMPTY = "@EMPTY@"
+
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeak:
+    """Published per-chip peaks of one accelerator."""
+
+    bf16_tflops: float
+    hbm_gbytes_per_s: float
+    hbm_gbytes: float
+    source: str
+
+
+# THE peaks table, keyed by jax's ``Device.device_kind``: the monitor's MFU
+# gauges, bench.py and tools/perf_probe.py all read it. A device that is
+# not listed — the CPU included — has no peak: no MFU gauge is set for it
+# and the benchmarks raise. Never a default for a device nobody asked
+# about.
+DEVICE_PEAKS: Dict[str, DevicePeak] = {
+    "TPU v5 lite": DevicePeak(
+        bf16_tflops=197.0, hbm_gbytes_per_s=819.0, hbm_gbytes=16.0,
+        source='Google Cloud documentation, "TPU v5e"'),
+}
+
+
+def device_peak(device_kind: str) -> DevicePeak:
+    """The table entry for ``device_kind``; a device that is not listed is
+    an error (what the benchmarks want — the monitor's gauges use
+    ``DEVICE_PEAKS.get`` and simply stay unset)."""
+    if device_kind not in DEVICE_PEAKS:
+        raise RuntimeError(
+            f"no published peak for device_kind {device_kind!r} in "
+            f"analysis.cost_model.DEVICE_PEAKS (has {sorted(DEVICE_PEAKS)})")
+    return DEVICE_PEAKS[device_kind]
 
 # ops whose grads cost exactly 2x forward (dgrad + wgrad / dQKV)
 MATMUL_CLASS = frozenset({"conv2d", "mul", "matmul",
@@ -87,13 +121,10 @@ class CostReport:
         denom = self.activation_bytes + self.param_bytes
         return self.flops_total / denom if denom else 0.0
 
-    def mfu(self, seconds_per_step: float,
-            peak_tflops: Optional[float] = None) -> float:
-        """Model FLOP utilisation of one measured step."""
-        if peak_tflops is None:
-            from ..flags import flag
-
-            peak_tflops = float(flag("device_peak_tflops"))
+    def mfu(self, seconds_per_step: float, peak_tflops: float) -> float:
+        """Model FLOP utilisation of one measured step against
+        ``peak_tflops`` (``DEVICE_PEAKS[device_kind].bf16_tflops`` of the
+        device that ran it)."""
         if seconds_per_step <= 0 or peak_tflops <= 0:
             return 0.0
         return self.flops_total / seconds_per_step / (peak_tflops * 1e12)
@@ -393,16 +424,13 @@ def estimate_comms(analysis) -> CommsReport:
 
 
 def comms_compute_ratio(comms: CommsReport, cost: CostReport,
-                        peak_tflops: Optional[float] = None,
+                        peak_tflops: float,
                         ici_gbytes_per_s: Optional[float] = None) -> float:
-    """Predicted comms-vs-compute ratio of one step: time on the wire over
-    time in the MXUs, both per chip (compute FLOPs divide by the mesh's
-    device count — the data-parallel split; >1.0 means the step is
-    predicted communication-bound)."""
-    if peak_tflops is None:
-        from ..flags import flag
-
-        peak_tflops = float(flag("device_peak_tflops"))
+    """Predicted comms-vs-compute ratio of one step on chips of
+    ``peak_tflops`` (``DEVICE_PEAKS``): time on the wire over time in the
+    MXUs, both per chip (compute FLOPs divide by the mesh's device count —
+    the data-parallel split; >1.0 means the step is predicted
+    communication-bound)."""
     n_dev = 1
     for s in comms.mesh.values():
         n_dev *= int(s)

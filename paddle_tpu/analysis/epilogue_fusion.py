@@ -421,21 +421,24 @@ def _run_witness(program: Program, fused_program: Program,
     actually runs, not the defaults."""
     import jax.numpy as jnp
 
-    from ..lowering import LowerCtx, lower_op
+    from ..lowering import LowerCtx, eager_platform, lower_op
 
     gb = program.global_block
+    # the witness EXECUTES the rules eagerly, so it runs (and routes) on
+    # the eager default device
+    platform = eager_platform()
     try:
         ext = sorted(set(chain.inputs.values()))
         base_env = _witness_inputs(gb, ext, batch=batch)
         env_a = {k: jnp.asarray(v) for k, v in base_env.items()}
-        ctx_a = LowerCtx(base_key=None, program=program)
+        ctx_a = LowerCtx(base_key=None, program=program, platform=platform)
         for oi in chain.op_indices:
             lower_op(gb.ops[oi], env_a, ctx_a)
         want = np.asarray(env_a[chain.out_name])
 
         env_b = {k: jnp.asarray(v) for k, v in base_env.items()}
         ctx_b = LowerCtx(base_key=None, program=fused_program,
-                         gemm_blocks=gemm_blocks)
+                         gemm_blocks=gemm_blocks, platform=platform)
         lower_op(fused_op, env_b, ctx_b)
         got = np.asarray(env_b[chain.out_name])
     except Exception as e:
@@ -454,7 +457,7 @@ def _run_witness(program: Program, fused_program: Program,
         route, _ = fused_gemm_route(
             m, n, k, layer_norm=bool(chain.attrs["layer_norm"]),
             blocks=resolve_gemm_blocks(ctx_b),
-            alpha=float(chain.attrs.get("alpha", 1.0)))
+            alpha=float(chain.attrs.get("alpha", 1.0)), platform=platform)
     except ValueError as e:       # use_fused_gemm=always on a bad tiling
         return str(e)
     wf = want.astype(np.float32)

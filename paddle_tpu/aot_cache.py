@@ -24,8 +24,9 @@ the compiled artifact:
 * the abstract signature of every argument leaf (shape + dtype + tree
   structure): state shapes come from the live scope, so two scopes with
   different-shaped state can never share an executable,
-* backend, jax version and framework version (an upgraded compiler's
-  executables are invisible, the cost-database staleness rule).
+* the platform of the devices the executable runs on, jax version and
+  framework version (an upgraded compiler's executables are invisible,
+  the cost-database staleness rule).
 
 Safety posture (the cost-database discipline): loads NEVER raise — a
 missing/corrupt/version-mismatched entry is a miss with one warning, and
@@ -91,7 +92,7 @@ def _count(name: str, help_: str, **labels) -> None:
         (c.labels(**labels) if labels else c).inc()
 
 
-def executable_key(parts: tuple, args) -> str:
+def executable_key(parts: tuple, args, devices) -> str:
     """Durable identity of one compiled executable.
 
     ``parts`` is the executor-stamped tuple
@@ -100,7 +101,8 @@ def executable_key(parts: tuple, args) -> str:
     autotuner's restart-stable hash — one identity shared by the cost
     database and this cache). ``args`` are the exact call arguments the
     executable will be lowered with; only their abstract signature
-    (tree structure + per-leaf shape/dtype) enters the key.
+    (tree structure + per-leaf shape/dtype) enters the key. ``devices``
+    are the devices it executes on (their platform enters the key).
     """
     import jax
 
@@ -114,7 +116,7 @@ def executable_key(parts: tuple, args) -> str:
         for v in leaves)
     fw, jx = _versions()
     material = repr((kind, fp, tuple(rest), leaf_sig, str(treedef),
-                     jax.default_backend(), fw, jx))
+                     devices[0].platform, fw, jx))
     return hashlib.sha256(material.encode()).hexdigest()[:32]
 
 
@@ -122,10 +124,11 @@ def _path(cache_dir: str, key: str) -> str:
     return os.path.join(cache_dir, key + _SUFFIX)
 
 
-def load_executable(cache_dir: str, key: str):
-    """The deserialized-and-loaded executable for ``key``, or None.
-    Counts a hit or a miss; never raises (corrupt/alien entries degrade
-    to a miss with one warning)."""
+def load_executable(cache_dir: str, key: str, devices):
+    """The executable for ``key`` deserialized and loaded onto ``devices``
+    (the step's own — ``deserialize_and_load`` otherwise spreads it over
+    every local device), or None. Counts a hit or a miss; never raises
+    (corrupt/alien entries degrade to a miss with one warning)."""
     path = _path(cache_dir, key)
     try:
         if not os.path.exists(path):
@@ -134,14 +137,13 @@ def load_executable(cache_dir: str, key: str):
             return None
         with open(path, "rb") as f:
             blob = pickle.load(f)
-        import jax
         from jax.experimental.serialize_executable import \
             deserialize_and_load
 
         fw, jx = _versions()
         if (not isinstance(blob, dict) or blob.get("schema") != _SCHEMA
                 or blob.get("jax") != jx or blob.get("framework") != fw
-                or blob.get("backend") != jax.default_backend()):
+                or blob.get("backend") != devices[0].platform):
             # a different compiler's executable is not a corrupt file —
             # it is simply not ours to load (staleness rule)
             _count("aot_cache_misses_total",
@@ -152,7 +154,9 @@ def load_executable(cache_dir: str, key: str):
                        path)
             return None
         loaded = deserialize_and_load(blob["payload"], blob["in_tree"],
-                                      blob["out_tree"])
+                                      blob["out_tree"],
+                                      backend=devices[0].client,
+                                      execution_devices=devices)
         _count("aot_cache_hits_total",
                "compiles skipped by loading a serialized AOT executable")
         return loaded
@@ -166,24 +170,26 @@ def load_executable(cache_dir: str, key: str):
         return None
 
 
-def save_executable(cache_dir: str, key: str, compiled) -> bool:
-    """Serialize ``compiled`` under ``key`` (atomic publish). Returns
-    whether the entry was written; failures warn once and return False —
-    a replica that cannot persist executables still serves."""
+def save_executable(cache_dir: str, key: str, compiled, devices) -> bool:
+    """Serialize ``compiled`` (built for ``devices``) under ``key`` (atomic
+    publish). Returns whether the entry was written; failures warn once
+    and return False — a replica that cannot persist executables still
+    serves."""
     try:
-        import jax
         from jax.experimental.serialize_executable import (
             deserialize_and_load, serialize)
 
         payload, in_tree, out_tree = serialize(compiled)
         # validate BEFORE publishing: an executable that itself came out
         # of jax's persistent compilation cache serializes to a blob
-        # that cannot load back ("Symbols not found" on XLA:CPU, jax
-        # 0.4.x) — publishing it would poison every future warm start.
+        # that cannot load back ("Symbols not found" on XLA:CPU) —
+        # publishing it would poison every future warm start.
         # One deserialize costs milliseconds against the seconds the
         # entry saves; an unloadable blob is simply never published.
         try:
-            deserialize_and_load(payload, in_tree, out_tree)
+            deserialize_and_load(payload, in_tree, out_tree,
+                                 backend=devices[0].client,
+                                 execution_devices=devices)
         except Exception as e:
             _count("aot_cache_errors_total",
                    "AOT executable cache operations that failed "
@@ -198,7 +204,7 @@ def save_executable(cache_dir: str, key: str, compiled) -> bool:
             return False
         fw, jx = _versions()
         blob = {"schema": _SCHEMA, "framework": fw, "jax": jx,
-                "backend": jax.default_backend(), "payload": payload,
+                "backend": devices[0].platform, "payload": payload,
                 "in_tree": in_tree, "out_tree": out_tree}
         os.makedirs(cache_dir, exist_ok=True)
         path = _path(cache_dir, key)

@@ -48,7 +48,7 @@ from .. import io as io_mod
 from .. import monitor as _monitor
 from .. import resilience as _resilience
 from .. import trace as _trace
-from ..executor import CPUPlace, Executor, Scope, scope_guard
+from ..executor import Executor, Scope, scope_guard
 from ..framework import Program, program_guard
 from ..parallel.compiled_program import CompiledProgram
 from ..resilience import elastic as _elastic
@@ -132,8 +132,8 @@ class Trainer:
                 loss = loss[0]
             self.loss = loss
             optimizer_func().minimize(loss)
-        self.place = place or CPUPlace()
-        self.exe = Executor(self.place)
+        self.exe = Executor(place)
+        self.place = self.exe.place
         self.scope = Scope()
         self._parallel = parallel
         self._build_strategy = build_strategy
@@ -767,7 +767,7 @@ class Inferencer:
         self.startup_program = Program()
         with program_guard(self.main_program, self.startup_program):
             self.predict_var = infer_func()
-        self.exe = Executor(place or CPUPlace())
+        self.exe = Executor(place)
         self.scope = Scope()
         with scope_guard(self.scope):
             self.exe.run(self.startup_program)

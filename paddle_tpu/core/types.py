@@ -82,45 +82,15 @@ def np_dtype(dtype) -> np.dtype:
 # what a 64-bit dtype request degrades to when jax runs with x64 disabled
 _X64_FALLBACK = {"int64": "int32", "uint64": "uint32", "float64": "float32"}
 
-# memoized behavioural probe result: does THIS jax runtime actually deliver
-# 64-bit dtypes? None = not probed yet. (Runtime enable_x64 toggling after
-# the first probe is not observed — the same documented contract as the
-# flags module's env-var reads.)
-_X64_ACTIVE = None
-
 
 def _x64_active() -> bool:
-    """Whether jax delivers 64-bit dtypes, decided by BEHAVIOUR, not
-    introspection: convert an int64 numpy array (an implicit conversion
-    never warns) and look at what comes back. Two generations of
-    introspection broke here — ``jax.config.jax_enable_x64`` became an
-    always-truthy holder object, and ``jax.dtypes.canonicalize_dtype``
-    raised on some backend builds while every jnp constructor still
-    truncated-and-warned (the int64 spam in every BENCH tail at
-    ops/tensor.py:30). The empty-array conversion is what the runtime
-    actually does, so it cannot drift from the warning behaviour."""
-    global _X64_ACTIVE
-    if _X64_ACTIVE is None:
-        try:
-            import jax.numpy as jnp
+    """Whether jax delivers 64-bit dtypes. Read from the config, never by
+    running a computation: this is called while a ``Program`` is being
+    BUILT, and touching a backend there would make a parent process that
+    only builds programs take the chip from the child that runs them."""
+    import jax
 
-            _X64_ACTIVE = bool(
-                np.dtype(jnp.asarray(np.zeros(0, np.int64)).dtype).itemsize
-                == 8)
-        except Exception:
-            # probe impossible (backend init failure mid-teardown): fall
-            # back to canonicalize_dtype, else assume the common x64-off
-            # default — requesting the narrow type in an x64-on runtime
-            # merely loses width; requesting the wide one in an x64-off
-            # runtime is the warn-per-traced-op spam this exists to kill
-            try:
-                import jax
-
-                _X64_ACTIVE = bool(np.dtype(jax.dtypes.canonicalize_dtype(
-                    np.dtype("int64"))).itemsize == 8)
-            except Exception:
-                _X64_ACTIVE = False
-    return _X64_ACTIVE
+    return bool(jax.config.jax_enable_x64)
 
 
 def jnp_dtype(dtype) -> np.dtype:
@@ -129,10 +99,8 @@ def jnp_dtype(dtype) -> np.dtype:
     off, explicitly requesting int64/float64 makes every call site emit a
     truncation warning before silently downcasting — spamming bench output
     once per traced op. Canonicalize here instead: request exactly the type
-    jax will deliver anyway, decided by the behavioural probe
-    ``_x64_active`` (introspection-based probes failed open twice — see its
-    docstring). Host-side numpy arrays (feeds, serialized attrs) keep full
-    width via ``np_dtype``."""
+    jax will deliver anyway (``_x64_active``). Host-side numpy arrays
+    (feeds, serialized attrs) keep full width via ``np_dtype``."""
     dt = np_dtype(dtype)
     if dt.name in _X64_FALLBACK and not _x64_active():
         return np.dtype(_X64_FALLBACK[dt.name])

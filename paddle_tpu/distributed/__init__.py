@@ -57,27 +57,6 @@ class ParallelEnv:
 _initialized = False
 
 
-def force_cpu_device_count(n: int) -> None:
-    """Pin the CPU backend to ``n`` virtual devices across jax generations:
-    newer jax has the ``jax_num_cpu_devices`` config; 0.4.x only honours
-    the XLA_FLAGS env var, which must land before the backend initializes
-    (both paths require that — backend init freezes the topology)."""
-    import jax
-
-    try:
-        jax.config.update("jax_num_cpu_devices", int(n))
-    except AttributeError:  # jax 0.4.x
-        import re
-
-        # replace (not append after) an inherited count — a pytest parent's
-        # 8-virtual-device XLA_FLAGS must not leak into a 1-device worker
-        flags = re.sub(r"--xla_force_host_platform_device_count=\d+", "",
-                       os.environ.get("XLA_FLAGS", "")).strip()
-        os.environ["XLA_FLAGS"] = (
-            flags + f" --xla_force_host_platform_device_count={int(n)}"
-        ).strip()
-
-
 def is_initialized() -> bool:
     return _initialized
 
@@ -106,7 +85,7 @@ def init_parallel_env(coordinator_address: Optional[str] = None) -> ParallelEnv:
             raise RuntimeError(
                 "init_parallel_env must run before JAX initializes a backend")
         jax.config.update("jax_platforms", "cpu")
-        force_cpu_device_count(env.local_devices or 1)
+        jax.config.update("jax_num_cpu_devices", env.local_devices or 1)
         jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
     coord = coordinator_address or (

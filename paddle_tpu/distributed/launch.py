@@ -65,6 +65,14 @@ def launch(args=None) -> int:
     args = args or _parse_args()
     node_ips = [ip for ip in args.cluster_node_ips.split(",") if ip]
     nproc = args.nproc_per_node
+    if nproc > 1 and args.backend != "cpu":
+        # a chip belongs to one process: every child would initialise the
+        # real backend on this host, and all but the first would fail or
+        # hang waiting for chips the first one holds
+        raise ValueError(
+            f"--nproc_per_node {nproc} needs --backend cpu (the multi-host "
+            f"simulation): on real hardware ONE process drives every chip "
+            f"of the host, so launch one process per node")
     if args.started_port:
         ports = [args.started_port + i for i in range(nproc)]
     else:

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import registry
-from ..lowering import LowerCtx
+from ..lowering import LowerCtx, eager_platform
 
 __all__ = ["VarBase", "guard", "to_variable", "enabled", "in_dygraph_mode",
            "current_tape"]
@@ -203,7 +203,8 @@ class Tape:
             in_uids[slot] = uids
             in_vals[slot] = vals
 
-        ctx = LowerCtx(base_key=self.base_key, uid=pos)
+        ctx = LowerCtx(base_key=self.base_key, uid=pos,
+                       platform=eager_platform())
         outs = opdef.lower(ctx, in_vals, full_attrs) or {}
         out_vbs: Dict[str, List[VarBase]] = {}
         out_uids: Dict[str, List[int]] = {}
@@ -230,6 +231,7 @@ class Tape:
         entries = self.entries if entries is None else entries
         const = self.const_values
         base_key = self.base_key
+        platform = eager_platform()
 
         def fn(leaf_vals: List[Any]):
             env = dict(const)
@@ -238,7 +240,8 @@ class Tape:
                 ins = {slot: [env.get(u) if u is not None else None
                               for u in uids]
                        for slot, uids in e.ins.items()}
-                ctx = LowerCtx(base_key=base_key, uid=e.pos)
+                ctx = LowerCtx(base_key=base_key, uid=e.pos,
+                               platform=platform)
                 outs = e.opdef.lower(ctx, ins, e.attrs) or {}
                 for slot, vals in outs.items():
                     if not isinstance(vals, (list, tuple)):

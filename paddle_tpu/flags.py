@@ -150,11 +150,6 @@ _DEFS: Dict[str, tuple] = {
                              "0 disables the recorder (incidents then "
                              "ship without span context — the "
                              "trace_check negative control)"),
-    "device_peak_tflops": (float, 197.0,
-                           "accelerator peak dense TF/s used for the "
-                           "cost-model MFU gauges (default: v5e bf16 "
-                           "peak; set per deployment). "
-                           "docs/PERF_NOTES.md"),
     "ici_gbytes_per_s": (float, 100.0,
                          "effective per-chip interconnect bandwidth "
                          "(GB/s) for the predicted comms-vs-compute "

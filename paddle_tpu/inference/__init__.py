@@ -20,7 +20,7 @@ from typing import Dict, List, Optional, Sequence
 import numpy as np
 
 from .. import io as io_mod
-from ..executor import CPUPlace, Executor, Scope, TPUPlace, scope_guard
+from ..executor import CPUPlace, Executor, Scope, scope_guard
 
 __all__ = ["AnalysisConfig", "AnalysisPredictor", "ZeroCopyTensor",
            "create_paddle_predictor", "export_stablehlo", "load_stablehlo",
@@ -99,8 +99,9 @@ class AnalysisPredictor:
 
     def __init__(self, config: AnalysisConfig):
         self._config = config
-        place = TPUPlace() if config.use_gpu() else CPUPlace()
-        self._exe = Executor(place)
+        # disable_gpu() pins the host; otherwise the default place — the
+        # accelerator wherever JAX has one
+        self._exe = Executor(None if config.use_gpu() else CPUPlace())
         self._scope = Scope()
         model_dir = config.model_dir()
         model_fn = params_fn = None

@@ -4,7 +4,7 @@ kernels. Everything here must also run under `interpret=True` on CPU (minus
 PRNG-dependent paths) so numerics are testable without hardware."""
 from .flash_attention import (classify_shapes, flash_attention,
                               flash_attention_with_lse, supports_shapes)
-from .decode_attention import (decode_attention_reference,
+from .decode_attention import (KERNEL_ROWS, decode_attention_reference,
                                flash_attention_decode, paged_kv_append,
                                paged_kv_append_rows)
 from .fused_gemm import (classify_gemm, fused_gemm, fused_gemm_reference,
@@ -12,6 +12,6 @@ from .fused_gemm import (classify_gemm, fused_gemm, fused_gemm_reference,
 
 __all__ = ["flash_attention", "flash_attention_with_lse", "supports_shapes",
            "classify_shapes", "flash_attention_decode", "paged_kv_append",
-           "paged_kv_append_rows",
+           "paged_kv_append_rows", "KERNEL_ROWS",
            "decode_attention_reference", "fused_gemm", "classify_gemm",
            "supports_gemm", "fused_gemm_reference"]

@@ -48,10 +48,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import NEG_INF, CompilerParams, _out_sds
+from .flash_attention import NEG_INF, _out_sds
 
 __all__ = ["flash_attention_decode", "paged_kv_append",
-           "paged_kv_append_rows", "decode_attention_reference"]
+           "paged_kv_append_rows", "decode_attention_reference",
+           "KERNEL_ROWS"]
+
+# query rows one kernel call serves: the chunk rides ONE f32 sublane tile
+KERNEL_ROWS = 8
 
 
 def paged_kv_append(cache, new, positions):
@@ -84,14 +88,38 @@ def paged_kv_append_rows(cache, new, positions):
     onto the LAST row — and the last row is never inside a live length
     mask (the serving layer caps ``prompt + max_new <= S_max`` and the
     final generated token is never appended), so overflow is unreadable
-    garbage, not corruption."""
+    garbage, not corruption.
+
+    Two lowerings of the same result, chosen by the row count. Up to
+    ``KERNEL_ROWS`` rows — the decode step and the verify chunk, the
+    shapes the Pallas kernel serves — one ``dynamic_update_slice`` per
+    row: XLA updates the donated cache in place next to the kernel's
+    custom call (compiled for v5e, decode needs 0.12 GiB of temporaries
+    this way and 1.7 GiB with a scatter). Past that — chunked-prefill
+    slices, which ride the primitive path anyway — ONE scatter: unrolled,
+    a 128-row chunk was 3,072 update ops over 12 layers and its compile
+    took minutes where its siblings take seconds."""
     S = cache.shape[-2]
     C = new.shape[-2]
     positions = positions.reshape(positions.shape[0]).astype(jnp.int32)
-    for i in range(C):
-        row_pos = jnp.minimum(positions + i, S - 1)
-        cache = paged_kv_append(cache, new[..., i:i + 1, :], row_pos)
-    return cache
+    if C <= KERNEL_ROWS:
+        for i in range(C):
+            row_pos = jnp.minimum(positions + i, S - 1)
+            cache = paged_kv_append(cache, new[..., i:i + 1, :], row_pos)
+        return cache
+    rows = positions[:, None] + jnp.arange(C, dtype=jnp.int32)    # [B, C]
+    # every row at or past S-1 clamps onto the last cache row, where the
+    # chunk's LAST row wins (what the row-by-row form does). The rows it
+    # shadows go out of range, where mode="drop" discards them: indices
+    # stay unique, so the result does not depend on the order a backend
+    # applies a scatter in
+    shadowed = (rows >= S - 1) & (jnp.arange(C) < C - 1)
+    idx = jnp.where(shadowed, S, jnp.minimum(rows, S - 1))
+
+    def upd(c, n, r):
+        return c.at[..., r, :].set(n.astype(c.dtype), mode="drop")
+
+    return jax.vmap(upd)(cache, new, idx)
 
 
 def decode_attention_reference(q, k_cache, v_cache, lengths, scale):
@@ -178,11 +206,11 @@ def flash_attention_decode(q, k_cache, v_cache, lengths, *,
     """
     BH, Sq, D = q.shape
     Sk = k_cache.shape[1]
-    if not 1 <= Sq <= 8:
+    if not 1 <= Sq <= KERNEL_ROWS:
         raise ValueError(
-            f"flash_attention_decode is the q_len<=8 chunk path (one "
-            f"sublane tile), got q_len={Sq}; use flash_attention for "
-            f"prefill/full-sequence shapes")
+            f"flash_attention_decode is the q_len<={KERNEL_ROWS} chunk "
+            f"path (one sublane tile), got q_len={Sq}; use flash_attention "
+            f"for prefill/full-sequence shapes")
     bk = min(page_size, Sk)
     if Sk % bk:
         raise ValueError(
@@ -223,7 +251,7 @@ def flash_attention_decode(q, k_cache, v_cache, lengths, *,
         functools.partial(_decode_kernel, scale, int(num_heads)),
         grid_spec=grid_spec,
         out_shape=[_out_sds((BH, 8, D), q.dtype, q, k_cache, v_cache)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(lengths, q8, k_cache, v_cache)

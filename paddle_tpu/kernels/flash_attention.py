@@ -50,10 +50,6 @@ __all__ = ["flash_attention", "flash_attention_with_lse", "supports_shapes",
 
 NEG_INF = -1e30          # finite sentinel: (-inf) - (-inf) would NaN
 
-# jax renamed TPUCompilerParams -> CompilerParams around 0.5; accept both so
-# the kernels load on either side of the rename
-CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
 # odd mixing constants for per-block reseeding, pre-wrapped to int32 range
 # (jax int32 multiply wraps, which is exactly the mixing we want)
 _SEED_MIX_BH = -1640532047   # int32(0x9E3779B1)
@@ -262,7 +258,7 @@ def _fwd(cfg: _Cfg, q, k, v, bias, scalars):
             _out_sds((BH, Sq, D), q.dtype, q, k, v),
             _out_sds((BH, 8, Sq), jnp.float32, q, k, v),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=cfg.interpret,
     )(scalars, *args)
@@ -400,7 +396,7 @@ def _bwd(cfg: _Cfg, q, k, v, bias, scalars, do, lse, delta):
             scratch_shapes=[pltpu.VMEM((cfg.block_q, D), jnp.float32)],
         ),
         out_shape=[_out_sds((BH, Sq, D), q.dtype, q, k, v, do)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=cfg.interpret,
     )(scalars, *args)[0]
@@ -430,7 +426,7 @@ def _bwd(cfg: _Cfg, q, k, v, bias, scalars, do, lse, delta):
         ),
         out_shape=[_out_sds((BH, Sk, D), k.dtype, q, k, v, do),
                    _out_sds((BH, Sk, D), v.dtype, q, k, v, do)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=cfg.interpret,
     )(scalars, *args)

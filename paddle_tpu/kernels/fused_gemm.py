@@ -252,10 +252,6 @@ def fused_gemm(x, y, bias=None, residual=None, ln_scale=None, ln_bias=None,
         in_specs.append(rowspec)
         args.append(_rows8(ln_bias))
 
-    # jax renamed TPUCompilerParams -> CompilerParams around 0.5 (see
-    # flash_attention.py) — accept both
-    CompilerParams = getattr(pltpu, "CompilerParams", None) \
-        or pltpu.TPUCompilerParams
     out = pl.pallas_call(
         functools.partial(_kernel, cfg),
         grid=(m // bm, n // bn, k // bk),
@@ -263,7 +259,7 @@ def fused_gemm(x, y, bias=None, residual=None, ln_scale=None, ln_bias=None,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=cfg.interpret,
     )(*args)

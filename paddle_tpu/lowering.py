@@ -74,10 +74,17 @@ class LowerCtx:
 
     def __init__(self, base_key=None, uid: int = 0, mesh=None, axis_env=None,
                  program=None, nan_checks=None, gemm_blocks=None,
-                 num_taps=None):
+                 num_taps=None, platform=None):
         self.base_key = base_key
         self.uid = uid
         self.mesh = mesh          # jax.sharding.Mesh when lowering under shard_map
+        # platform ("tpu", "cpu", ...) of the single device this step is
+        # lowered for, stamped by whoever builds the step (the executor:
+        # its place's device; eager callers: ``eager_platform()``). None =
+        # no device at all (build-time shape inference, portable StableHLO
+        # export). Read through ``lowering_platform``, which lets a mesh
+        # speak for itself.
+        self.platform = platform
         self.axis_env = axis_env  # dict of mesh axis names usable in collectives
         self.program = program    # owning Program: sub-block lookup for while/cond
         # FLAGS_check_nan_inf: list collecting (label, finite-bool-scalar)
@@ -108,7 +115,57 @@ class LowerCtx:
     def with_uid(self, uid: int) -> "LowerCtx":
         return LowerCtx(self.base_key, uid, self.mesh, self.axis_env,
                         self.program, self.nan_checks, self.gemm_blocks,
-                        self.num_taps)
+                        self.num_taps, self.platform)
+
+
+def lowering_platform(ctx: Optional[LowerCtx] = None, mesh=None):
+    """Platform of the device(s) the step being traced will run on — the
+    ONE thing every kernel and layout route keys on (Pallas vs primitive
+    attention/GEMM, NHWC convs, ``interpret=``). A mesh names its own
+    devices; otherwise it is the platform the step's builder stamped on
+    the ctx. ``None`` means "lowered for no device" and takes the
+    portable primitive routes.
+
+    Never the process default (``jax.default_backend()``): an
+    ``Executor(CPUPlace())`` on a TPU host must not lower Mosaic kernels
+    under ``jax.default_device(cpu)``, and a step lowered for a TPU from a
+    CPU-default process must not silently lose them."""
+    if mesh is None and ctx is not None:
+        mesh = ctx.mesh
+    if mesh is not None:
+        return mesh.devices.flat[0].platform
+    return ctx.platform if ctx is not None else None
+
+
+def eager_platform() -> str:
+    """Platform un-jitted jax ops run on right now: the innermost
+    ``jax.default_device`` when one is active, else the process default.
+    Only for callers that EXECUTE op rules eagerly (dygraph, the fusion
+    witness) — there the default device is the lowering device. Compiled
+    steps get theirs from the executor's place or the mesh."""
+    dev = jax.config.jax_default_device
+    if dev is None:
+        return jax.default_backend()
+    return dev if isinstance(dev, str) else dev.platform
+
+
+def note_kernel_route(ctx: Optional[LowerCtx], op: str, route: str) -> None:
+    """Count the path one kernel-routed op took at trace time
+    (``kernel_route_total{op, route, program}``): which programs ride a
+    Pallas kernel, the interpreter or the primitive composition is then
+    readable from the monitor registry instead of inferred from flags
+    (``chip_smoke.py`` prints it per executable)."""
+    from . import monitor
+
+    # a ctx with no device is build-time shape inference, not a lowering
+    if lowering_platform(ctx) is None or not monitor.enabled():
+        return
+    monitor.counter(
+        "kernel_route_total",
+        "kernel-routed op lowerings by op type, route taken "
+        "(pallas | pallas-interpret | primitive) and program serial"
+    ).labels(op=op, route=route,
+             program=str(int(getattr(ctx.program, "_serial", -1)))).inc()
 
 
 def _gather_inputs(op, env: Dict[str, Any]) -> Dict[str, List[Any]]:

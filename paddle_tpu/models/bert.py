@@ -11,6 +11,8 @@ from __future__ import annotations
 import dataclasses
 import math
 
+import numpy as np
+
 from .. import layers, optimizer as opt_mod
 from ..framework import Program, program_guard
 from ..initializer import Normal, TruncatedNormal
@@ -197,3 +199,24 @@ def build_bert_pretrain(cfg: BertConfig = None, seq_len: int = 128,
             "mlm_loss": mlm_loss, "nsp_loss": nsp_loss,
             "feeds": ("src_ids", "pos_ids", "sent_ids", "input_mask",
                       "mask_label", "next_sent_label")}
+
+
+def synthetic_pretrain_batch(cfg: BertConfig, batch: int, seq_len: int,
+                             seed: int = 0) -> dict:
+    """One seeded pretraining feed for :func:`build_bert_pretrain` (host
+    numpy): random tokens, full-length sequences, every 7th position
+    masked for the MLM head. The same batch for the smoke, the bench and
+    the multichip dry run, so their losses are comparable."""
+    rng = np.random.RandomState(seed)
+    mask_label = np.full((batch, seq_len), -100, np.int64)
+    mask_label[:, ::7] = rng.randint(0, cfg.vocab_size,
+                                     mask_label[:, ::7].shape)
+    return {
+        "src_ids": rng.randint(0, cfg.vocab_size,
+                               (batch, seq_len)).astype(np.int64),
+        "pos_ids": np.tile(np.arange(seq_len, dtype=np.int64), (batch, 1)),
+        "sent_ids": np.zeros((batch, seq_len), np.int64),
+        "input_mask": np.ones((batch, seq_len), np.float32),
+        "mask_label": mask_label,
+        "next_sent_label": rng.randint(0, 2, (batch, 1)).astype(np.int64),
+    }

@@ -196,7 +196,8 @@ def step_end(rec: Optional[StepRecord]) -> None:
         prog = getattr(rec, "_program", None)
         if prog is not None and rec.batch_rows:
             observe_step_cost(prog, rec.batch_rows, rec.duration_s,
-                              iterations=rec.iterations, path=rec.path)
+                              iterations=rec.iterations, path=rec.path,
+                              device_kind=rec.device_kind)
     if rec.feed_bytes:
         counter("executor_feed_bytes_total",
                 "host->device feed transfer bytes").inc(rec.feed_bytes)
@@ -257,21 +258,31 @@ def program_cost(program, batch: int):
     return rep
 
 
+def _peak_tflops(device_kind: str) -> Optional[float]:
+    """bf16 peak of ``device_kind`` from THE peaks table
+    (``analysis.cost_model.DEVICE_PEAKS``); None for a device that is not
+    in it — its MFU gauges are then not set at all."""
+    from ..analysis.cost_model import DEVICE_PEAKS
+
+    peak = DEVICE_PEAKS.get(device_kind)
+    return peak.bf16_tflops if peak is not None else None
+
+
 def observe_step_cost(program, batch: int, duration_s: float,
-                      iterations: int = 1, path: str = "run"):
+                      iterations: int = 1, path: str = "run",
+                      device_kind: str = ""):
     """Turn one measured dispatch into the cost-model gauges:
     ``executor_model_gflops_per_step`` (static, per program+batch),
-    ``executor_achieved_tflops`` and ``executor_mfu`` (per path+program+
-    batch, against ``FLAGS_device_peak_tflops``). Returns the achieved
-    TF/s, or None when disabled/unmeasurable."""
+    ``executor_achieved_tflops`` and — only when ``device_kind`` (the
+    device that ran the step) has an entry in the peaks table —
+    ``executor_mfu`` (per path+program+batch). Returns the achieved TF/s,
+    or None when disabled/unmeasurable."""
     if not enabled() or not duration_s or duration_s <= 0:
         return None
     rep = program_cost(program, batch)
     if rep is None or rep.flops_total <= 0:
         return None
-    from ..flags import flag
-
-    peak = float(flag("device_peak_tflops"))
+    peak = _peak_tflops(device_kind)
     achieved = rep.flops_total * max(1, int(iterations)) / duration_s / 1e12
     labels = {"path": path,
               "program": str(int(getattr(program, "_serial", -1))),
@@ -284,43 +295,44 @@ def observe_step_cost(program, batch: int, duration_s: float,
     gauge("executor_achieved_tflops",
           "achieved model TF/s of the most recent dispatch, by path/"
           "program/batch").labels(**labels).set(achieved)
-    if peak > 0:
+    if peak is not None:
         gauge("executor_mfu",
-              "model-FLOP utilisation of the most recent dispatch vs "
-              "FLAGS_device_peak_tflops").labels(**labels).set(
-            achieved / peak)
+              "model-FLOP utilisation of the most recent dispatch vs the "
+              "published bf16 peak of the device that ran it").labels(
+            **labels).set(achieved / peak)
     return achieved
 
 
 def observe_serving_cost(program, padded_rows: int, batch_s: float,
-                         bucket: str):
+                         bucket: str, device_kind: str = ""):
     """Serving flavour of :func:`observe_step_cost`: per shape-bucket
     ``serving_bucket_achieved_tflops`` / ``serving_bucket_mfu`` gauges
-    from one dispatched batch's wall time."""
+    from one dispatched batch's wall time (the MFU gauge only for a
+    ``device_kind`` in the peaks table)."""
     if not enabled() or not batch_s or batch_s <= 0:
         return None
     rep = program_cost(program, padded_rows)
     if rep is None or rep.flops_total <= 0:
         return None
-    from ..flags import flag
-
-    peak = float(flag("device_peak_tflops"))
+    peak = _peak_tflops(device_kind)
     achieved = rep.flops_total / batch_s / 1e12
     gauge("serving_bucket_achieved_tflops",
           "achieved model TF/s of the most recent batch, per shape "
           "bucket").labels(bucket=bucket).set(achieved)
-    if peak > 0:
+    if peak is not None:
         gauge("serving_bucket_mfu",
-              "model-FLOP utilisation of the most recent batch vs "
-              "FLAGS_device_peak_tflops, per shape bucket").labels(
-            bucket=bucket).set(achieved / peak)
+              "model-FLOP utilisation of the most recent batch vs the "
+              "published bf16 peak of the device that ran it, per shape "
+              "bucket").labels(bucket=bucket).set(achieved / peak)
     return achieved
 
 
-def observe_comms_cost(program, comms, cost=None) -> None:
+def observe_comms_cost(program, comms, cost=None,
+                       device_kind: str = "") -> None:
     """Static-sharding comms gauges (analysis.cost_model.estimate_comms):
     ``executor_comms_gbytes_per_step`` — predicted per-chip collective
-    wire volume of one step under the compiled sharding assignment — and
+    wire volume of one step under the compiled sharding assignment — and,
+    for a mesh of ``device_kind`` chips that the peaks table lists,
     ``executor_comms_compute_ratio`` — predicted wire time over MXU time
     (>1 = communication-bound). Labels carry the program serial and the
     mesh shape so multi-mesh runs stay distinguishable."""
@@ -333,13 +345,14 @@ def observe_comms_cost(program, comms, cost=None) -> None:
           "predicted per-chip collective wire GB of one step under the "
           "static sharding assignment, by program and mesh").labels(
         **labels).set(comms.gbytes_per_step)
-    if cost is not None and cost.flops_total > 0:
+    peak = _peak_tflops(device_kind)
+    if cost is not None and cost.flops_total > 0 and peak is not None:
         from ..analysis.cost_model import comms_compute_ratio
 
         gauge("executor_comms_compute_ratio",
               "predicted comms-vs-compute time ratio of one step "
               "(>1 = communication-bound), by program and mesh").labels(
-            **labels).set(comms_compute_ratio(comms, cost))
+            **labels).set(comms_compute_ratio(comms, cost, peak))
 
 
 def record_watchdog_timeout(section: str) -> None:

@@ -41,6 +41,7 @@ class StepRecord:
     donated_bytes: int = 0           # live bytes of the donated buffers
     batch_rows: int = 0              # leading feed dim (cost-model batch)
     fetch_names: Tuple[str, ...] = ()
+    device_kind: str = ""            # jax device_kind the step ran on
 
     def to_dict(self) -> dict:
         return dataclasses.asdict(self)

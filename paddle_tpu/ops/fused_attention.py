@@ -7,8 +7,9 @@ operators/jit/kernel_base.h). On TPU the one attention-shaped fusion XLA
 cannot do itself — never materialising the [S, S] score matrix — is the
 Pallas flash-attention kernel (kernels/flash_attention.py). This op routes:
 
-- TPU backend + supported shapes -> compiled Pallas kernel (in-kernel
-  PRNG dropout, online softmax, two-kernel flash backward);
+- step lowered for a TPU (``lowering.lowering_platform``) + supported
+  shapes -> compiled Pallas kernel (in-kernel PRNG dropout, online
+  softmax, two-kernel flash backward);
 - anything else -> an equivalent primitive composition that XLA fuses as
   well as it can (and which serves as the numerics oracle in tests).
 
@@ -17,15 +18,20 @@ Pallas flash-attention kernel (kernels/flash_attention.py). This op routes:
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from .common import IOSpec, register_op, x
 from .. import flags
+from ..lowering import lowering_platform, note_kernel_route
 
 
-def _route(sq: int, sk: int, dropout: float) -> str:
-    """'pallas' | 'pallas-interpret' | 'primitive'."""
+def _route(sq: int, sk: int, dropout: float, platform=None) -> str:
+    """'pallas' | 'pallas-interpret' | 'primitive' for a step lowered for
+    ``platform``."""
     from ..kernels import classify_shapes
 
     mode = flags.flag("use_flash_attention")
@@ -38,8 +44,7 @@ def _route(sq: int, sk: int, dropout: float) -> str:
                 f"FLAGS_use_flash_attention=always but seq lengths "
                 f"({sq}, {sk}) have no kernel tiling: {reason}")
         return "primitive"
-    on_tpu = jax.default_backend() == "tpu"
-    if on_tpu:
+    if platform == "tpu":
         return "pallas"
     if mode == "always":
         if dropout > 0.0:
@@ -48,7 +53,8 @@ def _route(sq: int, sk: int, dropout: float) -> str:
             # needs has no interpret-mode lowering
             raise NotImplementedError(
                 "FLAGS_use_flash_attention=always with attn_dropout>0 "
-                "requires a TPU backend (in-kernel PRNG dropout)")
+                "requires a step lowered for a TPU (in-kernel PRNG "
+                "dropout)")
         return "pallas-interpret"
     return "primitive"
 
@@ -128,22 +134,74 @@ def _fused_mha(ctx, ins, attrs):
             raise ValueError(
                 f"BiasQK must be [B, S] or [B, 1, 1, S], got {bias.shape}")
 
-    q3 = q.reshape(B * H, Sq, D)
-    k3 = k.reshape(B * H, Sk, D)
-    v3 = v.reshape(B * H, Sk, D)
-    route = _route(Sq, Sk, dropout)
+    route = _route(Sq, Sk, dropout, platform=lowering_platform(ctx))
+    note_kernel_route(ctx, "fused_multihead_attention", route)
     if route == "primitive":
-        o = _primitive_attention(ctx, q3, k3, v3, bias, causal, scale,
-                                 dropout, attrs.get("is_test", False))
-    else:
-        from ..kernels import flash_attention
+        o = _primitive_attention(ctx, q.reshape(B * H, Sq, D),
+                                 k.reshape(B * H, Sk, D),
+                                 v.reshape(B * H, Sk, D), bias, causal,
+                                 scale, dropout, attrs.get("is_test", False))
+        return {"Out": [o.reshape(B, H, Sq, D)]}
 
-        # deterministic seed tied to this op instance: the grad op folds in
-        # the forward uid, so backward regenerates identical dropout masks
-        seed = jax.lax.convert_element_type(
-            jax.random.bits(ctx.rng(), (), jnp.uint32) >> 1, jnp.int32)
-        o = flash_attention(q3, k3, v3, bias=bias, causal=causal,
-                            scale=scale, dropout_rate=dropout, seed=seed,
-                            num_heads=H,
-                            interpret=(route == "pallas-interpret"))
-    return {"Out": [o.reshape(B, H, Sq, D)]}
+    # deterministic seed tied to this op instance: the grad op folds in
+    # the forward uid, so backward regenerates identical dropout masks
+    seed = jax.lax.convert_element_type(
+        jax.random.bits(ctx.rng(), (), jnp.uint32) >> 1, jnp.int32)
+    kernel = functools.partial(
+        _kernel_attention, causal=causal, scale=scale, dropout=dropout,
+        interpret=(route == "pallas-interpret"))
+    # one device, or already inside a shard_map body (a pipeline stage):
+    # the kernel sees its own block either way
+    if ctx.mesh is None or jax.sharding.get_abstract_mesh().manual_axes:
+        return {"Out": [kernel(seed, q, k, v, bias)]}
+    return {"Out": [_kernel_attention_on_mesh(kernel, ctx.mesh, seed,
+                                              q, k, v, bias)]}
+
+
+def _kernel_attention(seed, q, k, v, bias=None, *, causal, scale, dropout,
+                      interpret):
+    """The flash kernel over one [B, H, S, D] block (bias [B, Sk])."""
+    from ..kernels import flash_attention
+
+    B, H, Sq, D = q.shape
+    Sk = k.shape[2]
+    o = flash_attention(q.reshape(B * H, Sq, D), k.reshape(B * H, Sk, D),
+                        v.reshape(B * H, Sk, D), bias=bias, causal=causal,
+                        scale=scale, dropout_rate=dropout, seed=seed,
+                        num_heads=H, interpret=interpret)
+    return o.reshape(B, H, Sq, D)
+
+
+def _kernel_attention_on_mesh(kernel, mesh, seed, q, k, v, bias):
+    """Under a mesh the step is partitioned by GSPMD, and a Mosaic kernel
+    cannot be ("Mosaic kernels cannot be automatically partitioned" — the
+    first thing the four-chip host said): run it per shard under
+    ``shard_map``. Attention is independent per batch row and per head, so
+    the batch splits over 'dp' and the heads over 'tp' — the Megatron
+    layout the q/k/v projections already produce — and no collective is
+    needed; an axis that does not divide its dim stays unsplit."""
+    B, H = q.shape[:2]
+
+    def axis(name, dim):
+        return name if name in mesh.axis_names \
+            and dim % mesh.shape[name] == 0 else None
+
+    b_ax, h_ax = axis("dp", B), axis("tp", H)
+    spec = P(b_ax, h_ax, None, None)
+
+    def local(seed, *blocks):
+        # a shard-local seed: shards must not share dropout masks
+        for ax, mix in ((b_ax, 0x9E3779B1), (h_ax, 0x85EBCA77)):
+            if ax is not None:
+                seed = seed ^ (jax.lax.axis_index(ax).astype(jnp.int32)
+                               * jnp.int32(mix & 0x7FFFFFFF))
+        return kernel(seed, *blocks)
+
+    args, specs = [seed, q, k, v], [P(), spec, spec, spec]
+    if bias is not None:
+        args.append(bias)
+        specs.append(P(b_ax, None))
+    # check_vma off: the kernel's scalar operands vary per shard (see
+    # parallel/ring_attention.py)
+    return jax.shard_map(local, mesh=mesh, in_specs=tuple(specs),
+                         out_specs=spec, check_vma=False)(*args)

@@ -4,7 +4,7 @@ into (analysis/epilogue_fusion.py; CODA, PAPERS.md).
 
 Routing mirrors fused_attention.py:
 
-- TPU backend + supported tiling -> the Pallas fused-GEMM kernel
+- step lowered for a TPU + supported tiling -> the Pallas fused-GEMM kernel
   (kernels/fused_gemm.py): the whole epilogue runs on the in-VMEM f32
   accumulator tile;
 - anything else -> a dense replay of the ORIGINAL unfused op rules, in the
@@ -26,12 +26,12 @@ from __future__ import annotations
 
 from typing import Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from .. import flags
 from ..core import registry
+from ..lowering import lowering_platform, note_kernel_route
 from .common import IOSpec, register_op, x
 
 __all__ = ["fused_gemm_route", "resolve_gemm_blocks"]
@@ -60,8 +60,9 @@ def resolve_gemm_blocks(ctx=None) -> Tuple[int, int, int]:
 
 def fused_gemm_route(m: int, n: int, k: int, *, layer_norm: bool,
                      blocks: Tuple[int, int, int],
-                     alpha: float = 1.0) -> Tuple[str, str]:
-    """('pallas' | 'pallas-interpret' | 'primitive', reason). The single
+                     alpha: float = 1.0, platform=None) -> Tuple[str, str]:
+    """('pallas' | 'pallas-interpret' | 'primitive', reason) for a step
+    lowered for ``platform`` (``lowering.lowering_platform``). The single
     route authority: the op lowering, the fusion pass's fidelity witness
     and its PT755 reporting must all agree on which path runs."""
     from ..kernels.fused_gemm import classify_gemm
@@ -84,11 +85,11 @@ def fused_gemm_route(m: int, n: int, k: int, *, layer_norm: bool,
                 f"FLAGS_use_fused_gemm=always but (m={m}, n={n}, k={k}) "
                 f"has no kernel tiling: {reason}")
         return "primitive", reason
-    if jax.default_backend() == "tpu":
+    if platform == "tpu":
         return "pallas", reason
     if mode == "always":
         return "pallas-interpret", reason
-    return "primitive", f"non-TPU backend ({reason})"
+    return "primitive", f"not lowered for a TPU ({reason})"
 
 
 def _amp_cast(ctx, op_type: str, ins: dict) -> dict:
@@ -192,7 +193,9 @@ def _fused_gemm_epilogue(ctx, ins, attrs):
     n = int(y2.shape[1])
     route, _reason = fused_gemm_route(
         m, n, k, layer_norm=bool(attrs["layer_norm"]), blocks=blocks,
-        alpha=float(attrs.get("alpha", 1.0)))
+        alpha=float(attrs.get("alpha", 1.0)),
+        platform=lowering_platform(ctx))
+    note_kernel_route(ctx, "fused_gemm_epilogue", route)
     if route == "primitive":
         return {"Out": [_primitive_chain(ctx, xv, yv, bias, residual,
                                          ln_scale, ln_bias, attrs)]}

@@ -27,15 +27,19 @@ import jax.numpy as jnp
 from .common import IOSpec, register_op, x
 from .. import flags
 from ..core.types import jnp_dtype
+from ..lowering import lowering_platform, note_kernel_route
 
 
-def _route_decode(s_max: int, page_size: int, q_len: int = 1) -> str:
+def _route_decode(s_max: int, page_size: int, q_len: int = 1,
+                  platform=None) -> str:
     """'pallas' | 'pallas-interpret' | 'primitive' for a decode/chunk
-    shape. ``q_len`` > 1 is the chunked-prefill / speculative-verify
-    chunk; the kernel rides one 8-row sublane tile, so chunks past 8
-    rows fall back to the primitive path (never an error — the chunk
-    size is a scheduling knob, not a hardware contract)."""
-    from ..kernels import classify_shapes
+    shape lowered for ``platform`` (``lowering.lowering_platform``).
+    ``q_len`` > 1 is the chunked-prefill / speculative-verify chunk; the
+    kernel rides one 8-row sublane tile, so chunks past 8 rows take the
+    primitive path (never an error — the chunk size is a scheduling
+    knob, not a hardware contract; ``kernel_route_total`` says which
+    programs do)."""
+    from ..kernels import KERNEL_ROWS, classify_shapes
 
     mode = flags.flag("use_flash_attention")
     if mode == "never":
@@ -47,9 +51,9 @@ def _route_decode(s_max: int, page_size: int, q_len: int = 1) -> str:
                 f"FLAGS_use_flash_attention=always but the decode shape "
                 f"has no kernel tiling: {reason}")
         return "primitive"
-    if q_len > 8:
+    if q_len > KERNEL_ROWS:
         return "primitive"
-    if jax.default_backend() == "tpu":
+    if platform == "tpu":
         return "pallas"
     return "pallas-interpret" if mode == "always" else "primitive"
 
@@ -112,7 +116,9 @@ def _fused_decode_attention(ctx, ins, attrs):
     q3 = q.reshape(B * H, q_len, D)
     k3 = ck2.reshape(B * H, S, D)
     v3 = cv2.reshape(B * H, S, D)
-    route = _route_decode(S, page, q_len=q_len)
+    route = _route_decode(S, page, q_len=q_len,
+                          platform=lowering_platform(ctx))
+    note_kernel_route(ctx, "fused_decode_attention", route)
     if route == "primitive":
         o = decode_attention_reference(q3, k3, v3,
                                        jnp.repeat(lengths, H, axis=0), scale)

@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..lowering import lowering_platform
 from .common import IOSpec, out, register_op, x
 
 
@@ -51,12 +52,12 @@ def _conv_padding(padding, ksize, dilations):
     return [(p, p) for p in padding]
 
 
-def _use_nhwc() -> bool:
+def _use_nhwc(ctx) -> bool:
     """TPU convs want channels on the 128-lane minor dim (NHWC). The API
     stays NCHW (the reference layout); the lowering transposes at the op
     boundary — consecutive conv/pool layers' transposes cancel in XLA, so
-    steady-state compute runs NHWC end to end. docs/PERF_NOTES.md has the
-    measured effect."""
+    steady-state compute runs NHWC end to end. ``auto`` follows the device
+    the step is lowered for (``lowering.lowering_platform``)."""
     from .. import flags
 
     mode = flags.flag("conv_use_nhwc")
@@ -64,7 +65,7 @@ def _use_nhwc() -> bool:
         return True
     if mode == "never":
         return False
-    return jax.default_backend() == "tpu"
+    return lowering_platform(ctx) == "tpu"
 
 
 @register_op("conv2d", inputs=[IOSpec("Input"), IOSpec("Filter"),
@@ -75,7 +76,7 @@ def _use_nhwc() -> bool:
 def _conv2d(ctx, ins, attrs):
     inp, flt = x(ins, "Input"), x(ins, "Filter")
     pad = _conv_padding(attrs["paddings"], flt.shape[2:], attrs["dilations"])
-    if _use_nhwc():
+    if _use_nhwc(ctx):
         res = jax.lax.conv_general_dilated(
             inp.transpose(0, 2, 3, 1), flt.transpose(2, 3, 1, 0),
             window_strides=attrs["strides"], padding=pad,
@@ -127,7 +128,7 @@ def _conv2d_transpose(ctx, ins, attrs):
     if groups != 1:
         raise NotImplementedError("conv2d_transpose groups>1 not supported")
     wf = jnp.flip(flt, (2, 3))
-    if _use_nhwc():
+    if _use_nhwc(ctx):
         res = jax.lax.conv_general_dilated(
             inp.transpose(0, 2, 3, 1), wf.transpose(2, 3, 0, 1),
             window_strides=(1, 1), padding=pad,
@@ -178,7 +179,7 @@ def _pool2d(ctx, ins, attrs):
             extra[i] = max(
                 0, (out_ceil - 1) * strides[i] + ksize[i]
                 - (in_hw[i] + 2 * pads[i]))
-    nhwc = _use_nhwc()
+    nhwc = _use_nhwc(ctx)
     if nhwc:
         xv = xv.transpose(0, 2, 3, 1)   # keep the conv chain in NHWC
         window = (1,) + tuple(ksize) + (1,)

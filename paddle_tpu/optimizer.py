@@ -166,7 +166,7 @@ class Optimizer:
 
         from .core import registry
         from .dygraph import base as dy
-        from .lowering import LowerCtx
+        from .lowering import LowerCtx, eager_platform
 
         if parameter_list is None:
             raise ValueError(
@@ -183,7 +183,7 @@ class Optimizer:
                 "no gradients found — call loss.backward() before minimize")
         clipped = self._eager_clip_grads(params)
         lr = self._current_lr()
-        ctx = LowerCtx()
+        ctx = LowerCtx(platform=eager_platform())
         updated = []
         for p in params:
             # static-path order (reference _create_optimization_pass):

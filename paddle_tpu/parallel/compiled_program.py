@@ -402,7 +402,8 @@ class CompiledProgram:
                 fetch_names=fetch_names, batch_size=batch)
             _monitor.observe_comms_cost(
                 program, estimate_comms(analysis),
-                estimate_cost(program, batch_size=batch))
+                estimate_cost(program, batch_size=batch),
+                device_kind=self._mesh.devices.flat[0].device_kind)
         except Exception:
             pass
 

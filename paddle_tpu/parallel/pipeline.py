@@ -31,9 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .sharding import (axis_size, has_varying_types, pvary_compat,
-                       shard_map_compat)
-
 __all__ = ["pipeline_spmd", "pipeline", "stack_stage_params"]
 
 
@@ -51,7 +48,7 @@ def pipeline_spmd(stage_fn: Callable, stage_params, x_micro,
 
     Returns [M, B_mb, ...] outputs of the final stage, replicated.
     """
-    P_ = axis_size(axis_name)
+    P_ = jax.lax.axis_size(axis_name)
     s = jax.lax.axis_index(axis_name)
     M = x_micro.shape[0]
     T = M + P_ - 1
@@ -65,7 +62,7 @@ def pipeline_spmd(stage_fn: Callable, stage_params, x_micro,
     # the carry must be typed as VARYING over the pipeline axis (its value
     # depends on axis_index from tick 1 on), or the scan carry types clash
     carry0 = jax.tree.map(
-        lambda t: pvary_compat(t, axis_name),
+        lambda t: jax.lax.pcast(t, (axis_name,), to="varying"),
         (jnp.zeros_like(x_micro[0]), jnp.zeros_like(x_micro)))
 
     def tick(carry, t):
@@ -138,10 +135,8 @@ def pipeline(stage_fn: Callable, stacked_params, x, mesh: Mesh,
         ym = pipeline_spmd(stage_fn, params, xm, axis_name)
         return ym.reshape(xl.shape)
 
-    # 0.4.x jax cannot type the scan carry as varying (no pcast/pvary), so
-    # the replication check must be off there; newer jax keeps it on
-    fn = shard_map_compat(local, mesh=mesh, in_specs=(pspec, xspec),
-                          out_specs=xspec, check_vma=has_varying_types())
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(pspec, xspec),
+                       out_specs=xspec)
     if place_params and _needs_place(stacked_params, mesh):
         stacked_params = jax.device_put(
             stacked_params,

@@ -22,14 +22,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .sharding import axis_size, shard_map_compat
-
 __all__ = ["ring_attention", "ring_attention_local", "attention_reference"]
 
 
 def ring_attention_local(q, k, v, axis_name: str, causal: bool = False,
                          scale: Optional[float] = None,
-                         use_flash: Optional[bool] = None):
+                         use_flash: Optional[bool] = None,
+                         platform: Optional[str] = None):
     """The per-shard body — call inside shard_map over ``axis_name``.
 
     q, k, v: [B, T_local, H, D] local chunks. Returns [B, T_local, H, D].
@@ -37,25 +36,28 @@ def ring_attention_local(q, k, v, axis_name: str, causal: bool = False,
     ``use_flash`` routes the per-block attention through the Pallas flash
     kernel (kernels/flash_attention.py) — the same kernel as
     fused_multihead_attention — combining ring steps through each block's
-    log-sum-exp instead of carrying (m, l) explicitly. None = auto: kernel
-    on TPU when the local block shapes divide its tiles, jnp math
-    elsewhere (the CPU test mesh keeps the einsum path — Pallas interpret
-    inside shard_map is slow and PRNG-free anyway).
+    log-sum-exp instead of carrying (m, l) explicitly. ``platform`` is the
+    platform of the mesh this body is shard_mapped over
+    (``lowering.lowering_platform(mesh=...)`` — the body cannot see the
+    mesh itself). None = auto: kernel on a TPU mesh when the local block
+    shapes divide its tiles, jnp math elsewhere (the CPU test mesh keeps
+    the einsum path — Pallas interpret inside shard_map is slow and
+    PRNG-free anyway); forcing ``use_flash=True`` off a TPU mesh runs the
+    kernel in the Pallas interpreter (tests only).
     """
     if use_flash is None:
-        import jax as _jax
-
         from ..kernels import supports_shapes
 
-        use_flash = (_jax.default_backend() == "tpu"
+        use_flash = (platform == "tpu"
                      and supports_shapes(q.shape[1], k.shape[1]))
     if use_flash:
-        return _ring_attention_local_flash(q, k, v, axis_name, causal, scale)
+        return _ring_attention_local_flash(q, k, v, axis_name, causal, scale,
+                                           interpret=platform != "tpu")
     return _ring_attention_local_jnp(q, k, v, axis_name, causal, scale)
 
 
 def _ring_attention_local_flash(q, k, v, axis_name: str, causal: bool,
-                                scale: Optional[float]):
+                                scale: Optional[float], interpret: bool):
     """Ring body where each block product is one flash-kernel call.
 
     Blocks combine by log-sum-exp re-weighting: for partials (o_a, lse_a)
@@ -65,13 +67,10 @@ def _ring_attention_local_flash(q, k, v, axis_name: str, causal: bool,
     from ..kernels import flash_attention_with_lse
 
     B, Tl, H, D = q.shape
-    P_ = axis_size(axis_name)
+    P_ = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
     perm = [(i, (i + 1) % P_) for i in range(P_)]
-    # forcing the flash path on the CPU test mesh runs the kernel in the
-    # pallas interpreter (slow, tests only); compiled Mosaic on TPU
-    interpret = jax.default_backend() != "tpu"
 
     # kernel layout is [B*H, T, D] head-major; transpose ALL of q/k/v once
     # up front and rotate k/v around the ring already head-major (ppermute
@@ -124,7 +123,7 @@ def _ring_attention_local_jnp(q, k, v, axis_name: str, causal: bool = False,
                               scale: Optional[float] = None):
     """Einsum ring body (runs anywhere, incl. the 8-device CPU test mesh)."""
     B, Tl, H, D = q.shape
-    P_ = axis_size(axis_name)
+    P_ = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
     q = q * scale
@@ -182,22 +181,25 @@ def ring_attention(q, k, v, mesh: Mesh, seq_axis: str = "sp",
                    use_flash: Optional[bool] = None):
     """shard_map wrapper: q/k/v [B, T, H, D] (global); T shards over
     ``seq_axis``, batch over 'dp' when the mesh has one."""
+    from ..lowering import lowering_platform
+
     batch_axis = "dp" if "dp" in mesh.axis_names else None
     spec = P(batch_axis, seq_axis, None, None)
+    platform = lowering_platform(mesh=mesh)
 
     if use_flash is None:
         from ..kernels import supports_shapes
 
         n_sp = mesh.shape[seq_axis]
         t_local = q.shape[1] // n_sp
-        use_flash = (jax.default_backend() == "tpu"
+        use_flash = (platform == "tpu"
                      and supports_shapes(t_local, t_local))
     # check_vma=False on the flash path: the kernel's scalar operands
     # (global position offsets) legitimately vary over the ring axis, which
     # the vma checker's pallas handling rejects
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         partial(ring_attention_local, axis_name=seq_axis, causal=causal,
-                scale=scale, use_flash=use_flash),
+                scale=scale, use_flash=use_flash, platform=platform),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=not use_flash)
     # eager dispatches ride the ICI ring (P ppermute rotations) — the one

@@ -20,49 +20,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``shard_map`` across the jax API rename: newer jax spells the
-    replication-check kwarg ``check_vma``, 0.4.x spells it ``check_rep``
-    (same semantics). Callers use the new spelling; this maps it to
-    whichever the installed jax accepts."""
-    import inspect
-
-    try:
-        from jax import shard_map as _sm
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map as _sm
-    params = inspect.signature(_sm).parameters
-    kw = "check_vma" if "check_vma" in params else "check_rep"
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               **{kw: check_vma})
-
-
-def axis_size(axis_name: str):
-    """``jax.lax.axis_size`` where it exists; the ``psum(1, axis)``
-    idiom (folded to a constant at trace time) on 0.4.x."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def has_varying_types() -> bool:
-    """Does the installed jax type values as varying-over-axis inside
-    shard_map (``pcast``/``pvary``)? 0.4.x has neither — callers that
-    need a varying scan carry disable the replication check instead."""
-    return hasattr(jax.lax, "pcast") or hasattr(jax.lax, "pvary")
-
-
-def pvary_compat(t, axis_name: str):
-    """Type ``t`` as varying over ``axis_name`` inside shard_map, across
-    the jax API generations (``pcast(to="varying")`` / ``pvary``); a
-    no-op on 0.4.x, where the caller must pass ``check_vma=False``."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(t, (axis_name,), to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(t, (axis_name,))
-    return t
-
-
 def make_mesh(shape: Dict[str, int], devices=None) -> Mesh:
     """mesh({'dp': 2, 'tp': 4}) over the first prod(shape) devices.
     Axis order follows dict order; put the fastest-varying (intra-chip ICI

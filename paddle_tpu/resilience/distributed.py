@@ -340,14 +340,6 @@ def _pspec_of(v):
     return spec if spec is not None else P()
 
 
-def _get_shard_map():
-    try:
-        from jax import shard_map
-    except ImportError:     # older jax
-        from jax.experimental.shard_map import shard_map
-    return shard_map
-
-
 def replica_divergence_check(mesh, values: Dict[str, Any],
                              axis: Optional[str] = None) -> List[str]:
     """Names in ``values`` whose device copies disagree where the sharding
@@ -396,7 +388,7 @@ def replica_divergence_check(mesh, values: Dict[str, Any],
             out = jnp.stack(sums)                      # [V, 2] per device
             return out.reshape((1,) * n_axes + out.shape)
 
-        fn = jax.jit(_get_shard_map()(
+        fn = jax.jit(jax.shard_map(
             local, mesh=mesh, in_specs=in_specs,
             out_specs=P(*axis_names, None, None)))
         # bounded: evict oldest so dead meshes / compiled checkers from

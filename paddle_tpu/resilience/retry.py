@@ -11,7 +11,9 @@ metric-emitting retry loop. Non-transient errors (shape/dtype mistakes,
 Classification is by exception type: ``RuntimeError`` / ``OSError`` /
 ``TimeoutError`` / ``ConnectionError`` are transient, everything else
 (``TypeError``, ``ValueError`` — including ``ProgramVerificationError`` —
-``FloatingPointError``, ...) is permanent and re-raised immediately.
+``FloatingPointError``, ...) is permanent and re-raised immediately, and so
+is an XLA error whose status code names a deterministic refusal
+(``RESOURCE_EXHAUSTED``, ``INTERNAL`` from Mosaic, ...).
 
 Metrics (docs/OBSERVABILITY.md): ``resilience_retries_total{site}`` on each
 retried attempt, ``resilience_giveups_total{site}`` when the budget is
@@ -44,6 +46,16 @@ _PERMANENT = (TypeError, ValueError, KeyError, IndexError, AttributeError,
               RecursionError, AssertionError)
 
 
+# XLA reports its own deterministic refusals — out of HBM or VMEM, a Mosaic
+# kernel the compiler rejects, an invalid program — through the same
+# RuntimeError subclass as infrastructure hiccups; the status code leading
+# the message tells them apart. Retrying one repeats a multi-minute compile
+# to fail the same way.
+_XLA_PERMANENT_STATUS = ("RESOURCE_EXHAUSTED", "INVALID_ARGUMENT", "INTERNAL",
+                         "UNIMPLEMENTED", "FAILED_PRECONDITION",
+                         "OUT_OF_RANGE")
+
+
 def is_transient(exc: BaseException) -> bool:
     # classes can opt out of retry explicitly (WatchdogTimeout,
     # ReplicaDivergenceError, DeviceLostError: RuntimeErrors by type,
@@ -52,6 +64,9 @@ def is_transient(exc: BaseException) -> bool:
     if getattr(exc, "transient", None) is False:
         return False
     if not isinstance(exc, _TRANSIENT) or isinstance(exc, _PERMANENT):
+        return False
+    if type(exc).__name__ == "JaxRuntimeError" \
+            and str(exc).startswith(_XLA_PERMANENT_STATUS):
         return False
     # a transient-typed wrapper chained onto a permanent cause is a
     # deterministic bug in disguise (e.g. lowering's _OpLoweringError, a

@@ -1158,8 +1158,9 @@ class ServingEngine:
             self._gauge_open_buckets()
         batch_span.set_attribute("outcome", "ok")
         batch_span.end()
-        _monitor.observe_serving_cost(self._program, padded, batch_s,
-                                      label)
+        _monitor.observe_serving_cost(
+            self._program, padded, batch_s, label,
+            device_kind=self._exe.place.jax_device().device_kind)
         if _monitor.enabled():
             _monitor.counter("serving_batches_total",
                              "dispatched batches by result").labels(

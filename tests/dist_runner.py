@@ -25,10 +25,8 @@ def main():
     else:
         import jax
 
-        from paddle_tpu.distributed import force_cpu_device_count
-
         jax.config.update("jax_platforms", "cpu")
-        force_cpu_device_count(2)
+        jax.config.update("jax_num_cpu_devices", 2)
 
     import paddle_tpu as fluid
 

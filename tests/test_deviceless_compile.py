@@ -1,0 +1,86 @@
+"""Every Pallas kernel compiles for the v5e — without a chip.
+
+The installed libtpu compiles for a TPU topology it is only told about:
+``jax.experimental.topologies.get_topology_desc(platform="tpu",
+topology_name="v5e:2x2")`` returns four ``TPU v5 lite`` devices in a
+sandbox that has none, and lowering a jitted function for
+``ShapeDtypeStruct``s placed on one of them runs Mosaic and the TPU compiler
+for real. So "the kernels compile" stays true in every PR at no chip time.
+Compiling says nothing about results, run-time memory or speed: those are
+``chip_smoke.py``'s and the benchmark's to find on the chip.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.kernels import (flash_attention, flash_attention_decode,
+                                fused_gemm)
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """ShapeDtypeStruct factory for one device of a deviceless v5e 2x2."""
+    try:
+        from jax.experimental import topologies
+
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:        # no libtpu in this installation
+        pytest.skip(f"no deviceless TPU topology here: {e}")
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    sharding = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=sharding)
+
+
+def _compiles_with_mosaic(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+def test_flash_attention_fwd_bwd_compiles_at_the_bert_base_shape(v5e):
+    """bs 32 x 12 heads, S 512, D 64, bf16, key bias, in-kernel dropout:
+    the forward and both backward kernels."""
+    def loss(q, k, v, bias):
+        o = flash_attention(q, k, v, bias=bias, dropout_rate=0.1, seed=3,
+                            num_heads=12)
+        return o.astype(jnp.float32).sum()
+
+    qkv = v5e((384, 512, 64), jnp.bfloat16)
+    text = _compiles_with_mosaic(jax.grad(loss, argnums=(0, 1, 2)),
+                                 qkv, qkv, qkv, v5e((32, 512), jnp.float32))
+    assert text.count("tpu_custom_call") >= 3
+
+
+def test_flash_attention_decode_compiles_at_the_gpt2_base_shape(v5e):
+    """8 slots x 12 heads against a 1024-row f32 cache in pages of 128,
+    q_len 8 (the speculative-verify chunk; q_len 1 rides the same tile)."""
+    _compiles_with_mosaic(
+        lambda q, k, v, n: flash_attention_decode(q, k, v, n, num_heads=12,
+                                                  page_size=128),
+        v5e((96, 8, 64), jnp.float32), v5e((96, 1024, 64), jnp.float32),
+        v5e((96, 1024, 64), jnp.float32), v5e((8,), jnp.int32))
+
+
+def test_fused_gemm_compiles_at_the_bert_base_ffn_shape(v5e):
+    """[bs 32 x 512, 768] @ [768, 3072] + bias + tanh-gelu, bf16."""
+    _compiles_with_mosaic(
+        lambda x, y, b: fused_gemm(x, y, bias=b, activation="gelu",
+                                   gelu_approximate=True),
+        v5e((16384, 768), jnp.bfloat16), v5e((768, 3072), jnp.bfloat16),
+        v5e((3072,), jnp.float32))
+
+
+@pytest.mark.xfail(strict=True, raises=NotImplementedError,
+                   reason="jax 0.9 Mosaic has no lowering for erfc, so the "
+                          "fused GEMM's EXACT gelu epilogue (what the model "
+                          "zoo's act='gelu' asks for) does not compile for "
+                          "a TPU; recorded in PR 21, not fixed — the kernel "
+                          "is behind FLAGS_epilogue_fusion, off by default")
+def test_fused_gemm_exact_gelu_epilogue_compiles(v5e):
+    _compiles_with_mosaic(
+        lambda x, y, b: fused_gemm(x, y, bias=b, activation="gelu"),
+        v5e((16384, 768), jnp.bfloat16), v5e((768, 3072), jnp.bfloat16),
+        v5e((3072,), jnp.float32))

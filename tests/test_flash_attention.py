@@ -127,10 +127,13 @@ def test_fully_masked_rows_zero_output_and_grads():
 
 def test_op_level_kernel_vs_primitive_path():
     """The registered op under FLAGS_use_flash_attention=always (interpret
-    kernel) must match =never (primitive path) through a whole Program."""
+    kernel) must match =never (primitive path) through a whole Program —
+    on one device, and over a dp x tp mesh, where GSPMD cannot partition a
+    Mosaic kernel and the op runs it per shard under shard_map."""
     from paddle_tpu import flags
+    from paddle_tpu.parallel.sharding import make_mesh
 
-    def run(mode):
+    def run(mode, mesh=None):
         flags.set_flags({"FLAGS_use_flash_attention": mode})
         try:
             with un.guard():
@@ -149,22 +152,25 @@ def test_op_level_kernel_vs_primitive_path():
                 exe = fluid.Executor(fluid.CPUPlace())
                 scope = fluid.Scope()
                 rng = np.random.RandomState(5)
-                feed = {n: rng.randn(3, 2, 128, 32).astype(np.float32)
+                feed = {n: rng.randn(4, 2, 128, 32).astype(np.float32)
                         for n in ("q", "k", "v")}
-                feed["m"] = np.where(rng.rand(3, 128) > 0.3, 0.0,
+                feed["m"] = np.where(rng.rand(4, 128) > 0.3, 0.0,
                                      -10000.0).astype(np.float32)
+                prog = main if mesh is None else fluid.CompiledProgram(
+                    main).with_data_parallel(places=make_mesh(mesh))
                 with fluid.scope_guard(scope):
                     exe.run(startup)
-                    res = exe.run(main, feed=feed,
+                    res = exe.run(prog, feed=feed,
                                   fetch_list=[out.name, loss.name])
                 return [np.asarray(r) for r in res]
         finally:
             flags.set_flags({"FLAGS_use_flash_attention": "auto"})
 
-    o_kernel, l_kernel = run("always")
     o_prim, l_prim = run("never")
-    np.testing.assert_allclose(o_kernel, o_prim, atol=2e-5, rtol=1e-4)
-    np.testing.assert_allclose(l_kernel, l_prim, rtol=1e-5)
+    for o_kernel, l_kernel in (run("always"),
+                               run("always", mesh={"dp": 2, "tp": 2})):
+        np.testing.assert_allclose(o_kernel, o_prim, atol=2e-5, rtol=1e-4)
+        np.testing.assert_allclose(l_kernel, l_prim, rtol=1e-5)
 
 
 def test_bert_attention_uses_fused_op():

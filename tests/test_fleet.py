@@ -751,14 +751,15 @@ def test_aot_cache_key_changes_with_config_and_shape(tmp_path):
     args_a = ([np.zeros((1, 13), np.float32)], [], [], None)
     args_b = ([np.zeros((2, 13), np.float32)], [], [], None)
     parts = ("run", infer, (pred,), (), None)
-    k1 = aot_cache.executable_key(parts, args_a)
-    assert k1 == aot_cache.executable_key(parts, args_a)   # stable
-    assert k1 != aot_cache.executable_key(parts, args_b)   # batch shape
+    devs = [fluid.CPUPlace().jax_device()]
+    k1 = aot_cache.executable_key(parts, args_a, devs)
+    assert k1 == aot_cache.executable_key(parts, args_a, devs)   # stable
+    assert k1 != aot_cache.executable_key(parts, args_b, devs)   # shape
     parts_opts = ("run", infer, (pred,),
                   (("xla_cpu_enable_fast_min_max", True),), None)
-    assert k1 != aot_cache.executable_key(parts_opts, args_a)
+    assert k1 != aot_cache.executable_key(parts_opts, args_a, devs)
     parts_chained = ("chained", infer, (pred,), (), None, 3)
-    assert k1 != aot_cache.executable_key(parts_chained, args_a)
+    assert k1 != aot_cache.executable_key(parts_chained, args_a, devs)
 
 
 def test_aot_cache_corrupt_and_stale_entries_degrade(

@@ -487,10 +487,10 @@ def test_watchdog_direct_section(flags_guard):
 
 def test_multichip_paths_no_dtype_truncation_warnings():
     """The int64 UserWarning the MULTICHIP tail showed came from
-    ops/tensor.py's jnp.full boundary when jnp_dtype's hand-rolled x64
-    probe failed open on newer jax. jnp_dtype now asks
-    jax.dtypes.canonicalize_dtype; this runs an int64-heavy program
-    through the CompiledProgram mesh path (the dryrun's route) with
+    ops/tensor.py's jnp.full boundary requesting a width jax would not
+    deliver. jnp_dtype requests what ``jax.config.jax_enable_x64`` says
+    jax delivers; this runs an int64-heavy program through the
+    CompiledProgram mesh path (the dryrun's route) with
     warnings-as-errors."""
     with un.guard():
         main, startup = fluid.Program(), fluid.Program()

@@ -579,14 +579,22 @@ def test_observe_comms_cost_gauges():
     an = run(m["main"], {"dp": 8}, fetches=[m["loss"].name], batch=64)
     comms = estimate_comms(an)
     cost = estimate_cost(m["main"], batch_size=64)
-    monitor.observe_comms_cost(m["main"], comms, cost)
+    from paddle_tpu.analysis.cost_model import DEVICE_PEAKS
+
     serial = str(m["main"]._serial)
+    # a mesh of chips the peaks table does not list: volume yes, ratio no
+    monitor.observe_comms_cost(m["main"], comms, cost, device_kind="cpu")
     g = monitor.metric_value("executor_comms_gbytes_per_step",
                              program=serial, mesh="dp=8")
     assert g == pytest.approx(comms.gbytes_per_step)
+    assert monitor.metric_value("executor_comms_compute_ratio", None,
+                                program=serial, mesh="dp=8") is None
+    monitor.observe_comms_cost(m["main"], comms, cost,
+                               device_kind="TPU v5 lite")
     r = monitor.metric_value("executor_comms_compute_ratio",
                              program=serial, mesh="dp=8")
-    assert r == pytest.approx(comms_compute_ratio(comms, cost))
+    assert r == pytest.approx(comms_compute_ratio(
+        comms, cost, DEVICE_PEAKS["TPU v5 lite"].bf16_tflops))
 
 
 def test_parallel_compile_emits_comms_gauges():

@@ -5,6 +5,11 @@
 on a host with a real accelerator. The CPU suite auto-skips these. Covers
 the TPU-numerics policy (bf16 matmul tolerance), one real train step, and
 the recompute remat surviving into the chip executable.
+
+The quickest proof that the system starts on the chip at the real widths
+is ``python chip_smoke.py`` at the repo root (BERT-base training and
+GPT-2-base serving end to end, every Pallas route against its oracle);
+``ci/run_ci.sh tpu`` runs it before this file.
 """
 import numpy as np
 import pytest
@@ -67,3 +72,46 @@ def test_recompute_remat_survives_to_executable():
     pa, ra = plain.memory_analysis(), rc.memory_analysis()
     assert ra.argument_size_in_bytes == pa.argument_size_in_bytes
     assert ra.generated_code_size_in_bytes > pa.generated_code_size_in_bytes
+
+
+def test_hbm_compile_error_reaches_the_caller_once(monkeypatch):
+    """A program whose activations cannot fit the chip: the TPU compiler's
+    own RESOURCE_EXHAUSTED must reach the caller of ``exe.run`` after ONE
+    build — not retried as transient, not retried through jit."""
+    import jax
+    from jax import stages
+
+    from paddle_tpu import monitor
+
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.data("x", shape=[64], dtype="float32")
+        h = x
+        # 8 x [262144, 4096] f32 = 32 GiB the backward needs live (tanh's
+        # gradient needs its output; a relu net keeps bits and just fits)
+        for _ in range(8):
+            h = fluid.layers.fc(h, 4096, act="tanh")
+        loss = fluid.layers.mean(h)
+        fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
+    builds = []
+    real = stages.Lowered.compile
+
+    def counting(self, *a, **k):
+        builds.append(self)
+        return real(self, *a, **k)
+
+    monkeypatch.setattr(stages.Lowered, "compile", counting)
+    exe = fluid.Executor(fluid.TPUPlace())
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe.run(startup)
+        builds.clear()
+        monitor.reset()
+        with pytest.raises(jax.errors.JaxRuntimeError,
+                           match="RESOURCE_EXHAUSTED") as err:
+            exe.run(main, feed={"x": np.ones((262144, 64), np.float32)},
+                    fetch_list=[loss.name])
+    assert "hbm" in str(err.value)
+    assert len(builds) == 1
+    assert monitor.metric_value("resilience_retries_total", 0.0,
+                                site="compile") == 0

@@ -145,7 +145,7 @@ def run_probe(name, fused: bool, report):
     prev = fluid.get_flags(["FLAGS_epilogue_fusion"])
     fluid.set_flags({"FLAGS_epilogue_fusion": fused})
     try:
-        exe = fluid.Executor(fluid.TPUPlace())
+        exe = fluid.Executor()
         scope = fluid.Scope()
         with fluid.scope_guard(scope):
             exe.run(startup)
@@ -282,7 +282,7 @@ def _child(mode: str, db_path: str) -> int:
     from paddle_tpu import monitor, tuning
 
     main, startup, fetch, feed = probe_mlp()
-    exe = fluid.Executor(fluid.TPUPlace())
+    exe = fluid.Executor()
     scope = fluid.Scope()
     out = {"mode": mode, "fp": tuning.program_content_fingerprint(main)}
     with fluid.scope_guard(scope):

@@ -49,6 +49,11 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 import paddle_tpu as fluid  # noqa: E402
 from paddle_tpu.analysis import Severity, verify_program  # noqa: E402
 
+# the chip the per-chip plan is predicted FOR (CI passes its HBM as
+# --hbm-budget-mb 15872): its entry in analysis.cost_model.DEVICE_PEAKS
+# prices the comms-vs-compute ratio. A static prediction, not a measurement.
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
 
 def _book_programs():
     """(name, program, feed_names, fetch_names) for the book models the
@@ -113,7 +118,8 @@ def _specs_for(program, mesh, specs_mode):
 
 def _per_chip_entry(program, feeds, fetches, batch, mesh, specs_mode):
     """The per-chip section of one program's JSON entry."""
-    from paddle_tpu.analysis.cost_model import (comms_compute_ratio,
+    from paddle_tpu.analysis.cost_model import (DEVICE_PEAKS,
+                                                comms_compute_ratio,
                                                 estimate_comms,
                                                 estimate_cost)
 
@@ -130,7 +136,9 @@ def _per_chip_entry(program, feeds, fetches, batch, mesh, specs_mode):
         "sharding": analysis.to_dict(),
         "comms": comms.to_dict(),
         "comms_compute_ratio": round(
-            comms_compute_ratio(comms, cost), 4),
+            comms_compute_ratio(
+                comms, cost, DEVICE_PEAKS[TARGET_DEVICE_KIND].bf16_tflops),
+            4),
     }
     return plan, section
 

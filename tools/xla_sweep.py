@@ -125,7 +125,7 @@ def time_one(main, startup, loss_name, feed, k_short, k_long, repeats):
     import paddle_tpu as fluid
     from paddle_tpu import tuning
 
-    exe = fluid.Executor(fluid.TPUPlace())
+    exe = fluid.Executor()
     scope = fluid.Scope()
     with fluid.scope_guard(scope):
         exe.run(startup)
