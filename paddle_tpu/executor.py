@@ -54,8 +54,36 @@ class Place:
         return type(self).__name__ + "()"
 
 
+_runtime_started = False
+
+
+def _start_runtime() -> None:
+    """The program's first touch of JAX's backends, timed once into the
+    gauge ``device_runtime_start_seconds`` (on a TPU host: the TPU
+    runtime's own start, seconds of every process's set-up). Every place
+    goes through here before it asks JAX for devices. Where the embedding
+    process has started the backends already, there is nothing to time
+    and the gauge stays unset."""
+    global _runtime_started
+    if _runtime_started:
+        return
+    _runtime_started = True
+    from jax._src import xla_bridge
+
+    if xla_bridge.backends_are_initialized():
+        return
+    t0 = time.perf_counter()
+    jax.local_devices()
+    if _monitor.enabled():
+        _monitor.gauge(
+            "device_runtime_start_seconds",
+            "wall of the process's first backend initialisation, when the "
+            "program made it").set(time.perf_counter() - t0)
+
+
 class CPUPlace(Place):
     def jax_device(self):
+        _start_runtime()
         # local, not global: under multi-process the global list includes
         # other trainers' devices, which are not addressable here. backend=
         # "cpu" because plain local_devices() lists only the default backend
@@ -72,6 +100,7 @@ class TPUPlace(Place):
         self.device_id = device_id
 
     def jax_device(self):
+        _start_runtime()
         devs = [d for d in jax.local_devices() if d.platform != "cpu"]
         if not 0 <= self.device_id < len(devs):
             raise RuntimeError(
@@ -92,6 +121,7 @@ def default_place() -> Place:
     the caller names none: the accelerator when JAX's default backend is
     one, the host only where JAX itself is held to it
     (``JAX_PLATFORMS=cpu``, the test suite)."""
+    _start_runtime()
     return CPUPlace() if jax.default_backend() == "cpu" else TPUPlace()
 
 
@@ -166,6 +196,10 @@ def _feed_host_bytes(v) -> int:
         return int(np.asarray(v).nbytes)
     except Exception:
         return 0
+
+
+def _feed_bytes(feed) -> int:
+    return sum(_feed_host_bytes(v) for v in feed.values())
 
 
 def _live_bytes(vals) -> int:
@@ -792,38 +826,56 @@ class Executor:
         fetch_names = [f.name if isinstance(f, Variable) else f
                        for f in (fetch_list or [])]
 
-        submitted = program
-        program = self._maybe_auto_remat(program, feed, fetch_names)
-        program = self._maybe_epilogue_fusion(program, feed, fetch_names,
-                                              tuning_program=submitted)
-        self._verify_once(program, fetch_names)
-        mrec = _monitor.step_begin("run", program)
         # child of whatever request/step trace is ambient on this thread
-        # (serving attaches the request root; the Trainer its step root)
-        with _trace.span("executor.run",
-                         program=int(getattr(program, "_serial", -1))):
+        # (serving attaches the request root; the Trainer its step root).
+        # Its phases, contiguous children (OBSERVABILITY.md "Phase spans"):
+        # bind, feed, bind, step, writeback, fetch, writeback. bind comes
+        # twice because the lookup names the feeds and the executable is
+        # lowered from the fed arrays.
+        with _trace.span("executor.run") as sp:
+            mrec = None
             try:
-                return self._run_body(program, feed, fetch_names, scope,
-                                      return_numpy, use_program_cache, mrec,
-                                      tuning_program=submitted)
+                with _trace.phase("executor.bind") as ph:
+                    submitted = program
+                    program = self._maybe_auto_remat(program, feed,
+                                                     fetch_names)
+                    program = self._maybe_epilogue_fusion(
+                        program, feed, fetch_names, tuning_program=submitted)
+                    self._verify_once(program, fetch_names)
+                    mrec = _monitor.step_begin("run", program)
+                    step = self._get_compiled(
+                        program, feed, fetch_names, scope,
+                        use_cache=use_program_cache, mrec=mrec,
+                        tuning_program=submitted)
+                    if ph.traced:
+                        sp.set_attribute(
+                            "program", int(getattr(program, "_serial", -1)))
+                        ph.set_attributes(cache_hit=bool(mrec.cache_hit)
+                                          if mrec is not None else None)
+                return self._run_body(program, feed, scope, return_numpy,
+                                      step, mrec)
             finally:
                 # always paired with step_begin — a step that raises (e.g.
-                # FLAGS_check_nan_inf) still counts and hooks stay in sync
-                _monitor.step_end(mrec)
+                # FLAGS_check_nan_inf) still counts and hooks stay in sync.
+                # The monitor's accounting is the tail of the writeback
+                # phase: results to the scope, then to the records.
+                with _trace.phase("executor.writeback"):
+                    _monitor.step_end(mrec)
 
-    def _run_body(self, program, feed, fetch_names, scope, return_numpy,
-                  use_program_cache, mrec, tuning_program=None):
-        step = self._get_compiled(program, feed, fetch_names, scope,
-                                  use_cache=use_program_cache, mrec=mrec,
-                                  tuning_program=tuning_program)
+    def _run_body(self, program, feed, scope, return_numpy, step, mrec):
         device = self.place.jax_device()
+        cache_hit = None
         if mrec is not None:
-            mrec.fetch_names = tuple(fetch_names)
-            mrec.feed_bytes = sum(_feed_host_bytes(v) for v in feed.values())
+            mrec.fetch_names = step.fetch_names
+            mrec.feed_bytes = _feed_bytes(feed)
             mrec.batch_rows = _feed_batch_rows(feed)
             mrec.device_kind = device.device_kind
-        feed_vals = [self._to_device_array(feed[n], program, n)
-                     for n in step.feed_names]
+            cache_hit = bool(mrec.cache_hit)
+        with _trace.phase("executor.feed") as ph:
+            feed_vals = [self._to_device_array(feed[n], program, n)
+                         for n in step.feed_names]
+            if ph.traced:
+                ph.set_attributes(bytes=_feed_bytes(feed))
 
         def read_state(names):
             vals = []
@@ -842,72 +894,86 @@ class Executor:
                 vals.append(v)
             return vals
 
-        donated_vals = read_state(step.donated_names)
-        ro_vals = read_state(step.ro_names)
-        # step-site fault probe fires BEFORE any buffer is donated, so an
-        # injected step failure leaves the scope fully usable
-        _faults.fault_point("step")
-        if mrec is not None:
-            mrec.donated_buffers = len(step.donated_names)
-            mrec.kept_buffers = len(step.kept_names)
-            mrec.donated_bytes = _live_bytes(donated_vals)
-        key = jax.random.key(self._next_seed(program))
-        rollback = None
-        with jax.default_device(device):
-            if step.nan_check_meta is not None \
-                    and _nonfinite.rollback_active():
-                # nan_inf_policy=skip|zero_grad must be able to restore the
-                # EXACT pre-step bits, but donation consumes the inputs —
-                # so donate fresh device copies and keep the originals
-                rollback = list(zip(step.donated_names, donated_vals))
-                donated_vals = [jnp.array(v) for v in donated_vals]
-            else:
-                # inside default_device so the one-time host->device copy
-                # of planted numpy state lands on THIS executor's device
-                donated_vals = _own_donated(donated_vals)
-            fn = self._ensure_executable(
-                step, (feed_vals, donated_vals, ro_vals, key))
-            # watchdog-armed dispatch: a hang here (injected via the
-            # 'hang' fault site, or a real stuck collective) is dumped +
-            # raised as WatchdogTimeout under FLAGS_step_timeout_s
-            with _trace.span("executor.step",
-                             cache_hit=bool(mrec.cache_hit)
-                             if mrec is not None else None), \
-                    RecordEvent("executor::step"), \
-                    _dist.watchdog_section("step", program=program) as tok:
-                _faults.fault_point("hang")
-                try:
-                    result = fn(feed_vals, donated_vals, ro_vals, key)
-                except (TypeError, ValueError):
-                    if fn is step.fn:
-                        raise
-                    # the AOT executable is stricter than jit dispatch:
-                    # structure mismatches raise TypeError, committed-to-
-                    # another-device shardings raise ValueError — both are
-                    # checked before any buffer is donated, so retry
-                    # through jit (which adapts) and stop using the AOT
-                    # fast path for this step
-                    step._aot = False
-                    result = step.fn(feed_vals, donated_vals, ro_vals, key)
-                if tok is not None:
-                    # dispatch is async — without this the section would
-                    # disarm before a stuck device computation ever ran.
-                    # Only under FLAGS_step_timeout_s, which opts into
-                    # deadline-over-overlap
-                    jax.block_until_ready(result)
-        result = strip_witness_stats(step, result, path="run")
-        fetches, new_state = unpack_step_result(step, result, scope,
-                                                path="run", exe=self,
-                                                rollback=rollback)
-        if new_state is not None:
-            for n, v in zip(step.state_out_names, new_state):
-                scope.set_var(n, v)
-        if return_numpy:
-            outs = [np.asarray(v) for v in fetches]
+        with _trace.phase("executor.bind", cache_hit=cache_hit):
+            donated_vals = read_state(step.donated_names)
+            ro_vals = read_state(step.ro_names)
+            # step-site fault probe fires BEFORE any buffer is donated, so
+            # an injected step failure leaves the scope fully usable
+            _faults.fault_point("step")
             if mrec is not None:
-                mrec.fetch_bytes = _live_bytes(outs)
-            return outs
-        return list(fetches)
+                mrec.donated_buffers = len(step.donated_names)
+                mrec.kept_buffers = len(step.kept_names)
+                mrec.donated_bytes = _live_bytes(donated_vals)
+            key = jax.random.key(self._next_seed(program))
+            rollback = None
+            with jax.default_device(device):
+                if step.nan_check_meta is not None \
+                        and _nonfinite.rollback_active():
+                    # nan_inf_policy=skip|zero_grad must be able to restore
+                    # the EXACT pre-step bits, but donation consumes the
+                    # inputs — so donate fresh device copies and keep the
+                    # originals
+                    rollback = list(zip(step.donated_names, donated_vals))
+                    donated_vals = [jnp.array(v) for v in donated_vals]
+                else:
+                    # inside default_device so the one-time host->device
+                    # copy of planted numpy state lands on THIS executor's
+                    # device
+                    donated_vals = _own_donated(donated_vals)
+                fn = self._ensure_executable(
+                    step, (feed_vals, donated_vals, ro_vals, key))
+        # watchdog-armed dispatch: a hang here (injected via the 'hang'
+        # fault site, or a real stuck collective) is dumped + raised as
+        # WatchdogTimeout under FLAGS_step_timeout_s
+        with jax.default_device(device), \
+                _trace.span("executor.step", cache_hit=cache_hit), \
+                RecordEvent("executor::step"), \
+                _dist.watchdog_section("step", program=program) as tok:
+            _faults.fault_point("hang")
+            try:
+                result = fn(feed_vals, donated_vals, ro_vals, key)
+            except (TypeError, ValueError):
+                if fn is step.fn:
+                    raise
+                # the AOT executable is stricter than jit dispatch:
+                # structure mismatches raise TypeError, committed-to-
+                # another-device shardings raise ValueError — both are
+                # checked before any buffer is donated, so retry through
+                # jit (which adapts) and stop using the AOT fast path for
+                # this step
+                step._aot = False
+                result = step.fn(feed_vals, donated_vals, ro_vals, key)
+            if tok is not None:
+                # dispatch is async — without this the section would
+                # disarm before a stuck device computation ever ran. Only
+                # under FLAGS_step_timeout_s, which opts into
+                # deadline-over-overlap
+                jax.block_until_ready(result)
+        with _trace.phase("executor.writeback"):
+            result = strip_witness_stats(step, result, path="run")
+            fetches, new_state = unpack_step_result(step, result, scope,
+                                                    path="run", exe=self,
+                                                    rollback=rollback)
+            if new_state is not None:
+                for n, v in zip(step.state_out_names, new_state):
+                    scope.set_var(n, v)
+        if not return_numpy:
+            return list(fetches)
+        return self._fetch_to_host(fetches, mrec)
+
+    @staticmethod
+    def _fetch_to_host(fetches, mrec):
+        """The ``executor.fetch`` phase: the host blocks on the device's
+        results and copies them back. Its wall is the dispatch's
+        ``fetch_wait_s`` (``executor_fetch_wait_seconds``)."""
+        with _trace.phase("executor.fetch", timed=mrec is not None) as ph:
+            outs = [np.asarray(v) for v in fetches]
+            if ph.traced:
+                ph.set_attributes(bytes=_live_bytes(outs))
+        if mrec is not None:
+            mrec.fetch_bytes = _live_bytes(outs)
+            mrec.fetch_wait_s = ph.seconds
+        return outs
 
     def run_chained(
         self,
@@ -950,11 +1016,34 @@ class Executor:
                 "run_chained with PipelineOptimizer programs: the pipeline "
                 "step is already a scan; nest via GradientMergeOptimizer")
 
-        submitted = program
-        program = self._maybe_auto_remat(program, feed, fetch_names)
-        program = self._maybe_epilogue_fusion(program, feed, fetch_names,
-                                              tuning_program=submitted)
-        self._verify_once(program, fetch_names)
+        with _trace.span("executor.run_chained", steps=int(steps)) as sp:
+            mrec = None
+            try:
+                with _trace.phase("executor.bind") as ph:
+                    submitted = program
+                    program = self._maybe_auto_remat(program, feed,
+                                                     fetch_names)
+                    program = self._maybe_epilogue_fusion(
+                        program, feed, fetch_names, tuning_program=submitted)
+                    self._verify_once(program, fetch_names)
+                    mrec = _monitor.step_begin("chained", program)
+                    step, hit = self._lookup_chained(
+                        submitted, program, feed, fetch_names, steps, scope,
+                        mrec)
+                    if ph.traced:
+                        sp.set_attribute(
+                            "program", int(getattr(program, "_serial", -1)))
+                        ph.set_attributes(cache_hit=hit)
+                return self._dispatch_chained(program, feed, steps, scope,
+                                              return_numpy, step, mrec, hit)
+            finally:
+                with _trace.phase("executor.writeback"):
+                    _monitor.step_end(mrec)
+
+    def _lookup_chained(self, submitted, program, feed, fetch_names, steps,
+                        scope, mrec):
+        """The chained cache key, its lookup, and the scan wrapper's build
+        on a miss (first segment of ``executor.bind``)."""
         # tuning keys on the SUBMITTED program: measure_candidates records
         # trials under its content fingerprint, before the auto-remat /
         # fusion clones (whose fingerprints differ) are swapped in
@@ -967,35 +1056,20 @@ class Executor:
                gemm_blocks)
         with self._lock:
             step = self._cache.get(key)
-        mrec = _monitor.step_begin("chained", program)
+        hit = step is not None
         if mrec is not None:
-            mrec.cache_hit = step is not None
+            mrec.cache_hit = hit
             mrec.iterations = int(steps)
             mrec.fetch_names = tuple(fetch_names)
-            mrec.feed_bytes = sum(_feed_host_bytes(v) for v in feed.values())
+            mrec.feed_bytes = _feed_bytes(feed)
             mrec.batch_rows = _feed_batch_rows(feed)
             mrec.device_kind = self.place.jax_device().device_kind
-        _monitor.record_cache_lookup("chained", step is not None)
-        with _trace.span("executor.run_chained",
-                         program=int(getattr(program, "_serial", -1)),
-                         steps=int(steps)):
-            try:
-                return self._run_chained_body(program, feed, fetch_names,
-                                              steps, scope, return_numpy,
-                                              key, step, feed_sig, mrec,
-                                              (opts, xla_opts, gemm_blocks))
-            finally:
-                _monitor.step_end(mrec)
-
-    def _run_chained_body(self, program, feed, fetch_names, steps, scope,
-                          return_numpy, key, step, feed_sig, mrec,
-                          compile_cfg):
+        _monitor.record_cache_lookup("chained", hit)
         if step is None:
             step = self._build_chained_step(program, feed, fetch_names,
                                             steps, scope, key, feed_sig,
-                                            compile_cfg)
-        return self._dispatch_chained(program, feed, steps, scope,
-                                      return_numpy, step, mrec)
+                                            (opts, xla_opts, gemm_blocks))
+        return step, hit
 
     def _build_chained_step(self, program, feed, fetch_names, steps, scope,
                             key, feed_sig, compile_cfg):
@@ -1150,9 +1224,42 @@ class Executor:
             return step
 
     def _dispatch_chained(self, program, feed, steps, scope,
-                          return_numpy, step, mrec):
-        feed_vals = [self._to_device_array(feed[n], program, n)
-                     for n in step.feed_names]
+                          return_numpy, step, mrec, cache_hit):
+        # the same phases as _run_body, children of executor.run_chained
+        with _trace.phase("executor.feed") as ph:
+            feed_vals = [self._to_device_array(feed[n], program, n)
+                         for n in step.feed_names]
+            if ph.traced:
+                ph.set_attributes(bytes=_feed_bytes(feed))
+        with _trace.phase("executor.bind", cache_hit=cache_hit):
+            args, fn, rollback, check = self._bind_chained(
+                program, steps, scope, step, mrec, feed_vals)
+        with jax.default_device(self.place.jax_device()), \
+                _trace.span("executor.step", cache_hit=cache_hit), \
+                RecordEvent("executor::run_chained"), \
+                _dist.watchdog_section("chained", program=program) as tok:
+            _faults.fault_point("hang")
+            try:
+                stacked, fin_carried, fin_wo = fn(*args)
+            except (TypeError, ValueError):
+                if fn is step.fn:
+                    raise
+                step._aot = False
+                stacked, fin_carried, fin_wo = step.fn(*args)
+            if tok is not None:
+                # async dispatch: keep the section armed until the
+                # scanned computation actually finished on device
+                jax.block_until_ready((stacked, fin_carried, fin_wo))
+        with _trace.phase("executor.writeback"):
+            self._writeback_chained(steps, scope, step, rollback, check,
+                                    fin_carried, fin_wo)
+        if not return_numpy:
+            return list(stacked)
+        return self._fetch_to_host(stacked, mrec)
+
+    def _bind_chained(self, program, steps, scope, step, mrec, feed_vals):
+        """State, keys, write-only carries and the executable of one
+        chained dispatch (second segment of ``executor.bind``)."""
         donated_vals = [scope.find_var(n) for n in step.donated_names]
         kept_vals = [scope.find_var(n) for n in step.kept_names]
         ro_vals = [scope.find_var(n) for n in step.ro_names]
@@ -1228,21 +1335,13 @@ class Executor:
             args = (feed_vals, donated_vals, kept_vals, ro_vals, keys,
                     wo_init, jnp.float32(0))
             fn = self._ensure_executable(step, args)
-            with RecordEvent("executor::run_chained"), \
-                    _dist.watchdog_section("chained",
-                                           program=program) as tok:
-                _faults.fault_point("hang")
-                try:
-                    stacked, fin_carried, fin_wo = fn(*args)
-                except (TypeError, ValueError):
-                    if fn is step.fn:
-                        raise
-                    step._aot = False
-                    stacked, fin_carried, fin_wo = step.fn(*args)
-                if tok is not None:
-                    # async dispatch: keep the section armed until the
-                    # scanned computation actually finished on device
-                    jax.block_until_ready((stacked, fin_carried, fin_wo))
+        return args, fn, rollback, check
+
+    def _writeback_chained(self, steps, scope, step, rollback, check,
+                           fin_carried, fin_wo) -> None:
+        """The coarse non-finite check and the scope writeback of one
+        chained dispatch; a dispatch dropped under ``FLAGS_nan_inf_policy``
+        skip/zero_grad leaves the scope on its pre-scan values."""
         if check:
             bad = next((n for n, v in
                         list(zip(step.carried_names, fin_carried))
@@ -1268,20 +1367,12 @@ class Executor:
                         f"(run_chained coarse check, scope restored to "
                         f"pre-scan values; use run for per-op provenance)")
                 _nonfinite.record_skip("chained", label, self)
-                if return_numpy:
-                    return [np.asarray(v) for v in stacked]
-                return list(stacked)
+                return
             _nonfinite.record_clean(self)
         for n, v in zip(step.carried_names, fin_carried):
             scope.set_var(n, v)
         for n, v in zip(step.wo_names, fin_wo):
             scope.set_var(n, v)
-        if return_numpy:
-            outs = [np.asarray(v) for v in stacked]
-            if mrec is not None:
-                mrec.fetch_bytes = _live_bytes(outs)
-            return outs
-        return list(stacked)
 
     def close(self):
         with self._lock:

@@ -48,7 +48,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import NEG_INF, _out_sds
+from .flash_attention import _PALLAS_SCOPE, NEG_INF, _out_sds
 
 __all__ = ["flash_attention_decode", "paged_kv_append",
            "paged_kv_append_rows", "decode_attention_reference",
@@ -186,6 +186,7 @@ def _decode_kernel(scale, num_heads, scal_ref, q_ref, k_ref, v_ref,
         o_ref[0] = (acc[:] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
+@jax.named_scope(_PALLAS_SCOPE)
 def flash_attention_decode(q, k_cache, v_cache, lengths, *,
                            scale=None, num_heads: int = 1,
                            page_size: int = 128,
@@ -254,5 +255,6 @@ def flash_attention_decode(q, k_cache, v_cache, lengths, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="decode_attention",
     )(lengths, q8, k_cache, v_cache)
     return o8[:, :Sq, :]

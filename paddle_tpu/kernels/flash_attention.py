@@ -220,6 +220,16 @@ def _fwd_kernel(cfg: _Cfg, scal_ref, *refs):
         lse_ref[0] = jnp.broadcast_to(lse_row[None, :], lse_ref.shape[1:])
 
 
+# Device-trace names (docs/OBSERVABILITY.md "Device names"): XLA names a
+# custom call after the innermost scope of its ``op_name``, and JAX wraps
+# that scope in the transforms it sits under (``jvp(...)``,
+# ``transpose(jvp(...))``). ``pallas_call(name=...)`` opens the innermost
+# scope; this outer one takes the wrapping, so a kernel keeps ONE name in
+# the HLO and the profiler however it is reached.
+_PALLAS_SCOPE = "pallas"
+
+
+@jax.named_scope(_PALLAS_SCOPE)
 def _fwd(cfg: _Cfg, q, k, v, bias, scalars):
     BH, Sq, D = q.shape
     Sk = k.shape[1]
@@ -261,6 +271,7 @@ def _fwd(cfg: _Cfg, q, k, v, bias, scalars):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=cfg.interpret,
+        name="flash_attention_fwd",
     )(scalars, *args)
     return o, lse[:, 0, :]
 
@@ -366,6 +377,7 @@ def _dkv_kernel(cfg: _Cfg, scal_ref, *refs):
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
+@jax.named_scope(_PALLAS_SCOPE)
 def _bwd(cfg: _Cfg, q, k, v, bias, scalars, do, lse, delta):
     BH, Sq, D = q.shape
     Sk = k.shape[1]
@@ -399,6 +411,7 @@ def _bwd(cfg: _Cfg, q, k, v, bias, scalars, do, lse, delta):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=cfg.interpret,
+        name="flash_attention_bwd_dq",
     )(scalars, *args)[0]
 
     # k-outer grid: swap the roles of the q/k grid axes in the index maps
@@ -429,6 +442,7 @@ def _bwd(cfg: _Cfg, q, k, v, bias, scalars, do, lse, delta):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=cfg.interpret,
+        name="flash_attention_bwd_dkv",
     )(scalars, *args)
     return dq, dk, dv
 

@@ -42,6 +42,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .flash_attention import _PALLAS_SCOPE
+
 __all__ = ["fused_gemm", "classify_gemm", "supports_gemm",
            "fused_gemm_reference", "DEFAULT_BLOCKS", "EPILOGUE_ACTIVATIONS"]
 
@@ -189,6 +191,7 @@ def _kernel(cfg: _Cfg, *refs):
         o_ref[...] = a.astype(o_ref.dtype)
 
 
+@jax.named_scope(_PALLAS_SCOPE)
 def fused_gemm(x, y, bias=None, residual=None, ln_scale=None, ln_bias=None,
                activation: str = "none", gelu_approximate: bool = False,
                layer_norm: bool = False, ln_eps: float = 1e-5,
@@ -262,6 +265,7 @@ def fused_gemm(x, y, bias=None, residual=None, ln_scale=None, ln_bias=None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=cfg.interpret,
+        name="fused_gemm",
     )(*args)
     return out
 

@@ -197,7 +197,10 @@ def lower_op(op, env: Dict[str, Any], ctx: LowerCtx) -> None:
     if op.type in ("feed", "fetch"):  # spliced by the executor, never lowered
         return
     try:
-        _lower_op_inner(op, env, ctx)
+        # trace-time only: the Fluid op type becomes a scope of every HLO
+        # instruction's op_name (benchmark/tools/op_origin.py reads it back)
+        with jax.named_scope(op.type):
+            _lower_op_inner(op, env, ctx)
     except _OpLoweringError:
         raise
     except Exception as e:

@@ -193,6 +193,17 @@ def step_end(rec: Optional[StepRecord]) -> None:
                   "wall time of one executor dispatch (feed packing + "
                   "device step + state writeback)").labels(**p).observe(
             rec.duration_s)
+        # the same wall split in two, exactly: the executor.fetch phase
+        # (host blocked on the device's results + the copy back) and the
+        # rest (host work around an asynchronous launch)
+        histogram("executor_fetch_wait_seconds",
+                  "of one dispatch's wall: the host waiting for and "
+                  "copying back the fetches").labels(**p).observe(
+            rec.fetch_wait_s)
+        histogram("executor_host_seconds",
+                  "of one dispatch's wall: everything but the fetch "
+                  "wait").labels(**p).observe(
+            rec.duration_s - rec.fetch_wait_s)
         prog = getattr(rec, "_program", None)
         if prog is not None and rec.batch_rows:
             observe_step_cost(prog, rec.batch_rows, rec.duration_s,

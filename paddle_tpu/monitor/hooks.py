@@ -42,6 +42,7 @@ class StepRecord:
     batch_rows: int = 0              # leading feed dim (cost-model batch)
     fetch_names: Tuple[str, ...] = ()
     device_kind: str = ""            # jax device_kind the step ran on
+    fetch_wait_s: float = 0.0        # of duration_s: host blocked on fetches
 
     def to_dict(self) -> dict:
         return dataclasses.asdict(self)

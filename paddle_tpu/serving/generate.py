@@ -76,6 +76,19 @@ __all__ = ["GenerationConfig", "GenerativeEngine"]
 
 logger = logging.getLogger("paddle_tpu.serving")
 
+_LOOP_HELP = ("wall of one phase of the generative dispatch thread's loop "
+              "(idle_wait, schedule, admit, feed, settle, publish); with "
+              "the executor's dispatches they tile the thread's time")
+
+
+def _loop_phase(name: str, parent=None):
+    """One phase of the dispatch thread's loop: the span ``serving.<name>``
+    (``FLAGS_trace``) and an observation on
+    ``serving_loop_seconds{phase=<name>}`` (``FLAGS_monitor``), from one
+    timing (docs/OBSERVABILITY.md "Phase spans")."""
+    return _trace.phase("serving." + name, parent=parent, histogram=(
+        "serving_loop_seconds", _LOOP_HELP, {"phase": name}))
+
 
 @dataclasses.dataclass
 class GenerationConfig:
@@ -213,6 +226,7 @@ class GenerativeEngine(ServingEngine):
                     "generation state and cannot run on a started engine "
                     "(resident streams would silently decode from zeroed "
                     "caches); call it before start()")
+        t0 = time.perf_counter()
         self.reset_generation_state()
         compiled = 0
         for bucket in self._buckets:
@@ -245,6 +259,12 @@ class GenerativeEngine(ServingEngine):
             self._note_compiles("verify", self._spec_k, net["main"])
             compiled += 1
         self.reset_generation_state()
+        if _monitor.enabled():
+            _monitor.gauge(
+                "serving_warm_up_seconds",
+                "wall of the last GenerativeEngine.warm_up(): every "
+                "(phase, bucket) executable built or loaded and run once"
+            ).set(time.perf_counter() - t0)
         return compiled
 
     def _use_chunked(self) -> bool:
@@ -328,27 +348,42 @@ class GenerativeEngine(ServingEngine):
         return req
 
     # -- scheduler -------------------------------------------------------
+    def _idle_locked(self) -> bool:
+        return (self._running and not self._queue
+                and not any(r is not None for r in self._slots))
+
     def _dispatch_forever(self) -> None:
+        # Every instant of this thread lies in one leaf span (and, always
+        # on, in one serving_loop_seconds phase or one executor dispatch):
+        # idle_wait | schedule | admit | <phase root>{feed, executor.*} |
+        # publish | settle. Keep new work inside one of them.
         self._current_batch = []
         while True:
             with self._lock:
-                while (self._running and not self._queue
-                       and not any(r is not None for r in self._slots)):
-                    self._work.wait(timeout=0.05)
-                    self._sweep_expired_locked(self._now())
-                    self._update_pressure_locked(self._now())
-                active = [r for r in self._slots if r is not None]
-                stopping = not self._running and (
-                    not self._drain or (not self._queue and not active))
-                if stopping:
-                    leftovers, self._queue = self._queue, []
-                    self._slots = [None] * len(self._slots)
-                    self._gauge_depth_locked()
-                else:
-                    now = self._now()
-                    self._sweep_expired_locked(now)
-                    self._update_pressure_locked(now)
-                    newcomers = self._refill_locked()
+                if self._idle_locked():
+                    with _loop_phase("idle_wait"):
+                        while self._idle_locked():
+                            self._work.wait(timeout=0.05)
+                            self._sweep_expired_locked(self._now())
+                            self._update_pressure_locked(self._now())
+                with _loop_phase("schedule") as ph:
+                    active = [r for r in self._slots if r is not None]
+                    stopping = not self._running and (
+                        not self._drain or (not self._queue and not active))
+                    if stopping:
+                        leftovers, self._queue = self._queue, []
+                        self._slots = [None] * len(self._slots)
+                        self._gauge_depth_locked()
+                    else:
+                        now = self._now()
+                        self._sweep_expired_locked(now)
+                        self._update_pressure_locked(now)
+                        newcomers = self._refill_locked()
+                        if ph.traced:
+                            ph.set_attributes(
+                                queued=len(self._queue),
+                                resident=len(active) + len(newcomers),
+                                newcomers=len(newcomers))
             if stopping:
                 for r in leftovers + active:
                     if not r.future.done():
@@ -363,7 +398,13 @@ class GenerativeEngine(ServingEngine):
             # ones inside one dispatch
             self._current_batch = [r for r in self._slots if r is not None]
             if newcomers:
-                self._run_prefill(self._admit_newcomers(newcomers))
+                with _loop_phase("admit") as ph:
+                    bucketed = self._admit_newcomers(newcomers)
+                    if ph.traced:
+                        ph.set_attributes(
+                            hits=sum(1 for r in newcomers if r.prefix_rows),
+                            rows=sum(r.prefix_rows for r in newcomers))
+                self._run_prefill(bucketed)
                 self._current_batch = [r for r in self._slots
                                        if r is not None]
             # one chunk slice per pending chunked request per iteration,
@@ -379,7 +420,6 @@ class GenerativeEngine(ServingEngine):
                     self._run_decode_chunk()
                 self._current_batch = [r for r in self._slots
                                        if r is not None]
-            self._gauge_kv_occupancy()
 
     def _refill_locked(self) -> List[_GenRequest]:
         """Assign queued requests to free slots (FIFO). Runs under
@@ -443,12 +483,10 @@ class GenerativeEngine(ServingEngine):
                     arr[slot, :, i * P:(i + 1) * P, :] = e[kv][li]
                 self._scope.set_var(name, arr)
 
-    def _publish_pages(self, r: _GenRequest) -> None:
+    def _publish_pages(self, r: _GenRequest) -> int:
         """After ``r``'s prefill completes, publish COPIES of its whole-
         page prompt rows under their chain hashes (cheap no-op for pages
-        already stored)."""
-        if self._prefix_cache is None:
-            return
+        already stored). Returns the number of pages newly stored."""
         P, slot = self._page_size, r.slot
 
         def page_rows(i):
@@ -460,12 +498,27 @@ class GenerativeEngine(ServingEngine):
                     self._scope.find_var(nv))[slot, :, i * P:(i + 1) * P, :]))
             return ks, vs
 
-        self._prefix_cache.insert(r.prompt, page_rows)
+        added = self._prefix_cache.insert(r.prompt, page_rows)
         if _monitor.enabled():
             _monitor.gauge(
                 "serving_prefix_pages",
                 "KV pages resident in the prefix cache").set(
                 float(len(self._prefix_cache)))
+        return added
+
+    def _publish(self, reqs: Sequence[_GenRequest]) -> None:
+        """The ``publish`` phase: the just-prefilled requests' whole prompt
+        pages go to the prefix cache, before their first tokens go out (a
+        client that has its token can count on its pages being stored).
+        Each new page pulls every K/V buffer through the host."""
+        if self._prefix_cache is None:
+            return
+        with _loop_phase("publish") as ph:
+            pages = sum(self._publish_pages(r) for r in reqs)
+            if ph.traced:
+                ph.set_attributes(pages=pages, host_bytes=pages * sum(
+                    int(getattr(self._scope.find_var(n), "nbytes", 0))
+                    for pair in self._cache_names for n in pair))
 
     def _deactivate_slot(self, slot: int) -> None:
         """Host-side decode-gate clear on retire: the slot's ``active``
@@ -513,14 +566,7 @@ class GenerativeEngine(ServingEngine):
                    if r is not None and r.chunked and not r.prefilled]
         live: List[_GenRequest] = []
         for r in pending:
-            if r.deadline is not None and r.deadline.expired:
-                self._retire(r)
-                self._settle_error(
-                    r, "deadline_exceeded",
-                    DeadlineExceeded(r.deadline.what, r.deadline.budget_s,
-                                     r.deadline.elapsed()),
-                    dispatched=True)
-            else:
+            if not self._expired(r):
                 live.append(r)
         if not live:
             return
@@ -532,7 +578,8 @@ class GenerativeEngine(ServingEngine):
                 request_traces=",".join(r.span.trace_id for r in live))
         try:
             _faults.fault_point("batch_dispatch")
-            feed = self._chunk_feed(live)
+            with _loop_phase("feed", parent=span):
+                feed = self._chunk_feed(live)
             t0 = time.perf_counter()
             with _trace.attach(span):
                 outs = self._exe.run(net["main"], feed=feed,
@@ -548,32 +595,36 @@ class GenerativeEngine(ServingEngine):
             self._fail_all_resident(e, phase="prefill_chunk")
             return
         span.end()
-        self._note_compiles("chunk", self._prefill_chunk, net["main"])
-        self.prefill_chunks += len(live)
-        if _monitor.enabled():
-            _monitor.counter(
-                "serving_prefill_chunks_total",
-                "chunked-prefill slices dispatched (per request)"
-            ).inc(len(live))
-            _monitor.histogram(
-                "serving_prefill_seconds",
-                "wall time of one slot-masked prefill dispatch").observe(dt)
-        first = np.asarray(outs[0]).reshape(len(self._slots))
         C = self._prefill_chunk
+        done: List[_GenRequest] = []
         for r in live:
-            n = min(C, len(r.prompt) - r.next_off)
-            r.next_off += n
-            if r.next_off < len(r.prompt):
-                continue
-            r.prefilled = True
-            self._publish_pages(r)
+            r.next_off += min(C, len(r.prompt) - r.next_off)
+            if r.next_off >= len(r.prompt):
+                r.prefilled = True
+                done.append(r)
+        self._publish(done)
+        with _loop_phase("settle") as ph:
+            self._note_compiles("chunk", self._prefill_chunk, net["main"])
+            self.prefill_chunks += len(live)
             if _monitor.enabled():
+                _monitor.counter(
+                    "serving_prefill_chunks_total",
+                    "chunked-prefill slices dispatched (per request)"
+                ).inc(len(live))
                 _monitor.histogram(
-                    "serving_first_token_seconds",
-                    "submit-to-first-token latency (prefill + queue)"
-                ).observe(self._now() - r.submitted)
-            self._emit(r, [int(first[r.slot])], dt,
-                       record_intertoken=False)
+                    "serving_prefill_seconds",
+                    "wall time of one slot-masked prefill dispatch"
+                ).observe(dt)
+            first = np.asarray(outs[0]).reshape(len(self._slots))
+            for r in done:
+                if _monitor.enabled():
+                    _monitor.histogram(
+                        "serving_first_token_seconds",
+                        "submit-to-first-token latency (prefill + queue)"
+                    ).observe(self._now() - r.submitted)
+                self._emit(r, [int(first[r.slot])], dt,
+                           record_intertoken=False)
+            self._settled(ph, done, len(done))
 
     # -- speculative decoding --------------------------------------------
     def _ngram_draft(self, hist: np.ndarray, n: int) -> List[int]:
@@ -646,7 +697,8 @@ class GenerativeEngine(ServingEngine):
         net = self._verify
         try:
             _faults.fault_point("batch_dispatch")
-            feed = self._verify_feed(active)
+            with _loop_phase("feed", parent=span):
+                feed = self._verify_feed(active)
             t0 = time.perf_counter()
             with _trace.attach(span):
                 outs = self._exe.run(
@@ -664,37 +716,31 @@ class GenerativeEngine(ServingEngine):
             self._fail_all_resident(e, phase="spec_verify")
             return True
         span.end()
-        self._note_compiles("verify", k, net["main"])
-        self.spec_chunks += 1
-        accept = np.asarray(outs[0]).reshape(len(self._slots))
-        sampled = np.asarray(outs[1]).reshape(len(self._slots), k)
-        if _monitor.enabled():
-            _monitor.histogram(
-                "serving_decode_chunk_seconds",
-                "wall time of one chained decode chunk").observe(dt)
-        for r in active:
-            if r.deadline is not None and r.deadline.expired:
-                self._retire(r)
-                self._settle_error(
-                    r, "deadline_exceeded",
-                    DeadlineExceeded(r.deadline.what, r.deadline.budget_s,
-                                     r.deadline.elapsed()),
-                    dispatched=True)
-                continue
-            m = int(accept[r.slot])
-            self.spec_accepted += m
+        with _loop_phase("settle") as ph:
+            self._note_compiles("verify", k, net["main"])
+            self.spec_chunks += 1
+            accept = np.asarray(outs[0]).reshape(len(self._slots))
+            sampled = np.asarray(outs[1]).reshape(len(self._slots), k)
             if _monitor.enabled():
                 _monitor.histogram(
-                    "serving_spec_accepted_len",
-                    "draft tokens accepted per verify chunk (0..k-1; the "
-                    "bonus token is on top)").observe(float(m))
-            take = sampled[r.slot, :m + 1][:r.max_new - r.emitted]
-            eos = self.gen_config.eos_id
-            if eos >= 0:
-                hits = np.nonzero(take == eos)[0]
-                if hits.size:
-                    take = take[:int(hits[0]) + 1]
-            self._emit(r, [int(t) for t in take], dt)
+                    "serving_decode_chunk_seconds",
+                    "wall time of one chained decode chunk").observe(dt)
+            tokens = 0
+            for r in active:
+                if self._expired(r):
+                    continue
+                m = int(accept[r.slot])
+                self.spec_accepted += m
+                if _monitor.enabled():
+                    _monitor.histogram(
+                        "serving_spec_accepted_len",
+                        "draft tokens accepted per verify chunk (0..k-1; "
+                        "the bonus token is on top)").observe(float(m))
+                take = self._cut_at_eos(
+                    sampled[r.slot, :m + 1][:r.max_new - r.emitted])
+                tokens += len(take)
+                self._emit(r, [int(t) for t in take], dt)
+            self._settled(ph, active, tokens)
         return True
 
     # -- prefill ---------------------------------------------------------
@@ -735,7 +781,8 @@ class GenerativeEngine(ServingEngine):
                         bucket=bucket, slot=r.slot)
             try:
                 _faults.fault_point("batch_dispatch")
-                feed = self._prefill_feed(bucket, reqs)
+                with _loop_phase("feed", parent=span):
+                    feed = self._prefill_feed(bucket, reqs)
                 t0 = time.perf_counter()
                 with _trace.attach(span):
                     outs = self._exe.run(net["main"], feed=feed,
@@ -755,35 +802,32 @@ class GenerativeEngine(ServingEngine):
                 self._fail_all_resident(e, phase="prefill")
                 return
             span.end()
-            self._note_compiles("prefill", bucket, net["main"])
-            if _monitor.enabled():
-                _monitor.histogram(
-                    "serving_prefill_seconds",
-                    "wall time of one slot-masked prefill dispatch"
-                ).observe(dt)
-            first = np.asarray(outs[0]).reshape(len(self._slots))
-            for r in reqs:
-                r.prefilled = True
-                r.next_off = len(r.prompt)
-                self._publish_pages(r)
-                if r.deadline is not None and r.deadline.expired:
-                    self._retire(r)
-                    self._settle_error(
-                        r, "deadline_exceeded",
-                        DeadlineExceeded(r.deadline.what,
-                                         r.deadline.budget_s,
-                                         r.deadline.elapsed()),
-                        dispatched=True)
-                    continue
+            self._publish(reqs)
+            with _loop_phase("settle") as ph:
+                self._note_compiles("prefill", bucket, net["main"])
                 if _monitor.enabled():
                     _monitor.histogram(
-                        "serving_first_token_seconds",
-                        "submit-to-first-token latency (prefill + queue)"
-                    ).observe(self._now() - r.submitted)
-                # the first token's cost is the FIRST-TOKEN histogram's
-                # story — it must not pollute the inter-token latency
-                self._emit(r, [int(first[r.slot])], dt,
-                           record_intertoken=False)
+                        "serving_prefill_seconds",
+                        "wall time of one slot-masked prefill dispatch"
+                    ).observe(dt)
+                first = np.asarray(outs[0]).reshape(len(self._slots))
+                tokens = 0
+                for r in reqs:
+                    r.prefilled = True
+                    r.next_off = len(r.prompt)
+                    if self._expired(r):
+                        continue
+                    if _monitor.enabled():
+                        _monitor.histogram(
+                            "serving_first_token_seconds",
+                            "submit-to-first-token latency (prefill + "
+                            "queue)").observe(self._now() - r.submitted)
+                    # the first token's cost is the FIRST-TOKEN histogram's
+                    # story — it must not pollute the inter-token latency
+                    tokens += 1
+                    self._emit(r, [int(first[r.slot])], dt,
+                               record_intertoken=False)
+                self._settled(ph, reqs, tokens)
 
     # -- decode ----------------------------------------------------------
     def _run_decode_chunk(self) -> None:
@@ -817,34 +861,56 @@ class GenerativeEngine(ServingEngine):
             self._fail_all_resident(e, phase="decode")
             return
         span.end()
-        self._note_compiles("decode", len(self._slots), self._program)
-        toks = np.asarray(outs[0]).reshape(steps, len(self._slots))
-        per_tok = dt / steps
-        if _monitor.enabled():
-            _monitor.histogram(
-                "serving_decode_chunk_seconds",
-                "wall time of one chained decode chunk").observe(dt)
-        for r in active:
-            if r.deadline is not None and r.deadline.expired:
+        with _loop_phase("settle") as ph:
+            self._note_compiles("decode", len(self._slots), self._program)
+            toks = np.asarray(outs[0]).reshape(steps, len(self._slots))
+            per_tok = dt / steps
+            if _monitor.enabled():
+                _monitor.histogram(
+                    "serving_decode_chunk_seconds",
+                    "wall time of one chained decode chunk").observe(dt)
+            tokens = 0
+            for r in active:
                 # mid-stream expiry: the typed outcome is the LAST word —
                 # this chunk's tokens are discarded, the ones already
                 # streamed remain readable as partial results
-                self._retire(r)
-                self._settle_error(
-                    r, "deadline_exceeded",
-                    DeadlineExceeded(r.deadline.what, r.deadline.budget_s,
-                                     r.deadline.elapsed()),
-                    dispatched=True)
-                continue
-            take = toks[:r.max_new - r.emitted, r.slot]
-            eos = self.gen_config.eos_id
-            if eos >= 0:
-                hits = np.nonzero(take == eos)[0]
-                if hits.size:
-                    take = take[:int(hits[0]) + 1]
-            self._emit(r, [int(t) for t in take], per_tok * len(take))
+                if self._expired(r):
+                    continue
+                take = self._cut_at_eos(toks[:r.max_new - r.emitted, r.slot])
+                tokens += len(take)
+                self._emit(r, [int(t) for t in take], per_tok * len(take))
+            self._settled(ph, active, tokens)
 
     # -- shared settle paths ---------------------------------------------
+    def _expired(self, r: _GenRequest) -> bool:
+        """Retire ``r`` with its typed ``DeadlineExceeded`` if its deadline
+        has passed; True when it did."""
+        if r.deadline is None or not r.deadline.expired:
+            return False
+        self._retire(r)
+        self._settle_error(
+            r, "deadline_exceeded",
+            DeadlineExceeded(r.deadline.what, r.deadline.budget_s,
+                             r.deadline.elapsed()),
+            dispatched=True)
+        return True
+
+    def _cut_at_eos(self, take):
+        eos = self.gen_config.eos_id
+        if eos >= 0:
+            hits = np.nonzero(take == eos)[0]
+            if hits.size:
+                take = take[:int(hits[0]) + 1]
+        return take
+
+    def _settled(self, ph, reqs: Sequence[_GenRequest], tokens: int) -> None:
+        """Tail of every settle phase: the occupancy gauge, and what the
+        phase settled as its span's attributes."""
+        self._gauge_kv_occupancy()
+        if ph.traced:
+            ph.set_attributes(tokens=tokens, finished=sum(
+                1 for r in reqs if r.future.done()))
+
     def _emit(self, r: _GenRequest, toks: List[int], dt: float,
               record_intertoken: bool = True) -> None:
         """Stream ``toks`` to the future (partial results) and settle the

@@ -44,10 +44,12 @@ from collections import deque
 from typing import Any, Dict, List, Optional
 
 from .. import flags as _flags
+from .. import monitor as _monitor
 from ..monitor.lockwitness import make_lock
 
 __all__ = [
     "Span", "SpanContext", "enabled", "span", "root_span", "start_span",
+    "phase",
     "current_span", "current_context", "attach", "get_collector",
     "SpanCollector", "spans", "clear", "to_chrome_events", "export_chrome",
     "export_jsonl", "record_incident", "incidents", "clear_incidents",
@@ -165,14 +167,18 @@ class Span:
         return self
 
     def end(self, status: str = "ok",
-            error: Optional[BaseException] = None) -> None:
+            error: Optional[BaseException] = None,
+            t1_mono: Optional[float] = None) -> None:
         """Close the span exactly once (later calls no-op: a request span
         settled by the dispatch thread must not be re-closed by a racing
-        sweep). Closed spans land in the collector and flight recorder."""
+        sweep). Closed spans land in the collector and flight recorder.
+        ``t1_mono``: a ``time.perf_counter()`` reading the caller already
+        took for the end (:func:`phase` times once for both its sinks)."""
         if self._ended:
             return
         self._ended = True
-        self.duration_s = time.perf_counter() - self.t0_mono
+        self.duration_s = (time.perf_counter() if t1_mono is None
+                           else t1_mono) - self.t0_mono
         if error is not None:
             self.status = "error"
             self.error = f"{type(error).__name__}: {error}"
@@ -187,11 +193,15 @@ class Span:
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        self._leave(exc)
+        return False
+
+    def _leave(self, exc, t1_mono: Optional[float] = None) -> None:
         if self._token:
             _pop(self)
             self._token = None
-        self.end(error=exc if isinstance(exc, BaseException) else None)
-        return False
+        self.end(error=exc if isinstance(exc, BaseException) else None,
+                 t1_mono=t1_mono)
 
     def to_dict(self) -> dict:
         return {"name": self.name, "trace_id": self.trace_id,
@@ -338,6 +348,90 @@ def root_span(name: str, **attrs) -> Span:
     if not enabled():
         return NOOP_SPAN
     return _make_span(name, False, attrs)
+
+
+class _Phase:
+    """One timed phase of a loop, fed to two sinks: a span (``FLAGS_trace``)
+    and a monitor histogram child (``FLAGS_monitor``). See :func:`phase`."""
+
+    __slots__ = ("_span", "_hist", "_t0", "seconds")
+
+    def __init__(self, span, hist):
+        self._span = span
+        self._hist = hist
+        self._t0 = 0.0
+        self.seconds = 0.0
+
+    @property
+    def traced(self) -> bool:
+        """True when a real span is open: guard attribute expressions
+        that cost anything with it (``if ph.traced: ph.set_attributes``)."""
+        return self._span is not NOOP_SPAN
+
+    def set_attributes(self, **kwargs) -> "_Phase":
+        self._span.set_attributes(**kwargs)
+        return self
+
+    def __enter__(self) -> "_Phase":
+        sp = self._span
+        if sp is NOOP_SPAN:
+            self._t0 = time.perf_counter()
+        else:
+            sp.__enter__()
+            self._t0 = sp.t0_mono     # the span's own clock reading
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        t1 = time.perf_counter()
+        self.seconds = t1 - self._t0
+        sp = self._span
+        if sp is not NOOP_SPAN:
+            sp._leave(exc, t1_mono=t1)
+        if self._hist is not None:
+            self._hist.observe(self.seconds)
+        return False
+
+
+class _NoopPhase:
+    """Both sinks off: no clock read, no allocation."""
+
+    __slots__ = ()
+    traced = False
+    seconds = 0.0
+
+    def set_attributes(self, **kwargs):
+        return self
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+NOOP_PHASE = _NoopPhase()
+
+
+def phase(name: str, parent=None, histogram=None, timed: bool = False,
+          **attrs):
+    """``with trace.phase("serving.settle", histogram=(family, help,
+    labels)) as ph:`` — ONE site timed ONCE (``time.perf_counter()`` at
+    entry and exit) for two sinks: a child span named ``name`` when
+    ``FLAGS_trace`` is on, and an observation of the duration on the
+    monitor histogram ``family{labels}`` when ``FLAGS_monitor`` is on.
+    ``ph.seconds`` holds the duration after the block (``timed=True``
+    keeps the clock running with both sinks off, for a caller that stores
+    it, e.g. on a ``StepRecord``); ``ph.traced`` says whether attributes
+    are worth computing. With everything off this returns a singleton:
+    no clock read, no allocation (``tools/trace_check.py`` gates it)."""
+    sp = _make_span(name, parent, attrs) if enabled() else NOOP_SPAN
+    hist = None
+    if histogram is not None and _monitor.enabled():
+        family, help_text, labels = histogram
+        hist = _monitor.histogram(family, help_text).labels(**labels)
+    if sp is NOOP_SPAN and hist is None and not timed:
+        return NOOP_PHASE
+    return _Phase(sp, hist)
 
 
 def _make_span(name, parent, attrs) -> Span:

@@ -84,3 +84,38 @@ def test_fused_gemm_exact_gelu_epilogue_compiles(v5e):
         lambda x, y, b: fused_gemm(x, y, bias=b, activation="gelu"),
         v5e((16384, 768), jnp.bfloat16), v5e((768, 3072), jnp.bfloat16),
         v5e((3072,), jnp.float32))
+
+
+def test_kernels_keep_their_names_in_the_compiled_hlo(v5e):
+    """What a profiler's ``XLA Ops`` line prints is the instruction's own
+    name: each Mosaic call carries the name the program chose, and the
+    forward flash kernel ONE name whether reached plainly (a forward op)
+    or under ``jvp`` (a grad op's recomputation) — docs/OBSERVABILITY.md
+    "Device names"."""
+    import re
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, num_heads=12).astype(
+            jnp.float32).sum()
+
+    def step(q, k, v, qd, kc, vc, n, x, y):
+        with jax.named_scope("fused_attention"):
+            fwd = flash_attention(q, k, v, num_heads=12)
+        with jax.named_scope("fused_attention_grad"):
+            grads = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+        return (fwd, grads,
+                flash_attention_decode(qd, kc, vc, n, num_heads=12,
+                                       page_size=128),
+                fused_gemm(x, y))
+
+    qkv = v5e((24, 512, 64), jnp.float32)
+    text = _compiles_with_mosaic(
+        step, qkv, qkv, qkv, v5e((96, 1, 64), jnp.float32),
+        v5e((96, 1024, 64), jnp.float32), v5e((96, 1024, 64), jnp.float32),
+        v5e((8,), jnp.int32), v5e((1024, 768), jnp.bfloat16),
+        v5e((768, 3072), jnp.bfloat16))
+    names = sorted(re.sub(r"[.\d]+$", "", m) for m in re.findall(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text))
+    assert names == ["decode_attention", "flash_attention_bwd_dkv",
+                     "flash_attention_bwd_dq", "flash_attention_fwd",
+                     "flash_attention_fwd", "fused_gemm"]

@@ -97,8 +97,31 @@ def _verify_trace(trace_id: str) -> dict:
     return {"spans": len(tree), "one_root": len(roots) == 1,
             "all_closed": closed, "no_orphans": no_orphans,
             "parent_closes_after_children": parent_after_children,
+            "executor_phases_tile_each_dispatch": _phases_tile(tree),
             "root_has_outcome": bool(roots)
             and roots[0].attrs.get("outcome") is not None}
+
+
+EXECUTOR_PHASES = {"executor.bind", "executor.feed", "executor.step",
+                   "executor.fetch", "executor.writeback"}
+
+
+def _phases_tile(tree) -> bool:
+    """Every ``executor.run`` / ``executor.run_chained`` of the trace has
+    the five phase children (docs/OBSERVABILITY.md "Phase spans"), one
+    after the other: none starts before the one before it has ended. (A
+    first call also has ``executor.compile`` among them.)"""
+    for parent in tree:
+        if parent.name not in ("executor.run", "executor.run_chained"):
+            continue
+        kids = sorted((s for s in tree if s.parent_id == parent.span_id),
+                      key=lambda s: s.t0_mono)
+        if not EXECUTOR_PHASES <= {s.name for s in kids}:
+            return False
+        for a, b in zip(kids, kids[1:]):
+            if b.t0_mono < a.t0_mono + a.duration_s - 1e-9:
+                return False
+    return True
 
 
 def leg_serving_burst(n_requests=24, n_threads=3) -> dict:
@@ -298,17 +321,32 @@ def leg_watchdog_flight() -> dict:
             "terminal_chain": sorted(final_chain)}
 
 
-def leg_overhead(n=200_000, budget_ns=3000) -> dict:
+def leg_overhead(n=200_000, budget_ns=3000, histogram_budget_ns=20_000
+                 ) -> dict:
     """FLAGS_trace=0 span hot path: bounded ns/span, no allocation
-    (identity singleton)."""
+    (identity singleton); the same for a phase with no histogram; and a
+    bound on the always-on half of a phase (one timing, one histogram
+    observation) — a generative decode iteration makes two of those."""
     fluid.set_flags({"FLAGS_trace": 0})
     assert not trace.enabled()
     spans = [trace.span("bench") for _ in range(4)]
+    phases = [trace.phase("bench") for _ in range(4)]
     t0 = time.perf_counter()
     for _ in range(n):
         with trace.span("bench"):
             pass
     disabled_ns = (time.perf_counter() - t0) / n * 1e9
+    t0 = time.perf_counter()
+    for _ in range(n):
+        with trace.phase("bench"):
+            pass
+    phase_ns = (time.perf_counter() - t0) / n * 1e9
+    hist = ("trace_check_phase_seconds", "overhead probe", {"phase": "x"})
+    t0 = time.perf_counter()
+    for _ in range(n // 10):
+        with trace.phase("bench", histogram=hist):
+            pass
+    hist_ns = (time.perf_counter() - t0) / (n // 10) * 1e9
     fluid.set_flags({"FLAGS_trace": 1})
     t0 = time.perf_counter()
     for _ in range(n // 20):
@@ -318,14 +356,20 @@ def leg_overhead(n=200_000, budget_ns=3000) -> dict:
     trace.clear()
     checks = {
         "no_allocation_when_disabled": all(s is trace.NOOP_SPAN
-                                           for s in spans),
+                                           for s in spans)
+        and all(p is trace.NOOP_PHASE for p in phases),
         "disabled_under_budget": disabled_ns < budget_ns,
+        "disabled_phase_under_budget": phase_ns < budget_ns,
+        "histogram_phase_under_budget": hist_ns < histogram_budget_ns,
     }
     return {"name": "overhead_guard", "ok": all(checks.values()),
             "checks": checks,
             "disabled_ns_per_span": round(disabled_ns),
+            "disabled_ns_per_phase": round(phase_ns),
+            "histogram_ns_per_phase": round(hist_ns),
             "enabled_ns_per_span": round(enabled_ns),
-            "budget_ns": budget_ns}
+            "budget_ns": budget_ns,
+            "histogram_budget_ns": histogram_budget_ns}
 
 
 def leg_cost_model() -> dict:
