@@ -17,14 +17,18 @@ of the softmax, so cache capacity can be provisioned once and reused across
 requests at different positions.
 
 CODA (PAPERS.md, arXiv 2605.19269) motivates folding the decode-step
-epilogue work into the fused kernels instead of separate ops:
-:func:`flash_attention_decode` therefore also performs the KV APPEND — the
-new token's K/V rows are written into the cache at ``position`` before the
-attention walk, and the updated caches are returned alongside the output so
-the program-IR level sees ONE op that reads and writes the cache at the
-same index (which is what lets ``analysis.liveness.safe_donation_set``
-prove the cache buffer donatable: its last read is not after its last
-write).
+epilogue work into one op instead of separate ones. The fold is made one
+level up, in the op rule (``ops/generation.py`` ``fused_decode_attention``):
+it appends the chunk's K/V rows with :func:`paged_kv_append_rows` and then
+calls :func:`flash_attention_decode` on the updated caches, so the
+program-IR level sees ONE op that reads and writes the cache at the same
+index (which is what lets ``analysis.liveness.safe_donation_set`` prove the
+cache buffer donatable: its last read is not after its last write).
+:func:`flash_attention_decode` itself only READS the caches and returns the
+attention output; it neither appends nor returns them. The append helpers
+below are plain XLA updates of the donated buffer, one sequence at a time,
+and they take the slot mask: a mask gates the rows that are written, never
+the cache (see :func:`paged_kv_append`).
 
 Design notes
 - q rides in ``[BH, 8, D]`` sublane tiles (Mosaic needs the second-to-last
@@ -58,28 +62,50 @@ __all__ = ["flash_attention_decode", "paged_kv_append",
 KERNEL_ROWS = 8
 
 
-def paged_kv_append(cache, new, positions):
+def _keep(mask, batch):
+    """``mask`` ([B], [B, 1], any dtype; > 0 = write) as [B] bool, or None."""
+    return None if mask is None else mask.reshape(batch) > 0
+
+
+def paged_kv_append(cache, new, positions, mask=None):
     """Write ``new`` rows into ``cache`` at per-sequence ``positions``.
 
     cache: [B, ..., S_max, D]; new: [B, ..., L, D]; positions: [B] int —
-    the start row per sequence (the page-aligned case L == page_size is
-    the prefill bulk write; L == 1 is the decode append). XLA lowers the
-    per-sequence ``dynamic_update_slice`` in place when the cache buffer
-    is donated — this is the KV-append path the decode op fuses with the
-    attention walk. Out-of-range starts clamp (XLA semantics), so a
+    the start row per sequence (L == prompt bucket is the prefill bulk
+    write; L == 1 is one row of the decode append). One
+    ``dynamic_update_slice`` per sequence, which XLA applies to a donated
+    cache in place. Out-of-range starts clamp (XLA semantics), so a
     retired sequence whose position saturates keeps overwriting the last
     row instead of corrupting a neighbour.
+
+    ``mask`` ([B], > 0 = write) gates the ROWS, never the cache: a
+    sequence whose mask is 0 writes its own old rows back (they are read
+    at the same clamped start), so its cache stays bit-identical and the
+    masked append moves B x ... x L x D elements, like the unmasked one,
+    where a ``where`` over the result would rewrite every row of every
+    cache and hold the old cache alive beside the new one.
     """
-    positions = positions.reshape(positions.shape[0]).astype(jnp.int32)
+    B = cache.shape[0]
+    positions = positions.reshape(B).astype(jnp.int32)
+    new = new.astype(cache.dtype)
+    keep = _keep(mask, B)
 
-    def upd(c, n, p):
-        start = (jnp.int32(0),) * (c.ndim - 2) + (p, jnp.int32(0))
-        return jax.lax.dynamic_update_slice(c, n.astype(c.dtype), start)
+    # one sequence at a time, with scalar starts: XLA updates the carried
+    # buffer in place, where the batched forms are a gather and a scatter
+    # for which the TPU compiler re-lays the whole cache
+    def one(b, c):
+        start = (b,) + (jnp.int32(0),) * (c.ndim - 3) + (
+            positions[b], jnp.int32(0))
+        n = jax.lax.dynamic_index_in_dim(new, b, 0)
+        if keep is not None:
+            n = jnp.where(keep[b], n,
+                          jax.lax.dynamic_slice(c, start, n.shape))
+        return jax.lax.dynamic_update_slice(c, n, start)
 
-    return jax.vmap(upd)(cache, new, positions)
+    return jax.lax.fori_loop(0, B, one, cache)
 
 
-def paged_kv_append_rows(cache, new, positions):
+def paged_kv_append_rows(cache, new, positions, mask=None):
     """Chunked KV write with PER-ROW clamping: row ``i`` of ``new``
     ([B, ..., C, D]) lands at ``min(positions + i, S_max - 1)``. Unlike
     :func:`paged_kv_append` (one ``dynamic_update_slice`` of the whole
@@ -88,24 +114,29 @@ def paged_kv_append_rows(cache, new, positions):
     onto the LAST row — and the last row is never inside a live length
     mask (the serving layer caps ``prompt + max_new <= S_max`` and the
     final generated token is never appended), so overflow is unreadable
-    garbage, not corruption.
+    garbage, not corruption. ``mask`` ([B], > 0 = write) leaves the cache
+    of a sequence whose mask is 0 bit-identical, at the cost of the rows
+    and not of the cache (see :func:`paged_kv_append`).
 
     Two lowerings of the same result, chosen by the row count. Up to
     ``KERNEL_ROWS`` rows — the decode step and the verify chunk, the
     shapes the Pallas kernel serves — one ``dynamic_update_slice`` per
-    row: XLA updates the donated cache in place next to the kernel's
-    custom call (compiled for v5e, decode needs 0.12 GiB of temporaries
-    this way and 1.7 GiB with a scatter). Past that — chunked-prefill
-    slices, which ride the primitive path anyway — ONE scatter: unrolled,
-    a 128-row chunk was 3,072 update ops over 12 layers and its compile
-    took minutes where its siblings take seconds."""
+    row (a masked-out sequence writes its old row back): XLA updates the
+    donated cache in place next to the kernel's custom call. Past that —
+    chunked-prefill slices, which ride the primitive path anyway — ONE
+    scatter (a masked-out sequence's indices go out of range, where
+    ``mode="drop"`` discards them): unrolled, a 128-row chunk was 3,072
+    update ops over 12 layers and its compile took minutes where its
+    siblings take seconds."""
     S = cache.shape[-2]
     C = new.shape[-2]
-    positions = positions.reshape(positions.shape[0]).astype(jnp.int32)
+    B = cache.shape[0]
+    positions = positions.reshape(B).astype(jnp.int32)
     if C <= KERNEL_ROWS:
         for i in range(C):
             row_pos = jnp.minimum(positions + i, S - 1)
-            cache = paged_kv_append(cache, new[..., i:i + 1, :], row_pos)
+            cache = paged_kv_append(cache, new[..., i:i + 1, :], row_pos,
+                                    mask)
         return cache
     rows = positions[:, None] + jnp.arange(C, dtype=jnp.int32)    # [B, C]
     # every row at or past S-1 clamps onto the last cache row, where the
@@ -115,6 +146,9 @@ def paged_kv_append_rows(cache, new, positions):
     # applies a scatter in
     shadowed = (rows >= S - 1) & (jnp.arange(C) < C - 1)
     idx = jnp.where(shadowed, S, jnp.minimum(rows, S - 1))
+    keep = _keep(mask, B)
+    if keep is not None:
+        idx = jnp.where(keep[:, None], idx, S)
 
     def upd(c, n, r):
         return c.at[..., r, :].set(n.astype(c.dtype), mode="drop")

@@ -929,8 +929,9 @@ def fused_decode_attention(q, k_new, v_new, cache_k, cache_v, positions,
     persistable paged caches [B, H, S_max, D]; positions: [B, 1] int —
     each sequence's length before this chunk. Query row i attends keys at
     positions < pos + i + 1 (causal within the chunk). ``slot_mask``
-    [B, 1] (optional) keeps un-masked sequences' caches bit-untouched —
-    the chunked-prefill / speculative dispatches run a subset of slots.
+    [B, 1] (optional) gates the rows the append writes, so un-masked
+    sequences' caches stay bit-untouched — the chunked-prefill /
+    speculative dispatches run a subset of slots.
     The updated caches are written BACK INTO the cache vars (the single
     read+write op shape the donation proof needs), and the attended
     context [B, H, C, D] is returned. scale=0.0 means 1/sqrt(D)."""

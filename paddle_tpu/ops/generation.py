@@ -80,9 +80,15 @@ def _fused_decode_attention(ctx, ins, attrs):
        at positions < pos + i + 1 — its own K row and everything before,
        never a later chunk row).
 
-    ``SlotMask`` [B, 1] (optional) keeps un-masked sequences' caches
-    bit-untouched — the chunked-prefill and speculative-verify dispatches
-    run a subset of slots while their neighbours keep decoding.
+    ``SlotMask`` [B, 1] (optional) gates the ROWS that step 1 writes: a
+    sequence whose mask is 0 writes its own old rows back (or, past
+    ``KERNEL_ROWS`` rows, nothing), so its caches stay bit-untouched —
+    the chunked-prefill and speculative-verify dispatches run a subset of
+    slots while their neighbours keep decoding, and the decode chunk runs
+    under the ``active`` gate. The mask never selects between an old and
+    a new CACHE: after the append this rule holds no reference to the old
+    one, so the donated buffer is updated in place through the scan carry
+    and a masked append costs what an unmasked one does.
     ``CacheKOut``/``CacheVOut`` are the updated caches — program builders
     point them back at the cache vars, making this the one op that reads
     and writes them (the donation-proof shape, see module docstring).
@@ -105,12 +111,8 @@ def _fused_decode_attention(ctx, ins, attrs):
     page = int(attrs.get("page_size") or 128)
     scale = attrs["scale"] or float(D) ** -0.5
     pos_b = pos.reshape(B).astype(jnp.int32)
-    ck2 = paged_kv_append_rows(ck, kn, pos_b)
-    cv2 = paged_kv_append_rows(cv, vn, pos_b)
-    if smask is not None:
-        m = (smask.reshape(B) > 0).reshape((B, 1, 1, 1))
-        ck2 = jnp.where(m, ck2, ck)
-        cv2 = jnp.where(m, cv2, cv)
+    ck2 = paged_kv_append_rows(ck, kn, pos_b, smask)
+    cv2 = paged_kv_append_rows(cv, vn, pos_b, smask)
     lengths = jnp.minimum(pos_b + 1, S)
 
     q3 = q.reshape(B * H, q_len, D)
@@ -142,21 +144,17 @@ def _kv_cache_append(ctx, ins, attrs):
     """Bulk KV write: place ``New`` [B, H, L, D] rows into ``Cache``
     [B, H, S_max, D] starting at per-sequence ``Positions`` [B, 1] (the
     prefill path writes a whole prompt, L = prompt bucket, at position 0).
-    ``SlotMask`` [B, 1] (optional) keeps un-masked sequences' cache rows
-    untouched — the continuous-batching refill writes only the slots being
-    prefilled while their neighbours keep decoding. Builders point ``Out``
+    ``SlotMask`` [B, 1] (optional) gates the rows that are written: a
+    sequence whose mask is 0 writes its own L old rows back, so its cache
+    stays bit-untouched at the cost of L rows, not of the cache — the
+    continuous-batching refill writes only the slots being prefilled
+    while their neighbours keep decoding. Builders point ``Out``
     back at the cache var: the op reads and writes it at one index, so the
     buffer donates (liveness-proven in-place update)."""
     from ..kernels import paged_kv_append
 
     cache, new, pos = x(ins, "Cache"), x(ins, "New"), x(ins, "Positions")
-    mask = x(ins, "SlotMask")
-    B = cache.shape[0]
-    upd = paged_kv_append(cache, new, pos.reshape(B))
-    if mask is not None:
-        m = (mask.reshape(B) > 0).reshape((B,) + (1,) * (cache.ndim - 1))
-        upd = jnp.where(m, upd, cache)
-    return {"Out": [upd]}
+    return {"Out": [paged_kv_append(cache, new, pos, x(ins, "SlotMask"))]}
 
 
 @register_op(

@@ -119,3 +119,64 @@ def test_kernels_keep_their_names_in_the_compiled_hlo(v5e):
     assert names == ["decode_attention", "flash_attention_bwd_dkv",
                      "flash_attention_bwd_dq", "flash_attention_fwd",
                      "flash_attention_fwd", "fused_gemm"]
+
+
+def _whole_cache_work_in_loops(text, cache_shape):
+    """Names of the instructions outside the entry computation (so inside
+    a ``while`` body) that PRODUCE a whole cache: a ``copy`` or a select
+    of that shape. In-place updates (``dynamic-update-slice``, alone or
+    as a fusion's root) and tuple plumbing are not."""
+    import re
+
+    shape = re.escape("f32[" + ",".join(map(str, cache_shape)) + "]")
+    made = re.compile(r"^\s+(?:ROOT )?%?((?:copy|[\w\-]*select[\w\-]*)"
+                      r"[.\w]*) = \(?" + shape)
+    found, entry = [], False
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            entry = line.startswith("ENTRY")
+        m = made.match(line)
+        if m and not entry and "dynamic-update-slice" not in m.group(1):
+            found.append(m.group(1))
+    return found
+
+
+@pytest.mark.parametrize("form", ["rows", "where_over_the_cache"])
+def test_masked_append_keeps_the_scan_carry_in_place(v5e, form):
+    """The decode chunk as ``run_chained`` runs it: a scan whose carry is
+    the donated caches, each step a masked append and the decode kernel.
+    With the mask on the rows, nothing in the loop body produces a whole
+    cache; the old form (``where(m, appended, cache)``, kept here so that
+    the guard is seen to see) costs a select and copies of every cache
+    every token."""
+    from paddle_tpu.kernels import paged_kv_append_rows
+
+    B, H, S, D = 8, 12, 1024, 64
+
+    def append(cache, new, pos, mask):
+        if form == "rows":
+            return paged_kv_append_rows(cache, new, pos, mask)
+        appended = jax.vmap(lambda c, n, p: jax.lax.dynamic_update_slice(
+            c, n, (jnp.int32(0), p, jnp.int32(0))))(cache, new, pos)
+        m = (mask.reshape(B) > 0).reshape(B, 1, 1, 1)
+        return jnp.where(m, appended, cache)
+
+    def chunk(ck, cv, q, kn, vn, pos, mask):
+        def body(carry, _):
+            ck, cv, pos = carry
+            ck, cv = append(ck, kn, pos, mask), append(cv, vn, pos, mask)
+            o = flash_attention_decode(
+                q.reshape(B * H, 1, D), ck.reshape(B * H, S, D),
+                cv.reshape(B * H, S, D), jnp.minimum(pos + 1, S),
+                num_heads=H, page_size=128)
+            return (ck, cv, pos + mask.reshape(B).astype(pos.dtype)), o
+        return jax.lax.scan(body, (ck, cv, pos), None, length=4)
+
+    cache, row = v5e((B, H, S, D), jnp.float32), v5e((B, H, 1, D),
+                                                    jnp.float32)
+    text = jax.jit(chunk, donate_argnums=(0, 1)).lower(
+        cache, cache, row, row, row, v5e((B,), jnp.int32),
+        v5e((B, 1), jnp.float32)).compile().as_text()
+    assert "tpu_custom_call" in text
+    found = _whole_cache_work_in_loops(text, (B, H, S, D))
+    assert (found == []) if form == "rows" else len(found) >= 2

@@ -126,22 +126,124 @@ def test_paged_kv_append_at_page_boundaries():
                                       np.asarray(new)[b, :, 0])
 
 
-def test_kv_cache_append_op_slot_mask():
-    """The op face: a slot-masked append touches only masked sequences'
-    rows (the continuous-batching refill invariant)."""
+def _lower(op_type, ins, attrs=None):
     from paddle_tpu.core.registry import get_op_def
     from paddle_tpu.lowering import LowerCtx
 
+    return get_op_def(op_type).lower(LowerCtx(), ins, attrs or {})
+
+
+def test_kv_cache_append_op_slot_mask():
+    """The op face: a slot-masked append touches only masked sequences'
+    rows (the continuous-batching refill invariant)."""
     B, H, S, D = 2, 1, 16, 4
     cache = jnp.asarray(RNG.randn(B, H, S, D).astype(np.float32))
     new = jnp.asarray(RNG.randn(B, H, 4, D).astype(np.float32))
     ins = {"Cache": [cache], "New": [new],
            "Positions": [jnp.zeros((B, 1), jnp.int32)],
            "SlotMask": [jnp.asarray([[1.0], [0.0]], jnp.float32)]}
-    out = get_op_def("kv_cache_append").lower(LowerCtx(), ins, {})["Out"][0]
+    out = _lower("kv_cache_append", ins)["Out"][0]
     out = np.asarray(out)
     np.testing.assert_array_equal(out[0, :, :4], np.asarray(new)[0])
     np.testing.assert_array_equal(out[1], np.asarray(cache)[1])
+
+
+def _masked_append_case(rows, positions, bulk=False):
+    """Inputs of one masked append over 4 slots (mask 1, 0, 1, 0) and the
+    old form's answer: the unmasked append written out in numpy, then
+    ``where(mask, appended, cache)`` over the whole cache."""
+    B, H, S, D = 4, 2, 32, 8
+    rng = np.random.RandomState(26 + rows)
+    cache = rng.randn(B, H, S, D).astype(np.float32)
+    new = rng.randn(B, H, rows, D).astype(np.float32)
+    mask = np.array([1.0, 0.0, 1.0, 0.0], np.float32)
+    appended = cache.copy()
+    for b, p in enumerate(positions):
+        if bulk:                  # one block; an out-of-range START clamps
+            p = min(p, S - rows)
+            appended[b, :, p:p + rows] = new[b]
+        else:                     # row by row onto min(p + i, S - 1)
+            for i in range(rows):
+                appended[b, :, min(p + i, S - 1)] = new[b, :, i]
+    oracle = np.where(mask.reshape(B, 1, 1, 1) > 0, appended, cache)
+    return cache, new, np.asarray(positions, np.int64)[:, None], \
+        mask[:, None], oracle
+
+
+def _decode_append(cache, new, pos, mask):
+    """The caches ``fused_decode_attention`` returns (K and V alike)."""
+    got = _lower("fused_decode_attention", {
+        "Q": [new], "KNew": [new], "VNew": [new], "CacheK": [cache],
+        "CacheV": [cache], "Positions": [pos], "SlotMask": [mask]},
+        {"scale": 0.0, "page_size": 8})
+    return got["CacheKOut"][0], got["CacheVOut"][0]
+
+
+def _bulk_append(cache, new, pos, mask):
+    return (_lower("kv_cache_append", {
+        "Cache": [cache], "New": [new], "Positions": [pos],
+        "SlotMask": [mask]})["Out"][0],)
+
+
+# slots 2 and 3 sit where the write clamps at S_max - 1 = 31 (or, for the
+# bulk write, where its start clamps): one masked in, one masked out
+MASKED_APPENDS = {
+    "decode_C1": (1, (3, 5, 40, 31), False),
+    "verify_C4": (4, (3, 5, 30, 29), False),
+    "chunk_C16_scatter": (16, (3, 5, 20, 25), False),
+    "bulk_L16": (16, (0, 0, 20, 30), True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(MASKED_APPENDS))
+def test_masked_append_writes_rows_and_matches_where_oracle(case):
+    """The slot mask gates the rows that are written: a masked-out slot's
+    caches come back bit-identical, a masked-in slot's equal the old
+    ``where(m, appended, cache)`` form, clamped positions included."""
+    rows, positions, bulk = MASKED_APPENDS[case]
+    cache, new, pos, mask, oracle = _masked_append_case(rows, positions,
+                                                        bulk)
+    outs = (_bulk_append if bulk else _decode_append)(
+        *map(jnp.asarray, (cache, new, pos, mask)))
+    for out in outs:
+        out = np.asarray(out)
+        for b in (1, 3):
+            assert out[b].tobytes() == cache[b].tobytes()
+        np.testing.assert_array_equal(out, oracle)
+
+
+def test_masked_append_rules_make_no_cache_sized_elementwise_op():
+    """What a CPU can guard of the chip's cost: traced with a mask,
+    neither op rule holds an operation whose result is as large as a cache
+    other than the updates themselves and the calls that contain them. A
+    ``select_n`` over the cache (the old ``where(m, new_cache, cache)``)
+    rewrites every row of every cache every token, and holds the old
+    cache alive so that the update cannot be made in place."""
+    import jax
+
+    cache, new, pos, mask, _ = _masked_append_case(1, (3, 5, 40, 31))
+    bulk = _masked_append_case(16, (0, 0, 20, 30), bulk=True)[1]
+    may_hold_a_cache = {"dynamic_update_slice", "scatter", "while", "scan",
+                        "cond", "pjit", "closed_call", "core_call",
+                        "reshape", "pallas_call"}
+
+    def cache_sized(jaxpr, found):
+        for eqn in jaxpr.eqns:
+            for v in eqn.outvars:
+                if getattr(v.aval, "size", 0) >= cache.size \
+                        and eqn.primitive.name not in may_hold_a_cache:
+                    found.append((eqn.primitive.name, v.aval.shape))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                cache_sized(sub, found)
+        return found
+
+    for fn, rows in ((_decode_append, new), (_bulk_append, bulk)):
+        jaxpr = jax.make_jaxpr(fn)(cache, rows, pos, mask).jaxpr
+        assert cache_sized(jaxpr, []) == []
+    # the guard does see the old form
+    old = jax.make_jaxpr(lambda c, m: jnp.where(m.reshape(4, 1, 1, 1) > 0,
+                                                c + 0.0, c))(cache, mask)
+    assert {n for n, _ in cache_sized(old.jaxpr, [])} >= {"select_n"}
 
 
 # ---------------------------------------------------------------------------
