@@ -67,7 +67,7 @@ def _keep(mask, batch):
     return None if mask is None else mask.reshape(batch) > 0
 
 
-def paged_kv_append(cache, new, positions, mask=None):
+def paged_kv_append(cache, new, positions, mask=None, slots=None):
     """Write ``new`` rows into ``cache`` at per-sequence ``positions``.
 
     cache: [B, ..., S_max, D]; new: [B, ..., L, D]; positions: [B] int —
@@ -84,17 +84,27 @@ def paged_kv_append(cache, new, positions, mask=None):
     masked append moves B x ... x L x D elements, like the unmasked one,
     where a ``where`` over the result would rewrite every row of every
     cache and hold the old cache alive beside the new one.
+
+    ``slots`` ([B'] int): ``new`` holds B' <= B sequences and sequence
+    ``i`` goes to the cache's row ``slots[i]`` (a prefill that carries only
+    the sequences it serves); without it sequence ``i`` is row ``i``.
     """
-    B = cache.shape[0]
+    B = new.shape[0]
+    if slots is None and B != cache.shape[0]:
+        raise ValueError(f"paged_kv_append: {B} sequences of rows for a "
+                         f"cache of {cache.shape[0]}, and no slots")
     positions = positions.reshape(B).astype(jnp.int32)
     new = new.astype(cache.dtype)
     keep = _keep(mask, B)
+    if slots is not None:
+        slots = slots.reshape(B).astype(jnp.int32)
 
     # one sequence at a time, with scalar starts: XLA updates the carried
     # buffer in place, where the batched forms are a gather and a scatter
     # for which the TPU compiler re-lays the whole cache
     def one(b, c):
-        start = (b,) + (jnp.int32(0),) * (c.ndim - 3) + (
+        at = b if slots is None else slots[b]
+        start = (at,) + (jnp.int32(0),) * (c.ndim - 3) + (
             positions[b], jnp.int32(0))
         n = jax.lax.dynamic_index_in_dim(new, b, 0)
         if keep is not None:
@@ -105,9 +115,11 @@ def paged_kv_append(cache, new, positions, mask=None):
     return jax.lax.fori_loop(0, B, one, cache)
 
 
-def paged_kv_append_rows(cache, new, positions, mask=None):
+def paged_kv_append_rows(cache, new, positions, mask=None, ring=False):
     """Chunked KV write with PER-ROW clamping: row ``i`` of ``new``
-    ([B, ..., C, D]) lands at ``min(positions + i, S_max - 1)``. Unlike
+    ([B, ..., C, D]) lands at ``min(positions + i, S_max - 1)`` — or, with
+    ``ring`` (a windowed layer's cache, whose ``S_max`` rows are the last
+    ``S_max`` positions), at ``(positions + i) % S_max``. Unlike
     :func:`paged_kv_append` (one ``dynamic_update_slice`` of the whole
     block, whose out-of-range START shifts backwards over real rows), a
     chunk whose tail crosses the cache end collapses its overflow rows
@@ -134,10 +146,15 @@ def paged_kv_append_rows(cache, new, positions, mask=None):
     positions = positions.reshape(B).astype(jnp.int32)
     if C <= KERNEL_ROWS:
         for i in range(C):
-            row_pos = jnp.minimum(positions + i, S - 1)
+            row_pos = ((positions + i) % S if ring
+                       else jnp.minimum(positions + i, S - 1))
             cache = paged_kv_append(cache, new[..., i:i + 1, :], row_pos,
                                     mask)
         return cache
+    if ring:
+        raise NotImplementedError(
+            f"a {C}-row chunk into a ring cache: only steps of up to "
+            f"{KERNEL_ROWS} rows wrap")
     rows = positions[:, None] + jnp.arange(C, dtype=jnp.int32)    # [B, C]
     # every row at or past S-1 clamps onto the last cache row, where the
     # chunk's LAST row wins (what the row-by-row form does). The rows it
@@ -156,19 +173,22 @@ def paged_kv_append_rows(cache, new, positions, mask=None):
     return jax.vmap(upd)(cache, new, idx)
 
 
-def decode_attention_reference(q, k_cache, v_cache, lengths, scale):
+def decode_attention_reference(q, k_cache, v_cache, lengths, scale,
+                               group: int = 1):
     """Primitive oracle: masked softmax attention of a chunk of query rows
     per sequence against its cache. q: [BH, Sq, D]; caches: [BH, S, D];
     lengths: [BH] (already expanded per head) — the number of keys visible
     to query row 0; row ``i`` sees ``lengths + i`` keys (causal within the
     chunk, whose K rows were appended before the attention). Sq == 1 is
-    the classic decode step. Matches the kernel semantics exactly; also
-    the op's off-TPU lowering."""
+    the classic decode step. With ``group`` > 1 (grouped-query heads) BH
+    counts key/value heads and row ``i`` is query head ``i % group`` at
+    chunk position ``i // group``. Matches the kernel semantics exactly;
+    also the op's off-TPU lowering."""
     prec = "highest" if q.dtype == jnp.float32 else "default"
     s = jnp.einsum("bqd,bkd->bqk", q.astype(jnp.float32),
                    k_cache.astype(jnp.float32), precision=prec) * scale
     k_pos = jnp.arange(k_cache.shape[1])[None, None, :]
-    row = jnp.arange(q.shape[1])[None, :, None]
+    row = jnp.arange(q.shape[1])[None, :, None] // group
     s = jnp.where(k_pos < lengths[:, None, None] + row, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bqk,bkd->bqd", p, v_cache.astype(jnp.float32),
@@ -176,7 +196,7 @@ def decode_attention_reference(q, k_cache, v_cache, lengths, scale):
     return o.astype(q.dtype)
 
 
-def _decode_kernel(scale, num_heads, scal_ref, q_ref, k_ref, v_ref,
+def _decode_kernel(scale, num_heads, group, scal_ref, q_ref, k_ref, v_ref,
                    o_ref, m_scr, l_scr, acc):
     bh, ik = pl.program_id(0), pl.program_id(1)
     num_k = pl.num_programs(1)
@@ -198,6 +218,8 @@ def _decode_kernel(scale, num_heads, scal_ref, q_ref, k_ref, v_ref,
     # length - 1 + i) sees length + i keys; padding rows past the real
     # chunk see more keys, but their output is sliced away by the caller
     row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    if group > 1:       # grouped-query heads: `group` query heads a position
+        row = row // group
     s = jnp.where(k_pos < length + row, s, NEG_INF)
 
     m_prev = m_scr[:, :1]
@@ -223,7 +245,7 @@ def _decode_kernel(scale, num_heads, scal_ref, q_ref, k_ref, v_ref,
 @jax.named_scope(_PALLAS_SCOPE)
 def flash_attention_decode(q, k_cache, v_cache, lengths, *,
                            scale=None, num_heads: int = 1,
-                           page_size: int = 128,
+                           page_size: int = 128, group: int = 1,
                            interpret: bool = False):
     """One decode/verify chunk: q [BH, Sq, D] (1 <= Sq <= 8) against paged
     caches [BH, S_max, D].
@@ -238,13 +260,20 @@ def flash_attention_decode(q, k_cache, v_cache, lengths, *,
     ``S_max`` must divide into whole pages
     (``flash_attention.classify_shapes`` refuses otherwise). Returns
     o [BH, Sq, D]. Inference-only (no VJP).
+
+    ``group`` > 1 is grouped-query attention: BH counts KEY/VALUE heads
+    (``num_heads`` of them a sequence) and the ``group`` query heads that
+    share one ride the sublane rows beside each other — row ``i`` is query
+    head ``i % group`` at chunk position ``i // group`` — so a cache page
+    is read once for all of them.
     """
     BH, Sq, D = q.shape
     Sk = k_cache.shape[1]
-    if not 1 <= Sq <= KERNEL_ROWS:
+    if Sq % group or not 1 <= Sq // group <= KERNEL_ROWS:
         raise ValueError(
             f"flash_attention_decode is the q_len<={KERNEL_ROWS} chunk "
-            f"path (one sublane tile), got q_len={Sq}; use flash_attention "
+            f"path (one sublane tile), got q_len={Sq // group} "
+            f"(x {group} grouped heads); use flash_attention "
             f"for prefill/full-sequence shapes")
     bk = min(page_size, Sk)
     if Sk % bk:
@@ -257,35 +286,39 @@ def flash_attention_decode(q, k_cache, v_cache, lengths, *,
         raise ValueError(
             f"lengths has {lengths.shape[0]} rows but q has BH={BH} with "
             f"num_heads={num_heads} (expected {BH // num_heads})")
-    # pad the chunk to one full sublane tile: [BH, Sq, D] -> [BH, 8, D]
-    # (replicas of the last real row; their output is sliced away)
-    if Sq == 8:
+    # pad the chunk to whole sublane tiles: [BH, Sq, D] -> [BH, R, D]
+    # (replicas of the last real row; their output is sliced away). A
+    # packed 16-bit type tiles 16 rows.
+    tile = 8 * (4 // q.dtype.itemsize)
+    R = -(-Sq // tile) * tile
+    if Sq == R:
         q8 = q
     else:
         q8 = jnp.concatenate(
-            [q, jnp.broadcast_to(q[:, -1:, :], (BH, 8 - Sq, D))], axis=1)
+            [q, jnp.broadcast_to(q[:, -1:, :], (BH, R - Sq, D))], axis=1)
     nk = Sk // bk
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(BH, nk),
         in_specs=[
-            pl.BlockSpec((1, 8, D), lambda bh, ik, s: (bh, 0, 0)),
+            pl.BlockSpec((1, R, D), lambda bh, ik, s: (bh, 0, 0)),
             pl.BlockSpec((1, bk, D), lambda bh, ik, s: (bh, ik, 0)),
             pl.BlockSpec((1, bk, D), lambda bh, ik, s: (bh, ik, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 8, D), lambda bh, ik, s: (bh, 0, 0)),
+            pl.BlockSpec((1, R, D), lambda bh, ik, s: (bh, 0, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((8, 128), jnp.float32),     # running max
-            pltpu.VMEM((8, 128), jnp.float32),     # running denom
-            pltpu.VMEM((8, D), jnp.float32),       # numerator acc
+            pltpu.VMEM((R, 128), jnp.float32),     # running max
+            pltpu.VMEM((R, 128), jnp.float32),     # running denom
+            pltpu.VMEM((R, D), jnp.float32),       # numerator acc
         ],
     )
     (o8,) = pl.pallas_call(
-        functools.partial(_decode_kernel, scale, int(num_heads)),
+        functools.partial(_decode_kernel, scale, int(num_heads),
+                          int(group)),
         grid_spec=grid_spec,
-        out_shape=[_out_sds((BH, 8, D), q.dtype, q, k_cache, v_cache)],
+        out_shape=[_out_sds((BH, R, D), q.dtype, q, k_cache, v_cache)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,

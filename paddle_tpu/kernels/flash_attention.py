@@ -72,6 +72,12 @@ class _Cfg:
     # 'highest' for f32 inputs (true f32 multiplies), 'default' for bf16
     # (native MXU one-pass mode)
     precision: str
+    # sliding window: a query sees keys at 0 <= q_pos - k_pos < window
+    # (0: no window). Rides the causal comparison.
+    window: int = 0
+    # grouped-query heads: `kv_group` query heads read one key/value head
+    # (q is [B*Hq, ...], k and v [B*Hq/kv_group, ...]). Forward only.
+    kv_group: int = 1
 
 
 def classify_shapes(sq: int, sk: int, block_q: int = 128,
@@ -156,6 +162,14 @@ def _dropout_keep(seed, bh, iq, ik, shape, rate):
     return u >= rate
 
 
+def _visible(cfg: "_Cfg", q_pos, k_pos):
+    """The causal comparison, with the window where there is one."""
+    seen = q_pos >= k_pos
+    if cfg.window:
+        seen = seen & (q_pos - k_pos < cfg.window)
+    return seen
+
+
 # --------------------------------------------------------------------------
 # forward
 # --------------------------------------------------------------------------
@@ -188,7 +202,7 @@ def _fwd_kernel(cfg: _Cfg, scal_ref, *refs):
                  + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
         k_pos = (scal_ref[1] + ik * cfg.block_k
                  + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
-        s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+        s = jnp.where(_visible(cfg, q_pos, k_pos), s, NEG_INF)
 
     m_prev = m_scr[:, :1]                          # [bq, 1]
     m_cur = jnp.max(s, axis=1, keepdims=True)
@@ -234,10 +248,14 @@ def _fwd(cfg: _Cfg, q, k, v, bias, scalars):
     BH, Sq, D = q.shape
     Sk = k.shape[1]
     nq, nk = Sq // cfg.block_q, Sk // cfg.block_k
+    if cfg.kv_group == 1:
+        kv_map = lambda bh, iq, ik, s: (bh, ik, 0)
+    else:       # bh = b * Hq + h reads b * Hkv + h // G = bh // G
+        kv_map = lambda bh, iq, ik, s: (bh // cfg.kv_group, ik, 0)
     in_specs = [
         pl.BlockSpec((1, cfg.block_q, D), lambda bh, iq, ik, s: (bh, iq, 0)),
-        pl.BlockSpec((1, cfg.block_k, D), lambda bh, iq, ik, s: (bh, ik, 0)),
-        pl.BlockSpec((1, cfg.block_k, D), lambda bh, iq, ik, s: (bh, ik, 0)),
+        pl.BlockSpec((1, cfg.block_k, D), kv_map),
+        pl.BlockSpec((1, cfg.block_k, D), kv_map),
     ]
     args = [q, k, v]
     if cfg.has_bias:
@@ -292,7 +310,7 @@ def _recompute_p(cfg, scal_ref, q, k, b_ref, lse, iq, ik):
                  + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
         k_pos = (scal_ref[1] + ik * cfg.block_k
                  + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
-        s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+        s = jnp.where(_visible(cfg, q_pos, k_pos), s, NEG_INF)
     lse_safe = jnp.where(jnp.isfinite(lse), lse, -NEG_INF)  # dead rows: p=0
     return jnp.exp(s - lse_safe[:, None])
 
@@ -464,6 +482,10 @@ def _flash_fwd_rule(cfg, q, k, v, bias, scalars):
 def _flash_bwd_rule(cfg, res, cts):
     q, k, v, bias, scalars, o, lse = res
     do, dlse = cts
+    if cfg.kv_group != 1:
+        raise NotImplementedError(
+            "flash attention backward with grouped-query heads: the dK/dV "
+            "kernel does not sum over a group's query heads")
     # delta_i = sum_d dO_id * O_id  = rowsum(P_dropped * dP); the lse
     # cotangent enters the same P-weighted term (d lse/dS = P), so it folds
     # in by subtraction.
@@ -484,7 +506,7 @@ def flash_attention_with_lse(q, k, v, bias: Optional[jax.Array] = None,
                              q_offset=0, k_offset=0,
                              num_heads: int = 1,
                              block_q: int = 128, block_k: int = 128,
-                             interpret: bool = False):
+                             interpret: bool = False, window: int = 0):
     """Flash attention over [B*H, S, D] tensors; returns (O, lse).
 
     ``bias`` is an additive [B, Sk] key bias (the padding-mask encoding —
@@ -494,9 +516,18 @@ def flash_attention_with_lse(q, k, v, bias: Optional[jax.Array] = None,
     causal comparison to GLOBAL positions for ring attention. ``lse`` is the
     per-row log-sum-exp; its cotangent is honoured, so blockwise
     combinations that re-weight through lse differentiate correctly.
+
+    ``window`` > 0 (with ``causal``) lets a query see only the last
+    ``window`` positions, itself included. ``k``/``v`` may have a whole
+    fraction of ``q``'s leading B*H rows (grouped-query heads, forward
+    only): query head ``n`` reads key/value head ``n // group``.
     """
     BH, Sq, D = q.shape
     Sk = k.shape[1]
+    if BH % k.shape[0] or (window and not causal):
+        raise ValueError(
+            f"flash_attention: q has {BH} batch-heads, k {k.shape[0]}; "
+            f"window={window} needs causal")
     bq, bk = min(block_q, Sq), min(block_k, Sk)
     if Sq % bq or Sk % bk:
         raise ValueError(
@@ -513,7 +544,8 @@ def flash_attention_with_lse(q, k, v, bias: Optional[jax.Array] = None,
                num_heads=int(num_heads), has_bias=bias is not None,
                interpret=bool(interpret),
                precision=("highest" if q.dtype == jnp.float32
-                          else "default"))
+                          else "default"),
+               window=int(window), kv_group=BH // k.shape[0])
     scalars = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                          jnp.asarray(k_offset, jnp.int32),
                          jnp.asarray(seed, jnp.int32)])
@@ -526,10 +558,12 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
                     causal: bool = False, scale: Optional[float] = None,
                     dropout_rate: float = 0.0, seed=0,
                     num_heads: int = 1, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = False):
+                    block_k: int = 128, interpret: bool = False,
+                    window: int = 0):
     """Like :func:`flash_attention_with_lse` but returns only O."""
     o, _ = flash_attention_with_lse(
         q, k, v, bias=bias, causal=causal, scale=scale,
         dropout_rate=dropout_rate, seed=seed, num_heads=num_heads,
-        block_q=block_q, block_k=block_k, interpret=interpret)
+        block_q=block_q, block_k=block_k, interpret=interpret,
+        window=window)
     return o

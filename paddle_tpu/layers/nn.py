@@ -372,18 +372,24 @@ def mean(x, name=None):
     return _simple("mean")(x, name=name)
 
 
-def matmul(x, y, transpose_x=False, transpose_y=False, alpha=1.0, name=None):
+def matmul(x, y, transpose_x=False, transpose_y=False, alpha=1.0, name=None,
+           out_dtype=None):
+    """``out_dtype`` keeps the accumulator's type for the result (f32 out
+    of bf16 operands) where the operands' own type would round it."""
     helper = LayerHelper("matmul", name=name)
-    out = helper.create_variable_for_type_inference(x.dtype)
+    out = helper.create_variable_for_type_inference(out_dtype or x.dtype)
+    attrs = {"transpose_X": transpose_x, "transpose_Y": transpose_y,
+             "alpha": alpha}
+    if out_dtype:
+        attrs["out_dtype"] = str(out_dtype)
     helper.append_op("matmul", inputs={"X": x, "Y": y}, outputs={"Out": out},
-                     attrs={"transpose_X": transpose_x,
-                            "transpose_Y": transpose_y, "alpha": alpha})
+                     attrs=attrs)
     return out
 
 
 def fused_multihead_attention(q, k, v, bias_qk=None, causal=False,
                               scale=0.0, attn_dropout=0.0, is_test=False,
-                              sequence_parallel=False, name=None):
+                              sequence_parallel=False, name=None, window=0):
     """Fused multi-head attention (the reference `operators/fused/` role,
     here a Pallas flash kernel on TPU — ops/fused_attention.py).
 
@@ -395,18 +401,21 @@ def fused_multihead_attention(q, k, v, bias_qk=None, causal=False,
     'sp' axis (CompiledProgram places=mesh), attention runs as ring
     attention over that axis — sequence/context parallelism for sequences
     too long for one chip. bias_qk/attn_dropout are unsupported on that
-    path; without an sp axis it degrades to the plain fused path."""
+    path; without an sp axis it degrades to the plain fused path.
+
+    k/v may carry a whole fraction of q's heads (grouped-query attention,
+    inference only); window > 0 with causal is a sliding window."""
     helper = LayerHelper("fused_multihead_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
     inputs = {"Q": q, "K": k, "V": v}
     if bias_qk is not None:
         inputs["BiasQK"] = bias_qk
+    attrs = {"causal": causal, "scale": scale, "attn_dropout": attn_dropout,
+             "is_test": is_test, "sequence_parallel": sequence_parallel}
+    if window:
+        attrs["window"] = int(window)
     helper.append_op("fused_multihead_attention", inputs=inputs,
-                     outputs={"Out": out},
-                     attrs={"causal": causal, "scale": scale,
-                            "attn_dropout": attn_dropout,
-                            "is_test": is_test,
-                            "sequence_parallel": sequence_parallel})
+                     outputs={"Out": out}, attrs=attrs)
     return out
 
 

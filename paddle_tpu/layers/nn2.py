@@ -36,6 +36,7 @@ __all__ = [
     "lstm_unit", "autoincreased_step_counter", "adaptive_pool3d",
     "beam_search", "beam_search_decode", "filter_by_instag",
     "fused_decode_attention", "kv_cache_append", "sequence_gather",
+    "rotary_embedding", "moe_experts", "slot_assign",
     "sample_token", "spec_accept",
 ]
 
@@ -922,7 +923,7 @@ def filter_by_instag(ins, ins_tag, filter_tag, is_lod=True):
 
 def fused_decode_attention(q, k_new, v_new, cache_k, cache_v, positions,
                            scale=0.0, page_size=128, slot_mask=None,
-                           name=None):
+                           window=0, name=None):
     """One autoregressive decode/verify chunk with the KV append fused in
     (ops/generation.py). q/k_new/v_new: [B, H, C, D] (C == 1 is the
     classic decode step; C <= 8 rides the chunk kernel); cache_k/cache_v:
@@ -934,7 +935,10 @@ def fused_decode_attention(q, k_new, v_new, cache_k, cache_v, positions,
     speculative dispatches run a subset of slots.
     The updated caches are written BACK INTO the cache vars (the single
     read+write op shape the donation proof needs), and the attended
-    context [B, H, C, D] is returned. scale=0.0 means 1/sqrt(D)."""
+    context [B, H, C, D] is returned. scale=0.0 means 1/sqrt(D).
+    ``q`` may carry a whole multiple of the caches' heads (grouped-query
+    attention). ``window`` > 0: the caches are a ring of
+    ``min(window, max_seq)`` rows holding the last positions (C == 1)."""
     helper = LayerHelper("fused_decode_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
     inputs = {"Q": q, "KNew": k_new, "VNew": v_new,
@@ -942,27 +946,83 @@ def fused_decode_attention(q, k_new, v_new, cache_k, cache_v, positions,
               "Positions": positions}
     if slot_mask is not None:
         inputs["SlotMask"] = slot_mask
+    attrs = {"scale": float(scale), "page_size": int(page_size)}
+    if window:
+        attrs["window"] = int(window)
     helper.append_op(
         "fused_decode_attention",
         inputs=inputs,
         outputs={"Out": out, "CacheKOut": cache_k, "CacheVOut": cache_v},
-        attrs={"scale": float(scale), "page_size": int(page_size)})
+        attrs=attrs)
     return out
 
 
-def kv_cache_append(cache, new, positions, slot_mask=None, name=None):
+def rotary_embedding(x, positions, theta=10000.0, name=None):
+    """Rotary position embedding on interleaved pairs (ops/moe.py): ``x``
+    [B, heads, S, D], ``positions`` [B, S] int; same shape and type out."""
+    helper = LayerHelper("rotary_embedding", name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op("rotary_embedding",
+                     inputs={"X": x, "Positions": positions},
+                     outputs={"Out": out}, attrs={"theta": float(theta)})
+    return out
+
+
+def moe_experts(x, router_w, gate_w, up_w, down_w, num_experts, top_k,
+                expert_offset=0, token_mask=None, name=None):
+    """The routed experts a chip holds (ops/moe.py): routes ``x`` [..., H]
+    (f32) over all ``num_experts`` by ``router_w`` [H, num_experts] and
+    returns ``(out, stats)``: the part of the routed sum that the experts
+    ``expert_offset .. expert_offset + gate_w.shape[0] - 1`` give (f32),
+    and an int32 vector of the assignments each of them received, all
+    assignments made, and assignments dropped (always 0). ``token_mask``
+    (``x``'s leading shape, > 0 = a real token) keeps padding out of the
+    routing."""
+    helper = LayerHelper("moe_experts", name=name)
+    out = helper.create_variable_for_type_inference("float32")
+    stats = helper.create_variable_for_type_inference("int32",
+                                                      stop_gradient=True)
+    inputs = {"X": x, "RouterW": router_w, "GateW": gate_w, "UpW": up_w,
+              "DownW": down_w}
+    if token_mask is not None:
+        inputs["TokenMask"] = token_mask
+    helper.append_op(
+        "moe_experts", inputs=inputs,
+        outputs={"Out": out, "Stats": stats},
+        attrs={"num_experts": int(num_experts), "top_k": int(top_k),
+               "expert_offset": int(expert_offset)})
+    return out, stats
+
+
+def kv_cache_append(cache, new, positions, slot_mask=None, slots=None,
+                    name=None):
     """Bulk KV write into a paged cache var (ops/generation.py): ``new``
     [B, H, L, D] lands at per-sequence ``positions`` [B, 1]; with
     ``slot_mask`` [B, 1] only masked sequences' rows change (the
-    continuous-batching refill). Writes in place into ``cache`` (returns
-    the same var)."""
+    continuous-batching refill). With ``slots`` [B', 1], ``new`` carries
+    B' <= B sequences and sequence ``i`` goes to the cache's row
+    ``slots[i]``. Writes in place into ``cache`` (returns the same var)."""
     helper = LayerHelper("kv_cache_append", name=name)
     inputs = {"Cache": cache, "New": new, "Positions": positions}
     if slot_mask is not None:
         inputs["SlotMask"] = slot_mask
+    if slots is not None:
+        inputs["Slots"] = slots
     helper.append_op("kv_cache_append", inputs=inputs,
                      outputs={"Out": cache})
     return cache
+
+
+def slot_assign(x, slots, updates, mask=None, name=None):
+    """``x`` [B, ...] with row ``slots[i]`` replaced by ``updates[i]``
+    wherever ``mask[i]`` > 0 (ops/generation.py); written in place into
+    ``x`` (returns the same var)."""
+    helper = LayerHelper("slot_assign", name=name)
+    inputs = {"X": x, "Slots": slots, "Updates": updates}
+    if mask is not None:
+        inputs["Mask"] = mask
+    helper.append_op("slot_assign", inputs=inputs, outputs={"Out": x})
+    return x
 
 
 def sequence_gather(x, index, name=None):

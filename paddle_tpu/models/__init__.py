@@ -10,5 +10,7 @@ from .bert import BertConfig, build_bert_pretrain  # noqa: F401
 from .deepfm import build_deepfm  # noqa: F401
 from .gpt import (GptConfig, build_gpt_decode,  # noqa: F401
                   build_gpt_generative, build_gpt_prefill)
+from .cohere_moe import (CohereMoeConfig,  # noqa: F401
+                         build_cohere_moe_generative)
 from .seq2seq import (build_seq2seq_infer, build_seq2seq_train,  # noqa: F401
                       build_seq2seq_train_varlen)

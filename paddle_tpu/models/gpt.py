@@ -531,6 +531,10 @@ def build_gpt_generative(cfg: GptConfig = None, batch_slots: int = 4,
     return {"config": cfg, "startup": startup, "prefill": prefill,
             "decode": decode, "chunk": chunk, "verify": verify,
             "state_vars": decode["state_vars"],
+            # how the serving layer finds the caches and the decode gate
+            "cache_vars": [(f"gpt_kv_k_{i}", f"gpt_kv_v_{i}")
+                           for i in range(cfg.num_layers)],
+            "active_var": "gpt_gen_active",
             "batch_slots": batch_slots, "max_seq": max_seq,
             "page_size": page_size, "prompt_buckets": prompt_buckets,
             "prefill_chunk": prefill_chunk, "spec_k": int(spec_k),

@@ -65,7 +65,7 @@ def _route_decode(s_max: int, page_size: int, q_len: int = 1,
             IOSpec("Positions", no_grad=True),
             IOSpec("SlotMask", optional=True, no_grad=True)],
     outputs=["Out", "CacheKOut", "CacheVOut"],
-    attrs={"scale": 0.0, "page_size": 128},
+    attrs={"scale": 0.0, "page_size": 128, "window": 0},
     grad=None)
 def _fused_decode_attention(ctx, ins, attrs):
     """One autoregressive decode/verify chunk, epilogue fused:
@@ -95,6 +95,21 @@ def _fused_decode_attention(ctx, ins, attrs):
     Retired sequences whose position saturates past S_max - 1 clamp onto
     the last row and their output is garbage by design — the serving
     layer discards it (the last row is never inside a live length mask).
+
+    Grouped-query heads: ``Q`` may carry a whole multiple of the caches'
+    heads; query head ``n`` reads key/value head ``n // group``, and the
+    group's heads ride one kernel call beside each other, so a cache page
+    is read once for all of them.
+
+    ``window`` > 0 is a sliding-window layer: a query sees the last
+    ``window`` positions, itself included. Its caches hold
+    ``S_max = min(window, max_seq)`` rows as a ring — position ``p`` lives
+    in row ``p % S_max`` — so once positions pass the window a new row
+    overwrites the one that just left it, and every row the ring holds is
+    visible: the length mask is ``min(pos + 1, S_max)``, as it is without a
+    window. Keys carry their positions in themselves (rotary) or not at
+    all, so the order of the ring's rows does not matter to the softmax.
+    Only single-row steps wrap (a chunk's causal order is its row order).
     """
     from ..kernels import (decode_attention_reference, flash_attention_decode,
                            paged_kv_append_rows)
@@ -103,19 +118,33 @@ def _fused_decode_attention(ctx, ins, attrs):
     ck, cv = x(ins, "CacheK"), x(ins, "CacheV")
     pos = x(ins, "Positions")
     smask = x(ins, "SlotMask")
-    B, H, q_len, D = q.shape
+    B, Hq, q_len, D = q.shape
     if q_len < 1:
         raise ValueError(
             f"fused_decode_attention: q_len must be >= 1, got {q_len}")
-    S = ck.shape[2]
+    H, S = ck.shape[1], ck.shape[2]
+    G = Hq // H
+    window = int(attrs.get("window") or 0)
+    if Hq % H or kn.shape[1] != H:
+        raise ValueError(
+            f"fused_decode_attention: {Hq} query heads over caches of {H} "
+            f"heads and new rows of {kn.shape[1]}")
+    if window and (S > window or q_len > 1):
+        raise NotImplementedError(
+            f"fused_decode_attention: a window of {window} over caches of "
+            f"{S} rows in steps of {q_len}: a windowed layer's cache is a "
+            f"ring of at most `window` rows, written one row a step")
     page = int(attrs.get("page_size") or 128)
     scale = attrs["scale"] or float(D) ** -0.5
     pos_b = pos.reshape(B).astype(jnp.int32)
-    ck2 = paged_kv_append_rows(ck, kn, pos_b, smask)
-    cv2 = paged_kv_append_rows(cv, vn, pos_b, smask)
+    ck2 = paged_kv_append_rows(ck, kn, pos_b, smask, ring=bool(window))
+    cv2 = paged_kv_append_rows(cv, vn, pos_b, smask, ring=bool(window))
     lengths = jnp.minimum(pos_b + 1, S)
 
-    q3 = q.reshape(B * H, q_len, D)
+    # the G query heads of one key/value head beside each other, position-
+    # major: row i of a group is head i % G at chunk position i // G
+    q3 = q.reshape(B * H, G, q_len, D).swapaxes(1, 2).reshape(
+        B * H, q_len * G, D)
     k3 = ck2.reshape(B * H, S, D)
     v3 = cv2.reshape(B * H, S, D)
     route = _route_decode(S, page, q_len=q_len,
@@ -123,12 +152,15 @@ def _fused_decode_attention(ctx, ins, attrs):
     note_kernel_route(ctx, "fused_decode_attention", route)
     if route == "primitive":
         o = decode_attention_reference(q3, k3, v3,
-                                       jnp.repeat(lengths, H, axis=0), scale)
+                                       jnp.repeat(lengths, H, axis=0), scale,
+                                       group=G)
     else:
         o = flash_attention_decode(
             q3, k3, v3, lengths, scale=scale, num_heads=H,
-            page_size=page, interpret=(route == "pallas-interpret"))
-    return {"Out": [o.reshape(B, H, q_len, D)],
+            page_size=page, group=G,
+            interpret=(route == "pallas-interpret"))
+    o = o.reshape(B * H, q_len, G, D).swapaxes(1, 2)
+    return {"Out": [o.reshape(B, Hq, q_len, D)],
             "CacheKOut": [ck2], "CacheVOut": [cv2]}
 
 
@@ -136,7 +168,8 @@ def _fused_decode_attention(ctx, ins, attrs):
     "kv_cache_append",
     inputs=[IOSpec("Cache"), IOSpec("New"),
             IOSpec("Positions", no_grad=True),
-            IOSpec("SlotMask", optional=True, no_grad=True)],
+            IOSpec("SlotMask", optional=True, no_grad=True),
+            IOSpec("Slots", optional=True, no_grad=True)],
     outputs=["Out"],
     attrs={},
     grad=None)
@@ -150,11 +183,36 @@ def _kv_cache_append(ctx, ins, attrs):
     continuous-batching refill writes only the slots being prefilled
     while their neighbours keep decoding. Builders point ``Out``
     back at the cache var: the op reads and writes it at one index, so the
-    buffer donates (liveness-proven in-place update)."""
+    buffer donates (liveness-proven in-place update). ``Slots`` [B', 1]
+    (optional): ``New`` carries B' <= B sequences and sequence ``i`` is
+    written into the cache's row ``Slots[i]`` (``Positions`` and
+    ``SlotMask`` are then per sequence of ``New``)."""
     from ..kernels import paged_kv_append
 
     cache, new, pos = x(ins, "Cache"), x(ins, "New"), x(ins, "Positions")
-    return {"Out": [paged_kv_append(cache, new, pos, x(ins, "SlotMask"))]}
+    return {"Out": [paged_kv_append(cache, new, pos, x(ins, "SlotMask"),
+                                    x(ins, "Slots"))]}
+
+
+@register_op(
+    "slot_assign",
+    inputs=[IOSpec("X"), IOSpec("Slots", no_grad=True), IOSpec("Updates"),
+            IOSpec("Mask", optional=True, no_grad=True)],
+    outputs=["Out"],
+    attrs={},
+    grad=None)
+def _slot_assign(ctx, ins, attrs):
+    """Per-slot state written by the rows that serve a slot: ``Out`` is
+    ``X`` [B, ...] with row ``Slots[i]`` replaced by ``Updates[i]`` for
+    every ``i`` whose ``Mask[i]`` > 0 (``Slots``, ``Mask``: [B', 1]; a
+    masked row writes nothing). Builders point ``Out`` back at ``X``'s var,
+    as with ``kv_cache_append``."""
+    xv, slots, upd = x(ins, "X"), x(ins, "Slots"), x(ins, "Updates")
+    mask = x(ins, "Mask")
+    idx = slots.reshape(-1).astype(jnp.int32)
+    if mask is not None:        # a masked row goes out of range and is dropped
+        idx = jnp.where(mask.reshape(-1) > 0, idx, xv.shape[0])
+    return {"Out": [xv.at[idx].set(upd.astype(xv.dtype), mode="drop")]}
 
 
 @register_op(

@@ -1,0 +1,200 @@
+"""Sparse-expert and rotary ops: a routed-expert layer that is told which
+experts it holds, and rotary position embedding.
+
+``moe_experts`` is what expert parallelism asks of one chip: it routes
+every token over ALL ``num_experts`` experts (sigmoid scores, the ``top_k``
+largest, weights normalised over the chosen ones — all in f32) and
+computes the part of ``sum_e w_e E_e(x)`` that the ``experts_held``
+experts from ``expert_offset`` on contribute, each a gated feed-forward
+``(silu(x Wg) * (x Wu)) Wd`` with bf16 operands and f32 accumulation. With
+all experts held it is the whole layer. What the absent experts would add
+is left out, and nothing stands in for the exchange that would fetch it.
+No token is dropped for capacity: the row buffer holds the worst case.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from .common import IOSpec, register_op, x
+from .. import flags
+from ..lowering import lowering_platform, note_kernel_route
+
+# rows of one grouped-matmul tile: 16 (one packed bf16 sublane tile) while
+# a held expert expects a handful of rows, as in a decode step, where the
+# kernel streams weights; 256 once it expects a few hundred, as in
+# prefill, where a tile has to keep the MXU busy for the weights it loads
+_TM_SMALL, _TM_LARGE = 16, 256
+
+
+def _route_moe(T: int, H: int, platform) -> str:
+    mode = flags.flag("use_flash_attention")
+    if mode == "never" or T % 8 or H % 128:
+        return "primitive"
+    if platform == "tpu":
+        return "pallas"
+    return "pallas-interpret" if mode == "always" else "primitive"
+
+
+def route_tokens(scores, top_k: int):
+    """scores [T, E] f32 -> (experts [T, k] int32, weights [T, k] f32):
+    the ``top_k`` largest scores of each token (the lower index first among
+    equal scores) and the scores normalised over the chosen ones."""
+    vals, idx = jax.lax.top_k(scores, top_k)
+    return idx.astype(jnp.int32), vals / jnp.sum(vals, axis=-1,
+                                                 keepdims=True)
+
+
+def _dense_held(xb, wg, wu, wd, combine):
+    """Every held expert over every token, weighted by ``combine`` [T, Eh]
+    (0 where the token did not choose the expert): the primitive route."""
+    def one(args):
+        g, u, d, c = args
+        mm = lambda a, b: jnp.matmul(a, b,
+                                     preferred_element_type=jnp.float32)
+        h = (jax.nn.silu(mm(xb, g)) * mm(xb, u)).astype(xb.dtype)
+        return mm(h, d) * c[:, None]
+
+    return jnp.sum(jax.lax.map(one, (wg, wu, wd, combine.T)), axis=0)
+
+
+def _grouped_held(xb, wg, wu, wd, le, local, weights, counts, num_experts,
+                  interpret):
+    """The Pallas route: local assignments sorted by expert into whole
+    tiles, two grouped matmuls, and each token's rows gathered back."""
+    from ..kernels.moe import grouped_matmul
+
+    T, k = le.shape
+    Eh = wg.shape[0]
+    A = T * k
+    # rows a held expert expects under even routing
+    tm = _TM_LARGE if A // num_experts >= _TM_LARGE else _TM_SMALL
+    M = -(-A // tm) * tm + Eh * tm           # every assignment local
+    flat_e = jnp.where(local, le, Eh).reshape(A)
+    order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)
+    rank_sorted = jnp.zeros((A,), jnp.int32).at[order].set(
+        jnp.arange(A, dtype=jnp.int32))
+    padded = -(-counts // tm) * tm
+    pend = jnp.cumsum(padded)
+    pstart, ustart = pend - padded, jnp.cumsum(counts) - counts
+    tile_expert = jnp.minimum(jnp.searchsorted(
+        pend, jnp.arange(M // tm, dtype=jnp.int32) * tm, side="right"),
+        Eh - 1).astype(jnp.int32)
+    n_valid = (pend[-1] // tm).astype(jnp.int32)
+
+    rows = jnp.arange(M, dtype=jnp.int32)
+    e_r = jnp.repeat(tile_expert, tm)
+    rank = rows - pstart[e_r]
+    valid = (rank < counts[e_r]) & (rows < pend[-1])
+    src = order[jnp.clip(ustart[e_r] + rank, 0, A - 1)]
+    x_rows = jnp.where(valid[:, None], xb[src // k], 0)
+    h = grouped_matmul(x_rows, wg, tile_expert, n_valid, tm=tm, rhs2=wu,
+                       out_dtype=xb.dtype, interpret=interpret)
+    y = grouped_matmul(h, wd, tile_expert, n_valid, tm=tm,
+                       out_dtype=jnp.float32, interpret=interpret)
+
+    # each token gathers the rows of its own local assignments
+    e_a = jnp.minimum(flat_e, Eh - 1)
+    pos = (pstart[e_a] + rank_sorted - ustart[e_a]).reshape(T, k)
+    out = jnp.zeros((T, y.shape[1]), jnp.float32)
+    for j in range(k):
+        row = jnp.where(local[:, j], pos[:, j], 0)
+        out = out + jnp.where(local[:, j, None],
+                              weights[:, j, None] * y[row], 0.0)
+    return out, jnp.sum(local) - jnp.sum(valid)
+
+
+@register_op(
+    "moe_experts",
+    inputs=[IOSpec("X"), IOSpec("RouterW"), IOSpec("GateW"), IOSpec("UpW"),
+            IOSpec("DownW"), IOSpec("TokenMask", optional=True, no_grad=True)],
+    outputs=["Out", "Stats"],
+    attrs={"num_experts": 0, "top_k": 1, "expert_offset": 0},
+    grad=None)
+def _moe_experts(ctx, ins, attrs):
+    """``X`` [..., H] (f32: the router reads it unrounded); ``RouterW``
+    [H, num_experts]; ``GateW``/``UpW`` [experts_held, H, F] and ``DownW``
+    [experts_held, F, H] hold experts ``expert_offset ..
+    expert_offset + experts_held - 1``. ``Out`` [..., H] f32: the held
+    experts' part of the routed sum. ``TokenMask`` (optional, ``X``'s
+    leading shape, > 0 = a real token): padding, and the rows of sequences
+    that a dispatch does not serve, are routed nowhere; they cost the
+    experts nothing and their ``Out`` rows are 0 (identical padding rows
+    would otherwise all land on the same ``top_k`` experts, a load that
+    depends on nothing but the weights). ``Stats`` [experts_held + 2]
+    int32: assignments each held expert received, all assignments made
+    (``real tokens * top_k``), and local assignments that found no row in
+    the buffer (0 by construction; the serving layer counts it)."""
+    from ..kernels.moe import router_scores, router_scores_reference
+
+    xv, wr = x(ins, "X"), x(ins, "RouterW")
+    wg, wu, wd = x(ins, "GateW"), x(ins, "UpW"), x(ins, "DownW")
+    E, k = int(attrs["num_experts"]), int(attrs["top_k"])
+    off, Eh = int(attrs["expert_offset"]), wg.shape[0]
+    if wr.shape[-1] != E or not 0 < k <= E or not 0 <= off <= E - Eh:
+        raise ValueError(
+            f"moe_experts: router {wr.shape} for num_experts={E}, top_k={k}, "
+            f"{Eh} experts held from {off}")
+    lead, H = xv.shape[:-1], xv.shape[-1]
+    x2 = xv.reshape(-1, H)
+    T = x2.shape[0]
+    route = _route_moe(T, H, lowering_platform(ctx))
+    note_kernel_route(ctx, "moe_experts", route)
+    interpret = route == "pallas-interpret"
+    with jax.named_scope("moe_router"):
+        if route == "primitive":
+            scores = router_scores_reference(x2, wr)
+        else:
+            scores = router_scores(x2, wr, interpret=interpret)
+        experts, weights = route_tokens(scores, k)
+    local = (experts >= off) & (experts < off + Eh)
+    made = jnp.int32(T * k)
+    mask = x(ins, "TokenMask")
+    if mask is not None:
+        real = mask.reshape(T) > 0
+        local = local & real[:, None]
+        made = jnp.sum(real).astype(jnp.int32) * k
+    le = experts - off
+    hit = local[:, :, None] & (le[:, :, None] == jnp.arange(Eh))
+    counts = jnp.sum(hit, axis=(0, 1)).astype(jnp.int32)
+    xb = x2.astype(wg.dtype)
+    if route == "primitive":
+        combine = jnp.sum(jnp.where(hit, weights[:, :, None], 0.0), axis=1)
+        out = _dense_held(xb, wg, wu, wd, combine)
+        dropped = jnp.int32(0)
+    else:
+        out, dropped = _grouped_held(xb, wg, wu, wd, le, local, weights,
+                                     counts, E, interpret)
+    stats = jnp.concatenate([counts, jnp.stack([
+        made, dropped.astype(jnp.int32)])])
+    return {"Out": [out.reshape(lead + (H,))], "Stats": [stats]}
+
+
+@register_op(
+    "rotary_embedding",
+    inputs=[IOSpec("X"), IOSpec("Positions", no_grad=True)],
+    outputs=["Out"],
+    attrs={"theta": 10000.0},
+    grad=None)
+def _rotary_embedding(ctx, ins, attrs):
+    """Rotary positions on interleaved pairs (the GPT-J layout): ``X``
+    [B, heads, S, D], ``Positions`` [B, S] int. Pair ``i`` = dims
+    ``(2i, 2i+1)`` turns by ``pos * theta^(-2i/D)``. The pair swap is a
+    product with a constant signed permutation (exact in any float type)
+    and not a strided lane shuffle; angles, sines and the blend are f32."""
+    xv, pos = x(ins, "X"), x(ins, "Positions")
+    B, _, S, D = xv.shape
+    with jax.named_scope("rotary"):
+        inv = float(attrs["theta"]) ** (
+            -jnp.arange(0, D, 2, dtype=jnp.float32) / D)
+        ang = pos.reshape(B, 1, S, 1).astype(jnp.float32) * inv
+        cos = jnp.repeat(jnp.cos(ang), 2, axis=-1)
+        sin = jnp.repeat(jnp.sin(ang), 2, axis=-1)
+        even = jnp.arange(0, D, 2)
+        # (x @ swap)[2i] = -x[2i+1], (x @ swap)[2i+1] = x[2i]
+        swap = jnp.zeros((D, D), xv.dtype).at[even + 1, even].set(-1).at[
+            even, even + 1].set(1)
+        turned = jnp.matmul(xv, swap, preferred_element_type=jnp.float32,
+                            precision=jax.lax.Precision.HIGHEST)
+        out = xv.astype(jnp.float32) * cos + turned * sin
+    return {"Out": [out.astype(xv.dtype)]}

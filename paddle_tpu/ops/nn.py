@@ -29,7 +29,8 @@ def _mul(ctx, ins, attrs):
 
 
 @register_op("matmul", inputs=["X", "Y"], outputs=["Out"],
-             attrs={"transpose_X": False, "transpose_Y": False, "alpha": 1.0})
+             attrs={"transpose_X": False, "transpose_Y": False, "alpha": 1.0,
+                    "out_dtype": ""})
 def _matmul(ctx, ins, attrs):
     xv, yv = x(ins, "X"), x(ins, "Y")
     if attrs["transpose_X"]:
@@ -42,7 +43,13 @@ def _matmul(ctx, ins, attrs):
             pass
         else:
             yv = jnp.swapaxes(yv, -1, -2)
-    res = jnp.matmul(xv, yv)
+    if attrs.get("out_dtype"):      # the accumulator's type, not the operands'
+        from ..core.types import jnp_dtype
+
+        res = jnp.matmul(xv, yv,
+                         preferred_element_type=jnp_dtype(attrs["out_dtype"]))
+    else:
+        res = jnp.matmul(xv, yv)
     if attrs.get("alpha", 1.0) != 1.0:
         res = res * attrs["alpha"]
     return out(res)

@@ -30,7 +30,7 @@ ISSUE 20 adds two composable phases on the same slot/bucket discipline:
   scheduler iteration, interleaved with the resident decode chunks (the
   same path admits prompts longer than the largest bucket). The final
   slice samples the first token in-program and flips the slot's decode
-  gate (``gpt_gen_active``); completed prefills publish their pages.
+  gate (the model's ``active_var``); completed prefills publish their pages.
 * **speculative decoding** (``GenerationConfig.speculative``) — each
   round a host-side draft (prompt-lookup n-gram by default, swappable
   via ``engine.draft_fn``) proposes ``spec_k - 1`` tokens and the target
@@ -59,7 +59,7 @@ from __future__ import annotations
 import dataclasses
 import logging
 import time
-from collections import defaultdict
+from collections import Counter, defaultdict
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
@@ -79,6 +79,10 @@ logger = logging.getLogger("paddle_tpu.serving")
 _LOOP_HELP = ("wall of one phase of the generative dispatch thread's loop "
               "(idle_wait, schedule, admit, feed, settle, publish); with "
               "the executor's dispatches they tile the thread's time")
+
+
+def _var_names(var) -> List[str]:
+    return [] if var is None else [var.name]
 
 
 def _loop_phase(name: str, parent=None):
@@ -169,9 +173,20 @@ class GenerativeEngine(ServingEngine):
         self._prefill_chunk = int(model.get("prefill_chunk") or
                                   self._page_size)
         self._spec_k = int(model.get("spec_k") or 0)
-        self._cache_names = sorted(
-            (n, "gpt_kv_v_" + n[len("gpt_kv_k_"):])
-            for n in model["state_vars"] if n.startswith("gpt_kv_k_"))
+        # the model names its own state: one (K, V) cache pair per layer,
+        # whose row counts and type may differ from layer to layer, and the
+        # per-slot decode gate
+        self._cache_names = [tuple(pair) for pair in model["cache_vars"]]
+        self._active_var = model["active_var"]
+        # rows a layer's cache holds -> how many layers hold that many
+        self._cache_rows = Counter(int(model["state_vars"][nk][0][2])
+                                   for nk, _ in self._cache_names)
+        # a model with routed experts hands back, per dispatch, the
+        # assignments each held expert received (``layers.moe_experts``)
+        self._stats_fetch = {
+            "decode": _var_names(decode.get("expert_stats")),
+            **{("prefill", b): _var_names(net.get("expert_stats"))
+               for b, net in model["prefill"].items()}}
         gc = self.gen_config
         self._prefix_cache = None
         if gc.prefix_cache and self._chunk is not None:
@@ -185,6 +200,7 @@ class GenerativeEngine(ServingEngine):
         # Default: prompt-lookup n-gram (see _ngram_draft). Swappable for
         # tests and for a real draft model.
         self.draft_fn = None
+        self._moe_local = self._moe_made = 0
         self.prefill_chunks = 0    # chunk slices dispatched (per request)
         self.spec_chunks = 0       # verify dispatches
         self.spec_accepted = 0     # draft tokens accepted in total
@@ -194,8 +210,22 @@ class GenerativeEngine(ServingEngine):
         """Plant zeroed generation state (tokens, positions, KV pages) in
         the scope. Called at warm-up/start and after a real mid-dispatch
         failure (consumed donated buffers are never reused)."""
+        kinds = self._model.get("cache_kinds", {})
+        caches = {n for pair in self._cache_names for n in pair}
+        held = defaultdict(int)
         for name, (shape, dt) in self._model["state_vars"].items():
-            self._scope.set_var(name, np.zeros(shape, np_dtype(dt)))
+            zeros = np.zeros(shape, np_dtype(dt))
+            self._scope.set_var(name, zeros)
+            if name in caches:
+                held[kinds.get(name, "full")] += zeros.nbytes
+        if _monitor.enabled():
+            for kind, nbytes in held.items():
+                _monitor.gauge(
+                    "serving_kv_cache_bytes",
+                    "bytes of KV cache the engine planted, by the kind of "
+                    "layer that owns them (window: a ring of the last "
+                    "positions; full: every position)"
+                ).labels(kind=kind).set(float(nbytes))
 
     def _ensure_state(self) -> None:
         for name in self._model["state_vars"]:
@@ -233,12 +263,12 @@ class GenerativeEngine(ServingEngine):
             net = self._model["prefill"][bucket]
             feed = self._prefill_feed(bucket, [])
             self._exe.run(net["main"], feed=feed,
-                          fetch_list=[net["first_token"].name],
+                          fetch_list=self._prefill_fetches(bucket),
                           scope=self._scope)
             self._note_compiles("prefill", bucket, net["main"])
             compiled += 1
         self._exe.run_chained(self._program, feed={},
-                              fetch_list=self._fetch_names,
+                              fetch_list=self._decode_fetches(),
                               steps=self.gen_config.decode_chunk,
                               scope=self._scope)
         self._note_compiles("decode", len(self._slots), self._program)
@@ -524,12 +554,12 @@ class GenerativeEngine(ServingEngine):
         """Host-side decode-gate clear on retire: the slot's ``active``
         flag goes 0 so later decode/verify dispatches leave its state and
         cache rows untouched until the next admission re-arms it."""
-        cur = self._scope.find_var("gpt_gen_active")
+        cur = self._scope.find_var(self._active_var)
         if cur is None:
             return
         arr = np.array(cur)
         arr[slot, 0] = 0.0
-        self._scope.set_var("gpt_gen_active", arr)
+        self._scope.set_var(self._active_var, arr)
 
     # -- chunked prefill -------------------------------------------------
     def _chunk_feed(self, pending: Sequence[_GenRequest]) -> dict:
@@ -744,9 +774,16 @@ class GenerativeEngine(ServingEngine):
         return True
 
     # -- prefill ---------------------------------------------------------
+    def _prefill_rows(self, bucket: int) -> Optional[int]:
+        """Sequences one dispatch of this bucket's program carries, where
+        its rows name their slots (``slot_ids``); None where row ``i`` IS
+        slot ``i`` and every dispatch carries the whole slot batch."""
+        return self._model["prefill"][bucket].get("rows")
+
     def _prefill_feed(self, bucket: int,
                       reqs: Sequence[_GenRequest]) -> dict:
-        B = len(self._slots)
+        rows = self._prefill_rows(bucket)
+        B = rows or len(self._slots)
         feed = {
             "prompt_ids": np.zeros((B, bucket), np.int64),
             "prompt_pos": np.tile(np.arange(bucket, dtype=np.int64),
@@ -755,20 +792,33 @@ class GenerativeEngine(ServingEngine):
             "prompt_len": np.ones((B, 1), np.int64),
             "slot_mask": np.zeros((B, 1), np.float32),
         }
-        for r in reqs:
+        if rows:
+            feed["slot_ids"] = np.zeros((B, 1), np.int64)
+        for i, r in enumerate(reqs):
+            row = i if rows else r.slot
             L = len(r.prompt)
-            feed["prompt_ids"][r.slot, :L] = r.prompt
-            feed["prompt_mask"][r.slot, :L] = 1.0
-            feed["prompt_len"][r.slot, 0] = L
-            feed["slot_mask"][r.slot, 0] = 1.0
+            feed["prompt_ids"][row, :L] = r.prompt
+            feed["prompt_mask"][row, :L] = 1.0
+            feed["prompt_len"][row, 0] = L
+            feed["slot_mask"][row, 0] = 1.0
+            if rows:
+                feed["slot_ids"][row, 0] = r.slot
         return feed
 
     def _run_prefill(self, newcomers: List[_GenRequest]) -> None:
         by_bucket = defaultdict(list)
         for r in newcomers:
             by_bucket[r.bucket].append(r)
+        # one dispatch per bucket, or per `rows` requests of it where the
+        # bucket's program carries that many sequences a dispatch
+        groups = []
         for bucket in sorted(by_bucket):
             reqs = by_bucket[bucket]
+            n = self._prefill_rows(bucket) or len(reqs)
+            groups += [(bucket, reqs[i:i + n])
+                       for i in range(0, len(reqs), n)]
+        for bucket, reqs in groups:
+            by_row = self._prefill_rows(bucket) is not None
             net = self._model["prefill"][bucket]
             span = _trace.NOOP_SPAN
             if _trace.enabled():
@@ -785,9 +835,10 @@ class GenerativeEngine(ServingEngine):
                     feed = self._prefill_feed(bucket, reqs)
                 t0 = time.perf_counter()
                 with _trace.attach(span):
-                    outs = self._exe.run(net["main"], feed=feed,
-                                         fetch_list=[net["first_token"].name],
-                                         scope=self._scope)
+                    outs = self._exe.run(
+                        net["main"], feed=feed,
+                        fetch_list=self._prefill_fetches(bucket),
+                        scope=self._scope)
                 dt = time.perf_counter() - t0
             except _faults.InjectedFault as e:
                 # fired before any dispatch: state intact, only this
@@ -805,14 +856,15 @@ class GenerativeEngine(ServingEngine):
             self._publish(reqs)
             with _loop_phase("settle") as ph:
                 self._note_compiles("prefill", bucket, net["main"])
+                self._observe_expert_stats("prefill", outs[1:])
                 if _monitor.enabled():
                     _monitor.histogram(
                         "serving_prefill_seconds",
                         "wall time of one slot-masked prefill dispatch"
                     ).observe(dt)
-                first = np.asarray(outs[0]).reshape(len(self._slots))
+                first = np.asarray(outs[0]).reshape(-1)
                 tokens = 0
-                for r in reqs:
+                for i, r in enumerate(reqs):
                     r.prefilled = True
                     r.next_off = len(r.prompt)
                     if self._expired(r):
@@ -825,14 +877,14 @@ class GenerativeEngine(ServingEngine):
                     # the first token's cost is the FIRST-TOKEN histogram's
                     # story — it must not pollute the inter-token latency
                     tokens += 1
-                    self._emit(r, [int(first[r.slot])], dt,
+                    self._emit(r, [int(first[i if by_row else r.slot])], dt,
                                record_intertoken=False)
                 self._settled(ph, reqs, tokens)
 
     # -- decode ----------------------------------------------------------
     def _run_decode_chunk(self) -> None:
         # only decode-eligible residents: slots mid-chunked-prefill keep
-        # their in-program decode gate (``gpt_gen_active``) at 0, so the
+        # their in-program decode gate (the model's ``active_var``) at 0, so the
         # dispatch leaves their state and cache rows bit-untouched
         active = [r for r in self._slots if r is not None and r.prefilled]
         steps = self.gen_config.decode_chunk
@@ -846,7 +898,8 @@ class GenerativeEngine(ServingEngine):
             t0 = time.perf_counter()
             with _trace.attach(span):
                 outs = self._exe.run_chained(
-                    self._program, feed={}, fetch_list=self._fetch_names,
+                    self._program, feed={},
+                    fetch_list=self._decode_fetches(),
                     steps=steps, scope=self._scope)
             dt = time.perf_counter() - t0
         except _faults.InjectedFault as e:
@@ -863,6 +916,7 @@ class GenerativeEngine(ServingEngine):
         span.end()
         with _loop_phase("settle") as ph:
             self._note_compiles("decode", len(self._slots), self._program)
+            self._observe_expert_stats("decode", outs[1:])
             toks = np.asarray(outs[0]).reshape(steps, len(self._slots))
             per_tok = dt / steps
             if _monitor.enabled():
@@ -1041,16 +1095,76 @@ class GenerativeEngine(ServingEngine):
     def _gauge_kv_occupancy(self) -> None:
         if not _monitor.enabled():
             return
-        pages = self._max_seq // self._page_size
+        # a layer's cache holds min(length, its rows) rows of a sequence
+        # (a windowed layer keeps the last ones only)
+        P = self._page_size
         used = 0
         for r in self._slots:
             if r is not None:
-                length = min(len(r.prompt) + r.emitted, self._max_seq)
-                used += -(-length // self._page_size)   # ceil
+                length = len(r.prompt) + r.emitted
+                used += sum(n * -(-min(length, rows) // P)   # ceil
+                            for rows, n in self._cache_rows.items())
+        pages = sum(n * (rows // P) for rows, n in self._cache_rows.items())
         _monitor.gauge(
             "serving_kv_page_occupancy",
             "fraction of KV cache pages held by resident sequences"
         ).set(used / (pages * len(self._slots)))
+
+    # -- routed experts ----------------------------------------------------
+    def _prefill_fetches(self, bucket: int) -> List[str]:
+        net = self._model["prefill"][bucket]
+        return [net["first_token"].name] + self._stats_fetch[
+            "prefill", bucket]
+
+    def _decode_fetches(self) -> List[str]:
+        return self._fetch_names + self._stats_fetch["decode"]
+
+    def _observe_expert_stats(self, phase: str, fetched) -> None:
+        """What a dispatch's expert ops counted (``layers.moe_experts``
+        ``Stats``, [..., layers, experts_held + 2]; a chained decode stacks
+        its steps in front): per layer and execution the assignments each
+        held expert received, all assignments made, and local assignments
+        that found no row."""
+        if not fetched or not _monitor.enabled():
+            return
+        stats = np.asarray(fetched[0])
+        stats = stats.reshape((-1,) + stats.shape[-2:]).astype(np.int64)
+        load, made, dropped = stats[..., :-2], stats[..., -2], stats[..., -1]
+        tokens = _monitor.counter(
+            "moe_expert_tokens_total",
+            "token assignments the held experts received, by layer and "
+            "phase of the dispatch")
+        hit = _monitor.counter(
+            "moe_experts_hit_total",
+            "held experts that received at least one token, summed over "
+            "the expert op's executions")
+        calls = _monitor.counter(
+            "moe_expert_calls_total", "executions of the expert op")
+        for layer in range(stats.shape[1]):
+            lab = dict(layer=str(layer), phase=phase)
+            tokens.labels(**lab).inc(float(load[:, layer].sum()))
+            hit.labels(**lab).inc(float((load[:, layer] > 0).sum()))
+            calls.labels(**lab).inc(float(stats.shape[0]))
+        mean = load.mean(axis=-1)
+        skew = _monitor.histogram(
+            "moe_expert_load_max_over_mean",
+            "per execution of the expert op, the busiest held expert's "
+            "assignments over the mean of the held experts' (1 = even)")
+        for v in (load.max(axis=-1)[mean > 0] / mean[mean > 0]).ravel():
+            skew.observe(float(v))
+        self._moe_local += int(load.sum())
+        self._moe_made += int(made.sum())
+        _monitor.gauge(
+            "moe_local_assignment_share",
+            "share of all token-to-expert assignments that fell on experts "
+            "held here, since the engine was built (experts_held / "
+            "num_experts under even routing)"
+        ).set(self._moe_local / max(self._moe_made, 1))
+        _monitor.counter(
+            "moe_dropped_assignments_total",
+            "local assignments the expert op found no buffer row for; the "
+            "buffer holds the worst case, so anything but 0 is a bug"
+        ).inc(float(dropped.sum()))
 
     def generation_stats(self) -> dict:
         """Decode-side snapshot for reports: resident slots, compiled

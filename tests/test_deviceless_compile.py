@@ -9,6 +9,8 @@ for real. So "the kernels compile" stays true in every PR at no chip time.
 Compiling says nothing about results, run-time memory or speed: those are
 ``chip_smoke.py``'s and the benchmark's to find on the chip.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -180,3 +182,49 @@ def test_masked_append_keeps_the_scan_carry_in_place(v5e, form):
     assert "tpu_custom_call" in text
     found = _whole_cache_work_in_loops(text, (B, H, S, D))
     assert (found == []) if form == "rows" else len(found) >= 2
+
+
+# -- the sparse-expert decoder's kernels at its published widths (PR 27) ------
+
+@pytest.mark.parametrize("rows,tm", [(768, 16), (16640, 16), (69632, 256)])
+def test_grouped_expert_matmul_compiles_at_the_published_widths(v5e, rows,
+                                                                tm):
+    """16 stacked experts of 4096 x 4096 in bf16; the row buffer of a
+    decode step of 64 tokens, of a prefill of 16 x 128, and of 64 x 128 in
+    tiles of 256: the gated gate-and-up call, then down, under one name."""
+    from paddle_tpu.kernels.moe import grouped_matmul
+
+    def ffn(x, wg, wu, wd, tile_expert, n_valid):
+        h = grouped_matmul(x, wg, tile_expert, n_valid, tm=tm, rhs2=wu,
+                           out_dtype=jnp.bfloat16)
+        return grouped_matmul(h, wd, tile_expert, n_valid, tm=tm)
+
+    w = v5e((16, 4096, 4096), jnp.bfloat16)
+    text = _compiles_with_mosaic(
+        ffn, v5e((rows, 4096), jnp.bfloat16), w, w, w,
+        v5e((rows // tm,), jnp.int32), v5e((), jnp.int32))
+    assert len(re.findall(r"%moe_expert_matmul[.\d]* = ", text)) == 2
+
+
+def test_router_and_grouped_query_attention_compile_in_bf16(v5e):
+    """The f32 router over 128 experts; one decode step of 64 slots x 8
+    key/value heads with the 16 query heads of a group in the sublane rows
+    of one call, against bf16 caches of 1024 rows; the flash forward with
+    128 query heads over 8 key/value heads and a window."""
+    from paddle_tpu.kernels.moe import router_scores
+
+    text = _compiles_with_mosaic(
+        router_scores, v5e((8192, 4096), jnp.float32),
+        v5e((4096, 128), jnp.float32))
+    assert re.search(r"%moe_router[.\d]* = ", text)
+    cache = v5e((512, 1024, 128), jnp.bfloat16)
+    _compiles_with_mosaic(
+        lambda q, k, v, n: flash_attention_decode(
+            q, k, v, n, num_heads=8, page_size=128, group=16),
+        v5e((512, 16, 128), jnp.bfloat16), cache, cache, v5e((64,), jnp.int32))
+    kv = v5e((16 * 8, 256, 128), jnp.bfloat16)
+    _compiles_with_mosaic(
+        lambda q, k, v, b: flash_attention(q, k, v, bias=b, causal=True,
+                                           num_heads=128, window=192),
+        v5e((16 * 128, 256, 128), jnp.bfloat16), kv, kv,
+        v5e((16, 256), jnp.float32))
