@@ -7,14 +7,22 @@ the chunked-prefill slice and the speculative-verify chunk (ISSUE 20),
 where query row ``i`` is the token at cache position ``length - 1 + i`` and
 may see exactly ``length + i`` keys (causal *within* the chunk, since the
 chunk's own K rows are appended before the walk). The cache is *paged* —
-logically ``[BH, S_max, D]`` where ``S_max = num_pages * page_size`` and
-the kernel walks it one page (``block_k = page_size``) at a time with the
-same online-softmax recurrence as the prefill kernel, masking key positions
-``>= length + row`` per sequence and query row.
+logically ``[BH, S_max, D]`` where ``S_max = num_pages * page_size`` — and
+``page_size`` is the CACHE's page: the unit of the prefix cache, of
+``S_max``'s divisibility, of ``classify_shapes``. What a grid step carries
+is the kernel's own (:func:`kv_tile`): whole pages of as many of a
+sequence's heads as make the step worth its fixed cost, since a sequence's
+heads share its length. The kernel walks a sequence's cache k-block by
+k-block with the same online-softmax recurrence as the prefill kernel, up
+to the sequence's LAST LIVE block: past it the K and V index maps repeat
+that block's index, for which the pipeline issues no DMA, and the body is
+skipped. Inside the last live block key positions ``>= length + row`` are
+masked per sequence and query row.
 Pages past a sequence's length hold stale/garbage rows by design (they are
-overwritten when the sequence reaches them); the length mask keeps them out
-of the softmax, so cache capacity can be provisioned once and reused across
-requests at different positions.
+overwritten when the sequence reaches them): they are never fetched, and
+the length mask keeps the tail of the last live block out of the softmax,
+so cache capacity can be provisioned once and reused across requests at
+different positions at the cost of the keys they hold.
 
 CODA (PAPERS.md, arXiv 2605.19269) motivates folding the decode-step
 epilogue work into one op instead of separate ones. The fold is made one
@@ -37,9 +45,10 @@ Design notes
   rows: rows ``q_len..7`` are padding (replicas of the last real row) whose
   output is discarded, so the q_len=1 decode step and the q_len<=8 chunk
   use one kernel with a per-row length mask ``k_pos < length + row``.
-- per-sequence lengths arrive as scalar-prefetch values so the kernel's
-  mask needs no extra VMEM traffic; ``lengths[bh // num_heads]`` maps the
-  fused B*H grid axis back to its batch row.
+- per-sequence lengths arrive as scalar-prefetch values: the index maps
+  read them to end a sequence's walk (:func:`last_live_block`) and the
+  kernel's mask needs no extra VMEM traffic. The grid is ``(sequence,
+  group of heads, k-block)``.
 - inference-only: no custom VJP (decode never differentiates).
 - interpret=True runs the same kernel on CPU for tests/CI parity.
 """
@@ -49,6 +58,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -56,7 +66,7 @@ from .flash_attention import _PALLAS_SCOPE, NEG_INF, _out_sds
 
 __all__ = ["flash_attention_decode", "paged_kv_append",
            "paged_kv_append_rows", "decode_attention_reference",
-           "KERNEL_ROWS"]
+           "decode_walk_blocks", "KERNEL_ROWS"]
 
 # query rows one kernel call serves: the chunk rides ONE f32 sublane tile
 KERNEL_ROWS = 8
@@ -196,11 +206,73 @@ def decode_attention_reference(q, k_cache, v_cache, lengths, scale,
     return o.astype(q.dtype)
 
 
-def _decode_kernel(scale, num_heads, group, scal_ref, q_ref, k_ref, v_ref,
+# K and V bytes ONE grid step carries, at most (the pipeline holds twice
+# that in VMEM, 3 of the 16 MiB scoped to a kernel on a v5e). A step costs
+# 0.2-0.5 us whatever it moves, a third of a microsecond's worth of HBM is
+# 290 KB, so a step of 1-1.5 MB is bound by its bytes (750 GB/s on a full
+# cache) and more rows than that only fetch more rows past the length:
+# tools/probe_decode_walk.py, PERF.md section 6, PR 28
+_STEP_BYTES = 3 << 19
+
+
+def kv_tile(num_heads: int, s_max: int, head_dim: int, dtype,
+            page_size: int):
+    """``(heads, rows)`` of a cache that ONE grid step of the decode kernel
+    carries, from what the kernel sees when it is traced. ``page_size`` is
+    the cache's page, the unit of everything outside this module; the tile
+    is the kernel's own.
+
+    The heads of a sequence share its length, so heads come first: a page
+    of as many of them as ``_STEP_BYTES`` holds (a divisor of
+    ``num_heads``) makes a step fuller at no row fetched past the length.
+    Then rows: the largest whole number of pages that divides ``s_max``
+    and still fits, since each further page is fetched whole where the
+    length ends inside it."""
+    page = min(page_size, s_max)
+    # K and V of one row of one head in VMEM: the head dimension padded to
+    # whole 128-lane vregs
+    row_bytes = 2 * -(-head_dim // 128) * 128 * jnp.dtype(dtype).itemsize
+    heads = max(h for h in range(1, num_heads + 1) if num_heads % h == 0
+                and (h == 1 or h * page * row_bytes <= _STEP_BYTES))
+    pages = s_max // page
+    rows = page * max(m for m in range(1, pages + 1) if pages % m == 0
+                      and (m == 1
+                           or m * heads * page * row_bytes <= _STEP_BYTES))
+    return heads, rows
+
+
+def last_live_block(lengths, q_len: int, block_k: int, num_k: int):
+    """Index of the last k-block of ``block_k`` rows that any query row of
+    a chunk sees: the chunk's last row, ``q_len - 1``, sees ``lengths +
+    q_len - 1`` keys. Block 0 for an empty sequence; never past the cache
+    (a chunk whose tail crosses its end). Works on a traced scalar (the
+    kernel's index maps and its body) and on a numpy array (the host's
+    count), which is what keeps the two from drifting."""
+    last = (lengths + q_len - 2) // block_k
+    return (jnp if isinstance(last, jax.Array) else np).clip(
+        last, 0, num_k - 1)
+
+
+def decode_walk_blocks(lengths, cache_shape, dtype, page_size: int,
+                       q_len: int = 1):
+    """``(fetched, capacity)``: the k-blocks one call of the kernel fetches
+    for sequences of ``lengths`` (host integers, visible keys of query row
+    0) out of those their caches ``[B, H, S_max, D]`` hold. Pure host
+    arithmetic on the kernel's own tile and walk."""
+    _, H, S, D = cache_shape
+    _, rows = kv_tile(H, S, D, dtype, page_size)
+    num_k = S // rows
+    live = last_live_block(np.asarray(lengths, np.int64), q_len, rows,
+                           num_k) + 1
+    return int(live.sum()), int(live.size * num_k)
+
+
+def _decode_kernel(scale, group, q_len, len_ref, q_ref, k_ref, v_ref,
                    o_ref, m_scr, l_scr, acc):
-    bh, ik = pl.program_id(0), pl.program_id(1)
-    num_k = pl.num_programs(1)
-    block_k = k_ref.shape[1]
+    b, ik = pl.program_id(0), pl.program_id(2)
+    num_k = pl.num_programs(2)
+    block_k = k_ref.shape[2]
+    length = len_ref[b]
 
     @pl.when(ik == 0)
     def _init():
@@ -208,38 +280,90 @@ def _decode_kernel(scale, num_heads, group, scal_ref, q_ref, k_ref, v_ref,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc[:] = jnp.zeros_like(acc)
 
-    length = scal_ref[bh // num_heads]
-    q = q_ref[0]                                    # [8, D] (chunk rows)
-    k = k_ref[0]                                    # [block_k, D]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    k_pos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    # per-row causal length: query row i (the token at cache position
-    # length - 1 + i) sees length + i keys; padding rows past the real
-    # chunk see more keys, but their output is sliced away by the caller
-    row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    if group > 1:       # grouped-query heads: `group` query heads a position
-        row = row // group
-    s = jnp.where(k_pos < length + row, s, NEG_INF)
+    # a block past the last live one was not fetched (its index map
+    # repeats the last live block) and is not scored
+    @pl.when(ik <= last_live_block(length, q_len, block_k, num_k))
+    def _walk():
+        q = q_ref[...]                              # [heads, R, D]
+        k = k_ref[0]                                # [heads, block_k, D]
+        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+                                preferred_element_type=jnp.float32) * scale
+        k_pos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                                        2)
+        # per-row causal length: query row i (the token at cache position
+        # length - 1 + i) sees length + i keys; padding rows past the real
+        # chunk see more keys, but their output is sliced away by the
+        # caller
+        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        if group > 1:   # grouped-query heads: `group` query heads a position
+            row = row // group
+        s = jnp.where(k_pos < length + row, s, NEG_INF)
 
-    m_prev = m_scr[:, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    alive = m_new > NEG_INF * 0.5
-    m_safe = jnp.where(alive, m_new, 0.0)
-    corr = jnp.exp(m_prev - m_safe)
-    p = jnp.exp(s - m_safe)
-    l_new = corr * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True)
-    pv = jax.lax.dot_general(p.astype(v_ref.dtype), v_ref[0],
-                             (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    acc[:] = acc[:] * corr + pv
-    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-    l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        m_prev = m_scr[:, :, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        alive = m_new > NEG_INF * 0.5
+        m_safe = jnp.where(alive, m_new, 0.0)
+        corr = jnp.exp(m_prev - m_safe)
+        p = jnp.exp(s - m_safe)
+        l_new = corr * l_scr[:, :, :1] + jnp.sum(p, axis=2, keepdims=True)
+        pv = jax.lax.dot_general(p.astype(v_ref.dtype), v_ref[0],
+                                 (((2,), (1,)), ((0,), (0,))),
+                                 preferred_element_type=jnp.float32)
+        acc[:] = acc[:] * corr + pv
+        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
     @pl.when(ik == num_k - 1)
     def _finish():
-        l = l_scr[:, :1]
-        o_ref[0] = (acc[:] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+        l = l_scr[:, :, :1]
+        o_ref[...] = (acc[:] / jnp.where(l == 0.0, 1.0, l)).astype(
+            o_ref.dtype)
+
+
+def _kv_index_map(q_len: int, block_k: int, num_k: int):
+    """Index map of the K and V tiles: k-block ``ik`` while it is live,
+    the last live one after it. The pipeline issues no DMA for a block
+    index that repeats (``kernels/moe.py`` ``frozen``), so nothing past a
+    sequence's length is fetched."""
+    def index(b, hg, ik, lens):
+        return (b, hg, jnp.minimum(
+            ik, last_live_block(lens[b], q_len, block_k, num_k)), 0)
+    return index
+
+
+def _decode_call(q, k_cache, v_cache, lengths, tile, *, scale, group, q_len,
+                 interpret):
+    """The Pallas call on ``q`` [B * H, R, D] and caches [B, H, S_max, D]
+    with ``tile = (heads, rows)`` of a cache a grid step
+    (:func:`kv_tile`'s choice; ``tools/probe_decode_walk.py`` sweeps it)."""
+    B, H = k_cache.shape[:2]
+    _, R, D = q.shape
+    hb, bk = tile
+    nk = k_cache.shape[2] // bk
+    q_spec = pl.BlockSpec((hb, R, D),
+                          lambda b, hg, ik, s: (b * (H // hb) + hg, 0, 0))
+    kv_spec = pl.BlockSpec((1, hb, bk, D), _kv_index_map(q_len, bk, nk))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(B, H // hb, nk),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=[q_spec],
+        scratch_shapes=[
+            pltpu.VMEM((hb, R, 128), jnp.float32),     # running max
+            pltpu.VMEM((hb, R, 128), jnp.float32),     # running denom
+            pltpu.VMEM((hb, R, D), jnp.float32),       # numerator acc
+        ],
+    )
+    (o,) = pl.pallas_call(
+        functools.partial(_decode_kernel, scale, int(group), int(q_len)),
+        grid_spec=grid_spec,
+        out_shape=[_out_sds((B * H, R, D), q.dtype, q, k_cache, v_cache)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="decode_attention",
+    )(lengths, q, k_cache, v_cache)
+    return o
 
 
 @jax.named_scope(_PALLAS_SCOPE)
@@ -256,9 +380,11 @@ def flash_attention_decode(q, k_cache, v_cache, lengths, *,
     cache before the walk). Sq == 1 is the classic decode step; Sq > 1 is
     the chunked-prefill / speculative-verify shape riding the same 8-row
     sublane tile (rows past Sq are padding, sliced off the output).
-    ``page_size`` is the kernel's k-block — the cache page granularity;
-    ``S_max`` must divide into whole pages
-    (``flash_attention.classify_shapes`` refuses otherwise). Returns
+    ``page_size`` is the CACHE's page — the unit of the prefix cache and of
+    ``S_max`` (``flash_attention.classify_shapes`` refuses a cache that is
+    not whole pages). What a grid step carries is the kernel's own choice
+    (:func:`kv_tile`): whole pages of several of a sequence's heads, up to
+    the sequence's last live block and nothing past it. Returns
     o [BH, Sq, D]. Inference-only (no VJP).
 
     ``group`` > 1 is grouped-query attention: BH counts KEY/VALUE heads
@@ -275,16 +401,16 @@ def flash_attention_decode(q, k_cache, v_cache, lengths, *,
             f"path (one sublane tile), got q_len={Sq // group} "
             f"(x {group} grouped heads); use flash_attention "
             f"for prefill/full-sequence shapes")
-    bk = min(page_size, Sk)
-    if Sk % bk:
+    if Sk % min(page_size, Sk):
         raise ValueError(
             f"decode cache length S_max={Sk} must divide into whole pages "
-            f"of page_size={bk}")
+            f"of page_size={page_size}")
     scale = float(scale if scale is not None else D ** -0.5)
     lengths = jnp.asarray(lengths).reshape(-1).astype(jnp.int32)
-    if lengths.shape[0] * num_heads != BH:
+    B = lengths.shape[0]
+    if B * num_heads != BH:
         raise ValueError(
-            f"lengths has {lengths.shape[0]} rows but q has BH={BH} with "
+            f"lengths has {B} rows but q has BH={BH} with "
             f"num_heads={num_heads} (expected {BH // num_heads})")
     # pad the chunk to whole sublane tiles: [BH, Sq, D] -> [BH, R, D]
     # (replicas of the last real row; their output is sliced away). A
@@ -296,32 +422,11 @@ def flash_attention_decode(q, k_cache, v_cache, lengths, *,
     else:
         q8 = jnp.concatenate(
             [q, jnp.broadcast_to(q[:, -1:, :], (BH, R - Sq, D))], axis=1)
-    nk = Sk // bk
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(BH, nk),
-        in_specs=[
-            pl.BlockSpec((1, R, D), lambda bh, ik, s: (bh, 0, 0)),
-            pl.BlockSpec((1, bk, D), lambda bh, ik, s: (bh, ik, 0)),
-            pl.BlockSpec((1, bk, D), lambda bh, ik, s: (bh, ik, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, R, D), lambda bh, ik, s: (bh, 0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((R, 128), jnp.float32),     # running max
-            pltpu.VMEM((R, 128), jnp.float32),     # running denom
-            pltpu.VMEM((R, D), jnp.float32),       # numerator acc
-        ],
-    )
-    (o8,) = pl.pallas_call(
-        functools.partial(_decode_kernel, scale, int(num_heads),
-                          int(group)),
-        grid_spec=grid_spec,
-        out_shape=[_out_sds((BH, R, D), q.dtype, q, k_cache, v_cache)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-        name="decode_attention",
-    )(lengths, q8, k_cache, v_cache)
+    # a sequence's heads beside each other: they share its length, so one
+    # grid step can carry a page of each
+    o8 = _decode_call(
+        q8, k_cache.reshape(B, num_heads, Sk, D),
+        v_cache.reshape(B, num_heads, Sk, D), lengths,
+        kv_tile(num_heads, Sk, D, k_cache.dtype, page_size),
+        scale=scale, group=group, q_len=Sq // group, interpret=interpret)
     return o8[:, :Sq, :]

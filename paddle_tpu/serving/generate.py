@@ -178,9 +178,14 @@ class GenerativeEngine(ServingEngine):
         # per-slot decode gate
         self._cache_names = [tuple(pair) for pair in model["cache_vars"]]
         self._active_var = model["active_var"]
+        # (shape, dtype) of a layer's K cache -> how many layers hold such
+        self._cache_shapes = Counter(
+            (tuple(model["state_vars"][nk][0]), model["state_vars"][nk][1])
+            for nk, _ in self._cache_names)
         # rows a layer's cache holds -> how many layers hold that many
-        self._cache_rows = Counter(int(model["state_vars"][nk][0][2])
-                                   for nk, _ in self._cache_names)
+        self._cache_rows = Counter()
+        for (shape, _), n in self._cache_shapes.items():
+            self._cache_rows[int(shape[2])] += n
         # a model with routed experts hands back, per dispatch, the
         # assignments each held expert received (``layers.moe_experts``)
         self._stats_fetch = {
@@ -917,6 +922,7 @@ class GenerativeEngine(ServingEngine):
         with _loop_phase("settle") as ph:
             self._note_compiles("decode", len(self._slots), self._program)
             self._observe_expert_stats("decode", outs[1:])
+            self._observe_walk(active, steps)
             toks = np.asarray(outs[0]).reshape(steps, len(self._slots))
             per_tok = dt / steps
             if _monitor.enabled():
@@ -934,6 +940,31 @@ class GenerativeEngine(ServingEngine):
                 tokens += len(take)
                 self._emit(r, [int(t) for t in take], per_tok * len(take))
             self._settled(ph, active, tokens)
+
+    def _observe_walk(self, active: Sequence[_GenRequest],
+                      steps: int) -> None:
+        """How much of the residents' caches this decode dispatch's
+        attention kernels walk: k-blocks fetched over k-blocks held, from
+        the lengths this thread holds and the kernel module's own count
+        (on the CPU too, where the primitive route scores every row)."""
+        if not active or not _monitor.enabled():
+            return
+        from ..kernels import decode_walk_blocks
+
+        # step s of the chunk sees the keys so far and its own
+        lengths = (np.array([len(r.prompt) + r.emitted for r in active])
+                   + np.arange(steps)[:, None])
+        fetched = held = 0
+        for (shape, dt), n in self._cache_shapes.items():
+            f, h = decode_walk_blocks(np.minimum(lengths, shape[2]), shape,
+                                      np_dtype(dt), self._page_size)
+            fetched, held = fetched + n * f, held + n * h
+        _monitor.histogram(
+            "decode_attention_walk_share",
+            "per decode dispatch: k-blocks the decode attention kernel "
+            "fetches over k-blocks the resident sequences' caches hold "
+            "(kernels.decode_walk_blocks on the host's lengths)"
+        ).observe(fetched / held)
 
     # -- shared settle paths ---------------------------------------------
     def _expired(self, r: _GenRequest) -> bool:

@@ -1,0 +1,155 @@
+"""The decode kernel walks the keys a sequence HAS (PR 28).
+
+``flash_attention_decode`` carries a tile of its own choosing a grid step
+(``kernels.decode_attention.kv_tile``: a page of several of a sequence's heads, or a few
+pages), fetches k-blocks up to each sequence's last live one and scores
+nothing past it. Proven here on the CPU, ``interpret=True``, at the two
+serving cells' head shapes: blocks wholly past a sequence's length hold NaN
+and the tail of its last live block holds 1e30, so an output that is finite
+and equal to the reference's (computed on the clean caches) was made
+without a dead block ever being scored.
+"""
+import types
+import zlib
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as fluid
+import paddle_tpu.unique_name as un
+from paddle_tpu import monitor, serving
+from paddle_tpu.kernels import (decode_attention_reference,
+                                decode_walk_blocks, flash_attention_decode)
+from paddle_tpu.kernels.decode_attention import (_kv_index_map, kv_tile,
+                                                 last_live_block)
+from paddle_tpu.models.gpt import GptConfig, build_gpt_generative
+
+PAGE = 128
+# name: key/value heads, cache rows, head dim, dtype, query heads a group
+SHAPES = {
+    "f32-d64": (12, 512, 64, jnp.float32, 1),          # GPT-2's, R 8
+    "bf16-d128-g16": (8, 1024, 128, jnp.bfloat16, 16),  # Command A+'s
+}
+# name: (lengths in units of (k-blocks, rows): n = blocks * block + rows,
+#        q_len)
+WALKS = {
+    "empty-and-one": ([(0, 0), (0, 1)], 1),
+    "around-a-block": ([(1, -1), (1, 0), (1, 1)], 1),
+    "ragged": ([(0, 1), (2, 7), (4, 0), (1, 1)], 1),
+    "full-ring": ([(4, 0), (4, 0)], 1),
+    "chunk-across-a-block": ([(1, -3), (2, -1), (0, 5), (1, 0)], 8),
+    "chunk-across-the-end": ([(4, -2), (1, 0)], 4),
+}
+
+
+def _case(shape, walk):
+    H, S, D, dt, G = SHAPES[shape]
+    spec, q_len = WALKS[walk]
+    _, block = kv_tile(H, S, D, dt, PAGE)
+    assert S // block == 4, "the cases count in a cache of four k-blocks"
+    lengths = np.array([b * block + r for b, r in spec], np.int32)
+    if G > 1:
+        q_len = min(q_len, 2)       # 2 x 16 heads: two sublane tiles
+    return H, S, D, dt, G, block, lengths, q_len
+
+
+@pytest.mark.parametrize("walk", sorted(WALKS))
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_kernel_scores_nothing_past_a_sequences_length(shape, walk):
+    H, S, D, dt, G, block, lengths, q_len = _case(shape, walk)
+    B = len(lengths)
+    rng = np.random.default_rng(zlib.crc32(f"{shape}/{walk}".encode()))
+    q = jnp.asarray(rng.normal(size=(B * H, q_len * G, D)), dt)
+    k, v = (rng.normal(size=(B, H, S, D)).astype(np.float32)
+            for _ in range(2))
+    ref = decode_attention_reference(
+        q, jnp.asarray(k.reshape(B * H, S, D), dt),
+        jnp.asarray(v.reshape(B * H, S, D), dt),
+        jnp.asarray(np.repeat(lengths, H)), D ** -0.5, group=G)
+    # what the chunk's last row sees is the last key any row may touch
+    seen = np.minimum(lengths + q_len - 1, S)
+    live = -(-np.maximum(seen, 1) // block) * block       # block 0 is kept
+    k, v = k.copy(), v.copy()       # the reference may still read its own
+    for b in range(B):
+        for c in (k, v):
+            c[b, :, seen[b]:live[b]] = 1e30
+            c[b, :, live[b]:] = np.nan
+    out = flash_attention_decode(
+        q, jnp.asarray(k.reshape(B * H, S, D), dt),
+        jnp.asarray(v.reshape(B * H, S, D), dt), lengths, num_heads=H,
+        page_size=PAGE, group=G, interpret=True)
+    out = np.asarray(out, np.float32).reshape(B, H, q_len * G, D)
+    ref = np.asarray(ref, np.float32).reshape(B, H, q_len * G, D)
+    assert np.isfinite(out).all()
+    tol = dict(atol=2e-5, rtol=1e-4) if dt == jnp.float32 else dict(
+        atol=3e-2, rtol=3e-2)
+    for b in range(B):
+        # rows that see no key at all are zeros (an empty slot, as ever);
+        # the reference's softmax over nothing is a mean of every row
+        blind = np.repeat(lengths[b] + np.arange(q_len) == 0, G)
+        assert not out[b][:, blind].any()
+        np.testing.assert_allclose(out[b][:, ~blind], ref[b][:, ~blind],
+                                   **tol)
+
+
+@pytest.mark.parametrize("q_len", [1, 8])
+def test_index_map_repeats_the_last_live_block(q_len):
+    """Past a sequence's last live block the K and V block index repeats,
+    which is what makes the pipeline issue no DMA there."""
+    block, num_k = 128, 8
+    lens = np.array([0, 1, 127, 128, 129, 300, 1017, 1024], np.int32)
+    index = _kv_index_map(q_len, block, num_k)
+    for b, n in enumerate(lens):
+        last = min(max(int(n) + q_len - 2, 0) // block, num_k - 1)
+        assert int(last_live_block(n, q_len, block, num_k)) == last
+        walked = [tuple(int(i) for i in index(b, 3, ik, lens))
+                  for ik in range(num_k)]
+        assert walked == [(b, 3, min(ik, last), 0) for ik in range(num_k)]
+
+
+def test_tile_is_whole_pages_of_whole_heads_inside_its_budget():
+    from paddle_tpu.kernels.decode_attention import _STEP_BYTES
+
+    assert kv_tile(12, 1024, 64, jnp.float32, 128) == (12, 128)
+    assert kv_tile(8, 1024, 128, jnp.bfloat16, 128) == (8, 256)
+    for H, S, D, dt, page in [(12, 1024, 64, jnp.float32, 128),
+                              (8, 4096, 128, jnp.bfloat16, 128),
+                              (128, 2048, 128, jnp.bfloat16, 128),
+                              (2, 32, 16, jnp.float32, 8),
+                              (7, 96, 256, jnp.float32, 32),
+                              (1, 64, 64, jnp.float32, 128)]:
+        heads, rows = kv_tile(H, S, D, dt, page)
+        assert H % heads == 0 and S % rows == 0
+        assert rows % min(page, S) == 0
+        lanes = -(-D // 128) * 128
+        if (heads, rows) != (1, min(page, S)):
+            assert 2 * heads * rows * lanes * jnp.dtype(
+                dt).itemsize <= _STEP_BYTES
+
+
+def test_walk_share_histogram_counts_what_the_kernel_helper_counts():
+    """``decode_attention_walk_share`` is the kernel module's own count on
+    the lengths the dispatch thread holds: k-blocks fetched over k-blocks
+    held, over every step of the chunk and every layer."""
+    cfg = GptConfig(vocab_size=64, hidden_size=48, num_layers=2,
+                    num_heads=12, intermediate_size=48, max_position=512)
+    with un.guard():
+        net = build_gpt_generative(cfg, batch_slots=4, max_seq=512,
+                                   page_size=128, prompt_buckets=(128,))
+    eng = serving.GenerativeEngine(
+        net, scope=fluid.Scope(), executor=fluid.Executor(fluid.CPUPlace()),
+        gen_config=serving.GenerationConfig(decode_chunk=4))
+    active = [types.SimpleNamespace(prompt=np.zeros(p), emitted=e)
+              for p, e in [(30, 1), (120, 7), (100, 160), (128, 383)]]
+    monitor.reset()
+    eng._observe_walk(active, 4)
+    got = monitor.metric_value("decode_attention_walk_share", default=None)
+    # lengths 31.., 127.. (crosses into block 1 at its third step), 260..,
+    # 511.. (the cache's end): blocks of 128 rows, 4 a cache
+    lengths = np.array([31, 127, 260, 511]) + np.arange(4)[:, None]
+    fetched, held = decode_walk_blocks(np.minimum(lengths, 512),
+                                       (4, 12, 512, 4), "float32", 128)
+    assert (fetched, held) == (4 * 1 + (2 * 1 + 2 * 2) + 4 * 3 + 4 * 4, 64)
+    assert got["count"] == 1
+    assert got["sum"] == pytest.approx(fetched / held)
