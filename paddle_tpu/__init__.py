@@ -41,7 +41,6 @@ from . import regularizer
 from . import resilience
 from . import serving
 from . import analysis
-from . import tuning
 from . import aot_cache
 from .core import registry as op_registry
 from .flags import get_flags, set_flags

@@ -21,11 +21,6 @@ Public surface:
   new static-analysis families (``static_checks``: PT700s dtype/shape
   consistency, PT710s donation-race, PT720s dead-code + opt-in DCE) run
   through it too.
-* ``epilogue_fusion`` — Pass 7, GEMM-epilogue fusion (CODA): mul/matmul →
-  bias/activation/residual/layer_norm chains rewritten into the
-  ``fused_gemm_epilogue`` op under a per-chain numerical fidelity witness,
-  wired to the executor via ``FLAGS_epilogue_fusion``
-  (docs/PERF_NOTES.md "Epilogue fusion").
 * ``CODES`` — the diagnostic-code table (see docs/ANALYSIS.md).
 """
 from .diagnostics import (CODES, Diagnostic, ProgramVerificationError,
@@ -53,8 +48,6 @@ from .cost_model import (CommsReport, CostReport, comms_compute_ratio,
 from . import sharding_check
 from .sharding_check import (CollectiveEvent, ShardingAnalysis,
                              propagate_sharding)
-from . import epilogue_fusion
-from .epilogue_fusion import (FusedChain, FusionDecision, fuse_epilogues)
 from . import numerics
 from .numerics import (Interval, NumericsReport, analyze_numerics,
                        check_numerics, static_intervals)
@@ -77,7 +70,6 @@ __all__ = [
     "estimate_comms", "comms_compute_ratio",
     "sharding_check", "CollectiveEvent", "ShardingAnalysis",
     "propagate_sharding",
-    "epilogue_fusion", "FusedChain", "FusionDecision", "fuse_epilogues",
     "numerics", "Interval", "NumericsReport", "analyze_numerics",
     "check_numerics", "static_intervals",
 ]

@@ -124,19 +124,6 @@ def _numerics_check_pass(program, ctx):
     return check_numerics(program, ctx)
 
 
-def _epilogue_fusion_pass(program, ctx):
-    """GEMM-epilogue fusion (analysis/epilogue_fusion.py, PT750-PT755):
-    rewrite mul/matmul -> bias/activation/residual/layer_norm chains into
-    fused_gemm_epilogue ops, gated by the per-chain fidelity witness.
-    Consumes the cached liveness chains for the single-consumer and
-    fetched-intermediate proofs. Returns the ``FusionDecision`` — the
-    manager swaps in ``decision.program`` and the executor reads the
-    decision from ``result.values["epilogue_fusion"]``."""
-    from .epilogue_fusion import epilogue_fusion_pass
-
-    return epilogue_fusion_pass(program, ctx)
-
-
 def _dce_pass(program, ctx):
     """Opt-in dead-code elimination, proven by the fidelity witness in
     ``static_checks.dce_program`` (refuses rather than risk a wrong
@@ -165,9 +152,6 @@ def register_builtins(reg: PassRegistry) -> None:
     reg.register(FunctionPass(_numerics_check_pass, "numerics_check",
                               ANALYSIS))
     reg.register(FunctionPass(_auto_remat_pass, "auto_remat", TRANSFORM,
-                              invalidates=("*",)))
-    reg.register(FunctionPass(_epilogue_fusion_pass, "epilogue_fusion",
-                              TRANSFORM, requires=("liveness",),
                               invalidates=("*",)))
     reg.register(FunctionPass(_dce_pass, "dce", TRANSFORM,
                               requires=("dead_code",),

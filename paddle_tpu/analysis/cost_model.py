@@ -3,8 +3,8 @@
 The MFU push (ROADMAP item 4; CODA arXiv 2605.19269, "Learning to
 Optimize Tensor Programs" arXiv 1805.08166) needs per-program FLOP/byte
 accounting the framework never computed: measured TF/s is only meaningful
-against the program's MODEL FLOPs, and fusion/autotuning decisions need
-arithmetic intensity (FLOPs per byte moved). This pass derives both from
+against the program's MODEL FLOPs, and a roofline needs arithmetic
+intensity (FLOPs per byte moved). This pass derives both from
 the ``infer_shape`` metadata already recorded on every var at build time —
 no execution, no tracing, one walk over the ops.
 
@@ -22,9 +22,8 @@ Consumers:
   turns measured step durations into ``executor_mfu`` / achieved-TF/s
   gauges (per program serial and shape bucket);
 * ``ServingEngine`` emits the same per (bucket) after every batch;
-* ``bench.py`` reports cost-model FLOPs next to the hand-derived
-  analytic counts (the two must agree within 10% — the
-  ``tools/trace_check.py`` CI gate asserts it);
+* ``tools/trace_check.py`` (a CI gate) holds the cost-model FLOPs to
+  within 10% of the hand-derived analytic counts;
 * registered as analysis pass ``cost_model`` so lint pipelines and
   custom passes can require it (``ctx.analysis("cost_model")``).
 """
@@ -55,9 +54,9 @@ class DevicePeak:
 
 
 # THE peaks table, keyed by jax's ``Device.device_kind``: the monitor's MFU
-# gauges, bench.py and tools/perf_probe.py all read it. A device that is
-# not listed — the CPU included — has no peak: no MFU gauge is set for it
-# and the benchmarks raise. Never a default for a device nobody asked
+# gauges and tools/perf_probe.py read it. A device that is not listed —
+# the CPU included — has no peak: no MFU gauge is set for it and
+# perf_probe raises. Never a default for a device nobody asked
 # about.
 DEVICE_PEAKS: Dict[str, DevicePeak] = {
     "TPU v5 lite": DevicePeak(

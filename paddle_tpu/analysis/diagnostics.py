@@ -164,28 +164,6 @@ CODES = {
     "PT744": (Severity.INFO,
               "no sharding propagation rule for this op: specs are "
               "conservatively replicated past it"),
-    # -- epilogue_fusion transform (analysis/epilogue_fusion.py) --------
-    "PT750": (Severity.INFO,
-              "GEMM-epilogue chain fused into one fused_gemm_epilogue op"),
-    "PT751": (Severity.INFO,
-              "fusion refused: a chain intermediate is fetched — the "
-              "caller observes the unfused value"),
-    "PT752": (Severity.INFO,
-              "fusion refused: a chain intermediate has more than one "
-              "consumer — fusing would recompute or break a reader"),
-    "PT753": (Severity.INFO,
-              "fusion refused: program carries backward/optimizer ops "
-              "(epilogue fusion only proves forward-only rewrites)"),
-    "PT754": (Severity.WARNING,
-              "fusion fidelity witness failed — the program runs "
-              "untransformed (never a wrong program)"),
-    "PT755": (Severity.INFO,
-              "fused chain has no kernel tiling on this backend — the "
-              "dense replay of the original op rules will run"),
-    "PT756": (Severity.INFO,
-              "fusion refused: an op between the chain's ops rewrites a "
-              "var the chain reads — the fused op's relocated reads "
-              "would see the redefined value"),
     # -- source-level concurrency analysis (analysis/concurrency.py) ----
     # These three codes lint the framework's own Python source (lock
     # attributes, with-regions, thread entry points), not a Program IR;

@@ -39,8 +39,7 @@ a grad reaching an optimizer update without passing through
 PT906 quantizability report — one finding per forward GEMM/conv site,
 carrying contraction width, quant-annotation state and static/calibrated
 abs-max. PT906 is the exact work-list the int8 epilogue-lowering PR
-consumes, and is asserted (tests/test_numerics.py) to be a superset of
-``epilogue_fusion``'s fusable chain bases.
+consumes.
 
 Calibration: ``ctx.options["numerics_calibration"] = {var: absmax}`` (the
 witness's observed abs-max, fed back by tools/lint_numerics.py --witness)
@@ -84,15 +83,13 @@ DTYPE_FINITE_MAX = {
 LOW_PRECISION_DTYPES = frozenset({"float16", "bfloat16"})
 
 # the GEMM/conv families the QAT pass annotates and the int8 PR lowers —
-# kept in sync with contrib/slim's _DEFAULT_QUANTIZABLE and (for mul/
-# matmul) epilogue_fusion._BASE_TYPES, asserted in tests/test_numerics.py
+# kept in sync with contrib/slim's _DEFAULT_QUANTIZABLE
 QUANT_SITE_TYPES = ("conv2d", "depthwise_conv2d", "mul", "matmul")
 
 # legal consumers of a fake-quant output under the int8 rewrite contract:
-# the GEMM/conv site itself, the fused form of that site, or the site's
-# grad replay (training programs read the quantized activation from the
-# backward ops)
-QUANT_CONSUMER_TYPES = frozenset(QUANT_SITE_TYPES) | {"fused_gemm_epilogue"}
+# the GEMM/conv site itself, or the site's grad replay (training programs
+# read the quantized activation from the backward ops)
+QUANT_CONSUMER_TYPES = frozenset(QUANT_SITE_TYPES)
 
 FAKE_QUANT_TYPES = frozenset({
     "fake_quantize_dequantize_abs_max",

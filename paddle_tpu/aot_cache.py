@@ -13,22 +13,20 @@ drops from seconds-per-bucket to milliseconds (measured cold-vs-warm in
 
 Keying. The in-memory step-cache keys lean on per-process serials
 (``program._serial``, ``scope._serial``) — useless across restarts. The
-disk key reuses the autotuner's durable identity
-(:func:`paddle_tpu.tuning.program_content_fingerprint` — the PR 13
-content hash that survives restarts) plus everything else that shapes
+disk key is a content hash of the program that survives restarts
+(:func:`program_content_fingerprint`) plus everything else that shapes
 the compiled artifact:
 
 * the execution kind (``run`` / ``chained`` + step count) and fetch list,
-* the compiler configuration (xla_options, tuned GEMM blocks, the
-  nan-check flag — all of which change the traced/compiled program),
+* the compiler configuration (``FLAGS_xla_options``; nan-checked and
+  witness-instrumented steps are never cached),
 * the abstract signature of every argument leaf (shape + dtype + tree
   structure): state shapes come from the live scope, so two scopes with
   different-shaped state can never share an executable,
 * the platform of the devices the executable runs on, jax version and
-  framework version (an upgraded compiler's executables are invisible,
-  the cost-database staleness rule).
+  framework version (an upgraded compiler's executables are invisible).
 
-Safety posture (the cost-database discipline): loads NEVER raise — a
+Safety posture: loads NEVER raise — a
 missing/corrupt/version-mismatched entry is a miss with one warning, and
 the executor compiles as if the cache did not exist. Saves are atomic
 (temp sibling + fsync + rename) so a killed replica can never publish a
@@ -48,7 +46,7 @@ from typing import Any, Optional, Tuple
 from .monitor.lockwitness import make_lock
 
 __all__ = ["executable_key", "load_executable", "save_executable",
-           "cache_dir_flag", "cache_stats"]
+           "cache_dir_flag", "cache_stats", "program_content_fingerprint"]
 
 logger = logging.getLogger("paddle_tpu.aot_cache")
 
@@ -92,21 +90,51 @@ def _count(name: str, help_: str, **labels) -> None:
         (c.labels(**labels) if labels else c).inc()
 
 
+_VOLATILE_ATTRS = ("__uid__", "op_callstack", "op_namescope")
+
+
+def program_content_fingerprint(program) -> str:
+    """Stable CONTENT hash of a program — unlike ``program._serial`` (a
+    per-process counter) it survives process restarts, which is what makes
+    the cache durable. Hashes op types, slot wiring, non-volatile attrs
+    and var metadata in deterministic order; memoized per (program,
+    version)."""
+    cached = getattr(program, "_content_fp", None)
+    if cached is not None and cached[0] == getattr(program, "_version", 0):
+        return cached[1]
+    h = hashlib.sha256()
+    for blk in program.blocks:
+        for name in sorted(blk.vars):
+            v = blk.vars[name]
+            h.update(f"v|{blk.idx}|{name}|{v.shape}|{v.dtype}|"
+                     f"{v.persistable}|{v.is_data}\n".encode())
+        for op in blk.ops:
+            attrs = sorted((k, repr(val)) for k, val in op.attrs.items()
+                           if k not in _VOLATILE_ATTRS)
+            h.update(f"o|{blk.idx}|{op.type}|"
+                     f"{sorted((k, tuple(v)) for k, v in op.inputs.items())}|"
+                     f"{sorted((k, tuple(v)) for k, v in op.outputs.items())}"
+                     f"|{attrs}\n".encode())
+    fp = h.hexdigest()[:16]
+    try:
+        program._content_fp = (getattr(program, "_version", 0), fp)
+    except Exception:
+        pass
+    return fp
+
+
 def executable_key(parts: tuple, args, devices) -> str:
     """Durable identity of one compiled executable.
 
     ``parts`` is the executor-stamped tuple
-    ``(kind, program, fetch_names, xla_opts, gemm_blocks, extra...)``;
-    the program element is replaced by its content fingerprint (the
-    autotuner's restart-stable hash — one identity shared by the cost
-    database and this cache). ``args`` are the exact call arguments the
-    executable will be lowered with; only their abstract signature
-    (tree structure + per-leaf shape/dtype) enters the key. ``devices``
-    are the devices it executes on (their platform enters the key).
+    ``(kind, program, fetch_names, xla_opts, extra...)``; the program
+    element is replaced by its content fingerprint. ``args`` are the
+    exact call arguments the executable will be lowered with; only their
+    abstract signature (tree structure + per-leaf shape/dtype) enters the
+    key. ``devices`` are the devices it executes on (their platform
+    enters the key).
     """
     import jax
-
-    from .tuning import program_content_fingerprint
 
     kind, program, *rest = parts
     fp = program_content_fingerprint(program)

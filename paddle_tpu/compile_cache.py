@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives.
 
-One rule for every entry point that compiles (``chip_smoke.py``,
-``bench.py``, the test suite): ``JAX_COMPILATION_CACHE_DIR`` wins when the
+One rule for every entry point that compiles (``chip_smoke.py``, the
+test suite): ``JAX_COMPILATION_CACHE_DIR`` wins when the
 environment sets it — JAX reads it itself and nothing here overrides it —
 otherwise the cache sits at one fixed, git-ignored directory inside the
 checkout. The path is part of the cache key's environment (a directory that

@@ -11,15 +11,6 @@ fake-quant op is spliced before each float input — weights (persistable
 params) get in-graph abs_max, activations get a moving-average scale held
 in a new persistable state var. Must run BEFORE minimize() so the
 backward differentiates through the straight-through estimators.
-
-Pass-order contract with GEMM-epilogue fusion (docs/ANALYSIS.md
-"Quantization and epilogue fusion"): QAT must ALSO run before
-``analysis.epilogue_fusion`` — fusion consumes the fake-quant outputs as
-GEMM inputs and the PT900 pairing check stays satisfiable; run the other
-way round, the GEMM this pass wants to annotate has been swallowed into a
-``fused_gemm_epilogue`` op it does not know how to split, and the quant
-scaffolding would silently attach to nothing. ``apply`` refuses a
-pre-fused program loudly instead of mis-pairing.
 """
 from __future__ import annotations
 
@@ -47,18 +38,6 @@ class QuantizationTransformPass:
         """Insert fake-quant ops; returns how many inputs were quantized."""
         startup = startup_program or default_startup_program()
         block = program.global_block
-        fused = [i for i, op in enumerate(block.ops)
-                 if op.type == "fused_gemm_epilogue"]
-        if fused:
-            raise ValueError(
-                f"QuantizationTransformPass: program already contains "
-                f"{len(fused)} fused_gemm_epilogue op(s) (first at global "
-                f"block index {fused[0]}) — quantization must run BEFORE "
-                f"epilogue fusion, not after: the GEMMs this pass would "
-                f"annotate are gone and the fake-quant/GEMM pairing the "
-                f"PT900 check enforces could not be established. Apply "
-                f"quant_aware() to the unfused program, then fuse "
-                f"(docs/ANALYSIS.md, 'Quantization and epilogue fusion').")
         quantized_of = {}  # source var -> fake-quant output name
         n = 0
         new_ops = []

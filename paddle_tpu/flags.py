@@ -360,38 +360,6 @@ _DEFS: Dict[str, tuple] = {
                         "MiB: the cheapest checkpoint set (fewest "
                         "recomputed ops) whose PREDICTED peak fits is "
                         "chosen; 0 = no budget, sqrt(N) segmentation"),
-    "epilogue_fusion": (bool, False,
-                        "GEMM-epilogue fusion (analysis/epilogue_fusion.py, "
-                        "registered transform pass): rewrite mul/matmul -> "
-                        "bias-add -> activation -> residual -> layer_norm "
-                        "chains in forward-only programs into the "
-                        "fused_gemm_epilogue op, gated by a fidelity "
-                        "witness (unfusable or witness-failing programs "
-                        "refuse and run untransformed — never a wrong "
-                        "program). Fused programs get their own serial so "
-                        "compile caches never alias fused and plain "
-                        "variants. docs/PERF_NOTES.md"),
-    "use_fused_gemm": (str, "auto",
-                       "fused_gemm_epilogue path: auto (Pallas kernel on "
-                       "TPU when the tiling fits, dense replay of the "
-                       "original op rules elsewhere), always (force "
-                       "kernel; interpret mode off-TPU — slow, tests "
-                       "only; unsupported tilings raise instead of "
-                       "silently falling back), never (dense replay)"),
-    "fused_gemm_blocks": (str, "",
-                          "kernel block sizes for fused_gemm_epilogue as "
-                          "'m,n,k' (e.g. '128,128,128'); empty defers to "
-                          "the autotuner's best-known config "
-                          "(FLAGS_autotune=use|measure) and then the "
-                          "(128,128,128) default. Part of the compile-"
-                          "cache key"),
-    "autotune": (str, "off",
-                 "persistent autotuner (paddle_tpu.tuning): off (no DB "
-                 "access), use (best-known FLAGS_xla_options / fused-"
-                 "kernel block sizes from the cost database feed the "
-                 "executor compile path automatically; explicit flags "
-                 "still win), measure (use + the measure loop may run "
-                 "trials and record them). docs/PERF_NOTES.md"),
     "aot_cache_dir": (str, "",
                       "warm-start AOT executable cache directory "
                       "(paddle_tpu.aot_cache): after every successful "
@@ -403,19 +371,13 @@ _DEFS: Dict[str, tuple] = {
                       "corrupt or version-mismatched entries degrade to "
                       "a recompile with one warning. Empty disables "
                       "(default). docs/SERVING.md"),
-    "autotune_db": (str, "",
-                    "path of the autotuner cost database (JSON, atomic "
-                    "rewrite); empty = ~/.cache/paddle_tpu/"
-                    "autotune_db.json. Keyed by (program content "
-                    "fingerprint, shape bucket, backend); entries from a "
-                    "different framework/jax version are ignored"),
     "xla_options": (str, "",
                     "XLA compiler options forwarded to jax.jit("
                     "compiler_options=...) on every executor compile; "
                     "JSON object or comma-separated k=v pairs, e.g. "
                     "'{\"xla_tpu_enable_latency_hiding_scheduler\": true}' "
                     "or 'xla_cpu_enable_fast_min_max=true'. Part of the "
-                    "compile-cache key; sweep with tools/xla_sweep.py"),
+                    "compile-cache key"),
     "paddle_num_threads": (int, 1, "host threads hint (XLA owns scheduling)"),
     "seq_bucket_sizes": (str, "", "override DataFeeder varlen buckets, csv"),
     "conv_use_nhwc": (str, "auto",

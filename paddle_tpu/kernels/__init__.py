@@ -7,11 +7,8 @@ from .flash_attention import (classify_shapes, flash_attention,
 from .decode_attention import (KERNEL_ROWS, decode_attention_reference,
                                decode_walk_blocks, flash_attention_decode,
                                paged_kv_append, paged_kv_append_rows)
-from .fused_gemm import (classify_gemm, fused_gemm, fused_gemm_reference,
-                         supports_gemm)
 
 __all__ = ["flash_attention", "flash_attention_with_lse", "supports_shapes",
            "classify_shapes", "flash_attention_decode", "paged_kv_append",
            "paged_kv_append_rows", "KERNEL_ROWS", "decode_walk_blocks",
-           "decode_attention_reference", "fused_gemm", "classify_gemm",
-           "supports_gemm", "fused_gemm_reference"]
+           "decode_attention_reference"]

@@ -73,8 +73,8 @@ class LowerCtx:
     """Context passed to every op lowering rule."""
 
     def __init__(self, base_key=None, uid: int = 0, mesh=None, axis_env=None,
-                 program=None, nan_checks=None, gemm_blocks=None,
-                 num_taps=None, platform=None):
+                 program=None, nan_checks=None, num_taps=None,
+                 platform=None):
         self.base_key = base_key
         self.uid = uid
         self.mesh = mesh          # jax.sharding.Mesh when lowering under shard_map
@@ -97,12 +97,6 @@ class LowerCtx:
         # (monitor.numwitness). Shares nan_checks' tracer-escape rule:
         # sub-block lowerings must null it.
         self.num_taps = num_taps
-        # autotuner-chosen fused-GEMM block sizes for THIS compile, bound
-        # at step-fn build time (the same values that sit in the compile
-        # cache key) — a shared per-Program stamp read lazily at trace
-        # time would let a concurrent compile with a different tuned
-        # config leak its blocks into this executable
-        self.gemm_blocks = gemm_blocks
 
     def rng(self):
         """PRNG key unique to this op instance; grad ops fold in the forward
@@ -114,14 +108,14 @@ class LowerCtx:
 
     def with_uid(self, uid: int) -> "LowerCtx":
         return LowerCtx(self.base_key, uid, self.mesh, self.axis_env,
-                        self.program, self.nan_checks, self.gemm_blocks,
-                        self.num_taps, self.platform)
+                        self.program, self.nan_checks, self.num_taps,
+                        self.platform)
 
 
 def lowering_platform(ctx: Optional[LowerCtx] = None, mesh=None):
     """Platform of the device(s) the step being traced will run on — the
     ONE thing every kernel and layout route keys on (Pallas vs primitive
-    attention/GEMM, NHWC convs, ``interpret=``). A mesh names its own
+    attention, NHWC convs, ``interpret=``). A mesh names its own
     devices; otherwise it is the platform the step's builder stamped on
     the ctx. ``None`` means "lowered for no device" and takes the
     portable primitive routes.

@@ -61,7 +61,7 @@ __all__ = [
     "add_hook", "remove_hook", "clear_hooks", "get_registry", "counter",
     "gauge", "histogram", "metric_value", "enabled", "record_cache_lookup",
     "observe_compile", "complete_compile", "step_begin", "step_end",
-    "record_pass", "record_remat", "record_fusion",
+    "record_pass", "record_remat",
     "record_watchdog_timeout",
     "program_cost", "observe_step_cost", "observe_serving_cost",
     "observe_comms_cost",
@@ -397,24 +397,6 @@ def record_pass(name: str, kind: str, seconds: float,
         histogram("pass_duration_seconds",
                   "wall time of one IR pass execution, by pass").labels(
             **{"pass": name}).observe(seconds)
-
-
-def record_fusion(decision) -> None:
-    """Record one FLAGS_epilogue_fusion decision
-    (analysis/epilogue_fusion.py FusionDecision): programs transformed vs
-    refused, and fused chains by epilogue kind (docs/OBSERVABILITY.md)."""
-    if not enabled():
-        return
-    counter("fusion_programs_total",
-            "epilogue-fusion decisions by outcome").labels(
-        outcome="applied" if decision.applied else "refused").inc()
-    if not decision.applied:
-        return
-    for c in decision.chains:
-        counter("fusion_ops_fused_total",
-                "GEMM-epilogue chains rewritten into fused_gemm_epilogue, "
-                "by epilogue kind").labels(
-            epilogue=c.get("epilogue", "?")).inc()
 
 
 def record_remat(decision) -> None:

@@ -1,7 +1,7 @@
 """Event-hook API: subscribe to executor lifecycle events.
 
 ``add_hook(on_step_begin=..., on_step_end=..., on_compile=...)`` lets
-trainers, ``bench.py`` and serving wrappers observe execution without
+trainers and serving wrappers observe execution without
 patching the executor (the reference exposed the same seam as the
 device_worker/trainer callbacks; here it is three well-typed events fed by
 ``Executor.run`` / ``run_chained`` / ``CompiledProgram``).

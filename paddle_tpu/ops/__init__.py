@@ -14,7 +14,6 @@ from . import moe  # noqa: F401
 from . import detection  # noqa: F401
 from . import quant_ops  # noqa: F401
 from . import fused_attention  # noqa: F401
-from . import fused_gemm  # noqa: F401
 from . import pipeline_op  # noqa: F401
 from . import image  # noqa: F401
 from . import misc  # noqa: F401

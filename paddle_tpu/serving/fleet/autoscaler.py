@@ -13,8 +13,8 @@ Every sensor and actuator already existed — this module connects them.
 * the **supervisor's replica states** (spawning / ready / backoff),
 
 and drives exactly two actuators: ``ReplicaSupervisor.add_replica``
-(scale-out, warm through the fleet-shared AOT cache + autotune
-CostDatabase carried by ``SupervisorConfig.shared_flags``) and
+(scale-out, warm through the fleet-shared AOT cache the loop's
+``aot_dir`` names) and
 ``ReplicaSupervisor.drain`` (scale-in, strictly the graceful-preemption
 path: the victim flips ready-false, finishes everything admitted, exits
 0, and the fleet ledger stays ``exact`` throughout).

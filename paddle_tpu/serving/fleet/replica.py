@@ -176,21 +176,8 @@ def _serve(args, t_start: float, state: dict) -> int:
     warm_up_s = time.perf_counter() - t0
     cache = aot_cache.cache_stats()
 
-    # fleet-shared autotuning visibility: how many warm-up compiles hit
-    # the shared CostDatabase vs re-measured (the autoscale gate asserts
-    # a scaled-out replica warms with hits >= 1 and zero re-trials)
-    from paddle_tpu import monitor, tuning
-    autotune = {"mode": tuning.autotune_mode(),
-                "hits": int(monitor.metric_value(
-                    "autotune_hits_total", 0.0)),
-                "misses": int(monitor.metric_value(
-                    "autotune_misses_total", 0.0)),
-                "trials": int(monitor.metric_value(
-                    "autotune_trials_total", 0.0))}
-
     startup = {"model": args.model, "warm_up_s": warm_up_s,
                "buckets": buckets, "aot_cache": cache,
-               "autotune": autotune,
                "time_to_ready_s": time.perf_counter() - t_start}
     frontend = ServingFrontend(eng, host=args.host, port=args.port,
                                replica_id=args.replica_id,

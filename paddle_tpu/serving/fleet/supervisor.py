@@ -91,10 +91,8 @@ class SupervisorConfig:
     seed: int = 0
     restart: bool = True
     # fleet-shared flags, passed to EVERY spawned replica as
-    # ``--set-flag name=value`` pairs: how one autotune CostDatabase
-    # (FLAGS_autotune_db — flock-merge safe) and one AOT cache warm the
-    # whole fleet, so a scale-out replica compiles straight to
-    # best-known configs instead of re-measuring
+    # ``--set-flag name=value`` pairs, so they also reach the replicas
+    # the autoscaler mints later
     shared_flags: Optional[Dict[str, str]] = None
 
 

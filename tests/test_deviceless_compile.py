@@ -16,8 +16,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from paddle_tpu.kernels import (flash_attention, flash_attention_decode,
-                                fused_gemm)
+from paddle_tpu.kernels import flash_attention, flash_attention_decode
 
 
 @pytest.fixture(scope="module")
@@ -66,28 +65,6 @@ def test_flash_attention_decode_compiles_at_the_gpt2_base_shape(v5e):
         v5e((96, 1024, 64), jnp.float32), v5e((8,), jnp.int32))
 
 
-def test_fused_gemm_compiles_at_the_bert_base_ffn_shape(v5e):
-    """[bs 32 x 512, 768] @ [768, 3072] + bias + tanh-gelu, bf16."""
-    _compiles_with_mosaic(
-        lambda x, y, b: fused_gemm(x, y, bias=b, activation="gelu",
-                                   gelu_approximate=True),
-        v5e((16384, 768), jnp.bfloat16), v5e((768, 3072), jnp.bfloat16),
-        v5e((3072,), jnp.float32))
-
-
-@pytest.mark.xfail(strict=True, raises=NotImplementedError,
-                   reason="jax 0.9 Mosaic has no lowering for erfc, so the "
-                          "fused GEMM's EXACT gelu epilogue (what the model "
-                          "zoo's act='gelu' asks for) does not compile for "
-                          "a TPU; recorded in PR 21, not fixed — the kernel "
-                          "is behind FLAGS_epilogue_fusion, off by default")
-def test_fused_gemm_exact_gelu_epilogue_compiles(v5e):
-    _compiles_with_mosaic(
-        lambda x, y, b: fused_gemm(x, y, bias=b, activation="gelu"),
-        v5e((16384, 768), jnp.bfloat16), v5e((768, 3072), jnp.bfloat16),
-        v5e((3072,), jnp.float32))
-
-
 def test_kernels_keep_their_names_in_the_compiled_hlo(v5e):
     """What a profiler's ``XLA Ops`` line prints is the instruction's own
     name: each Mosaic call carries the name the program chose, and the
@@ -100,27 +77,25 @@ def test_kernels_keep_their_names_in_the_compiled_hlo(v5e):
         return flash_attention(q, k, v, num_heads=12).astype(
             jnp.float32).sum()
 
-    def step(q, k, v, qd, kc, vc, n, x, y):
+    def step(q, k, v, qd, kc, vc, n):
         with jax.named_scope("fused_attention"):
             fwd = flash_attention(q, k, v, num_heads=12)
         with jax.named_scope("fused_attention_grad"):
             grads = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
         return (fwd, grads,
                 flash_attention_decode(qd, kc, vc, n, num_heads=12,
-                                       page_size=128),
-                fused_gemm(x, y))
+                                       page_size=128))
 
     qkv = v5e((24, 512, 64), jnp.float32)
     text = _compiles_with_mosaic(
         step, qkv, qkv, qkv, v5e((96, 1, 64), jnp.float32),
         v5e((96, 1024, 64), jnp.float32), v5e((96, 1024, 64), jnp.float32),
-        v5e((8,), jnp.int32), v5e((1024, 768), jnp.bfloat16),
-        v5e((768, 3072), jnp.bfloat16))
+        v5e((8,), jnp.int32))
     names = sorted(re.sub(r"[.\d]+$", "", m) for m in re.findall(
         r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text))
     assert names == ["decode_attention", "flash_attention_bwd_dkv",
                      "flash_attention_bwd_dq", "flash_attention_fwd",
-                     "flash_attention_fwd", "fused_gemm"]
+                     "flash_attention_fwd"]
 
 
 def _whole_cache_work_in_loops(text, cache_shape):

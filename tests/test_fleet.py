@@ -750,16 +750,27 @@ def test_aot_cache_key_changes_with_config_and_shape(tmp_path):
     infer, _, pred = _build_infer()
     args_a = ([np.zeros((1, 13), np.float32)], [], [], None)
     args_b = ([np.zeros((2, 13), np.float32)], [], [], None)
-    parts = ("run", infer, (pred,), (), None)
+    parts = ("run", infer, (pred,), ())
     devs = [fluid.CPUPlace().jax_device()]
     k1 = aot_cache.executable_key(parts, args_a, devs)
     assert k1 == aot_cache.executable_key(parts, args_a, devs)   # stable
     assert k1 != aot_cache.executable_key(parts, args_b, devs)   # shape
     parts_opts = ("run", infer, (pred,),
-                  (("xla_cpu_enable_fast_min_max", True),), None)
+                  (("xla_cpu_enable_fast_min_max", True),))
     assert k1 != aot_cache.executable_key(parts_opts, args_a, devs)
-    parts_chained = ("chained", infer, (pred,), (), None, 3)
+    parts_chained = ("chained", infer, (pred,), (), 3)
     assert k1 != aot_cache.executable_key(parts_chained, args_a, devs)
+
+
+def test_content_fingerprint_stable_across_builds():
+    from paddle_tpu.aot_cache import program_content_fingerprint
+
+    m1, _, _ = _build_infer()
+    m2, _, _ = _build_infer()
+    m3, _, _ = _build_infer(hidden=8)
+    assert program_content_fingerprint(m1) == program_content_fingerprint(m2)
+    assert program_content_fingerprint(m1) != program_content_fingerprint(m3)
+    assert m1._serial != m2._serial  # serials differ; content hash doesn't
 
 
 def test_aot_cache_corrupt_and_stale_entries_degrade(

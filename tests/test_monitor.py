@@ -166,6 +166,56 @@ def test_recompile_diagnostic_names_fetch_list_and_scope():
     assert "scope" in ev.changed
 
 
+@pytest.mark.parametrize("path", ["run", "chained", "parallel"])
+def test_xla_options_reach_the_compile_and_its_key(path):
+    """FLAGS_xla_options on each dispatch path (Executor.run, run_chained,
+    CompiledProgram): the options are handed to the compiler and sit in
+    the step-cache key, an explicit '{}' is the same key as no options,
+    and a malformed value raises before anything compiles."""
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        loss = _build_train()
+    exe = fluid.Executor(fluid.CPUPlace())
+    scope = fluid.Scope()
+    target = (fluid.CompiledProgram(main).with_data_parallel(
+        loss_name=loss.name) if path == "parallel" else main)
+
+    def dispatch():
+        with fluid.scope_guard(scope):
+            if path == "chained":
+                return exe.run_chained(main, feed=_feed(),
+                                       fetch_list=[loss], steps=2)
+            return exe.run(target, feed=_feed(), fetch_list=[loss])
+
+    def compiles():
+        return len(monitor.recompile_events(recompiles_only=False))
+
+    prev = fluid.get_flags(["FLAGS_xla_options"])
+    try:
+        with fluid.scope_guard(scope):
+            exe.run(startup)
+        monitor.reset()
+        dispatch()
+        assert compiles() == 1
+        fluid.set_flags({"FLAGS_xla_options": "{}"})
+        dispatch()
+        assert compiles() == 1                       # same key: a hit
+        fluid.set_flags(
+            {"FLAGS_xla_options": "xla_cpu_enable_fast_min_max=true"})
+        assert np.isfinite(np.asarray(dispatch()[0])).all()
+        assert compiles() == 2
+        assert monitor.recompile_events()[-1].changed == ("xla_options",)
+        # an option the compiler does not know is refused by the compiler
+        fluid.set_flags({"FLAGS_xla_options": "no_such_xla_option=1"})
+        with pytest.raises(Exception, match="no_such_xla_option"):
+            dispatch()
+        fluid.set_flags({"FLAGS_xla_options": "not-a-pair"})
+        with pytest.raises(ValueError, match="is not k=v"):
+            dispatch()
+    finally:
+        fluid.set_flags(prev)
+
+
 def test_recompile_warns_after_threshold(caplog):
     fluid.set_flags({"FLAGS_recompile_warn_threshold": 2})
     try:

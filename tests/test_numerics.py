@@ -1,9 +1,7 @@
 """paddle_tpu.analysis.numerics — the PT900 range/precision linter
 (ISSUE 17 tentpole). Transfer-rule unit tests, a positive + negative
-(guarded) control per PT90x code, the PT906-superset-of-fusable-chains
-acceptance assertion, the QAT x epilogue-fusion pass-order contract
-(docs/ANALYSIS.md "Quantization and epilogue fusion"), and the
-numerics_check pass registration."""
+(guarded) control per PT90x code, and the numerics_check pass
+registration."""
 import importlib
 import math
 import os
@@ -15,9 +13,8 @@ import pytest
 import paddle_tpu as fluid
 import paddle_tpu.unique_name as un
 from paddle_tpu.analysis import ALL_ANALYSIS_PASSES, default_pass_manager
-from paddle_tpu.analysis.epilogue_fusion import fuse_epilogues
-from paddle_tpu.analysis.numerics import (FAKE_QUANT_TYPES, Interval,
-                                          NumericsReport, QUANT_SITE_TYPES,
+from paddle_tpu.analysis.numerics import (Interval, NumericsReport,
+                                          QUANT_SITE_TYPES,
                                           TOP, analyze_numerics,
                                           static_intervals)
 from paddle_tpu.contrib.slim.quantization import quant_aware
@@ -275,33 +272,6 @@ def test_pt906_sees_qat_annotations():
         "quant_aware — PT906 must see the annotation")
 
 
-def test_pt906_is_a_superset_of_fusable_chain_bases():
-    """Acceptance: every GEMM the epilogue-fusion pass can claim as a
-    chain base is in the PT906 work-list — the int8 PR never discovers a
-    fusable site the numerics report missed."""
-    for act in ("relu", "gelu"):
-        main, _startup, pred = _forward_mlp(act=act, width=128)
-        rep = analyze_numerics(main, fetch_names=[pred.name])
-        site_idxs = {s["op_idx"] for s in rep.quant_sites
-                     if s["block"] == 0}
-        decision = fuse_epilogues(main, fetch_names=[pred.name])
-        assert decision.applied and decision.n_fused == 2
-        # recover the chain bases from the ORIGINAL program: the fused
-        # ops' epilogue labels aside, every base op index must be a
-        # PT906 site
-        from paddle_tpu.analysis.liveness import block_liveness
-        from paddle_tpu.analysis.epilogue_fusion import find_fusable_chains
-        gb = main.global_block
-        feeds = sorted(v.name for v in gb.vars.values() if v.is_data)
-        live = block_liveness(gb, feeds, [pred.name])
-        chains = find_fusable_chains(main, live, [pred.name])
-        assert chains
-        for c in chains:
-            assert c.op_indices[0] in site_idxs, (
-                f"fusable base op {c.op_indices[0]} missing from the "
-                f"PT906 work-list {sorted(site_idxs)}")
-
-
 def test_calibration_is_tracked_separately_from_proofs():
     """Observed abs-max seeds flow but never enter the proven set — the
     witness containment surface stays calibration-free."""
@@ -328,44 +298,6 @@ def test_calibration_is_tracked_separately_from_proofs():
     rep2 = analyze_numerics(m2, calibration={"a": 1.5})
     (site,) = rep2.quant_sites
     assert site["calibrated_absmax"] == {"a": 1.5}
-
-
-# ---------------------------------------------------------------------------
-# QAT x epilogue fusion: the pass-order contract (docs/ANALYSIS.md)
-# ---------------------------------------------------------------------------
-
-def test_qat_then_fusion_keeps_the_pt900_contract():
-    """Legal order: quant_aware BEFORE epilogue fusion. The fused op is a
-    legal fake-quant consumer (QUANT_CONSUMER_TYPES), so PT900 holds on
-    the fused program too."""
-    with un.guard():
-        main, startup = fluid.Program(), fluid.Program()
-        with fluid.program_guard(main, startup):
-            x = fluid.layers.data("x", shape=[128], dtype="float32")
-            h = fluid.layers.fc(x, 128, act="relu")
-            pred = fluid.layers.fc(h, 128)
-            quant_aware(main, startup)
-    decision = fuse_epilogues(main, fetch_names=[pred.name])
-    assert decision.applied, decision.reason
-    fused = decision.program
-    types = [op.type for op in fused.global_block.ops]
-    assert "fused_gemm_epilogue" in types
-    assert any(t in FAKE_QUANT_TYPES for t in types), (
-        "fusion must not swallow the fake-quant annotations")
-    rep = analyze_numerics(fused, fetch_names=[pred.name])
-    assert "PT900" not in _codes(rep), [
-        d.message for d in _findings(rep, "PT900")]
-
-
-def test_fusion_then_qat_refuses_loudly():
-    """Illegal order: quantizing an already-fused program must raise —
-    the QAT pass cannot annotate operands a fused op swallowed."""
-    main, _startup, pred = _forward_mlp(width=128)
-    decision = fuse_epilogues(main, fetch_names=[pred.name])
-    assert decision.applied
-    startup = fluid.Program()
-    with pytest.raises(ValueError, match="BEFORE epilogue fusion"):
-        quant_aware(decision.program, startup)
 
 
 # ---------------------------------------------------------------------------

@@ -72,7 +72,6 @@ def test_lowering_platform_reads_the_mesh_then_the_ctx():
 
 def test_routes_key_on_the_lowering_platform():
     from paddle_tpu.ops.fused_attention import _route
-    from paddle_tpu.ops.fused_gemm import fused_gemm_route
     from paddle_tpu.ops.generation import _route_decode
     from paddle_tpu.ops.nn import _use_nhwc
 
@@ -80,9 +79,6 @@ def test_routes_key_on_the_lowering_platform():
                             (None, "primitive")):
         assert _route(512, 512, 0.1, platform=platform) == route
         assert _route_decode(1024, 128, q_len=8, platform=platform) == route
-        assert fused_gemm_route(256, 256, 256, layer_norm=False,
-                                blocks=(128, 128, 128),
-                                platform=platform)[0] == route
         assert _use_nhwc(LowerCtx(platform=platform)) == (platform == "tpu")
     # past the kernel's 8-row tile a chunk rides the primitive path
     assert _route_decode(1024, 128, q_len=128, platform="tpu") == "primitive"

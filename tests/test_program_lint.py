@@ -63,7 +63,6 @@ def test_every_code_is_documented_and_tested():
     # the CODES table is the single source of truth; this file (or
     # test_pass_manager.py, which owns the PT70x-PT72x pass-manager
     # families, test_sharding_check.py, which owns PT73x,
-    # test_epilogue_fusion.py, which owns PT75x,
     # test_concurrency_lint.py, which owns the source-level PT80x
     # family, or test_numerics.py, which owns the PT90x numerics
     # family) must cover every code
@@ -77,8 +76,6 @@ def test_every_code_is_documented_and_tested():
                                "test_pass_manager.py"),
                   os.path.join(os.path.dirname(here),
                                "test_sharding_check.py"),
-                  os.path.join(os.path.dirname(here),
-                               "test_epilogue_fusion.py"),
                   os.path.join(os.path.dirname(here),
                                "test_concurrency_lint.py"),
                   os.path.join(os.path.dirname(here),

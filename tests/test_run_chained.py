@@ -1,9 +1,8 @@
 """Executor.run_chained — K scanned steps must equal K separate run() calls.
 
 This is the compiled-train-loop role (reference trainer.cc RunFromDataset
-runs the loop outside Python) and the measurement substrate for bench.py:
-iterations inside one dispatch are serialized by while-loop semantics, so
-timing it measures compute, not dispatch rate.
+runs the loop outside Python); the generative engine decodes a chunk of
+tokens through it.
 """
 import numpy as np
 

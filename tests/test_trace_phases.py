@@ -15,8 +15,7 @@ import paddle_tpu.layers as layers
 import paddle_tpu.unique_name as un
 from paddle_tpu import monitor, serving, trace
 from paddle_tpu.framework import Program, program_guard
-from paddle_tpu.kernels import (flash_attention, flash_attention_decode,
-                                fused_gemm)
+from paddle_tpu.kernels import flash_attention, flash_attention_decode
 from paddle_tpu.models.gpt import GptConfig, build_gpt_generative
 
 EXECUTOR_PHASES = {"executor.bind", "executor.feed", "executor.step",
@@ -265,9 +264,6 @@ KERNEL_CALLS = {
                 q, k, v, np.array([5], np.int32), num_heads=2, page_size=8,
                 interpret=True))(jnp.ones((2, 1, 64)), jnp.ones((2, 32, 64)),
                                  jnp.ones((2, 32, 64))),
-        "fused_gemm": lambda: jax.make_jaxpr(
-            lambda x, y: fused_gemm(x, y, interpret=True))(
-            jnp.ones((128, 128)), jnp.ones((128, 128))),
     },
     "grad": dict.fromkeys(
         ("flash_attention_fwd", "flash_attention_bwd_dq",

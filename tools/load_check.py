@@ -1168,10 +1168,10 @@ def leg_fleet_telemetry(name, ci, log_dir="."):
 # fair-share — docs/SERVING.md "Fleet control loop". A supervised fleet
 # behind the router plus a FleetAutoscaler: a hot-tenant flood must burn
 # the SLO, scale OUT a second replica warm through the fleet-shared AOT
-# cache AND the fleet-shared autotune CostDatabase, shed the hot tenant
-# typed tenant_quota while innocent tenants keep completing, then scale
-# back IN strictly via preemption-drain once calm — fleet ledger exact
-# throughout, every decision typed/metered/audited.
+# cache, shed the hot tenant typed tenant_quota while innocent tenants
+# keep completing, then scale back IN strictly via preemption-drain once
+# calm — fleet ledger exact throughout, every decision typed/metered/
+# audited.
 # ---------------------------------------------------------------------------
 
 _AUTOSCALE_REPLICA_ARGS = [
@@ -1189,46 +1189,6 @@ _AUTOSCALE_SLO_FLAGS = [
     # burn -> recover round trip fits one CI leg
     "--set-flag", "FLAGS_serving_slo_fast_window_s=2",
     "--set-flag", "FLAGS_serving_slo_slow_window_s=6"]
-
-
-def _seed_shared_autotune_db(db_path):
-    """Populate the fleet-shared autotune CostDatabase IN-PROCESS with a
-    real (tiny) measured sweep over the replica probe's warm-up buckets.
-    ``build_probe`` guarantees the program CONTENT fingerprint matches
-    what every replica process builds, so a replica spawned with
-    ``FLAGS_autotune=use`` + this DB warms straight to best-known
-    configs: lookups hit, zero re-trials. (measure_candidates is not
-    safe under live traffic — which is exactly why the harness seeds the
-    DB offline and the fleet only ever consumes it.)"""
-    from paddle_tpu import tuning
-    from paddle_tpu.core.types import np_dtype
-    from paddle_tpu.serving.fleet.replica import build_probe
-
-    fluid.set_flags({"FLAGS_autotune": "measure",
-                     "FLAGS_autotune_db": db_path})
-    tuning.reset_database_cache()
-    eng, _meta = build_probe("mlp_tiny", serving.ServingConfig(max_batch=4))
-    db = tuning.get_database(db_path)
-    candidates = [tuning.TunedConfig.make({}),
-                  tuning.TunedConfig.make(
-                      {"xla_cpu_enable_fast_min_max": True})]
-    blk = eng._program.global_block
-    buckets = []
-    for b in (1, 2, 4):   # the warm-up buckets for max_batch=4
-        feed = {}
-        for n in eng._feed_names:
-            v = blk.var(n)
-            tail = tuple(int(d) for d in v.shape[1:])
-            feed[n] = np.zeros((b,) + tail, dtype=np_dtype(v.dtype))
-        rep = tuning.measure_candidates(
-            eng._exe, eng._program, feed, eng._fetch_names, eng._scope,
-            candidates=candidates, k_short=1, k_long=2, repeats=1,
-            batch_rows=b, db=db)
-        buckets.append(rep["bucket"])
-    # this process is done measuring; the fleet consumes in use mode
-    fluid.set_flags({"FLAGS_autotune": "use"})
-    return {"path": db_path, "trials": db.trial_count(),
-            "buckets": buckets}
 
 
 def _drive_autoscale_burst(router, stop_ev, pause_ev=None, hog_threads=8,
@@ -1317,43 +1277,32 @@ def _drive_autoscale_burst(router, stop_ev, pause_ev=None, hog_threads=8,
 
 def leg_autoscale(name, ci, log_dir="."):
     """--autoscale: the closed fleet control loop, end to end over
-    processes. One supervised replica starts COLD (empty AOT cache, but
-    the harness-seeded shared autotune DB); a hot-tenant flood burns the
-    SLO budget through typed tenant_quota sheds; the FleetAutoscaler
-    must scale out a second replica (warm: shared AOT cache + autotune
-    hits, zero re-trials, measurably faster time-to-ready than the cold
+    processes. One supervised replica starts COLD (empty AOT cache); a
+    hot-tenant flood burns the SLO budget through typed tenant_quota
+    sheds; the FleetAutoscaler must scale out a second replica (warm:
+    shared AOT cache, measurably faster time-to-ready than the cold
     baseline), refuse further scale-out typed at_max_replicas, and —
     once the burst stops and the squeezed burn windows drain — scale
     back in strictly via preemption-drain (victim exits 0 with an exact
     ledger) then hold the floor typed at_min_replicas. Innocent tenants
     must keep completing with their caller-side p99 held the whole
     time."""
-    from paddle_tpu import flags as flags_mod
     from paddle_tpu.serving.fleet import (AutoscalerConfig,
                                           FleetAutoscaler,
                                           ReplicaSupervisor,
                                           SupervisorConfig)
 
     aot_dir = tempfile.mkdtemp(prefix="paddle_tpu_autoscale_aot_")
-    db_dir = tempfile.mkdtemp(prefix="paddle_tpu_autoscale_db_")
-    db_path = os.path.join(db_dir, "autotune_db.json")
-    saved_overrides = dict(flags_mod._overrides)
     router = sup = auto = None
     stop_ev = threading.Event()
     threads = []
     try:
-        seeded = _seed_shared_autotune_db(db_path)
         replica_args = (_AUTOSCALE_REPLICA_ARGS + _AUTOSCALE_TENANT_FLAGS
                         + _AUTOSCALE_SLO_FLAGS)
         router = _chaos_router(request_timeout_s=30.0)
         sup = ReplicaSupervisor(
             router,
-            SupervisorConfig(
-                ready_timeout_s=240.0, exit_grace_s=60.0,
-                # the fleet-shared autotune story rides EVERY spawn —
-                # including the autoscaler's, which never mentions it
-                shared_flags={"FLAGS_autotune": "use",
-                              "FLAGS_autotune_db": db_path}),
+            SupervisorConfig(ready_timeout_s=240.0, exit_grace_s=60.0),
             log_dir=log_dir, env=_replica_env(), cwd=_REPO_ROOT)
         sup.add_replica("r0", "mlp_tiny", aot_dir,
                         extra_args=replica_args)
@@ -1447,12 +1396,6 @@ def leg_autoscale(name, ci, log_dir="."):
             "warm_loaded_from_aot_cache":
                 warm is not None and warm["aot_cache"]["hits"] >= 1
                 and warm["aot_cache"]["misses"] == 0,
-            "autotune_shared_db_hit":
-                warm is not None and warm["autotune"]["mode"] == "use"
-                and warm["autotune"]["hits"] >= 1,
-            "autotune_zero_retrials":
-                warm is not None and warm["autotune"]["trials"] == 0
-                and cold["autotune"]["trials"] == 0,
             "hot_tenant_shed_typed_tenant_quota":
                 hog.get("shed_tenant_quota", 0) >= 1,
             "innocent_tenants_kept_admitted":
@@ -1483,11 +1426,10 @@ def leg_autoscale(name, ci, log_dir="."):
         }
         warmstart = {
             "cold": {k: cold.get(k) for k in
-                     ("time_to_ready_s", "warm_up_s", "aot_cache",
-                      "autotune")},
+                     ("time_to_ready_s", "warm_up_s", "aot_cache")},
             "warm": ({k: warm.get(k) for k in
-                      ("time_to_ready_s", "warm_up_s", "aot_cache",
-                       "autotune")} if warm is not None else None),
+                      ("time_to_ready_s", "warm_up_s", "aot_cache")}
+                     if warm is not None else None),
             "ready_speedup": (cold["time_to_ready_s"]
                               / max(warm["time_to_ready_s"], 1e-9)
                               if warm is not None else None),
@@ -1496,7 +1438,7 @@ def leg_autoscale(name, ci, log_dir="."):
                 "requests": seen["submitted"], "caller_view": seen,
                 "router_accounting": acct,
                 "victim_accounting": victim_acct,
-                "tenants": per_tenant, "autotune_seed": seeded,
+                "tenants": per_tenant,
                 "warmstart": warmstart,
                 "innocent_latency": {"count": len(small_lat),
                                      "p99_s": p99},
@@ -1505,8 +1447,8 @@ def leg_autoscale(name, ci, log_dir="."):
                                "spawned": status["spawned"]},
                 "checks": checks,
                 "why": "hot-tenant SLO burn scales out warm (shared AOT "
-                       "cache + autotune DB, zero re-trials), the hog is "
-                       "shed typed tenant_quota while innocents hold, "
+                       "cache), the hog is shed typed tenant_quota "
+                       "while innocents hold, "
                        "calm scales back in via preemption-drain with "
                        "the fleet ledger exact, and every refusal is "
                        "typed + metered"}
@@ -1521,9 +1463,6 @@ def leg_autoscale(name, ci, log_dir="."):
         if router is not None:
             router.stop()
         shutil.rmtree(aot_dir, ignore_errors=True)
-        shutil.rmtree(db_dir, ignore_errors=True)
-        flags_mod._overrides.clear()
-        flags_mod._overrides.update(saved_overrides)
 
 
 def leg_autoscale_negative(name, ci, log_dir="."):
@@ -2114,8 +2053,7 @@ def main(argv=None) -> int:
                     help="run the fleet CONTROL-LOOP gate: a supervised "
                          "replica + FleetAutoscaler under a hot-tenant "
                          "flood — sustained SLO burn scales out a second "
-                         "replica warm (shared AOT cache + shared "
-                         "autotune DB, zero re-trials), the hog is shed "
+                         "replica warm (shared AOT cache), the hog is shed "
                          "typed tenant_quota while innocent tenants hold "
                          "their p99, calm scales back in strictly via "
                          "preemption-drain (ledger exact), and every "
@@ -2221,9 +2159,7 @@ def main(argv=None) -> int:
                 print(f"scale-out warm start: cold ready "
                       f"{ws['cold']['time_to_ready_s']:.2f}s -> warm "
                       f"{ws['warm']['time_to_ready_s']:.2f}s "
-                      f"(speedup {ws['ready_speedup']:.1f}x), autotune "
-                      f"hits={ws['warm']['autotune']['hits']} "
-                      f"trials={ws['warm']['autotune']['trials']}, "
+                      f"(speedup {ws['ready_speedup']:.1f}x), "
                       f"aot hits={ws['warm']['aot_cache']['hits']} "
                       f"misses={ws['warm']['aot_cache']['misses']}")
             for e in (l.get("autoscaler") or {}).get("audit", []):

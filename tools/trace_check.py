@@ -401,8 +401,7 @@ def leg_cost_model() -> dict:
         "cost_model_gflops_per_img": round(per_img_i / 1e9, 2),
         "analytic_gflops_per_img": 8.18,
         "ratio": round(per_img_i / 8.18e9, 3)}
-    # BERT-base pretrain: 6ND + the attention-score term (bench.py's
-    # analytic formula)
+    # BERT-base pretrain: 6ND + the attention-score term
     cfg = BertConfig.base()
     B, S = 8, 128
     with un.guard():
