@@ -346,8 +346,10 @@ def leg_bert(cfg=None, batch: int = 32, seq_len: int = 512) -> dict:
           f"loss fell over {steps} Adam steps on one batch "
           f"({losses[0]:.3f} -> {losses[-1]:.3f})")
     check(set(routes.get("bert.main", {})) ==
-          {"fused_multihead_attention:pallas"},
-          "every attention op of the train step took the Pallas route")
+          {"fused_multihead_attention:pallas",
+           "fused_multihead_attention_grad:pallas"},
+          "every attention op of the train step took the Pallas route, and "
+          "every gradient op rode the forward's saved residuals")
     tpu = exe.place.jax_device()
     homes = {d for v in scope.vars.values() if isinstance(v, jax.Array)
              for d in v.devices()}
@@ -610,9 +612,10 @@ def leg_multichip() -> dict:
               for k, n in route_totals().items()
               if n > routes_before.get(k, 0)}
     say(leg, f"routes taken in this leg: {routes}")
-    check(set(routes) == {"fused_multihead_attention:pallas"},
-          "on the meshes too every attention op took the Pallas route "
-          "(per shard, under shard_map)")
+    check(set(routes) == {"fused_multihead_attention:pallas",
+                          "fused_multihead_attention_grad:pallas"},
+          "on the meshes too every attention op and its gradient took the "
+          "Pallas route (per shard, under shard_map)")
     return {"ran": True}
 
 

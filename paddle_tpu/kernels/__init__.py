@@ -3,12 +3,13 @@ role (xbyak runtime codegen and hand-fused kernels) rebuilt as Mosaic
 kernels. Everything here must also run under `interpret=True` on CPU (minus
 PRNG-dependent paths) so numerics are testable without hardware."""
 from .flash_attention import (classify_shapes, flash_attention,
-                              flash_attention_with_lse, supports_shapes)
+                              flash_attention_bwd, flash_attention_with_lse,
+                              supports_shapes)
 from .decode_attention import (KERNEL_ROWS, decode_attention_reference,
                                decode_walk_blocks, flash_attention_decode,
                                paged_kv_append, paged_kv_append_rows)
 
-__all__ = ["flash_attention", "flash_attention_with_lse", "supports_shapes",
-           "classify_shapes", "flash_attention_decode", "paged_kv_append",
-           "paged_kv_append_rows", "KERNEL_ROWS", "decode_walk_blocks",
+__all__ = ["flash_attention", "flash_attention_with_lse",
+           "flash_attention_bwd", "supports_shapes", "classify_shapes",
+           "flash_attention_decode", "paged_kv_append", "paged_kv_append_rows", "KERNEL_ROWS", "decode_walk_blocks",
            "decode_attention_reference"]

@@ -45,8 +45,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["flash_attention", "flash_attention_with_lse", "supports_shapes",
-           "classify_shapes"]
+__all__ = ["flash_attention", "flash_attention_with_lse",
+           "flash_attention_bwd", "supports_shapes", "classify_shapes"]
 
 NEG_INF = -1e30          # finite sentinel: (-inf) - (-inf) would NaN
 
@@ -482,6 +482,14 @@ def _flash_fwd_rule(cfg, q, k, v, bias, scalars):
 def _flash_bwd_rule(cfg, res, cts):
     q, k, v, bias, scalars, o, lse = res
     do, dlse = cts
+    dq, dk, dv = _bwd_from_residuals(cfg, q, k, v, bias, scalars, o, lse,
+                                     do, dlse)
+    return dq, dk, dv, None, None
+
+
+def _bwd_from_residuals(cfg, q, k, v, bias, scalars, o, lse, do, dlse=None):
+    """(dQ, dK, dV) from what the forward kernel left: its output and its
+    log-sum-exp. No forward call."""
     if cfg.kv_group != 1:
         raise NotImplementedError(
             "flash attention backward with grouped-query heads: the dK/dV "
@@ -490,38 +498,18 @@ def _flash_bwd_rule(cfg, res, cts):
     # cotangent enters the same P-weighted term (d lse/dS = P), so it folds
     # in by subtraction.
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    delta = delta - dlse.astype(jnp.float32)
-    dq, dk, dv = _bwd(cfg, q, k, v, bias, scalars, do, lse, delta)
-    return dq, dk, dv, None, None
+    if dlse is not None:
+        delta = delta - dlse.astype(jnp.float32)
+    return _bwd(cfg, q, k, v, bias, scalars, do, lse, delta)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
-def flash_attention_with_lse(q, k, v, bias: Optional[jax.Array] = None,
-                             causal: bool = False,
-                             scale: Optional[float] = None,
-                             dropout_rate: float = 0.0,
-                             seed=0,
-                             q_offset=0, k_offset=0,
-                             num_heads: int = 1,
-                             block_q: int = 128, block_k: int = 128,
-                             interpret: bool = False, window: int = 0):
-    """Flash attention over [B*H, S, D] tensors; returns (O, lse).
-
-    ``bias`` is an additive [B, Sk] key bias (the padding-mask encoding —
-    models/bert.py builds (mask-1)*10000 exactly like this); ``num_heads``
-    tells the kernel how the leading B*H axis factors so bias rows map to
-    batches. ``q_offset``/``k_offset`` (may be traced scalars) shift the
-    causal comparison to GLOBAL positions for ring attention. ``lse`` is the
-    per-row log-sum-exp; its cotangent is honoured, so blockwise
-    combinations that re-weight through lse differentiate correctly.
-
-    ``window`` > 0 (with ``causal``) lets a query see only the last
-    ``window`` positions, itself included. ``k``/``v`` may have a whole
-    fraction of ``q``'s leading B*H rows (grouped-query heads, forward
-    only): query head ``n`` reads key/value head ``n // group``.
-    """
+def _prepare(q, k, bias, causal, scale, dropout_rate, seed, q_offset,
+             k_offset, num_heads, block_q, block_k, interpret, window):
+    """The checks, the static configuration and the scalar operands that
+    the forward call and a backward from saved residuals share."""
     BH, Sq, D = q.shape
     Sk = k.shape[1]
     if BH % k.shape[0] or (window and not causal):
@@ -549,9 +537,55 @@ def flash_attention_with_lse(q, k, v, bias: Optional[jax.Array] = None,
     scalars = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                          jnp.asarray(k_offset, jnp.int32),
                          jnp.asarray(seed, jnp.int32)])
-    return _flash(cfg, q, k, v,
-                  bias if bias is None else bias.astype(jnp.float32),
-                  scalars)
+    return cfg, bias if bias is None else bias.astype(jnp.float32), scalars
+
+
+def flash_attention_with_lse(q, k, v, bias: Optional[jax.Array] = None,
+                             causal: bool = False,
+                             scale: Optional[float] = None,
+                             dropout_rate: float = 0.0,
+                             seed=0,
+                             q_offset=0, k_offset=0,
+                             num_heads: int = 1,
+                             block_q: int = 128, block_k: int = 128,
+                             interpret: bool = False, window: int = 0):
+    """Flash attention over [B*H, S, D] tensors; returns (O, lse).
+
+    ``bias`` is an additive [B, Sk] key bias (the padding-mask encoding —
+    models/bert.py builds (mask-1)*10000 exactly like this); ``num_heads``
+    tells the kernel how the leading B*H axis factors so bias rows map to
+    batches. ``q_offset``/``k_offset`` (may be traced scalars) shift the
+    causal comparison to GLOBAL positions for ring attention. ``lse`` is the
+    per-row log-sum-exp; its cotangent is honoured, so blockwise
+    combinations that re-weight through lse differentiate correctly.
+
+    ``window`` > 0 (with ``causal``) lets a query see only the last
+    ``window`` positions, itself included. ``k``/``v`` may have a whole
+    fraction of ``q``'s leading B*H rows (grouped-query heads, forward
+    only): query head ``n`` reads key/value head ``n // group``.
+    """
+    cfg, bias, scalars = _prepare(q, k, bias, causal, scale, dropout_rate,
+                                  seed, q_offset, k_offset, num_heads,
+                                  block_q, block_k, interpret, window)
+    return _flash(cfg, q, k, v, bias, scalars)
+
+
+def flash_attention_bwd(q, k, v, o, lse, do,
+                        bias: Optional[jax.Array] = None,
+                        causal: bool = False, scale: Optional[float] = None,
+                        dropout_rate: float = 0.0, seed=0,
+                        num_heads: int = 1, block_q: int = 128,
+                        block_k: int = 128, interpret: bool = False,
+                        window: int = 0):
+    """(dQ, dK, dV) of :func:`flash_attention` for the cotangent ``do``,
+    from the ``(o, lse)`` that :func:`flash_attention_with_lse` returned
+    for the same operands, options and ``seed``: the two backward kernels
+    alone, no forward call. What ``jax.vjp`` over the forward computes,
+    for a caller that kept the residuals itself."""
+    cfg, bias, scalars = _prepare(q, k, bias, causal, scale, dropout_rate,
+                                  seed, 0, 0, num_heads, block_q, block_k,
+                                  interpret, window)
+    return _bwd_from_residuals(cfg, q, k, v, bias, scalars, o, lse, do)
 
 
 def flash_attention(q, k, v, bias: Optional[jax.Array] = None,

@@ -404,9 +404,15 @@ def fused_multihead_attention(q, k, v, bias_qk=None, causal=False,
     path; without an sp axis it degrades to the plain fused path.
 
     k/v may carry a whole fraction of q's heads (grouped-query attention,
-    inference only); window > 0 with causal is a sliding window."""
+    inference only); window > 0 with causal is a sliding window.
+
+    The op also keeps the softmax's log-sum-exp ([B, num_heads, S],
+    float32) for its gradient op, as layer_norm keeps Mean/Variance; a
+    forward-only program never computes with it."""
     helper = LayerHelper("fused_multihead_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
+    lse = helper.create_variable_for_type_inference("float32",
+                                                    stop_gradient=True)
     inputs = {"Q": q, "K": k, "V": v}
     if bias_qk is not None:
         inputs["BiasQK"] = bias_qk
@@ -415,7 +421,9 @@ def fused_multihead_attention(q, k, v, bias_qk=None, causal=False,
     if window:
         attrs["window"] = int(window)
     helper.append_op("fused_multihead_attention", inputs=inputs,
-                     outputs={"Out": out}, attrs=attrs)
+                     outputs={"Out": out, "SoftmaxLse": lse}, attrs=attrs)
+    if q.shape is not None:     # only the kernel routes emit it to infer from
+        lse.shape = tuple(q.shape[:3])
     return out
 
 

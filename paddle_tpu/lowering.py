@@ -69,6 +69,15 @@ def _amp_policy_of(ctx) -> Optional[AmpPolicy]:
     return getattr(ctx.program, "_amp_policy", None) if ctx.program else None
 
 
+def amp_cast_ins(ctx, op_type: str, ins: Dict[str, List[Any]]):
+    """``ins`` as the program's AMP policy hands them to an ``op_type``
+    rule (unchanged without a policy). A grad rule that calls kernels
+    itself casts its forward operands through this, so forward and
+    backward cannot disagree on operand type."""
+    amp = _amp_policy_of(ctx)
+    return ins if amp is None else amp.cast_ins(op_type, ins)
+
+
 class LowerCtx:
     """Context passed to every op lowering rule."""
 
@@ -171,6 +180,10 @@ def _gather_inputs(op, env: Dict[str, Any]) -> Dict[str, List[Any]]:
                 vals.append(None)
             elif n in env:
                 vals.append(env[n])
+            elif slot.startswith("__out__"):
+                # a grad op's echo of a forward output the forward rule did
+                # not emit on the route it took (an optional residual)
+                vals.append(None)
             else:
                 raise KeyError(
                     f"op {op.type}: input var '{n}' (slot {slot}) not found in "
@@ -249,10 +262,7 @@ def _lower_op_inner(op, env: Dict[str, Any], ctx: LowerCtx) -> None:
         op_ctx.num_taps = None  # same tracer-escape rule as nan_checks
         opdef.lower(op_ctx, op, env)
         return
-    ins = _gather_inputs(op, env)
-    amp = _amp_policy_of(ctx)
-    if amp is not None:
-        ins = amp.cast_ins(op.type, ins)
+    ins = amp_cast_ins(ctx, op.type, _gather_inputs(op, env))
     outs = opdef.lower(op_ctx, ins, op.attrs)
     _write_outputs(op, outs, env)
 
@@ -290,7 +300,8 @@ def _lower_generic_grad(op, env: Dict[str, Any], ctx: LowerCtx) -> None:
 
     Grad-op desc layout (see backward.py make_grad_op):
       inputs:  <slot>            forward inputs, per fwd schema
-               __out__<slot>     forward outputs (unused here; kept for parity)
+               __out__<slot>     forward outputs (unused by the vjp path; a
+                                 ``grad_lower`` may ride them as residuals)
                <slot>@GRAD       cotangents of forward outputs (may be @EMPTY@)
       outputs: <slot>@GRAD       grads of forward inputs (aligned, @EMPTY@ holes)
       attrs:   __fwd_type__, __fwd_uid__ + all forward attrs
@@ -319,10 +330,6 @@ def _lower_generic_grad(op, env: Dict[str, Any], ctx: LowerCtx) -> None:
         _write_outputs(op, outs, env)
         return
 
-    fwd_attrs = {k: v for k, v in op.attrs.items() if not k.startswith("__")}
-    fwd_attrs["__uid__"] = op.attrs.get("__fwd_uid__", 0)
-    fwd_ctx = ctx.with_uid(op.attrs.get("__fwd_uid__", 0))
-
     # Reconstruct forward inputs from the grad op's inputs.
     fwd_in_slots = [s.name for s in fwd_def.inputs if s.name in op.inputs]
     fwd_ins: Dict[str, List[Any]] = {}
@@ -346,16 +353,35 @@ def _lower_generic_grad(op, env: Dict[str, Any], ctx: LowerCtx) -> None:
     if not diff_pos:
         return
 
-    amp = _amp_policy_of(ctx)
+    # Cotangents: out-grad inputs where present.
+    out_grads = {
+        slot: [env.get(n) if n != EMPTY_VAR_NAME else None for n in names]
+        for slot, names in op.inputs.items() if slot.endswith("@GRAD")
+    }
+    _write_outputs(op, _vjp_forward_rule(ctx, fwd_def, fwd_ins, out_grads,
+                                         op.attrs, diff_pos), env)
+
+
+def _vjp_forward_rule(ctx: LowerCtx, fwd_def, fwd_ins, out_grads, attrs,
+                      diff_pos) -> Dict[str, List[Any]]:
+    """Differentiate ``fwd_def``'s forward rule at ``fwd_ins`` under
+    ``jax.vjp``: gradients of the ``diff_pos`` input positions
+    ``(slot, idx)`` for the cotangents ``out_grads``
+    (``{<out slot>@GRAD: [value or None]}``; absent ones are zeros), as
+    ``{<in slot>@GRAD: [grad or None]}`` aligned with ``fwd_ins``.
+    ``attrs`` are the grad op's."""
+    fwd_type = fwd_def.type
+    fwd_attrs = {k: v for k, v in attrs.items() if not k.startswith("__")}
+    fwd_attrs["__uid__"] = attrs.get("__fwd_uid__", 0)
+    fwd_ctx = ctx.with_uid(attrs.get("__fwd_uid__", 0))
 
     def fwd_fn(diff_vals):
         ins2 = {s: list(vs) for s, vs in fwd_ins.items()}
         for (slot, i), v in zip(diff_pos, diff_vals):
             ins2[slot][i] = v
-        if amp is not None:
-            # cast INSIDE the vjp'd function: primals stay fp32, so the
-            # returned gradients are fp32 toward the master weights
-            ins2 = amp.cast_ins(fwd_type, ins2)
+        # cast INSIDE the vjp'd function: primals stay fp32, so the
+        # returned gradients are fp32 toward the master weights
+        ins2 = amp_cast_ins(ctx, fwd_type, ins2)
         outs = fwd_def.lower(fwd_ctx, ins2, fwd_attrs)
         # flatten only inexact outputs, in schema order, tracking identity
         flat, keys = [], []
@@ -379,10 +405,8 @@ def _lower_generic_grad(op, env: Dict[str, Any], ctx: LowerCtx) -> None:
     # Cotangents: out-grad inputs where present, zeros elsewhere.
     cts = []
     for (oslot, i), val in zip(keys, flat_outs):
-        gnames = op.inputs.get(oslot + "@GRAD", [])
-        g = None
-        if i < len(gnames) and gnames[i] != EMPTY_VAR_NAME:
-            g = env.get(gnames[i])
+        gs = out_grads.get(oslot + "@GRAD", [])
+        g = gs[i] if i < len(gs) else None
         if g is None:
             g = jnp.zeros_like(val)
         else:
@@ -393,19 +417,29 @@ def _lower_generic_grad(op, env: Dict[str, Any], ctx: LowerCtx) -> None:
         cts.append(g)
 
     (grads,) = vjp_fn(cts)
+    outs: Dict[str, List[Any]] = {}
+    for (slot, i), g in zip(diff_pos, grads):
+        outs.setdefault(slot + "@GRAD", [None] * len(fwd_ins[slot]))[i] = g
+    return outs
 
-    # Write input grads.
-    grad_map = dict(zip(diff_pos, grads))
-    for slot in fwd_in_slots:
-        out_names = op.outputs.get(slot + "@GRAD")
-        if not out_names:
-            continue
-        for i, gname in enumerate(out_names):
-            if gname == EMPTY_VAR_NAME:
-                continue
-            g = grad_map.get((slot, i))
-            if g is not None:
-                env[gname] = g
+
+def generic_grad(ctx: LowerCtx, fwd_type: str, ins: Dict[str, List[Any]],
+                 attrs) -> Dict[str, List[Any]]:
+    """What the generic ``<fwd>_grad`` lowering computes, in the form a
+    non-raw ``grad_lower`` rule returns: for a rule that rides saved
+    residuals where it can and differentiates the forward rule where it
+    cannot. ``ins`` and ``attrs`` are the grad op's, ``ctx`` the one the
+    rule was given. Every inexact input of a slot that takes a gradient is
+    differentiated; the lowering drops what the op does not list."""
+    fwd_def = registry.get_op_def(fwd_type)
+    fwd_ins = {s.name: list(ins[s.name]) for s in fwd_def.inputs
+               if s.name in ins}
+    diff_pos = [(s.name, i) for s in fwd_def.inputs
+                if s.name in fwd_ins and not s.no_grad
+                for i, v in enumerate(fwd_ins[s.name]) if _is_inexact(v)]
+    out_grads = {k: v for k, v in ins.items() if k.endswith("@GRAD")}
+    return _vjp_forward_rule(ctx, fwd_def, fwd_ins, out_grads, attrs,
+                             diff_pos)
 
 
 # ---------------------------------------------------------------------------

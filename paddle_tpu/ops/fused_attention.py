@@ -26,7 +26,8 @@ from jax.sharding import PartitionSpec as P
 
 from .common import IOSpec, register_op, x
 from .. import flags
-from ..lowering import lowering_platform, note_kernel_route
+from ..lowering import (amp_cast_ins, generic_grad, lowering_platform,
+                        note_kernel_route)
 
 
 def _route(sq: int, sk: int, dropout: float, platform=None) -> str:
@@ -82,18 +83,131 @@ def _primitive_attention(ctx, q, k, v, bias, causal, scale, dropout,
     return jnp.einsum("bqk,bkd->bqd", p.astype(v.dtype), v, precision=prec)
 
 
+class _Plan:
+    """What the forward rule and the gradient rule both read off one op
+    instance: checked shapes and resolved options."""
+
+    def __init__(self, ins, attrs):
+        q, k = x(ins, "Q"), x(ins, "K")
+        self.B, self.H, self.Sq, self.D = q.shape
+        self.Hkv, self.Sk = k.shape[1], k.shape[2]
+        self.scale = attrs["scale"] or float(self.D) ** -0.5
+        self.is_test = bool(attrs.get("is_test"))
+        self.dropout = 0.0 if self.is_test else float(attrs["attn_dropout"])
+        self.causal = bool(attrs["causal"])
+        self.window = int(attrs.get("window") or 0)
+        self.ring = bool(attrs.get("sequence_parallel"))
+        H, Hkv, window = self.H, self.Hkv, self.window
+        if H % Hkv or (window and not self.causal):
+            raise ValueError(
+                f"fused_multihead_attention: {H} query heads over {Hkv} "
+                f"key/value heads; window={window} needs causal")
+        if self.ring and (window or Hkv != H):
+            raise NotImplementedError(
+                "sequence_parallel attention with a window or grouped-query "
+                "heads: the ring path carries neither")
+
+    def rides_the_ring(self, mesh) -> bool:
+        """sequence_parallel under a mesh with a real 'sp' axis; without
+        one a 1-shard ring IS plain attention."""
+        return (self.ring and mesh is not None and "sp" in mesh.axis_names
+                and mesh.shape["sp"] > 1)
+
+    def route(self, ctx) -> str:
+        return _route(self.Sq, self.Sk, self.dropout,
+                      platform=lowering_platform(ctx))
+
+    def kernel_options(self, route) -> dict:
+        return dict(causal=self.causal, scale=self.scale,
+                    dropout=self.dropout, window=self.window,
+                    interpret=(route == "pallas-interpret"))
+
+
+def _key_bias(ins):
+    """BiasQK as the [B, S] the attention paths take."""
+    bias = x(ins, "BiasQK")
+    if bias is not None and bias.ndim == 4:          # [B, 1, 1, S]
+        return bias.reshape(bias.shape[0], bias.shape[-1])
+    if bias is not None and bias.ndim != 2:
+        raise ValueError(
+            f"BiasQK must be [B, S] or [B, 1, 1, S], got {bias.shape}")
+    return bias
+
+
+def _op_seed(ctx):
+    """Deterministic seed tied to the forward op instance: the grad op's
+    ctx carries the forward uid, so the backward kernels regenerate the
+    forward's dropout masks."""
+    return jax.lax.convert_element_type(
+        jax.random.bits(ctx.rng(), (), jnp.uint32) >> 1, jnp.int32)
+
+
+def _run_kernel(ctx, kernel, blocks, out_ranks):
+    """One device, or already inside a shard_map body (a pipeline stage):
+    the kernel sees its own block. Under a mesh: per shard."""
+    seed = _op_seed(ctx)
+    if ctx.mesh is None or jax.sharding.get_abstract_mesh().manual_axes:
+        return kernel(seed, *blocks)
+    return _kernel_on_mesh(kernel, ctx.mesh, seed, blocks, out_ranks)
+
+
+def _fused_mha_grad(ctx, ins, attrs):
+    """Gradients of Q, K, V from the residuals the forward op kept. Where
+    the forward ran the flash kernel and its ``SoftmaxLse`` is at hand, the
+    two backward kernels take ``__out__Out`` and that log-sum-exp: no
+    forward call. Anywhere else (the primitive route, ring attention, a
+    program built before the op had the output) the forward rule is
+    differentiated as for any op (``lowering.generic_grad``)."""
+    plan = _Plan(ins, attrs)
+    o, lse, do = (x(ins, "__out__Out"), x(ins, "__out__SoftmaxLse"),
+                  x(ins, "Out@GRAD"))
+    route = "primitive"
+    if all(t is not None for t in (o, lse, do)) \
+            and not plan.rides_the_ring(ctx.mesh):
+        route = plan.route(ctx)
+    note_kernel_route(ctx, "fused_multihead_attention_grad", route)
+    if route == "primitive":
+        return generic_grad(ctx, "fused_multihead_attention", ins, attrs)
+
+    # the operands as the forward rule saw them; the gradients go back to
+    # the types the program holds
+    seen = amp_cast_ins(ctx, "fused_multihead_attention",
+                        {s: ins[s] for s in ("Q", "K", "V", "BiasQK")
+                         if s in ins})
+    kernel = functools.partial(_kernel_attention_bwd,
+                               **plan.kernel_options(route))
+    grads = _run_kernel(
+        ctx, kernel,
+        [x(seen, "Q"), x(seen, "K"), x(seen, "V"), _key_bias(seen), o, lse,
+         do.astype(o.dtype).reshape(o.shape)],
+        out_ranks=(4, 4, 4))
+    return {s + "@GRAD": [g.astype(x(ins, s).dtype)]
+            for s, g in zip(("Q", "K", "V"), grads)}
+
+
 @register_op("fused_multihead_attention",
              inputs=[IOSpec("Q"), IOSpec("K"), IOSpec("V"),
                      IOSpec("BiasQK", optional=True, no_grad=True)],
-             outputs=["Out"],
+             outputs=["Out",
+                      IOSpec("SoftmaxLse", optional=True, no_grad=True)],
              attrs={"causal": False, "scale": 0.0, "attn_dropout": 0.0,
                     "is_test": False, "sequence_parallel": False,
                     "window": 0},
-             needs_rng=True)
+             needs_rng=True, grad_lower=_fused_mha_grad)
 def _fused_mha(ctx, ins, attrs):
     """Q/K/V: [B, num_heads, S, head_dim]. BiasQK: additive key bias,
     [B, S] or [B, 1, 1, S] (the models/bert.py padding-mask encoding).
     scale 0.0 means 1/sqrt(head_dim).
+
+    ``SoftmaxLse`` ([B, num_heads, S], float32; optional) is the softmax's
+    log-sum-exp per query row, kept for the gradient op as ``layer_norm``
+    keeps ``Mean``/``Variance``. Only the flash-kernel routes emit it (the
+    kernel writes it anyway); ``fused_multihead_attention_grad`` reads it
+    with ``Out`` and then runs the backward kernels alone. On the
+    primitive route, under ring attention, or in a program whose op lacks
+    the output, the gradient differentiates this rule instead
+    (``kernel_route_total{op="fused_multihead_attention_grad"}`` says
+    which, per program).
 
     ``sequence_parallel=True`` lowers onto ring attention over the mesh's
     'sp' axis (parallel/ring_attention.py — K/V blocks rotate via
@@ -106,122 +220,120 @@ def _fused_mha(ctx, ins, attrs):
     ``n // group``. ``window`` > 0 (with ``causal``) is a sliding window:
     key ``j`` is visible to query ``i`` iff ``0 <= i - j < window``."""
     q, k, v = x(ins, "Q"), x(ins, "K"), x(ins, "V")
-    bias = x(ins, "BiasQK")
+    plan = _Plan(ins, attrs)
     B, H, Sq, D = q.shape
-    Hkv, Sk = k.shape[1], k.shape[2]
-    scale = attrs["scale"] or float(D) ** -0.5
-    dropout = 0.0 if attrs.get("is_test") else float(attrs["attn_dropout"])
-    causal = bool(attrs["causal"])
-    window = int(attrs.get("window") or 0)
-    if H % Hkv or (window and not causal):
-        raise ValueError(
-            f"fused_multihead_attention: {H} query heads over {Hkv} "
-            f"key/value heads; window={window} needs causal")
-    if attrs.get("sequence_parallel") and (window or Hkv != H):
-        raise NotImplementedError(
-            "sequence_parallel attention with a window or grouped-query "
-            "heads: the ring path carries neither")
 
-    if attrs.get("sequence_parallel"):
-        mesh = ctx.mesh
-        if mesh is not None and "sp" in mesh.axis_names \
-                and mesh.shape["sp"] > 1:
-            if bias is not None:
-                raise NotImplementedError(
-                    "sequence_parallel attention with BiasQK: fold padding "
-                    "into the sequence instead — the ring path has no "
-                    "global [B, S] bias plumbing yet")
-            if dropout > 0.0:
-                raise NotImplementedError(
-                    "sequence_parallel attention with attn_dropout>0: the "
-                    "ring path's per-block kernels do not coordinate a "
-                    "global dropout mask")
-            from ..parallel.ring_attention import ring_attention
+    if plan.rides_the_ring(ctx.mesh):
+        if x(ins, "BiasQK") is not None:
+            raise NotImplementedError(
+                "sequence_parallel attention with BiasQK: fold padding "
+                "into the sequence instead — the ring path has no "
+                "global [B, S] bias plumbing yet")
+        if plan.dropout > 0.0:
+            raise NotImplementedError(
+                "sequence_parallel attention with attn_dropout>0: the "
+                "ring path's per-block kernels do not coordinate a "
+                "global dropout mask")
+        from ..parallel.ring_attention import ring_attention
 
-            o = ring_attention(q.transpose(0, 2, 1, 3),
-                               k.transpose(0, 2, 1, 3),
-                               v.transpose(0, 2, 1, 3),
-                               mesh, seq_axis="sp", causal=causal,
-                               scale=scale)
-            return {"Out": [o.transpose(0, 2, 1, 3)]}
-        # no mesh / degenerate sp axis: a 1-shard ring IS plain attention
+        o = ring_attention(q.transpose(0, 2, 1, 3),
+                           k.transpose(0, 2, 1, 3),
+                           v.transpose(0, 2, 1, 3),
+                           ctx.mesh, seq_axis="sp", causal=plan.causal,
+                           scale=plan.scale)
+        return {"Out": [o.transpose(0, 2, 1, 3)]}
 
-    if bias is not None:
-        if bias.ndim == 4:          # [B, 1, 1, S]
-            bias = bias.reshape(bias.shape[0], bias.shape[-1])
-        elif bias.ndim != 2:
-            raise ValueError(
-                f"BiasQK must be [B, S] or [B, 1, 1, S], got {bias.shape}")
-
-    route = _route(Sq, Sk, dropout, platform=lowering_platform(ctx))
+    route = plan.route(ctx)
     note_kernel_route(ctx, "fused_multihead_attention", route)
     if route == "primitive":
         o = _primitive_attention(ctx, q.reshape(B * H, Sq, D),
-                                 k.reshape(B * Hkv, Sk, D),
-                                 v.reshape(B * Hkv, Sk, D), bias, causal,
-                                 scale, dropout, attrs.get("is_test", False),
-                                 window)
+                                 k.reshape(B * plan.Hkv, plan.Sk, D),
+                                 v.reshape(B * plan.Hkv, plan.Sk, D),
+                                 _key_bias(ins), plan.causal, plan.scale,
+                                 plan.dropout, plan.is_test, plan.window)
         return {"Out": [o.reshape(B, H, Sq, D)]}
 
-    # deterministic seed tied to this op instance: the grad op folds in
-    # the forward uid, so backward regenerates identical dropout masks
-    seed = jax.lax.convert_element_type(
-        jax.random.bits(ctx.rng(), (), jnp.uint32) >> 1, jnp.int32)
-    kernel = functools.partial(
-        _kernel_attention, causal=causal, scale=scale, dropout=dropout,
-        interpret=(route == "pallas-interpret"), window=window)
-    # one device, or already inside a shard_map body (a pipeline stage):
-    # the kernel sees its own block either way
-    if ctx.mesh is None or jax.sharding.get_abstract_mesh().manual_axes:
-        return {"Out": [kernel(seed, q, k, v, bias)]}
-    return {"Out": [_kernel_attention_on_mesh(kernel, ctx.mesh, seed,
-                                              q, k, v, bias)]}
+    kernel = functools.partial(_kernel_attention,
+                               **plan.kernel_options(route))
+    o, lse = _run_kernel(ctx, kernel, [q, k, v, _key_bias(ins)],
+                         out_ranks=(4, 3))
+    return {"Out": [o], "SoftmaxLse": [lse]}
 
 
 def _kernel_attention(seed, q, k, v, bias=None, *, causal, scale, dropout,
                       interpret, window=0):
-    """The flash kernel over one [B, H, S, D] block (bias [B, Sk])."""
-    from ..kernels import flash_attention
+    """The flash kernel over one [B, H, S, D] block (bias [B, Sk]):
+    the output and its log-sum-exp [B, H, Sq]."""
+    from ..kernels import flash_attention_with_lse
 
     B, H, Sq, D = q.shape
     Hkv, Sk = k.shape[1], k.shape[2]
-    o = flash_attention(q.reshape(B * H, Sq, D), k.reshape(B * Hkv, Sk, D),
-                        v.reshape(B * Hkv, Sk, D), bias=bias, causal=causal,
-                        scale=scale, dropout_rate=dropout, seed=seed,
-                        num_heads=H, interpret=interpret, window=window)
-    return o.reshape(B, H, Sq, D)
+    o, lse = flash_attention_with_lse(
+        q.reshape(B * H, Sq, D), k.reshape(B * Hkv, Sk, D),
+        v.reshape(B * Hkv, Sk, D), bias=bias, causal=causal, scale=scale,
+        dropout_rate=dropout, seed=seed, num_heads=H, interpret=interpret,
+        window=window)
+    return o.reshape(B, H, Sq, D), lse.reshape(B, H, Sq)
 
 
-def _kernel_attention_on_mesh(kernel, mesh, seed, q, k, v, bias):
+def _kernel_attention_bwd(seed, q, k, v, bias, o, lse, do, *, causal, scale,
+                          dropout, interpret, window=0):
+    """The two backward kernels over one [B, H, S, D] block, from the
+    forward's saved ``o`` and ``lse``: (dQ, dK, dV)."""
+    from ..kernels import flash_attention_bwd
+
+    B, H, Sq, D = q.shape
+    Hkv, Sk = k.shape[1], k.shape[2]
+    dq, dk, dv = flash_attention_bwd(
+        q.reshape(B * H, Sq, D), k.reshape(B * Hkv, Sk, D),
+        v.reshape(B * Hkv, Sk, D), o.reshape(B * H, Sq, D),
+        lse.reshape(B * H, Sq), do.reshape(B * H, Sq, D), bias=bias,
+        causal=causal, scale=scale, dropout_rate=dropout, seed=seed,
+        num_heads=H, interpret=interpret, window=window)
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+
+
+def _kernel_on_mesh(kernel, mesh, seed, blocks, out_ranks):
     """Under a mesh the step is partitioned by GSPMD, and a Mosaic kernel
     cannot be ("Mosaic kernels cannot be automatically partitioned" — the
     first thing the four-chip host said): run it per shard under
     ``shard_map``. Attention is independent per batch row and per head, so
     the batch splits over 'dp' and the heads over 'tp' — the Megatron
     layout the q/k/v projections already produce — and no collective is
-    needed; an axis that does not divide its dim stays unsplit."""
-    B, H = q.shape[:2]
+    needed; an axis that does not divide its dim stays unsplit.
+
+    ``blocks`` are ``kernel``'s operands after the seed, ``None`` where an
+    optional one is absent; ``out_ranks`` the ranks of its results. A
+    [B, H, ...] operand follows the batch and the heads, a [B, S] bias the
+    batch."""
+    B, H = blocks[0].shape[:2]
 
     def axis(name, dim):
         return name if name in mesh.axis_names \
             and dim % mesh.shape[name] == 0 else None
 
     b_ax, h_ax = axis("dp", B), axis("tp", H)
-    spec = P(b_ax, h_ax, None, None)
 
-    def local(seed, *blocks):
+    def spec(rank):
+        return P(b_ax, None) if rank == 2 \
+            else P(b_ax, h_ax, *([None] * (rank - 2)))
+
+    present = [b for b in blocks if b is not None]
+
+    def local(seed, *shards):
         # a shard-local seed: shards must not share dropout masks
         for ax, mix in ((b_ax, 0x9E3779B1), (h_ax, 0x85EBCA77)):
             if ax is not None:
                 seed = seed ^ (jax.lax.axis_index(ax).astype(jnp.int32)
                                * jnp.int32(mix & 0x7FFFFFFF))
-        return kernel(seed, *blocks)
+        shards = iter(shards)
+        return kernel(seed, *[None if b is None else next(shards)
+                              for b in blocks])
 
-    args, specs = [seed, q, k, v], [P(), spec, spec, spec]
-    if bias is not None:
-        args.append(bias)
-        specs.append(P(b_ax, None))
     # check_vma off: the kernel's scalar operands vary per shard (see
     # parallel/ring_attention.py)
-    return jax.shard_map(local, mesh=mesh, in_specs=tuple(specs),
-                         out_specs=spec, check_vma=False)(*args)
+    return jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(P(), *[spec(b.ndim) for b in present]),
+        out_specs=tuple(spec(r) for r in out_ranks),
+        check_vma=False)(seed, *present)

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 import paddle_tpu as fluid
 import paddle_tpu.unique_name as un
+from paddle_tpu.core.types import np_dtype
 from paddle_tpu.kernels import flash_attention, flash_attention_with_lse
 
 RNG = np.random.RandomState(3)
@@ -184,6 +185,269 @@ def test_bert_attention_uses_fused_op():
     types = [op.type for op in model["main"].global_block.ops]
     assert types.count("fused_multihead_attention") == 2  # tiny: 2 layers
     assert "softmax" not in types  # attention softmax is inside the op
+
+
+# --------------------------------------------------------------------------
+# fused_multihead_attention_grad: the backward kernels on the forward op's
+# saved Out and SoftmaxLse; the generic vjp path wherever those are absent
+# --------------------------------------------------------------------------
+
+ATTN, ATTN_GRAD = "fused_multihead_attention", "fused_multihead_attention_grad"
+
+
+def _attention_grads(mode, use_bias=False, causal=False, window=0,
+                     keep_lse=True, mesh=None, kv_heads=2, seq_par=False,
+                     amp_attention=False, roundtrip=False, S=128):
+    """Out and the gradients of Q, K, V of ``mean(tanh(attention))``
+    through a Program and the executor, with the routes the program's
+    attention ops noted. ``keep_lse=False`` deletes the op's SoftmaxLse
+    output before the backward is appended: the program an older build
+    would have made, whose gradient op has only the generic path."""
+    from paddle_tpu import flags, monitor
+    from paddle_tpu.lowering import AmpPolicy
+    from paddle_tpu.parallel.sharding import make_mesh
+
+    flags.set_flags({"FLAGS_use_flash_attention": mode})
+    try:
+        with un.guard():
+            main, startup = fluid.Program(), fluid.Program()
+            with fluid.program_guard(main, startup):
+                q = fluid.layers.data("q", shape=[2, S, 32], dtype="float32")
+                k, v = (fluid.layers.data(n, shape=[kv_heads, S, 32],
+                                          dtype="float32") for n in "kv")
+                for t in (q, k, v):
+                    t.stop_gradient = False
+                m = fluid.layers.data("m", shape=[S], dtype="float32")
+                out = fluid.layers.fused_multihead_attention(
+                    q, k, v, bias_qk=m if use_bias else None, causal=causal,
+                    window=window, sequence_parallel=seq_par)
+                if not keep_lse:
+                    del main.global_block.ops[-1].outputs["SoftmaxLse"]
+                loss = fluid.layers.mean(fluid.layers.tanh(out))
+                grads = fluid.backward.calc_gradient([loss], [q, k, v])
+        if roundtrip:
+            main = fluid.Program.from_json(main.to_json())
+        if amp_attention:
+            main._amp_policy = AmpPolicy({ATTN}, ())
+        rng = np.random.RandomState(5)
+        feed = {"q": rng.randn(4, 2, S, 32).astype(np.float32)}
+        for n in "kv":
+            feed[n] = rng.randn(4, kv_heads, S, 32).astype(np.float32)
+        feed["m"] = np.where(rng.rand(4, S) > 0.3, 0.0,
+                             -10000.0).astype(np.float32)
+        prog = main if mesh is None else fluid.CompiledProgram(
+            main).with_data_parallel(places=make_mesh(mesh))
+        monitor.reset()
+        with fluid.scope_guard(fluid.Scope()):
+            res = fluid.Executor(fluid.CPUPlace()).run(
+                prog, feed=feed,
+                fetch_list=[out.name] + [g.name for g in grads])
+        return [np.asarray(r) for r in res], _routes(main)
+    finally:
+        flags.set_flags({"FLAGS_use_flash_attention": "auto"})
+
+
+def _routes(program):
+    """{(op, route): lowerings} of one program from kernel_route_total."""
+    from paddle_tpu import monitor
+
+    fam = monitor.get_registry().get("kernel_route_total")
+    return {(lab["op"], lab["route"]): int(n.value)
+            for lab, n in (fam.children() if fam else ())
+            if lab["program"] == str(program._serial)}
+
+
+@pytest.mark.parametrize("use_bias,causal,window", [
+    (False, False, 0), (True, False, 0), (False, True, 0), (True, True, 0),
+    (False, True, 64), (True, True, 64)])
+def test_grad_rule_equals_the_generic_vjp_bit_for_bit(use_bias, causal,
+                                                      window):
+    """The same kernels on bit-equal operands: riding the saved residuals
+    changes no digit of dQ, dK, dV, and drops the second forward call."""
+    kw = dict(use_bias=use_bias, causal=causal, window=window, S=256)
+    saved, routes = _attention_grads("always", **kw)
+    generic, old_routes = _attention_grads("always", keep_lse=False, **kw)
+    for a, b in zip(saved, generic):
+        np.testing.assert_array_equal(a, b)
+    assert routes == {(ATTN, "pallas-interpret"): 1,
+                      (ATTN_GRAD, "pallas-interpret"): 1}
+    assert old_routes == {(ATTN, "pallas-interpret"): 2,
+                          (ATTN_GRAD, "primitive"): 1}
+
+
+def test_grad_rule_casts_operands_as_the_amp_policy_does():
+    """Were attention on the AMP white list, the rule must hand the
+    kernels the operands the forward saw (bf16) and return f32 grads."""
+    saved, routes = _attention_grads("always", amp_attention=True,
+                                     use_bias=True)
+    generic, _ = _attention_grads("always", amp_attention=True,
+                                  use_bias=True, keep_lse=False)
+    assert routes[(ATTN_GRAD, "pallas-interpret")] == 1
+    assert saved[0].dtype == jnp.bfloat16
+    for a, b in zip(saved[1:], generic[1:]):
+        assert a.dtype == np.float32
+        np.testing.assert_array_equal(a, b)
+
+
+def _pallas_calls(jaxpr, out=None):
+    out = {} if out is None else out
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            name = eqn.params["name"]
+            out[name] = out.get(name, 0) + 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _pallas_calls(sub, out)
+    return out
+
+
+@pytest.mark.parametrize("amp", [False, True])
+def test_bert_training_step_runs_the_forward_kernel_once_a_layer(amp):
+    """A 2-layer BERT step holds one forward and one of each backward
+    kernel per layer (it held two forwards a layer while the grad op
+    differentiated the forward rule), and the counter says every backward
+    rode the saved residuals."""
+    from paddle_tpu import flags, monitor
+    from paddle_tpu.models.bert import BertConfig, build_bert_pretrain
+
+    import dataclasses
+
+    # the in-kernel dropout has no interpret-mode lowering
+    cfg = dataclasses.replace(BertConfig.tiny(), attention_dropout=0.0)
+    flags.set_flags({"FLAGS_use_flash_attention": "always"})
+    try:
+        with un.guard():
+            model = build_bert_pretrain(cfg, seq_len=128, amp=amp)
+        main = model["main"]
+        feeds = {n: v for n, v in main.global_block.vars.items()
+                 if getattr(v, "is_data", False)}
+        exe, scope = fluid.Executor(fluid.CPUPlace()), fluid.Scope()
+        monitor.reset()
+        with fluid.scope_guard(scope):
+            exe.run(model["startup"])
+            step = exe._compile(main, set(feeds), [model["loss"].name],
+                                scope)
+            args = ([jax.ShapeDtypeStruct((2,) + tuple(feeds[n].shape[1:]),
+                                          np.dtype(feeds[n].dtype))
+                     for n in step.feed_names],
+                    [scope.find_var(n) for n in step.donated_names],
+                    [scope.find_var(n) for n in step.ro_names],
+                    jax.random.key(0))
+            calls = _pallas_calls(step.fn.trace(*args).jaxpr.jaxpr)
+    finally:
+        flags.set_flags({"FLAGS_use_flash_attention": "auto"})
+    assert calls == {"flash_attention_fwd": cfg.num_layers,
+                     "flash_attention_bwd_dq": cfg.num_layers,
+                     "flash_attention_bwd_dkv": cfg.num_layers}
+    assert _routes(main) == {
+        (ATTN, "pallas-interpret"): cfg.num_layers,
+        (ATTN_GRAD, "pallas-interpret"): cfg.num_layers}
+
+
+@pytest.mark.parametrize("case", ["primitive", "ring"])
+def test_grad_rule_falls_back_where_no_kernel_ran(case):
+    """The primitive route and ring attention keep no log-sum-exp: their
+    gradient is the forward rule's vjp, as before the op had a rule."""
+    kw = (dict(mode="never", use_bias=True) if case == "primitive" else
+          dict(mode="always", causal=True, seq_par=True,
+               mesh={"dp": 2, "sp": 2}))
+    with_slot, routes = _attention_grads(**kw)
+    without, _ = _attention_grads(keep_lse=False, **kw)
+    for a, b in zip(with_slot, without):
+        np.testing.assert_array_equal(a, b)
+    assert routes[(ATTN_GRAD, "primitive")] == 1
+    assert (ATTN_GRAD, "pallas-interpret") not in routes
+    # today's gradients: the plain path's, whatever route computed them
+    plain, _ = _attention_grads("never", use_bias=case == "primitive",
+                                causal=case == "ring")
+    for a, b in zip(with_slot, plain):
+        np.testing.assert_allclose(a, b, atol=2e-6, rtol=2e-4)
+
+
+def test_program_without_the_saved_lse_still_differentiates():
+    """A program saved before the op had SoftmaxLse: loaded back, its
+    gradient op finds no residual and differentiates the forward rule."""
+    old, routes = _attention_grads("always", use_bias=True, keep_lse=False,
+                                   roundtrip=True)
+    new, _ = _attention_grads("always", use_bias=True, roundtrip=True)
+    assert routes == {(ATTN, "pallas-interpret"): 2,
+                      (ATTN_GRAD, "primitive"): 1}
+    for a, b in zip(old, new):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_grad_rule_refuses_grouped_query_heads():
+    with pytest.raises(Exception, match="backward with grouped-query heads"):
+        _attention_grads("always", causal=True, kv_heads=1)
+    # the primitive route sums over a group's heads itself
+    grads, _ = _attention_grads("never", causal=True, kv_heads=1)
+    assert grads[2].shape == (4, 1, 128, 32)
+
+
+def test_grad_rule_under_a_dp_tp_mesh_equals_one_device():
+    """Four virtual devices, batch over dp and heads over tp: the backward
+    kernels run per shard as the forward does, on the shard's own rows of
+    the saved Out and SoftmaxLse."""
+    one, _ = _attention_grads("always", use_bias=True)
+    four, routes = _attention_grads("always", use_bias=True,
+                                    mesh={"dp": 2, "tp": 2})
+    assert routes == {(ATTN, "pallas-interpret"): 1,
+                      (ATTN_GRAD, "pallas-interpret"): 1}
+    for a, b in zip(four, one):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_verifier_accepts_the_grad_op_with_its_residuals():
+    from paddle_tpu.analysis import Severity, verify_program
+    from paddle_tpu.models.bert import BertConfig, build_bert_pretrain
+
+    with un.guard():
+        model = build_bert_pretrain(BertConfig.tiny(), seq_len=128)
+    main = model["main"]
+    (grad_op,) = [op for op in main.global_block.ops
+                  if op.type == ATTN_GRAD][:1]
+    assert {"__out__Out", "__out__SoftmaxLse", "Out@GRAD"} <= set(
+        grad_op.inputs)
+    diags = verify_program(main, fetch_names=[model["loss"].name])
+    bad = [d for d in diags if d.severity == Severity.ERROR
+           or (d.op_type or "").startswith(ATTN)]
+    assert not bad, [f"{d.code}: {d.message}" for d in bad]
+
+
+def test_served_prefill_with_the_extra_output_clones_saves_and_loads(
+        tmp_path):
+    """A forward-only program carries the SoftmaxLse variable and never
+    computes with it: clone(for_test), save and load must not mind."""
+    from paddle_tpu.models.gpt import GptConfig, build_gpt_prefill
+
+    B, S = 2, 16
+    with un.guard():
+        net = build_gpt_prefill(GptConfig.tiny(), B, S, max_seq=32)
+    main = net["main"]
+    attn = [op for op in main.global_block.ops if op.type == ATTN]
+    assert attn and all(op.outputs.get("SoftmaxLse") for op in attn)
+    rng = np.random.RandomState(0)
+    feed = {"prompt_ids": rng.randint(0, 64, (B, S)).astype(np.int64),
+            "prompt_pos": np.tile(np.arange(S, dtype=np.int64), (B, 1)),
+            "prompt_mask": np.ones((B, S), np.float32),
+            "prompt_len": np.full((B, 1), S, np.int64),
+            "slot_mask": np.ones((B, 1), np.float32)}
+    fetch = net["first_token"]
+    exe = fluid.Executor(fluid.CPUPlace())
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe.run(net["startup"])
+        for name, (shape, dt) in net["state_vars"].items():
+            scope.set_var(name, np.zeros(shape, np_dtype(dt)))
+        test_prog = main.clone(for_test=True)
+        (want,) = exe.run(test_prog, feed=feed, fetch_list=[fetch.name])
+        fluid.io.save_inference_model(str(tmp_path / "m"), sorted(feed),
+                                      [fetch], exe, main_program=main)
+    with fluid.scope_guard(fluid.Scope()):
+        prog, feed_names, fetches = fluid.io.load_inference_model(
+            str(tmp_path / "m"), exe)
+        (got,) = exe.run(prog, feed={n: feed[n] for n in feed_names},
+                         fetch_list=fetches)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 @pytest.mark.tpu

@@ -79,6 +79,10 @@ ALLOWLIST = {
         "Mean/Variance are grad-side state slots read only by "
         "layer_norm_grad; inference-only programs (the GPT generative "
         "phases) never read them",
+    ("PT721", "fused_multihead_attention"):
+        "SoftmaxLse is the grad-side residual read only by "
+        "fused_multihead_attention_grad (the flash kernel writes it "
+        "anyway); a serving prefill never reads it",
     ("PT743", ""):
         "prediction/eval fetch surfaces materialize per-example outputs; "
         "the fetch all-gather is the intended result delivery and is "
