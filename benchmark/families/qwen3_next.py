@@ -1,0 +1,49 @@
+"""The ``qwen3_next`` decoder through the program's own builder and
+engine; sizes from ``reference.qwen3_next.model_config``."""
+from __future__ import annotations
+
+
+def build(cfg: dict) -> dict:
+    import paddle_tpu.unique_name as un
+    from paddle_tpu.models.qwen3_next import (Qwen3NextConfig,
+                                              build_qwen3_next_generative)
+
+    m, s = cfg["model"], cfg["serving"]
+    mc = Qwen3NextConfig(
+        vocab_size=m["vocab_size"], hidden_size=m["hidden_size"],
+        num_layers=m["num_hidden_layers"],
+        num_heads=m["num_attention_heads"],
+        num_kv_heads=m["num_key_value_heads"], head_dim=m["head_dim"],
+        partial_rotary_factor=m["partial_rotary_factor"],
+        rope_theta=m["rope_theta"],
+        full_attention_interval=m["full_attention_interval"],
+        linear_num_key_heads=m["linear_num_key_heads"],
+        linear_num_value_heads=m["linear_num_value_heads"],
+        linear_key_head_dim=m["linear_key_head_dim"],
+        linear_value_head_dim=m["linear_value_head_dim"],
+        linear_conv_kernel_dim=m["linear_conv_kernel_dim"],
+        intermediate_size=m["moe_intermediate_size"],
+        shared_expert_intermediate_size=m["shared_expert_intermediate_size"],
+        num_experts=m["num_experts_total"], experts_held=m["num_experts"],
+        expert_offset=m["expert_offset"], top_k=m["num_experts_per_tok"],
+        rms_norm_eps=m["rms_norm_eps"],
+        initializer_range=m["initializer_range"], dtype=m["storage"])
+    with un.guard():
+        return build_qwen3_next_generative(
+            mc, batch_slots=s["slots"], max_seq=s["max_seq"],
+            page_size=s["page_size"],
+            prompt_buckets=tuple(s["prompt_buckets"]),
+            prefill_rows=s.get("prefill_rows"))
+
+
+def engine(cfg: dict, net: dict, scope, exe):
+    """``GenerativeEngine`` as an operator starts it: every field the
+    configuration does not name stays at its flag's default."""
+    from paddle_tpu import serving
+
+    s = cfg["serving"]
+    return serving.GenerativeEngine(
+        net, scope=scope, executor=exe,
+        config=serving.ServingConfig(max_batch=s["slots"],
+                                     deadline_s=s["deadline_s"]),
+        gen_config=serving.GenerationConfig(**s["generation"]))

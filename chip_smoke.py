@@ -7,7 +7,8 @@ full width of the models the repo supports, with seeded random weights:
 
 * kernels   — every Pallas route (flash attention forward/backward, its
               in-kernel dropout PRNG, the paged decode kernel at q_len 1
-              and 8) against its primitive oracle on the same inputs at
+              and 8, the gated delta rule's scan and step kernels) against
+              its primitive oracle on the same inputs at
               the models' own shapes, under written tolerances;
 * bert      — BERT-base pretraining, bs 32 x 512, bf16 AMP, through
               ``Executor(TPUPlace()).run`` on one fixed batch;
@@ -294,6 +295,38 @@ def leg_kernels() -> dict:
               f"flash_attention_decode q_len={q_len} agrees with "
               f"decode_attention_reference (<= {BF16_ATOL}, bf16-rounded "
               f"operands)")
+    # -- gated delta rule (kernels/gdn.py): the chunked scan over two
+    #    prompts in a 640-row bucket (one of 500 real rows) and then the
+    #    decode step, 16 key / 32 value heads of 128 x 128, f32, against the
+    #    token loop in plain jax.numpy
+    from paddle_tpu.kernels.gdn import (gdn_chunk_scan, gdn_decode_step,
+                                        gdn_scan_reference,
+                                        gdn_step_reference)
+
+    R, Hk, Hv, S, Dh = 2, 16, 32, 640, 128
+    unit = lambda t: t / jnp.linalg.norm(t, axis=-1, keepdims=True)
+    f32 = lambda *shape: jnp.asarray(rng.randn(*shape), jnp.float32)
+    live = (jnp.arange(S)[None] < jnp.asarray([S, 500])[:, None])[:, None]
+    qg, kg = unit(f32(R, Hk, S, Dh)) * Dh ** -0.5, unit(f32(R, Hk, S, Dh))
+    vg = f32(R, Hv, S, Dh)
+    gg = -0.3 * jnp.exp(f32(R, Hv, S)) * live
+    bg = jax.nn.sigmoid(f32(R, Hv, S)) * live
+    o_k, s_k = jax.jit(gdn_chunk_scan)(qg, kg, vg, gg, bg)
+    o_r, s_r = jax.jit(gdn_scan_reference)(qg, kg, vg, gg, bg)
+    e = max(maxerr(o_k[0], o_r[0]), maxerr(o_k[1, :, :500], o_r[1, :, :500]),
+            maxerr(s_k, s_r))
+    say(leg, f"gdn_chunk_scan {R}x{Hv}x{S}x{Dh}: max|err| {e:.2e}")
+    check(e <= 1e-4, "gdn_chunk_scan agrees with the token loop (<= 1e-4, "
+                     "f32 in another order)")
+    rep = lambda t: jnp.repeat(t, Hv // Hk, axis=1)
+    args = (s_r, rep(qg[:, :, 7]), rep(kg[:, :, 7]), vg[:, :, 7],
+            jnp.exp(gg[:, :, 7]), bg[:, :, 7])
+    o_k, s_k = jax.jit(gdn_decode_step)(*args)
+    o_r, s_r = jax.jit(gdn_step_reference)(*args)
+    e = max(maxerr(o_k, o_r), maxerr(s_k, s_r))
+    say(leg, f"gdn_decode_step {R}x{Hv}x{Dh}x{Dh}: max|err| {e:.2e}")
+    check(e <= 1e-4, "gdn_decode_step agrees with one step of the token "
+                     "loop (<= 1e-4)")
     hbm(leg)
     return {}
 

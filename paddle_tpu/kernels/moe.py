@@ -5,8 +5,8 @@ A sparse-expert layer routes every token over ALL ``num_experts`` experts
 and computes, on this chip, the part of the result that the experts held
 here give (``ops/moe.py``). Two kernels carry it:
 
-* :func:`router_scores` — ``sigmoid(x @ w)`` in f32 with true f32
-  products. The top-k sits on these scores, and a score rounded to bf16
+* :func:`router_scores` — ``sigmoid(x @ w)``, or a softmax over the
+  experts, in f32 with true f32 products. The top-k sits on these scores, and a score rounded to bf16
   flips the k-th place between near-equal experts, which changes the
   layer's output by a whole expert's worth: the router is the one matmul
   of the layer that does not run in bf16.
@@ -71,30 +71,41 @@ def gmm_blocks(K: int, N: int, block_k: int = 2048, block_n: int = 512):
 # router
 # --------------------------------------------------------------------------
 
-def router_scores_reference(x, w):
-    """``sigmoid(x @ w)`` with f32 operands and true f32 products."""
+def _score(s, score_fn: str):
+    """``sigmoid``: each expert's logit alone; ``softmax``: over all the
+    experts of a row (the kernel's block holds the whole row)."""
+    if score_fn == "sigmoid":
+        return jax.nn.sigmoid(s)
+    e = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    return e / jnp.sum(e, axis=-1, keepdims=True)
+
+
+def router_scores_reference(x, w, score_fn: str = "sigmoid"):
+    """``score_fn(x @ w)`` with f32 operands and true f32 products."""
     s = jnp.matmul(x.astype(jnp.float32), w.astype(jnp.float32),
                    precision=jax.lax.Precision.HIGHEST)
-    return jax.nn.sigmoid(s)
+    return _score(s, score_fn)
 
 
-def _router_kernel(x_ref, w_ref, o_ref):
+def _router_kernel(score_fn, x_ref, w_ref, o_ref):
     s = jax.lax.dot_general(x_ref[...], w_ref[...],
                             (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32,
                             precision=jax.lax.Precision.HIGHEST)
-    o_ref[...] = jax.nn.sigmoid(s)
+    o_ref[...] = _score(s, score_fn)
 
 
 @jax.named_scope(_PALLAS_SCOPE)
-def router_scores(x, w, *, block_t: int = 256, interpret: bool = False):
-    """x [T, H], w [H, E] -> sigmoid(x @ w) [T, E] f32. ``T`` must divide
-    into ``block_t``-row tiles or be one tile of a multiple of 8 rows."""
+def router_scores(x, w, *, score_fn: str = "sigmoid", block_t: int = 256,
+                  interpret: bool = False):
+    """x [T, H], w [H, E] -> score_fn(x @ w) [T, E] f32 (``sigmoid``, or
+    ``softmax`` over E). ``T`` must divide into ``block_t``-row tiles or
+    be one tile of a multiple of 8 rows."""
     T, H = x.shape
     E = w.shape[1]
     bt = block_t if T % block_t == 0 else T
     return pl.pallas_call(
-        _router_kernel,
+        functools.partial(_router_kernel, score_fn),
         grid=(T // bt,),
         in_specs=[pl.BlockSpec((bt, H), lambda i: (i, 0)),
                   pl.BlockSpec((H, E), lambda i: (0, 0))],

@@ -36,7 +36,8 @@ __all__ = [
     "lstm_unit", "autoincreased_step_counter", "adaptive_pool3d",
     "beam_search", "beam_search_decode", "filter_by_instag",
     "fused_decode_attention", "kv_cache_append", "sequence_gather",
-    "rotary_embedding", "moe_experts", "slot_assign",
+    "rotary_embedding", "moe_experts", "slot_assign", "gated_delta_rule",
+    "rms_norm",
     "sample_token", "spec_accept",
 ]
 
@@ -957,19 +958,72 @@ def fused_decode_attention(q, k_new, v_new, cache_k, cache_v, positions,
     return out
 
 
-def rotary_embedding(x, positions, theta=10000.0, name=None):
-    """Rotary position embedding on interleaved pairs (ops/moe.py): ``x``
-    [B, heads, S, D], ``positions`` [B, S] int; same shape and type out."""
+def rotary_embedding(x, positions, theta=10000.0, rotary_dim=0,
+                     pairing="interleaved", name=None):
+    """Rotary position embedding (ops/moe.py): ``x`` [B, heads, S, D],
+    ``positions`` [B, S] int; same shape and type out. The first
+    ``rotary_dim`` dims of a head turn (0: all of them), as ``interleaved``
+    pairs ``(2i, 2i+1)`` or rotate-``half`` pairs ``(i, i + rotary_dim/2)``."""
     helper = LayerHelper("rotary_embedding", name=name)
     out = helper.create_variable_for_type_inference(x.dtype)
+    attrs = {"theta": float(theta)}
+    if rotary_dim:
+        attrs["rotary_dim"] = int(rotary_dim)
+    if pairing != "interleaved":
+        attrs["pairing"] = str(pairing)
     helper.append_op("rotary_embedding",
                      inputs={"X": x, "Positions": positions},
-                     outputs={"Out": out}, attrs={"theta": float(theta)})
+                     outputs={"Out": out}, attrs=attrs)
     return out
 
 
+def rms_norm(x, scale, epsilon=1e-6, zero_centered=False, name=None):
+    """``x / sqrt(mean(x^2) + epsilon) * s`` over the last dim in f32
+    (ops/gdn.py); ``s`` is ``scale`` [D], or ``1 + scale`` where
+    ``zero_centered``."""
+    helper = LayerHelper("rms_norm", name=name)
+    out = helper.create_variable_for_type_inference("float32")
+    helper.append_op("rms_norm", inputs={"X": x, "Scale": scale},
+                     outputs={"Out": out},
+                     attrs={"epsilon": float(epsilon),
+                            "zero_centered": bool(zero_centered)})
+    return out
+
+
+def gated_delta_rule(x, conv_w, a, b, a_log, dt_bias, state, conv_state,
+                     mask, num_k_heads, num_v_heads, head_k_dim, head_v_dim,
+                     mode="scan", slots=None, slot_mask=None, name=None):
+    """The gated delta rule of a Gated DeltaNet layer behind its causal
+    convolution (ops/gdn.py). ``mode="scan"``: ``x`` [R, S, C] whole
+    prompts, ``mask`` [R, S]; sequence ``i`` overwrites the state of slot
+    ``slots[i]`` where ``slot_mask[i]`` > 0. ``mode="step"``: ``x``
+    [slots, 1, C], ``mask`` [slots, 1] the decode gate. ``state`` and
+    ``conv_state`` are written in place. Returns ``(out [R, S, Hv Dv] f32,
+    stats [1] int32: rows the rule advanced)``."""
+    helper = LayerHelper("gated_delta_rule", name=name)
+    out = helper.create_variable_for_type_inference("float32")
+    stats = helper.create_variable_for_type_inference("int32",
+                                                      stop_gradient=True)
+    inputs = {"X": x, "ConvW": conv_w, "A": a, "B": b, "ALog": a_log,
+              "DtBias": dt_bias, "State": state, "ConvState": conv_state,
+              "Mask": mask}
+    if slots is not None:
+        inputs["Slots"] = slots
+    if slot_mask is not None:
+        inputs["SlotMask"] = slot_mask
+    helper.append_op(
+        "gated_delta_rule", inputs=inputs,
+        outputs={"Out": out, "StateOut": state, "ConvStateOut": conv_state,
+                 "Stats": stats},
+        attrs={"mode": str(mode), "num_k_heads": int(num_k_heads),
+               "num_v_heads": int(num_v_heads),
+               "head_k_dim": int(head_k_dim), "head_v_dim": int(head_v_dim)})
+    return out, stats
+
+
 def moe_experts(x, router_w, gate_w, up_w, down_w, num_experts, top_k,
-                expert_offset=0, token_mask=None, name=None):
+                expert_offset=0, token_mask=None, score_fn="sigmoid",
+                name=None):
     """The routed experts a chip holds (ops/moe.py): routes ``x`` [..., H]
     (f32) over all ``num_experts`` by ``router_w`` [H, num_experts] and
     returns ``(out, stats)``: the part of the routed sum that the experts
@@ -977,7 +1031,8 @@ def moe_experts(x, router_w, gate_w, up_w, down_w, num_experts, top_k,
     and an int32 vector of the assignments each of them received, all
     assignments made, and assignments dropped (always 0). ``token_mask``
     (``x``'s leading shape, > 0 = a real token) keeps padding out of the
-    routing."""
+    routing. ``score_fn``: ``sigmoid`` of each expert's logit, or
+    ``softmax`` over all of them."""
     helper = LayerHelper("moe_experts", name=name)
     out = helper.create_variable_for_type_inference("float32")
     stats = helper.create_variable_for_type_inference("int32",
@@ -990,7 +1045,8 @@ def moe_experts(x, router_w, gate_w, up_w, down_w, num_experts, top_k,
         "moe_experts", inputs=inputs,
         outputs={"Out": out, "Stats": stats},
         attrs={"num_experts": int(num_experts), "top_k": int(top_k),
-               "expert_offset": int(expert_offset)})
+               "expert_offset": int(expert_offset),
+               "score_fn": str(score_fn)})
     return out, stats
 
 

@@ -74,6 +74,7 @@ class CohereMoeConfig:
     logit_scale: float = 1.0
     initializer_range: float = 0.02
     dtype: str = "bfloat16"
+    score_fn: str = "sigmoid"            # the router's, as ``moe_experts``
 
     def __post_init__(self):
         if self.experts_held is None:
@@ -107,7 +108,14 @@ class CohereMoeConfig:
         return max_seq
 
 
-def _attr(name: str, cfg: CohereMoeConfig):
+# ``cfg`` below is this module's configuration or another sparse-expert
+# decoder's (``models/qwen3_next.py``): what a helper reads of it is
+# ``initializer_range`` and ``dtype``, and for the feed-forward
+# ``hidden_size``, ``intermediate_size`` (an expert's width), ``num_experts``,
+# ``experts_held``, ``expert_offset``, ``top_k``, ``num_shared_experts``
+# and ``score_fn``.
+
+def _attr(name: str, cfg):
     return ParamAttr(name=name,
                      initializer=TruncatedNormal(0.0, cfg.initializer_range))
 
@@ -118,12 +126,12 @@ def _ln(x, name: str, cfg: CohereMoeConfig, axis: int = 2):
                              param_attr=ParamAttr(name=f"{name}_scale"))
 
 
-def _proj(x, size: int, name: str, cfg: CohereMoeConfig, act=None):
+def _proj(x, size: int, name: str, cfg, act=None):
     return layers.fc(x, size, num_flatten_dims=2, act=act, bias_attr=False,
                      param_attr=_attr(f"{name}_w", cfg))
 
 
-def _proj_out(x, size: int, name: str, cfg: CohereMoeConfig):
+def _proj_out(x, size: int, name: str, cfg):
     """A projection back onto the residual stream: the f32 accumulator is
     kept, where ``fc`` would round it to the operands' type on its way to
     an f32 sum (every rounding upstream of a router moves its k-th place)."""
@@ -138,7 +146,7 @@ def _split_heads(t, seq_len: int, heads: int, head_dim: int):
     return layers.transpose(t, [0, 2, 1, 3])
 
 
-def _expert_weights(name: str, cfg: CohereMoeConfig):
+def _expert_weights(name: str, cfg):
     """Router over all experts; gate, up and down of the held ones,
     stacked."""
     helper = LayerHelper("cohere_moe")
@@ -149,7 +157,7 @@ def _expert_weights(name: str, cfg: CohereMoeConfig):
             mk("up", [Eh, H, F]), mk("down", [Eh, F, H]))
 
 
-def _ffn(h, hb, p: str, cfg: CohereMoeConfig, real=None):
+def _ffn(h, hb, p: str, cfg, real=None):
     """The feed-forward of one layer on the normed rows ``h`` (f32, what
     the router reads) and ``hb`` (the same in ``cfg.dtype``, what the
     matmuls read); ``real`` [B, S] marks the rows that are tokens of a
@@ -157,7 +165,8 @@ def _ffn(h, hb, p: str, cfg: CohereMoeConfig, real=None):
     mean of the shared experts (both f32) and the expert op's statistics."""
     routed, stats = layers.moe_experts(
         h, *_expert_weights(p, cfg), num_experts=cfg.num_experts,
-        top_k=cfg.top_k, expert_offset=cfg.expert_offset, token_mask=real)
+        top_k=cfg.top_k, expert_offset=cfg.expert_offset, token_mask=real,
+        score_fn=cfg.score_fn)
     # the shared experts side by side: columns t*F..(t+1)*F of gate and up,
     # and the same rows of down, are shared expert t, so one product with
     # the stacked down matrix is their sum
@@ -209,31 +218,29 @@ def _stack_layers(x, cfg: CohereMoeConfig, positions, real, attend):
     return _ln(x, f"{_P}_lnf", cfg), layers.stack(stats, axis=0)
 
 
-def _embed(ids, cfg: CohereMoeConfig):
+def _embed(ids, cfg, name: str = f"{_P}_word_emb"):
     emb = layers.embedding(ids, (cfg.vocab_size, cfg.hidden_size),
-                           dtype=cfg.dtype,
-                           param_attr=_attr(f"{_P}_word_emb", cfg))
+                           dtype=cfg.dtype, param_attr=_attr(name, cfg))
     return layers.cast(emb, "float32")
 
 
-def _logits(h2d, cfg: CohereMoeConfig, block):
-    """[B, H] f32 rows -> f32 logits over the held vocabulary through the
-    tied embedding (bf16 operands, the f32 accumulator kept: a logit
+def _logits(h2d, cfg, weight, logit_scale: float = 1.0):
+    """[B, H] f32 rows -> f32 logits over the held vocabulary through
+    ``weight`` [V, H] (bf16 operands, the f32 accumulator kept: a logit
     rounded to bf16 moves by more than the gap between near-best tokens)."""
-    out = layers.matmul(layers.cast(h2d, cfg.dtype),
-                        block.var(f"{_P}_word_emb"), transpose_y=True,
-                        out_dtype="float32")
-    if cfg.logit_scale != 1.0:
-        out = layers.scale(out, scale=float(cfg.logit_scale))
+    out = layers.matmul(layers.cast(h2d, cfg.dtype), weight,
+                        transpose_y=True, out_dtype="float32")
+    if logit_scale != 1.0:
+        out = layers.scale(out, scale=float(logit_scale))
     return out
 
 
-def _state_vars(block, cfg: CohereMoeConfig, batch_slots: int, max_seq: int):
-    """Current token, position and decode gate per slot, and one K/V cache
-    pair per layer: ``[slots, kv_heads, rows, head_dim]`` in ``cfg.dtype``
-    with ``rows`` by the layer's type (see ``models/gpt.py:_state_vars``
-    for what the executor does with them)."""
-    sv, kinds = {}, {}
+def _state_table(block, prefix: str, batch_slots: int):
+    """``(mk, sv, tok, pos, active)``: ``mk(name, shape, dtype)`` makes a
+    persistable state var and enters it in ``sv`` (name -> (shape,
+    dtype)); the current token, position and decode gate per slot are in
+    it already."""
+    sv = {}
 
     def mk(name, shape, dtype):
         block.create_var(name=name, shape=tuple(shape), dtype=dtype,
@@ -241,10 +248,18 @@ def _state_vars(block, cfg: CohereMoeConfig, batch_slots: int, max_seq: int):
         sv[name] = (tuple(shape), dtype)
         return block.var(name)
 
-    tok = mk(f"{_P}_gen_tokens", (batch_slots, 1), "int64")
-    pos = mk(f"{_P}_gen_pos", (batch_slots, 1), "int64")
-    active = mk(f"{_P}_gen_active", (batch_slots, 1), "float32")
-    caches = []
+    return (mk, sv, mk(f"{prefix}_gen_tokens", (batch_slots, 1), "int64"),
+            mk(f"{prefix}_gen_pos", (batch_slots, 1), "int64"),
+            mk(f"{prefix}_gen_active", (batch_slots, 1), "float32"))
+
+
+def _state_vars(block, cfg: CohereMoeConfig, batch_slots: int, max_seq: int):
+    """Current token, position and decode gate per slot, and one K/V cache
+    pair per layer: ``[slots, kv_heads, rows, head_dim]`` in ``cfg.dtype``
+    with ``rows`` by the layer's type (see ``models/gpt.py:_state_vars``
+    for what the executor does with them)."""
+    mk, sv, tok, pos, active = _state_table(block, _P, batch_slots)
+    kinds, caches = {}, []
     for i in range(cfg.num_layers):
         shape = (batch_slots, cfg.num_kv_heads, cfg.cache_rows(i, max_seq),
                  cfg.head_dim)
@@ -256,22 +271,52 @@ def _state_vars(block, cfg: CohereMoeConfig, batch_slots: int, max_seq: int):
     return tok, pos, active, caches, sv, kinds
 
 
+PREFILL_FEEDS = ("prompt_ids", "prompt_pos", "prompt_mask", "prompt_len",
+                 "slot_mask", "slot_ids")
+
+
+def _prefill_feeds(R: int, S: int):
+    """The feeds of a prefill that carries ``R`` sequences of up to ``S``
+    rows, each naming its slot, in the order of ``PREFILL_FEEDS``: those of
+    ``models/gpt.py:build_gpt_prefill`` with ``R`` rows (``slot_mask`` 1
+    on the rows in use), and ``slot_ids`` [R, 1] int64."""
+    shapes = ([R, S], [R, S], [R, S], [R, 1], [R, 1], [R, 1])
+    types = ("int64", "int64", "float32", "int64", "float32", "int64")
+    return [layers.data(n, shape=shape, dtype=dt, append_batch_size=False)
+            for n, shape, dt in zip(PREFILL_FEEDS, shapes, types)]
+
+
+def _commit_prefill(tok, pos, active, slots, first_tok, plen, smask):
+    """Each row in use commits its slot's first token and position and
+    opens its decode gate."""
+    layers.slot_assign(tok, slots, first_tok, smask)
+    layers.slot_assign(pos, slots, plen, smask)
+    layers.slot_assign(
+        active, slots,
+        layers.fill_constant([slots.shape[0], 1], "float32", 1.0), smask)
+
+
+def _commit_decode(tok, pos, active, next_tok, max_seq: int):
+    """The slots whose gate is open take the sampled token and move on one
+    position (never past the cache)."""
+    B = tok.shape[0]
+    one = layers.fill_constant([B, 1], "int64", 1)
+    act_i64 = layers.cast(active, "int64")
+    inv = layers.elementwise_sub(one, act_i64)
+    layers.assign(_merge_state(next_tok, tok, act_i64, inv), output=tok)
+    new_pos = layers.elementwise_min(
+        layers.elementwise_add(pos, one),
+        layers.fill_constant([B, 1], "int64", max_seq))
+    layers.assign(_merge_state(new_pos, pos, act_i64, inv), output=pos)
+
+
 def _build_prefill(cfg, B, R, S, max_seq, sample, startup):
     """The full-sequence phase for one prompt bucket. A dispatch carries
     ``R`` <= ``B`` sequences, each with the slot it is for, and costs
-    ``R x S`` tokens whichever they are: the feeds of
-    ``models/gpt.py:build_gpt_prefill`` with ``R`` rows (``slot_mask`` 1 on
-    the rows in use), and ``slot_ids`` [R, 1] int64."""
+    ``R x S`` tokens whichever they are (:func:`_prefill_feeds`)."""
     main = Program()
     with program_guard(main, startup):
-        data = lambda n, shape, dt: layers.data(
-            n, shape=shape, dtype=dt, append_batch_size=False)
-        ids = data("prompt_ids", [R, S], "int64")
-        pos_ids = data("prompt_pos", [R, S], "int64")
-        pmask = data("prompt_mask", [R, S], "float32")
-        plen = data("prompt_len", [R, 1], "int64")
-        smask = data("slot_mask", [R, 1], "float32")
-        slots = data("slot_ids", [R, 1], "int64")
+        ids, pos_ids, pmask, plen, smask, slots = _prefill_feeds(R, S)
         tok, pos, active, caches, sv, _ = _state_vars(
             main.global_block, cfg, B, max_seq)
         bias = layers.unsqueeze(
@@ -292,19 +337,13 @@ def _build_prefill(cfg, B, R, S, max_seq, sample, startup):
                                  attend)
         one = layers.fill_constant([R, 1], "int64", 1)
         last_h = layers.sequence_gather(h, layers.elementwise_sub(plen, one))
-        logits = _logits(last_h, cfg, main.global_block)
+        logits = _logits(last_h, cfg, main.global_block.var(f"{_P}_word_emb"),
+                         cfg.logit_scale)
         first_tok = layers.sample_token(logits, **sample)
-        # each row in use commits its slot's first token and position and
-        # opens its decode gate
-        layers.slot_assign(tok, slots, first_tok, smask)
-        layers.slot_assign(pos, slots, plen, smask)
-        layers.slot_assign(active, slots,
-                           layers.fill_constant([R, 1], "float32", 1.0),
-                           smask)
+        _commit_prefill(tok, pos, active, slots, first_tok, plen, smask)
     return {"main": main, "first_token": first_tok, "state_vars": sv,
             "last_logits": logits, "expert_stats": stats, "rows": R,
-            "feeds": ("prompt_ids", "prompt_pos", "prompt_mask",
-                      "prompt_len", "slot_mask", "slot_ids")}
+            "feeds": PREFILL_FEEDS}
 
 
 def _build_decode(cfg, B, max_seq, page_size, sample):
@@ -325,16 +364,10 @@ def _build_decode(cfg, B, max_seq, page_size, sample):
         x = layers.unsqueeze(_embed(tok, cfg), [1])
         h, stats = _stack_layers(x, cfg, pos, active, attend)
         logits = _logits(layers.reshape(h, [0, cfg.hidden_size]), cfg,
-                         main.global_block)
+                         main.global_block.var(f"{_P}_word_emb"),
+                         cfg.logit_scale)
         next_tok = layers.sample_token(logits, **sample)
-        one = layers.fill_constant([B, 1], "int64", 1)
-        act_i64 = layers.cast(active, "int64")
-        inv = layers.elementwise_sub(one, act_i64)
-        layers.assign(_merge_state(next_tok, tok, act_i64, inv), output=tok)
-        new_pos = layers.elementwise_min(
-            layers.elementwise_add(pos, one),
-            layers.fill_constant([B, 1], "int64", max_seq))
-        layers.assign(_merge_state(new_pos, pos, act_i64, inv), output=pos)
+        _commit_decode(tok, pos, active, next_tok, max_seq)
     return {"main": main, "next_token": next_tok, "state_vars": sv,
             "logits": logits, "expert_stats": stats,
             "cache_kinds": kinds,
@@ -377,11 +410,19 @@ def build_cohere_moe_generative(cfg: CohereMoeConfig = None,
     prefill = {S: _build_prefill(cfg, batch_slots, rows, S, max_seq, sample,
                                  startup) for S in prompt_buckets}
     decode = _build_decode(cfg, batch_slots, max_seq, page_size, sample)
+    return _generative(cfg, startup, prefill, decode, batch_slots, max_seq,
+                       page_size, strategy)
+
+
+def _generative(cfg, startup, prefill, decode, batch_slots, max_seq,
+                page_size, strategy):
+    """The dict ``serving.GenerativeEngine`` takes, from a builder's
+    programs (no chunk or verify program: ``spec_k`` 0)."""
     return {"config": cfg, "startup": startup, "prefill": prefill,
             "decode": decode, "state_vars": decode["state_vars"],
             "cache_vars": decode["cache_vars"],
             "cache_kinds": decode["cache_kinds"],
             "active_var": decode["active_var"],
             "batch_slots": batch_slots, "max_seq": max_seq,
-            "page_size": page_size, "prompt_buckets": prompt_buckets,
+            "page_size": page_size, "prompt_buckets": tuple(sorted(prefill)),
             "spec_k": 0, "strategy": strategy}

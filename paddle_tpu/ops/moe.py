@@ -2,7 +2,8 @@
 experts it holds, and rotary position embedding.
 
 ``moe_experts`` is what expert parallelism asks of one chip: it routes
-every token over ALL ``num_experts`` experts (sigmoid scores, the ``top_k``
+every token over ALL ``num_experts`` experts (scores by ``score_fn``: a
+sigmoid of each logit, or a softmax over all of them; the ``top_k``
 largest, weights normalised over the chosen ones — all in f32) and
 computes the part of ``sum_e w_e E_e(x)`` that the ``experts_held``
 experts from ``expert_offset`` on contribute, each a gated feed-forward
@@ -22,9 +23,20 @@ from ..lowering import lowering_platform, note_kernel_route
 
 # rows of one grouped-matmul tile: 16 (one packed bf16 sublane tile) while
 # a held expert expects a handful of rows, as in a decode step, where the
-# kernel streams weights; 256 once it expects a few hundred, as in
-# prefill, where a tile has to keep the MXU busy for the weights it loads
+# kernel streams weights; 256 once it expects a few hundred, as in a large
+# prefill, where a tile has to keep the MXU busy for the weights it loads;
+# between the two, the power of two that holds the rows an expert expects
+# under even routing, so that an expert is one or two tiles and its
+# weights are read once or twice, not once for every 16 of its rows
 _TM_SMALL, _TM_LARGE = 16, 256
+
+
+def _tile_rows(expected: int) -> int:
+    """Rows of a tile for an expert that expects ``expected`` rows."""
+    tm = _TM_SMALL
+    while tm < min(expected, _TM_LARGE):
+        tm *= 2
+    return tm
 
 
 def _route_moe(T: int, H: int, platform) -> str:
@@ -68,7 +80,7 @@ def _grouped_held(xb, wg, wu, wd, le, local, weights, counts, num_experts,
     Eh = wg.shape[0]
     A = T * k
     # rows a held expert expects under even routing
-    tm = _TM_LARGE if A // num_experts >= _TM_LARGE else _TM_SMALL
+    tm = _tile_rows(A // num_experts)
     M = -(-A // tm) * tm + Eh * tm           # every assignment local
     flat_e = jnp.where(local, le, Eh).reshape(A)
     order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)
@@ -109,13 +121,16 @@ def _grouped_held(xb, wg, wu, wd, le, local, weights, counts, num_experts,
     inputs=[IOSpec("X"), IOSpec("RouterW"), IOSpec("GateW"), IOSpec("UpW"),
             IOSpec("DownW"), IOSpec("TokenMask", optional=True, no_grad=True)],
     outputs=["Out", "Stats"],
-    attrs={"num_experts": 0, "top_k": 1, "expert_offset": 0},
+    attrs={"num_experts": 0, "top_k": 1, "expert_offset": 0,
+           "score_fn": "sigmoid"},
     grad=None)
 def _moe_experts(ctx, ins, attrs):
     """``X`` [..., H] (f32: the router reads it unrounded); ``RouterW``
     [H, num_experts]; ``GateW``/``UpW`` [experts_held, H, F] and ``DownW``
     [experts_held, F, H] hold experts ``expert_offset ..
-    expert_offset + experts_held - 1``. ``Out`` [..., H] f32: the held
+    expert_offset + experts_held - 1``. ``score_fn``: ``sigmoid`` scores
+    each expert alone, ``softmax`` all ``num_experts`` against each other;
+    either way the chosen scores are divided by their sum. ``Out`` [..., H] f32: the held
     experts' part of the routed sum. ``TokenMask`` (optional, ``X``'s
     leading shape, > 0 = a real token): padding, and the rows of sequences
     that a dispatch does not serve, are routed nowhere; they cost the
@@ -131,10 +146,12 @@ def _moe_experts(ctx, ins, attrs):
     wg, wu, wd = x(ins, "GateW"), x(ins, "UpW"), x(ins, "DownW")
     E, k = int(attrs["num_experts"]), int(attrs["top_k"])
     off, Eh = int(attrs["expert_offset"]), wg.shape[0]
-    if wr.shape[-1] != E or not 0 < k <= E or not 0 <= off <= E - Eh:
+    score_fn = str(attrs.get("score_fn", "sigmoid"))
+    if (wr.shape[-1] != E or not 0 < k <= E or not 0 <= off <= E - Eh
+            or score_fn not in ("sigmoid", "softmax")):
         raise ValueError(
             f"moe_experts: router {wr.shape} for num_experts={E}, top_k={k}, "
-            f"{Eh} experts held from {off}")
+            f"{Eh} experts held from {off}, score_fn={score_fn!r}")
     lead, H = xv.shape[:-1], xv.shape[-1]
     x2 = xv.reshape(-1, H)
     T = x2.shape[0]
@@ -143,9 +160,10 @@ def _moe_experts(ctx, ins, attrs):
     interpret = route == "pallas-interpret"
     with jax.named_scope("moe_router"):
         if route == "primitive":
-            scores = router_scores_reference(x2, wr)
+            scores = router_scores_reference(x2, wr, score_fn)
         else:
-            scores = router_scores(x2, wr, interpret=interpret)
+            scores = router_scores(x2, wr, score_fn=score_fn,
+                                   interpret=interpret)
         experts, weights = route_tokens(scores, k)
     local = (experts >= off) & (experts < off + Eh)
     made = jnp.int32(T * k)
@@ -174,26 +192,43 @@ def _moe_experts(ctx, ins, attrs):
     "rotary_embedding",
     inputs=[IOSpec("X"), IOSpec("Positions", no_grad=True)],
     outputs=["Out"],
-    attrs={"theta": 10000.0},
+    attrs={"theta": 10000.0, "rotary_dim": 0, "pairing": "interleaved"},
     grad=None)
 def _rotary_embedding(ctx, ins, attrs):
-    """Rotary positions on interleaved pairs (the GPT-J layout): ``X``
-    [B, heads, S, D], ``Positions`` [B, S] int. Pair ``i`` = dims
-    ``(2i, 2i+1)`` turns by ``pos * theta^(-2i/D)``. The pair swap is a
-    product with a constant signed permutation (exact in any float type)
-    and not a strided lane shuffle; angles, sines and the blend are f32."""
+    """Rotary positions: ``X`` [B, heads, S, D], ``Positions`` [B, S] int.
+    The first ``rotary_dim`` dims of a head turn (0: all ``D``), the rest
+    carry no position. ``pairing`` ``interleaved`` (the GPT-J layout):
+    pair ``i`` = dims ``(2i, 2i+1)``; ``half`` (rotate-half, the NeoX
+    layout): pair ``i`` = dims ``(i, i + rotary_dim/2)``. Pair ``i`` turns
+    by ``pos * theta^(-2i/rotary_dim)``. The pair swap is a product with a
+    constant signed permutation (exact in any float type) and not a lane
+    shuffle; angles, sines and the blend are f32."""
     xv, pos = x(ins, "X"), x(ins, "Positions")
     B, _, S, D = xv.shape
+    rot = int(attrs.get("rotary_dim", 0)) or D
+    half = str(attrs.get("pairing", "interleaved")) == "half"
+    if rot % 2 or rot > D:
+        raise ValueError(f"rotary_embedding: rotary_dim {rot} of {D} dims")
     with jax.named_scope("rotary"):
         inv = float(attrs["theta"]) ** (
-            -jnp.arange(0, D, 2, dtype=jnp.float32) / D)
+            -jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
         ang = pos.reshape(B, 1, S, 1).astype(jnp.float32) * inv
-        cos = jnp.repeat(jnp.cos(ang), 2, axis=-1)
-        sin = jnp.repeat(jnp.sin(ang), 2, axis=-1)
-        even = jnp.arange(0, D, 2)
-        # (x @ swap)[2i] = -x[2i+1], (x @ swap)[2i+1] = x[2i]
-        swap = jnp.zeros((D, D), xv.dtype).at[even + 1, even].set(-1).at[
-            even, even + 1].set(1)
+        if half:
+            spread = lambda t: jnp.concatenate([t, t], axis=-1)
+            first = jnp.arange(rot // 2)
+            second = first + rot // 2
+        else:
+            spread = lambda t: jnp.repeat(t, 2, axis=-1)
+            first = jnp.arange(0, rot, 2)
+            second = first + 1
+        cos, sin = spread(jnp.cos(ang)), spread(jnp.sin(ang))
+        if rot < D:
+            still = [(0, 0)] * 3 + [(0, D - rot)]
+            cos = jnp.pad(cos, still, constant_values=1.0)
+            sin = jnp.pad(sin, still)
+        # (x @ swap)[first] = -x[second], (x @ swap)[second] = x[first]
+        swap = jnp.zeros((D, D), xv.dtype).at[second, first].set(-1).at[
+            first, second].set(1)
         turned = jnp.matmul(xv, swap, preferred_element_type=jnp.float32,
                             precision=jax.lax.Precision.HIGHEST)
         out = xv.astype(jnp.float32) * cos + turned * sin

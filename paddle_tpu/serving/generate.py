@@ -173,10 +173,19 @@ class GenerativeEngine(ServingEngine):
         self._prefill_chunk = int(model.get("prefill_chunk") or
                                   self._page_size)
         self._spec_k = int(model.get("spec_k") or 0)
-        # the model names its own state: one (K, V) cache pair per layer,
-        # whose row counts and type may differ from layer to layer, and the
-        # per-slot decode gate
-        self._cache_names = [tuple(pair) for pair in model["cache_vars"]]
+        # the model names its own state, a layer at a time, and says of
+        # what kind each layer's is (``cache_kinds``; a model that says
+        # nothing holds ``full`` pairs): a (K, V) cache pair whose rows
+        # follow the sequence (``full``: every position, ``window``: a ring
+        # of the last ones), with row counts and type of its own, or a
+        # ``recurrent`` layer's state, which has no rows at all. And the
+        # per-slot decode gate.
+        kinds = model.get("cache_kinds", {})
+        self._state_kinds = {n: kinds.get(n, "full")
+                             for names in model["cache_vars"] for n in names}
+        self._cache_names = [
+            tuple(names) for names in model["cache_vars"]
+            if self._state_kinds[names[0]] != "recurrent"]
         self._active_var = model["active_var"]
         # (shape, dtype) of a layer's K cache -> how many layers hold such
         self._cache_shapes = Counter(
@@ -186,12 +195,19 @@ class GenerativeEngine(ServingEngine):
         self._cache_rows = Counter()
         for (shape, _), n in self._cache_shapes.items():
             self._cache_rows[int(shape[2])] += n
-        # a model with routed experts hands back, per dispatch, the
-        # assignments each held expert received (``layers.moe_experts``)
+        # what a dispatch counted on the device, fetched beside its tokens:
+        # a model with routed experts hands back the assignments each held
+        # expert received (``layers.moe_experts``), one with recurrent
+        # layers the rows each layer's rule advanced
+        # (``layers.gated_delta_rule``)
+        stats = lambda net: {k: net[f"{k}_stats"].name
+                             for k in ("expert", "rule")
+                             if net.get(f"{k}_stats") is not None}
         self._stats_fetch = {
-            "decode": _var_names(decode.get("expert_stats")),
-            **{("prefill", b): _var_names(net.get("expert_stats"))
+            "decode": stats(decode),
+            **{("prefill", b): stats(net)
                for b, net in model["prefill"].items()}}
+        self._rule_layers = list(decode.get("rule_layers", ()))
         gc = self.gen_config
         self._prefix_cache = None
         if gc.prefix_cache and self._chunk is not None:
@@ -215,21 +231,20 @@ class GenerativeEngine(ServingEngine):
         """Plant zeroed generation state (tokens, positions, KV pages) in
         the scope. Called at warm-up/start and after a real mid-dispatch
         failure (consumed donated buffers are never reused)."""
-        kinds = self._model.get("cache_kinds", {})
-        caches = {n for pair in self._cache_names for n in pair}
         held = defaultdict(int)
         for name, (shape, dt) in self._model["state_vars"].items():
             zeros = np.zeros(shape, np_dtype(dt))
             self._scope.set_var(name, zeros)
-            if name in caches:
-                held[kinds.get(name, "full")] += zeros.nbytes
+            if name in self._state_kinds:
+                held[self._state_kinds[name]] += zeros.nbytes
         if _monitor.enabled():
             for kind, nbytes in held.items():
                 _monitor.gauge(
                     "serving_kv_cache_bytes",
-                    "bytes of KV cache the engine planted, by the kind of "
-                    "layer that owns them (window: a ring of the last "
-                    "positions; full: every position)"
+                    "bytes of per-layer state the engine planted, by the "
+                    "kind of layer that owns them (window: a ring of the "
+                    "last positions' keys and values; full: every "
+                    "position's; recurrent: a fixed-size state)"
                 ).labels(kind=kind).set(float(nbytes))
 
     def _ensure_state(self) -> None:
@@ -861,7 +876,7 @@ class GenerativeEngine(ServingEngine):
             self._publish(reqs)
             with _loop_phase("settle") as ph:
                 self._note_compiles("prefill", bucket, net["main"])
-                self._observe_expert_stats("prefill", outs[1:])
+                self._observe_stats("prefill", ("prefill", bucket), outs[1:])
                 if _monitor.enabled():
                     _monitor.histogram(
                         "serving_prefill_seconds",
@@ -921,7 +936,7 @@ class GenerativeEngine(ServingEngine):
         span.end()
         with _loop_phase("settle") as ph:
             self._note_compiles("decode", len(self._slots), self._program)
-            self._observe_expert_stats("decode", outs[1:])
+            self._observe_stats("decode", "decode", outs[1:])
             self._observe_walk(active, steps)
             toks = np.asarray(outs[0]).reshape(steps, len(self._slots))
             per_tok = dt / steps
@@ -1141,24 +1156,48 @@ class GenerativeEngine(ServingEngine):
             "fraction of KV cache pages held by resident sequences"
         ).set(used / (pages * len(self._slots)))
 
-    # -- routed experts ----------------------------------------------------
+    # -- what the device counted -------------------------------------------
     def _prefill_fetches(self, bucket: int) -> List[str]:
         net = self._model["prefill"][bucket]
-        return [net["first_token"].name] + self._stats_fetch[
-            "prefill", bucket]
+        return [net["first_token"].name] + list(
+            self._stats_fetch["prefill", bucket].values())
 
     def _decode_fetches(self) -> List[str]:
-        return self._fetch_names + self._stats_fetch["decode"]
+        return self._fetch_names + list(self._stats_fetch["decode"].values())
 
-    def _observe_expert_stats(self, phase: str, fetched) -> None:
+    def _observe_stats(self, phase: str, key, fetched) -> None:
+        if not _monitor.enabled():
+            return
+        got = dict(zip(self._stats_fetch[key], fetched))
+        if "expert" in got:
+            self._observe_expert_stats(phase, np.asarray(got["expert"]))
+        if "rule" in got:
+            self._observe_rule_stats(phase, np.asarray(got["rule"]))
+
+    def _observe_rule_stats(self, phase: str, stats) -> None:
+        """What a dispatch's recurrent layers counted
+        (``layers.gated_delta_rule`` ``Stats``, [..., layers, 1]; a chained
+        decode stacks its steps in front): the real rows each layer's rule
+        advanced, an execution at a time."""
+        stats = stats.reshape(-1, stats.shape[-2]).astype(np.int64)
+        tokens = _monitor.counter(
+            "gdn_tokens_total",
+            "rows of real tokens the gated delta rule advanced, by layer "
+            "and phase of the dispatch")
+        calls = _monitor.counter(
+            "gdn_calls_total", "executions of the gated delta rule op")
+        for j in range(stats.shape[1]):
+            layer = self._rule_layers[j] if self._rule_layers else j
+            lab = dict(layer=str(layer), phase=phase)
+            tokens.labels(**lab).inc(float(stats[:, j].sum()))
+            calls.labels(**lab).inc(float(stats.shape[0]))
+
+    def _observe_expert_stats(self, phase: str, stats) -> None:
         """What a dispatch's expert ops counted (``layers.moe_experts``
         ``Stats``, [..., layers, experts_held + 2]; a chained decode stacks
         its steps in front): per layer and execution the assignments each
         held expert received, all assignments made, and local assignments
         that found no row."""
-        if not fetched or not _monitor.enabled():
-            return
-        stats = np.asarray(fetched[0])
         stats = stats.reshape((-1,) + stats.shape[-2:]).astype(np.int64)
         load, made, dropped = stats[..., :-2], stats[..., -2], stats[..., -1]
         tokens = _monitor.counter(

@@ -203,3 +203,58 @@ def test_router_and_grouped_query_attention_compile_in_bf16(v5e):
                                            num_heads=128, window=192),
         v5e((16 * 128, 256, 128), jnp.bfloat16), kv, kv,
         v5e((16, 256), jnp.float32))
+
+
+# -- the hybrid decoder's kernels at its published widths (PR 31) -------------
+
+def test_gated_delta_rule_kernels_compile_at_the_published_widths(v5e):
+    """The chunked scan over two prompts of 3,072 rows (16 key heads, 32
+    value heads of 128 x 128, f32) and the decode step of 64 slots, whose
+    134 MB state is rewritten in place: donated, nothing of its shape is
+    copied."""
+    from paddle_tpu.kernels.gdn import gdn_chunk_scan, gdn_decode_step
+
+    f32 = jnp.float32
+    qk, v = v5e((2, 16, 3072, 128), f32), v5e((2, 32, 3072, 128), f32)
+    gb = v5e((2, 32, 3072), f32)
+    text = _compiles_with_mosaic(gdn_chunk_scan, qk, qk, v, gb, gb)
+    assert re.search(r"%gdn_chunk_scan[.\d]* = ", text)
+    vec, head = v5e((64, 32, 128), f32), v5e((64, 32), f32)
+    text = jax.jit(gdn_decode_step, donate_argnums=(0,)).lower(
+        v5e((64, 32, 128, 128), f32), vec, vec, vec, head,
+        head).compile().as_text()
+    assert re.search(r"%gdn_decode_step[.\d]* = ", text)
+    assert not re.search(r"%copy[.\d]* = f32\[64,32,128,128\]", text)
+
+
+def test_softmax_router_and_head_256_attention_compile(v5e):
+    """The softmax router over 512 experts; 256 stacked experts of 2048 x
+    512 at a decode step's row buffer; one decode step of 64 slots x 2
+    key/value heads of 256 dims with the 8 query heads of a group in one
+    call, against bf16 caches of 4,096 rows; the causal flash forward at
+    3,072 rows, 16 query heads over 2."""
+    from paddle_tpu.kernels.moe import grouped_matmul, router_scores
+
+    text = _compiles_with_mosaic(
+        lambda x, w: router_scores(x, w, score_fn="softmax"),
+        v5e((3072, 2048), jnp.float32), v5e((2048, 512), jnp.float32))
+    assert re.search(r"%moe_router[.\d]* = ", text)
+    bf = jnp.bfloat16
+    wgu, wd = v5e((256, 2048, 512), bf), v5e((256, 512, 2048), bf)
+
+    def ffn(x, wg, wu, wd, tile_expert, n_valid):
+        h = grouped_matmul(x, wg, tile_expert, n_valid, tm=16, rhs2=wu,
+                           out_dtype=bf)
+        return grouped_matmul(h, wd, tile_expert, n_valid, tm=16)
+
+    _compiles_with_mosaic(ffn, v5e((4736, 2048), bf), wgu, wgu, wd,
+                          v5e((296,), jnp.int32), v5e((), jnp.int32))
+    cache = v5e((128, 4096, 256), bf)
+    _compiles_with_mosaic(
+        lambda q, k, v, n: flash_attention_decode(
+            q, k, v, n, num_heads=2, page_size=128, group=8),
+        v5e((128, 8, 256), bf), cache, cache, v5e((64,), jnp.int32))
+    kv = v5e((2, 3072, 256), bf)
+    _compiles_with_mosaic(
+        lambda q, k, v: flash_attention(q, k, v, causal=True, num_heads=16),
+        v5e((16, 3072, 256), bf), kv, kv)
