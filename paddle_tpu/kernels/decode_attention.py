@@ -45,6 +45,14 @@ Design notes
   rows: rows ``q_len..7`` are padding (replicas of the last real row) whose
   output is discarded, so the q_len=1 decode step and the q_len<=8 chunk
   use one kernel with a per-row length mask ``k_pos < length + row``.
+- a cache whose head dimension is not whole 128-lane tiles (GPT-2's 64)
+  is read and appended to in the view ``[B, H, D, S_max]``, rows in lanes
+  (:func:`rows_minor`, beside :func:`kv_tile`): that is how the runtime
+  stores such an array, so the view is a bitcast, where the logical shape
+  cost a layout conversion of every cache around every chunk. One kernel,
+  one walk, one mask: a block's two axes in the other order, chosen from
+  the shape as the call is traced. Heads of 128 and 256 lie as declared
+  and are read so.
 - per-sequence lengths arrive as scalar-prefetch values: the index maps
   read them to end a sequence's walk (:func:`last_live_block`) and the
   kernel's mask needs no extra VMEM traffic. The grid is ``(sequence,
@@ -66,7 +74,7 @@ from .flash_attention import _PALLAS_SCOPE, NEG_INF, _out_sds
 
 __all__ = ["flash_attention_decode", "paged_kv_append",
            "paged_kv_append_rows", "decode_attention_reference",
-           "decode_walk_blocks", "KERNEL_ROWS"]
+           "decode_walk_blocks", "rows_minor", "KERNEL_ROWS"]
 
 # query rows one kernel call serves: the chunk rides ONE f32 sublane tile
 KERNEL_ROWS = 8
@@ -77,7 +85,8 @@ def _keep(mask, batch):
     return None if mask is None else mask.reshape(batch) > 0
 
 
-def paged_kv_append(cache, new, positions, mask=None, slots=None):
+def paged_kv_append(cache, new, positions, mask=None, slots=None,
+                    row_axis: int = -2):
     """Write ``new`` rows into ``cache`` at per-sequence ``positions``.
 
     cache: [B, ..., S_max, D]; new: [B, ..., L, D]; positions: [B] int —
@@ -87,6 +96,10 @@ def paged_kv_append(cache, new, positions, mask=None, slots=None):
     cache in place. Out-of-range starts clamp (XLA semantics), so a
     retired sequence whose position saturates keeps overwriting the last
     row instead of corrupting a neighbour.
+
+    ``row_axis`` = -1 is the same write in the rows-minor view
+    (:func:`rows_minor`): cache [B, ..., D, S_max], new [B, ..., D, L], a
+    row a column of the last axis.
 
     ``mask`` ([B], > 0 = write) gates the ROWS, never the cache: a
     sequence whose mask is 0 writes its own old rows back (they are read
@@ -113,9 +126,9 @@ def paged_kv_append(cache, new, positions, mask=None, slots=None):
     # buffer in place, where the batched forms are a gather and a scatter
     # for which the TPU compiler re-lays the whole cache
     def one(b, c):
-        at = b if slots is None else slots[b]
-        start = (at,) + (jnp.int32(0),) * (c.ndim - 3) + (
-            positions[b], jnp.int32(0))
+        start = [jnp.int32(0)] * c.ndim
+        start[0] = b if slots is None else slots[b]
+        start[row_axis] = positions[b]
         n = jax.lax.dynamic_index_in_dim(new, b, 0)
         if keep is not None:
             n = jnp.where(keep[b], n,
@@ -125,9 +138,12 @@ def paged_kv_append(cache, new, positions, mask=None, slots=None):
     return jax.lax.fori_loop(0, B, one, cache)
 
 
-def paged_kv_append_rows(cache, new, positions, mask=None, ring=False):
+def paged_kv_append_rows(cache, new, positions, mask=None, ring=False,
+                         row_axis: int = -2):
     """Chunked KV write with PER-ROW clamping: row ``i`` of ``new``
-    ([B, ..., C, D]) lands at ``min(positions + i, S_max - 1)`` — or, with
+    ([B, ..., C, D]; with ``row_axis`` = -1 cache and rows in the
+    rows-minor view, [B, ..., D, S_max] and [B, ..., D, C], see
+    :func:`rows_minor`) lands at ``min(positions + i, S_max - 1)`` — or, with
     ``ring`` (a windowed layer's cache, whose ``S_max`` rows are the last
     ``S_max`` positions), at ``(positions + i) % S_max``. Unlike
     :func:`paged_kv_append` (one ``dynamic_update_slice`` of the whole
@@ -150,21 +166,23 @@ def paged_kv_append_rows(cache, new, positions, mask=None, ring=False):
     ``mode="drop"`` discards them): unrolled, a 128-row chunk was 3,072
     update ops over 12 layers and its compile took minutes where its
     siblings take seconds."""
-    S = cache.shape[-2]
-    C = new.shape[-2]
+    S = cache.shape[row_axis]
+    C = new.shape[row_axis]
     B = cache.shape[0]
     positions = positions.reshape(B).astype(jnp.int32)
     if C <= KERNEL_ROWS:
         for i in range(C):
             row_pos = ((positions + i) % S if ring
                        else jnp.minimum(positions + i, S - 1))
-            cache = paged_kv_append(cache, new[..., i:i + 1, :], row_pos,
-                                    mask)
+            cache = paged_kv_append(
+                cache, jax.lax.slice_in_dim(new, i, i + 1, axis=row_axis),
+                row_pos, mask, row_axis=row_axis)
         return cache
-    if ring:
+    if ring or row_axis != -2:
         raise NotImplementedError(
-            f"a {C}-row chunk into a ring cache: only steps of up to "
-            f"{KERNEL_ROWS} rows wrap")
+            f"a {C}-row chunk into a ring cache or a rows-minor view: only "
+            f"steps of up to {KERNEL_ROWS} rows, the kernel's, wrap or "
+            f"write columns")
     rows = positions[:, None] + jnp.arange(C, dtype=jnp.int32)    # [B, C]
     # every row at or past S-1 clamps onto the last cache row, where the
     # chunk's LAST row wins (what the row-by-row form does). The rows it
@@ -209,10 +227,31 @@ def decode_attention_reference(q, k_cache, v_cache, lengths, scale,
 # K and V bytes ONE grid step carries, at most (the pipeline holds twice
 # that in VMEM, 3 of the 16 MiB scoped to a kernel on a v5e). A step costs
 # 0.2-0.5 us whatever it moves, a third of a microsecond's worth of HBM is
-# 290 KB, so a step of 1-1.5 MB is bound by its bytes (750 GB/s on a full
-# cache) and more rows than that only fetch more rows past the length:
-# tools/probe_decode_walk.py, PERF.md section 6, PR 28
+# 290 KB, so a step of 0.75-1.5 MB is bound by its bytes (740-750 GB/s on
+# a full cache at either end of that range) and more rows than that only
+# fetch more rows past the length: tools/probe_decode_walk.py, PERF.md
+# section 6, PRs 28 and 32
 _STEP_BYTES = 3 << 19
+
+
+def rows_minor(head_dim: int, dtype, page: int) -> bool:
+    """Whether the decode step works on a cache ``[B, H, S_max, D]`` in
+    the view ``[B, H, D, S_max]``: rows in lanes, the head dimension in
+    sublanes. The TPU runtime stores an array whose minor dimension is not
+    whole 128-lane tiles with its second-minor dimension in lanes instead
+    (``f32[64,12,1024,64]{2,3,1,0:T(8,128)}``: nothing padded), so that
+    view is a bitcast of the buffer as it lies, where a Mosaic call on the
+    logical shape wants ``D`` minor, 64 padded to 128 lanes, and XLA
+    converts every cache on the way in and out of the program: 48 copies
+    and 9.86 GB of scratch a chunk at GPT-2's geometry (PERF.md section 6,
+    PR 32). The append has to work in the same view, or the conversion
+    moves into the loop. Read from the shape as the kernel is traced:
+    a head dimension of whole lane tiles (128, 256) keeps the logical
+    view, and so does one that is not whole sublane tiles of ``dtype`` or
+    a ``page`` (the unit of a k-block) that is not whole lane tiles."""
+    sublanes = 8 * (4 // jnp.dtype(dtype).itemsize)
+    return (head_dim % 128 != 0 and head_dim % sublanes == 0
+            and page % 128 == 0)
 
 
 def kv_tile(num_heads: int, s_max: int, head_dim: int, dtype,
@@ -225,20 +264,28 @@ def kv_tile(num_heads: int, s_max: int, head_dim: int, dtype,
     The heads of a sequence share its length, so heads come first: a page
     of as many of them as ``_STEP_BYTES`` holds (a divisor of
     ``num_heads``) makes a step fuller at no row fetched past the length.
-    Then rows: the largest whole number of pages that divides ``s_max``
-    and still fits, since each further page is fetched whole where the
-    length ends inside it."""
+    Then rows: the fewest whole pages (a number that divides ``s_max``'s)
+    that bring the step to half of ``_STEP_BYTES``, from where it is bound
+    by its bytes, or else as many as fit: each further page is fetched
+    whole where the length ends inside it. The head dimension in sublanes
+    (:func:`rows_minor`), a row weighs what it holds: GPT-2's tile stays
+    12 heads x 128 rows at 0.79 MB, where padded to 128 lanes it weighed
+    1.57 (256 rows, the step the budget would also hold, took 0.229 ms a
+    call on the saturated mix against 0.236 and 0.229 against 0.197 on
+    sequences of one key: PR 32's sweep)."""
     page = min(page_size, s_max)
     # K and V of one row of one head in VMEM: the head dimension padded to
-    # whole 128-lane vregs
-    row_bytes = 2 * -(-head_dim // 128) * 128 * jnp.dtype(dtype).itemsize
+    # whole 128-lane vregs, or, rows in lanes, as it is
+    lanes = (head_dim if rows_minor(head_dim, dtype, page)
+             else -(-head_dim // 128) * 128)
+    row_bytes = 2 * lanes * jnp.dtype(dtype).itemsize
     heads = max(h for h in range(1, num_heads + 1) if num_heads % h == 0
                 and (h == 1 or h * page * row_bytes <= _STEP_BYTES))
-    pages = s_max // page
-    rows = page * max(m for m in range(1, pages + 1) if pages % m == 0
-                      and (m == 1
-                           or m * heads * page * row_bytes <= _STEP_BYTES))
-    return heads, rows
+    pages, step = s_max // page, heads * page * row_bytes
+    fits = [m for m in range(1, pages + 1) if pages % m == 0
+            and (m == 1 or m * step <= _STEP_BYTES)]
+    return heads, page * next(
+        (m for m in fits if 2 * m * step >= _STEP_BYTES), fits[-1])
 
 
 def last_live_block(lengths, q_len: int, block_k: int, num_k: int):
@@ -267,11 +314,14 @@ def decode_walk_blocks(lengths, cache_shape, dtype, page_size: int,
     return int(live.sum()), int(live.size * num_k)
 
 
-def _decode_kernel(scale, group, q_len, len_ref, q_ref, k_ref, v_ref,
+def _decode_kernel(scale, group, q_len, minor, len_ref, q_ref, k_ref, v_ref,
                    o_ref, m_scr, l_scr, acc):
     b, ik = pl.program_id(0), pl.program_id(2)
     num_k = pl.num_programs(2)
-    block_k = k_ref.shape[2]
+    # a K or V block is [heads, block_k, D] or, rows-minor, [heads, D,
+    # block_k]: the same products, the block's axes in the other order
+    rows_at, d_at = (2, 1) if minor else (1, 2)
+    block_k = k_ref.shape[1 + rows_at]
     length = len_ref[b]
 
     @pl.when(ik == 0)
@@ -285,8 +335,8 @@ def _decode_kernel(scale, group, q_len, len_ref, q_ref, k_ref, v_ref,
     @pl.when(ik <= last_live_block(length, q_len, block_k, num_k))
     def _walk():
         q = q_ref[...]                              # [heads, R, D]
-        k = k_ref[0]                                # [heads, block_k, D]
-        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+        s = jax.lax.dot_general(q, k_ref[0],
+                                (((2,), (d_at,)), ((0,), (0,))),
                                 preferred_element_type=jnp.float32) * scale
         k_pos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape,
                                                         2)
@@ -307,7 +357,7 @@ def _decode_kernel(scale, group, q_len, len_ref, q_ref, k_ref, v_ref,
         p = jnp.exp(s - m_safe)
         l_new = corr * l_scr[:, :, :1] + jnp.sum(p, axis=2, keepdims=True)
         pv = jax.lax.dot_general(p.astype(v_ref.dtype), v_ref[0],
-                                 (((2,), (1,)), ((0,), (0,))),
+                                 (((2,), (rows_at,)), ((0,), (0,))),
                                  preferred_element_type=jnp.float32)
         acc[:] = acc[:] * corr + pv
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
@@ -320,29 +370,38 @@ def _decode_kernel(scale, group, q_len, len_ref, q_ref, k_ref, v_ref,
             o_ref.dtype)
 
 
-def _kv_index_map(q_len: int, block_k: int, num_k: int):
+def _kv_index_map(q_len: int, block_k: int, num_k: int,
+                  minor: bool = False):
     """Index map of the K and V tiles: k-block ``ik`` while it is live,
-    the last live one after it. The pipeline issues no DMA for a block
-    index that repeats (``kernels/moe.py`` ``frozen``), so nothing past a
-    sequence's length is fetched."""
+    the last live one after it (on the last axis where the cache comes
+    rows-minor). The pipeline issues no DMA for a block index that repeats
+    (``kernels/moe.py`` ``frozen``), so nothing past a sequence's length
+    is fetched."""
     def index(b, hg, ik, lens):
-        return (b, hg, jnp.minimum(
-            ik, last_live_block(lens[b], q_len, block_k, num_k)), 0)
+        blk = jnp.minimum(ik, last_live_block(lens[b], q_len, block_k,
+                                              num_k))
+        return (b, hg, 0, blk) if minor else (b, hg, blk, 0)
     return index
 
 
-def _decode_call(q, k_cache, v_cache, lengths, tile, *, scale, group, q_len,
-                 interpret):
+def _decode_call(q, k_cache, v_cache, lengths, tile, *, minor, scale, group,
+                 q_len, interpret):
     """The Pallas call on ``q`` [B * H, R, D] and caches [B, H, S_max, D]
     with ``tile = (heads, rows)`` of a cache a grid step
-    (:func:`kv_tile`'s choice; ``tools/probe_decode_walk.py`` sweeps it)."""
+    (:func:`kv_tile`'s choice; ``tools/probe_decode_walk.py`` sweeps it).
+    With ``minor`` (:func:`rows_minor` of the cache) the call takes the
+    caches as [B, H, D, S_max], a bitcast of how they lie, and a block of
+    them as ``(1, heads, D, rows)``."""
     B, H = k_cache.shape[:2]
     _, R, D = q.shape
     hb, bk = tile
     nk = k_cache.shape[2] // bk
+    if minor:
+        k_cache, v_cache = k_cache.swapaxes(2, 3), v_cache.swapaxes(2, 3)
     q_spec = pl.BlockSpec((hb, R, D),
                           lambda b, hg, ik, s: (b * (H // hb) + hg, 0, 0))
-    kv_spec = pl.BlockSpec((1, hb, bk, D), _kv_index_map(q_len, bk, nk))
+    kv_spec = pl.BlockSpec((1, hb, D, bk) if minor else (1, hb, bk, D),
+                           _kv_index_map(q_len, bk, nk, minor))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, H // hb, nk),
@@ -355,7 +414,8 @@ def _decode_call(q, k_cache, v_cache, lengths, tile, *, scale, group, q_len,
         ],
     )
     (o,) = pl.pallas_call(
-        functools.partial(_decode_kernel, scale, int(group), int(q_len)),
+        functools.partial(_decode_kernel, scale, int(group), int(q_len),
+                          minor),
         grid_spec=grid_spec,
         out_shape=[_out_sds((B * H, R, D), q.dtype, q, k_cache, v_cache)],
         compiler_params=pltpu.CompilerParams(
@@ -428,5 +488,6 @@ def flash_attention_decode(q, k_cache, v_cache, lengths, *,
         q8, k_cache.reshape(B, num_heads, Sk, D),
         v_cache.reshape(B, num_heads, Sk, D), lengths,
         kv_tile(num_heads, Sk, D, k_cache.dtype, page_size),
-        scale=scale, group=group, q_len=Sq // group, interpret=interpret)
+        minor=rows_minor(D, k_cache.dtype, min(page_size, Sk)), scale=scale,
+        group=group, q_len=Sq // group, interpret=interpret)
     return o8[:, :Sq, :]

@@ -112,7 +112,7 @@ def _fused_decode_attention(ctx, ins, attrs):
     Only single-row steps wrap (a chunk's causal order is its row order).
     """
     from ..kernels import (decode_attention_reference, flash_attention_decode,
-                           paged_kv_append_rows)
+                           paged_kv_append_rows, rows_minor)
 
     q, kn, vn = x(ins, "Q"), x(ins, "KNew"), x(ins, "VNew")
     ck, cv = x(ins, "CacheK"), x(ins, "CacheV")
@@ -137,8 +137,24 @@ def _fused_decode_attention(ctx, ins, attrs):
     page = int(attrs.get("page_size") or 128)
     scale = attrs["scale"] or float(D) ** -0.5
     pos_b = pos.reshape(B).astype(jnp.int32)
-    ck2 = paged_kv_append_rows(ck, kn, pos_b, smask, ring=bool(window))
-    cv2 = paged_kv_append_rows(cv, vn, pos_b, smask, ring=bool(window))
+    route = _route_decode(S, page, q_len=q_len,
+                          platform=lowering_platform(ctx))
+    note_kernel_route(ctx, "fused_decode_attention", route)
+    # the append works in the view the kernel reads (kernels.rows_minor:
+    # [B, H, D, S_max] where the runtime stores the cache so), or a layout
+    # conversion of every cache lands between the two, inside the scan;
+    # the swaps themselves are bitcasts
+    minor = route != "primitive" and rows_minor(D, ck.dtype, min(page, S))
+
+    def append(cache, new):
+        if minor:
+            cache, new = cache.swapaxes(2, 3), new.swapaxes(2, 3)
+        cache = paged_kv_append_rows(cache, new, pos_b, smask,
+                                     ring=bool(window),
+                                     row_axis=-1 if minor else -2)
+        return cache.swapaxes(2, 3) if minor else cache
+
+    ck2, cv2 = append(ck, kn), append(cv, vn)
     lengths = jnp.minimum(pos_b + 1, S)
 
     # the G query heads of one key/value head beside each other, position-
@@ -147,9 +163,6 @@ def _fused_decode_attention(ctx, ins, attrs):
         B * H, q_len * G, D)
     k3 = ck2.reshape(B * H, S, D)
     v3 = cv2.reshape(B * H, S, D)
-    route = _route_decode(S, page, q_len=q_len,
-                          platform=lowering_platform(ctx))
-    note_kernel_route(ctx, "fused_decode_attention", route)
     if route == "primitive":
         o = decode_attention_reference(q3, k3, v3,
                                        jnp.repeat(lengths, H, axis=0), scale,

@@ -20,7 +20,9 @@ import paddle_tpu as fluid
 import paddle_tpu.unique_name as un
 from paddle_tpu import monitor, serving
 from paddle_tpu.kernels import (decode_attention_reference,
-                                decode_walk_blocks, flash_attention_decode)
+                                decode_walk_blocks, flash_attention_decode,
+                                paged_kv_append, paged_kv_append_rows,
+                                rows_minor)
 from paddle_tpu.kernels.decode_attention import (_kv_index_map, kv_tile,
                                                  last_live_block)
 from paddle_tpu.models.gpt import GptConfig, build_gpt_generative
@@ -30,7 +32,11 @@ PAGE = 128
 SHAPES = {
     "f32-d64": (12, 512, 64, jnp.float32, 1),          # GPT-2's, R 8
     "bf16-d128-g16": (8, 1024, 128, jnp.bfloat16, 16),  # Command A+'s
+    # heads of 64 again: the kernel reads all three rows-minor (PR 32)
+    "f32-d64-g2": (12, 512, 64, jnp.float32, 2),
+    "bf16-d64": (24, 512, 64, jnp.bfloat16, 1),
 }
+ROWS_MINOR = {"f32-d64", "f32-d64-g2", "bf16-d64"}
 # name: (lengths in units of (k-blocks, rows): n = blocks * block + rows,
 #        q_len)
 WALKS = {
@@ -48,9 +54,9 @@ def _case(shape, walk):
     spec, q_len = WALKS[walk]
     _, block = kv_tile(H, S, D, dt, PAGE)
     assert S // block == 4, "the cases count in a cache of four k-blocks"
+    assert rows_minor(D, dt, PAGE) == (shape in ROWS_MINOR)
     lengths = np.array([b * block + r for b, r in spec], np.int32)
-    if G > 1:
-        q_len = min(q_len, 2)       # 2 x 16 heads: two sublane tiles
+    q_len = min(q_len, 32 // G)     # 2 x 16 heads: two sublane tiles
     return H, S, D, dt, G, block, lengths, q_len
 
 
@@ -93,19 +99,154 @@ def test_kernel_scores_nothing_past_a_sequences_length(shape, walk):
                                    **tol)
 
 
+@pytest.mark.parametrize("minor", [False, True])
 @pytest.mark.parametrize("q_len", [1, 8])
-def test_index_map_repeats_the_last_live_block(q_len):
+def test_index_map_repeats_the_last_live_block(q_len, minor):
     """Past a sequence's last live block the K and V block index repeats,
-    which is what makes the pipeline issue no DMA there."""
+    which is what makes the pipeline issue no DMA there: on the rows' axis,
+    the last one where the cache comes rows-minor."""
     block, num_k = 128, 8
     lens = np.array([0, 1, 127, 128, 129, 300, 1017, 1024], np.int32)
-    index = _kv_index_map(q_len, block, num_k)
+    index = _kv_index_map(q_len, block, num_k, minor)
     for b, n in enumerate(lens):
         last = min(max(int(n) + q_len - 2, 0) // block, num_k - 1)
         assert int(last_live_block(n, q_len, block, num_k)) == last
         walked = [tuple(int(i) for i in index(b, 3, ik, lens))
                   for ik in range(num_k)]
-        assert walked == [(b, 3, min(ik, last), 0) for ik in range(num_k)]
+        assert walked == [
+            (b, 3, 0, min(ik, last)) if minor else (b, 3, min(ik, last), 0)
+            for ik in range(num_k)]
+
+
+def test_view_is_read_from_the_cache_shape():
+    """Rows in lanes where the head dimension is whole sublane tiles of the
+    dtype and not whole lane tiles, and a page is whole lane tiles (a
+    k-block is then whole vregs of rows): GPT-2's 64. The other decoders'
+    heads, 128 and 256, keep the logical view, and so does every small
+    shape the CPU tests drive."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    assert rows_minor(64, f32, 128) and rows_minor(64, bf16, 128)
+    assert rows_minor(96, f32, 256) and rows_minor(192, bf16, 128)
+    for D, dt, page in [(128, bf16, 128), (256, bf16, 128), (128, f32, 128),
+                        (64, f32, 64), (64, f32, 8), (16, f32, 8),
+                        (8, bf16, 128), (4, f32, 128), (72, bf16, 128)]:
+        assert not rows_minor(D, dt, page)
+
+
+# name: dtype, rows a step, start rows of four sequences in a cache of 256
+# rows (mask 1, 0, 1, 1), ring
+COLUMN_APPENDS = {
+    "f32-step": (jnp.float32, 1, (3, 200, 255, 300), False),
+    "bf16-step": (jnp.bfloat16, 1, (0, 7, 128, 255), False),
+    "f32-chunk-across-the-end": (jnp.float32, 8, (3, 100, 250, 255), False),
+    "bf16-chunk": (jnp.bfloat16, 8, (0, 249, 127, 300), False),
+    "f32-ring": (jnp.float32, 1, (3, 255, 256, 1000), True),
+    "bf16-ring": (jnp.bfloat16, 1, (511, 5, 256, 257), True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(COLUMN_APPENDS))
+def test_column_append_is_the_row_append_in_the_other_view(case):
+    """``row_axis=-1`` on the swapped cache writes what the row form writes
+    on the logical one, bit for bit: the per-row clamp onto the last row,
+    the ring, and a masked-out sequence's cache untouched."""
+    dt, rows, positions, ring = COLUMN_APPENDS[case]
+    B, H, S, D = 4, 3, 256, 64
+    rng = np.random.default_rng(zlib.crc32(case.encode()))
+    cache = jnp.asarray(rng.normal(size=(B, H, S, D)), dt)
+    new = jnp.asarray(rng.normal(size=(B, H, rows, D)), dt)
+    pos = jnp.asarray(positions, jnp.int32)
+    mask = jnp.asarray([1.0, 0.0, 1.0, 1.0], jnp.float32)
+    want = np.asarray(paged_kv_append_rows(cache, new, pos, mask, ring=ring),
+                      np.float32)
+    got = np.asarray(paged_kv_append_rows(
+        cache.swapaxes(2, 3), new.swapaxes(2, 3), pos, mask, ring=ring,
+        row_axis=-1).swapaxes(2, 3), np.float32)
+    assert got.tobytes() == want.tobytes()
+    old = np.asarray(cache, np.float32)
+    assert got[1].tobytes() == old[1].tobytes()
+    assert (got[0] != old[0]).any()
+    # written out: row i of a step lands on min(p + i, S - 1), or p % S
+    for b in (0, 2, 3):
+        for i in range(rows):
+            at = (positions[b] + i) % S if ring else min(positions[b] + i,
+                                                         S - 1)
+            if i == rows - 1 or positions[b] + i < S - 1:
+                np.testing.assert_array_equal(
+                    got[b, :, at], np.asarray(new, np.float32)[b, :, i])
+
+
+def test_column_append_takes_slots():
+    """The bulk form in the other view: two sequences' rows into the cache
+    rows their ``slots`` name, one masked out."""
+    B, H, S, D = 4, 3, 256, 64
+    rng = np.random.default_rng(32)
+    cache = jnp.asarray(rng.normal(size=(B, H, S, D)), jnp.float32)
+    new = jnp.asarray(rng.normal(size=(2, H, 5, D)), jnp.float32)
+    args = (jnp.asarray([7, 130]), jnp.asarray([1.0, 0.0]),
+            jnp.asarray([3, 1]))
+    want = np.asarray(paged_kv_append(cache, new, *args))
+    got = np.asarray(paged_kv_append(cache.swapaxes(2, 3), new.swapaxes(2, 3),
+                                     *args, row_axis=-1).swapaxes(2, 3))
+    assert got.tobytes() == want.tobytes()
+    np.testing.assert_array_equal(got[3, :, 7:12], np.asarray(new)[0])
+    np.testing.assert_array_equal(got[[0, 1, 2]], np.asarray(cache)[[0, 1, 2]])
+
+
+# name: dtype, q_len, query heads a key/value head, window
+OP_STEPS = {
+    "f32-step": (jnp.float32, 1, 1, 0),
+    "f32-chunk-g2": (jnp.float32, 8, 2, 0),
+    "bf16-step-g2": (jnp.bfloat16, 1, 2, 0),
+    "bf16-chunk": (jnp.bfloat16, 8, 1, 0),
+    "f32-ring": (jnp.float32, 1, 1, 256),
+}
+
+
+@pytest.mark.parametrize("case", sorted(OP_STEPS))
+def test_op_appends_and_attends_in_one_view(case):
+    """``fused_decode_attention`` on the kernel's route at heads of 64
+    (append and kernel both rows-minor) against its primitive route (both
+    on the logical shape): the caches it returns are the same bits, a
+    masked-out slot's and one clamped onto the last row among them, and
+    the attention agrees."""
+    from paddle_tpu.core.registry import get_op_def
+    from paddle_tpu.lowering import LowerCtx
+    from paddle_tpu.ops.generation import _route_decode
+
+    dt, q_len, G, window = OP_STEPS[case]
+    B, H, S, D = 4, 3, 256, 64
+    rng = np.random.default_rng(zlib.crc32(case.encode()))
+    ins = {"Q": [jnp.asarray(rng.normal(size=(B, H * G, q_len, D)), dt)],
+           "KNew": [jnp.asarray(rng.normal(size=(B, H, q_len, D)), dt)],
+           "VNew": [jnp.asarray(rng.normal(size=(B, H, q_len, D)), dt)],
+           "CacheK": [jnp.asarray(rng.normal(size=(B, H, S, D)), dt)],
+           "CacheV": [jnp.asarray(rng.normal(size=(B, H, S, D)), dt)],
+           "Positions": [jnp.asarray([[5], [130], [S - 3], [700]] if window
+                                     else [[5], [130], [S - 3], [S + 9]])],
+           "SlotMask": [jnp.asarray([[1.0], [0.0], [1.0], [1.0]])]}
+    attrs = {"scale": 0.0, "page_size": PAGE, "window": window}
+    got = {}
+    for mode in ("never", "always"):
+        fluid.set_flags({"FLAGS_use_flash_attention": mode})
+        try:
+            route = _route_decode(S, PAGE, q_len=q_len, platform="cpu")
+            got[route] = get_op_def("fused_decode_attention").lower(
+                LowerCtx(platform="cpu"), ins, attrs)
+        finally:
+            fluid.set_flags({"FLAGS_use_flash_attention": "auto"})
+    assert sorted(got) == ["pallas-interpret", "primitive"]
+    assert rows_minor(D, dt, PAGE)
+    for name in ("CacheKOut", "CacheVOut"):
+        a, b = (np.asarray(got[r][name][0], np.float32) for r in sorted(got))
+        assert a.tobytes() == b.tobytes()
+        old = np.asarray(ins[name[:6]][0], np.float32)
+        assert a[1].tobytes() == old[1].tobytes() and (a[0] != old[0]).any()
+    a, b = (np.asarray(got[r]["Out"][0], np.float32) for r in sorted(got))
+    live = [0, 1, 2] + ([3] if window else [])  # slot 3 ran off its cache
+    tol = dict(atol=2e-5, rtol=1e-4) if dt == jnp.float32 else dict(
+        atol=3e-2, rtol=3e-2)
+    np.testing.assert_allclose(a[live], b[live], **tol)
 
 
 def test_tile_is_whole_pages_of_whole_heads_inside_its_budget():

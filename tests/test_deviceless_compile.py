@@ -55,14 +55,17 @@ def test_flash_attention_fwd_bwd_compiles_at_the_bert_base_shape(v5e):
     assert text.count("tpu_custom_call") >= 3
 
 
-def test_flash_attention_decode_compiles_at_the_gpt2_base_shape(v5e):
-    """8 slots x 12 heads against a 1024-row f32 cache in pages of 128,
-    q_len 8 (the speculative-verify chunk; q_len 1 rides the same tile)."""
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_flash_attention_decode_compiles_at_the_gpt2_base_shape(v5e, dtype):
+    """8 slots x 12 heads against a 1024-row cache in pages of 128, q_len 8
+    (the speculative-verify chunk; q_len 1 rides the same tile): heads of
+    64, which the kernel reads rows-minor, in the configuration's f32 and
+    in bf16 (sublane tiles of 16)."""
     _compiles_with_mosaic(
         lambda q, k, v, n: flash_attention_decode(q, k, v, n, num_heads=12,
                                                   page_size=128),
-        v5e((96, 8, 64), jnp.float32), v5e((96, 1024, 64), jnp.float32),
-        v5e((96, 1024, 64), jnp.float32), v5e((8,), jnp.int32))
+        v5e((96, 8, 64), dtype), v5e((96, 1024, 64), dtype),
+        v5e((96, 1024, 64), dtype), v5e((8,), jnp.int32))
 
 
 def test_kernels_keep_their_names_in_the_compiled_hlo(v5e):
@@ -98,14 +101,15 @@ def test_kernels_keep_their_names_in_the_compiled_hlo(v5e):
                      "flash_attention_fwd"]
 
 
-def _whole_cache_work_in_loops(text, cache_shape):
-    """Names of the instructions outside the entry computation (so inside
-    a ``while`` body) that PRODUCE a whole cache: a ``copy`` or a select
-    of that shape. In-place updates (``dynamic-update-slice``, alone or
-    as a fusion's root) and tuple plumbing are not."""
-    import re
-
-    shape = re.escape("f32[" + ",".join(map(str, cache_shape)) + "]")
+def _whole_cache_work(text, cache_shape, where="loops"):
+    """Names of the instructions that PRODUCE a whole cache, as it is
+    declared or in the rows-minor view (the last two dimensions swapped):
+    a ``copy`` or a select of that shape. In-place updates
+    (``dynamic-update-slice``, alone or as a fusion's root) and tuple
+    plumbing are not. ``where``: outside the entry computation (so inside
+    a ``while`` body), or ``"entry"``, around the loop."""
+    B, H, S, D = cache_shape
+    shape = "f32\\[%d,%d,(?:%d,%d|%d,%d)\\]" % (B, H, S, D, D, S)
     made = re.compile(r"^\s+(?:ROOT )?%?((?:copy|[\w\-]*select[\w\-]*)"
                       r"[.\w]*) = \(?" + shape)
     found, entry = [], False
@@ -113,50 +117,101 @@ def _whole_cache_work_in_loops(text, cache_shape):
         if line.endswith("{") and not line.startswith(" "):
             entry = line.startswith("ENTRY")
         m = made.match(line)
-        if m and not entry and "dynamic-update-slice" not in m.group(1):
+        if m and entry == (where == "entry") \
+                and "dynamic-update-slice" not in m.group(1):
             found.append(m.group(1))
     return found
 
 
-@pytest.mark.parametrize("form", ["rows", "where_over_the_cache"])
-def test_masked_append_keeps_the_scan_carry_in_place(v5e, form):
+def _decode_chunk(B, H, S, D, form, layers=1, steps=4):
     """The decode chunk as ``run_chained`` runs it: a scan whose carry is
-    the donated caches, each step a masked append and the decode kernel.
-    With the mask on the rows, nothing in the loop body produces a whole
-    cache; the old form (``where(m, appended, cache)``, kept here so that
-    the guard is seen to see) costs a select and copies of every cache
-    every token."""
+    the donated caches of ``layers`` layers, each step a masked append and
+    the decode kernel a layer. ``form`` says how the step is made:
+    ``"op"`` is ``fused_decode_attention``'s own rule, which appends in the
+    view the kernel reads; ``"logical_rows"`` appends on the declared shape
+    whatever the kernel reads (the rule before PR 32);
+    ``"where_over_the_cache"`` selects between an appended cache and the
+    old one (the rule before PR 26)."""
+    from paddle_tpu.core.registry import get_op_def
     from paddle_tpu.kernels import paged_kv_append_rows
-
-    B, H, S, D = 8, 12, 1024, 64
+    from paddle_tpu.lowering import LowerCtx
 
     def append(cache, new, pos, mask):
-        if form == "rows":
+        if form == "logical_rows":
             return paged_kv_append_rows(cache, new, pos, mask)
         appended = jax.vmap(lambda c, n, p: jax.lax.dynamic_update_slice(
             c, n, (jnp.int32(0), p, jnp.int32(0))))(cache, new, pos)
         m = (mask.reshape(B) > 0).reshape(B, 1, 1, 1)
         return jnp.where(m, appended, cache)
 
-    def chunk(ck, cv, q, kn, vn, pos, mask):
-        def body(carry, _):
-            ck, cv, pos = carry
-            ck, cv = append(ck, kn, pos, mask), append(cv, vn, pos, mask)
-            o = flash_attention_decode(
-                q.reshape(B * H, 1, D), ck.reshape(B * H, S, D),
-                cv.reshape(B * H, S, D), jnp.minimum(pos + 1, S),
-                num_heads=H, page_size=128)
-            return (ck, cv, pos + mask.reshape(B).astype(pos.dtype)), o
-        return jax.lax.scan(body, (ck, cv, pos), None, length=4)
+    def layer(ck, cv, q, kn, vn, pos, mask):
+        if form == "op":
+            got = get_op_def("fused_decode_attention").lower(
+                LowerCtx(platform="tpu"),
+                {"Q": [q], "KNew": [kn], "VNew": [vn], "CacheK": [ck],
+                 "CacheV": [cv], "Positions": [pos.reshape(B, 1)],
+                 "SlotMask": [mask]}, {"scale": 0.0, "page_size": 128})
+            return got["CacheKOut"][0], got["CacheVOut"][0], got["Out"][0]
+        ck, cv = append(ck, kn, pos, mask), append(cv, vn, pos, mask)
+        o = flash_attention_decode(
+            q.reshape(B * H, 1, D), ck.reshape(B * H, S, D),
+            cv.reshape(B * H, S, D), jnp.minimum(pos + 1, S), num_heads=H,
+            page_size=128)
+        return ck, cv, o.reshape(B, H, 1, D)
 
-    cache, row = v5e((B, H, S, D), jnp.float32), v5e((B, H, 1, D),
-                                                    jnp.float32)
-    text = jax.jit(chunk, donate_argnums=(0, 1)).lower(
-        cache, cache, row, row, row, v5e((B,), jnp.int32),
-        v5e((B, 1), jnp.float32)).compile().as_text()
+    def chunk(caches, q, kn, vn, pos, mask):
+        def body(carry, _):
+            caches, pos, q = carry
+            out = []
+            for ck, cv in zip(caches[::2], caches[1::2]):
+                ck, cv, q = layer(ck, cv, q, kn, vn, pos, mask)
+                out += [ck, cv]
+            return (out, pos + mask.reshape(B).astype(pos.dtype), q), None
+        return jax.lax.scan(body, (caches, pos, q), None, length=steps)[0]
+
+    return jax.jit(chunk, donate_argnums=(0,)), 2 * layers
+
+
+def _compiled_chunk(v5e, B, H, S, D, form, layers=1):
+    chunk, n = _decode_chunk(B, H, S, D, form, layers)
+    row = v5e((B, H, 1, D), jnp.float32)
+    return chunk.lower(
+        [v5e((B, H, S, D), jnp.float32)] * n, row, row, row,
+        v5e((B,), jnp.int32), v5e((B, 1), jnp.float32)).compile()
+
+
+@pytest.mark.parametrize("form", ["op", "logical_rows",
+                                  "where_over_the_cache"])
+def test_masked_append_keeps_the_scan_carry_in_place(v5e, form):
+    """With the mask on the rows and the append in the kernel's view,
+    nothing in the loop body produces a whole cache. The two old forms are
+    kept here so that the guard is seen to see: ``where(m, appended,
+    cache)`` costs a select and copies of every cache every token, and
+    rows appended on the declared shape beside a kernel that reads heads
+    of 64 rows-minor put the layout conversion inside the loop."""
+    text = _compiled_chunk(v5e, 8, 12, 1024, 64, form).as_text()
     assert "tpu_custom_call" in text
-    found = _whole_cache_work_in_loops(text, (B, H, S, D))
-    assert (found == []) if form == "rows" else len(found) >= 2
+    found = _whole_cache_work(text, (8, 12, 1024, 64))
+    assert (found == []) if form == "op" else len(found) >= 2
+
+
+def test_gpt2_chained_decode_converts_no_cache(v5e):
+    """The serving cell's geometry (64 slots x 12 heads x 1,024 rows x 64,
+    f32; two layers of the twelve, a chunk of 4 steps): the runtime stores
+    such a cache rows in lanes, the step appends and attends in that view,
+    so no ``copy`` of a cache stands at the program's entry, at its exit or
+    in the loop, the caches are updated by ``dynamic-update-slice``, and
+    the compiler holds no cache-sized scratch (two conversions a cache and
+    9.86 GB of it at twelve layers before PR 32)."""
+    compiled = _compiled_chunk(v5e, 64, 12, 1024, 64, "op", layers=2)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2
+    shape = (64, 12, 1024, 64)
+    assert _whole_cache_work(text, shape) == []
+    assert _whole_cache_work(text, shape, "entry") == []
+    assert len(re.findall(
+        r"= f32\[64,12,64,1024\]\S* dynamic-update-slice\(", text)) >= 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
 
 
 # -- the sparse-expert decoder's kernels at its published widths (PR 27) ------

@@ -2,7 +2,7 @@
 """What one call of the decode kernel costs, by the tile a grid step carries
 and by how long the sequences are (PERF.md, PR 28).
 
-    chiprun -- python3 tools/probe_decode_walk.py [--parent DIR]
+    chiprun -- python3 tools/probe_decode_walk.py [--parent DIR] [--shape gpt2]
     python3 tools/probe_decode_walk.py --deviceless        # compiles only
 
 Two shapes, the two serving cells' (``f32[64,12,1024,64]`` with one query
@@ -104,7 +104,8 @@ def variants(shape, parent):
                 q[:, -1:], (B * H, R - G, D))], axis=1) if R > G else q
             o = da._decode_call(
                 q8, k.reshape(B, H, S, D), v.reshape(B, H, S, D), n, tile,
-                scale=D ** -0.5, group=G, q_len=1, interpret=False)
+                minor=da.rows_minor(D, dt, PAGE), scale=D ** -0.5, group=G,
+                q_len=1, interpret=False)
             return o[:, :G]
         return call
 
@@ -118,6 +119,8 @@ def main(argv=None) -> int:
     ap.add_argument("--parent", help="an unpacked other commit to time too")
     ap.add_argument("--deviceless", action="store_true",
                     help="compile every variant for a v5e, time nothing")
+    ap.add_argument("--shape", choices=sorted(SHAPES),
+                    help="one of the two shapes only")
     ap.add_argument("--calls", type=int, default=24)
     ap.add_argument("--reps", type=int, default=5)
     ap.add_argument("--seed", type=int, default=0)
@@ -138,6 +141,8 @@ def main(argv=None) -> int:
 
     results = []
     for name, shape in SHAPES.items():
+        if args.shape not in (None, name):
+            continue
         B, H, S, D, dt, G = shape
         chosen = "tile %dx%d" % da.kv_tile(H, S, D, dt, PAGE)
         for label, call in variants(shape, parent).items():
