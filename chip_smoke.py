@@ -346,6 +346,35 @@ def leg_kernels() -> dict:
     say(leg, f"gdn_decode_step {R}x{Hv}x{Dh}x{Dh}: max|err| {e:.2e}")
     check(e <= 1e-4, "gdn_decode_step agrees with one step of the token "
                      "loop (<= 1e-4)")
+    # -- latent attention (kernels/latent_attention.py): the decode kernel
+    #    over a latent cache at the published widths, 20 heads on rows of
+    #    512 + 64 in 640 lanes, bf16, 4,096 rows in blocks of 1,024: lengths
+    #    at a block's end, across one, one row, the full cache, and a slot
+    #    that sees nothing
+    from paddle_tpu.kernels.latent_attention import (
+        latent_block_rows, latent_row_width, mla_decode_attention,
+        mla_decode_attention_reference)
+
+    Bl, Hl, Sl, dc, dr = 6, 20, 4096, 512, 64
+    Wl = latent_row_width(dc, dr)
+    used = jnp.arange(Wl) < dc + dr
+    bf = lambda *shape: jnp.where(
+        used, jnp.asarray(rng.randn(*shape) * 0.5, jnp.bfloat16), 0)
+    ql, cl = bf(Bl, Hl, Wl), bf(Bl, Sl, Wl)
+    lens = jnp.asarray([1024, 1025, 1, 4096, 0, 2500], jnp.int32)
+    check(Wl == 640 and latent_block_rows(Sl, Wl, jnp.bfloat16, 128) == 1024,
+          "a latent cache of 512 + 64 lies in 640 lanes and is walked in "
+          "blocks of 1,024 rows")
+    u_k = jax.jit(lambda *a: mla_decode_attention(
+        *a, latent_dim=dc, scale=1 / 16, page_size=128))(ql, cl, lens)
+    u_r = jax.jit(lambda *a: mla_decode_attention_reference(
+        *a, dc, 1 / 16))(ql, cl, lens)
+    e = maxerr(u_k.astype(jnp.float32), u_r.astype(jnp.float32))
+    say(leg, f"mla_decode_attention {Bl}x{Hl}x{Sl}x({dc}+{dr}) bf16: "
+             f"max|err| {e:.2e}")
+    check(e <= 2e-2 and not bool(jnp.any(u_k[4])),
+          "mla_decode_attention agrees with its reference (<= 2e-2, bf16 "
+          "probabilities against f32), and a slot that sees no key gives 0")
     hbm(leg)
     return {}
 

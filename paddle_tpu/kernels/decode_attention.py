@@ -255,7 +255,7 @@ def rows_minor(head_dim: int, dtype, page: int) -> bool:
 
 
 def kv_tile(num_heads: int, s_max: int, head_dim: int, dtype,
-            page_size: int):
+            page_size: int, row_bytes: int = None):
     """``(heads, rows)`` of a cache that ONE grid step of the decode kernel
     carries, from what the kernel sees when it is traced. ``page_size`` is
     the cache's page, the unit of everything outside this module; the tile
@@ -272,13 +272,16 @@ def kv_tile(num_heads: int, s_max: int, head_dim: int, dtype,
     12 heads x 128 rows at 0.79 MB, where padded to 128 lanes it weighed
     1.57 (256 rows, the step the budget would also hold, took 0.229 ms a
     call on the saturated mix against 0.236 and 0.229 against 0.197 on
-    sequences of one key: PR 32's sweep)."""
+    sequences of one key: PR 32's sweep). ``row_bytes``: what a step
+    fetches of one row of one head where that is not a K and a V row of
+    ``head_dim`` (a latent cache's row is fetched once)."""
     page = min(page_size, s_max)
     # K and V of one row of one head in VMEM: the head dimension padded to
     # whole 128-lane vregs, or, rows in lanes, as it is
     lanes = (head_dim if rows_minor(head_dim, dtype, page)
              else -(-head_dim // 128) * 128)
-    row_bytes = 2 * lanes * jnp.dtype(dtype).itemsize
+    if row_bytes is None:
+        row_bytes = 2 * lanes * jnp.dtype(dtype).itemsize
     heads = max(h for h in range(1, num_heads + 1) if num_heads % h == 0
                 and (h == 1 or h * page * row_bytes <= _STEP_BYTES))
     pages, step = s_max // page, heads * page * row_bytes
@@ -301,13 +304,15 @@ def last_live_block(lengths, q_len: int, block_k: int, num_k: int):
 
 
 def decode_walk_blocks(lengths, cache_shape, dtype, page_size: int,
-                       q_len: int = 1):
+                       q_len: int = 1, rows: int = None):
     """``(fetched, capacity)``: the k-blocks one call of the kernel fetches
     for sequences of ``lengths`` (host integers, visible keys of query row
     0) out of those their caches ``[B, H, S_max, D]`` hold. Pure host
-    arithmetic on the kernel's own tile and walk."""
+    arithmetic on the kernel's own tile and walk (``rows``: the rows of a
+    step of another kernel that walks as this one does)."""
     _, H, S, D = cache_shape
-    _, rows = kv_tile(H, S, D, dtype, page_size)
+    if rows is None:
+        _, rows = kv_tile(H, S, D, dtype, page_size)
     num_k = S // rows
     live = last_live_block(np.asarray(lengths, np.int64), q_len, rows,
                            num_k) + 1

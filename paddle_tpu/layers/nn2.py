@@ -37,7 +37,7 @@ __all__ = [
     "beam_search", "beam_search_decode", "filter_by_instag",
     "fused_decode_attention", "kv_cache_append", "sequence_gather",
     "rotary_embedding", "moe_experts", "slot_assign", "gated_delta_rule",
-    "rms_norm",
+    "rms_norm", "latent_attention",
     "sample_token", "spec_accept",
 ]
 
@@ -1021,9 +1021,42 @@ def gated_delta_rule(x, conv_w, a, b, a_log, dt_bias, state, conv_state,
     return out, stats
 
 
+def latent_attention(q, c, k_rope, kv_b_w, cache, positions, nope_dim,
+                     mode="decode", page_size=128, slot_mask=None,
+                     slots=None, name=None):
+    """Multi-head latent attention over a latent cache
+    (ops/latent_attention.py). ``q`` [B, heads, S, dn + dr] (``nope_dim`` =
+    dn; the rotary part turned), ``c`` [B, S, dc] and ``k_rope`` [B, S, dr]
+    the rows to append, ``kv_b_w`` [dc, heads x (dn + dv)] the
+    up-projection. ``cache`` [slots, 1, S_max, W] (rows ``[c | k_rope |
+    0]``, ``W`` whole lane tiles) is written in place. ``mode="prefill"``:
+    whole prompts written at row 0 of the slots ``slots`` names (where
+    ``slot_mask`` > 0), keys and values expanded, the flash kernel.
+    ``mode="decode"``: one row a slot at ``positions`` under the gate
+    ``slot_mask``, the up-projection absorbed, attention over the latent
+    rows. Returns ``(out [B, heads, S, dv], stats [1] int32: cache rows
+    the attention read)``."""
+    helper = LayerHelper("latent_attention", name=name)
+    out = helper.create_variable_for_type_inference(q.dtype)
+    stats = helper.create_variable_for_type_inference("int32",
+                                                      stop_gradient=True)
+    inputs = {"Q": q, "C": c, "KRope": k_rope, "KVBW": kv_b_w,
+              "Cache": cache, "Positions": positions}
+    if slot_mask is not None:
+        inputs["SlotMask"] = slot_mask
+    if slots is not None:
+        inputs["Slots"] = slots
+    helper.append_op(
+        "latent_attention", inputs=inputs,
+        outputs={"Out": out, "CacheOut": cache, "Stats": stats},
+        attrs={"mode": str(mode), "nope_dim": int(nope_dim),
+               "page_size": int(page_size)})
+    return out, stats
+
+
 def moe_experts(x, router_w, gate_w, up_w, down_w, num_experts, top_k,
                 expert_offset=0, token_mask=None, score_fn="sigmoid",
-                name=None):
+                select_bias=None, route_scale=1.0, name=None):
     """The routed experts a chip holds (ops/moe.py): routes ``x`` [..., H]
     (f32) over all ``num_experts`` by ``router_w`` [H, num_experts] and
     returns ``(out, stats)``: the part of the routed sum that the experts
@@ -1032,7 +1065,9 @@ def moe_experts(x, router_w, gate_w, up_w, down_w, num_experts, top_k,
     assignments made, and assignments dropped (always 0). ``token_mask``
     (``x``'s leading shape, > 0 = a real token) keeps padding out of the
     routing. ``score_fn``: ``sigmoid`` of each expert's logit, or
-    ``softmax`` over all of them."""
+    ``softmax`` over all of them. ``select_bias`` [num_experts] f32: the
+    experts are chosen by ``score + bias`` while the weights stay the
+    unbiased scores; ``route_scale`` multiplies the normalised weights."""
     helper = LayerHelper("moe_experts", name=name)
     out = helper.create_variable_for_type_inference("float32")
     stats = helper.create_variable_for_type_inference("int32",
@@ -1041,12 +1076,14 @@ def moe_experts(x, router_w, gate_w, up_w, down_w, num_experts, top_k,
               "DownW": down_w}
     if token_mask is not None:
         inputs["TokenMask"] = token_mask
-    helper.append_op(
-        "moe_experts", inputs=inputs,
-        outputs={"Out": out, "Stats": stats},
-        attrs={"num_experts": int(num_experts), "top_k": int(top_k),
-               "expert_offset": int(expert_offset),
-               "score_fn": str(score_fn)})
+    if select_bias is not None:
+        inputs["SelectBias"] = select_bias
+    attrs = {"num_experts": int(num_experts), "top_k": int(top_k),
+             "expert_offset": int(expert_offset), "score_fn": str(score_fn)}
+    if route_scale != 1.0:
+        attrs["route_scale"] = float(route_scale)
+    helper.append_op("moe_experts", inputs=inputs,
+                     outputs={"Out": out, "Stats": stats}, attrs=attrs)
     return out, stats
 
 

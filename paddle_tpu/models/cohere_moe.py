@@ -42,7 +42,7 @@ from typing import Optional, Tuple
 
 from .. import layers
 from ..framework import Program, program_guard
-from ..initializer import TruncatedNormal
+from ..initializer import TruncatedNormal, Uniform
 from ..layer_helper import LayerHelper
 from ..param_attr import ParamAttr
 from .gpt import _merge_state
@@ -112,8 +112,8 @@ class CohereMoeConfig:
 # decoder's (``models/qwen3_next.py``): what a helper reads of it is
 # ``initializer_range`` and ``dtype``, and for the feed-forward
 # ``hidden_size``, ``intermediate_size`` (an expert's width), ``num_experts``,
-# ``experts_held``, ``expert_offset``, ``top_k``, ``num_shared_experts``
-# and ``score_fn``.
+# ``experts_held``, ``expert_offset``, ``top_k``, ``num_shared_experts``,
+# ``score_fn`` and, where it has them, ``select_bias`` and ``route_scale``.
 
 def _attr(name: str, cfg):
     return ParamAttr(name=name,
@@ -157,25 +157,49 @@ def _expert_weights(name: str, cfg):
             mk("up", [Eh, H, F]), mk("down", [Eh, F, H]))
 
 
-def _ffn(h, hb, p: str, cfg, real=None):
+def _gated_mlp(hb, width: int, name: str, cfg):
+    """``(silu(hb Wg) * (hb Wu)) Wd`` of ``width`` back onto the residual
+    stream (f32): a dense feed-forward, or shared experts side by side."""
+    gate = _proj(hb, width, f"{name}_gate", cfg, act="silu")
+    up = _proj(hb, width, f"{name}_up", cfg)
+    return _proj_out(layers.elementwise_mul(gate, up), cfg.hidden_size,
+                     f"{name}_down", cfg)
+
+
+def _ffn(h, hb, p: str, cfg, real=None, join: str = "mean"):
     """The feed-forward of one layer on the normed rows ``h`` (f32, what
     the router reads) and ``hb`` (the same in ``cfg.dtype``, what the
     matmuls read); ``real`` [B, S] marks the rows that are tokens of a
     sequence this dispatch serves (the rest are routed nowhere). Returns the held experts' part of the routed sum, the
-    mean of the shared experts (both f32) and the expert op's statistics."""
+    shared experts' part (both f32) and the expert op's statistics. How
+    the shared experts join the routed sum is the model's (``join``):
+    their ``mean``; the one expert behind a learned sigmoid gate
+    (``gated``); or their plain ``sum``. A configuration with
+    ``select_bias`` chooses its experts by score plus a stored bias, and
+    one with ``route_scale`` scales the routed weights
+    (``layers.moe_experts``)."""
+    bias = None
+    if getattr(cfg, "select_bias", False):
+        bias = LayerHelper("cohere_moe").create_parameter(
+            ParamAttr(name=f"{p}_router_bias",
+                      initializer=Uniform(-0.1, 0.1)),
+            [cfg.num_experts], "float32")
     routed, stats = layers.moe_experts(
         h, *_expert_weights(p, cfg), num_experts=cfg.num_experts,
         top_k=cfg.top_k, expert_offset=cfg.expert_offset, token_mask=real,
-        score_fn=cfg.score_fn)
+        score_fn=cfg.score_fn, select_bias=bias,
+        route_scale=getattr(cfg, "route_scale", 1.0))
     # the shared experts side by side: columns t*F..(t+1)*F of gate and up,
     # and the same rows of down, are shared expert t, so one product with
     # the stacked down matrix is their sum
-    ns, F = cfg.num_shared_experts, cfg.intermediate_size
-    gate = _proj(hb, ns * F, f"{p}_shared_gate", cfg, act="silu")
-    up = _proj(hb, ns * F, f"{p}_shared_up", cfg)
-    shared = _proj_out(layers.elementwise_mul(gate, up), cfg.hidden_size,
-                       f"{p}_shared_down", cfg)
-    return routed, layers.scale(shared, scale=1.0 / ns), stats
+    ns = cfg.num_shared_experts
+    shared = _gated_mlp(hb, ns * cfg.intermediate_size, f"{p}_shared", cfg)
+    if join != "sum":
+        shared = layers.scale(shared, scale=1.0 / ns)
+    if join == "gated":
+        shared = layers.elementwise_mul(shared, layers.sigmoid(
+            _proj_out(hb, 1, f"{p}_shared_mix", cfg)))
+    return routed, shared, stats
 
 
 def _block(x, i: int, cfg: CohereMoeConfig, positions, real, attend):

@@ -206,10 +206,8 @@ def _block(x, i: int, cfg: Qwen3NextConfig, positions, real, mix):
     x = layers.elementwise_add(x, att)
     h = _norm(x, f"{p}_ln_post", cfg, H)
     hb = layers.cast(h, cfg.dtype)
-    routed, shared, stats = _ffn(h, hb, p, cfg, real)
-    gate = layers.sigmoid(_proj_out(hb, 1, f"{p}_shared_mix", cfg))
-    x = layers.elementwise_add(x, layers.elementwise_add(
-        routed, layers.elementwise_mul(shared, gate)))
+    routed, shared, stats = _ffn(h, hb, p, cfg, real, join="gated")
+    x = layers.elementwise_add(x, layers.elementwise_add(routed, shared))
     return x, stats, rule
 
 

@@ -48,13 +48,23 @@ def _route_moe(T: int, H: int, platform) -> str:
     return "pallas-interpret" if mode == "always" else "primitive"
 
 
-def route_tokens(scores, top_k: int):
+def route_tokens(scores, top_k: int, select_bias=None, scale: float = 1.0):
     """scores [T, E] f32 -> (experts [T, k] int32, weights [T, k] f32):
     the ``top_k`` largest scores of each token (the lower index first among
-    equal scores) and the scores normalised over the chosen ones."""
-    vals, idx = jax.lax.top_k(scores, top_k)
-    return idx.astype(jnp.int32), vals / jnp.sum(vals, axis=-1,
-                                                 keepdims=True)
+    equal scores) and the scores normalised over the chosen ones. With
+    ``select_bias`` [E] the experts are the ``top_k`` largest of ``score +
+    bias`` and the weights still the scores themselves, normalised over
+    the chosen; ``scale`` multiplies the weights."""
+    if select_bias is None:
+        vals, idx = jax.lax.top_k(scores, top_k)
+    else:
+        _, idx = jax.lax.top_k(scores + select_bias.astype(scores.dtype),
+                               top_k)
+        vals = jnp.take_along_axis(scores, idx, axis=-1)
+    weights = vals / jnp.sum(vals, axis=-1, keepdims=True)
+    if scale != 1.0:
+        weights = weights * scale
+    return idx.astype(jnp.int32), weights
 
 
 def _dense_held(xb, wg, wu, wd, combine):
@@ -119,10 +129,11 @@ def _grouped_held(xb, wg, wu, wd, le, local, weights, counts, num_experts,
 @register_op(
     "moe_experts",
     inputs=[IOSpec("X"), IOSpec("RouterW"), IOSpec("GateW"), IOSpec("UpW"),
-            IOSpec("DownW"), IOSpec("TokenMask", optional=True, no_grad=True)],
+            IOSpec("DownW"), IOSpec("TokenMask", optional=True, no_grad=True),
+            IOSpec("SelectBias", optional=True, no_grad=True)],
     outputs=["Out", "Stats"],
     attrs={"num_experts": 0, "top_k": 1, "expert_offset": 0,
-           "score_fn": "sigmoid"},
+           "score_fn": "sigmoid", "route_scale": 1.0},
     grad=None)
 def _moe_experts(ctx, ins, attrs):
     """``X`` [..., H] (f32: the router reads it unrounded); ``RouterW``
@@ -130,8 +141,12 @@ def _moe_experts(ctx, ins, attrs):
     [experts_held, F, H] hold experts ``expert_offset ..
     expert_offset + experts_held - 1``. ``score_fn``: ``sigmoid`` scores
     each expert alone, ``softmax`` all ``num_experts`` against each other;
-    either way the chosen scores are divided by their sum. ``Out`` [..., H] f32: the held
-    experts' part of the routed sum. ``TokenMask`` (optional, ``X``'s
+    either way the chosen scores are divided by their sum, and multiplied
+    by ``route_scale``. ``SelectBias`` (optional, [num_experts] f32): the
+    ``top_k`` are taken of ``score + bias``; the weights are the unbiased
+    scores of the chosen (a bias that balances load without entering the
+    output). ``Out`` [..., H] f32: the held experts' part of the routed
+    sum. ``TokenMask`` (optional, ``X``'s
     leading shape, > 0 = a real token): padding, and the rows of sequences
     that a dispatch does not serve, are routed nowhere; they cost the
     experts nothing and their ``Out`` rows are 0 (identical padding rows
@@ -164,7 +179,9 @@ def _moe_experts(ctx, ins, attrs):
         else:
             scores = router_scores(x2, wr, score_fn=score_fn,
                                    interpret=interpret)
-        experts, weights = route_tokens(scores, k)
+        experts, weights = route_tokens(
+            scores, k, x(ins, "SelectBias"),
+            float(attrs.get("route_scale", 1.0)))
     local = (experts >= off) & (experts < off + Eh)
     made = jnp.int32(T * k)
     mask = x(ins, "TokenMask")

@@ -177,37 +177,46 @@ class GenerativeEngine(ServingEngine):
         # what kind each layer's is (``cache_kinds``; a model that says
         # nothing holds ``full`` pairs): a (K, V) cache pair whose rows
         # follow the sequence (``full``: every position, ``window``: a ring
-        # of the last ones), with row counts and type of its own, or a
-        # ``recurrent`` layer's state, which has no rows at all. And the
-        # per-slot decode gate.
+        # of the last ones), with row counts and type of its own; a
+        # ``latent`` layer's cache, whose rows follow the sequence as a
+        # ``full`` layer's do but are one "head" and no K/V pair (one array
+        # of compressed rows with their rotary keys); or a ``recurrent``
+        # layer's state, which has no rows at all. And the per-slot decode
+        # gate.
         kinds = model.get("cache_kinds", {})
         self._state_kinds = {n: kinds.get(n, "full")
                              for names in model["cache_vars"] for n in names}
-        self._cache_names = [
-            tuple(names) for names in model["cache_vars"]
-            if self._state_kinds[names[0]] != "recurrent"]
+        of_kind = lambda *ks: [tuple(names) for names in model["cache_vars"]
+                               if self._state_kinds[names[0]] in ks]
+        self._cache_names = of_kind("full", "window")
         self._active_var = model["active_var"]
-        # (shape, dtype) of a layer's K cache -> how many layers hold such
-        self._cache_shapes = Counter(
-            (tuple(model["state_vars"][nk][0]), model["state_vars"][nk][1])
-            for nk, _ in self._cache_names)
+        # (shape, dtype) of a layer's K cache, or of a latent layer's one
+        # array -> how many layers hold such
+        sd = lambda n: (tuple(model["state_vars"][n][0]),
+                        model["state_vars"][n][1])
+        self._cache_shapes = Counter(sd(nk) for nk, _ in self._cache_names)
+        self._latent_shapes = Counter(sd(n) for n, in of_kind("latent"))
         # rows a layer's cache holds -> how many layers hold that many
         self._cache_rows = Counter()
-        for (shape, _), n in self._cache_shapes.items():
-            self._cache_rows[int(shape[2])] += n
+        for shapes in (self._cache_shapes, self._latent_shapes):
+            for (shape, _), n in shapes.items():
+                self._cache_rows[int(shape[2])] += n
         # what a dispatch counted on the device, fetched beside its tokens:
         # a model with routed experts hands back the assignments each held
         # expert received (``layers.moe_experts``), one with recurrent
         # layers the rows each layer's rule advanced
-        # (``layers.gated_delta_rule``)
+        # (``layers.gated_delta_rule``), one with latent attention the
+        # cache rows each layer's attention read
+        # (``layers.latent_attention``)
         stats = lambda net: {k: net[f"{k}_stats"].name
-                             for k in ("expert", "rule")
+                             for k in ("expert", "rule", "latent")
                              if net.get(f"{k}_stats") is not None}
         self._stats_fetch = {
             "decode": stats(decode),
             **{("prefill", b): stats(net)
                for b, net in model["prefill"].items()}}
         self._rule_layers = list(decode.get("rule_layers", ()))
+        self._expert_layers = list(decode.get("expert_layers", ()))
         gc = self.gen_config
         self._prefix_cache = None
         if gc.prefix_cache and self._chunk is not None:
@@ -244,7 +253,9 @@ class GenerativeEngine(ServingEngine):
                     "bytes of per-layer state the engine planted, by the "
                     "kind of layer that owns them (window: a ring of the "
                     "last positions' keys and values; full: every "
-                    "position's; recurrent: a fixed-size state)"
+                    "position's; latent: every position's compressed row "
+                    "and rotary key, one for all heads; recurrent: a "
+                    "fixed-size state)"
                 ).labels(kind=kind).set(float(nbytes))
 
     def _ensure_state(self) -> None:
@@ -965,6 +976,7 @@ class GenerativeEngine(ServingEngine):
         if not active or not _monitor.enabled():
             return
         from ..kernels import decode_walk_blocks
+        from ..kernels.latent_attention import latent_walk_blocks
 
         # step s of the chunk sees the keys so far and its own
         lengths = (np.array([len(r.prompt) + r.emitted for r in active])
@@ -972,6 +984,10 @@ class GenerativeEngine(ServingEngine):
         fetched = held = 0
         for (shape, dt), n in self._cache_shapes.items():
             f, h = decode_walk_blocks(np.minimum(lengths, shape[2]), shape,
+                                      np_dtype(dt), self._page_size)
+            fetched, held = fetched + n * f, held + n * h
+        for (shape, dt), n in self._latent_shapes.items():
+            f, h = latent_walk_blocks(np.minimum(lengths, shape[2]), shape,
                                       np_dtype(dt), self._page_size)
             fetched, held = fetched + n * f, held + n * h
         _monitor.histogram(
@@ -1173,23 +1189,49 @@ class GenerativeEngine(ServingEngine):
             self._observe_expert_stats(phase, np.asarray(got["expert"]))
         if "rule" in got:
             self._observe_rule_stats(phase, np.asarray(got["rule"]))
+        if "latent" in got:
+            self._observe_latent_stats(phase, np.asarray(got["latent"]))
+
+    def _observe_latent_stats(self, phase: str, stats) -> None:
+        """What a dispatch's latent-attention layers counted
+        (``layers.latent_attention`` ``Stats``, [..., layers, 1]; a chained
+        decode stacks its steps in front): the cache rows each layer's
+        attention read, an execution at a time."""
+        self._count_by_layer(
+            phase, stats, (),
+            _monitor.counter(
+                "latent_attention_rows_total",
+                "latent-cache rows the attention read, by layer and phase "
+                "of the dispatch: in decode whole blocks up to each "
+                "sequence's last live one, in prefill the bucket's rows"),
+            _monitor.counter(
+                "latent_attention_calls_total",
+                "executions of the latent attention op"))
 
     def _observe_rule_stats(self, phase: str, stats) -> None:
         """What a dispatch's recurrent layers counted
         (``layers.gated_delta_rule`` ``Stats``, [..., layers, 1]; a chained
         decode stacks its steps in front): the real rows each layer's rule
         advanced, an execution at a time."""
+        self._count_by_layer(
+            phase, stats, self._rule_layers,
+            _monitor.counter(
+                "gdn_tokens_total",
+                "rows of real tokens the gated delta rule advanced, by "
+                "layer and phase of the dispatch"),
+            _monitor.counter(
+                "gdn_calls_total", "executions of the gated delta rule op"))
+
+    @staticmethod
+    def _count_by_layer(phase: str, stats, layers, rows, calls) -> None:
+        """``stats`` [..., n, 1]: one count an execution from each of ``n``
+        ops of a kind, in layer order (``layers`` names their layers where
+        they are not all of them). Sums go on ``rows``, executions on
+        ``calls``, by layer and phase."""
         stats = stats.reshape(-1, stats.shape[-2]).astype(np.int64)
-        tokens = _monitor.counter(
-            "gdn_tokens_total",
-            "rows of real tokens the gated delta rule advanced, by layer "
-            "and phase of the dispatch")
-        calls = _monitor.counter(
-            "gdn_calls_total", "executions of the gated delta rule op")
         for j in range(stats.shape[1]):
-            layer = self._rule_layers[j] if self._rule_layers else j
-            lab = dict(layer=str(layer), phase=phase)
-            tokens.labels(**lab).inc(float(stats[:, j].sum()))
+            lab = dict(layer=str(layers[j] if layers else j), phase=phase)
+            rows.labels(**lab).inc(float(stats[:, j].sum()))
             calls.labels(**lab).inc(float(stats.shape[0]))
 
     def _observe_expert_stats(self, phase: str, stats) -> None:
@@ -1210,10 +1252,11 @@ class GenerativeEngine(ServingEngine):
             "the expert op's executions")
         calls = _monitor.counter(
             "moe_expert_calls_total", "executions of the expert op")
-        for layer in range(stats.shape[1]):
+        for j in range(stats.shape[1]):
+            layer = self._expert_layers[j] if self._expert_layers else j
             lab = dict(layer=str(layer), phase=phase)
-            tokens.labels(**lab).inc(float(load[:, layer].sum()))
-            hit.labels(**lab).inc(float((load[:, layer] > 0).sum()))
+            tokens.labels(**lab).inc(float(load[:, j].sum()))
+            hit.labels(**lab).inc(float((load[:, j] > 0).sum()))
             calls.labels(**lab).inc(float(stats.shape[0]))
         mean = load.mean(axis=-1)
         skew = _monitor.histogram(

@@ -453,7 +453,7 @@ def test_engine_serves_the_tiny_model(window, max_seq, rows):
     share = fams["moe_local_assignment_share"]["values"][0]["value"]
     assert 0.0 < share < 0.5                    # 2 of 16 experts held
     assert {v["labels"]["kind"] for v in
-            fams["serving_kv_cache_bytes"]["values"]} == {"window", "full"}
+            fams["serving_kv_cache_bytes"]["values"]} >= {"window", "full"}
 
 
 def test_a_prompt_bucket_past_the_window_is_refused_by_mechanism():

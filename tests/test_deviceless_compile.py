@@ -313,3 +313,70 @@ def test_softmax_router_and_head_256_attention_compile(v5e):
     _compiles_with_mosaic(
         lambda q, k, v: flash_attention(q, k, v, causal=True, num_heads=16),
         v5e((16, 3072, 256), bf), kv, kv)
+
+
+# -- the latent-attention decoder's kernels at its published widths (PR 33) ---
+
+def test_mla_decode_attention_compiles_at_the_published_widths(v5e):
+    """One decode step of 128 slots: 20 heads (32 sublanes) on a latent
+    cache of 4,096 rows of 512 + 64 in 640 lanes, bf16, in blocks of 1,024
+    rows; nothing of the cache's shape is copied on the way in."""
+    from paddle_tpu.kernels.latent_attention import (latent_block_rows,
+                                                     mla_decode_attention)
+
+    bf = jnp.bfloat16
+    assert latent_block_rows(4096, 640, bf, 128) == 1024
+    text = _compiles_with_mosaic(
+        lambda q, cache, n: mla_decode_attention(
+            q, cache, n, latent_dim=512, scale=1 / 16, page_size=128),
+        v5e((128, 20, 640), bf), v5e((128, 4096, 640), bf),
+        v5e((128,), jnp.int32))
+    assert re.search(r"%mla_decode_attention[.\d]* = ", text)
+    assert not re.search(r"%copy[.\d]* = bf16\[128,4096,640\]", text)
+
+
+def _latent_op(v5e, mode, B, S, donate=True):
+    """The op's rule lowered for a TPU on ``B`` sequences of ``S`` rows and
+    a cache of 128 slots x 4,096 rows at the published widths."""
+    import paddle_tpu  # noqa: F401  (registers the ops)
+    from paddle_tpu.core.registry import get_op_def
+    from paddle_tpu.lowering import LowerCtx
+
+    bf = jnp.bfloat16
+    rule = get_op_def("latent_attention").lower
+
+    def fn(q, c, kr, w, cache, pos, smask, slots):
+        ins = {"Q": [q], "C": [c], "KRope": [kr], "KVBW": [w],
+               "Cache": [cache], "Positions": [pos], "SlotMask": [smask]}
+        if mode == "prefill":
+            ins["Slots"] = [slots]
+        out = rule(LowerCtx(platform="tpu"), ins,
+                   {"mode": mode, "nope_dim": 192, "page_size": 128})
+        return out["Out"][0], out["CacheOut"][0]
+
+    col = lambda dt: v5e((B, 1), dt)
+    return jax.jit(fn, donate_argnums=(4,)).lower(
+        v5e((B, 20, S, 256), bf), v5e((B, S, 512), bf), v5e((B, S, 64), bf),
+        v5e((512, 20 * 448), bf), v5e((128, 1, 4096, 640), bf),
+        col(jnp.int32), col(jnp.float32), col(jnp.int32)).compile()
+
+
+def test_latent_prefill_compiles_at_the_published_widths(v5e):
+    """The op's prefill form for one prompt of 1,024 rows into a cache of
+    128 slots: keys and values expanded from the latent rows, the causal
+    flash forward over 20 heads of 256, and the bucket written into the
+    named slot in place (donated: no copy of the cache)."""
+    text = _latent_op(v5e, "prefill", 1, 1024).as_text()
+    assert re.search(r"%flash_attention_fwd[.\d]* = ", text)
+    assert not re.search(r"%copy[.\d]* = bf16\[128,1,4096,640\]", text)
+
+
+def test_latent_decode_appends_by_one_scatter_in_place(v5e):
+    """The op's decode form for 128 slots: the step's rows go into the
+    donated cache by one scatter, with no copy of the cache and no
+    cache-sized scratch, and the kernel reads the result."""
+    compiled = _latent_op(v5e, "decode", 128, 1)
+    text = compiled.as_text()
+    assert re.search(r"%mla_decode_attention[.\d]* = ", text)
+    assert not re.search(r"%copy[.\d]* = bf16\[128,1,4096,640\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64e6
