@@ -296,24 +296,25 @@ def leg_kernels() -> dict:
               f"decode_attention_reference (<= {BF16_ATOL}, bf16-rounded "
               f"operands)")
     # -- the append in the view the kernel reads heads of 64 in
-    #    (kernels.rows_minor): columns of [slots, heads, D, rows] against
-    #    the row append on the declared shape, a chunk of 8 rows, one slot
-    #    masked out and two clamped onto the last row
-    from paddle_tpu.kernels import paged_kv_append_rows, rows_minor
+    #    (kernels.rows_minor): the kernel that writes columns of [slots,
+    #    heads, D, rows] in place against the row append on the declared
+    #    shape, a chunk of 8 rows, one slot masked out, one across a block
+    #    edge (row 127 on) and two clamped onto the last row
+    from paddle_tpu.kernels import (kv_append, paged_kv_append_rows,
+                                    rows_minor)
     check(rows_minor(D, jnp.float32, page),
           "a cache of 64-wide heads in pages of 128 is read rows-minor")
     c4 = kc.reshape(Bg, H, S_max, D)
     new = jnp.asarray(rng.randn(Bg, H, 8, D), jnp.float32)
     keep = jnp.asarray([1, 1, 0, 1, 1, 1, 1, 1], jnp.float32)
     by_rows = jax.jit(paged_kv_append_rows)(c4, new, lengths + 6, keep)
-    by_cols = jax.jit(lambda c, n, p, m: paged_kv_append_rows(
-        c.swapaxes(2, 3), n.swapaxes(2, 3), p, m,
-        row_axis=-1).swapaxes(2, 3))(c4, new, lengths + 6, keep)
+    by_cols = jax.jit(lambda c, n, p, m: kv_append(
+        c.swapaxes(2, 3), n, p, m).swapaxes(2, 3))(c4, new, lengths + 6, keep)
     check(bool(jnp.array_equal(by_rows, by_cols))
           and bool(jnp.array_equal(by_cols[2], c4[2]))
           and not bool(jnp.array_equal(by_cols[0], c4[0])),
-          "the column append writes what the row append writes, bit for "
-          "bit, and leaves a masked-out slot's cache as it was")
+          "kv_append writes what the row append writes, bit for bit, and "
+          "leaves a masked-out slot's cache as it was")
     # -- gated delta rule (kernels/gdn.py): the chunked scan over two
     #    prompts in a 640-row bucket (one of 500 real rows) and then the
     #    decode step, 16 key / 32 value heads of 128 x 128, f32, against the

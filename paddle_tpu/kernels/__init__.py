@@ -7,13 +7,13 @@ from .flash_attention import (classify_shapes, flash_attention,
                               supports_shapes)
 from .decode_attention import (KERNEL_ROWS, decode_attention_reference,
                                decode_walk_blocks, flash_attention_decode,
-                               paged_kv_append, paged_kv_append_rows,
-                               rows_minor)
+                               kv_append, paged_kv_append,
+                               paged_kv_append_rows, rows_minor)
 from .latent_attention import (mla_decode_attention,
                                mla_decode_attention_reference)
 
 __all__ = ["flash_attention", "flash_attention_with_lse",
            "flash_attention_bwd", "supports_shapes", "classify_shapes",
-           "flash_attention_decode", "paged_kv_append", "paged_kv_append_rows", "KERNEL_ROWS", "decode_walk_blocks",
+           "flash_attention_decode", "kv_append", "paged_kv_append", "paged_kv_append_rows", "KERNEL_ROWS", "decode_walk_blocks",
            "decode_attention_reference", "rows_minor",
            "mla_decode_attention", "mla_decode_attention_reference"]

@@ -27,16 +27,19 @@ different positions at the cost of the keys they hold.
 CODA (PAPERS.md, arXiv 2605.19269) motivates folding the decode-step
 epilogue work into one op instead of separate ones. The fold is made one
 level up, in the op rule (``ops/generation.py`` ``fused_decode_attention``):
-it appends the chunk's K/V rows with :func:`paged_kv_append_rows` and then
+it appends the chunk's K/V rows (:func:`paged_kv_append_rows`, or
+:func:`kv_append` where the cache is worked on rows-minor) and then
 calls :func:`flash_attention_decode` on the updated caches, so the
 program-IR level sees ONE op that reads and writes the cache at the same
 index (which is what lets ``analysis.liveness.safe_donation_set`` prove the
 cache buffer donatable: its last read is not after its last write).
 :func:`flash_attention_decode` itself only READS the caches and returns the
-attention output; it neither appends nor returns them. The append helpers
-below are plain XLA updates of the donated buffer, one sequence at a time,
-and they take the slot mask: a mask gates the rows that are written, never
-the cache (see :func:`paged_kv_append`).
+attention output; it neither appends nor returns them. The ``paged_``
+append helpers below are plain XLA updates of the donated buffer, one
+sequence at a time, for rows that are whole lane tiles; :func:`kv_append`
+is a Pallas call that aliases the cache, for rows that are columns. All
+take the slot mask: a mask gates the rows that are written, never the
+cache (see :func:`paged_kv_append`).
 
 Design notes
 - q rides in ``[BH, 8, D]`` sublane tiles (Mosaic needs the second-to-last
@@ -72,7 +75,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import _PALLAS_SCOPE, NEG_INF, _out_sds
 
-__all__ = ["flash_attention_decode", "paged_kv_append",
+__all__ = ["flash_attention_decode", "kv_append", "paged_kv_append",
            "paged_kv_append_rows", "decode_attention_reference",
            "decode_walk_blocks", "rows_minor", "KERNEL_ROWS"]
 
@@ -85,8 +88,7 @@ def _keep(mask, batch):
     return None if mask is None else mask.reshape(batch) > 0
 
 
-def paged_kv_append(cache, new, positions, mask=None, slots=None,
-                    row_axis: int = -2):
+def paged_kv_append(cache, new, positions, mask=None, slots=None):
     """Write ``new`` rows into ``cache`` at per-sequence ``positions``.
 
     cache: [B, ..., S_max, D]; new: [B, ..., L, D]; positions: [B] int —
@@ -96,10 +98,6 @@ def paged_kv_append(cache, new, positions, mask=None, slots=None,
     cache in place. Out-of-range starts clamp (XLA semantics), so a
     retired sequence whose position saturates keeps overwriting the last
     row instead of corrupting a neighbour.
-
-    ``row_axis`` = -1 is the same write in the rows-minor view
-    (:func:`rows_minor`): cache [B, ..., D, S_max], new [B, ..., D, L], a
-    row a column of the last axis.
 
     ``mask`` ([B], > 0 = write) gates the ROWS, never the cache: a
     sequence whose mask is 0 writes its own old rows back (they are read
@@ -128,7 +126,7 @@ def paged_kv_append(cache, new, positions, mask=None, slots=None,
     def one(b, c):
         start = [jnp.int32(0)] * c.ndim
         start[0] = b if slots is None else slots[b]
-        start[row_axis] = positions[b]
+        start[-2] = positions[b]
         n = jax.lax.dynamic_index_in_dim(new, b, 0)
         if keep is not None:
             n = jnp.where(keep[b], n,
@@ -138,12 +136,15 @@ def paged_kv_append(cache, new, positions, mask=None, slots=None,
     return jax.lax.fori_loop(0, B, one, cache)
 
 
-def paged_kv_append_rows(cache, new, positions, mask=None, ring=False,
-                         row_axis: int = -2):
+def _row_positions(positions, i: int, s_max: int, ring: bool):
+    """Where row ``i`` of a chunk that starts at ``positions`` lands."""
+    return ((positions + i) % s_max if ring
+            else jnp.minimum(positions + i, s_max - 1))
+
+
+def paged_kv_append_rows(cache, new, positions, mask=None, ring=False):
     """Chunked KV write with PER-ROW clamping: row ``i`` of ``new``
-    ([B, ..., C, D]; with ``row_axis`` = -1 cache and rows in the
-    rows-minor view, [B, ..., D, S_max] and [B, ..., D, C], see
-    :func:`rows_minor`) lands at ``min(positions + i, S_max - 1)`` — or, with
+    ([B, ..., C, D]) lands at ``min(positions + i, S_max - 1)`` — or, with
     ``ring`` (a windowed layer's cache, whose ``S_max`` rows are the last
     ``S_max`` positions), at ``(positions + i) % S_max``. Unlike
     :func:`paged_kv_append` (one ``dynamic_update_slice`` of the whole
@@ -166,23 +167,20 @@ def paged_kv_append_rows(cache, new, positions, mask=None, ring=False,
     ``mode="drop"`` discards them): unrolled, a 128-row chunk was 3,072
     update ops over 12 layers and its compile took minutes where its
     siblings take seconds."""
-    S = cache.shape[row_axis]
-    C = new.shape[row_axis]
+    S = cache.shape[-2]
+    C = new.shape[-2]
     B = cache.shape[0]
     positions = positions.reshape(B).astype(jnp.int32)
     if C <= KERNEL_ROWS:
         for i in range(C):
-            row_pos = ((positions + i) % S if ring
-                       else jnp.minimum(positions + i, S - 1))
             cache = paged_kv_append(
-                cache, jax.lax.slice_in_dim(new, i, i + 1, axis=row_axis),
-                row_pos, mask, row_axis=row_axis)
+                cache, jax.lax.slice_in_dim(new, i, i + 1, axis=-2),
+                _row_positions(positions, i, S, ring), mask)
         return cache
-    if ring or row_axis != -2:
+    if ring:
         raise NotImplementedError(
-            f"a {C}-row chunk into a ring cache or a rows-minor view: only "
-            f"steps of up to {KERNEL_ROWS} rows, the kernel's, wrap or "
-            f"write columns")
+            f"a {C}-row chunk into a ring cache: only steps of up to "
+            f"{KERNEL_ROWS} rows, the kernel's, wrap")
     rows = positions[:, None] + jnp.arange(C, dtype=jnp.int32)    # [B, C]
     # every row at or past S-1 clamps onto the last cache row, where the
     # chunk's LAST row wins (what the row-by-row form does). The rows it
@@ -252,6 +250,71 @@ def rows_minor(head_dim: int, dtype, page: int) -> bool:
     sublanes = 8 * (4 // jnp.dtype(dtype).itemsize)
     return (head_dim % 128 != 0 and head_dim % sublanes == 0
             and page % 128 == 0)
+
+
+_LANES = 128
+
+
+def _append_kernel(pos_ref, keep_ref, new_ref, c_ref, o_ref):
+    b = pl.program_id(0)
+    col = pos_ref[b] % _LANES
+    # sequence b's new column stands in lane b % 128 of its block of `new`;
+    # the roll turns it to the lane its row has in the cache's block
+    # (Mosaic rotates 32-bit lanes only: a 16-bit float widens exactly)
+    new = pltpu.roll(new_ref[...].astype(jnp.float32), (col - b) % _LANES,
+                     2).astype(o_ref.dtype)
+    lane = jax.lax.broadcasted_iota(jnp.int32, new.shape, 2)
+    o_ref[0] = jnp.where((lane == col) & (keep_ref[b] > 0), new, c_ref[0])
+
+
+@jax.named_scope(_PALLAS_SCOPE)
+def kv_append(cache, new, positions, mask=None, ring=False, *,
+              interpret: bool = False):
+    """:func:`paged_kv_append_rows` for a cache in the rows-minor view
+    (:func:`rows_minor`), where a row is a column: ``cache`` [B, H, D,
+    S_max] with ``S_max`` whole lane tiles, ``new`` [B, H, C, D] as the op
+    has it, ``C <= KERNEL_ROWS``; the same per-row clamp or ``ring``, the
+    same ``mask``, the same bits. A column is ``H x D`` numbers in as many
+    ``(8, 128)`` tiles, which XLA writes one sequence at a time at 7 us
+    each (PERF.md section 6, PRs 32 and 34). Here one Pallas call a row of
+    the chunk walks the sequences: the index map picks the cache's block
+    ``(1, H, D, 128)`` that holds the row (``position // 128``, scalar
+    prefetch), the body selects the column in by a lane mask and the block
+    goes back where it came from, the cache being the call's own result
+    (``input_output_aliases``), so a donated cache is updated in place. A
+    sequence whose mask is 0 writes its block back as it was."""
+    B, H, D, S = cache.shape
+    if S % _LANES:
+        raise ValueError(f"kv_append: a rows-minor cache of {S} rows is not "
+                         f"whole {_LANES}-lane tiles")
+    positions = positions.reshape(B).astype(jnp.int32)
+    keep = (jnp.ones((B,), jnp.int32) if mask is None
+            else _keep(mask, B).astype(jnp.int32))
+    # [C, H, D, B]: a row as a column, the sequences beside each other in
+    # whole lane tiles (one block of 128 sequences is fetched once)
+    cols = jnp.pad(new.astype(cache.dtype).transpose(2, 1, 3, 0),
+                   ((0, 0),) * 3 + ((0, -B % _LANES),))
+    c_spec = pl.BlockSpec((1, H, D, _LANES),
+                          lambda b, pos, keep: (b, 0, 0, pos[b] // _LANES))
+    for i in range(new.shape[2]):
+        cache = pl.pallas_call(
+            _append_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(B,),
+                in_specs=[pl.BlockSpec((H, D, _LANES),
+                                       lambda b, pos, keep: (0, 0,
+                                                             b // _LANES)),
+                          c_spec],
+                out_specs=c_spec),
+            out_shape=_out_sds(cache.shape, cache.dtype, cache, cols),
+            input_output_aliases={3: 0},
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=interpret,
+            name="kv_append",
+        )(_row_positions(positions, i, S, ring), keep, cols[i], cache)
+    return cache
 
 
 def kv_tile(num_heads: int, s_max: int, head_dim: int, dtype,

@@ -112,7 +112,7 @@ def _fused_decode_attention(ctx, ins, attrs):
     Only single-row steps wrap (a chunk's causal order is its row order).
     """
     from ..kernels import (decode_attention_reference, flash_attention_decode,
-                           paged_kv_append_rows, rows_minor)
+                           kv_append, paged_kv_append_rows, rows_minor)
 
     q, kn, vn = x(ins, "Q"), x(ins, "KNew"), x(ins, "VNew")
     ck, cv = x(ins, "CacheK"), x(ins, "CacheV")
@@ -143,16 +143,20 @@ def _fused_decode_attention(ctx, ins, attrs):
     # the append works in the view the kernel reads (kernels.rows_minor:
     # [B, H, D, S_max] where the runtime stores the cache so), or a layout
     # conversion of every cache lands between the two, inside the scan;
-    # the swaps themselves are bitcasts
+    # the swaps themselves are bitcasts. There a row is a column, and its
+    # one writer is a kernel that updates the cache in place
     minor = route != "primitive" and rows_minor(D, ck.dtype, min(page, S))
+    if minor:
+        note_kernel_route(ctx, "kv_append", route)
 
     def append(cache, new):
-        if minor:
-            cache, new = cache.swapaxes(2, 3), new.swapaxes(2, 3)
-        cache = paged_kv_append_rows(cache, new, pos_b, smask,
-                                     ring=bool(window),
-                                     row_axis=-1 if minor else -2)
-        return cache.swapaxes(2, 3) if minor else cache
+        if not minor:
+            return paged_kv_append_rows(cache, new, pos_b, smask,
+                                        ring=bool(window))
+        return kv_append(cache.swapaxes(2, 3), new, pos_b, smask,
+                         ring=bool(window),
+                         interpret=(route == "pallas-interpret")
+                         ).swapaxes(2, 3)
 
     ck2, cv2 = append(ck, kn), append(cv, vn)
     lengths = jnp.minimum(pos_b + 1, S)

@@ -12,6 +12,7 @@ without a dead block ever being scored.
 import types
 import zlib
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -21,8 +22,8 @@ import paddle_tpu.unique_name as un
 from paddle_tpu import monitor, serving
 from paddle_tpu.kernels import (decode_attention_reference,
                                 decode_walk_blocks, flash_attention_decode,
-                                paged_kv_append, paged_kv_append_rows,
-                                rows_minor)
+                                kv_append, paged_kv_append,
+                                paged_kv_append_rows, rows_minor)
 from paddle_tpu.kernels.decode_attention import (_kv_index_map, kv_tile,
                                                  last_live_block)
 from paddle_tpu.models.gpt import GptConfig, build_gpt_generative
@@ -133,6 +134,22 @@ def test_view_is_read_from_the_cache_shape():
         assert not rows_minor(D, dt, page)
 
 
+_kv_append_jit = jax.jit(
+    lambda c, n, p, m, ring: kv_append(c, n, p, m, ring, interpret=True),
+    static_argnums=4)
+
+
+def _kernel_append(cache, new, pos, mask=None, ring=False):
+    """``kv_append`` under the interpreter, on the swapped cache, swapped
+    back: what ``fused_decode_attention`` does on its Pallas routes."""
+    return _kv_append_jit(cache.swapaxes(2, 3), new, pos, mask,
+                          ring).swapaxes(2, 3)
+
+
+def _bits(a):
+    return np.asarray(a, np.float32).tobytes()
+
+
 # name: dtype, rows a step, start rows of four sequences in a cache of 256
 # rows (mask 1, 0, 1, 1), ring
 COLUMN_APPENDS = {
@@ -147,9 +164,9 @@ COLUMN_APPENDS = {
 
 @pytest.mark.parametrize("case", sorted(COLUMN_APPENDS))
 def test_column_append_is_the_row_append_in_the_other_view(case):
-    """``row_axis=-1`` on the swapped cache writes what the row form writes
-    on the logical one, bit for bit: the per-row clamp onto the last row,
-    the ring, and a masked-out sequence's cache untouched."""
+    """The kernel on the swapped cache writes what the row form writes on
+    the logical one, bit for bit: the per-row clamp onto the last row, the
+    ring, and a masked-out sequence's cache untouched."""
     dt, rows, positions, ring = COLUMN_APPENDS[case]
     B, H, S, D = 4, 3, 256, 64
     rng = np.random.default_rng(zlib.crc32(case.encode()))
@@ -159,9 +176,7 @@ def test_column_append_is_the_row_append_in_the_other_view(case):
     mask = jnp.asarray([1.0, 0.0, 1.0, 1.0], jnp.float32)
     want = np.asarray(paged_kv_append_rows(cache, new, pos, mask, ring=ring),
                       np.float32)
-    got = np.asarray(paged_kv_append_rows(
-        cache.swapaxes(2, 3), new.swapaxes(2, 3), pos, mask, ring=ring,
-        row_axis=-1).swapaxes(2, 3), np.float32)
+    got = np.asarray(_kernel_append(cache, new, pos, mask, ring), np.float32)
     assert got.tobytes() == want.tobytes()
     old = np.asarray(cache, np.float32)
     assert got[1].tobytes() == old[1].tobytes()
@@ -176,21 +191,93 @@ def test_column_append_is_the_row_append_in_the_other_view(case):
                     got[b, :, at], np.asarray(new, np.float32)[b, :, i])
 
 
-def test_column_append_takes_slots():
-    """The bulk form in the other view: two sequences' rows into the cache
-    rows their ``slots`` name, one masked out."""
+def test_column_append_of_a_chunk_is_the_bulk_write_where_nothing_clamps():
+    """Five rows a sequence, row by row through the kernel, against the
+    bulk form's one ``dynamic_update_slice`` a sequence on the logical
+    cache: inside the cache the two agree, a chunk across a block edge
+    (rows 126-130) and a masked-out sequence among them."""
     B, H, S, D = 4, 3, 256, 64
     rng = np.random.default_rng(32)
     cache = jnp.asarray(rng.normal(size=(B, H, S, D)), jnp.float32)
-    new = jnp.asarray(rng.normal(size=(2, H, 5, D)), jnp.float32)
-    args = (jnp.asarray([7, 130]), jnp.asarray([1.0, 0.0]),
-            jnp.asarray([3, 1]))
-    want = np.asarray(paged_kv_append(cache, new, *args))
-    got = np.asarray(paged_kv_append(cache.swapaxes(2, 3), new.swapaxes(2, 3),
-                                     *args, row_axis=-1).swapaxes(2, 3))
+    new = jnp.asarray(rng.normal(size=(B, H, 5, D)), jnp.float32)
+    pos, mask = jnp.asarray([7, 130, 126, 251]), jnp.asarray([1., 0., 1., 1.])
+    want = np.asarray(paged_kv_append(cache, new, pos, mask))
+    got = np.asarray(_kernel_append(cache, new, pos, mask))
     assert got.tobytes() == want.tobytes()
-    np.testing.assert_array_equal(got[3, :, 7:12], np.asarray(new)[0])
-    np.testing.assert_array_equal(got[[0, 1, 2]], np.asarray(cache)[[0, 1, 2]])
+    np.testing.assert_array_equal(got[2, :, 126:131], np.asarray(new)[2])
+    np.testing.assert_array_equal(got[1], np.asarray(cache)[1])
+
+
+# name: dtype, heads, head dimension (64: GPT-2's; 16: the tiny test models')
+APPEND_SHAPES = {
+    "f32-d64-h12": (jnp.float32, 12, 64),
+    "bf16-d64-h12": (jnp.bfloat16, 12, 64),
+    "f32-d64-h1": (jnp.float32, 1, 64),
+    "bf16-d64-h1": (jnp.bfloat16, 1, 64),
+    "f32-d16-h2": (jnp.float32, 2, 16),
+}
+APPEND_MASKS = {"all-on": (1, 1, 1, 1, 1, 1), "mixed": (1, 0, 0, 1, 0, 1),
+                "all-off": (0, 0, 0, 0, 0, 0), "none": None}
+
+
+@pytest.mark.parametrize("rows", [1, 2, 8])
+@pytest.mark.parametrize("mask", sorted(APPEND_MASKS))
+@pytest.mark.parametrize("shape", sorted(APPEND_SHAPES))
+def test_kv_append_kernel_is_the_row_form_bit_for_bit(shape, mask, rows):
+    """``kv_append`` against ``paged_kv_append_rows``: six sequences in a
+    cache of three lane blocks start at rows 0, 127 (a chunk crosses into
+    the next block), 128, ``S_max - 2``, ``S_max - 1`` and past the end
+    (both clamp onto the last row, where the chunk's last row wins); a
+    sequence whose mask is 0 keeps its cache to the bit."""
+    dt, H, D = APPEND_SHAPES[shape]
+    B, S = 6, 384
+    rng = np.random.default_rng(zlib.crc32(f"{shape}/{mask}/{rows}".encode()))
+    cache = jnp.asarray(rng.normal(size=(B, H, S, D)), dt)
+    new = jnp.asarray(rng.normal(size=(B, H, rows, D)), dt)
+    pos = jnp.asarray([0, 127, 128, S - 2, S - 1, S + 40], jnp.int32)
+    keep = APPEND_MASKS[mask]
+    m = None if keep is None else jnp.asarray(keep, jnp.float32)[:, None]
+    want = paged_kv_append_rows(cache, new, pos, m)
+    got = _kernel_append(cache, new, pos, m)
+    assert got.dtype == cache.dtype and _bits(got) == _bits(want)
+    for b in range(B):
+        same = _bits(got[b]) == _bits(cache[b])
+        assert same == (keep is not None and not keep[b])
+    if keep is None or keep[1]:
+        last = min(rows, 2) - 1      # rows 127 and 128: two lane blocks
+        np.testing.assert_array_equal(
+            np.asarray(got[1, :, 127 + last], np.float32),
+            np.asarray(new[1, :, last], np.float32))
+
+
+def test_kv_append_kernel_walks_more_sequences_than_a_lane_tile():
+    """The sequences' columns ride in lanes, 128 a block of ``new``: 130
+    sequences take a second block."""
+    B, H, S, D = 130, 1, 128, 16
+    rng = np.random.default_rng(34)
+    cache = jnp.asarray(rng.normal(size=(B, H, S, D)), jnp.float32)
+    new = jnp.asarray(rng.normal(size=(B, H, 1, D)), jnp.float32)
+    pos = jnp.asarray(rng.integers(0, S, B), jnp.int32)
+    mask = jnp.asarray(rng.integers(0, 2, B), jnp.float32)
+    assert _bits(_kernel_append(cache, new, pos, mask)) == _bits(
+        paged_kv_append_rows(cache, new, pos, mask))
+
+
+def test_kv_append_kernel_refuses_a_cache_of_broken_lane_tiles():
+    with pytest.raises(ValueError, match="whole 128-lane tiles"):
+        kv_append(jnp.zeros((1, 1, 16, 192)), jnp.zeros((1, 1, 1, 16)),
+                  jnp.zeros((1,), jnp.int32))
+
+
+def _append_routes():
+    """``kernel_route_total{op="kv_append"}`` as {route: lowerings}."""
+    fam = monitor.get_registry().get("kernel_route_total")
+    out = {}
+    for labels, ctr in (fam.children() if fam is not None else ()):
+        if labels["op"] == "kv_append":
+            out[labels["route"]] = out.get(labels["route"], 0) + int(
+                ctr.value)
+    return out
 
 
 # name: dtype, q_len, query heads a key/value head, window
@@ -227,6 +314,7 @@ def test_op_appends_and_attends_in_one_view(case):
            "SlotMask": [jnp.asarray([[1.0], [0.0], [1.0], [1.0]])]}
     attrs = {"scale": 0.0, "page_size": PAGE, "window": window}
     got = {}
+    monitor.reset()
     for mode in ("never", "always"):
         fluid.set_flags({"FLAGS_use_flash_attention": mode})
         try:
@@ -237,6 +325,8 @@ def test_op_appends_and_attends_in_one_view(case):
             fluid.set_flags({"FLAGS_use_flash_attention": "auto"})
     assert sorted(got) == ["pallas-interpret", "primitive"]
     assert rows_minor(D, dt, PAGE)
+    # the rows-minor append is the kernel's, and counted where it engages
+    assert _append_routes() == {"pallas-interpret": 1}
     for name in ("CacheKOut", "CacheVOut"):
         a, b = (np.asarray(got[r][name][0], np.float32) for r in sorted(got))
         assert a.tobytes() == b.tobytes()
@@ -247,6 +337,62 @@ def test_op_appends_and_attends_in_one_view(case):
     tol = dict(atol=2e-5, rtol=1e-4) if dt == jnp.float32 else dict(
         atol=3e-2, rtol=3e-2)
     np.testing.assert_allclose(a[live], b[live], **tol)
+
+
+def _trace_for_tpu(program, fetch):
+    """Trace ``program``'s step as the executor would lower it for a TPU
+    (nothing compiles or runs): routes are counted at trace time."""
+    from paddle_tpu.core.types import np_dtype
+    from paddle_tpu.executor import analyze_block_io, make_step_fn
+
+    block = program.global_block
+    feeds = {n for n, v in block.vars.items() if getattr(v, "is_data", False)}
+    io = analyze_block_io(block, feeds, [fetch.name])
+
+    def shaped(name):
+        v = block.var(name)
+        return jax.ShapeDtypeStruct(
+            tuple(int(d) for d in v.shape),
+            jax.dtypes.canonicalize_dtype(np.dtype(np_dtype(v.dtype))))
+
+    step = make_step_fn(block, io, [fetch.name], platform="tpu")
+    jax.jit(step).trace(*([shaped(n) for n in io[k]] for k in (
+        "feed_order", "donated", "ro")), jax.random.key(0))
+
+
+def _decoder(name):
+    from paddle_tpu.models import cohere_moe, glm4_moe_lite, qwen3_next
+
+    if name == "gpt-heads-of-64":
+        cfg = GptConfig(vocab_size=64, hidden_size=128, num_layers=3,
+                        num_heads=2, intermediate_size=64, max_position=256)
+        return build_gpt_generative(cfg, batch_slots=2, max_seq=256,
+                                    page_size=128, prompt_buckets=(128,))
+    if name == "gpt-tiny":
+        return build_gpt_generative()
+    return {"cohere-moe": cohere_moe.build_cohere_moe_generative,
+            "qwen3-next": qwen3_next.build_qwen3_next_generative,
+            "glm4-moe-lite": glm4_moe_lite.build_glm4_moe_lite_generative}[
+                name]()
+
+
+@pytest.mark.parametrize("name,appends", [
+    ("gpt-heads-of-64", 3), ("gpt-tiny", 0), ("cohere-moe", 0),
+    ("qwen3-next", 0), ("glm4-moe-lite", 0)])
+def test_kv_append_route_counts_the_layers_that_take_the_kernel(name,
+                                                                appends):
+    """``kernel_route_total{op="kv_append"}`` for a decode program lowered
+    for a TPU: one a layer where the caches are worked on rows-minor
+    (heads of 64 in pages of 128), none for the other decoders' caches
+    (the tiny ones here, heads of 128 and 256 or a latent cache at the
+    published widths) though their decode attention rides its kernel."""
+    with un.guard():
+        net = _decoder(name)
+    monitor.reset()
+    _trace_for_tpu(net["decode"]["main"], net["decode"]["next_token"])
+    assert _append_routes() == ({"pallas": appends} if appends else {})
+    fam = monitor.get_registry().get("kernel_route_total")
+    assert any(labels["route"] == "pallas" for labels, _ in fam.children())
 
 
 def test_tile_is_whole_pages_of_whole_heads_inside_its_budget():

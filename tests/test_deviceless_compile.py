@@ -195,23 +195,74 @@ def test_masked_append_keeps_the_scan_carry_in_place(v5e, form):
     assert (found == []) if form == "op" else len(found) >= 2
 
 
-def test_gpt2_chained_decode_converts_no_cache(v5e):
+def test_gpt2_chained_decode_appends_by_one_aliased_call_a_cache(v5e):
     """The serving cell's geometry (64 slots x 12 heads x 1,024 rows x 64,
     f32; two layers of the twelve, a chunk of 4 steps): the runtime stores
     such a cache rows in lanes, the step appends and attends in that view,
     so no ``copy`` of a cache stands at the program's entry, at its exit or
-    in the loop, the caches are updated by ``dynamic-update-slice``, and
-    the compiler holds no cache-sized scratch (two conversions a cache and
-    9.86 GB of it at twelve layers before PR 32)."""
+    in the loop (two conversions a cache and 9.86 GB of scratch at twelve
+    layers before PR 32). The append is one ``kv_append`` call a layer and
+    cache, its cache operand aliased to its result: the scan is the one
+    ``while`` left (a loop over the sequences a cache before PR 34),
+    nothing else produces a whole cache, and the compiler holds no
+    scratch for one."""
     compiled = _compiled_chunk(v5e, 64, 12, 1024, 64, "op", layers=2)
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 2
     shape = (64, 12, 1024, 64)
     assert _whole_cache_work(text, shape) == []
     assert _whole_cache_work(text, shape, "entry") == []
-    assert len(re.findall(
-        r"= f32\[64,12,64,1024\]\S* dynamic-update-slice\(", text)) >= 4
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+    assert len(re.findall(r" while\(", text)) == 1
+    assert len(re.findall(r"%decode_attention[.\d]* = ", text)) == 2
+    cache = r"f32\[64,12,(?:1024,64|64,1024)\]\S* "
+    appends = re.findall(
+        r"%kv_append[.\d]* = " + cache + r"custom-call\(([^)]*)\)"
+        r"[^\n]*output_to_operand_aliasing=\{\{\}: \((\d+), \{\}\)\}", text)
+    assert len(appends) == 4
+    for operands, aliased in appends:
+        assert int(aliased) == len(operands.split(", ")) - 1 == 3
+    made = re.findall(r"%([a-zA-Z_\-]+)[.\w]* = " + cache + r"([a-z\-]+)\(",
+                      text)
+    assert {op for _, op in made} <= {"parameter", "get-tuple-element",
+                                      "bitcast", "custom-call"}
+    assert [n for n, op in made if op == "custom-call"] == ["kv_append"] * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
+
+
+# name: query heads, key/value heads, cache rows, head dim, window
+OTHER_DECODERS = {
+    "command-a-plus-full": (128, 8, 1024, 128, 0),
+    "command-a-plus-ring": (128, 8, 1024, 128, 4096),
+    "qwen3-next": (16, 2, 4096, 256, 0),
+}
+
+
+@pytest.mark.parametrize("decoder", sorted(OTHER_DECODERS))
+def test_heads_of_whole_lane_tiles_keep_the_row_append(v5e, decoder):
+    """The other decoders' decode steps (64 slots, bf16, heads of 128 and
+    256: ``rows_minor`` is false) hold no ``kv_append`` call: their rows
+    are whole lane tiles and the loop form writes them as before, in
+    place."""
+    from paddle_tpu.core.registry import get_op_def
+    from paddle_tpu.lowering import LowerCtx
+
+    Hq, H, S, D, window = OTHER_DECODERS[decoder]
+    B, bf = 64, jnp.bfloat16
+
+    def step(q, kn, vn, ck, cv, pos, mask):
+        got = get_op_def("fused_decode_attention").lower(
+            LowerCtx(platform="tpu"),
+            {"Q": [q], "KNew": [kn], "VNew": [vn], "CacheK": [ck],
+             "CacheV": [cv], "Positions": [pos], "SlotMask": [mask]},
+            {"scale": 0.0, "page_size": 128, "window": window})
+        return got["Out"][0], got["CacheKOut"][0], got["CacheVOut"][0]
+
+    row, cache = v5e((B, H, 1, D), bf), v5e((B, H, S, D), bf)
+    text = jax.jit(step, donate_argnums=(3, 4)).lower(
+        v5e((B, Hq, 1, D), bf), row, row, cache, cache,
+        v5e((B, 1), jnp.int32), v5e((B, 1), jnp.float32)).compile().as_text()
+    assert re.search(r"%decode_attention[.\d]* = ", text)
+    assert not re.search(r"%kv_append[.\d]* = ", text)
+    assert len(re.findall(r"dynamic-update-slice\(", text)) >= 2
 
 
 # -- the sparse-expert decoder's kernels at its published widths (PR 27) ------
@@ -378,5 +429,6 @@ def test_latent_decode_appends_by_one_scatter_in_place(v5e):
     compiled = _latent_op(v5e, "decode", 128, 1)
     text = compiled.as_text()
     assert re.search(r"%mla_decode_attention[.\d]* = ", text)
+    assert not re.search(r"%kv_append[.\d]* = ", text)
     assert not re.search(r"%copy[.\d]* = bf16\[128,1,4096,640\]", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 64e6
