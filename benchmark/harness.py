@@ -332,9 +332,14 @@ def print_result(cell: Cell, chips: dict, result: dict, trace: bool) -> int:
     any name: a CPU number never stands where a device metric would."""
     checks = result["checks"]
     for c in checks:
-        say(f"compared {c['name']}: {c['value']!r} against limit "
-            f"{c['limit']!r} ({c['rule']}) -> "
-            f"{'ok' if c['ok'] else 'NOT CORRECT'}")
+        # on both streams: the free text above the result line, and the
+        # last lines of standard error, which is what is kept of a run
+        # that is not correct
+        text = (f"compared {c['name']}: {c['value']!r} against limit "
+                f"{c['limit']!r} ({c['rule']}) -> "
+                f"{'ok' if c['ok'] else 'NOT CORRECT'}")
+        say(text)
+        print("[benchmark]", text, file=sys.stderr, flush=True)
     correct = bool(checks) and all(c["ok"] for c in checks)
     if cell.rehearse:
         print(json.dumps({
@@ -363,6 +368,10 @@ def print_result(cell: Cell, chips: dict, result: dict, trace: bool) -> int:
         device["window_s"] = result["window_s"]
         if result.get("breakdown"):
             line["breakdown"] = result["breakdown"]
+    # each number compared beside its limit, under a key that comes last
+    line["compared"] = {c["name"]: {"value": c["value"], "limit": c["limit"],
+                                    "rule": c["rule"], "ok": c["ok"]}
+                        for c in checks}
     sys.stdout.flush()
     print(json.dumps(line), flush=True)
     return 0

@@ -60,7 +60,7 @@ def test_the_cell_has_its_hybrid_metrics_and_only_lists_itself():
         else:
             assert CELL not in m.get("workloads", [])
     e2e = {m["name"]: m for m in BENCH["end_to_end"]}
-    assert e2e["decode_tokens_per_s"]["workloads"][-1] == CELL
+    assert CELL in e2e["decode_tokens_per_s"]["workloads"]
 
 
 @pytest.mark.parametrize("metric,hits", [

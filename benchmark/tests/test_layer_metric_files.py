@@ -63,7 +63,9 @@ def test_program_metrics_find_something_in_the_rehearsal(cell):
 
 
 # the left-hand sides of the Mosaic custom calls in the compiled HLO of a
-# v5e (tests/test_deviceless_compile.py keeps them so), with one fusion
+# v5e (tests/test_deviceless_compile.py keeps them so), with one fusion;
+# "append" is the decode step's K/V append of PR 34, a Mosaic call that is
+# no attention
 HLO = {
     "fwd": '%flash_attention_fwd.3 = (f32[24,512,64]{2,1,0:T(8,128)S(1)}, '
            'f32[24,8,512]{2,1,0}) custom-call(%a), '
@@ -75,6 +77,9 @@ HLO = {
            'custom_call_target="tpu_custom_call"',
     "decode": '%decode_attention.1 = f32[96,8,64]{2,1,0} custom-call(%a), '
               'custom_call_target="tpu_custom_call"',
+    "append": '%kv_append.5 = f32[64,12,64,1024]{3,2,1,0:T(8,128)} '
+              'custom-call(%c, %n, %l), '
+              'custom_call_target="tpu_custom_call"',
     "fusion": '%fusion.7 = f32[8]{0} fusion(f32[8]{0} %flash_attention_fwd.3)'
               ', kind=kLoop',
 }
@@ -84,7 +89,9 @@ HLO = {
     ("flash_fwd_time_pct.train", {"fwd"}),
     ("flash_bwd_time_pct.train", {"dq", "dkv"}),
     ("decode_kernel_time_pct.saturated", {"decode"}),
-    ("attention_time_pct.train", {"fwd", "dq", "dkv", "decode"}),
+    ("attention_time_pct.train", {"fwd", "dq", "dkv", "decode", "append"}),
+    ("attention_time_pct.saturated", {"fwd", "decode"}),
+    ("kv_append_time_pct.saturated", {"append"}),
 ])
 def test_kernel_share_patterns(metric, hits):
     args = FILES[metric]["args"]

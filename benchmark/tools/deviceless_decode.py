@@ -1,22 +1,18 @@
 """Compile the chained decode executable (``Executor.run_chained`` over the
 decode program: a scan whose carry is the donated KV caches) for a v5e that
 is described and not attached, and say what ``deviceless.py`` cannot: the
-compiler's memory for it, and which instructions produce a whole cache
-inside the loop and around it. A ``copy`` or a select of cache shape in the
-loop body is paid every token; a ``dynamic-update-slice`` is the in-place
-append.
+compiler's memory for it (``--hlo`` writes the optimized HLO's text, where
+a ``copy`` of cache shape in the loop body would show).
 
     python3 benchmark/tools/deviceless_decode.py [--slots 64,80] [--hlo out.txt]
 
-Run with JAX_PLATFORMS=cpu. Nothing runs on a device; counts of
-instructions and the compiler's bytes, no measurement.
+Run with JAX_PLATFORMS=cpu. Nothing runs on a device; the compiler's
+bytes, no measurement.
 """
 import argparse
-import collections
 import importlib
 import json
 import os
-import re
 import sys
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -27,9 +23,6 @@ sys.path.insert(1, os.path.dirname(HERE))
 import harness                                              # noqa: E402
 from tools.deviceless import (_DescribedPlace, describe_v5e,  # noqa: E402
                               memory_of)
-
-_HEAD = re.compile(r"^(ENTRY )?%?[\w.\-]+ \(.*\{$")
-_PLUMBING = {"get-tuple-element", "parameter", "bitcast", "tuple", "while"}
 
 
 def compile_chained_decode(cfg: dict, dev):
@@ -76,29 +69,6 @@ def compile_chained_decode(cfg: dict, dev):
                          ).compile()
 
 
-def whole_cache_instructions(hlo_text: str, cache_shape) -> dict:
-    """``{"loop": Counter, "entry": Counter}`` of the opcodes whose result
-    is a whole cache (as stored, or reshaped to [B*H, S, D] for the
-    kernel), tuple plumbing left out; fused computations are read through
-    the fusion that calls them."""
-    b, h, s, d = cache_shape
-    shape = re.compile(r" = \(?f32\[(?:%d,%d,%d,%d|%d,%d,%d)\]\{.*?[})] "
-                       r"([a-z][\w\-]*)\(" % (b, h, s, d, b * h, s, d))
-    out = {"loop": collections.Counter(), "entry": collections.Counter()}
-    where = None
-    for line in hlo_text.splitlines():
-        head = _HEAD.match(line)
-        if head:
-            where = None if "fused_computation" in line else (
-                "entry" if head.group(1) else "loop")
-        elif where:
-            m = shape.search(line)
-            if m and m.group(1) not in _PLUMBING:
-                name = re.match(r"\s+(?:ROOT )?%?([\w\-]+?)[.\d]* = ", line)
-                out[where][name.group(1)] += 1
-    return out
-
-
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--config", default="gpt2-base-serve")
@@ -110,8 +80,8 @@ def main():
         cfg = harness.load_json(HERE, "configs", a.config + ".json")
         if slots:
             cfg["serving"]["slots"] = slots
-        m, s = cfg["model"], cfg["serving"]
-        row = {"slots": s["slots"], "program": "chained decode"}
+        row = {"slots": cfg["serving"]["slots"],
+               "program": "chained decode"}
         try:
             compiled = compile_chained_decode(cfg, dev)
         except Exception as e:      # the compiler's refusal is the answer
@@ -119,17 +89,10 @@ def main():
                                   error=str(e).split("\n")[0][:300])),
                   flush=True)
             continue
-        text = compiled.as_text()
-        found = whole_cache_instructions(text, (
-            s["slots"], m["num_heads"], s["max_seq"],
-            m["hidden_size"] // m["num_heads"]))
-        print(json.dumps(dict(row, **memory_of(compiled),
-                              whole_cache_in_loop=dict(found["loop"]),
-                              whole_cache_at_entry=dict(found["entry"]))),
-              flush=True)
+        print(json.dumps(dict(row, **memory_of(compiled))), flush=True)
         if a.hlo:
             with open(a.hlo, "w") as f:
-                f.write(text)
+                f.write(compiled.as_text())
 
 
 if __name__ == "__main__":
