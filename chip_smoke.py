@@ -563,8 +563,10 @@ def leg_gpt(cfg=None, slots: int = 8, max_seq: int = 1024, page: int = 128,
     log.close()
     want = {f"gpt.prefill:{b}": {"fused_multihead_attention:pallas"}
             for b in buckets}
-    want["gpt.decode"] = {"fused_decode_attention:pallas"}
-    want[f"gpt.verify:{spec_k}"] = {"fused_decode_attention:pallas"}
+    # a kernel-routed decode step appends through ``kv_append`` (PR 34),
+    # which counts its own lowerings beside the attention's
+    want["gpt.decode"] = want[f"gpt.verify:{spec_k}"] = {
+        "fused_decode_attention:pallas", "kv_append:pallas"}
     # a 128-row chunk is past the decode kernel's 8-row tile: the chunk
     # program rides the primitive path, and says so
     want[f"gpt.chunk:{net['prefill_chunk']}"] = {

@@ -792,8 +792,8 @@ class Executor:
         # fault site, or a real stuck collective) is dumped + raised as
         # WatchdogTimeout under FLAGS_step_timeout_s
         with jax.default_device(device), \
-                _trace.span("executor.step", cache_hit=cache_hit), \
-                RecordEvent("executor::step"), \
+                _trace.phase("executor.step", timed=mrec is not None) as ph, \
+                self._launched(ph, step, mrec, cache_hit), \
                 _dist.watchdog_section("step", program=program) as tok:
             _faults.fault_point("hang")
             try:
@@ -831,14 +831,14 @@ class Executor:
     def _fetch_to_host(fetches, mrec):
         """The ``executor.fetch`` phase: the host blocks on the device's
         results and copies them back. Its wall is the dispatch's
-        ``fetch_wait_s`` (``executor_fetch_wait_seconds``)."""
+        ``fetch_wait_s``, its exit the dispatch's ``ready_t``."""
         with _trace.phase("executor.fetch", timed=mrec is not None) as ph:
             outs = [np.asarray(v) for v in fetches]
             if ph.traced:
                 ph.set_attributes(bytes=_live_bytes(outs))
         if mrec is not None:
             mrec.fetch_bytes = _live_bytes(outs)
-            mrec.fetch_wait_s = ph.seconds
+            mrec.fetch_wait_s, mrec.ready_t = ph.seconds, ph.t1
         return outs
 
     def run_chained(
@@ -1090,8 +1090,8 @@ class Executor:
             args, fn, rollback, check = self._bind_chained(
                 program, steps, scope, step, mrec, feed_vals)
         with jax.default_device(self.place.jax_device()), \
-                _trace.span("executor.step", cache_hit=cache_hit), \
-                RecordEvent("executor::run_chained"), \
+                _trace.phase("executor.step", timed=mrec is not None) as ph, \
+                self._launched(ph, step, mrec, cache_hit), \
                 _dist.watchdog_section("chained", program=program) as tok:
             _faults.fault_point("hang")
             try:
@@ -1440,3 +1440,46 @@ class Executor:
                 # on_compile hooks waiting forever
                 _monitor.complete_compile(ev, t_trace, t_compile)
         return step._aot or step.fn
+
+    # -- in flight and starved -------------------------------------------
+    # (down here, not beside ``run``: the lines from ``make_step_fn`` to
+    # ``_ensure_executable_locked`` are on every kernel's call stack, whose
+    # source locations are part of the persistent compile cache's key)
+
+    # the ``StepRecord`` of the last launch: its ``ready_t`` (None where it
+    # raised or did not fetch) starts the gap that
+    # ``executor_starved_seconds`` measures at the next launch
+    _last_dispatch: Optional[_monitor.StepRecord] = None
+
+    def forget_last_dispatch(self) -> None:
+        """The next dispatch observes no ``executor_starved_seconds``: what
+        lies between it and the one before is no wait of the device's for
+        the host (a warm-up's end, generation state planted anew, a
+        ``CompiledProgram`` step)."""
+        self._last_dispatch = None
+
+    def _launched(self, ph, step: _CompiledStep, mrec, cache_hit):
+        """Right after the ``executor.step`` phase ``ph`` is entered: the
+        launch's clock reading and the fetch return of the dispatch before
+        go on the ``StepRecord`` (``executor_inflight_seconds``,
+        ``executor_starved_seconds``). Only while ``FLAGS_trace`` is on,
+        what ties the launch to a profile: the span says which dispatch it
+        is (the ``StepRecord``'s ``step_index``) and which module it
+        launched (the executable's name as a device trace prints it on
+        ``XLA Modules``), and the ``TraceAnnotation`` returned, of the
+        span's name, carries the same ``dispatch`` into the profile on the
+        profiler's clock (``trace.join_dispatches`` reads both)."""
+        before, self._last_dispatch = self._last_dispatch, mrec
+        ident = {}
+        if mrec is not None:
+            mrec.launch_t = ph.t0
+            mrec.prev_ready_t = before.ready_t if before is not None else None
+            ident["dispatch"] = mrec.step_index
+        if not ph.traced:
+            return _NOT_TRACED
+        ph.set_attributes(cache_hit=cache_hit,
+                          module="jit_" + step.fn.__name__, **ident)
+        return jax.profiler.TraceAnnotation("executor.step", **ident)
+
+
+_NOT_TRACED = contextlib.nullcontext()

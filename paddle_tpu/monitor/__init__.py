@@ -25,9 +25,9 @@ retry), ``resilience_faults_injected_total`` (FLAGS_fault_plan),
 docs/RESILIENCE.md.
 
 Everything is on by default (``FLAGS_monitor=0`` disables collection —
-hooks, counters and diagnostics all go quiet). Executor spans additionally
-flow through ``profiler.RecordEvent`` so they land in the host timeline
-(``tools/timeline.py``). ``tools/metrics_report.py`` dumps
+hooks, counters and diagnostics all go quiet). The executor's build and
+compile stages additionally flow through ``profiler.RecordEvent`` so they
+land in the host timeline (``tools/timeline.py``). ``tools/metrics_report.py`` dumps
 ``monitor.snapshot()`` as the CI metrics artifact and gates on unexpected
 recompiles. Metric names and semantics: docs/OBSERVABILITY.md.
 """
@@ -209,6 +209,21 @@ def step_end(rec: Optional[StepRecord]) -> None:
             observe_step_cost(prog, rec.batch_rows, rec.duration_s,
                               iterations=rec.iterations, path=rec.path,
                               device_kind=rec.device_kind)
+    if rec.ready_t is not None and rec.launch_t is not None:
+        # a dispatch that fetched. With the gap before it (to the fetch
+        # return of the dispatch before it on the same Executor) the two
+        # tile that executor's life from its first launch to its last
+        # fetch, exactly
+        histogram("executor_inflight_seconds",
+                  "launch call to fetch return of one dispatch: the time "
+                  "the device had this executor's work in flight").labels(
+            **p).observe(rec.ready_t - rec.launch_t)
+        if rec.prev_ready_t is not None:
+            histogram("executor_starved_seconds",
+                      "fetch return of the dispatch before to this "
+                      "dispatch's launch call: the time the device had "
+                      "nothing of this executor's in flight").labels(
+                **p).observe(rec.launch_t - rec.prev_ready_t)
     if rec.feed_bytes:
         counter("executor_feed_bytes_total",
                 "host->device feed transfer bytes").inc(rec.feed_bytes)

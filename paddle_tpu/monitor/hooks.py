@@ -43,6 +43,13 @@ class StepRecord:
     fetch_names: Tuple[str, ...] = ()
     device_kind: str = ""            # jax device_kind the step ran on
     fetch_wait_s: float = 0.0        # of duration_s: host blocked on fetches
+    # time.perf_counter() readings the phases made: entry of executor.step,
+    # exit of executor.fetch (None: the dispatch raised or fetched nothing),
+    # and the ready_t of the launch before it on the same Executor (None:
+    # there was none, it did not fetch, or the executor was told to forget)
+    launch_t: Optional[float] = None
+    ready_t: Optional[float] = None
+    prev_ready_t: Optional[float] = None
 
     def to_dict(self) -> dict:
         return dataclasses.asdict(self)

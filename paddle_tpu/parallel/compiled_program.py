@@ -156,6 +156,7 @@ class CompiledProgram:
         # remat cache; the transformed program's fresh _serial keys this
         # CompiledProgram's own step cache apart from the plain variant
         program = exe._maybe_auto_remat(self._program, feed, fetch_names)
+        exe.forget_last_dispatch()    # no run / run_chained dispatch, this
         mrec = _monitor.step_begin("parallel", program)
         from .. import trace as _trace
 

@@ -6,8 +6,9 @@ tracer (platform/profiler.h, device_tracer.h). On TPU the equivalent
 substrate is the XLA/XPlane trace: jax.profiler.trace writes a TensorBoard-
 loadable (and Perfetto-convertible) dump — the tools/timeline.py role.
 Op-level host annotations use jax.profiler.TraceAnnotation, the RecordEvent
-analogue; ``paddle_tpu.monitor`` feeds its executor spans (compile stages,
-step dispatch) through RecordEvent too, so they land in the same timeline.
+analogue; the executor feeds its build and compile stages through
+RecordEvent too, so they land in the same timeline (the launch of a step is
+the span ``executor.step`` of ``paddle_tpu.trace``, not a RecordEvent).
 
 Thread-safety: all host-side state (event aggregates, span list, tid map)
 is guarded by one module lock — RecordEvent is used from DataLoader worker

@@ -246,6 +246,8 @@ class GenerativeEngine(ServingEngine):
             self._scope.set_var(name, zeros)
             if name in self._state_kinds:
                 held[self._state_kinds[name]] += zeros.nbytes
+        # the time since the last dispatch was no wait of the device's
+        self._exe.forget_last_dispatch()
         if _monitor.enabled():
             for kind, nbytes in held.items():
                 _monitor.gauge(
@@ -658,11 +660,15 @@ class GenerativeEngine(ServingEngine):
         span.end()
         C = self._prefill_chunk
         done: List[_GenRequest] = []
+        seated = 0
         for r in live:
-            r.next_off += min(C, len(r.prompt) - r.next_off)
+            take = min(C, len(r.prompt) - r.next_off)
+            seated += take
+            r.next_off += take
             if r.next_off >= len(r.prompt):
                 r.prefilled = True
                 done.append(r)
+        self._count_prefill_tokens(seated, len(self._slots) * C)
         self._publish(done)
         with _loop_phase("settle") as ph:
             self._note_compiles("chunk", self._prefill_chunk, net["main"])
@@ -805,6 +811,20 @@ class GenerativeEngine(ServingEngine):
         return True
 
     # -- prefill ---------------------------------------------------------
+    @staticmethod
+    def _count_prefill_tokens(prompt: int, run: int) -> None:
+        """What one prefill dispatch's rows were: the prompt tokens it
+        seated against the positions its program ran (rows times bucket or
+        chunk length, padding and empty rows included)."""
+        if _monitor.enabled():
+            tokens = _monitor.counter(
+                "serving_prefill_tokens_total",
+                "tokens of prefill dispatches: kind=prompt the prompt "
+                "tokens seated, kind=run the positions the programs ran "
+                "(rows x bucket length)")
+            tokens.labels(kind="prompt").inc(float(prompt))
+            tokens.labels(kind="run").inc(float(run))
+
     def _prefill_rows(self, bucket: int) -> Optional[int]:
         """Sequences one dispatch of this bucket's program carries, where
         its rows name their slots (``slot_ids``); None where row ``i`` IS
@@ -888,6 +908,10 @@ class GenerativeEngine(ServingEngine):
             with _loop_phase("settle") as ph:
                 self._note_compiles("prefill", bucket, net["main"])
                 self._observe_stats("prefill", ("prefill", bucket), outs[1:])
+                self._count_prefill_tokens(
+                    sum(len(r.prompt) for r in reqs),
+                    (self._prefill_rows(bucket) or len(self._slots))
+                    * bucket)
                 if _monitor.enabled():
                     _monitor.histogram(
                         "serving_prefill_seconds",
@@ -1265,6 +1289,14 @@ class GenerativeEngine(ServingEngine):
             "assignments over the mean of the held experts' (1 = even)")
         for v in (load.max(axis=-1)[mean > 0] / mean[mean > 0]).ravel():
             skew.observe(float(v))
+        held = _monitor.histogram(
+            "moe_held_assignments_per_step",
+            "per execution of the expert op (one step of one layer), the "
+            "assignments that fell on the experts held here: the load a "
+            "seed's router deals this share of the deployment").labels(
+            phase=phase)
+        for v in load.sum(axis=-1).ravel():
+            held.observe(float(v))
         self._moe_local += int(load.sum())
         self._moe_made += int(made.sum())
         _monitor.gauge(

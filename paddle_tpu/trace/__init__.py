@@ -46,6 +46,7 @@ from typing import Any, Dict, List, Optional
 from .. import flags as _flags
 from .. import monitor as _monitor
 from ..monitor.lockwitness import make_lock
+from .dispatch_join import join_dispatches
 
 __all__ = [
     "Span", "SpanContext", "enabled", "span", "root_span", "start_span",
@@ -53,7 +54,7 @@ __all__ = [
     "current_span", "current_context", "attach", "get_collector",
     "SpanCollector", "spans", "clear", "to_chrome_events", "export_chrome",
     "export_jsonl", "record_incident", "incidents", "clear_incidents",
-    "flight_recorder_spans", "trace_tree",
+    "flight_recorder_spans", "trace_tree", "join_dispatches",
 ]
 
 logger = logging.getLogger("paddle_tpu.trace")
@@ -354,12 +355,14 @@ class _Phase:
     """One timed phase of a loop, fed to two sinks: a span (``FLAGS_trace``)
     and a monitor histogram child (``FLAGS_monitor``). See :func:`phase`."""
 
-    __slots__ = ("_span", "_hist", "_t0", "seconds")
+    __slots__ = ("_span", "_hist", "t0", "t1", "seconds")
 
     def __init__(self, span, hist):
         self._span = span
         self._hist = hist
-        self._t0 = 0.0
+        # the two clock readings (``time.perf_counter()``), for a caller
+        # that keeps the instants and not only their distance
+        self.t0 = self.t1 = 0.0
         self.seconds = 0.0
 
     @property
@@ -375,15 +378,15 @@ class _Phase:
     def __enter__(self) -> "_Phase":
         sp = self._span
         if sp is NOOP_SPAN:
-            self._t0 = time.perf_counter()
+            self.t0 = time.perf_counter()
         else:
             sp.__enter__()
-            self._t0 = sp.t0_mono     # the span's own clock reading
+            self.t0 = sp.t0_mono      # the span's own clock reading
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        t1 = time.perf_counter()
-        self.seconds = t1 - self._t0
+        self.t1 = t1 = time.perf_counter()
+        self.seconds = t1 - self.t0
         sp = self._span
         if sp is not NOOP_SPAN:
             sp._leave(exc, t1_mono=t1)
@@ -397,7 +400,7 @@ class _NoopPhase:
 
     __slots__ = ()
     traced = False
-    seconds = 0.0
+    t0 = t1 = seconds = 0.0
 
     def set_attributes(self, **kwargs):
         return self
@@ -419,9 +422,10 @@ def phase(name: str, parent=None, histogram=None, timed: bool = False,
     entry and exit) for two sinks: a child span named ``name`` when
     ``FLAGS_trace`` is on, and an observation of the duration on the
     monitor histogram ``family{labels}`` when ``FLAGS_monitor`` is on.
-    ``ph.seconds`` holds the duration after the block (``timed=True``
-    keeps the clock running with both sinks off, for a caller that stores
-    it, e.g. on a ``StepRecord``); ``ph.traced`` says whether attributes
+    ``ph.seconds`` holds the duration after the block and ``ph.t0`` /
+    ``ph.t1`` the two readings (``timed=True`` keeps the clock running
+    with both sinks off, for a caller that stores them, e.g. on a
+    ``StepRecord``); ``ph.traced`` says whether attributes
     are worth computing. With everything off this returns a singleton:
     no clock read, no allocation (``tools/trace_check.py`` gates it)."""
     sp = _make_span(name, parent, attrs) if enabled() else NOOP_SPAN

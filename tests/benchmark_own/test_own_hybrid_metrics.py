@@ -2,20 +2,3 @@
 from _own import load
 
 globals().update(load("test_hybrid_metrics.py"))
-
-
-def test_the_cell_has_its_hybrid_metrics_and_only_lists_itself():
-    """The loaded file's test of this name with one line put right: it
-    asks that the cell be the LAST of ``decode_tokens_per_s``'s workloads,
-    which held until the next cell was appended (PR 33); what it means is
-    that the cell is listed. No file under ``benchmark/`` may be edited by
-    a PR that adds a cell, so the repair waits for a ``benchmark`` PR
-    (PERF.md section 7) and this module carries the test meanwhile."""
-    assert len(NAMES) == 15                                   # noqa: F821
-    for m in BENCH["per_layer"]:                              # noqa: F821
-        if m["name"].endswith(".hybrid"):
-            assert m["workloads"] == [CELL]                   # noqa: F821
-        else:
-            assert CELL not in m.get("workloads", [])         # noqa: F821
-    e2e = {m["name"]: m for m in BENCH["end_to_end"]}         # noqa: F821
-    assert CELL in e2e["decode_tokens_per_s"]["workloads"]    # noqa: F821

@@ -19,6 +19,13 @@ against the executor's RecordEvent intervals in one merged timeline:
 profiler rows under pid 0, trace spans under pid 1 (grouped per thread),
 with trace/span ids in each event's ``args``.
 
+With ``--xplane <profile.xplane.pb>`` beside ``--trace_path`` the device
+modules of the profile are joined to the dispatches that launched them
+(``paddle_tpu.trace.join_dispatches``; this one option imports the
+framework and JAX) and land on a third row group, pid 2, each with its
+launch latency, device time and return latency in ``args``; what could not
+be joined is counted on standard output.
+
 Usage:
     python tools/timeline.py --profile_path /tmp/profile \
                              --timeline_path /tmp/timeline.json
@@ -27,6 +34,9 @@ Usage:
     python tools/timeline.py --profile_path /tmp/profile \
                              --trace_path spans.jsonl \
                              --timeline_path /tmp/merged.json
+    python tools/timeline.py --trace_path spans.jsonl \
+                             --xplane /tmp/prof/.../host.xplane.pb \
+                             --timeline_path /tmp/joined.json
 """
 from __future__ import annotations
 
@@ -63,8 +73,31 @@ def _load_trace_spans(trace_path: str) -> Optional[list]:
     return spans
 
 
+def _device_rows(xplane_path: str, tspans: list) -> List[dict]:
+    """The profile's modules that ``join_dispatches`` ties to a dispatch
+    among ``tspans``, as events on the spans' epoch clock (a module's
+    start is its dispatch's launch plus the launch latency)."""
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+    from paddle_tpu.trace import join_dispatches
+
+    joined = join_dispatches(xplane_path, tspans)
+    rows = joined.pop("joined")
+    print(f"joined {len(rows)} of {joined['inside']} dispatches inside the "
+          f"profile to a device module: {joined}")
+    return [{"name": f"{d['module']} #{d['dispatch']}", "ph": "X",
+             "ts": (d["launch_t"] + d["launch_latency_s"]) * 1e6,
+             "dur": d["device_s"] * 1e6, "pid": 2, "tid": 0,
+             "cat": "device",
+             "args": {"dispatch": d["dispatch"], "path": d["path"],
+                      "launch_latency_ms": 1e3 * d["launch_latency_s"],
+                      "device_ms": 1e3 * d["device_s"],
+                      "return_latency_ms": 1e3 * d["return_latency_s"]}}
+            for d in rows]
+
+
 def convert(profile_path: Optional[str], timeline_path: str,
-            trace_path: Optional[str] = None) -> int:
+            trace_path: Optional[str] = None,
+            xplane_path: Optional[str] = None) -> int:
     host = _load_host_spans(profile_path) if profile_path else []
     if host is None:
         return 1
@@ -126,12 +159,18 @@ def convert(profile_path: Optional[str], timeline_path: str,
             "cat": "trace",
             "args": args,
         })
+    n_device = 0
+    if xplane_path:
+        device = _device_rows(xplane_path, tspans)
+        n_device = len(device)
+        events += device
     with open(timeline_path, "w") as f:
         json.dump({"traceEvents": events,
                    "displayTimeUnit": "ms"}, f)
     print(f"wrote {len(events)} events to {timeline_path} "
           f"({len(host)} profiler, "
-          f"{len(events) - len(host)} trace)")
+          f"{len(events) - len(host) - n_device} trace, "
+          f"{n_device} device)")
     return 0
 
 
@@ -141,12 +180,17 @@ def main(argv=None):
                     help="profiler dump dir (host_events.json)")
     ap.add_argument("--trace_path",
                     help="paddle_tpu.trace JSONL span dump to merge")
+    ap.add_argument("--xplane",
+                    help="a .xplane.pb profile: its device modules joined "
+                         "to the dispatches of --trace_path")
     ap.add_argument("--timeline_path", required=True)
     args = ap.parse_args(argv)
     if not args.profile_path and not args.trace_path:
         ap.error("need --profile_path and/or --trace_path")
+    if args.xplane and not args.trace_path:
+        ap.error("--xplane joins to the spans of --trace_path")
     return convert(args.profile_path, args.timeline_path,
-                   trace_path=args.trace_path)
+                   trace_path=args.trace_path, xplane_path=args.xplane)
 
 
 if __name__ == "__main__":

@@ -321,12 +321,75 @@ def leg_watchdog_flight() -> dict:
             "terminal_chain": sorted(final_chain)}
 
 
+def _dispatch_off_cost() -> dict:
+    """A warm ``run`` and ``run_chained`` with ``FLAGS_trace`` and
+    ``FLAGS_monitor`` both off: the launch and the fetch are timed for
+    ``executor_inflight_seconds`` / ``executor_starved_seconds`` only when
+    a ``StepRecord`` is there to take the readings, so here the program
+    reads no clock, enters no annotation and observes nothing."""
+    import paddle_tpu.unique_name as un
+    from paddle_tpu import executor as executor_mod
+
+    with un.guard():
+        main, startup = fluid.Program(), fluid.Program()
+        with fluid.program_guard(main, startup):
+            x = fluid.layers.data("x", shape=[4], dtype="float32")
+            y = fluid.layers.fc(x, 2)
+    exe = fluid.Executor(fluid.CPUPlace())
+    scope = fluid.Scope()
+    feed = {"x": np.ones((2, 4), np.float32)}
+
+    def both():
+        exe.run(main, feed=feed, fetch_list=[y.name], scope=scope)
+        exe.run_chained(main, feed=feed, fetch_list=[y.name], steps=2,
+                        scope=scope)
+
+    def observed():
+        snap = monitor.get_registry().to_dict()
+        return sum(c["value"]["count"]
+                   for fam in ("executor_inflight_seconds",
+                               "executor_starved_seconds")
+                   for c in snap.get(fam, {"values": []})["values"])
+
+    exe.run(startup, scope=scope)
+    both()                                   # compiled, monitor still on
+    before = observed()
+    both()
+    on = observed() - before                 # 2 in flight + 2 gaps
+    reads = []
+    real = time.perf_counter
+
+    def counting():
+        who = sys._getframe(1).f_globals.get("__name__", "")
+        if who.startswith("paddle_tpu"):
+            reads.append(who)
+        return real()
+
+    fluid.set_flags({"FLAGS_monitor": 0})
+    time.perf_counter = counting
+    try:
+        both()
+    finally:
+        time.perf_counter = real
+        fluid.set_flags({"FLAGS_monitor": 1})
+    return {
+        "dispatch_observes_when_monitor_on": on == 4,
+        "dispatch_reads_no_clock_when_off": not reads,
+        "dispatch_observes_nothing_when_off": observed() - before == on,
+        "launch_untimed_and_unannotated_when_off":
+            trace.phase("executor.step", timed=False) is trace.NOOP_PHASE
+            and exe._launched(trace.NOOP_PHASE, None, None, None)
+            is executor_mod._NOT_TRACED,
+    }
+
+
 def leg_overhead(n=200_000, budget_ns=3000, histogram_budget_ns=20_000
                  ) -> dict:
     """FLAGS_trace=0 span hot path: bounded ns/span, no allocation
-    (identity singleton); the same for a phase with no histogram; and a
+    (identity singleton); the same for a phase with no histogram; a
     bound on the always-on half of a phase (one timing, one histogram
-    observation) — a generative decode iteration makes two of those."""
+    observation) — a generative decode iteration makes two of those; and
+    an executor dispatch with both flags off (``_dispatch_off_cost``)."""
     fluid.set_flags({"FLAGS_trace": 0})
     assert not trace.enabled()
     spans = [trace.span("bench") for _ in range(4)]
@@ -362,6 +425,9 @@ def leg_overhead(n=200_000, budget_ns=3000, histogram_budget_ns=20_000
         "disabled_phase_under_budget": phase_ns < budget_ns,
         "histogram_phase_under_budget": hist_ns < histogram_budget_ns,
     }
+    fluid.set_flags({"FLAGS_trace": 0})
+    checks.update(_dispatch_off_cost())
+    fluid.set_flags({"FLAGS_trace": 1})
     return {"name": "overhead_guard", "ok": all(checks.values()),
             "checks": checks,
             "disabled_ns_per_span": round(disabled_ns),
