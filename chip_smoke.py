@@ -488,9 +488,14 @@ def leg_gpt(cfg=None, slots: int = 8, max_seq: int = 1024, page: int = 128,
               and int(out.max()) < cfg.vocab_size,
               f"{what}: {max_new} tokens, all inside the vocabulary")
 
+    rows = {net["prefill"][b]["rows"] for b in buckets}
     say(leg, f"GPT-2-base {cfg.hidden_size} wide x {cfg.num_layers} layers "
              f"x {cfg.num_heads} heads, {slots} slots x max_seq {max_seq}, "
-             f"page {page}, buckets {buckets}, spec_k {spec_k}")
+             f"page {page}, buckets {buckets}, spec_k {spec_k}, "
+             f"{sorted(rows)} sequences a bucket prefill")
+    check(max(rows) < slots,
+          "a bucket prefill carries fewer sequences than there are slots, "
+          "each naming its slot")
     eng = engine(False)
     t0 = time.perf_counter()
     n_exec = eng.warm_up()

@@ -4,12 +4,14 @@ The autoregressive workload class (ROADMAP item 1): a pre-LN GPT-2-style
 decoder expressed as fluid Programs, built TWICE over one shared weight set:
 
 * **prefill** — full-sequence causal forward over a padded prompt bucket.
-  Runs once per admitted request batch: computes every layer's K/V for the
-  whole prompt, bulk-writes them into the paged KV caches
+  Runs once per admitted request batch, on the sequences it seats only:
+  each row names its slot, computes every layer's K/V for the whole
+  prompt, bulk-writes them into that slot's paged KV caches
   (``layers.kv_cache_append``), samples the FIRST generated token from the
-  last real prompt position, and merges the per-sequence generation state
-  (current token, position) under a slot mask so a refill touches only the
-  slots being prefilled while their neighbours keep decoding.
+  last real prompt position, and commits the slot's generation state
+  (current token, position, decode gate; ``layers.slot_assign``), so a
+  refill touches only the slots being prefilled while their neighbours
+  keep decoding.
 * **decode** — one token for every sequence in the batch, at per-sequence
   positions. No feeds at all: the current token, position and paged KV
   caches are persistable state threaded through the executor — which is
@@ -167,24 +169,54 @@ def _activate_slots(active, mask_f32, one_f32):
         mask_f32, layers.elementwise_mul(active, inv)), output=active)
 
 
+# the feeds of a bucket prefill, as the three later builders have them
+# (``models/cohere_moe.py`` ``_prefill_feeds``)
+PREFILL_FEEDS = ("prompt_ids", "prompt_pos", "prompt_mask", "prompt_len",
+                 "slot_mask", "slot_ids")
+
+
+def _prefill_rows(rows, batch_slots: int) -> int:
+    """Sequences a prefill dispatch carries. Where the caller names no
+    number: a quarter of the slots, at least one. A saturated closed loop
+    at 16 decode steps a turn seats about an eighth of its slots a turn
+    (7.8 of 64, deviation under 3), so a quarter takes a turn's newcomers
+    in one dispatch all but one turn in a thousand, at a quarter of what
+    a row for every slot costs (PERF.md section 6, PR 40)."""
+    rows = int(rows or max(1, batch_slots // 4))
+    if not 1 <= rows <= batch_slots:
+        raise ValueError(f"prefill rows {rows} for {batch_slots} slots")
+    return rows
+
+
 def build_gpt_prefill(cfg: GptConfig, batch_slots: int, prompt_bucket: int,
                       max_seq: int, page_size: int = 8,
                       strategy: str = "greedy", temperature: float = 1.0,
                       top_k: int = 0, fetch_logits: bool = False,
-                      startup: Program = None):
+                      startup: Program = None, rows: int = None):
     """The full-sequence phase for ONE prompt bucket (prompts padded to
-    ``prompt_bucket`` tokens). Feeds (all with the static ``batch_slots``
-    leading dim — every dispatch carries the full slot batch):
+    ``prompt_bucket`` tokens). A dispatch carries ``rows`` <= ``batch_slots``
+    sequences (default: :func:`_prefill_rows`, a quarter of the slots), each
+    naming the slot it is for, and costs ``rows x prompt_bucket`` positions
+    whichever slots they are.
+    Feeds (``R`` = ``rows``, ``S`` = ``prompt_bucket``):
 
-    * ``prompt_ids``  [B, S] int64 — padded prompt tokens;
-    * ``prompt_pos``  [B, S] int64 — position ids (0..S-1);
-    * ``prompt_mask`` [B, S] float32 — 1 on real tokens, 0 on pads;
-    * ``prompt_len``  [B, 1] int64 — real prompt length per slot;
-    * ``slot_mask``   [B, 1] float32 — 1 on slots being (re)filled; other
-      slots' caches and generation state pass through untouched.
+    * ``prompt_ids``  [R, S] int64 — padded prompt tokens;
+    * ``prompt_pos``  [R, S] int64 — position ids (0..S-1);
+    * ``prompt_mask`` [R, S] float32 — 1 on real tokens, 0 on pads;
+    * ``prompt_len``  [R, 1] int64 — real prompt length per row;
+    * ``slot_mask``   [R, 1] float32 — 1 on the rows in use; a row whose
+      mask is 0 writes nothing, whatever its ``slot_ids``;
+    * ``slot_ids``    [R, 1] int64 — the slot each row (re)fills: its K/V
+      go to that slot's cache rows and its first token, position and
+      decode gate to that slot's state. Slots no row names pass through
+      untouched. ``rows == batch_slots`` with ``slot_ids = arange`` is the
+      slot-wide prefill.
 
-    Pass ``startup`` to share one startup program across buckets (only
-    the first call's parameter initializers land there)."""
+    The returned dict carries ``"rows"``, which is how
+    ``serving.GenerativeEngine`` groups newcomers and reads
+    ``first_token`` ([R, 1]) by row. Pass ``startup`` to share one startup
+    program across buckets (only the first call's parameter initializers
+    land there)."""
     if prompt_bucket > max_seq:
         raise ValueError(f"prompt_bucket {prompt_bucket} exceeds the KV "
                          f"capacity max_seq {max_seq}")
@@ -192,30 +224,27 @@ def build_gpt_prefill(cfg: GptConfig, batch_slots: int, prompt_bucket: int,
         raise ValueError(f"max_seq {max_seq} must be a whole number of "
                          f"pages of page_size {page_size}")
     B, S = batch_slots, prompt_bucket
+    R = _prefill_rows(rows, B)
     nh, hd = cfg.num_heads, cfg.hidden_size // cfg.num_heads
     main = Program()
     own_startup = startup is None
     startup = startup if startup is not None else Program()
     throwaway = Program()
     with program_guard(main, startup if own_startup else throwaway):
-        ids = layers.data("prompt_ids", shape=[B, S], dtype="int64",
-                          append_batch_size=False)
-        pos_ids = layers.data("prompt_pos", shape=[B, S], dtype="int64",
-                              append_batch_size=False)
-        pmask = layers.data("prompt_mask", shape=[B, S], dtype="float32",
-                            append_batch_size=False)
-        plen = layers.data("prompt_len", shape=[B, 1], dtype="int64",
-                           append_batch_size=False)
-        smask = layers.data("slot_mask", shape=[B, 1], dtype="float32",
-                            append_batch_size=False)
+        ids, pos_ids, pmask, plen, smask, slots = [
+            layers.data(n, shape=shape, dtype=dt, append_batch_size=False)
+            for n, shape, dt in zip(
+                PREFILL_FEEDS,
+                ([R, S], [R, S], [R, S], [R, 1], [R, 1], [R, 1]),
+                ("int64", "int64", "float32", "int64", "float32", "int64"))]
         tok, pos, active, caches, sv = _state_vars(main.global_block, cfg,
                                                    B, max_seq)
 
         x = layers.elementwise_add(_embed(ids, cfg), _pos_embed(pos_ids, cfg))
-        # additive key-padding bias [B,1,1,S]: (mask-1)*10000, bert idiom
+        # additive key-padding bias [R,1,1,S]: (mask-1)*10000, bert idiom
         bias = layers.unsqueeze(
             layers.scale(pmask, scale=10000.0, bias=-10000.0), [1, 2])
-        zero_pos = layers.fill_constant([B, 1], "int64", 0)
+        zero_pos = layers.fill_constant([R, 1], "int64", 0)
         for i in range(cfg.num_layers):
             p = f"gpt_l{i}"
             h = _ln(x, f"{p}_ln1")
@@ -223,10 +252,12 @@ def build_gpt_prefill(cfg: GptConfig, batch_slots: int, prompt_bucket: int,
             k = _split_heads(_proj(h, cfg.hidden_size, f"{p}_k", cfg), S, cfg)
             v = _split_heads(_proj(h, cfg.hidden_size, f"{p}_v", cfg), S, cfg)
             ck, cv = caches[i]
-            # bulk KV write: whole prompt at position 0, slot-masked so
-            # neighbouring sequences' pages survive a refill
-            layers.kv_cache_append(ck, k, zero_pos, slot_mask=smask)
-            layers.kv_cache_append(cv, v, zero_pos, slot_mask=smask)
+            # bulk KV write: each row's whole prompt at position 0 of the
+            # slot it names; slots that no row in use names keep their pages
+            layers.kv_cache_append(ck, k, zero_pos, slot_mask=smask,
+                                   slots=slots)
+            layers.kv_cache_append(cv, v, zero_pos, slot_mask=smask,
+                                   slots=slots)
             ctx = layers.fused_multihead_attention(
                 q, k, v, bias_qk=bias, causal=True,
                 scale=1.0 / math.sqrt(hd), is_test=True)
@@ -237,32 +268,31 @@ def build_gpt_prefill(cfg: GptConfig, batch_slots: int, prompt_bucket: int,
             x = layers.elementwise_add(x, _mlp(h, p, cfg))
         h = _ln(x, "gpt_lnf")
 
-        one = layers.fill_constant([B, 1], "int64", 1)
+        one = layers.fill_constant([R, 1], "int64", 1)
         last = layers.elementwise_sub(plen, one)
-        last_h = layers.sequence_gather(h, last)            # [B, H]
-        logits = _logits(last_h, cfg, main.global_block)    # [B, V]
+        last_h = layers.sequence_gather(h, last)            # [R, H]
+        logits = _logits(last_h, cfg, main.global_block)    # [R, V]
         first_tok = layers.sample_token(logits, strategy=strategy,
                                         temperature=temperature, top_k=top_k)
 
-        mask_i64 = layers.cast(smask, "int64")
-        inv = layers.elementwise_sub(one, mask_i64)
-        layers.assign(_merge_state(first_tok, tok, mask_i64, inv),
-                      output=tok)
-        layers.assign(_merge_state(plen, pos, mask_i64, inv), output=pos)
-        one_f = layers.fill_constant([B, 1], "float32", 1.0)
-        _activate_slots(active, smask, one_f)
+        # each row in use commits its slot's first token and position and
+        # opens its decode gate
+        layers.slot_assign(tok, slots, first_tok, smask)
+        layers.slot_assign(pos, slots, plen, smask)
+        layers.slot_assign(active, slots,
+                           layers.fill_constant([R, 1], "float32", 1.0),
+                           smask)
 
         out = {"main": main, "startup": startup,
-               "first_token": first_tok, "state_vars": sv,
-               "feeds": ("prompt_ids", "prompt_pos", "prompt_mask",
-                         "prompt_len", "slot_mask")}
+               "first_token": first_tok, "state_vars": sv, "rows": R,
+               "feeds": PREFILL_FEEDS}
         if fetch_logits:
             # all-position logits for the continuity tests
             flat = layers.reshape(h, [0, S * cfg.hidden_size])
-            flat = layers.reshape(flat, [B * S, cfg.hidden_size])
+            flat = layers.reshape(flat, [R * S, cfg.hidden_size])
             all_logits = layers.reshape(
                 _logits(flat, cfg, main.global_block),
-                [B, S, cfg.vocab_size])
+                [R, S, cfg.vocab_size])
             out["logits"] = all_logits
             out["last_logits"] = logits
     return out
@@ -486,12 +516,20 @@ def build_gpt_generative(cfg: GptConfig = None, batch_slots: int = 4,
                          prompt_buckets=(16,), strategy: str = "greedy",
                          temperature: float = 1.0, top_k: int = 0,
                          fetch_logits: bool = False,
-                         prefill_chunk: int = None, spec_k: int = 4):
+                         prefill_chunk: int = None, spec_k: int = 4,
+                         prefill_rows: int = None):
     """Everything the generative serving engine needs: one prefill program
     per prompt bucket + one decode program + the chunked-prefill and
     speculative-verify chunk programs (ISSUE 20) over shared weights, one
     startup program (parameters only — generation state is reset
     host-side by the engine), and the state-var table.
+
+    ``prefill_rows``: the sequences a bucket prefill dispatch carries,
+    each naming its slot (``build_gpt_prefill``'s ``rows``; default a
+    quarter of the slots, at least one), so a refill of a few slots does
+    not pay for all of them; a turn with more newcomers makes
+    ``ceil(n / prefill_rows)`` dispatches. The chunk and verify programs
+    stay slot-wide.
 
     ``prefill_chunk`` (default: one page) sizes the chunked-prefill
     slice; ``spec_k`` sizes the speculative chunk (1 committed token +
@@ -511,7 +549,8 @@ def build_gpt_generative(cfg: GptConfig = None, batch_slots: int = 4,
         net = build_gpt_prefill(cfg, batch_slots, S, max_seq,
                                 page_size=page_size, strategy=strategy,
                                 temperature=temperature, top_k=top_k,
-                                fetch_logits=fetch_logits, startup=startup)
+                                fetch_logits=fetch_logits, startup=startup,
+                                rows=prefill_rows)
         startup = net["startup"]
         prefill[S] = net
     decode = build_gpt_decode(cfg, batch_slots, max_seq,

@@ -387,11 +387,14 @@ def test_timeline_tool_is_the_joins_first_caller(tmp_path, recorded,
 # what a prefill's rows were; the held experts' load
 # ---------------------------------------------------------------------------
 
-def test_prefill_tokens_prompt_against_run():
+@pytest.mark.parametrize("rows", [1, 2, 4])
+def test_prefill_tokens_prompt_against_run(rows):
+    """``kind=run`` grows by ``rows x bucket`` a dispatch, and three
+    newcomers take ``ceil(3 / rows)`` dispatches."""
     with un.guard():
         net = build_gpt_generative(GptConfig.tiny(), batch_slots=4,
                                    max_seq=128, page_size=32,
-                                   prompt_buckets=(128,))
+                                   prompt_buckets=(128,), prefill_rows=rows)
     exe = fluid.Executor(fluid.CPUPlace())
     scope = fluid.Scope()
     exe.run(net["startup"], scope=scope)
@@ -413,8 +416,11 @@ def test_prefill_tokens_prompt_against_run():
     eng._run_prefill(reqs)
     assert monitor.metric_value("serving_prefill_tokens_total",
                                 kind="prompt") == sum(lengths)
+    dispatches = -(-len(reqs) // rows)
+    assert monitor.metric_value("serving_prefill_seconds")[
+        "count"] == dispatches
     assert monitor.metric_value("serving_prefill_tokens_total",
-                                kind="run") == 4 * 128
+                                kind="run") == dispatches * rows * 128
     assert all(r.prefilled for r in reqs)
 
 

@@ -421,7 +421,7 @@ def test_served_prefill_with_the_extra_output_clones_saves_and_loads(
 
     B, S = 2, 16
     with un.guard():
-        net = build_gpt_prefill(GptConfig.tiny(), B, S, max_seq=32)
+        net = build_gpt_prefill(GptConfig.tiny(), B, S, max_seq=32, rows=B)
     main = net["main"]
     attn = [op for op in main.global_block.ops if op.type == ATTN]
     assert attn and all(op.outputs.get("SoftmaxLse") for op in attn)
@@ -430,7 +430,8 @@ def test_served_prefill_with_the_extra_output_clones_saves_and_loads(
             "prompt_pos": np.tile(np.arange(S, dtype=np.int64), (B, 1)),
             "prompt_mask": np.ones((B, S), np.float32),
             "prompt_len": np.full((B, 1), S, np.int64),
-            "slot_mask": np.ones((B, 1), np.float32)}
+            "slot_mask": np.ones((B, 1), np.float32),
+            "slot_ids": np.arange(B, dtype=np.int64)[:, None]}
     fetch = net["first_token"]
     exe = fluid.Executor(fluid.CPUPlace())
     scope = fluid.Scope()

@@ -263,9 +263,11 @@ def _build_net(**kw):
 @pytest.fixture(scope="module")
 def gpt_net():
     """Shared tiny GPT (2 slots, 32-token KV in 8-token pages, one 16
-    prompt bucket) with all-position logits for the continuity tests."""
+    prompt bucket whose prefill carries a row a slot) with all-position
+    logits for the continuity tests."""
     return _build_net(batch_slots=2, max_seq=32, page_size=8,
-                      prompt_buckets=(16,), fetch_logits=True)
+                      prompt_buckets=(16,), fetch_logits=True,
+                      prefill_rows=2)
 
 
 @pytest.fixture()
@@ -277,25 +279,30 @@ def gpt_session(gpt_net):
     return exe, scope
 
 
-def _prefill_feed(net, bucket, prompts, slot_mask=None):
-    B = net["batch_slots"]
+def _prefill_feed(net, bucket, prompts, slot_mask=None, slots=None):
+    """Row ``r`` carries ``prompts[r]`` (None: a row not in use) for slot
+    ``slots[r]`` (default: slot ``r``)."""
+    R = net["prefill"][bucket]["rows"]
     S = bucket
-    ids = np.zeros((B, S), np.int64)
-    mask = np.zeros((B, S), np.float32)
-    plen = np.ones((B, 1), np.int64)
-    smask = np.zeros((B, 1), np.float32)
-    for b, p in enumerate(prompts):
+    ids = np.zeros((R, S), np.int64)
+    mask = np.zeros((R, S), np.float32)
+    plen = np.ones((R, 1), np.int64)
+    smask = np.zeros((R, 1), np.float32)
+    slot_ids = np.zeros((R, 1), np.int64)
+    slot_ids[:len(prompts), 0] = (range(len(prompts)) if slots is None
+                                  else slots)
+    for r, p in enumerate(prompts):
         if p is None:
             continue
-        ids[b, :len(p)] = p
-        mask[b, :len(p)] = 1.0
-        plen[b, 0] = len(p)
-        smask[b, 0] = 1.0
+        ids[r, :len(p)] = p
+        mask[r, :len(p)] = 1.0
+        plen[r, 0] = len(p)
+        smask[r, 0] = 1.0
     if slot_mask is not None:
         smask = slot_mask
     return {"prompt_ids": ids, "prompt_mask": mask, "prompt_len": plen,
-            "slot_mask": smask,
-            "prompt_pos": np.tile(np.arange(S, dtype=np.int64), (B, 1))}
+            "slot_mask": smask, "slot_ids": slot_ids,
+            "prompt_pos": np.tile(np.arange(S, dtype=np.int64), (R, 1))}
 
 
 def test_prefill_decode_logits_continuity(gpt_net, gpt_session):
@@ -332,6 +339,151 @@ def test_prefill_decode_logits_continuity(gpt_net, gpt_session):
                 dec_logits[t][b], all_logits[b, plen[b] + t],
                 atol=2e-4, rtol=1e-3,
                 err_msg=f"decode step {t}, sequence {b}")
+
+
+# -- a prefill's rows name their slots --------------------------------------
+
+@pytest.fixture(scope="module")
+def row_nets():
+    """One tiny GPT of 4 slots built twice over the same weights: a
+    prefill of 2 rows, and one with a row a slot."""
+    kw = dict(batch_slots=4, max_seq=32, page_size=8, prompt_buckets=(16,))
+    return _build_net(prefill_rows=2, **kw), _build_net(prefill_rows=4, **kw)
+
+
+def _random_state(net, seed):
+    """A state no prefill wrote: every slot mid-stream with its own rows."""
+    rng = np.random.RandomState(seed)
+    state = {}
+    for name, (shape, dt) in net["state_vars"].items():
+        if dt == "float32":
+            state[name] = rng.randn(*shape).astype(np.float32)
+        else:
+            state[name] = rng.randint(1, 30, shape).astype(np.int64)
+    state["gpt_gen_active"] = np.array([[1.], [0.], [1.], [0.]], np.float32)
+    return state
+
+
+def _run_prefill_on(net, exe, weights, state, feed):
+    """The prefill of ``net`` over ``state`` (copied); returns the first
+    tokens by row and the state after."""
+    scope = fluid.Scope()
+    for name, value in {**weights, **state}.items():
+        scope.set_var(name, np.array(value))
+    pf = net["prefill"][16]
+    first = exe.run(pf["main"], feed=feed, fetch_list=[pf["first_token"]],
+                    scope=scope)[0]
+    return np.asarray(first), {n: np.asarray(scope.find_var(n))
+                               for n in state}
+
+
+@pytest.fixture(scope="module")
+def row_weights(row_nets):
+    exe, scope = fluid.Executor(fluid.CPUPlace()), fluid.Scope()
+    exe.run(row_nets[0]["startup"], scope=scope)
+    names = [v.name for v in row_nets[0]["startup"].global_block.vars.values()
+             if v.persistable]
+    return exe, {n: np.asarray(scope.find_var(n)) for n in names}
+
+
+def test_prefill_feeds_name_their_slots(row_nets):
+    by_row, slot_wide = (n["prefill"][16] for n in row_nets)
+    assert by_row["rows"] == 2 and slot_wide["rows"] == 4
+    for pf in (by_row, slot_wide):
+        assert pf["feeds"] == ("prompt_ids", "prompt_pos", "prompt_mask",
+                               "prompt_len", "slot_mask", "slot_ids")
+        block = pf["main"].global_block
+        assert block.var("slot_ids").shape == (pf["rows"], 1)
+        assert block.var("prompt_ids").shape == (pf["rows"], 16)
+        assert pf["first_token"].shape[0] == pf["rows"]
+    # one form: the same ops in the same order, whatever the rows
+    assert [op.type for op in by_row["main"].global_block.ops] \
+        == [op.type for op in slot_wide["main"].global_block.ops]
+    # the chunk and verify programs stay a row a slot
+    assert row_nets[0]["chunk"]["main"].global_block.var(
+        "chunk_ids").shape[0] == 4
+
+
+@pytest.mark.parametrize("slots", [(3, 1), (0, 2), (2, 3)])
+def test_by_row_prefill_touches_only_the_slots_it_names(row_nets,
+                                                        row_weights, slots):
+    by_row, slot_wide = row_nets
+    exe, weights = row_weights
+    state = _random_state(by_row, seed=sum(slots))
+    prompts = [RNG.randint(1, 128, L).astype(np.int64) for L in (11, 4)]
+    first, after = _run_prefill_on(
+        by_row, exe, weights, state,
+        _prefill_feed(by_row, 16, prompts, slots=slots))
+    others = [b for b in range(4) if b not in slots]
+    for name, before in state.items():
+        np.testing.assert_array_equal(after[name][others], before[others],
+                                      err_msg=name)
+    # against the program with a row a slot, row b for slot b
+    wide = [None] * 4
+    for p, b in zip(prompts, slots):
+        wide[b] = p
+    first_w, after_w = _run_prefill_on(
+        slot_wide, exe, weights, state, _prefill_feed(slot_wide, 16, wide))
+    np.testing.assert_array_equal(first.ravel(),
+                                  first_w.ravel()[list(slots)])
+    for name in ("gpt_gen_tokens", "gpt_gen_pos", "gpt_gen_active"):
+        np.testing.assert_array_equal(after[name], after_w[name],
+                                      err_msg=name)
+    np.testing.assert_array_equal(after["gpt_gen_pos"][list(slots), 0],
+                                  [11, 4])
+    np.testing.assert_array_equal(after["gpt_gen_active"][list(slots), 0],
+                                  [1.0, 1.0])
+    for name in state:
+        if name.startswith("gpt_kv_"):
+            np.testing.assert_allclose(after[name], after_w[name],
+                                       atol=1e-5, rtol=1e-5, err_msg=name)
+            # rows past the bucket stay the slot's old ones
+            np.testing.assert_array_equal(after[name][:, :, 16:],
+                                          state[name][:, :, 16:])
+            assert not np.array_equal(after[name][slots[0], :, :11],
+                                      state[name][slots[0], :, :11])
+
+
+@pytest.mark.parametrize("slot_ids", [(0, 0), (3, 3), (1, 2)])
+def test_masked_row_writes_nothing_whatever_its_slot(row_nets, row_weights,
+                                                     slot_ids):
+    by_row, _ = row_nets
+    exe, weights = row_weights
+    state = _random_state(by_row, seed=5)
+    prompts = [RNG.randint(1, 128, 9).astype(np.int64) for _ in range(2)]
+    # both rows masked
+    feed = _prefill_feed(by_row, 16, prompts, slots=slot_ids,
+                         slot_mask=np.zeros((2, 1), np.float32))
+    _, after = _run_prefill_on(by_row, exe, weights, state, feed)
+    for name, before in state.items():
+        np.testing.assert_array_equal(after[name], before, err_msg=name)
+    # one row in use beside a masked row that names a slot too
+    feed = _prefill_feed(by_row, 16, prompts, slots=slot_ids,
+                         slot_mask=np.array([[0.], [1.]], np.float32))
+    _, after = _run_prefill_on(by_row, exe, weights, state, feed)
+    others = [b for b in range(4) if b != slot_ids[1]]
+    for name, before in state.items():
+        np.testing.assert_array_equal(after[name][others], before[others],
+                                      err_msg=name)
+    assert after["gpt_gen_pos"][slot_ids[1], 0] == 9
+
+
+@pytest.mark.parametrize("slots,rows", [(1, 1), (2, 1), (4, 1), (8, 2),
+                                        (64, 16)])
+def test_default_prefill_rows_is_a_quarter_of_the_slots(slots, rows):
+    net = _build_net(batch_slots=slots, max_seq=16, page_size=8,
+                     prompt_buckets=(8, 16), spec_k=1)
+    assert {pf["rows"] for pf in net["prefill"].values()} == {rows}
+    assert _build_net(batch_slots=slots, max_seq=16, page_size=8,
+                      prompt_buckets=(8,), spec_k=1,
+                      prefill_rows=slots)["prefill"][8]["rows"] == slots
+
+
+@pytest.mark.parametrize("rows", [5, -1])
+def test_prefill_rows_outside_the_slots_are_refused(rows):
+    with pytest.raises(ValueError, match="prefill rows"):
+        _build_net(batch_slots=4, max_seq=16, page_size=8,
+                   prompt_buckets=(8,), prefill_rows=rows)
 
 
 def test_kv_cache_proven_donated_through_chained_scan(gpt_net, gpt_session):
@@ -475,6 +627,49 @@ def test_generative_engine_end_to_end(serving_net):
         == sum(2 + i % 4 for i in range(6))
     it = monitor.metric_value("serving_intertoken_seconds", default=None)
     assert it and it["count"] > 0 and it["p99"] is not None
+
+
+def _serve_by_rows(rows, prompts, max_new):
+    """The streamed tokens of ``prompts`` from an engine of 4 slots whose
+    bucket prefill carries ``rows`` sequences, the newcomers of each
+    scheduler turn and the prefill dispatches made."""
+    net = _build_net(batch_slots=4, max_seq=32, page_size=8,
+                     prompt_buckets=(16,), prefill_rows=rows)
+    exe, scope = fluid.Executor(fluid.CPUPlace()), fluid.Scope()
+    exe.run(net["startup"], scope=scope)
+    eng = serving.GenerativeEngine(
+        net, scope=scope, executor=exe,
+        config=serving.ServingConfig(max_batch=4, queue_depth=64,
+                                     deadline_s=0),
+        gen_config=serving.GenerationConfig(
+            decode_chunk=2, prefix_cache=False, chunked_prefill=False))
+    eng.warm_up()
+    turns, run_prefill = [], eng._run_prefill
+
+    def counted(newcomers):
+        turns.append(len(newcomers))
+        run_prefill(newcomers)
+
+    eng._run_prefill = counted
+    monitor.reset()
+    with eng:
+        futs = [eng.submit(p, max_new_tokens=max_new) for p in prompts]
+        out = [list(f.result(timeout=120)[0]) for f in futs]
+    assert eng.accounting()["exact"] and eng.decode_recompiles == 0
+    return out, turns, monitor.metric_value("serving_prefill_seconds")["count"]
+
+
+def test_more_newcomers_than_rows_take_several_dispatches_same_tokens():
+    rng = np.random.RandomState(9)
+    prompts = [rng.randint(1, 128, 3 + 2 * i).astype(np.int64)
+               for i in range(7)]
+    want, turns, dispatches = _serve_by_rows(4, prompts, 5)
+    assert dispatches == len(turns)          # a row a slot: one a turn
+    for rows in (1, 2):
+        got, turns, dispatches = _serve_by_rows(rows, prompts, 5)
+        assert got == want, rows
+        assert sum(turns) == len(prompts)
+        assert dispatches == sum(-(-n // rows) for n in turns), (rows, turns)
 
 
 def test_recompile_guard_counts_warm_bucket_growth(serving_net):

@@ -120,9 +120,11 @@ def _generate(traced: bool, idle_s: float = 0.0):
     (the second re-sends a prompt, so admission hits published pages) and
     an idle end. Returns the spans it recorded."""
     with un.guard():
+        # a prefill row a slot: one prefill dispatch a bucket and wave, so
+        # the thread's time is in the phases and not between many of them
         net = build_gpt_generative(GptConfig.tiny(), batch_slots=4,
                                    max_seq=64, page_size=8,
-                                   prompt_buckets=(16, 32))
+                                   prompt_buckets=(16, 32), prefill_rows=4)
     exe, scope = fluid.Executor(fluid.CPUPlace()), fluid.Scope()
     exe.run(net["startup"], scope=scope)
     eng = serving.GenerativeEngine(

@@ -135,23 +135,25 @@ def _zoo_targets():
         out.append(("zoo/bert_tiny", m["main"], m["startup"],
                     [m["loss"].name], bert_feed))
 
-    def gpt_feed(rng):
-        B, S = 2, 16
-        ids = np.zeros((B, S), np.int64)
-        ids[:, :5] = rng.randint(1, 50, (B, 5))
-        mask = np.zeros((B, S), np.float32)
-        mask[:, :5] = 1.0
-        return {"prompt_ids": ids, "prompt_mask": mask,
-                "prompt_pos": np.tile(np.arange(S, dtype=np.int64),
-                                      (B, 1)),
-                "prompt_len": np.full((B, 1), 5, np.int64),
-                "slot_mask": np.ones((B, 1), np.float32)}
-
     with un.guard():
         g = build_gpt_generative(GptConfig.tiny(), batch_slots=2,
                                  max_seq=32, page_size=8,
                                  prompt_buckets=(16,))
         pf = g["prefill"][16]
+
+        def gpt_feed(rng, R=pf["rows"], S=16):
+            # every row of the prefill in use, row r for slot r
+            ids = np.zeros((R, S), np.int64)
+            ids[:, :5] = rng.randint(1, 50, (R, 5))
+            mask = np.zeros((R, S), np.float32)
+            mask[:, :5] = 1.0
+            return {"prompt_ids": ids, "prompt_mask": mask,
+                    "prompt_pos": np.tile(np.arange(S, dtype=np.int64),
+                                          (R, 1)),
+                    "prompt_len": np.full((R, 1), 5, np.int64),
+                    "slot_mask": np.ones((R, 1), np.float32),
+                    "slot_ids": np.arange(R, dtype=np.int64)[:, None]}
+
         out.append(("zoo/gpt_tiny/prefill", pf["main"], g["startup"],
                     [pf["first_token"].name], gpt_feed))
         out[-1] = out[-1] + (g,)   # state_vars needed by the witness run
