@@ -6,7 +6,11 @@ sequence's KV cache. q_len == 1 is the classic decode step; q_len > 1 is
 the chunked-prefill slice and the speculative-verify chunk (ISSUE 20),
 where query row ``i`` is the token at cache position ``length - 1 + i`` and
 may see exactly ``length + i`` keys (causal *within* the chunk, since the
-chunk's own K rows are appended before the walk). The cache is *paged* —
+chunk's own K rows are appended before the walk). A chunk may also be a
+*block* of block-diffusion generation (``whole_chunk``, PR 41): its rows see
+one another in both directions, every row ``length + q_len - 1`` keys, and
+what a forward of it yields is 0 to q_len tokens, not one. The cache is
+*paged* —
 logically ``[BH, S_max, D]`` where ``S_max = num_pages * page_size`` — and
 ``page_size`` is the CACHE's page: the unit of the prefix cache, of
 ``S_max``'s divisibility, of ``classify_shapes``. What a grid step carries
@@ -17,7 +21,8 @@ k-block with the same online-softmax recurrence as the prefill kernel, up
 to the sequence's LAST LIVE block: past it the K and V index maps repeat
 that block's index, for which the pipeline issues no DMA, and the body is
 skipped. Inside the last live block key positions ``>= length + row`` are
-masked per sequence and query row.
+masked per sequence and query row (``>= length + q_len - 1`` for every row
+of a whole chunk: the walk is the same, it ends at the last row's block).
 Pages past a sequence's length hold stale/garbage rows by design (they are
 overwritten when the sequence reaches them): they are never fetched, and
 the length mask keeps the tail of the last live block out of the softmax,
@@ -47,7 +52,8 @@ Design notes
   ``flash_attention._rows8``). The 8 sublane rows ARE the chunk's query
   rows: rows ``q_len..7`` are padding (replicas of the last real row) whose
   output is discarded, so the q_len=1 decode step and the q_len<=8 chunk
-  use one kernel with a per-row length mask ``k_pos < length + row``.
+  use one kernel with a per-row length mask ``k_pos < length + row``
+  (``row`` the chunk's last for every row of a whole chunk).
 - a cache whose head dimension is not whole 128-lane tiles (GPT-2's 64)
   is read and appended to in the view ``[B, H, D, S_max]``, rows in lanes
   (:func:`rows_minor`, beside :func:`kv_tile`): that is how the runtime
@@ -200,7 +206,7 @@ def paged_kv_append_rows(cache, new, positions, mask=None, ring=False):
 
 
 def decode_attention_reference(q, k_cache, v_cache, lengths, scale,
-                               group: int = 1):
+                               group: int = 1, whole_chunk: bool = False):
     """Primitive oracle: masked softmax attention of a chunk of query rows
     per sequence against its cache. q: [BH, Sq, D]; caches: [BH, S, D];
     lengths: [BH] (already expanded per head) — the number of keys visible
@@ -208,13 +214,18 @@ def decode_attention_reference(q, k_cache, v_cache, lengths, scale,
     chunk, whose K rows were appended before the attention). Sq == 1 is
     the classic decode step. With ``group`` > 1 (grouped-query heads) BH
     counts key/value heads and row ``i`` is query head ``i % group`` at
-    chunk position ``i // group``. Matches the kernel semantics exactly;
-    also the op's off-TPU lowering."""
+    chunk position ``i // group``. With ``whole_chunk`` every row sees
+    what the chunk's last row sees, ``lengths + Sq // group - 1`` keys: the
+    chunk's rows see one another in both directions (a block-diffusion
+    block). Matches the kernel semantics exactly; also the op's off-TPU
+    lowering."""
     prec = "highest" if q.dtype == jnp.float32 else "default"
     s = jnp.einsum("bqd,bkd->bqk", q.astype(jnp.float32),
                    k_cache.astype(jnp.float32), precision=prec) * scale
     k_pos = jnp.arange(k_cache.shape[1])[None, None, :]
     row = jnp.arange(q.shape[1])[None, :, None] // group
+    if whole_chunk:
+        row = q.shape[1] // group - 1
     s = jnp.where(k_pos < lengths[:, None, None] + row, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bqk,bkd->bqd", p, v_cache.astype(jnp.float32),
@@ -382,8 +393,8 @@ def decode_walk_blocks(lengths, cache_shape, dtype, page_size: int,
     return int(live.sum()), int(live.size * num_k)
 
 
-def _decode_kernel(scale, group, q_len, minor, len_ref, q_ref, k_ref, v_ref,
-                   o_ref, m_scr, l_scr, acc):
+def _decode_kernel(scale, group, q_len, minor, whole, len_ref, q_ref, k_ref,
+                   v_ref, o_ref, m_scr, l_scr, acc):
     b, ik = pl.program_id(0), pl.program_id(2)
     num_k = pl.num_programs(2)
     # a K or V block is [heads, block_k, D] or, rows-minor, [heads, D,
@@ -411,10 +422,14 @@ def _decode_kernel(scale, group, q_len, minor, len_ref, q_ref, k_ref, v_ref,
         # per-row causal length: query row i (the token at cache position
         # length - 1 + i) sees length + i keys; padding rows past the real
         # chunk see more keys, but their output is sliced away by the
-        # caller
-        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        if group > 1:   # grouped-query heads: `group` query heads a position
-            row = row // group
+        # caller. With `whole` the chunk's rows see one another in both
+        # directions: every row sees what the last one does
+        if whole:
+            row = q_len - 1
+        else:
+            row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            if group > 1:   # grouped-query heads: `group` heads a position
+                row = row // group
         s = jnp.where(k_pos < length + row, s, NEG_INF)
 
         m_prev = m_scr[:, :, :1]
@@ -453,7 +468,7 @@ def _kv_index_map(q_len: int, block_k: int, num_k: int,
 
 
 def _decode_call(q, k_cache, v_cache, lengths, tile, *, minor, scale, group,
-                 q_len, interpret):
+                 q_len, interpret, whole=False):
     """The Pallas call on ``q`` [B * H, R, D] and caches [B, H, S_max, D]
     with ``tile = (heads, rows)`` of a cache a grid step
     (:func:`kv_tile`'s choice; ``tools/probe_decode_walk.py`` sweeps it).
@@ -483,7 +498,7 @@ def _decode_call(q, k_cache, v_cache, lengths, tile, *, minor, scale, group,
     )
     (o,) = pl.pallas_call(
         functools.partial(_decode_kernel, scale, int(group), int(q_len),
-                          minor),
+                          minor, bool(whole)),
         grid_spec=grid_spec,
         out_shape=[_out_sds((B * H, R, D), q.dtype, q, k_cache, v_cache)],
         compiler_params=pltpu.CompilerParams(
@@ -498,7 +513,8 @@ def _decode_call(q, k_cache, v_cache, lengths, tile, *, minor, scale, group,
 def flash_attention_decode(q, k_cache, v_cache, lengths, *,
                            scale=None, num_heads: int = 1,
                            page_size: int = 128, group: int = 1,
-                           interpret: bool = False):
+                           interpret: bool = False,
+                           whole_chunk: bool = False):
     """One decode/verify chunk: q [BH, Sq, D] (1 <= Sq <= 8) against paged
     caches [BH, S_max, D].
 
@@ -520,6 +536,12 @@ def flash_attention_decode(q, k_cache, v_cache, lengths, *,
     share one ride the sublane rows beside each other — row ``i`` is query
     head ``i % group`` at chunk position ``i // group`` — so a cache page
     is read once for all of them.
+
+    ``whole_chunk``: the chunk is a block whose rows see one another in
+    both directions (block diffusion): every row sees ``lengths + Sq //
+    group - 1`` keys, what the chunk's last row sees without it. The walk
+    is the same (it ends at the last row's last block); only the mask of
+    that block differs.
     """
     BH, Sq, D = q.shape
     Sk = k_cache.shape[1]
@@ -557,5 +579,6 @@ def flash_attention_decode(q, k_cache, v_cache, lengths, *,
         v_cache.reshape(B, num_heads, Sk, D), lengths,
         kv_tile(num_heads, Sk, D, k_cache.dtype, page_size),
         minor=rows_minor(D, k_cache.dtype, min(page_size, Sk)), scale=scale,
-        group=group, q_len=Sq // group, interpret=interpret)
+        group=group, q_len=Sq // group, interpret=interpret,
+        whole=whole_chunk)
     return o8[:, :Sq, :]

@@ -78,6 +78,10 @@ class _Cfg:
     # grouped-query heads: `kv_group` query heads read one key/value head
     # (q is [B*Hq, ...], k and v [B*Hq/kv_group, ...]). Forward only.
     kv_group: int = 1
+    # causal by blocks of `causal_block` positions (0: by row): a query
+    # sees every key of its own block, in both directions, and of the
+    # blocks before it. Rides the causal comparison.
+    causal_block: int = 0
 
 
 def classify_shapes(sq: int, sk: int, block_q: int = 128,
@@ -163,7 +167,11 @@ def _dropout_keep(seed, bh, iq, ik, shape, rate):
 
 
 def _visible(cfg: "_Cfg", q_pos, k_pos):
-    """The causal comparison, with the window where there is one."""
+    """The causal comparison, with the window where there is one; by
+    blocks of positions where the mask is causal by block (the last
+    position of the query's block stands for the query)."""
+    if cfg.causal_block:
+        q_pos = (q_pos // cfg.causal_block + 1) * cfg.causal_block - 1
     seen = q_pos >= k_pos
     if cfg.window:
         seen = seen & (q_pos - k_pos < cfg.window)
@@ -507,7 +515,8 @@ _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
 def _prepare(q, k, bias, causal, scale, dropout_rate, seed, q_offset,
-             k_offset, num_heads, block_q, block_k, interpret, window):
+             k_offset, num_heads, block_q, block_k, interpret, window,
+             causal_block=0):
     """The checks, the static configuration and the scalar operands that
     the forward call and a backward from saved residuals share."""
     BH, Sq, D = q.shape
@@ -516,6 +525,10 @@ def _prepare(q, k, bias, causal, scale, dropout_rate, seed, q_offset,
         raise ValueError(
             f"flash_attention: q has {BH} batch-heads, k {k.shape[0]}; "
             f"window={window} needs causal")
+    if causal_block and (not causal or window or causal_block < 1):
+        raise ValueError(
+            f"flash_attention: causal_block={causal_block} needs causal "
+            f"and no window (window={window})")
     bq, bk = min(block_q, Sq), min(block_k, Sk)
     if Sq % bq or Sk % bk:
         raise ValueError(
@@ -533,7 +546,8 @@ def _prepare(q, k, bias, causal, scale, dropout_rate, seed, q_offset,
                interpret=bool(interpret),
                precision=("highest" if q.dtype == jnp.float32
                           else "default"),
-               window=int(window), kv_group=BH // k.shape[0])
+               window=int(window), kv_group=BH // k.shape[0],
+               causal_block=int(causal_block))
     scalars = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                          jnp.asarray(k_offset, jnp.int32),
                          jnp.asarray(seed, jnp.int32)])
@@ -548,7 +562,8 @@ def flash_attention_with_lse(q, k, v, bias: Optional[jax.Array] = None,
                              q_offset=0, k_offset=0,
                              num_heads: int = 1,
                              block_q: int = 128, block_k: int = 128,
-                             interpret: bool = False, window: int = 0):
+                             interpret: bool = False, window: int = 0,
+                             causal_block: int = 0):
     """Flash attention over [B*H, S, D] tensors; returns (O, lse).
 
     ``bias`` is an additive [B, Sk] key bias (the padding-mask encoding —
@@ -563,10 +578,15 @@ def flash_attention_with_lse(q, k, v, bias: Optional[jax.Array] = None,
     ``window`` positions, itself included. ``k``/``v`` may have a whole
     fraction of ``q``'s leading B*H rows (grouped-query heads, forward
     only): query head ``n`` reads key/value head ``n // group``.
+    ``causal_block`` = L > 0 (with ``causal``, no window) makes the mask
+    causal by blocks of L positions: key ``j`` is visible to query ``i``
+    iff ``j // L <= i // L`` (block-diffusion prefill). Only the tiles on
+    the diagonal differ from the row-causal ones.
     """
     cfg, bias, scalars = _prepare(q, k, bias, causal, scale, dropout_rate,
                                   seed, q_offset, k_offset, num_heads,
-                                  block_q, block_k, interpret, window)
+                                  block_q, block_k, interpret, window,
+                                  causal_block)
     return _flash(cfg, q, k, v, bias, scalars)
 
 
@@ -576,7 +596,7 @@ def flash_attention_bwd(q, k, v, o, lse, do,
                         dropout_rate: float = 0.0, seed=0,
                         num_heads: int = 1, block_q: int = 128,
                         block_k: int = 128, interpret: bool = False,
-                        window: int = 0):
+                        window: int = 0, causal_block: int = 0):
     """(dQ, dK, dV) of :func:`flash_attention` for the cotangent ``do``,
     from the ``(o, lse)`` that :func:`flash_attention_with_lse` returned
     for the same operands, options and ``seed``: the two backward kernels
@@ -584,7 +604,7 @@ def flash_attention_bwd(q, k, v, o, lse, do,
     for a caller that kept the residuals itself."""
     cfg, bias, scalars = _prepare(q, k, bias, causal, scale, dropout_rate,
                                   seed, 0, 0, num_heads, block_q, block_k,
-                                  interpret, window)
+                                  interpret, window, causal_block)
     return _bwd_from_residuals(cfg, q, k, v, bias, scalars, o, lse, do)
 
 
@@ -593,11 +613,11 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
                     dropout_rate: float = 0.0, seed=0,
                     num_heads: int = 1, block_q: int = 128,
                     block_k: int = 128, interpret: bool = False,
-                    window: int = 0):
+                    window: int = 0, causal_block: int = 0):
     """Like :func:`flash_attention_with_lse` but returns only O."""
     o, _ = flash_attention_with_lse(
         q, k, v, bias=bias, causal=causal, scale=scale,
         dropout_rate=dropout_rate, seed=seed, num_heads=num_heads,
         block_q=block_q, block_k=block_k, interpret=interpret,
-        window=window)
+        window=window, causal_block=causal_block)
     return o

@@ -389,7 +389,8 @@ def matmul(x, y, transpose_x=False, transpose_y=False, alpha=1.0, name=None,
 
 def fused_multihead_attention(q, k, v, bias_qk=None, causal=False,
                               scale=0.0, attn_dropout=0.0, is_test=False,
-                              sequence_parallel=False, name=None, window=0):
+                              sequence_parallel=False, name=None, window=0,
+                              causal_block=0):
     """Fused multi-head attention (the reference `operators/fused/` role,
     here a Pallas flash kernel on TPU — ops/fused_attention.py).
 
@@ -404,7 +405,9 @@ def fused_multihead_attention(q, k, v, bias_qk=None, causal=False,
     path; without an sp axis it degrades to the plain fused path.
 
     k/v may carry a whole fraction of q's heads (grouped-query attention,
-    inference only); window > 0 with causal is a sliding window.
+    inference only); window > 0 with causal is a sliding window;
+    causal_block = L > 0 with causal makes the mask causal by blocks of L
+    rows (key j visible to query i iff j // L <= i // L).
 
     The op also keeps the softmax's log-sum-exp ([B, num_heads, S],
     float32) for its gradient op, as layer_norm keeps Mean/Variance; a
@@ -420,6 +423,8 @@ def fused_multihead_attention(q, k, v, bias_qk=None, causal=False,
              "is_test": is_test, "sequence_parallel": sequence_parallel}
     if window:
         attrs["window"] = int(window)
+    if causal_block:
+        attrs["causal_block"] = int(causal_block)
     helper.append_op("fused_multihead_attention", inputs=inputs,
                      outputs={"Out": out, "SoftmaxLse": lse}, attrs=attrs)
     if q.shape is not None:     # only the kernel routes emit it to infer from

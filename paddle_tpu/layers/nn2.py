@@ -39,6 +39,7 @@ __all__ = [
     "rotary_embedding", "moe_experts", "slot_assign", "gated_delta_rule",
     "rms_norm", "latent_attention",
     "sample_token", "spec_accept",
+    "block_seed", "block_positions", "block_reveal",
 ]
 
 
@@ -924,13 +925,15 @@ def filter_by_instag(ins, ins_tag, filter_tag, is_lod=True):
 
 def fused_decode_attention(q, k_new, v_new, cache_k, cache_v, positions,
                            scale=0.0, page_size=128, slot_mask=None,
-                           window=0, name=None):
-    """One autoregressive decode/verify chunk with the KV append fused in
+                           window=0, name=None, whole_chunk=False):
+    """One decode/verify chunk with the KV append fused in
     (ops/generation.py). q/k_new/v_new: [B, H, C, D] (C == 1 is the
     classic decode step; C <= 8 rides the chunk kernel); cache_k/cache_v:
     persistable paged caches [B, H, S_max, D]; positions: [B, 1] int —
     each sequence's length before this chunk. Query row i attends keys at
-    positions < pos + i + 1 (causal within the chunk). ``slot_mask``
+    positions < pos + i + 1 (causal within the chunk), or, with
+    ``whole_chunk``, every row those at positions < pos + C (the chunk is
+    a block-diffusion block, its rows see one another). ``slot_mask``
     [B, 1] (optional) gates the rows the append writes, so un-masked
     sequences' caches stay bit-untouched — the chunked-prefill /
     speculative dispatches run a subset of slots.
@@ -950,6 +953,8 @@ def fused_decode_attention(q, k_new, v_new, cache_k, cache_v, positions,
     attrs = {"scale": float(scale), "page_size": int(page_size)}
     if window:
         attrs["window"] = int(window)
+    if whole_chunk:
+        attrs["whole_chunk"] = True
     helper.append_op(
         "fused_decode_attention",
         inputs=inputs,
@@ -1145,6 +1150,67 @@ def spec_accept(sampled, drafts, start, name=None):
                      outputs={"AcceptLen": accept, "NewTok": new_tok,
                               "NewPos": new_pos})
     return accept, new_tok, new_pos
+
+
+def block_seed(prompt_ids, prompt_len, block_length, mask_id, name=None):
+    """What a block-diffusion prefill leaves for the decode phase
+    (ops/block_diffusion.py): from ``prompt_ids`` [R, S] and ``prompt_len``
+    [R, 1] = P, ``(tokens [R, L], revealed_at [R, L], start [R, 1], seated
+    [R, S] f32)``: the first block with the prompt's ``P % L`` left-over
+    tokens known, the row it starts at, ``(P // L) * L``, and a mask of
+    the rows before it."""
+    helper = LayerHelper("block_seed", name=name)
+    mk = helper.create_variable_for_type_inference
+    toks, at, start = (mk("int64", stop_gradient=True) for _ in range(3))
+    seated = mk("float32", stop_gradient=True)
+    helper.append_op("block_seed",
+                     inputs={"PromptIds": prompt_ids,
+                             "PromptLen": prompt_len},
+                     outputs={"Tokens": toks, "RevealedAt": at,
+                              "Start": start, "Seated": seated},
+                     attrs={"block_length": int(block_length),
+                            "mask_id": int(mask_id)})
+    return toks, at, start, seated
+
+
+def block_positions(start, block_length, name=None):
+    """``start`` [B, 1] int -> [B, L]: the rows of the block that starts
+    there (ops/block_diffusion.py)."""
+    helper = LayerHelper("block_positions", name=name)
+    out = helper.create_variable_for_type_inference(start.dtype,
+                                                    stop_gradient=True)
+    helper.append_op("block_positions", inputs={"Start": start},
+                     outputs={"Out": out},
+                     attrs={"block_length": int(block_length)})
+    return out
+
+
+def block_reveal(logits, tokens, revealed_at, start, step, active, mask_id,
+                 denoising_steps, max_seq, name=None):
+    """The end of one block-diffusion decode forward
+    (ops/block_diffusion.py): a block with masked positions reveals its
+    most confident ones, a block with none commits and moves on. The
+    state (``tokens``, ``revealed_at`` [B, L]; ``start``, ``step`` [B, 1])
+    is written in place under the gate ``active``. Returns ``(emitted
+    [B, L], emitted_at [B, L], emit_count [B, 1])``: the tokens a commit
+    yields, the forward of the block each was revealed at, and how many
+    they are (0 on a denoise forward)."""
+    helper = LayerHelper("block_reveal", name=name)
+    mk = helper.create_variable_for_type_inference
+    emitted, emitted_at, count = (mk("int64", stop_gradient=True)
+                                  for _ in range(3))
+    helper.append_op(
+        "block_reveal",
+        inputs={"Logits": logits, "Tokens": tokens,
+                "RevealedAt": revealed_at, "Start": start, "Step": step,
+                "Active": active},
+        outputs={"TokensOut": tokens, "RevealedAtOut": revealed_at,
+                 "StartOut": start, "StepOut": step, "Emitted": emitted,
+                 "EmittedAt": emitted_at, "EmitCount": count},
+        attrs={"mask_id": int(mask_id),
+               "denoising_steps": int(denoising_steps),
+               "max_seq": int(max_seq)})
+    return emitted, emitted_at, count
 
 
 def sample_token(logits, strategy="greedy", temperature=1.0, top_k=0,

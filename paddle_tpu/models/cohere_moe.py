@@ -174,7 +174,8 @@ def _ffn(h, hb, p: str, cfg, real=None, join: str = "mean"):
     shared experts' part (both f32) and the expert op's statistics. How
     the shared experts join the routed sum is the model's (``join``):
     their ``mean``; the one expert behind a learned sigmoid gate
-    (``gated``); or their plain ``sum``. A configuration with
+    (``gated``); or their plain ``sum``; a model with none
+    (``num_shared_experts`` 0) gets None for their part. A configuration with
     ``select_bias`` chooses its experts by score plus a stored bias, and
     one with ``route_scale`` scales the routed weights
     (``layers.moe_experts``)."""
@@ -193,6 +194,8 @@ def _ffn(h, hb, p: str, cfg, real=None, join: str = "mean"):
     # and the same rows of down, are shared expert t, so one product with
     # the stacked down matrix is their sum
     ns = cfg.num_shared_experts
+    if not ns:
+        return routed, None, stats
     shared = _gated_mlp(hb, ns * cfg.intermediate_size, f"{p}_shared", cfg)
     if join != "sum":
         shared = layers.scale(shared, scale=1.0 / ns)
@@ -259,11 +262,11 @@ def _logits(h2d, cfg, weight, logit_scale: float = 1.0):
     return out
 
 
-def _state_table(block, prefix: str, batch_slots: int):
+def _state_table(block, prefix: str, batch_slots: int, tokens: int = 1):
     """``(mk, sv, tok, pos, active)``: ``mk(name, shape, dtype)`` makes a
     persistable state var and enters it in ``sv`` (name -> (shape,
-    dtype)); the current token, position and decode gate per slot are in
-    it already."""
+    dtype)); the current token (``tokens`` of them where a step carries a
+    block), position and decode gate per slot are in it already."""
     sv = {}
 
     def mk(name, shape, dtype):
@@ -272,7 +275,8 @@ def _state_table(block, prefix: str, batch_slots: int):
         sv[name] = (tuple(shape), dtype)
         return block.var(name)
 
-    return (mk, sv, mk(f"{prefix}_gen_tokens", (batch_slots, 1), "int64"),
+    return (mk, sv,
+            mk(f"{prefix}_gen_tokens", (batch_slots, tokens), "int64"),
             mk(f"{prefix}_gen_pos", (batch_slots, 1), "int64"),
             mk(f"{prefix}_gen_active", (batch_slots, 1), "float32"))
 

@@ -61,7 +61,7 @@ def _route(sq: int, sk: int, dropout: float, platform=None) -> str:
 
 
 def _primitive_attention(ctx, q, k, v, bias, causal, scale, dropout,
-                         is_test, window=0):
+                         is_test, window=0, causal_block=0):
     """[BH, S, D] oracle path; matches the kernel semantics exactly."""
     prec = ("highest" if q.dtype == jnp.float32 else "default")
     if k.shape[0] != q.shape[0]:            # grouped-query heads
@@ -73,7 +73,10 @@ def _primitive_attention(ctx, q, k, v, bias, causal, scale, dropout,
         s = s + jnp.repeat(bias.astype(s.dtype), H, axis=0)[:, None, :]
     if causal:
         sq, sk = q.shape[1], k.shape[1]
-        d = jnp.arange(sq)[:, None] - jnp.arange(sk)[None, :]
+        qi, kj = jnp.arange(sq)[:, None], jnp.arange(sk)[None, :]
+        if causal_block:        # a query stands at its block's last row
+            qi = (qi // causal_block + 1) * causal_block - 1
+        d = qi - kj
         m = (d >= 0) & (d < window) if window else d >= 0
         s = jnp.where(m[None], s, jnp.asarray(-1e30, s.dtype))
     p = jax.nn.softmax(s, axis=-1)
@@ -96,16 +99,21 @@ class _Plan:
         self.dropout = 0.0 if self.is_test else float(attrs["attn_dropout"])
         self.causal = bool(attrs["causal"])
         self.window = int(attrs.get("window") or 0)
+        self.causal_block = int(attrs.get("causal_block") or 0)
         self.ring = bool(attrs.get("sequence_parallel"))
         H, Hkv, window = self.H, self.Hkv, self.window
         if H % Hkv or (window and not self.causal):
             raise ValueError(
                 f"fused_multihead_attention: {H} query heads over {Hkv} "
                 f"key/value heads; window={window} needs causal")
-        if self.ring and (window or Hkv != H):
+        if self.causal_block and (window or not self.causal):
+            raise ValueError(
+                f"fused_multihead_attention: causal_block="
+                f"{self.causal_block} needs causal and no window")
+        if self.ring and (window or Hkv != H or self.causal_block):
             raise NotImplementedError(
-                "sequence_parallel attention with a window or grouped-query "
-                "heads: the ring path carries neither")
+                "sequence_parallel attention with a window, grouped-query "
+                "heads or a block-causal mask: the ring path carries none")
 
     def rides_the_ring(self, mesh) -> bool:
         """sequence_parallel under a mesh with a real 'sp' axis; without
@@ -120,6 +128,7 @@ class _Plan:
     def kernel_options(self, route) -> dict:
         return dict(causal=self.causal, scale=self.scale,
                     dropout=self.dropout, window=self.window,
+                    causal_block=self.causal_block,
                     interpret=(route == "pallas-interpret"))
 
 
@@ -192,7 +201,7 @@ def _fused_mha_grad(ctx, ins, attrs):
                       IOSpec("SoftmaxLse", optional=True, no_grad=True)],
              attrs={"causal": False, "scale": 0.0, "attn_dropout": 0.0,
                     "is_test": False, "sequence_parallel": False,
-                    "window": 0},
+                    "window": 0, "causal_block": 0},
              needs_rng=True, grad_lower=_fused_mha_grad)
 def _fused_mha(ctx, ins, attrs):
     """Q/K/V: [B, num_heads, S, head_dim]. BiasQK: additive key bias,
@@ -218,7 +227,10 @@ def _fused_mha(ctx, ins, attrs):
     ``K``/``V`` may carry a whole fraction of ``Q``'s heads (grouped-query
     attention, inference only): query head ``n`` reads key/value head
     ``n // group``. ``window`` > 0 (with ``causal``) is a sliding window:
-    key ``j`` is visible to query ``i`` iff ``0 <= i - j < window``."""
+    key ``j`` is visible to query ``i`` iff ``0 <= i - j < window``.
+    ``causal_block`` = L > 0 (with ``causal``, no window) is the mask of a
+    block-diffusion prefill, causal by blocks of L rows: key ``j`` is
+    visible to query ``i`` iff ``j // L <= i // L``."""
     q, k, v = x(ins, "Q"), x(ins, "K"), x(ins, "V")
     plan = _Plan(ins, attrs)
     B, H, Sq, D = q.shape
@@ -245,12 +257,16 @@ def _fused_mha(ctx, ins, attrs):
 
     route = plan.route(ctx)
     note_kernel_route(ctx, "fused_multihead_attention", route)
+    if plan.causal_block:
+        note_kernel_route(ctx, "fused_multihead_attention.causal_block",
+                          route)
     if route == "primitive":
         o = _primitive_attention(ctx, q.reshape(B * H, Sq, D),
                                  k.reshape(B * plan.Hkv, plan.Sk, D),
                                  v.reshape(B * plan.Hkv, plan.Sk, D),
                                  _key_bias(ins), plan.causal, plan.scale,
-                                 plan.dropout, plan.is_test, plan.window)
+                                 plan.dropout, plan.is_test, plan.window,
+                                 plan.causal_block)
         return {"Out": [o.reshape(B, H, Sq, D)]}
 
     kernel = functools.partial(_kernel_attention,
@@ -261,7 +277,7 @@ def _fused_mha(ctx, ins, attrs):
 
 
 def _kernel_attention(seed, q, k, v, bias=None, *, causal, scale, dropout,
-                      interpret, window=0):
+                      interpret, window=0, causal_block=0):
     """The flash kernel over one [B, H, S, D] block (bias [B, Sk]):
     the output and its log-sum-exp [B, H, Sq]."""
     from ..kernels import flash_attention_with_lse
@@ -272,12 +288,12 @@ def _kernel_attention(seed, q, k, v, bias=None, *, causal, scale, dropout,
         q.reshape(B * H, Sq, D), k.reshape(B * Hkv, Sk, D),
         v.reshape(B * Hkv, Sk, D), bias=bias, causal=causal, scale=scale,
         dropout_rate=dropout, seed=seed, num_heads=H, interpret=interpret,
-        window=window)
+        window=window, causal_block=causal_block)
     return o.reshape(B, H, Sq, D), lse.reshape(B, H, Sq)
 
 
 def _kernel_attention_bwd(seed, q, k, v, bias, o, lse, do, *, causal, scale,
-                          dropout, interpret, window=0):
+                          dropout, interpret, window=0, causal_block=0):
     """The two backward kernels over one [B, H, S, D] block, from the
     forward's saved ``o`` and ``lse``: (dQ, dK, dV)."""
     from ..kernels import flash_attention_bwd
@@ -289,7 +305,8 @@ def _kernel_attention_bwd(seed, q, k, v, bias, o, lse, do, *, causal, scale,
         v.reshape(B * Hkv, Sk, D), o.reshape(B * H, Sq, D),
         lse.reshape(B * H, Sq), do.reshape(B * H, Sq, D), bias=bias,
         causal=causal, scale=scale, dropout_rate=dropout, seed=seed,
-        num_heads=H, interpret=interpret, window=window)
+        num_heads=H, interpret=interpret, window=window,
+        causal_block=causal_block)
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 

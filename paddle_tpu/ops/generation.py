@@ -2,8 +2,13 @@
 bulk KV writes, last-position gathers and in-program token sampling.
 
 These are the decode-step building blocks of ``models/gpt.py`` and the
-serving layer's prefill/decode split (``serving.generate``). Two design
-rules shape them:
+serving layer's prefill/decode split (``serving.generate``). A decode step
+is one forward of every slot; what it yields is the model's to say: one
+token a slot for an autoregressive decoder (``sample_token``), or, for a
+decoder that generates by diffusion over blocks, 0 to ``block_length``
+tokens (``fused_decode_attention`` with ``whole_chunk`` over the block's
+rows, then ``ops/block_diffusion.py``'s ``block_reveal``). Two design rules
+shape them:
 
 * **The KV append is fused into the decode attention op** (CODA, PAPERS.md
   arXiv 2605.19269: fold decode-step epilogue work into the fused kernels):
@@ -14,9 +19,10 @@ rules shape them:
   ``run_chained``'s scan carry. A separate append-then-attend op pair
   would read the cache after its write and the liveness proof would
   (correctly) refuse the donation.
-* **Sampling runs in-program** (``sample_token``): the sampled token is a
-  program state write, so a whole decode chunk runs as ONE ``run_chained``
-  dispatch with no host round-trip per token; seeded through the op-uid
+* **Sampling runs in-program** (``sample_token``; a block's reveal and
+  commit likewise, ``block_reveal``): what a forward chose is a program
+  state write, so a whole decode chunk runs as ONE ``run_chained``
+  dispatch with no host round-trip per forward; seeded through the op-uid
   PRNG discipline, CI runs are deterministic.
 """
 from __future__ import annotations
@@ -65,7 +71,8 @@ def _route_decode(s_max: int, page_size: int, q_len: int = 1,
             IOSpec("Positions", no_grad=True),
             IOSpec("SlotMask", optional=True, no_grad=True)],
     outputs=["Out", "CacheKOut", "CacheVOut"],
-    attrs={"scale": 0.0, "page_size": 128, "window": 0},
+    attrs={"scale": 0.0, "page_size": 128, "window": 0,
+           "whole_chunk": False},
     grad=None)
 def _fused_decode_attention(ctx, ins, attrs):
     """One autoregressive decode/verify chunk, epilogue fused:
@@ -78,7 +85,12 @@ def _fused_decode_attention(ctx, ins, attrs):
     2. attend the C query rows against the updated cache with a
        per-sequence, per-row causal length mask (query row i sees keys
        at positions < pos + i + 1 — its own K row and everything before,
-       never a later chunk row).
+       never a later chunk row). With ``whole_chunk`` the chunk is a block
+       whose rows see one another in both directions (a block-diffusion
+       decode forward: C = block_length rows a sequence, which yield 0 to C
+       tokens): every row sees the keys at positions < pos + C. Such a
+       block is appended as one slice a sequence where the cache lies as
+       declared, so it has to lie inside the cache (no per-row clamp).
 
     ``SlotMask`` [B, 1] (optional) gates the ROWS that step 1 writes: a
     sequence whose mask is 0 writes its own old rows back (or, past
@@ -112,7 +124,8 @@ def _fused_decode_attention(ctx, ins, attrs):
     Only single-row steps wrap (a chunk's causal order is its row order).
     """
     from ..kernels import (decode_attention_reference, flash_attention_decode,
-                           kv_append, paged_kv_append_rows, rows_minor)
+                           kv_append, paged_kv_append, paged_kv_append_rows,
+                           rows_minor)
 
     q, kn, vn = x(ins, "Q"), x(ins, "KNew"), x(ins, "VNew")
     ck, cv = x(ins, "CacheK"), x(ins, "CacheV")
@@ -125,6 +138,7 @@ def _fused_decode_attention(ctx, ins, attrs):
     H, S = ck.shape[1], ck.shape[2]
     G = Hq // H
     window = int(attrs.get("window") or 0)
+    whole = bool(attrs.get("whole_chunk"))
     if Hq % H or kn.shape[1] != H:
         raise ValueError(
             f"fused_decode_attention: {Hq} query heads over caches of {H} "
@@ -140,6 +154,8 @@ def _fused_decode_attention(ctx, ins, attrs):
     route = _route_decode(S, page, q_len=q_len,
                           platform=lowering_platform(ctx))
     note_kernel_route(ctx, "fused_decode_attention", route)
+    if whole:
+        note_kernel_route(ctx, "fused_decode_attention.whole_chunk", route)
     # the append works in the view the kernel reads (kernels.rows_minor:
     # [B, H, D, S_max] where the runtime stores the cache so), or a layout
     # conversion of every cache lands between the two, inside the scan;
@@ -150,6 +166,12 @@ def _fused_decode_attention(ctx, ins, attrs):
         note_kernel_route(ctx, "kv_append", route)
 
     def append(cache, new):
+        if whole and not minor:
+            # a block goes in as one slice a sequence, a quarter of the
+            # row-by-row form's updates at 4 rows: its start clamps as a
+            # whole, so a block has to lie inside the cache (a start on a
+            # whole block in a cache of whole blocks does)
+            return paged_kv_append(cache, new, pos_b, smask)
         if not minor:
             return paged_kv_append_rows(cache, new, pos_b, smask,
                                         ring=bool(window))
@@ -170,12 +192,12 @@ def _fused_decode_attention(ctx, ins, attrs):
     if route == "primitive":
         o = decode_attention_reference(q3, k3, v3,
                                        jnp.repeat(lengths, H, axis=0), scale,
-                                       group=G)
+                                       group=G, whole_chunk=whole)
     else:
         o = flash_attention_decode(
             q3, k3, v3, lengths, scale=scale, num_heads=H,
             page_size=page, group=G,
-            interpret=(route == "pallas-interpret"))
+            interpret=(route == "pallas-interpret"), whole_chunk=whole)
     o = o.reshape(B * H, q_len, G, D).swapaxes(1, 2)
     return {"Out": [o.reshape(B, Hq, q_len, D)],
             "CacheKOut": [ck2], "CacheVOut": [cv2]}

@@ -320,6 +320,7 @@ class ServingFuture:
         # streamed partial results (generative requests): guarded by
         # _lock, waiters ride the shared-lock condition
         self._tokens: List[Any] = []
+        self._revealed_at: List[int] = []
         self._stream_cond = _monitor.make_condition(
             "ServingFuture._stream_cond", self._lock)
 
@@ -332,6 +333,14 @@ class ServingFuture:
         the salvage after a mid-stream typed failure)."""
         with self._lock:
             return list(self._tokens)
+
+    def revealed_at(self) -> List[int]:
+        """Beside each streamed token of a model that generates a block at
+        a time, the forward of its block at which it was revealed (0 =
+        the block's first); empty for a model that yields one token a
+        forward."""
+        with self._lock:
+            return list(self._revealed_at)
 
     def stream(self, timeout: Optional[float] = None):
         """Yield tokens as the engine emits them. Ends with normal
@@ -360,8 +369,10 @@ class ServingFuture:
                     raise self._error
                 return
 
-    def _emit_tokens(self, toks: Sequence[Any]) -> None:
-        """Engine side: append partial results and wake stream waiters.
+    def _emit_tokens(self, toks: Sequence[Any],
+                     revealed_at: Sequence[int] = ()) -> None:
+        """Engine side: append partial results (with, for a block at a
+        time, the forward each was revealed at) and wake stream waiters.
         Emitting after the terminal outcome is an engine bug — the
         settle is the LAST word on a request."""
         with self._stream_cond:
@@ -370,6 +381,7 @@ class ServingFuture:
                     "serving internal error: token emitted after the "
                     "request's terminal outcome")
             self._tokens.extend(toks)
+            self._revealed_at.extend(revealed_at)
             self._stream_cond.notify_all()
 
     def result(self, timeout: Optional[float] = None) -> List[np.ndarray]:

@@ -11,8 +11,9 @@ work into the two phases of ``models/gpt.py``:
   bucket (padded to the bucket length). The prefill writes the slot's KV
   pages, merges the slot's generation state, and produces the request's
   FIRST token — streamed immediately.
-* **decode** — every active slot advances ``decode_chunk`` tokens per
-  dispatch as ONE ``run_chained`` scan (the paged KV caches ride the scan
+* **decode** — every active slot advances ``decode_chunk`` forwards per
+  dispatch (a token each, or what the decode net's yield says: see
+  **Yield** below) as ONE ``run_chained`` scan (the paged KV caches ride the scan
   carry, donation-proven, updated in place; sampling runs in-program so
   no host round-trip separates tokens). Sequences sit at *different
   positions* inside one batch — position is data, not shape, so every
@@ -20,6 +21,22 @@ work into the two phases of ``models/gpt.py``:
   ``serving_decode_recompiles_total`` guard turns any violation (a shape
   leaking into a cache key as KV grows) into a counted, logged event and
   a CI-gated metric.
+
+**Yield.** What a decode dispatch gave its slots is read in one place
+(:class:`_Yield`). A decode net says how its tokens come out: with nothing
+said, one token a forward for every slot whose gate is open
+(``next_token`` [slots, 1], the autoregressive builders); or, under
+``yield``, ``tokens`` [slots, W] with ``count`` [slots, 1] (and
+``revealed_at`` [slots, W]) a forward: a model that generates a block of
+``block_length`` positions at a time (``models/sdar_moe.py``) carries the
+block through several forwards, yields 0 tokens on those that reveal
+positions and up to ``block_length`` on the one that commits the block. A
+request's budget may end inside a block (the block's tail is dropped), a
+deadline is checked after a dispatch whatever forward of a block it ended
+on (the slot's block state is device state and is reseeded by the next
+prefill), and such a model's prefill streams no token: its first tokens
+come with its first committed block. Every token metric, the per-token
+timing, the cache walk and the settle loop read the yield.
 
 ISSUE 20 adds two composable phases on the same slot/bucket discipline:
 
@@ -45,7 +62,7 @@ ONE terminal outcome. Streamed tokens are partial results, not outcomes —
 a request that expires mid-stream settles ``DeadlineExceeded`` (typed)
 with its partial tokens still readable from the future. Deadlines apply
 per token: they are re-checked before every prefill and after every
-decode chunk, so an expired stream stops within ``decode_chunk`` tokens.
+decode chunk, so an expired stream stops within ``decode_chunk`` forwards.
 
 Failure isolation: an injected ``batch_dispatch`` fault (the chaos gate's
 kill-one-batch leg) fails exactly the streams in that dispatch, typed
@@ -94,12 +111,64 @@ def _loop_phase(name: str, parent=None):
         "serving_loop_seconds", _LOOP_HELP, {"phase": name}))
 
 
+class _Yield:
+    """What one chained decode dispatch of ``steps`` forwards gave each
+    slot, forward by forward: ``tokens`` [steps, slots, W] of which the
+    first ``counts`` [steps, slots] of a forward are real, and (a model
+    that generates a block at a time) ``revealed_at`` [steps, slots, W],
+    the forward of its block at which each was revealed. ``rows`` is what
+    a slot's sequence moves on in the cache with a forward that yields:
+    one row for a model that yields one token a forward (W = 1, every
+    count 1), a block for one that commits blocks."""
+
+    def __init__(self, outs, steps: int, slots: int, block: int):
+        W = block or 1
+        self.steps, self.rows = steps, W
+        self.tokens = np.asarray(outs[0]).reshape(steps, slots, W)
+        if block:
+            self.counts = np.asarray(outs[1]).reshape(steps, slots)
+            self.revealed_at = np.asarray(outs[2]).reshape(steps, slots, W)
+        else:
+            self.counts = np.ones((steps, slots), np.int64)
+            self.revealed_at = None
+
+    def of(self, slot: int, budget: int, cut=None):
+        """``(tokens, revealed_at, forwards, dropped)`` for the request in
+        ``slot`` with ``budget`` tokens still to come: the tokens it takes
+        in order (through ``cut``, the stop-token rule), the forward each
+        was revealed at (empty where the model has none), how many of the
+        dispatch's forwards were this request's (through the one that
+        yielded its last token if it ends here, else all of them), and how
+        many tokens those forwards yielded past its budget."""
+        counts = self.counts[:, slot]
+        real = np.arange(self.rows)[None, :] < counts[:, None]
+        toks = self.tokens[:, slot][real]
+        take = toks[:budget]
+        if cut is not None:
+            take = cut(take)
+        ends = len(take) == budget or len(take) < min(len(toks), budget)
+        forwards, dropped = self.steps, 0
+        if ends and len(take):
+            fwd = np.repeat(np.arange(self.steps), counts)
+            forwards = int(fwd[len(take) - 1]) + 1
+            dropped = int(counts[:forwards].sum()) - len(take)
+        at = () if self.revealed_at is None else \
+            self.revealed_at[:, slot][real][:len(take)]
+        return take, at, forwards, dropped
+
+    def moved(self, slot: int):
+        """[steps]: the rows the slot's sequence had moved on in the cache
+        before each forward of the dispatch."""
+        c = (self.counts[:, slot] > 0) * self.rows
+        return np.cumsum(c) - c
+
+
 @dataclasses.dataclass
 class GenerationConfig:
     """Generative-scheduling knobs (the serving half; model geometry —
     slots, pages, buckets — lives on the ``build_gpt_generative`` dict)."""
 
-    decode_chunk: int = 4          # tokens per chained decode dispatch;
+    decode_chunk: int = 4          # forwards per chained decode dispatch;
     # also the deadline-enforcement granularity
     max_new_tokens_default: int = 16
     eos_id: int = -1               # < 0: no stop token
@@ -150,11 +219,22 @@ class GenerativeEngine(ServingEngine):
                  config: Optional[ServingConfig] = None,
                  gen_config: Optional[GenerationConfig] = None):
         decode = model["decode"]
+        # how the decode net's tokens come out (module docstring "Yield")
+        out = decode.get("yield")
         super().__init__(decode["main"], feed_names=[],
-                         fetch_list=[decode["next_token"]],
+                         fetch_list=([decode["next_token"]] if out is None
+                                     else [out["tokens"], out["count"],
+                                           out["revealed_at"]]),
                          scope=scope, place=place, executor=executor,
                          config=config)
         self._model = model
+        # rows a decode forward carries a slot where the model generates a
+        # block at a time; 0: one token a forward
+        self._block = int(model.get("block_length") or 0)
+        if bool(self._block) != (out is not None):
+            raise ValueError(
+                "serving: a model that generates a block at a time names "
+                "both its block_length and its decode net's yield")
         self.gen_config = (gen_config or GenerationConfig()).resolve()
         self._slots: List[Optional[_GenRequest]] = \
             [None] * int(model["batch_slots"])
@@ -223,6 +303,11 @@ class GenerativeEngine(ServingEngine):
             from .prefix_cache import PrefixCache
             self._prefix_cache = PrefixCache(
                 self._page_size, capacity_pages=gc.prefix_cache_pages)
+        if self._block and (gc.speculative or self._chunk is not None
+                            or self._verify is not None):
+            raise ValueError(
+                "serving: a model that generates a block at a time has no "
+                "speculative, prefix-cache or chunked-prefill phase")
         self._speculative = bool(
             gc.speculative and self._verify is not None and self._spec_k >= 2)
         # host-side draft proposer for speculative decoding: callable
@@ -684,11 +769,6 @@ class GenerativeEngine(ServingEngine):
                 ).observe(dt)
             first = np.asarray(outs[0]).reshape(len(self._slots))
             for r in done:
-                if _monitor.enabled():
-                    _monitor.histogram(
-                        "serving_first_token_seconds",
-                        "submit-to-first-token latency (prefill + queue)"
-                    ).observe(self._now() - r.submitted)
                 self._emit(r, [int(first[r.slot])], dt,
                            record_intertoken=False)
             self._settled(ph, done, len(done))
@@ -907,9 +987,13 @@ class GenerativeEngine(ServingEngine):
             self._publish(reqs)
             with _loop_phase("settle") as ph:
                 self._note_compiles("prefill", bucket, net["main"])
-                self._observe_stats("prefill", ("prefill", bucket), outs[1:])
+                self._observe_stats("prefill", ("prefill", bucket),
+                                    outs[0 if self._block else 1:])
+                # a prefill by blocks seats a prompt's whole blocks; what
+                # is left over opens the slot's first decode block
+                whole = self._block or 1
                 self._count_prefill_tokens(
-                    sum(len(r.prompt) for r in reqs),
+                    sum(len(r.prompt) // whole * whole for r in reqs),
                     (self._prefill_rows(bucket) or len(self._slots))
                     * bucket)
                 if _monitor.enabled():
@@ -917,18 +1001,14 @@ class GenerativeEngine(ServingEngine):
                         "serving_prefill_seconds",
                         "wall time of one slot-masked prefill dispatch"
                     ).observe(dt)
-                first = np.asarray(outs[0]).reshape(-1)
+                first = None if self._block \
+                    else np.asarray(outs[0]).reshape(-1)
                 tokens = 0
                 for i, r in enumerate(reqs):
                     r.prefilled = True
                     r.next_off = len(r.prompt)
-                    if self._expired(r):
+                    if self._expired(r) or first is None:
                         continue
-                    if _monitor.enabled():
-                        _monitor.histogram(
-                            "serving_first_token_seconds",
-                            "submit-to-first-token latency (prefill + "
-                            "queue)").observe(self._now() - r.submitted)
                     # the first token's cost is the FIRST-TOKEN histogram's
                     # story — it must not pollute the inter-token latency
                     tokens += 1
@@ -947,6 +1027,7 @@ class GenerativeEngine(ServingEngine):
         if _trace.enabled():
             span = _trace.root_span(
                 "serving.decode", steps=steps, requests=len(active),
+                block_length=self._block,
                 request_traces=",".join(r.span.trace_id for r in active))
         try:
             _faults.fault_point("batch_dispatch")
@@ -971,45 +1052,116 @@ class GenerativeEngine(ServingEngine):
         span.end()
         with _loop_phase("settle") as ph:
             self._note_compiles("decode", len(self._slots), self._program)
-            self._observe_stats("decode", "decode", outs[1:])
-            self._observe_walk(active, steps)
-            toks = np.asarray(outs[0]).reshape(steps, len(self._slots))
-            per_tok = dt / steps
+            n = len(self._fetch_names)
+            self._observe_stats("decode", "decode", outs[n:])
+            out = _Yield(outs[:n], steps, len(self._slots), self._block)
+            self._observe_walk(active, steps, out)
             if _monitor.enabled():
                 _monitor.histogram(
                     "serving_decode_chunk_seconds",
-                    "wall time of one chained decode chunk").observe(dt)
-            tokens = 0
+                    "wall time of one chained decode dispatch (its "
+                    "forwards yield what the decode net says: a token a "
+                    "slot each, or a block's when it commits)").observe(dt)
+            tokens, theirs = 0, []
             for r in active:
                 # mid-stream expiry: the typed outcome is the LAST word —
                 # this chunk's tokens are discarded, the ones already
                 # streamed remain readable as partial results
                 if self._expired(r):
                     continue
-                take = self._cut_at_eos(toks[:r.max_new - r.emitted, r.slot])
+                take, at, forwards, dropped = out.of(
+                    r.slot, r.max_new - r.emitted, self._cut_at_eos)
+                theirs.append((r.slot, forwards, dropped))
                 tokens += len(take)
-                self._emit(r, [int(t) for t in take], per_tok * len(take))
+                # the forwards that were this request's, over its tokens
+                self._emit(r, [int(t) for t in take], dt * forwards / steps,
+                           revealed_at=[int(t) for t in at])
+            if self._block:
+                self._count_block_forwards(out, theirs)
             self._settled(ph, active, tokens)
 
-    def _observe_walk(self, active: Sequence[_GenRequest],
-                      steps: int) -> None:
+    @staticmethod
+    def _count_block_forwards(out: _Yield, theirs) -> None:
+        """What a dispatch's forwards were, for a model that generates a
+        block at a time; ``theirs``: per live request ``(slot, its
+        forwards of the dispatch, tokens they yielded past its budget)``.
+        Forwards that committed a block and forwards that revealed
+        positions, the positions each of a committed block's forwards
+        revealed, and the tokens last blocks generated past their answers'
+        ends."""
+        if not _monitor.enabled():
+            return
+        commits = forwards = dropped = 0
+        revealed = _monitor.histogram(
+            "serving_tokens_revealed_per_forward",
+            "positions one denoise forward revealed in one slot's block, "
+            "observed when the block commits",
+            buckets=tuple(float(i) for i in range(1, 9)))
+        for slot, n, tail in theirs:
+            counts = out.counts[:n, slot]
+            forwards, dropped = forwards + n, dropped + tail
+            for s in np.nonzero(counts)[0]:
+                commits += 1
+                at = out.revealed_at[s, slot, :counts[s]]
+                for t in range(int(at.max()) + 1):
+                    revealed.observe(float((at == t).sum()))
+        fw = _monitor.counter(
+            "serving_block_forwards_total",
+            "decode forwards of resident requests of a model that "
+            "generates a block at a time, a slot at a time: kind=denoise "
+            "revealed positions of a block and yielded no token, "
+            "kind=commit ran a finished block, wrote its keys and values "
+            "and yielded its tokens")
+        fw.labels(kind="commit").inc(commits)
+        fw.labels(kind="denoise").inc(forwards - commits)
+        _monitor.counter(
+            "serving_blocks_committed_total",
+            "blocks committed to the cache and yielded").inc(commits)
+        _monitor.counter(
+            "serving_block_tail_tokens_total",
+            "tokens a request's last block generated past the end of its "
+            "answer, which are not streamed").inc(dropped)
+
+    def _observe_walk(self, active: Sequence[_GenRequest], steps: int,
+                      out: Optional[_Yield] = None) -> None:
         """How much of the residents' caches this decode dispatch's
         attention kernels walk: k-blocks fetched over k-blocks held, from
         the lengths this thread holds and the kernel module's own count
-        (on the CPU too, where the primitive route scores every row)."""
+        (on the CPU too, where the primitive route scores every row).
+        ``out``: what the dispatch yielded (without it, a row a forward)."""
         if not active or not _monitor.enabled():
             return
         from ..kernels import decode_walk_blocks
         from ..kernels.latent_attention import latent_walk_blocks
 
-        # step s of the chunk sees the keys so far and its own
-        lengths = (np.array([len(r.prompt) + r.emitted for r in active])
-                   + np.arange(steps)[:, None])
-        fetched = held = 0
+        # a forward's first row sees the keys so far and its own: a
+        # sequence's rows before the dispatch (the last token streamed is
+        # this dispatch's first row; a block starts on a whole block) and
+        # those it moved on inside it, which the yield says
+        q_len = out.rows if out is not None else 1
+        moved = np.arange(steps)[:, None] if out is None else np.stack(
+            [out.moved(r.slot) for r in active], axis=1)
+        before = np.array([(len(r.prompt) + r.emitted) // q_len * q_len
+                           - (0 if self._block else 1) for r in active])
+        lengths = before + 1 + moved
+        fetched = held = keys = 0
         for (shape, dt), n in self._cache_shapes.items():
             f, h = decode_walk_blocks(np.minimum(lengths, shape[2]), shape,
-                                      np_dtype(dt), self._page_size)
+                                      np_dtype(dt), self._page_size,
+                                      q_len=q_len)
             fetched, held = fetched + n * f, held + n * h
+            keys += n * int(np.minimum(lengths + q_len - 1, shape[2]).sum())
+        if keys:
+            _monitor.counter(
+                "decode_attention_keys_total",
+                "key rows the decode forwards' attention had to read: per "
+                "forward, resident sequence and layer with a K/V cache, "
+                "the keys its chunk's last row sees").inc(keys)
+            _monitor.counter(
+                "decode_attention_calls_total",
+                "calls of the decode attention over a K/V cache: a "
+                "forward and layer").inc(
+                steps * sum(self._cache_shapes.values()))
         for (shape, dt), n in self._latent_shapes.items():
             f, h = latent_walk_blocks(np.minimum(lengths, shape[2]), shape,
                                       np_dtype(dt), self._page_size)
@@ -1052,14 +1204,23 @@ class GenerativeEngine(ServingEngine):
                 1 for r in reqs if r.future.done()))
 
     def _emit(self, r: _GenRequest, toks: List[int], dt: float,
-              record_intertoken: bool = True) -> None:
-        """Stream ``toks`` to the future (partial results) and settle the
-        request when it reaches its token budget or stop token.
-        ``record_intertoken=False`` on the prefill-produced first token:
-        its cost belongs to ``serving_first_token_seconds``, not the
-        inter-token distribution."""
+              record_intertoken: bool = True, revealed_at=()) -> None:
+        """Stream ``toks`` to the future (partial results; ``revealed_at``
+        beside them where the model reveals a block's positions over
+        several forwards) and settle the request when it reaches its token
+        budget or stop token. ``record_intertoken=False`` on the
+        prefill-produced first token: its cost belongs to
+        ``serving_first_token_seconds``, not the inter-token
+        distribution."""
         if toks:
-            r.future._emit_tokens(toks)
+            if not r.emitted and _monitor.enabled():
+                # a request's first tokens: the prefill's, or, where a
+                # prefill streams none, its first committed block's
+                _monitor.histogram(
+                    "serving_first_token_seconds",
+                    "submit-to-first-token latency (prefill + queue)"
+                ).observe(self._now() - r.submitted)
+            r.future._emit_tokens(toks, revealed_at)
             r.out_tokens.extend(toks)
             r.emitted += len(toks)
             if _monitor.enabled():
@@ -1199,8 +1360,8 @@ class GenerativeEngine(ServingEngine):
     # -- what the device counted -------------------------------------------
     def _prefill_fetches(self, bucket: int) -> List[str]:
         net = self._model["prefill"][bucket]
-        return [net["first_token"].name] + list(
-            self._stats_fetch["prefill", bucket].values())
+        first = [] if self._block else [net["first_token"].name]
+        return first + list(self._stats_fetch["prefill", bucket].values())
 
     def _decode_fetches(self) -> List[str]:
         return self._fetch_names + list(self._stats_fetch["decode"].values())

@@ -432,3 +432,27 @@ def test_latent_decode_appends_by_one_scatter_in_place(v5e):
     assert not re.search(r"%kv_append[.\d]* = ", text)
     assert not re.search(r"%copy[.\d]* = bf16\[128,1,4096,640\]", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 64e6
+
+
+# -- the block-diffusion decoder's two masks at its published widths (PR 41) --
+
+def test_block_masks_compile_at_the_published_widths(v5e):
+    """One decode forward of 64 slots x 4 key/value heads of 128 dims with
+    a block of 4 rows of each of a group's 8 query heads (32 sublane rows)
+    in one call, every row seeing the whole block, against bf16 caches of
+    2,048 rows; the flash forward causal by blocks of 4 at 1,024 rows, 32
+    query heads over 4, with a padding bias."""
+    bf = jnp.bfloat16
+    cache = v5e((64 * 4, 2048, 128), bf)
+    text = _compiles_with_mosaic(
+        lambda q, k, v, n: flash_attention_decode(
+            q, k, v, n, num_heads=4, page_size=128, group=8,
+            whole_chunk=True),
+        v5e((64 * 4, 32, 128), bf), cache, cache, v5e((64,), jnp.int32))
+    assert re.search(r"%decode_attention[.\d]* = ", text)
+    kv = v5e((4, 1024, 128), bf)
+    text = _compiles_with_mosaic(
+        lambda q, k, v, b: flash_attention(q, k, v, bias=b, causal=True,
+                                           num_heads=32, causal_block=4),
+        v5e((32, 1024, 128), bf), kv, kv, v5e((1, 1024), jnp.float32))
+    assert re.search(r"%flash_attention_fwd[.\d]* = ", text)
