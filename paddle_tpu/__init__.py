@@ -11,8 +11,8 @@ from . import initializer, layers, unique_name
 from .backward import append_backward, calc_gradient, gradients
 from .clip import (GradientClipByGlobalNorm, GradientClipByNorm,
                    GradientClipByValue, set_gradient_clip)
-from .executor import (CPUPlace, CUDAPlace, Executor, Scope, TPUPlace,
-                       global_scope, scope_guard)
+from .executor import (FETCH_LATER, CPUPlace, CUDAPlace, Executor, Scope,
+                       TPUPlace, global_scope, scope_guard)
 from .framework import (Block, Operator, Parameter, Program, Variable,
                         default_main_program, default_startup_program,
                         in_dygraph_mode, name_scope, program_guard)

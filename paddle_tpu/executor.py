@@ -41,7 +41,7 @@ from .resilience import nonfinite as _nonfinite
 from .resilience.retry import call_with_retry
 
 __all__ = ["Executor", "Scope", "global_scope", "scope_guard", "CPUPlace",
-           "TPUPlace", "CUDAPlace", "default_place"]
+           "TPUPlace", "CUDAPlace", "default_place", "FETCH_LATER"]
 
 
 # ---------------------------------------------------------------------------
@@ -726,7 +726,7 @@ class Executor:
                 # The monitor's accounting is the tail of the writeback
                 # phase: results to the scope, then to the records.
                 with _trace.phase("executor.writeback"):
-                    _monitor.step_end(mrec)
+                    self._step_end(mrec)
 
     def _run_body(self, program, feed, scope, return_numpy, step, mrec):
         device = self.place.jax_device()
@@ -825,20 +825,20 @@ class Executor:
                     scope.set_var(n, v)
         if not return_numpy:
             return list(fetches)
-        return self._fetch_to_host(fetches, mrec)
+        return self._fetched(fetches, mrec, return_numpy)
 
-    @staticmethod
-    def _fetch_to_host(fetches, mrec):
+    def _fetch_to_host(self, fetches, mrec, parent=None):
         """The ``executor.fetch`` phase: the host blocks on the device's
-        results and copies them back. Its wall is the dispatch's
-        ``fetch_wait_s``, its exit the dispatch's ``ready_t``."""
-        with _trace.phase("executor.fetch", timed=mrec is not None) as ph:
+        results and copies them back (``fetch_wait_s``; its exit ``ready_t``)."""
+        with _trace.phase("executor.fetch", parent=parent,
+                          timed=mrec is not None) as ph:
             outs = [np.asarray(v) for v in fetches]
             if ph.traced:
                 ph.set_attributes(bytes=_live_bytes(outs))
         if mrec is not None:
             mrec.fetch_bytes = _live_bytes(outs)
             mrec.fetch_wait_s, mrec.ready_t = ph.seconds, ph.t1
+            self._landed(mrec)
         return outs
 
     def run_chained(
@@ -899,7 +899,7 @@ class Executor:
                                               return_numpy, step, mrec, hit)
             finally:
                 with _trace.phase("executor.writeback"):
-                    _monitor.step_end(mrec)
+                    self._step_end(mrec)
 
     def _lookup_chained(self, submitted, program, feed, fetch_names, steps,
                         scope, mrec):
@@ -1110,7 +1110,7 @@ class Executor:
                                     fin_carried, fin_wo)
         if not return_numpy:
             return list(stacked)
-        return self._fetch_to_host(stacked, mrec)
+        return self._fetched(stacked, mrec, return_numpy)
 
     def _bind_chained(self, program, steps, scope, step, mrec, feed_vals):
         """State, keys, write-only carries and the executable of one
@@ -1446,40 +1446,126 @@ class Executor:
     # ``_ensure_executable_locked`` are on every kernel's call stack, whose
     # source locations are part of the persistent compile cache's key)
 
-    # the ``StepRecord`` of the last launch: its ``ready_t`` (None where it
-    # raised or did not fetch) starts the gap that
-    # ``executor_starved_seconds`` measures at the next launch
+    # the ``StepRecord`` of the last launch, and the instant up to which
+    # this executor's life has been told apart into in flight and starved
+    # (a launch with nothing in flight moves it on and observes the gap, a
+    # fetch return moves it on and observes the time in flight; None: no
+    # stretch is running, the next launch starts one and observes no gap)
     _last_dispatch: Optional[_monitor.StepRecord] = None
+    _tiled_to: Optional[float] = None
 
     def forget_last_dispatch(self) -> None:
         """The next dispatch observes no ``executor_starved_seconds``: what
         lies between it and the one before is no wait of the device's for
         the host (a warm-up's end, generation state planted anew, a
         ``CompiledProgram`` step)."""
-        self._last_dispatch = None
+        self._last_dispatch = self._tiled_to = None
 
     def _launched(self, ph, step: _CompiledStep, mrec, cache_hit):
         """Right after the ``executor.step`` phase ``ph`` is entered: the
-        launch's clock reading and the fetch return of the dispatch before
-        go on the ``StepRecord`` (``executor_inflight_seconds``,
-        ``executor_starved_seconds``). Only while ``FLAGS_trace`` is on,
-        what ties the launch to a profile: the span says which dispatch it
-        is (the ``StepRecord``'s ``step_index``) and which module it
-        launched (the executable's name as a device trace prints it on
-        ``XLA Modules``), and the ``TraceAnnotation`` returned, of the
-        span's name, carries the same ``dispatch`` into the profile on the
-        profiler's clock (``trace.join_dispatches`` reads both)."""
+        launch's clock reading goes on the ``StepRecord``, and where
+        nothing of this executor's was in flight, the fetch return that
+        started the gap (``executor_starved_seconds``). A launch behind a
+        dispatch whose fetch is still to be taken (``FETCH_LATER``)
+        observes no gap: the device has that one's work. Only while
+        ``FLAGS_trace`` is on, what ties the launch to a profile: the span
+        says which dispatch it is (the ``StepRecord``'s ``step_index``) and
+        which module it launched (the executable's name as a device trace
+        prints it on ``XLA Modules``), and the ``TraceAnnotation``
+        returned, of the span's name, carries the same ``dispatch`` into
+        the profile on the profiler's clock (``trace.join_dispatches``
+        reads both)."""
         before, self._last_dispatch = self._last_dispatch, mrec
         ident = {}
         if mrec is not None:
             mrec.launch_t = ph.t0
-            mrec.prev_ready_t = before.ready_t if before is not None else None
+            if before is not None and before.ready_t is not None:
+                mrec.prev_ready_t = self._tiled_to
+                self._tiled_to = ph.t0
+            elif before is None or getattr(before, "_deferred", None) is None:
+                # none before it, or one that never fetched: a new stretch
+                self._tiled_to = ph.t0
             ident["dispatch"] = mrec.step_index
         if not ph.traced:
             return _NOT_TRACED
         ph.set_attributes(cache_hit=cache_hit,
                           module="jit_" + step.fn.__name__, **ident)
         return jax.profiler.TraceAnnotation("executor.step", **ident)
+
+    def _landed(self, mrec) -> None:
+        """A fetch returned: what of this executor's life lies before it
+        and is not told yet was in flight (``StepRecord.head_t`` to
+        ``ready_t``: the dispatch's whole wall where it ran alone, and
+        where dispatches overlap the part no earlier fetch return covers,
+        so the observations add up to the union of the walls)."""
+        mrec.head_t = mrec.launch_t if self._tiled_to is None \
+            else self._tiled_to
+        self._tiled_to = mrec.ready_t
+
+    # -- a fetch that is taken later --------------------------------------
+    def _fetched(self, fetches, mrec, how):
+        """The tail of ``run`` / ``run_chained`` where the caller wants
+        host values: now (the ``executor.fetch`` phase), or under
+        ``return_numpy=FETCH_LATER`` when it takes them."""
+        if how != FETCH_LATER:
+            return self._fetch_to_host(fetches, mrec)
+        return DeferredFetch(self, fetches, mrec, _trace.current_span())
+
+    def _step_end(self, mrec) -> None:
+        """``monitor.step_end`` for a dispatch that is over; one whose
+        fetch is still to be taken keeps its record open with the call's
+        wall on it, and ``DeferredFetch`` closes it."""
+        if mrec is not None and getattr(mrec, "_deferred", None) is not None:
+            mrec.duration_s = time.perf_counter() - mrec._t0
+        else:
+            _monitor.step_end(mrec)
+
+
+FETCH_LATER = "later"
+
+
+class DeferredFetch:
+    """What ``Executor.run`` / ``run_chained`` return under
+    ``return_numpy=FETCH_LATER``: the dispatch is launched, its state is in
+    the scope (as arrays the device is still computing), and its fetches
+    stay on the device until the caller takes them. The caller may launch
+    more in the meantime: the next program reads the state this one leaves,
+    and the device runs them in order without waiting for the host.
+
+    ``take()`` is the dispatch's ``executor.fetch`` phase, under the span of
+    the call that launched it: it blocks until the device is done, copies
+    the fetches back and closes the dispatch's ``StepRecord``
+    (``fetch_wait_s``, ``ready_t``; ``duration_s`` is the call's wall plus
+    this one's, the host's time between the two is not the dispatch's). An
+    error of the device's surfaces here. ``drop()`` closes the record
+    without fetching (the results are not wanted: a failure before it)."""
+
+    def __init__(self, exe: "Executor", fetches, mrec, span):
+        self._exe, self._fetches = exe, list(fetches)
+        self._mrec, self._span = mrec, span
+        if mrec is not None:
+            mrec._deferred = self       # open: Executor._launched reads it
+
+    def _close(self):
+        fetches, self._fetches = self._fetches, None
+        if fetches is None:
+            raise RuntimeError("DeferredFetch: taken or dropped before")
+        if self._mrec is not None:
+            self._mrec._deferred = None
+        return fetches
+
+    def take(self) -> List[np.ndarray]:
+        fetches, mrec = self._close(), self._mrec
+        try:
+            return self._exe._fetch_to_host(fetches, mrec, parent=self._span)
+        finally:
+            if mrec is not None:
+                mrec.duration_s += mrec.fetch_wait_s
+                _monitor.step_end(mrec)
+
+    def drop(self) -> None:
+        self._close()
+        _monitor.step_end(self._mrec)
 
 
 _NOT_TRACED = contextlib.nullcontext()

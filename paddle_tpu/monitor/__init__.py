@@ -211,18 +211,25 @@ def step_end(rec: Optional[StepRecord]) -> None:
                               device_kind=rec.device_kind)
     if rec.ready_t is not None and rec.launch_t is not None:
         # a dispatch that fetched. With the gap before it (to the fetch
-        # return of the dispatch before it on the same Executor) the two
-        # tile that executor's life from its first launch to its last
-        # fetch, exactly
+        # return before it on the same Executor) the two tile that
+        # executor's life from its first launch to its last fetch, exactly:
+        # where dispatches overlap (a fetch taken later, behind the next
+        # launch) each observes from the fetch return before its own, so
+        # the sum is the union of the walls, and a launch behind a
+        # dispatch still in flight observes no gap
+        head = rec.launch_t if rec.head_t is None else rec.head_t
         histogram("executor_inflight_seconds",
-                  "launch call to fetch return of one dispatch: the time "
-                  "the device had this executor's work in flight").labels(
-            **p).observe(rec.ready_t - rec.launch_t)
+                  "the time the device had this executor's work in flight: "
+                  "launch call to fetch return of one dispatch, less what "
+                  "an earlier dispatch's fetch return already covered "
+                  "(the sum is the union of the dispatches' walls)").labels(
+            **p).observe(rec.ready_t - head)
         if rec.prev_ready_t is not None:
             histogram("executor_starved_seconds",
                       "fetch return of the dispatch before to this "
-                      "dispatch's launch call: the time the device had "
-                      "nothing of this executor's in flight").labels(
+                      "dispatch's launch call, where nothing else was in "
+                      "flight: the time the device had nothing of this "
+                      "executor's in flight").labels(
                 **p).observe(rec.launch_t - rec.prev_ready_t)
     if rec.feed_bytes:
         counter("executor_feed_bytes_total",

@@ -45,11 +45,16 @@ class StepRecord:
     fetch_wait_s: float = 0.0        # of duration_s: host blocked on fetches
     # time.perf_counter() readings the phases made: entry of executor.step,
     # exit of executor.fetch (None: the dispatch raised or fetched nothing),
-    # and the ready_t of the launch before it on the same Executor (None:
-    # there was none, it did not fetch, or the executor was told to forget)
+    # and the fetch return before this launch on the same Executor (None:
+    # there was none, the dispatch before did not fetch, the executor was
+    # told to forget, or that dispatch's fetch was still to be taken: the
+    # device had work). head_t: from when this dispatch's time in flight is
+    # its own, its launch or, where it was launched behind others, the last
+    # fetch return before its own
     launch_t: Optional[float] = None
     ready_t: Optional[float] = None
     prev_ready_t: Optional[float] = None
+    head_t: Optional[float] = None
 
     def to_dict(self) -> dict:
         return dataclasses.asdict(self)

@@ -38,6 +38,35 @@ prefill), and such a model's prefill streams no token: its first tokens
 come with its first committed block. Every token metric, the per-token
 timing, the cache walk and the settle loop read the yield.
 
+**One dispatch ahead.** The dispatch thread launches a turn (its bucket
+prefills, then its decode chunk) while the decode chunk of the turn before
+is still running, and only then fetches and settles that one
+(``Executor.run*(return_numpy=FETCH_LATER)``): the device always has the
+next program queued behind the running one, and settle, schedule, admit,
+feed, bind and the runtime's launch and return latencies are off its
+critical path. Same programs, same order on the device, same state, same
+tokens. What the loop observes decides how far it looks ahead:
+
+* a request whose budget ends inside a launched dispatch (one token a
+  forward, so the host can count) leaves its slot at that launch: its gate
+  is cleared on the device before the next launch and the slot may be
+  seated again in the next turn. A stop only the tokens show (``eos_id``, a
+  deadline, a block model's yield) is found at the settle, one dispatch
+  late: that dispatch's tokens for the slot are dropped as the rest of a
+  chunk past a stop is (``serving_lookahead_dropped_tokens_total``);
+* a turn that needs fetched values before it can launch (a chunked prefill,
+  a speculative verify, a prefix-cache copy-in or publish) first settles
+  what is in flight and runs as the serial loop did: the drained case of
+  the same loop;
+* with slots free whose last answers are out and a queue that cannot fill
+  them, the thread waits for newcomers (``await_newcomers``) as long as the
+  chunk in flight leaves it: that chunk's expected end (the last decode
+  chunk's wall, from when it became the device's oldest) less twice what a
+  turn's launches took. So a caller that resubmits on completion is seated
+  in the turn after, as in the serial loop (``serving_seat_lag_turns``); it
+  comes within milliseconds, and the turn is then launched well before the
+  chunk ends.
+
 ISSUE 20 adds two composable phases on the same slot/bucket discipline:
 
 * **prefix reuse + chunked prefill** — admission first matches the
@@ -65,11 +94,14 @@ per token: they are re-checked before every prefill and after every
 decode chunk, so an expired stream stops within ``decode_chunk`` forwards.
 
 Failure isolation: an injected ``batch_dispatch`` fault (the chaos gate's
-kill-one-batch leg) fails exactly the streams in that dispatch, typed
-``BatchFailed``, and the engine keeps serving. A REAL executor failure
-mid-dispatch may have consumed donated state buffers, so it additionally
-fails every resident stream typed and resets the generation state —
-never a silent wrong-token continuation.
+kill-one-batch leg) fires before any launch: what is in flight is settled,
+then exactly the streams of that dispatch fail, typed ``BatchFailed``, and
+the engine keeps serving. A REAL executor failure (at a launch, or at a
+fetch taken later, with a successor already launched on the state the
+failed dispatch produced) may have consumed donated state buffers, so it
+fails every resident stream typed, those of every dispatch in flight too,
+drops their results and resets the generation state once — never a silent
+wrong-token continuation.
 """
 from __future__ import annotations
 
@@ -77,13 +109,14 @@ import dataclasses
 import logging
 import time
 from collections import Counter, defaultdict
-from typing import Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
 from .. import monitor as _monitor
 from .. import trace as _trace
 from ..core.types import np_dtype
+from ..executor import FETCH_LATER
 from ..resilience import faults as _faults
 from ..resilience.deadline import Deadline, DeadlineExceeded
 from .engine import (DEFAULT_TENANT, BatchFailed, EngineStopped,
@@ -94,8 +127,9 @@ __all__ = ["GenerationConfig", "GenerativeEngine"]
 logger = logging.getLogger("paddle_tpu.serving")
 
 _LOOP_HELP = ("wall of one phase of the generative dispatch thread's loop "
-              "(idle_wait, schedule, admit, feed, settle, publish); with "
-              "the executor's dispatches they tile the thread's time")
+              "(idle_wait, await_newcomers, schedule, admit, feed, settle, "
+              "publish); with the executor's dispatches they tile the "
+              "thread's time")
 
 
 def _var_names(var) -> List[str]:
@@ -207,6 +241,26 @@ class _GenRequest(_Request):
     prefilled: bool = False        # decode-eligible (prefill complete)
     prefix_rows: int = 0           # KV rows copied in from the prefix cache
     next_off: int = 0              # next prompt offset to prefill
+    # tokens the dispatches launched and not yet settled will yield it, by
+    # the host's count (a token a forward; 0 where only the tokens say)
+    ahead: int = 0
+
+
+@dataclasses.dataclass
+class _Launched:
+    """One dispatch the device has and the host has not fetched: what
+    ``_settle_inflight`` needs to settle it as if it had just returned."""
+
+    phase: str                     # "prefill" | "decode"
+    reqs: List[_GenRequest]        # the requests it carries (a prefill's
+    # in row order), resident when it was launched
+    pending: Any                   # executor.DeferredFetch
+    span: Any                      # its root span, ended at its settle
+    t0: float                      # perf_counter() before the launch call
+    index: int                     # decode dispatches launched up to and
+    # with it: what a slot's ``serving_seat_lag_turns`` counts from
+    bucket: int = 0                # a prefill's
+    ahead: int = 0                 # tokens a request, counted at launch
 
 
 class GenerativeEngine(ServingEngine):
@@ -316,6 +370,28 @@ class GenerativeEngine(ServingEngine):
         # tests and for a real draft model.
         self.draft_fn = None
         self._moe_local = self._moe_made = 0
+        # -- one dispatch ahead (module docstring) -------------------------
+        # launched and not fetched, oldest first (dispatch thread only; a
+        # list so that another thread may copy it)
+        self._inflight: List[_Launched] = []
+        # this turn may leave its dispatches unfetched (False: every launch
+        # is settled at once, the serial order)
+        self._ahead = False
+        # the host can count a request's tokens before they exist: a token
+        # a forward, no verify round that accepts as many as it likes
+        self._counts_ahead = not self._block and not self._speculative
+        self._gates: set = set()   # vacated slots whose gate is still open
+        # slot -> the last request that left it at a launch (its budget
+        # counted out): until its last dispatch is settled its caller
+        # cannot know, so nobody is awaited for that slot
+        self._leaving: Dict[int, _GenRequest] = {}
+        self._vacated: Dict[int, int] = {}   # slot -> ``index`` it ended in
+        self._decode_launches = 0
+        self._cursor: Optional[int] = None   # ``index`` being settled
+        self._head_t = 0.0         # the last fetch return (perf_counter)
+        self._decode_wall: Optional[float] = None   # last decode chunk's
+        self._launch_cost = 0.0    # schedule to decode launch, last turns
+        self._clear_gates = None   # jitted gate update, built at first use
         self.prefill_chunks = 0    # chunk slices dispatched (per request)
         self.spec_chunks = 0       # verify dispatches
         self.spec_accepted = 0     # draft tokens accepted in total
@@ -391,6 +467,10 @@ class GenerativeEngine(ServingEngine):
                               scope=self._scope)
         self._note_compiles("decode", len(self._slots), self._program)
         compiled += 1
+        # the gate's small update, on the array a dispatch leaves: no
+        # window compiles it
+        self._gates.add(0)
+        self._flush_gates()
         if self._use_chunked():
             net = self._chunk
             self._exe.run(net["main"], feed=self._chunk_feed([]),
@@ -497,23 +577,31 @@ class GenerativeEngine(ServingEngine):
 
     # -- scheduler -------------------------------------------------------
     def _idle_locked(self) -> bool:
-        return (self._running and not self._queue
+        return (self._running and not self._queue and not self._inflight
                 and not any(r is not None for r in self._slots))
 
     def _dispatch_forever(self) -> None:
         # Every instant of this thread lies in one leaf span (and, always
         # on, in one serving_loop_seconds phase or one executor dispatch):
-        # idle_wait | schedule | admit | <phase root>{feed, executor.*} |
-        # publish | settle. Keep new work inside one of them.
+        # idle_wait | await_newcomers | schedule | admit | <phase root>{feed,
+        # executor.*} | publish | settle. A phase root (serving.prefill,
+        # serving.decode) runs from its launch to its settle, so under the
+        # lookahead two of them overlap; their leaves never do: a
+        # dispatch's executor.fetch is taken later, under its own root.
+        # Keep new work inside one of the leaves.
         self._current_batch = []
         while True:
             with self._lock:
                 if self._idle_locked():
                     with _loop_phase("idle_wait"):
+                        # no launch is coming that would clear them
+                        self._flush_gates()
                         while self._idle_locked():
                             self._work.wait(timeout=0.05)
                             self._sweep_expired_locked(self._now())
                             self._update_pressure_locked(self._now())
+                self._await_newcomers_locked()
+                t_turn = time.perf_counter()
                 with _loop_phase("schedule") as ph:
                     active = [r for r in self._slots if r is not None]
                     stopping = not self._running and (
@@ -533,6 +621,9 @@ class GenerativeEngine(ServingEngine):
                                 resident=len(active) + len(newcomers),
                                 newcomers=len(newcomers))
             if stopping:
+                # what the device still has is settled first: a request
+                # that ends in it completes, the others keep its tokens
+                self._settle_inflight()
                 for r in leftovers + active:
                     if not r.future.done():
                         self._settle_error(
@@ -542,9 +633,16 @@ class GenerativeEngine(ServingEngine):
                             dispatched=(r in active))
                 self._current_batch = []
                 return
-            # the crash guard settles every RESIDENT request, not just the
-            # ones inside one dispatch
-            self._current_batch = [r for r in self._slots if r is not None]
+            # the crash guard settles every request the engine holds, not
+            # just those of one dispatch; after the schedule requests only
+            # leave, so once a turn
+            self._guard_residents()
+            # a turn that needs fetched values before it can launch settles
+            # what is in flight and runs in the serial order
+            self._ahead = self._may_look_ahead(newcomers)
+            if not self._ahead:
+                self._settle_inflight()
+            launched = self._decode_launches
             if newcomers:
                 with _loop_phase("admit") as ph:
                     bucketed = self._admit_newcomers(newcomers)
@@ -553,27 +651,98 @@ class GenerativeEngine(ServingEngine):
                             hits=sum(1 for r in newcomers if r.prefix_rows),
                             rows=sum(r.prefix_rows for r in newcomers))
                 self._run_prefill(bucketed)
-                self._current_batch = [r for r in self._slots
-                                       if r is not None]
             # one chunk slice per pending chunked request per iteration,
             # INTERLEAVED with the resident decode chunk below — a long
             # cold prompt never stalls the decoders
             if any(r is not None and r.chunked and not r.prefilled
                    for r in self._slots):
                 self._run_chunk_slices()
-                self._current_batch = [r for r in self._slots
-                                       if r is not None]
             if any(r is not None and r.prefilled for r in self._slots):
                 if not (self._speculative and self._run_spec_chunk()):
                     self._run_decode_chunk()
-                self._current_batch = [r for r in self._slots
-                                       if r is not None]
+            if self._decode_launches > launched:
+                # a decaying high-water mark: a turn with a prefill costs
+                # more than one without
+                self._launch_cost = max(time.perf_counter() - t_turn,
+                                        0.5 * self._launch_cost)
+            # the device has this turn's work: fetch and settle the turn
+            # before it, and this turn's prefills (a newcomer's first token
+            # goes out when its prefill ends, before the chunk behind it)
+            self._settle_inflight(keep=(
+                self._inflight[-1] if self._inflight
+                and self._decode_launches > launched else None))
+            self._ahead = False
+
+    def _held(self, also=()) -> List[_GenRequest]:
+        """The requests being served: in a slot, or out of it already (the
+        budget counted out at a launch) with the last dispatch in flight
+        (``also``: dispatches no longer on the list). Any thread."""
+        held = {id(r): r for r in self._slots if r is not None}
+        for e in list(self._inflight) + list(also):
+            held.update((id(r), r) for r in e.reqs if not r.future.done())
+        return list(held.values())
+
+    def _guard_residents(self) -> None:
+        self._current_batch = self._held()
+
+    def _may_look_ahead(self, newcomers: Sequence[_GenRequest]) -> bool:
+        """Whether this turn's launches can be made behind a dispatch still
+        in flight, read off the residents: a verify round drafts from the
+        tokens before it, a chunk slice and a prefix-cache copy-in or
+        publish pull buffers through the host."""
+        if self._speculative:
+            return False
+        pc = self._prefix_cache
+        if pc is not None and any(pc.pages_of(len(r.prompt))
+                                  for r in newcomers):
+            # a prompt with a whole page is copied in (a hit) or published
+            # (a miss); one without is neither
+            return False
+        return not any(r is not None and r.chunked and not r.prefilled
+                       for r in self._slots)
+
+    def _await_newcomers_locked(self) -> None:
+        """Between the settle of a chunk and the next turn's launches, under
+        ``_lock``: while slots are free whose last answer is out and the
+        queue cannot fill them, wait for ``submit`` (which notifies
+        ``_work``) as long as the chunk in flight leaves: its expected end
+        (the last decode chunk's wall, counted from the fetch return that
+        made it the device's oldest) less twice what a turn's launches last
+        took. A caller that resubmits when its request completes is then
+        seated in this turn and not the one after. With a queue, or nothing
+        in flight, the wait is never entered."""
+        if not self._inflight or self._decode_wall is None:
+            return
+        until = self._head_t + self._decode_wall - 2.0 * self._launch_cost
+
+        def short() -> float:
+            # free slots whose last request's outcome is out: a slot whose
+            # request left it at a launch and is still in flight has no
+            # caller who could know
+            free = sum(r is None and (j not in self._leaving
+                                      or self._leaving[j].future.done())
+                       for j, r in enumerate(self._slots))
+            if not self._running or len(self._queue) >= free:
+                return 0.0
+            return until - time.perf_counter()
+
+        if short() <= 0:
+            return
+        with _loop_phase("await_newcomers"):
+            left = short()
+            while left > 0:
+                self._work.wait(timeout=left)
+                left = short()
 
     def _refill_locked(self) -> List[_GenRequest]:
         """Assign queued requests to free slots (FIFO). Runs under
         ``_lock``; the assigned requests count as dispatched from here on
         (the accounting's in-flight arm)."""
-        free = [j for j, r in enumerate(self._slots) if r is None]
+        # the slot that has stood empty longest first (never vacated, then
+        # by the dispatch its request ended in): where a caller returns for
+        # every slot, each slot waits its one turn and none waits two
+        free = sorted((j for j, r in enumerate(self._slots) if r is None),
+                      key=lambda j: (self._vacated.get(j, -1), j))
         taken: List[_GenRequest] = []
         while free and self._queue:
             r = self._queue.pop(0)
@@ -581,6 +750,16 @@ class GenerativeEngine(ServingEngine):
             self._slots[r.slot] = r
             self._dispatched += 1
             taken.append(r)
+            ended = self._vacated.pop(r.slot, None)
+            if ended is not None and _monitor.enabled():
+                _monitor.histogram(
+                    "serving_seat_lag_turns",
+                    "decode dispatches launched after the one a slot's "
+                    "request ended in and before the slot is seated again: "
+                    "the turns the slot stood empty (0 with a queue, 1 "
+                    "where a caller resubmits on completion)",
+                    buckets=(0.0, 1.0, 2.0, 3.0, 4.0, 8.0)).observe(
+                    float(self._decode_launches - ended))
         if taken:
             self._gauge_depth_locked()
         return taken
@@ -668,16 +847,31 @@ class GenerativeEngine(ServingEngine):
                     int(getattr(self._scope.find_var(n), "nbytes", 0))
                     for pair in self._cache_names for n in pair))
 
-    def _deactivate_slot(self, slot: int) -> None:
-        """Host-side decode-gate clear on retire: the slot's ``active``
-        flag goes 0 so later decode/verify dispatches leave its state and
-        cache rows untouched until the next admission re-arms it."""
+    def _flush_gates(self) -> None:
+        """Clear the decode gate (the model's ``active_var``) of every slot
+        vacated since the last launch, on the device and in one update:
+        later decode / verify dispatches leave those slots' state and cache
+        rows untouched until the next admission re-arms them. Nothing comes
+        to the host (the gate may be the future result of a dispatch still
+        in flight, and reading it would wait that dispatch out); the update
+        queues behind the dispatches that still needed the gate open.
+        Called before every launch, under its ``feed`` phase, and when the
+        engine goes idle."""
+        if not self._gates:
+            return
+        slots, self._gates = sorted(self._gates), set()
         cur = self._scope.find_var(self._active_var)
         if cur is None:
             return
-        arr = np.array(cur)
-        arr[slot, 0] = 0.0
-        self._scope.set_var(self._active_var, arr)
+        import jax
+
+        if self._clear_gates is None:
+            self._clear_gates = jax.jit(lambda gate, keep: gate * keep)
+        keep = np.ones(cur.shape, cur.dtype)
+        keep[slots, 0] = 0
+        with jax.default_device(self._exe.place.jax_device()):
+            self._scope.set_var(self._active_var,
+                                self._clear_gates(cur, keep))
 
     # -- chunked prefill -------------------------------------------------
     def _chunk_feed(self, pending: Sequence[_GenRequest]) -> dict:
@@ -728,6 +922,7 @@ class GenerativeEngine(ServingEngine):
             _faults.fault_point("batch_dispatch")
             with _loop_phase("feed", parent=span):
                 feed = self._chunk_feed(live)
+                self._flush_gates()
             t0 = time.perf_counter()
             with _trace.attach(span):
                 outs = self._exe.run(net["main"], feed=feed,
@@ -846,6 +1041,7 @@ class GenerativeEngine(ServingEngine):
             _faults.fault_point("batch_dispatch")
             with _loop_phase("feed", parent=span):
                 feed = self._verify_feed(active)
+                self._flush_gates()
             t0 = time.perf_counter()
             with _trace.attach(span):
                 outs = self._exe.run(
@@ -949,7 +1145,6 @@ class GenerativeEngine(ServingEngine):
             groups += [(bucket, reqs[i:i + n])
                        for i in range(0, len(reqs), n)]
         for bucket, reqs in groups:
-            by_row = self._prefill_rows(bucket) is not None
             net = self._model["prefill"][bucket]
             span = _trace.NOOP_SPAN
             if _trace.enabled():
@@ -964,17 +1159,20 @@ class GenerativeEngine(ServingEngine):
                 _faults.fault_point("batch_dispatch")
                 with _loop_phase("feed", parent=span):
                     feed = self._prefill_feed(bucket, reqs)
+                    self._flush_gates()
                 t0 = time.perf_counter()
                 with _trace.attach(span):
-                    outs = self._exe.run(
+                    pending = self._exe.run(
                         net["main"], feed=feed,
                         fetch_list=self._prefill_fetches(bucket),
-                        scope=self._scope)
-                dt = time.perf_counter() - t0
+                        scope=self._scope, return_numpy=FETCH_LATER)
             except _faults.InjectedFault as e:
-                # fired before any dispatch: state intact, only this
-                # group fails (typed) — the engine keeps serving
+                # fired before any dispatch: state intact. What is in
+                # flight is sound and is settled as if this turn had not
+                # begun; then only this group fails (typed) — the engine
+                # keeps serving
                 span.end(error=e)
+                self._settle_inflight()
                 self._fail_group(reqs, e, phase="prefill")
                 continue
             except Exception as e:
@@ -983,38 +1181,118 @@ class GenerativeEngine(ServingEngine):
                 span.end(error=e)
                 self._fail_all_resident(e, phase="prefill")
                 return
-            span.end()
-            self._publish(reqs)
-            with _loop_phase("settle") as ph:
-                self._note_compiles("prefill", bucket, net["main"])
-                self._observe_stats("prefill", ("prefill", bucket),
-                                    outs[0 if self._block else 1:])
-                # a prefill by blocks seats a prompt's whole blocks; what
-                # is left over opens the slot's first decode block
-                whole = self._block or 1
-                self._count_prefill_tokens(
-                    sum(len(r.prompt) // whole * whole for r in reqs),
-                    (self._prefill_rows(bucket) or len(self._slots))
-                    * bucket)
-                if _monitor.enabled():
-                    _monitor.histogram(
-                        "serving_prefill_seconds",
-                        "wall time of one slot-masked prefill dispatch"
-                    ).observe(dt)
-                first = None if self._block \
-                    else np.asarray(outs[0]).reshape(-1)
-                tokens = 0
-                for i, r in enumerate(reqs):
-                    r.prefilled = True
-                    r.next_off = len(r.prompt)
-                    if self._expired(r) or first is None:
-                        continue
-                    # the first token's cost is the FIRST-TOKEN histogram's
-                    # story — it must not pollute the inter-token latency
-                    tokens += 1
-                    self._emit(r, [int(first[i if by_row else r.slot])], dt,
-                               record_intertoken=False)
-                self._settled(ph, reqs, tokens)
+            for r in reqs:
+                r.prefilled = True
+                r.next_off = len(r.prompt)
+            # a prefill by blocks streams no token: its first come with
+            # the slot's first committed block
+            self._launched(_Launched(
+                "prefill", reqs, pending, span, t0, self._decode_launches,
+                bucket=bucket), 0 if self._block else 1)
+
+    def _settle_prefill(self, e: _Launched, outs, dt: float) -> None:
+        bucket, reqs = e.bucket, e.reqs
+        by_row = self._prefill_rows(bucket) is not None
+        self._publish(reqs)
+        with _loop_phase("settle") as ph:
+            self._note_compiles("prefill", bucket,
+                                self._model["prefill"][bucket]["main"])
+            self._observe_stats("prefill", ("prefill", bucket),
+                                outs[0 if self._block else 1:])
+            # a prefill by blocks seats a prompt's whole blocks; what
+            # is left over opens the slot's first decode block
+            whole = self._block or 1
+            self._count_prefill_tokens(
+                sum(len(r.prompt) // whole * whole for r in reqs),
+                (self._prefill_rows(bucket) or len(self._slots))
+                * bucket)
+            if _monitor.enabled():
+                _monitor.histogram(
+                    "serving_prefill_seconds",
+                    "wall time of one slot-masked prefill dispatch (where "
+                    "it was launched behind another, from that one's "
+                    "fetch return)").observe(dt)
+            first = None if self._block \
+                else np.asarray(outs[0]).reshape(-1)
+            tokens = 0
+            for i, r in enumerate(reqs):
+                if r.future.done() or self._expired(r) or first is None:
+                    continue
+                # the first token's cost is the FIRST-TOKEN histogram's
+                # story — it must not pollute the inter-token latency
+                tokens += 1
+                self._emit(r, [int(first[i if by_row else r.slot])], dt,
+                           record_intertoken=False)
+            self._settled(ph, reqs, tokens)
+
+    # -- one dispatch ahead ------------------------------------------------
+    def _launched(self, e: _Launched, ahead: int) -> None:
+        """``e`` is on the device. Counted: whether it was queued behind a
+        dispatch still running; and, where the host can count a request's
+        tokens before they exist, who ends inside it: such a request leaves
+        its slot now (its gate is cleared before the next launch, the slot
+        can be seated in the next turn), so a length stop runs no forward
+        the serial loop did not run. ``ahead``: tokens ``e`` yields each of
+        its requests. Settled at once unless this turn looks ahead."""
+        if _monitor.enabled():
+            _monitor.counter(
+                "serving_launches_total",
+                "prefill and decode dispatches launched, by whether this "
+                "engine had a dispatch in flight then (queued_behind="
+                "running: the device goes from that one to this without "
+                "waiting for the host) or none (idle)").labels(
+                phase=e.phase, queued_behind="running" if self._inflight
+                else "idle").inc()
+        self._inflight.append(e)
+        if self._counts_ahead:
+            e.ahead = ahead
+            for r in e.reqs:
+                r.ahead += ahead
+                if r.emitted + r.ahead >= r.max_new \
+                        and self._slots[r.slot] is r:
+                    self._retire(r, ended=e.index)
+                    self._leaving[r.slot] = r
+        if not self._ahead:
+            self._settle_inflight()
+
+    def _settle_inflight(self, keep: Optional[_Launched] = None) -> None:
+        """Fetch and settle, oldest first, every dispatch in flight up to
+        ``keep`` (the chunk the device is left with). Deadlines are judged
+        here, a failure of the device's surfaces here."""
+        while self._inflight and self._inflight[0] is not keep:
+            e = self._inflight.pop(0)
+            asked = time.perf_counter()
+            try:
+                outs = e.pending.take()
+            except Exception as err:
+                e.span.end(error=err)
+                self._fail_all_resident(err, phase=e.phase, failed=e)
+                return
+            # the dispatch's own part of the device's time: from its launch
+            # or, where it was queued behind another, that one's return
+            now = time.perf_counter()
+            dt, self._head_t = now - max(e.t0, self._head_t), now
+            e.span.end()
+            for r in e.reqs:
+                r.ahead -= e.ahead
+            self._cursor = e.index
+            try:
+                if e.phase == "prefill":
+                    self._settle_prefill(e, outs, dt)
+                else:
+                    # what the wait for newcomers reckons a chunk's end
+                    # from: the device's time only where the fetch had to
+                    # wait for it. Where the results lay ready the thread
+                    # came late by an unknown time that ``dt`` holds too;
+                    # taking it would move the next wait's end out by as
+                    # much, and the lateness would feed itself
+                    if now - asked > 1e-3 or self._decode_wall is None:
+                        self._decode_wall = dt
+                    else:
+                        self._decode_wall = min(self._decode_wall, dt)
+                    self._settle_decode(e, outs, dt)
+            finally:
+                self._cursor = None
 
     # -- decode ----------------------------------------------------------
     def _run_decode_chunk(self) -> None:
@@ -1031,37 +1309,66 @@ class GenerativeEngine(ServingEngine):
                 request_traces=",".join(r.span.trace_id for r in active))
         try:
             _faults.fault_point("batch_dispatch")
+            if self._gates:
+                with _loop_phase("feed", parent=span):
+                    self._flush_gates()
             t0 = time.perf_counter()
             with _trace.attach(span):
-                outs = self._exe.run_chained(
+                pending = self._exe.run_chained(
                     self._program, feed={},
                     fetch_list=self._decode_fetches(),
-                    steps=steps, scope=self._scope)
-            dt = time.perf_counter() - t0
+                    steps=steps, scope=self._scope,
+                    return_numpy=FETCH_LATER)
         except _faults.InjectedFault as e:
-            # the chaos gate's kill-one-batch: every stream in THIS batch
-            # settles typed; state untouched (the fault fires before the
-            # dispatch), freed slots are re-prefilled next iteration
+            # the chaos gate's kill-one-batch: what is in flight is settled
+            # first (the fault fires before the dispatch, the state is
+            # untouched), then every stream in THIS batch that is still
+            # open settles typed; freed slots are re-prefilled next
+            # iteration
             span.end(error=e)
-            self._fail_group(active, e, phase="decode")
+            self._settle_inflight()
+            self._fail_group([r for r in active if not r.future.done()], e,
+                             phase="decode")
             return
         except Exception as e:
             span.end(error=e)
             self._fail_all_resident(e, phase="decode")
             return
-        span.end()
+        self._decode_launches += 1
+        self._launched(_Launched("decode", active, pending, span, t0,
+                                 self._decode_launches), steps)
+
+    def _settle_decode(self, e: _Launched, outs, dt: float) -> None:
+        steps = self.gen_config.decode_chunk
         with _loop_phase("settle") as ph:
             self._note_compiles("decode", len(self._slots), self._program)
             n = len(self._fetch_names)
             self._observe_stats("decode", "decode", outs[n:])
             out = _Yield(outs[:n], steps, len(self._slots), self._block)
+            # a request that ended before this dispatch was settled (a
+            # stop only its tokens showed, a deadline) ran it for nothing:
+            # what it yielded is dropped like the rest of a chunk past a
+            # stop
+            active = [r for r in e.reqs if not r.future.done()]
+            late = sum(int(out.counts[:, r.slot].sum())
+                       for r in e.reqs if r.future.done())
             self._observe_walk(active, steps, out)
             if _monitor.enabled():
                 _monitor.histogram(
                     "serving_decode_chunk_seconds",
                     "wall time of one chained decode dispatch (its "
                     "forwards yield what the decode net says: a token a "
-                    "slot each, or a block's when it commits)").observe(dt)
+                    "slot each, or a block's when it commits); where it "
+                    "was launched behind another, from that one's fetch "
+                    "return").observe(dt)
+                if late:
+                    _monitor.counter(
+                        "serving_lookahead_dropped_tokens_total",
+                        "tokens a dispatch yielded a slot whose request "
+                        "had ended when the dispatch was launched, as "
+                        "only the settle before showed (a stop token, a "
+                        "deadline, a block model's yield): the one "
+                        "dispatch by which such a stop is late").inc(late)
             tokens, theirs = 0, []
             for r in active:
                 # mid-stream expiry: the typed outcome is the LAST word —
@@ -1258,10 +1565,19 @@ class GenerativeEngine(ServingEngine):
             r.future._settle(
                 result=[np.asarray(r.out_tokens, dtype=np.int64)])
 
-    def _retire(self, r: _GenRequest) -> None:
+    def _retire(self, r: _GenRequest, ended: Optional[int] = None) -> None:
+        """``r`` leaves its slot (if it still holds it: a request whose
+        budget was counted out at a launch left then, and the slot may be
+        another's by now). The slot's gate is cleared on the device before
+        the next launch. ``ended``: the ``index`` of the dispatch it ended
+        in (default: the one being settled, else the last launched)."""
         if 0 <= r.slot < len(self._slots) and self._slots[r.slot] is r:
             self._slots[r.slot] = None
-            self._deactivate_slot(r.slot)
+            self._gates.add(r.slot)
+            if ended is None:
+                ended = self._decode_launches if self._cursor is None \
+                    else self._cursor
+            self._vacated[r.slot] = ended
 
     def _fail_group(self, reqs: List[_GenRequest], err: BaseException,
                     phase: str) -> None:
@@ -1285,14 +1601,26 @@ class GenerativeEngine(ServingEngine):
             context=reqs[0].span if reqs else None,
             detail=f"generative {phase}, {len(reqs)} stream(s)")
 
-    def _fail_all_resident(self, err: BaseException, phase: str) -> None:
-        resident = [r for r in self._slots if r is not None]
+    def _fail_all_resident(self, err: BaseException, phase: str,
+                           failed: Optional[_Launched] = None) -> None:
+        """A real failure, at a launch or at the fetch of ``failed``: every
+        request the engine holds fails typed, once: the residents and those
+        of every dispatch in flight (the successors ran on state the failed
+        one produced), whose results are dropped unfetched; then the
+        generation state is planted anew, once."""
+        dropped, self._inflight = self._inflight, []
+        for e in dropped:
+            e.pending.drop()
+            e.span.end(error=err)
+        resident = self._held(
+            also=dropped + ([failed] if failed is not None else []))
         logger.error(
             "serving: %s dispatch raised %s — generation state may hold "
             "consumed buffers; failing all %d resident stream(s) typed "
             "and resetting the generation state",
             phase, type(err).__name__, len(resident))
         self._fail_group(resident, err, phase)
+        self._gates.clear()         # the state planted below has none open
         self.reset_generation_state()
 
     # -- observability ---------------------------------------------------
@@ -1476,7 +1804,7 @@ class GenerativeEngine(ServingEngine):
         """Decode-side snapshot for reports: resident slots, compiled
         (phase, bucket) executables, recompiles, prefix-cache and
         speculative-decoding counters."""
-        resident = [r.seq for r in self._slots if r is not None]
+        resident = sorted(r.seq for r in self._held())
         pc = self._prefix_cache
         return {
             "slots": len(self._slots),

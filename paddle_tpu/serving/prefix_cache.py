@@ -75,11 +75,17 @@ class PrefixCache:
         self.evictions = 0
 
     # -- hashing ---------------------------------------------------------
+    def pages_of(self, prompt_len: int) -> int:
+        """Whole pages a prompt of ``prompt_len`` tokens can share: those
+        of ``prompt[:-1]`` (the last token is never cached — it must
+        produce the first logits). A prompt with none neither matches nor
+        publishes anything."""
+        return (int(prompt_len) - 1) // self.page_size
+
     def _chain(self, prompt: np.ndarray) -> List[bytes]:
-        """Chain hashes of every whole page of ``prompt[:-1]`` (the last
-        token is never cached — it must produce the first logits)."""
+        """Chain hashes of every whole page of ``prompt[:-1]``."""
         P = self.page_size
-        n = (int(prompt.shape[0]) - 1) // P
+        n = self.pages_of(prompt.shape[0])
         hashes, h = [], b""
         for i in range(n):
             page = np.ascontiguousarray(

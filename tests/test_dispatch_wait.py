@@ -97,6 +97,124 @@ def test_inflight_and_starved_tile_an_executors_life(records, path):
         assert before.ready_t <= r.launch_t <= r.ready_t
 
 
+def _union(intervals):
+    total, end = 0.0, float("-inf")
+    for lo, hi in sorted(intervals):
+        total += max(0.0, hi - max(lo, end))
+        end = max(end, hi)
+    return total
+
+
+# the order of launches (L) and fetches taken later (T) of dispatches 0..n:
+# each launched behind the one before it and fetched under its successor
+# (the generative engine's order); two in flight behind a third; a serial
+# dispatch between overlapped ones
+OVERLAPS = {
+    "one_ahead": "L0 L1 T0 L2 T1 L3 T2 T3",
+    "two_ahead": "L0 L1 L2 T0 T1 L3 T2 T3",
+    "drained_between": "L0 L1 T0 T1 L2 T2 L3 L4 T3 T4",
+    "taken_out_of_order": "L0 L1 T1 T0 L2 T2",
+}
+
+
+@pytest.mark.parametrize("order", sorted(OVERLAPS))
+@pytest.mark.parametrize("path", ["run", "chained"])
+def test_overlapped_dispatches_tile_the_life_by_their_union(records, path,
+                                                            order):
+    """Fetches taken later (``FETCH_LATER``), with the next dispatch
+    launched in between: in flight sums to the union of the launch-to-ready
+    intervals, starved to the time with none open, never negative, and the
+    two tile first launch to last fetch return exactly."""
+    exe, main, dispatch = _session()
+    dispatch(path)                                      # compiles
+    exe.forget_last_dispatch()                          # the life starts
+    monitor.reset()
+    pending, serial = {}, []
+    for i, move in enumerate(OVERLAPS[order].split()):
+        time.sleep(0.001 * (i % 3))
+        if move[0] == "L":
+            pending[move[1:]] = dispatch(path, return_numpy=fluid.FETCH_LATER)
+        else:
+            out = pending.pop(move[1:]).take()
+            assert isinstance(out[0], np.ndarray)
+    time.sleep(0.002)
+    serial.append(dispatch(path))                       # and a plain one
+    mine = [r for r in records if r.program_serial == main._serial][1:]
+    assert len(mine) == OVERLAPS[order].count("L") + 1
+    assert all(r.ready_t is not None and r.head_t <= r.ready_t
+               for r in mine)
+    # its own from its launch on, or from the fetch return before its own;
+    # taken before an older one, it tells that one's time too
+    assert all(r.launch_t <= r.head_t for r in mine) \
+        == (order != "taken_out_of_order")
+    (n_in, s_in), (n_st, s_st) = _sums(path)
+    life = max(r.ready_t for r in mine) - min(r.launch_t for r in mine)
+    union = _union([(r.launch_t, r.ready_t) for r in mine])
+    assert n_in == len(mine)
+    assert abs(s_in - union) < 1e-6
+    assert abs(s_in + s_st - life) < 1e-6
+    assert s_st >= 0.002 and all(
+        r.launch_t >= r.prev_ready_t for r in mine
+        if r.prev_ready_t is not None)
+    # a launch behind a dispatch still in flight observes no gap
+    gaps = sum(r.prev_ready_t is not None for r in mine)
+    assert n_st == gaps < len(mine)
+    # the record of a fetch taken later: the call's wall and the fetch's
+    for r in mine:
+        assert r.duration_s >= r.fetch_wait_s > 0
+    step, host, wait = (monitor.metric_value(n, path=path) for n in (
+        "executor_step_seconds", "executor_host_seconds",
+        "executor_fetch_wait_seconds"))
+    assert step["count"] == host["count"] == wait["count"] == len(mine)
+    assert host["sum"] + wait["sum"] == pytest.approx(step["sum"], rel=1e-9)
+
+
+@pytest.mark.parametrize("path", ["run", "chained"])
+def test_a_fetch_taken_later_is_taken_once_or_dropped(records, path):
+    exe, main, dispatch = _session()
+    dispatch(path)
+    exe.forget_last_dispatch()
+    monitor.reset()
+    a = dispatch(path, return_numpy=fluid.FETCH_LATER)
+    b = dispatch(path, return_numpy=fluid.FETCH_LATER)
+    n = len(records)
+    b.drop()                    # closes its record, observes no time
+    assert len(records) == n + 1 and records[-1].ready_t is None
+    a.take()
+    assert records[-1].ready_t is not None
+    for gone in (a, b):
+        with pytest.raises(RuntimeError, match="taken or dropped"):
+            gone.take()
+    (n_in, _), (n_st, _) = _sums(path)
+    assert (n_in, n_st) == (1, 0)
+    assert monitor.metric_value("executor_steps_total", path=path) == 2
+
+
+def test_the_deferred_fetch_span_joins_its_launch(records):
+    """Traced: the ``executor.fetch`` of a fetch taken later is a child of
+    the call that launched it, so ``dispatches_of`` still pairs launch and
+    fetch return, with another dispatch's spans in between."""
+    _, main, dispatch = _session()
+    dispatch("chained")
+    fluid.set_flags({"FLAGS_trace": 1})
+    trace.clear()
+    try:
+        a = dispatch("chained", return_numpy=fluid.FETCH_LATER)
+        b = dispatch("chained", return_numpy=fluid.FETCH_LATER)
+        a.take()
+        b.take()
+        spans = trace.spans()
+    finally:
+        fluid.set_flags({"FLAGS_trace": 0})
+        trace.clear()
+    got = dispatch_join.dispatches_of(spans)
+    assert [d["dispatch"] for d in got] \
+        == [r.step_index for r in records[-2:]]
+    for d, r in zip(got, records[-2:]):
+        assert d["launch_t"] == r.launch_t and d["ready_t"] == r.ready_t
+    assert got[1]["launch_t"] < got[0]["ready_t"] < got[1]["ready_t"]
+
+
 @pytest.mark.parametrize("path", ["run", "chained"])
 def test_a_dispatch_that_does_not_fetch_observes_neither(records, path):
     exe, main, dispatch = _session()

@@ -138,3 +138,54 @@ def test_a_block_at_a_time():
     assert (list(take), list(when), forwards, dropped) == ([], [], 5, 0)
     assert list(out.moved(0)) == [0, 0, 4, 4, 4]
     assert list(out.moved(1)) == [0] * 5
+
+
+@pytest.mark.parametrize("chunk", [2, 3])
+def test_a_block_models_stop_is_found_one_dispatch_late(chunk):
+    """What a block model yields cannot be counted before it exists, so
+    under the lookahead every request is found done at the settle, with the
+    next dispatch already launched: its answer is the reference loop's all
+    the same, the tokens that dispatch yielded its slot are dropped and
+    counted, and its slot is seated one turn later."""
+    import jax
+    from test_sdar_moe import (SdarMoeConfig, _count, _engine, _ref_cfg,
+                               _session, ref)
+
+    cfg = SdarMoeConfig.tiny(dtype="float32")
+    net, exe, scope, params = _session(cfg, batch_slots=2, prefill_rows=1)
+    rc = _ref_cfg(cfg)
+    eng = _engine(net, exe, scope, chunk)
+    names = ("serving_lookahead_dropped_tokens_total",
+             "serving_decode_tokens_total")
+    before = {n: _count(n) for n in names}
+    lag0 = (_count("serving_seat_lag_turns"),
+            monitor.metric_value("serving_seat_lag_turns",
+                                 {"sum": 0.0})["sum"])
+    behind0 = _count("serving_launches_total", phase="decode",
+                     queued_behind="running")
+    sizes = [(3, 9), (16, 5), (12, 7), (7, 14), (21, 8), (9, 12)]
+    rng = np.random.default_rng(chunk)
+    prompts = [rng.integers(1, 128, n) for n, _ in sizes]
+    with eng:
+        futs = [eng.submit(p, max_new_tokens=m)
+                for p, (_, m) in zip(prompts, sizes)]
+        outs = [f.result(timeout=300)[0] for f in futs]
+    fn = {}
+    for p, (_, m), o, f in zip(prompts, sizes, outs, futs):
+        total = -(-(len(p) + m) // cfg.block_length) * cfg.block_length
+        if total not in fn:
+            fn[total] = jax.jit(lambda t: ref.logits(params, t, rc))
+        want, at, _ = ref.generate(params, p, m, rc, fn[total])
+        assert list(o) == list(want) and f.revealed_at() == list(at)
+    assert eng.accounting()["exact"] and not eng._inflight
+    assert eng.generation_stats()["decode_recompiles"] == 0
+    got = {n: _count(n) - before[n] for n in names}
+    assert got["serving_decode_tokens_total"] == sum(m for _, m in sizes)
+    # whole blocks, of the dispatches that ran past an answer's end
+    late = got["serving_lookahead_dropped_tokens_total"]
+    assert late > 0 and late % cfg.block_length == 0
+    assert _count("serving_launches_total", phase="decode",
+                  queued_behind="running") - behind0 >= 6
+    seats = _count("serving_seat_lag_turns") - lag0[0]
+    turns = monitor.metric_value("serving_seat_lag_turns")["sum"] - lag0[1]
+    assert seats == len(sizes) - 2 and turns == seats

@@ -396,3 +396,59 @@ def test_negative_controls_prefix_off_spec_off(net):
     assert monitor.metric_value("serving_spec_accepted_len",
                                 default=None) is None
     assert eng.accounting()["exact"]
+
+
+# ---------------------------------------------------------------------------
+# one dispatch ahead (ISSUE 42): a turn that needs fetched values drains first
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("needs", ["chunked", "speculative", "prefix_hit"])
+def test_a_turn_that_needs_fetched_values_drains_first(net, needs):
+    """A chunk slice, a verify round (it drafts from the tokens before it)
+    and a prefix-cache copy-in (it writes pages through the host) cannot be
+    launched behind a dispatch whose results are still on the device: when
+    a turn holds one of them the loop settles what is in flight first, and
+    the tokens are the serial order's. A resident that needs none of it
+    beside them still decodes a chunk ahead on the turns in between."""
+    p_res = RNG.randint(1, 128, 6).astype(np.int64)
+    shared = RNG.randint(1, 128, 12).astype(np.int64)
+    p_new = {"chunked": RNG.randint(1, 128, 30).astype(np.int64),
+             "speculative": RNG.randint(1, 128, 9).astype(np.int64),
+             "prefix_hit": np.concatenate([shared, [7, 8, 9]])}[needs]
+    kw = {"chunked": dict(prefix_cache=False, chunked_prefill=True),
+          "speculative": dict(prefix_cache=False, chunked_prefill=False,
+                              speculative=True),
+          "prefix_hit": dict(prefix_cache=True, chunked_prefill=True)}[needs]
+    base_eng = _engine(net, prefix_cache=False,
+                       chunked_prefill=(needs == "chunked"))
+    base_eng.warm_up()
+    with base_eng:
+        cold_res = _run_one(base_eng, p_res, max_new=30)
+        cold_new = _run_one(base_eng, p_new, max_new=8)
+    eng = _engine(net, **kw)
+    eng.warm_up()
+    monitor.reset()
+    seen = []
+    site = {"chunked": "_run_chunk_slices", "speculative": "_run_spec_chunk",
+            "prefix_hit": "_copy_in_prefix"}[needs]
+    orig = getattr(eng, site)
+
+    def spied(*a, **k):
+        seen.append(len(eng._inflight))
+        return orig(*a, **k)
+
+    setattr(eng, site, spied)
+    with eng:
+        if needs == "prefix_hit":
+            _run_one(eng, np.concatenate([shared, [5, 6]]))   # publishes
+        f_res = eng.submit(p_res, max_new_tokens=30)
+        next(f_res.stream(timeout=120))       # resident and decoding
+        f_new = eng.submit(p_new, max_new_tokens=8)
+        assert list(f_new.result(timeout=120)[0]) == cold_new
+        assert list(f_res.result(timeout=120)[0]) == cold_res
+    assert seen and not any(seen), seen
+    assert eng.accounting()["exact"] and not eng._inflight
+    behind = monitor.metric_value("serving_launches_total", 0.0,
+                                  phase="decode", queued_behind="running")
+    # a verify round every turn: nothing is ever launched behind another
+    assert (behind == 0) if needs == "speculative" else (behind > 0)

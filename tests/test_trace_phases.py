@@ -17,10 +17,12 @@ from paddle_tpu import monitor, serving, trace
 from paddle_tpu.framework import Program, program_guard
 from paddle_tpu.kernels import flash_attention, flash_attention_decode
 from paddle_tpu.models.gpt import GptConfig, build_gpt_generative
+from slow_device import slow_device
 
 EXECUTOR_PHASES = {"executor.bind", "executor.feed", "executor.step",
                    "executor.fetch", "executor.writeback"}
-LOOP_PHASES = ("idle_wait", "schedule", "admit", "feed", "settle", "publish")
+LOOP_PHASES = ("idle_wait", "await_newcomers", "schedule", "admit", "feed",
+               "settle", "publish")
 # the leaves that tile the dispatch thread; spans nested deeper
 # (retry.device_put, executor.compile) are detail inside one of them
 LEAVES = EXECUTOR_PHASES | {"serving." + p for p in LOOP_PHASES}
@@ -117,8 +119,9 @@ def test_step_seconds_is_host_plus_fetch_wait(mlp, path):
 
 def _generate(traced: bool, idle_s: float = 0.0):
     """A tiny engine through warm-up, an idle start, two waves of requests
-    (the second re-sends a prompt, so admission hits published pages) and
-    an idle end. Returns the spans it recorded."""
+    (the second re-sends a prompt, so admission hits published pages, and
+    its turns without newcomers run a chunk ahead) and an idle end. Returns
+    the spans it recorded."""
     with un.guard():
         # a prefill row a slot: one prefill dispatch a bucket and wave, so
         # the thread's time is in the phases and not between many of them
@@ -133,14 +136,19 @@ def _generate(traced: bool, idle_s: float = 0.0):
                                      deadline_s=0),
         gen_config=serving.GenerationConfig(decode_chunk=2))
     eng.warm_up()
+    # a decode chunk that takes 20 ms, as a device's would: the thread
+    # then has a chunk in flight to wait for newcomers under
+    slow_device(exe, 0.02)
     fluid.set_flags({"FLAGS_trace": int(traced)})
     trace.clear()
     rng = np.random.RandomState(3)
     prompts = [rng.randint(1, 128, 9 + 3 * i) for i in range(6)]
     with eng:
         time.sleep(idle_s)
-        for wave in (prompts, prompts[:2]):
-            futs = [eng.submit(p, max_new_tokens=2 + i % 4)
+        # the second wave's answers are longer: turns without newcomers,
+        # launched a chunk ahead, with two slots free and no queue
+        for k, wave in enumerate((prompts, prompts[:2])):
+            futs = [eng.submit(p, max_new_tokens=(2 + i % 4) * (1 + 2 * k))
                     for i, p in enumerate(wave)]
             for f in futs:
                 f.result(timeout=120)
@@ -195,12 +203,36 @@ def test_loop_phase_spans_carry_what_they_did(generation):
     assert sum(s.attrs["hits"] for s in by["serving.admit"]) == 2
     assert sum(s.attrs["rows"] for s in by["serving.admit"]) >= 16
     assert sum(s.attrs["tokens"] for s in by["serving.settle"]) == sum(
-        2 + i % 4 for i in range(6)) + 2 + 3
+        2 + i % 4 for i in range(6)) + 6 + 9
     assert sum(s.attrs["finished"] for s in by["serving.settle"]) == 8
     # the chained dispatch has the launch span the plain one always had
     chained = {s.span_id for s in by["executor.run_chained"]}
     assert sum(s.parent_id in chained for s in by["executor.step"]) \
         == len(chained)
+
+
+def test_a_chunk_launched_ahead_is_fetched_under_its_own_root(generation):
+    """``serving.decode`` stays one span a dispatch, from its launch to
+    its settle: its ``executor.fetch``, taken after the next chunk's
+    launch, is a child of the call that launched it, and roots overlap
+    where the leaves do not."""
+    spans, _, _ = generation
+    by_id = {s.span_id: s for s in spans}
+    roots = sorted((s for s in spans if s.name == "serving.decode"),
+                   key=lambda s: s.t0_mono)
+    calls = {s.parent_id: s for s in spans
+             if s.name == "executor.run_chained"}
+    assert set(calls) == {r.span_id for r in roots}
+    for r in roots:
+        kids = [s for s in spans if s.parent_id == calls[r.span_id].span_id]
+        assert sorted(s.name for s in kids).count("executor.fetch") == 1
+        (fetch,) = [s for s in kids if s.name == "executor.fetch"]
+        assert by_id[fetch.parent_id].name == "executor.run_chained"
+        assert fetch.t0_mono + fetch.duration_s \
+            <= r.t0_mono + r.duration_s + 1e-9
+    ahead = [b for a, b in zip(roots, roots[1:])
+             if b.t0_mono < a.t0_mono + a.duration_s]
+    assert len(ahead) >= 2
 
 
 @pytest.mark.parametrize("phase", LOOP_PHASES)
