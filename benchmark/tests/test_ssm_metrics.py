@@ -1,0 +1,272 @@
+"""The ``.ssm`` per-layer metrics of
+``granite-4.0-h-small-ep2.decode-short-chat`` and the configuration's cut:
+the kernel-name patterns of their files against the names as the TPU
+compiler prints them for this configuration (``tools/deviceless_ssm.py
+--hlo``) and nothing else, the ops-and-bytes functions of
+``kernel_costs_ssm.py`` against counts made by hand, the roofline reader on a
+made-up window (and on a program without the counters: nothing, no raise),
+the file's published keys against the cut it states, and the fp8 control
+against the tiny configuration's limit."""
+import glob
+import json
+import os
+
+import numpy as np
+import pytest
+
+import harness
+import kernel_costs_ssm as costs
+from readers import kernel_roofline, kernel_roofline_in, trace_op_share
+
+CELL = "granite-4.0-h-small-ep2.decode-short-chat"
+BENCH = harness.load_json(harness.REPO, "BENCHMARK.json")
+CFG = harness.load_json(harness.HERE, "configs",
+                        "granite-4.0-h-small-ep2-serve.json")
+PEAKS = harness.load_json(harness.HERE, "peaks.json")["devices"][
+    "TPU v5 lite"]
+NAMES = sorted(m["name"] for m in BENCH["per_layer"]
+               if m["name"].endswith(".ssm"))
+FILES = {n: harness.load_json(harness.HERE, "layer_metrics", n + ".json")
+         for n in NAMES}
+
+# left-hand sides and targets of the Mosaic calls in the compiled prefill
+# and decode programs of a described v5e, with a fusion that reads one
+HLO = {
+    "scan": '%ssd_chunk_scan.5 = (f32[8,768,8192]{2,1,0:T(8,128)}, '
+            'f32[8,64,128,128]{3,2,1,0:T(8,128)}) custom-call(%a, %b, %c, '
+            '%d, %e), custom_call_target="tpu_custom_call"',
+    "step": '%ssd_decode_step.3 = (f32[64,4,64,32]{3,2,1,0:T(8,128)}, '
+            'f32[64,128,64,128]{3,2,1,0:T(8,128)}) custom-call(%a, %b, %c, '
+            '%d), custom_call_target="tpu_custom_call"',
+    "gate_up": '%moe_expert_matmul.56 = bf16[1216,768]{1,0:T(8,128)(2,1)}'
+               ' custom-call(%a, %b, %c, %d, %e), '
+               'custom_call_target="tpu_custom_call"',
+    "down": '%moe_expert_matmul.9 = f32[1216,4096]{1,0:T(8,128)} '
+            'custom-call(%a, %b, %moe_expert_matmul.8, %d), '
+            'custom_call_target="tpu_custom_call"',
+    "router": '%moe_router.28 = f32[64,72]{1,0:T(8,128)S(1)} '
+              'custom-call(%a, %b), custom_call_target="tpu_custom_call"',
+    "decode": '%decode_attention.28 = bf16[512,4,128]{2,1,0:T(8,128)(2,1)'
+              'S(1)} custom-call(%a, %b, %c, %d), '
+              'custom_call_target="tpu_custom_call"',
+    "flash": '%flash_attention_fwd.4 = (bf16[256,768,128]{2,1,0}, '
+             'f32[256,8,768]{2,1,0}) custom-call(%a), '
+             'custom_call_target="tpu_custom_call"',
+    "gdn": '%gdn_decode_step.3 = (f32[64,4,8,128]{3,2,1,0:T(8,128)}, '
+           'f32[64,32,128,128]{3,2,1,0:T(8,128)}) custom-call(%a, %b, %c), '
+           'custom_call_target="tpu_custom_call"',
+    "fusion": '%fusion.40 = f32[64,128,64]{2,1,0} '
+              'fusion(f32[64,4,64,32]{3,2,1,0} %ssd_decode_step.3), '
+              'kind=kLoop',
+}
+
+
+def test_the_cell_has_its_ssm_metrics_and_only_lists_itself():
+    assert len(NAMES) == 19
+    for m in BENCH["per_layer"]:
+        if m["name"].endswith(".ssm"):
+            assert m["workloads"] == [CELL]
+        else:
+            assert CELL not in m.get("workloads", [])
+    e2e = {m["name"]: m for m in BENCH["end_to_end"]}
+    assert e2e["decode_tokens_per_s"]["workloads"][-1] == CELL
+    cell = {w["name"]: w for w in BENCH["workloads"]}[CELL]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "granite-4.0-h-small-ep2-serve", "decode-short-chat", 1)
+    # what PERF.md section 7 says is not to be listed under PR 42's overlap
+    for stem in ("device_idle_window_pct", "dispatch_overhead_ms",
+                 "device_idle_pct"):
+        assert stem + ".ssm" not in NAMES
+
+
+@pytest.mark.parametrize("metric,hits", [
+    ("ssd_scan_time_pct.ssm", {"scan"}),
+    ("ssd_scan_roofline_pct.ssm", {"scan"}),
+    ("ssd_step_time_pct.ssm", {"step"}),
+    ("ssd_step_roofline_pct.ssm", {"step"}),
+    ("expert_time_pct.ssm", {"gate_up", "down"}),
+    ("expert_matmul_roofline_pct.ssm", {"gate_up", "down"}),
+    ("router_time_pct.ssm", {"router"}),
+    ("decode_kernel_time_pct.ssm", {"decode"}),
+    ("flash_fwd_time_pct.ssm", {"flash"}),
+])
+def test_kernel_name_patterns(metric, hits):
+    pattern = FILES[metric]["args"]["pattern"]
+    for key, line in HLO.items():
+        one = {"trace": {"busy_s": 1.0, "op_seconds": {line: 1.0}}}
+        got = trace_op_share.read(one, pattern=pattern)
+        assert (got == pytest.approx(100.0)) if key in hits else got is None
+
+
+def test_the_costs_match_the_hand_counts():
+    # one decode step of one Mamba-2 layer, 64 slots: 128 heads x a 64 x 128
+    # f32 state read and written, two 64 x 128 products a head
+    ops, moved = costs.ssd_step_cost(64, 128, 64, 128)
+    assert moved == 64 * 128 * 64 * 128 * 4 * 2 == 536_870_912
+    assert ops == 64 * 128 * 2 * 2 * 64 * 128 == 268_435_456
+    # the bytes bound it: 0.66 ms against a microsecond of multiplies
+    assert moved / PEAKS["hbm_bytes_per_s"] > 100 * ops / PEAKS[
+        "bf16_flops_per_s"]
+    # a small shape by hand: 3 rows, 2 heads of 4 over a state of 8
+    assert costs.ssd_step_cost(3, 2, 4, 8) == (3 * 2 * 2 * 2 * 4 * 8,
+                                               3 * 2 * 2 * 4 * 8 * 4)
+    assert costs.ssd_scan_cost(3, 2, 4, 8) == (
+        3 * 2 * 2 * 2 * 4 * 8, 3 * (2 * 2 * 4 + 2 * 8 + 2) * 4)
+    # a prefill of 3,328 real rows through one Mamba-2 layer: 4 x 64 x 128
+    # operations a head and row; u and y (8,192 each), B, C (128 each) and
+    # the log-decay (128) in f32
+    ops, moved = costs.ssd_scan_cost(3328, 128, 64, 128)
+    assert ops == 3328 * 128 * 4 * 64 * 128 == 13_958_643_712
+    assert moved == 3328 * (2 * 8192 + 2 * 128 + 128) * 4 == 223_215_616
+    # one decode step of one layer: 64 x 10 choices, half local, all 36 held
+    # experts hit; an expert is three 4096 x 768 matrices
+    H, F = CFG["hidden_size"], CFG["intermediate_size"]
+    assert (H, F, CFG["shared_intermediate_size"]) == (4096, 768, 1536)
+    ops, moved = costs.expert_matmul_cost(320, 36, H, F)
+    assert moved == 36 * 3 * 4096 * 768 * 2 == 679_477_248
+    assert ops == 2 * 3 * 4096 * 768 * 320
+
+
+def _counters(decode_calls, prefill_calls):
+    c = {}
+    for layer in range(10):
+        for phase, calls, tokens, hit in (
+                ("decode", decode_calls, 320.0, 36.0),
+                ("prefill", prefill_calls, 16000.0, 36.0)):
+            lab = f"{{layer={layer},phase={phase}}}"
+            c["moe_expert_calls_total" + lab] = float(calls)
+            c["moe_expert_tokens_total" + lab] = calls * tokens
+            c["moe_experts_hit_total" + lab] = calls * hit
+    for layer in (0, 1, 2, 3, 4, 6, 7, 8, 9):
+        for phase, calls, tokens in (("decode", decode_calls, 63.0),
+                                     ("prefill", prefill_calls, 3300.0)):
+            lab = f"{{layer={layer},phase={phase}}}"
+            c["ssm_calls_total" + lab] = float(calls)
+            c["ssm_tokens_total" + lab] = calls * tokens
+    return c
+
+
+def test_roofline_reader_finds_its_cost_module(monkeypatch):
+    counters = _counters(2000, 100)
+    step, calls = costs.ssd_step_seconds(CFG, counters, PEAKS)
+    assert calls == 9 * 2000
+    assert step == pytest.approx(
+        9 * 2000 * 63 * 128 * 64 * 128 * 4 * 2 / 819e9, rel=1e-6)
+    scan, calls = costs.ssd_scan_seconds(CFG, counters, PEAKS)
+    assert calls == 9 * 100
+    assert scan == pytest.approx(
+        9 * 100 * 3300 * (2 * 8192 + 2 * 128 + 128) * 4 / 819e9, rel=1e-6)
+    experts, calls = costs.moe_expert_matmul_seconds(CFG, counters, PEAKS)
+    assert calls == 2 * 10 * 2100
+    assert experts == pytest.approx(
+        10 * 2000 * 36 * 3 * 4096 * 768 * 2 / 819e9
+        + 10 * 100 * 16000 * 2 * 3 * 4096 * 768 / 197e12, rel=1e-6)
+    ops = [(HLO["step"], 0, 900_000), (HLO["step"], 9, 900_009),
+           (HLO["scan"], 0, 5_000_000), (HLO["router"], 0, 100_000)]
+    monkeypatch.setattr(kernel_roofline, "_newest_profile", lambda: "p")
+    monkeypatch.setattr(kernel_roofline_in.xplane, "load",
+                        lambda path: {"devices": {"d": {"ops": ops}}})
+    ctx = {"trace": {"window_s": 4.0}, "peaks": PEAKS, "config": CFG,
+           "counters": counters}
+    args = FILES["ssd_step_roofline_pct.ssm"]["args"]
+    got = kernel_roofline_in.read(ctx, **args)
+    assert got == pytest.approx(100.0 * (step / 18000) / 900e-6)
+    assert 0 < got < 100
+    assert 0 < kernel_roofline_in.read(
+        ctx, **FILES["ssd_scan_roofline_pct.ssm"]["args"]) < 100
+    # no matching operation in the trace, a program without the counters
+    # (the parent commit, or the delta rule's net), or no trace: nothing,
+    # and no raise
+    gdn_only = {k.replace("ssm_", "gdn_"): v for k, v in counters.items()}
+    for name in ("ssd_step_roofline_pct.ssm", "ssd_scan_roofline_pct.ssm",
+                 "expert_matmul_roofline_pct.ssm"):
+        args = FILES[name]["args"]
+        assert kernel_roofline_in.read(dict(ctx, counters={}),
+                                       **args) is None
+        assert kernel_roofline_in.read(dict(ctx, trace=None), **args) is None
+    for name in ("ssd_step_roofline_pct.ssm", "ssd_scan_roofline_pct.ssm"):
+        assert kernel_roofline_in.read(
+            dict(ctx, counters={k: v for k, v in gdn_only.items()
+                                if k.startswith("gdn_")}),
+            **FILES[name]["args"]) is None
+    assert kernel_roofline_in.read(
+        ctx, **FILES["expert_matmul_roofline_pct.ssm"]["args"]) is None
+
+
+def test_the_file_holds_the_published_widths_and_states_its_cut():
+    with open("/opt/skills/guides/model-configs/architectures.jsonl") as f:
+        rows = [json.loads(line) for line in f]
+    (row,) = [r for r in rows if r["name"] == "granite-4.0-h-small"]
+    entry = {c["name"]: c for c in BENCH["configs"]}[
+        "granite-4.0-h-small-ep2-serve"]
+    assert entry["source"] == CFG["source"] == row["source_url"]
+    assert sorted(entry["reduced"]) == sorted(CFG["reduced"]) == [
+        "num_hidden_layers", "num_local_experts", "vocab_size"]
+    pub, dep = row["config"], CFG["deployment"]
+    for key, value in pub.items():
+        if key in CFG["reduced"]:
+            assert CFG["reduced"][key]["published"] == value
+            assert CFG["reduced"][key]["here"] == CFG[key]
+        elif key != "layer_types":
+            assert CFG[key] == value, key
+    # the cut: one whole period, half the experts, half the vocabulary; the
+    # published list of layer types stays whole and its first ten are built
+    from reference import granitemoehybrid as ref
+
+    assert CFG["layer_types"] == pub["layer_types"]
+    built = ref.model_config(CFG)["layer_types"]
+    assert built == pub["layer_types"][:10] == pub["layer_types"][10:20]
+    assert CFG["num_hidden_layers"] == len(built) == 10
+    assert built.count("attention") == 1
+    assert CFG["num_local_experts"] * dep["chips_per_layer"] == 72 \
+        == dep["num_experts_total"] == pub["num_local_experts"]
+    assert CFG["vocab_size"] * dep["chips_per_layer"] == 100_352 \
+        == dep["vocab_size_total"]
+    assert CFG["num_hidden_layers"] * dep["pipeline_stages"] == 40 \
+        == dep["num_hidden_layers_total"]
+    s = CFG["serving"]
+    assert s["slots"] == dep["chips_per_layer"] * dep["streams_per_chip"]
+    assert max(s["prompt_buckets"]) >= harness.load_json(
+        harness.HERE, "traffic", "decode-short-chat.json")[
+        "prompt_len"]["max"]
+
+
+def test_the_traffic_is_the_issues():
+    mix = harness.load_json(harness.HERE, "traffic", "decode-short-chat.json")
+    assert (mix["generator"], mix["clients"], mix["pool"]) == (
+        "closed_loop", CFG["serving"]["slots"], 64)
+    assert (mix["prompt_len"], mix["answer_len"]) == (
+        {"dist": "uniform", "min": 64, "max": 768},
+        {"dist": "uniform", "min": 64, "max": 320})
+    assert mix["max_total"] == CFG["serving"]["max_seq"] == 2048
+    seeds = [harness.load_json(p).get("mix_seed") for p in glob.glob(
+        os.path.join(harness.HERE, "traffic", "*.json"))]
+    assert seeds.count(mix["mix_seed"]) == 1
+    # one warm request a bucket
+    buckets = CFG["serving"]["prompt_buckets"]
+    hit = sorted(min(b for b in buckets if b >= p) for p, _ in mix["warm"])
+    assert hit == buckets
+
+
+def test_fp8_control_fails_the_tiny_limit_that_the_reference_passes():
+    """Reference against reference at the rehearsal's sizes: the
+    reference's own choices score 0, the fp8-operand control's lie further
+    below the best than the tiny configuration's limit, and a scan state
+    kept in bf16 moves the logits too."""
+    import jax.numpy as jnp
+
+    from reference import granitemoehybrid as ref
+
+    cell = harness.Cell(BENCH, CELL, rehearse=True)
+    limit = cell.config["check"]["logit_gap_limit"]
+    model = ref.model_config(cell.config)
+    w = dict(ref.make_weights(ref.param_spec(model), 11))
+    rng = np.random.default_rng(11)
+    ids = jnp.asarray(rng.integers(1, model["vocab_size"], 96), jnp.int32)
+    full = ref.logits(w, ids, model)
+    best = jnp.argmax(full, axis=-1).astype(jnp.int32)
+    served, ctl = ref.gaps_fn(model, "fp8")(w, ids, best)
+    assert float(jnp.max(served)) == 0.0
+    assert float(jnp.max(ctl)) > limit, float(jnp.max(ctl))
+    low = ref.logits(w, ids, model, state_dtype=jnp.bfloat16)
+    assert float(jnp.max(jnp.abs(low - full))) > 0.0

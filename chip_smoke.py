@@ -347,6 +347,34 @@ def leg_kernels() -> dict:
     say(leg, f"gdn_decode_step {R}x{Hv}x{Dh}x{Dh}: max|err| {e:.2e}")
     check(e <= 1e-4, "gdn_decode_step agrees with one step of the token "
                      "loop (<= 1e-4)")
+    # -- Mamba-2 selective scan (kernels/ssd.py): the chunked scan over two
+    #    prompts in a 768-row bucket (one of 500 real rows) from a carried
+    #    state and then the decode step, 128 heads of 64 over a state of 128,
+    #    f32, against the token loop in plain jax.numpy
+    from paddle_tpu.kernels.ssd import (ssd_chunk_scan, ssd_decode_step,
+                                        ssd_scan_reference,
+                                        ssd_step_reference)
+
+    R, Hm, S, Pm, Nm = 2, 128, 768, 64, 128
+    live = (jnp.arange(S)[None] < jnp.asarray([S, 500])[:, None])[..., None]
+    um = 0.3 * f32(R, S, Hm, Pm) * live[..., None]
+    gm = -jnp.exp(f32(R, S, Hm) - 2.0) * live
+    bm, cm, s0 = f32(R, S, Nm), f32(R, S, Nm), f32(R, Hm, Pm, Nm)
+    y_k, s_k = jax.jit(ssd_chunk_scan)(um, gm, bm, cm, s0)
+    y_r, s_r = jax.jit(ssd_scan_reference)(um, gm, bm, cm, s0)
+    e = max(maxerr(y_k[0], y_r[0]), maxerr(y_k[1, :500], y_r[1, :500]),
+            maxerr(s_k, s_r))
+    say(leg, f"ssd_chunk_scan {R}x{S}x{Hm}x{Pm}x{Nm}: max|err| {e:.2e}")
+    check(e <= 5e-4, "ssd_chunk_scan agrees with the token loop (<= 5e-4 on "
+                     "sums of 128 products of order 10, f32 in another "
+                     "order)")
+    args = (s_r, um[:, 7], jnp.exp(gm[:, 7]), bm[:, 7], cm[:, 7])
+    y_k, s_k = jax.jit(ssd_decode_step)(*args)
+    y_r, s_r = jax.jit(ssd_step_reference)(*args)
+    e = max(maxerr(y_k, y_r), maxerr(s_k, s_r))
+    say(leg, f"ssd_decode_step {R}x{Hm}x{Pm}x{Nm}: max|err| {e:.2e}")
+    check(e <= 5e-4, "ssd_decode_step agrees with one step of the token "
+                     "loop (<= 5e-4)")
     # -- latent attention (kernels/latent_attention.py): the decode kernel
     #    over a latent cache at the published widths, 20 heads on rows of
     #    512 + 64 in 640 lanes, bf16, 4,096 rows in blocks of 1,024: lengths

@@ -37,7 +37,7 @@ __all__ = [
     "beam_search", "beam_search_decode", "filter_by_instag",
     "fused_decode_attention", "kv_cache_append", "sequence_gather",
     "rotary_embedding", "moe_experts", "slot_assign", "gated_delta_rule",
-    "rms_norm", "latent_attention",
+    "mamba2_scan", "rms_norm", "latent_attention",
     "sample_token", "spec_accept",
     "block_seed", "block_positions", "block_reveal",
 ]
@@ -1023,6 +1023,38 @@ def gated_delta_rule(x, conv_w, a, b, a_log, dt_bias, state, conv_state,
         attrs={"mode": str(mode), "num_k_heads": int(num_k_heads),
                "num_v_heads": int(num_v_heads),
                "head_k_dim": int(head_k_dim), "head_v_dim": int(head_v_dim)})
+    return out, stats
+
+
+def mamba2_scan(x, conv_w, conv_b, dt, a_log, dt_bias, d, state, conv_state,
+                mask, num_heads, head_dim, state_dim, mode="scan", chunk=256,
+                slots=None, slot_mask=None, name=None):
+    """The selective scan of a Mamba-2 mixer behind its causal convolution
+    (ops/ssd.py). ``mode="scan"``: ``x`` [R, S, H P + 2 N] whole prompts
+    (``[x | B | C]`` before the convolution), ``dt`` [R, S, H], ``mask``
+    [R, S]; sequence ``i`` overwrites the state of slot ``slots[i]`` where
+    ``slot_mask[i]`` > 0. ``mode="step"``: ``x`` [slots, 1, C], ``mask``
+    [slots, 1] the decode gate. ``state`` and ``conv_state`` are written in
+    place. Returns ``(out [R, S, H P] f32: y + D x, stats [1] int32: rows
+    the scan advanced)``."""
+    helper = LayerHelper("mamba2_scan", name=name)
+    out = helper.create_variable_for_type_inference("float32")
+    stats = helper.create_variable_for_type_inference("int32",
+                                                      stop_gradient=True)
+    inputs = {"X": x, "ConvW": conv_w, "ConvB": conv_b, "Dt": dt,
+              "ALog": a_log, "DtBias": dt_bias, "D": d, "State": state,
+              "ConvState": conv_state, "Mask": mask}
+    if slots is not None:
+        inputs["Slots"] = slots
+    if slot_mask is not None:
+        inputs["SlotMask"] = slot_mask
+    helper.append_op(
+        "mamba2_scan", inputs=inputs,
+        outputs={"Out": out, "StateOut": state, "ConvStateOut": conv_state,
+                 "Stats": stats},
+        attrs={"mode": str(mode), "num_heads": int(num_heads),
+               "head_dim": int(head_dim), "state_dim": int(state_dim),
+               "chunk": int(chunk)})
     return out, stats
 
 

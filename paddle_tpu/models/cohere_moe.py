@@ -113,7 +113,9 @@ class CohereMoeConfig:
 # ``initializer_range`` and ``dtype``, and for the feed-forward
 # ``hidden_size``, ``intermediate_size`` (an expert's width), ``num_experts``,
 # ``experts_held``, ``expert_offset``, ``top_k``, ``num_shared_experts``,
-# ``score_fn`` and, where it has them, ``select_bias`` and ``route_scale``.
+# ``score_fn`` and, where it has them, ``select_bias``, ``route_scale`` and
+# ``shared_intermediate_size`` (the shared experts' width in all, where it is
+# not ``num_shared_experts`` routed widths).
 
 def _attr(name: str, cfg):
     return ParamAttr(name=name,
@@ -196,7 +198,9 @@ def _ffn(h, hb, p: str, cfg, real=None, join: str = "mean"):
     ns = cfg.num_shared_experts
     if not ns:
         return routed, None, stats
-    shared = _gated_mlp(hb, ns * cfg.intermediate_size, f"{p}_shared", cfg)
+    width = (getattr(cfg, "shared_intermediate_size", None)
+             or ns * cfg.intermediate_size)
+    shared = _gated_mlp(hb, width, f"{p}_shared", cfg)
     if join != "sum":
         shared = layers.scale(shared, scale=1.0 / ns)
     if join == "gated":

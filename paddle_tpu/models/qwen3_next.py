@@ -336,6 +336,7 @@ def _build_decode(cfg, B, max_seq, page_size, sample):
             "logits": logits, "expert_stats": stats, "rule_stats": rules,
             "rule_layers": [i for i in range(cfg.num_layers)
                             if cfg.layer_type(i) == LINEAR],
+            "rule_family": "gdn",
             "cache_kinds": kinds,
             "cache_vars": [tuple(v.name for v in pair) for pair in state],
             "active_var": active.name}

@@ -12,6 +12,7 @@ from . import rnn_ops  # noqa: F401
 from . import generation  # noqa: F401
 from . import moe  # noqa: F401
 from . import gdn  # noqa: F401
+from . import ssd  # noqa: F401
 from . import latent_attention  # noqa: F401
 from . import block_diffusion  # noqa: F401
 from . import detection  # noqa: F401

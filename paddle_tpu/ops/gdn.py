@@ -43,6 +43,39 @@ def _unit(t):
     return t * jax.lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True) + 1e-6)
 
 
+def short_conv(mixed, w, tail, mask, step: bool):
+    """The causal depthwise convolution in front of a recurrent rule, both
+    forms: ``mixed`` [R, S, C] against taps ``w`` [C, taps]. A step reads
+    the stored ``tail`` [R, taps - 1, C] before its row; a scan starts from
+    zeros. Returns the sums (no bias, no activation) and the tail to store:
+    the window's last rows for a step, for a scan the last ``taps - 1`` real
+    rows by ``mask`` [R, S] (zeros before the sequence)."""
+    R, S, C = mixed.shape
+    taps = w.shape[1]
+    before = tail.astype(F32) if step else jnp.zeros((R, taps - 1, C), F32)
+    window = jnp.concatenate([before, mixed], axis=1)
+    conv = sum(window[:, j:j + S] * w[:, j] for j in range(taps))
+    if step:
+        return conv, window[:, 1:]
+    n = jnp.sum(mask, axis=1).astype(jnp.int32)                      # [R]
+    at = n[:, None] + jnp.arange(taps - 1)             # into ``window``
+    return conv, jnp.take_along_axis(window, at[:, :, None], axis=1)
+
+
+def write_slots(state, tail, final, new_tail, slots, smask, live):
+    """A scan's end: sequence ``i`` OVERWRITES the state and tail of slot
+    ``slots[i]`` (default ``i``) where ``smask[i]`` > 0. Returns both and
+    ``live`` with the masked sequences' rows taken out."""
+    R = final.shape[0]
+    idx = (jnp.arange(R) if slots is None
+           else slots.reshape(R)).astype(jnp.int32)
+    if smask is not None:       # a masked sequence goes out of range: dropped
+        idx = jnp.where(smask.reshape(R) > 0, idx, state.shape[0])
+        live = live * (smask.reshape((R,) + (1,) * (live.ndim - 1)) > 0)
+    return (state.at[idx].set(final.astype(state.dtype), mode="drop"),
+            tail.at[idx].set(new_tail.astype(tail.dtype), mode="drop"), live)
+
+
 @register_op(
     "gated_delta_rule",
     inputs=[IOSpec("X"), IOSpec("ConvW"), IOSpec("A"), IOSpec("B"),
@@ -96,17 +129,8 @@ def _gated_delta_rule(ctx, ins, attrs):
     interpret = route == "pallas-interpret"
 
     with jax.named_scope("gdn_conv"):
-        before = tail.astype(F32) if step else jnp.zeros(
-            (R, taps - 1, C), F32)
-        window = jnp.concatenate([before, mixed], axis=1)
-        conv = sum(window[:, j:j + S] * w[:, j] for j in range(taps))
+        conv, new_tail = short_conv(mixed, w, tail, mask, step)
         conv = jax.nn.silu(conv)
-        if step:
-            new_tail = window[:, 1:]
-        else:       # the last taps - 1 real rows; zeros before the sequence
-            n = jnp.sum(mask, axis=1).astype(jnp.int32)              # [R]
-            at = n[:, None] + jnp.arange(taps - 1)     # into ``window``
-            new_tail = jnp.take_along_axis(window, at[:, :, None], axis=1)
     q, k, v = jnp.split(conv, [Hk * Dk, 2 * Hk * Dk], axis=-1)
     heads = lambda t, n, d: t.reshape(R, S, n, d).transpose(0, 2, 1, 3)
     q = _unit(heads(q, Hk, Dk)) * Dk ** -0.5
@@ -135,14 +159,9 @@ def _gated_delta_rule(ctx, ins, attrs):
             o, final = gdn_scan_reference(q, k, v, g, beta)
         else:
             o, final = gdn_chunk_scan(q, k, v, g, beta, interpret=interpret)
-        slots, smask = x(ins, "Slots"), x(ins, "SlotMask")
-        idx = (jnp.arange(R) if slots is None
-               else slots.reshape(R)).astype(jnp.int32)
-        if smask is not None:   # a masked sequence goes out of range: dropped
-            idx = jnp.where(smask.reshape(R) > 0, idx, state.shape[0])
-            live = live * (smask.reshape(R, 1, 1) > 0)
-        state2 = state.at[idx].set(final, mode="drop")
-        tail2 = tail.at[idx].set(new_tail.astype(tail.dtype), mode="drop")
+        state2, tail2, live = write_slots(
+            state, tail, final, new_tail, x(ins, "Slots"),
+            x(ins, "SlotMask"), live)
         advanced = jnp.sum(live > 0)
     out = o.transpose(0, 2, 1, 3).reshape(R, S, Hv * Dv)
     return {"Out": [out], "StateOut": [state2.astype(state.dtype)],

@@ -339,7 +339,8 @@ class GenerativeEngine(ServingEngine):
         # a model with routed experts hands back the assignments each held
         # expert received (``layers.moe_experts``), one with recurrent
         # layers the rows each layer's rule advanced
-        # (``layers.gated_delta_rule``), one with latent attention the
+        # (``layers.gated_delta_rule``, ``layers.mamba2_scan``: the net
+        # names the counters' family), one with latent attention the
         # cache rows each layer's attention read
         # (``layers.latent_attention``)
         stats = lambda net: {k: net[f"{k}_stats"].name
@@ -350,6 +351,11 @@ class GenerativeEngine(ServingEngine):
             **{("prefill", b): stats(net)
                for b, net in model["prefill"].items()}}
         self._rule_layers = list(decode.get("rule_layers", ()))
+        self._rule_family = decode.get("rule_family")
+        if "rule" in self._stats_fetch["decode"] and not self._rule_family:
+            raise ValueError(
+                "serving: a decode net with rule_stats names the counters' "
+                "family (rule_family: 'gdn', 'ssm')")
         self._expert_layers = list(decode.get("expert_layers", ()))
         gc = self.gen_config
         self._prefix_cache = None
@@ -1722,18 +1728,21 @@ class GenerativeEngine(ServingEngine):
                 "executions of the latent attention op"))
 
     def _observe_rule_stats(self, phase: str, stats) -> None:
-        """What a dispatch's recurrent layers counted
-        (``layers.gated_delta_rule`` ``Stats``, [..., layers, 1]; a chained
-        decode stacks its steps in front): the real rows each layer's rule
-        advanced, an execution at a time."""
+        """What a dispatch's recurrent layers counted (the ``Stats`` of
+        ``layers.gated_delta_rule`` or ``layers.mamba2_scan``, [..., layers,
+        1]; a chained decode stacks its steps in front): the real rows each
+        layer's rule advanced, an execution at a time, under the family the
+        decode net names (``rule_family``: ``gdn``, ``ssm``)."""
+        fam = self._rule_family
         self._count_by_layer(
             phase, stats, self._rule_layers,
             _monitor.counter(
-                "gdn_tokens_total",
-                "rows of real tokens the gated delta rule advanced, by "
-                "layer and phase of the dispatch"),
+                f"{fam}_tokens_total",
+                f"rows of real tokens the recurrent layers' rule ({fam}) "
+                f"advanced, by layer and phase of the dispatch"),
             _monitor.counter(
-                "gdn_calls_total", "executions of the gated delta rule op"))
+                f"{fam}_calls_total",
+                f"executions of the recurrent layers' op ({fam})"))
 
     @staticmethod
     def _count_by_layer(phase: str, stats, layers, rows, calls) -> None:

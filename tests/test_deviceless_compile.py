@@ -333,6 +333,39 @@ def test_gated_delta_rule_kernels_compile_at_the_published_widths(v5e):
     assert not re.search(r"%copy[.\d]* = f32\[64,32,128,128\]", text)
 
 
+def test_selective_scan_kernels_compile_at_the_published_widths(v5e):
+    """Mamba-2's chunked scan over eight prompts of 768 rows (128 heads of 64
+    over a state of 128, f32, two heads a grid step, chunks of 256) from a
+    start state, and the decode step of 64 slots, whose 268 MB state is
+    rewritten in place: donated, nothing of its shape is copied. And the 36
+    held experts of 4096 x 768 at a decode step's row buffer."""
+    from paddle_tpu.kernels.moe import grouped_matmul
+    from paddle_tpu.kernels.ssd import ssd_chunk_scan, ssd_decode_step
+
+    f32 = jnp.float32
+    rows = lambda *w: v5e((8, 768) + w, f32)
+    text = _compiles_with_mosaic(ssd_chunk_scan, rows(128, 64), rows(128),
+                                 rows(128), rows(128),
+                                 v5e((8, 128, 64, 128), f32))
+    assert re.search(r"%ssd_chunk_scan[.\d]* = ", text)
+    text = jax.jit(ssd_decode_step, donate_argnums=(0,)).lower(
+        v5e((64, 128, 64, 128), f32), v5e((64, 128, 64), f32),
+        v5e((64, 128), f32), v5e((64, 128), f32),
+        v5e((64, 128), f32)).compile().as_text()
+    assert re.search(r"%ssd_decode_step[.\d]* = ", text)
+    assert not re.search(r"%copy[.\d]* = f32\[64,128,64,128\]", text)
+    bf = jnp.bfloat16
+    wgu, wd = v5e((36, 4096, 768), bf), v5e((36, 768, 4096), bf)
+
+    def ffn(x, wg, wu, wd, tile_expert, n_valid):
+        h = grouped_matmul(x, wg, tile_expert, n_valid, tm=16, rhs2=wu,
+                           out_dtype=bf)
+        return grouped_matmul(h, wd, tile_expert, n_valid, tm=16)
+
+    _compiles_with_mosaic(ffn, v5e((1216, 4096), bf), wgu, wgu, wd,
+                          v5e((76,), jnp.int32), v5e((), jnp.int32))
+
+
 def test_softmax_router_and_head_256_attention_compile(v5e):
     """The softmax router over 512 experts; 256 stacked experts of 2048 x
     512 at a decode step's row buffer; one decode step of 64 slots x 2
