@@ -26,8 +26,10 @@ from ..lowering import lowering_platform, note_kernel_route
 # kernel streams weights; 256 once it expects a few hundred, as in a large
 # prefill, where a tile has to keep the MXU busy for the weights it loads;
 # between the two, the power of two that holds the rows an expert expects
-# under even routing, so that an expert is one or two tiles and its
-# weights are read once or twice, not once for every 16 of its rows
+# under even routing, so that an expert is one or two tiles and each row
+# tile meets the expert's weights in one MXU pass, not one for every 16 of
+# its rows (the weights themselves cross HBM once a call whatever the
+# tile: the kernel keeps a block resident over an expert's tiles)
 _TM_SMALL, _TM_LARGE = 16, 256
 
 
@@ -37,6 +39,13 @@ def _tile_rows(expected: int) -> int:
     while tm < min(expected, _TM_LARGE):
         tm *= 2
     return tm
+
+
+def expert_tile_rows(tokens: int, top_k: int, num_experts: int) -> int:
+    """Rows of a tile in a ``moe_experts`` op over ``tokens`` rows: what
+    the grouped route tiles by, and what the serving layer counts an
+    execution's live tiles by (``ceil(assignments / rows)`` a hit expert)."""
+    return _tile_rows(tokens * top_k // num_experts)
 
 
 def _route_moe(T: int, H: int, platform) -> str:
@@ -90,7 +99,7 @@ def _grouped_held(xb, wg, wu, wd, le, local, weights, counts, num_experts,
     Eh = wg.shape[0]
     A = T * k
     # rows a held expert expects under even routing
-    tm = _tile_rows(A // num_experts)
+    tm = expert_tile_rows(T, k, num_experts)
     M = -(-A // tm) * tm + Eh * tm           # every assignment local
     flat_e = jnp.where(local, le, Eh).reshape(A)
     order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)

@@ -136,6 +136,23 @@ def _var_names(var) -> List[str]:
     return [] if var is None else [var.name]
 
 
+def _expert_tile_rows(net) -> List[int]:
+    """Rows of a grouped-matmul tile in each ``moe_experts`` op of a net's
+    program, in program order (which is the order the net stacks their
+    ``Stats``): the op's own rule over its static shapes."""
+    from ..ops.moe import expert_tile_rows
+    block = net["main"].global_block
+    rows = [expert_tile_rows(
+        int(np.prod(block.var(op.input("X")[0]).shape[:-1])),
+        int(op.attr("top_k")), int(op.attr("num_experts")))
+        for op in block.ops if op.type == "moe_experts"]
+    if len(rows) != net["expert_stats"].shape[0]:
+        raise ValueError(
+            f"serving: expert_stats stacks {net['expert_stats'].shape[0]} "
+            f"layers' counts, the program has {len(rows)} moe_experts ops")
+    return rows
+
+
 def _loop_phase(name: str, parent=None):
     """One phase of the dispatch thread's loop: the span ``serving.<name>``
     (``FLAGS_trace``) and an observation on
@@ -357,6 +374,14 @@ class GenerativeEngine(ServingEngine):
                 "serving: a decode net with rule_stats names the counters' "
                 "family (rule_family: 'gdn', 'ssm')")
         self._expert_layers = list(decode.get("expert_layers", ()))
+        # rows of a grouped-matmul tile in each expert op of a program, in
+        # the order the ops' Stats are stacked: the key of a stats fetch ->
+        # one number a layer with experts
+        self._expert_tile_rows = {
+            key: _expert_tile_rows(net) for key, net in (
+                ("decode", decode),
+                *((("prefill", b), n) for b, n in model["prefill"].items()))
+            if "expert" in self._stats_fetch[key]}
         gc = self.gen_config
         self._prefix_cache = None
         if gc.prefix_cache and self._chunk is not None:
@@ -1705,7 +1730,8 @@ class GenerativeEngine(ServingEngine):
             return
         got = dict(zip(self._stats_fetch[key], fetched))
         if "expert" in got:
-            self._observe_expert_stats(phase, np.asarray(got["expert"]))
+            self._observe_expert_stats(phase, np.asarray(got["expert"]),
+                                       self._expert_tile_rows[key])
         if "rule" in got:
             self._observe_rule_stats(phase, np.asarray(got["rule"]))
         if "latent" in got:
@@ -1756,12 +1782,13 @@ class GenerativeEngine(ServingEngine):
             rows.labels(**lab).inc(float(stats[:, j].sum()))
             calls.labels(**lab).inc(float(stats.shape[0]))
 
-    def _observe_expert_stats(self, phase: str, stats) -> None:
+    def _observe_expert_stats(self, phase: str, stats, tile_rows) -> None:
         """What a dispatch's expert ops counted (``layers.moe_experts``
         ``Stats``, [..., layers, experts_held + 2]; a chained decode stacks
         its steps in front): per layer and execution the assignments each
         held expert received, all assignments made, and local assignments
-        that found no row."""
+        that found no row. ``tile_rows``: a layer's rows of a
+        grouped-matmul tile, by which its live tiles are counted."""
         stats = stats.reshape((-1,) + stats.shape[-2:]).astype(np.int64)
         load, made, dropped = stats[..., :-2], stats[..., -2], stats[..., -1]
         tokens = _monitor.counter(
@@ -1774,11 +1801,19 @@ class GenerativeEngine(ServingEngine):
             "the expert op's executions")
         calls = _monitor.counter(
             "moe_expert_calls_total", "executions of the expert op")
+        tiles = _monitor.counter(
+            "moe_expert_tiles_total",
+            "row tiles of the grouped expert matmul that held rows "
+            "(ceil(assignments / tile rows) a hit expert), summed over the "
+            "expert op's executions: over moe_experts_hit_total, the tiles "
+            "that rode one fetch of an expert's weights")
         for j in range(stats.shape[1]):
             layer = self._expert_layers[j] if self._expert_layers else j
             lab = dict(layer=str(layer), phase=phase)
             tokens.labels(**lab).inc(float(load[:, j].sum()))
             hit.labels(**lab).inc(float((load[:, j] > 0).sum()))
+            tiles.labels(**lab).inc(float(
+                (-(-load[:, j] // tile_rows[j])).sum()))
             calls.labels(**lab).inc(float(stats.shape[0]))
         mean = load.mean(axis=-1)
         skew = _monitor.histogram(

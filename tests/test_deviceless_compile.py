@@ -267,12 +267,20 @@ def test_heads_of_whole_lane_tiles_keep_the_row_append(v5e, decoder):
 
 # -- the sparse-expert decoder's kernels at its published widths (PR 27) ------
 
-@pytest.mark.parametrize("rows,tm", [(768, 16), (16640, 16), (69632, 256)])
-def test_grouped_expert_matmul_compiles_at_the_published_widths(v5e, rows,
-                                                                tm):
-    """16 stacked experts of 4096 x 4096 in bf16; the row buffer of a
+@pytest.mark.parametrize("held,H,F,rows,tm", [
+    (16, 4096, 4096, 768, 16), (16, 4096, 4096, 16640, 16),
+    (16, 4096, 4096, 69632, 256),
+    # SDAR's 128 experts of 2048 x 768: a forward of 64 blocks, and a
+    # prefill of 1,024 rows (64 rows an expert)
+    (128, 2048, 768, 4096, 16), (128, 2048, 768, 16384, 64)])
+def test_grouped_expert_matmul_compiles_at_the_published_widths(
+        v5e, held, H, F, rows, tm):
+    """16 stacked experts of 4096 x 4096 in bf16 at the row buffer of a
     decode step of 64 tokens, of a prefill of 16 x 128, and of 64 x 128 in
-    tiles of 256: the gated gate-and-up call, then down, under one name."""
+    tiles of 256; 128 experts of 2048 x 768, a width no power of two
+    divides into lanes, whose blocks hold ``K`` whole (3 MiB each, resident
+    across an expert's tiles): the gated gate-and-up call, then down, under
+    one name."""
     from paddle_tpu.kernels.moe import grouped_matmul
 
     def ffn(x, wg, wu, wd, tile_expert, n_valid):
@@ -280,9 +288,10 @@ def test_grouped_expert_matmul_compiles_at_the_published_widths(v5e, rows,
                            out_dtype=jnp.bfloat16)
         return grouped_matmul(h, wd, tile_expert, n_valid, tm=tm)
 
-    w = v5e((16, 4096, 4096), jnp.bfloat16)
+    wgu = v5e((held, H, F), jnp.bfloat16)
     text = _compiles_with_mosaic(
-        ffn, v5e((rows, 4096), jnp.bfloat16), w, w, w,
+        ffn, v5e((rows, H), jnp.bfloat16), wgu, wgu,
+        v5e((held, F, H), jnp.bfloat16),
         v5e((rows // tm,), jnp.int32), v5e((), jnp.int32))
     assert len(re.findall(r"%moe_expert_matmul[.\d]* = ", text)) == 2
 

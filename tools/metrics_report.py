@@ -168,6 +168,56 @@ def scenario_infer_clone():
     return {"name": "infer_clone_repeat", "metrics": got, "expect": expect}
 
 
+def _family_sum(name: str) -> float:
+    """A labelled counter family summed over its children."""
+    fam = monitor.get_registry().get(name)
+    return float(sum(child.snapshot() for _, child in fam.children())
+                 ) if fam else 0.0
+
+
+def scenario_routed_experts():
+    """A sparse-expert decoder (SDAR, CI-sized: top 2 of 8 experts, all
+    held) served three requests: what the expert op counted reaches the
+    ``moe_expert_*`` families, and a live tile of the grouped matmul is
+    counted for every hit expert at the least (``expert_tiles`` over
+    ``experts_hit`` = the tiles that rode one fetch of an expert's
+    weights; anything dropped is a bug)."""
+    import paddle_tpu.unique_name as un
+    from paddle_tpu import serving
+    from paddle_tpu.models.sdar_moe import (SdarMoeConfig,
+                                            build_sdar_moe_generative)
+
+    tracked = {"expert_tiles": "moe_expert_tiles_total",
+               "experts_hit": "moe_experts_hit_total",
+               "expert_tokens": "moe_expert_tokens_total",
+               "dropped_assignments": "moe_dropped_assignments_total"}
+    with un.guard():
+        net = build_sdar_moe_generative(
+            SdarMoeConfig.tiny(dtype="float32"), batch_slots=2, max_seq=32,
+            page_size=8, prompt_buckets=(16,), prefill_rows=1)
+    exe, scope = fluid.Executor(fluid.CPUPlace()), fluid.Scope()
+    exe.run(net["startup"], scope=scope)
+    eng = serving.GenerativeEngine(
+        net, scope=scope, executor=exe,
+        config=serving.ServingConfig(max_batch=2, queue_depth=8,
+                                     deadline_s=0),
+        gen_config=serving.GenerationConfig(decode_chunk=2))
+    eng.warm_up()
+    now = lambda: dict({k: _family_sum(f) for k, f in tracked.items()},
+                       recompiles=float(monitor.recompile_count()))
+    before = now()
+    rng = np.random.RandomState(0)
+    with eng:
+        for fut in [eng.submit(rng.randint(1, 100, n), max_new_tokens=4)
+                    for n in (5, 9, 12)]:
+            fut.result()
+    got = _delta(before, now())
+    return {"name": "routed_experts", "metrics": got,
+            "expect": {"dropped_assignments": 0, "recompiles": 0},
+            "extra_ok": got["expert_tokens"] >= got["expert_tiles"]
+            >= got["experts_hit"] > 0}
+
+
 def scenario_forced_recompile(n: int):
     """Negative control: grow the feed batch size every run so each run
     after the first misses the cache with a fresh signature — n recompiles,
@@ -197,7 +247,7 @@ def scenario_forced_recompile(n: int):
 
 
 SCENARIOS = [scenario_run_repeat, scenario_chained_kept_state,
-             scenario_infer_clone]
+             scenario_infer_clone, scenario_routed_experts]
 
 
 def main(argv=None) -> int:
