@@ -315,6 +315,35 @@ def leg_kernels() -> dict:
           and not bool(jnp.array_equal(by_cols[0], c4[0])),
           "kv_append writes what the row append writes, bit for bit, and "
           "leaves a masked-out slot's cache as it was")
+    # -- a step of one row: the decode kernel merges the column into the
+    #    last live block it fetches and writes that block back (PR 45),
+    #    against `kv_append` and then the kernel: the attention and both
+    #    caches bit for bit, slot 2 masked out
+    one, q1 = new[:, :, :1], jnp.asarray(rng.randn(Bg * H, 1, D), jnp.float32)
+    v4 = vc.reshape(Bg, H, S_max, D)
+
+    @jax.jit
+    def append_then_attend(q, ck, cv, n, at, m):
+        ck, cv = (kv_append(c.swapaxes(2, 3), n, at, m).swapaxes(2, 3)
+                  for c in (ck, cv))
+        return flash_attention_decode(
+            q, ck.reshape(kc.shape), cv.reshape(kc.shape), at + 1,
+            num_heads=H, page_size=page), ck, cv
+
+    @jax.jit
+    def append_in_kernel(q, ck, cv, n, at, m):
+        o, ck2, cv2 = flash_attention_decode(
+            q, ck.reshape(kc.shape), cv.reshape(kc.shape), at + 1,
+            num_heads=H, page_size=page, append=(n, n, m))
+        return o, ck2.reshape(ck.shape), cv2.reshape(cv.shape)
+
+    two = append_then_attend(q1, c4, v4, one, lengths - 1, keep)
+    fused = append_in_kernel(q1, c4, v4, one, lengths - 1, keep)
+    check(all(bool(jnp.array_equal(a, b)) for a, b in zip(two, fused))
+          and bool(jnp.array_equal(fused[1][2], c4[2]))
+          and not bool(jnp.array_equal(fused[1][0], c4[0])),
+          "flash_attention_decode(append=...) returns what kv_append and "
+          "then the kernel return, bit for bit, a masked-out slot untouched")
     # -- gated delta rule (kernels/gdn.py): the chunked scan over two
     #    prompts in a 640-row bucket (one of 500 real rows) and then the
     #    decode step, 16 key / 32 value heads of 128 x 128, f32, against the
@@ -596,10 +625,14 @@ def leg_gpt(cfg=None, slots: int = 8, max_seq: int = 1024, page: int = 128,
     log.close()
     want = {f"gpt.prefill:{b}": {"fused_multihead_attention:pallas"}
             for b in buckets}
-    # a kernel-routed decode step appends through ``kv_append`` (PR 34),
-    # which counts its own lowerings beside the attention's
-    want["gpt.decode"] = want[f"gpt.verify:{spec_k}"] = {
+    # a kernel-routed verify chunk appends through ``kv_append`` (PR 34),
+    # a decode step of one row inside the decode kernel (PR 45): each
+    # counts its own lowerings beside the attention's
+    want[f"gpt.verify:{spec_k}"] = {
         "fused_decode_attention:pallas", "kv_append:pallas"}
+    want["gpt.decode"] = {
+        "fused_decode_attention:pallas",
+        "fused_decode_attention.append_in_kernel:pallas"}
     # a 128-row chunk is past the decode kernel's 8-row tile: the chunk
     # program rides the primitive path, and says so
     want[f"gpt.chunk:{net['prefill_chunk']}"] = {

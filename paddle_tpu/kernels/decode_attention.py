@@ -39,10 +39,16 @@ program-IR level sees ONE op that reads and writes the cache at the same
 index (which is what lets ``analysis.liveness.safe_donation_set`` prove the
 cache buffer donatable: its last read is not after its last write).
 :func:`flash_attention_decode` itself only READS the caches and returns the
-attention output; it neither appends nor returns them. The ``paged_``
+attention output; it neither appends nor returns them, but for a decode
+step of ONE row on a rows-minor cache (``append=``, PR 45): there the row
+is a column, whoever writes it fetches and rewrites the block around it,
+and that block is the walk's last live one, so the kernel merges the
+column in, scores the block as merged and copies it back itself, the
+caches its aliased results. The ``paged_``
 append helpers below are plain XLA updates of the donated buffer, one
 sequence at a time, for rows that are whole lane tiles; :func:`kv_append`
-is a Pallas call that aliases the cache, for rows that are columns. All
+is a Pallas call that aliases the cache, for rows that are columns (a
+chunk of rows, a ring). All
 take the slot mask: a mask gates the rows that are written, never the
 cache (see :func:`paged_kv_append`).
 
@@ -92,6 +98,12 @@ KERNEL_ROWS = 8
 def _keep(mask, batch):
     """``mask`` ([B], [B, 1], any dtype; > 0 = write) as [B] bool, or None."""
     return None if mask is None else mask.reshape(batch) > 0
+
+
+def _keep_flags(mask, batch):
+    """The same as [B] int32 for a kernel's scalar prefetch; ones for None."""
+    return (jnp.ones((batch,), jnp.int32) if mask is None
+            else _keep(mask, batch).astype(jnp.int32))
 
 
 def paged_kv_append(cache, new, positions, mask=None, slots=None):
@@ -266,16 +278,36 @@ def rows_minor(head_dim: int, dtype, page: int) -> bool:
 _LANES = 128
 
 
+def _with_column(block, new_ref, b, row, keep):
+    """``block`` [heads, D, rows] of a rows-minor cache with row ``row`` of
+    the cache (its lane ``row % rows``) replaced by sequence ``b``'s new
+    column where ``keep``. The column stands in lane ``b % 128`` of
+    ``new_ref``'s block [heads, D, 128]; the roll turns it to the lane its
+    row has in its lane tile (Mosaic rotates 32-bit lanes only: a 16-bit
+    float widens exactly), and a block of several lane tiles sees it once
+    a tile, of which the mask takes the row's."""
+    rows = block.shape[2]
+    col = row % rows
+    new = pltpu.roll(new_ref[...].astype(jnp.float32), (col - b) % _LANES,
+                     2).astype(block.dtype)
+    if rows > _LANES:
+        new = jnp.concatenate([new] * (rows // _LANES), axis=2)
+    lane = jax.lax.broadcasted_iota(jnp.int32, block.shape, 2)
+    return jnp.where((lane == col) & keep, new, block)
+
+
 def _append_kernel(pos_ref, keep_ref, new_ref, c_ref, o_ref):
     b = pl.program_id(0)
-    col = pos_ref[b] % _LANES
-    # sequence b's new column stands in lane b % 128 of its block of `new`;
-    # the roll turns it to the lane its row has in the cache's block
-    # (Mosaic rotates 32-bit lanes only: a 16-bit float widens exactly)
-    new = pltpu.roll(new_ref[...].astype(jnp.float32), (col - b) % _LANES,
-                     2).astype(o_ref.dtype)
-    lane = jax.lax.broadcasted_iota(jnp.int32, new.shape, 2)
-    o_ref[0] = jnp.where((lane == col) & (keep_ref[b] > 0), new, c_ref[0])
+    o_ref[0] = _with_column(c_ref[0], new_ref, b, pos_ref[b],
+                            keep_ref[b] > 0)
+
+
+def _columns(new, dtype):
+    """``new`` [B, H, C, D] as [C, H, D, B']: a row as a column, the
+    sequences beside each other in whole lane tiles (one block of 128
+    sequences is fetched once)."""
+    return jnp.pad(new.astype(dtype).transpose(2, 1, 3, 0),
+                   ((0, 0),) * 3 + ((0, -new.shape[0] % _LANES),))
 
 
 @jax.named_scope(_PALLAS_SCOPE)
@@ -299,12 +331,8 @@ def kv_append(cache, new, positions, mask=None, ring=False, *,
         raise ValueError(f"kv_append: a rows-minor cache of {S} rows is not "
                          f"whole {_LANES}-lane tiles")
     positions = positions.reshape(B).astype(jnp.int32)
-    keep = (jnp.ones((B,), jnp.int32) if mask is None
-            else _keep(mask, B).astype(jnp.int32))
-    # [C, H, D, B]: a row as a column, the sequences beside each other in
-    # whole lane tiles (one block of 128 sequences is fetched once)
-    cols = jnp.pad(new.astype(cache.dtype).transpose(2, 1, 3, 0),
-                   ((0, 0),) * 3 + ((0, -B % _LANES),))
+    keep = _keep_flags(mask, B)
+    cols = _columns(new, cache.dtype)
     c_spec = pl.BlockSpec((1, H, D, _LANES),
                           lambda b, pos, keep: (b, 0, 0, pos[b] // _LANES))
     for i in range(new.shape[2]):
@@ -393,8 +421,13 @@ def decode_walk_blocks(lengths, cache_shape, dtype, page_size: int,
     return int(live.sum()), int(live.size * num_k)
 
 
-def _decode_kernel(scale, group, q_len, minor, whole, len_ref, q_ref, k_ref,
-                   v_ref, o_ref, m_scr, l_scr, acc):
+def _decode_kernel(scale, group, q_len, minor, whole, append, len_ref,
+                   *refs):
+    if append:      # the step's new K/V row rides the last live block
+        (keep_ref, q_ref, k_ref, v_ref, kn_ref, vn_ref, o_ref, ko_hbm,
+         vo_hbm, m_scr, l_scr, acc, k_buf, v_buf, sems) = refs
+    else:
+        q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc = refs
     b, ik = pl.program_id(0), pl.program_id(2)
     num_k = pl.num_programs(2)
     # a K or V block is [heads, block_k, D] or, rows-minor, [heads, D,
@@ -409,10 +442,9 @@ def _decode_kernel(scale, group, q_len, minor, whole, len_ref, q_ref, k_ref,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc[:] = jnp.zeros_like(acc)
 
-    # a block past the last live one was not fetched (its index map
-    # repeats the last live block) and is not scored
-    @pl.when(ik <= last_live_block(length, q_len, block_k, num_k))
-    def _walk():
+    last = last_live_block(length, q_len, block_k, num_k)
+
+    def walk(k_ref, v_ref):
         q = q_ref[...]                              # [heads, R, D]
         s = jax.lax.dot_general(q, k_ref[0],
                                 (((2,), (d_at,)), ((0,), (0,))),
@@ -446,11 +478,59 @@ def _decode_kernel(scale, group, q_len, minor, whole, len_ref, q_ref, k_ref,
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
+    if not append:
+        # a block past the last live one was not fetched (its index map
+        # repeats the last live block) and is not scored
+        pl.when(ik <= last)(lambda: walk(k_ref, v_ref))
+    else:
+        pl.when(ik < last)(lambda: walk(k_ref, v_ref))
+        hg, heads = pl.program_id(1), k_ref.shape[1]
+        visit = b * pl.num_programs(1) + hg     # a sequence's group of heads
+        slot = visit % 2
+
+        def flushes(slot, b=0, hg=0, block=0):
+            """The two copies that write a slot's merged blocks to where
+            they came from in the caches (at the defaults: a handle of the
+            same extent, to wait on)."""
+            at = (b, pl.ds(hg * heads, heads), slice(None),
+                  pl.ds(pl.multiple_of(block * block_k, _LANES), block_k))
+            return [pltpu.make_async_copy(buf.at[slot], cache.at[at],
+                                          sems.at[i, slot])
+                    for i, (buf, cache) in enumerate(((k_buf, ko_hbm),
+                                                      (v_buf, vo_hbm)))]
+
+        def drain(slot):
+            for copy in flushes(slot):
+                copy.wait()
+
+        # the new row is the sequence's last visible key, row length - 1:
+        # its block is the last live one. It is merged (kept as fetched
+        # where the slot's mask is 0) into one of two buffers, scored from
+        # there, and flushed to the cache behind the visits that follow:
+        # the buffer is waited for when its turn comes again, two visits
+        # on. (As a pipelined result the block went out between two visits
+        # and the pipeline waited for it there: 2 us a visit, what the
+        # append kernel had cost. PERF.md section 6, PR 45)
+        @pl.when(ik == last)
+        def _append_and_walk():
+            pl.when(visit >= 2)(lambda: drain(slot))
+            keep = keep_ref[b] > 0
+            k_buf[slot] = _with_column(k_ref[0], kn_ref, b, length - 1, keep)
+            v_buf[slot] = _with_column(v_ref[0], vn_ref, b, length - 1, keep)
+            walk(k_buf.at[pl.ds(slot, 1)], v_buf.at[pl.ds(slot, 1)])
+            for copy in flushes(slot, b, hg, last):
+                copy.start()
+
     @pl.when(ik == num_k - 1)
     def _finish():
         l = l_scr[:, :, :1]
         o_ref[...] = (acc[:] / jnp.where(l == 0.0, 1.0, l)).astype(
             o_ref.dtype)
+        if append:      # the call's last step: both buffers' flushes land
+            @pl.when(visit == pl.num_programs(0) * pl.num_programs(1) - 1)
+            def _drain_all():
+                drain(slot)
+                pl.when(visit >= 1)(lambda: drain(1 - slot))
 
 
 def _kv_index_map(q_len: int, block_k: int, num_k: int,
@@ -460,7 +540,7 @@ def _kv_index_map(q_len: int, block_k: int, num_k: int,
     rows-minor). The pipeline issues no DMA for a block index that repeats
     (``kernels/moe.py`` ``frozen``), so nothing past a sequence's length
     is fetched."""
-    def index(b, hg, ik, lens):
+    def index(b, hg, ik, lens, *_):
         blk = jnp.minimum(ik, last_live_block(lens[b], q_len, block_k,
                                               num_k))
         return (b, hg, 0, blk) if minor else (b, hg, blk, 0)
@@ -468,13 +548,18 @@ def _kv_index_map(q_len: int, block_k: int, num_k: int,
 
 
 def _decode_call(q, k_cache, v_cache, lengths, tile, *, minor, scale, group,
-                 q_len, interpret, whole=False):
+                 q_len, interpret, whole=False, append=None):
     """The Pallas call on ``q`` [B * H, R, D] and caches [B, H, S_max, D]
     with ``tile = (heads, rows)`` of a cache a grid step
     (:func:`kv_tile`'s choice; ``tools/probe_decode_walk.py`` sweeps it).
     With ``minor`` (:func:`rows_minor` of the cache) the call takes the
     caches as [B, H, D, S_max], a bitcast of how they lie, and a block of
-    them as ``(1, heads, D, rows)``."""
+    them as ``(1, heads, D, rows)``. ``append = (k_new, v_new, keep)``
+    (:func:`flash_attention_decode`): the caches are the call's own results
+    too (``input_output_aliases``, so a donated cache is updated in place).
+    As results they stay in HBM, and the kernel itself copies one block a
+    sequence and group of heads into them, the last live one with the
+    column merged in, from two VMEM buffers a cache."""
     B, H = k_cache.shape[:2]
     _, R, D = q.shape
     hb, bk = tile
@@ -482,31 +567,57 @@ def _decode_call(q, k_cache, v_cache, lengths, tile, *, minor, scale, group,
     if minor:
         k_cache, v_cache = k_cache.swapaxes(2, 3), v_cache.swapaxes(2, 3)
     q_spec = pl.BlockSpec((hb, R, D),
-                          lambda b, hg, ik, s: (b * (H // hb) + hg, 0, 0))
-    kv_spec = pl.BlockSpec((1, hb, D, bk) if minor else (1, hb, bk, D),
-                           _kv_index_map(q_len, bk, nk, minor))
+                          lambda b, hg, ik, *_: (b * (H // hb) + hg, 0, 0))
+    kv_block = (1, hb, D, bk) if minor else (1, hb, bk, D)
+    kv_spec = pl.BlockSpec(kv_block, _kv_index_map(q_len, bk, nk, minor))
+    scalars, operands = [lengths], [q, k_cache, v_cache]
+    in_specs, out_specs = [q_spec, kv_spec, kv_spec], [q_spec]
+    out_shape = [_out_sds((B * H, R, D), q.dtype, q, k_cache, v_cache)]
+    scratch = [pltpu.VMEM((hb, R, 128), jnp.float32),     # running max
+               pltpu.VMEM((hb, R, 128), jnp.float32),     # running denom
+               pltpu.VMEM((hb, R, D), jnp.float32)]       # numerator acc
+    aliases, order = {}, ("parallel", "parallel", "arbitrary")
+    if append is not None:
+        k_new, v_new, keep = append
+        new_spec = pl.BlockSpec((hb, D, _LANES),
+                                lambda b, hg, ik, *_: (hg, 0, b // _LANES))
+        scalars.append(keep)
+        operands += [_columns(k_new, k_cache.dtype)[0],
+                     _columns(v_new, v_cache.dtype)[0]]
+        in_specs += [new_spec, new_spec]
+        # the caches as results stay in HBM: the kernel flushes the blocks
+        # it merged itself, from two buffers a cache, so a flush has two
+        # visits to land in
+        out_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
+        out_shape += [_out_sds(c.shape, c.dtype, c, *operands)
+                      for c in (k_cache, v_cache)]
+        scratch += [pltpu.VMEM((2,) + kv_block[1:], k_cache.dtype),
+                    pltpu.VMEM((2,) + kv_block[1:], v_cache.dtype),
+                    pltpu.SemaphoreType.DMA((2, 2))]
+        # the caches follow the scalars and q among the call's operands
+        aliases = {len(scalars) + 1: 1, len(scalars) + 2: 2}
+        # a buffer is handed from one visit to the one after the next
+        order = ("arbitrary",) * 3
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=len(scalars),
         grid=(B, H // hb, nk),
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=[q_spec],
-        scratch_shapes=[
-            pltpu.VMEM((hb, R, 128), jnp.float32),     # running max
-            pltpu.VMEM((hb, R, 128), jnp.float32),     # running denom
-            pltpu.VMEM((hb, R, D), jnp.float32),       # numerator acc
-        ],
+        in_specs=in_specs,
+        out_specs=out_specs,
+        scratch_shapes=scratch,
     )
-    (o,) = pl.pallas_call(
+    o, *caches = pl.pallas_call(
         functools.partial(_decode_kernel, scale, int(group), int(q_len),
-                          minor, bool(whole)),
+                          minor, bool(whole), append is not None),
         grid_spec=grid_spec,
-        out_shape=[_out_sds((B * H, R, D), q.dtype, q, k_cache, v_cache)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        out_shape=out_shape,
+        input_output_aliases=aliases,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=order),
         interpret=interpret,
         name="decode_attention",
-    )(lengths, q, k_cache, v_cache)
-    return o
+    )(*scalars, *operands)
+    if append is None:
+        return o
+    return (o, *(c.swapaxes(2, 3) if minor else c for c in caches))
 
 
 @jax.named_scope(_PALLAS_SCOPE)
@@ -514,7 +625,7 @@ def flash_attention_decode(q, k_cache, v_cache, lengths, *,
                            scale=None, num_heads: int = 1,
                            page_size: int = 128, group: int = 1,
                            interpret: bool = False,
-                           whole_chunk: bool = False):
+                           whole_chunk: bool = False, append=None):
     """One decode/verify chunk: q [BH, Sq, D] (1 <= Sq <= 8) against paged
     caches [BH, S_max, D].
 
@@ -542,6 +653,21 @@ def flash_attention_decode(q, k_cache, v_cache, lengths, *,
     group - 1`` keys, what the chunk's last row sees without it. The walk
     is the same (it ends at the last row's last block); only the mask of
     that block differs.
+
+    ``append = (k_new, v_new, mask)``: the call also WRITES the step's new
+    K/V row (``k_new``, ``v_new`` [B, num_heads, 1, D]; ``mask`` [B] or
+    [B, 1], > 0 = write, or None), which is the sequence's last visible
+    key, row ``lengths - 1``, and returns ``(o, k_cache, v_cache)``, the
+    caches aliased to the call's operands. For a step of one row
+    (Sq == group) on a rows-minor cache (:func:`rows_minor`), where a row
+    is a column and its one writer has to fetch and rewrite the whole
+    block ``(heads, D, rows)`` around it (:func:`kv_append`): that block is
+    the last live one of the walk, fetched here anyway, so the column is
+    merged into it in VMEM, it is scored as merged, and it goes back once
+    a sequence and group of heads, by a copy the kernel starts itself and
+    waits for two visits later (or at the call's last step). The same
+    values as append-then-attend in the same products: the three results
+    are that route's bit for bit.
     """
     BH, Sq, D = q.shape
     Sk = k_cache.shape[1]
@@ -562,6 +688,15 @@ def flash_attention_decode(q, k_cache, v_cache, lengths, *,
         raise ValueError(
             f"lengths has {B} rows but q has BH={BH} with "
             f"num_heads={num_heads} (expected {BH // num_heads})")
+    minor = rows_minor(D, k_cache.dtype, min(page_size, Sk))
+    if append is not None:
+        if not minor or Sq != group or whole_chunk:
+            raise ValueError(
+                f"flash_attention_decode appends one row a step to a "
+                f"rows-minor cache, got q_len={Sq // group}, "
+                f"whole_chunk={whole_chunk}, head_dim={D} in pages of "
+                f"{page_size}; use kv_append or paged_kv_append_rows first")
+        append = (*append[:2], _keep_flags(append[2], B))
     # pad the chunk to whole sublane tiles: [BH, Sq, D] -> [BH, R, D]
     # (replicas of the last real row; their output is sliced away). A
     # packed 16-bit type tiles 16 rows.
@@ -574,11 +709,13 @@ def flash_attention_decode(q, k_cache, v_cache, lengths, *,
             [q, jnp.broadcast_to(q[:, -1:, :], (BH, R - Sq, D))], axis=1)
     # a sequence's heads beside each other: they share its length, so one
     # grid step can carry a page of each
-    o8 = _decode_call(
+    out = _decode_call(
         q8, k_cache.reshape(B, num_heads, Sk, D),
         v_cache.reshape(B, num_heads, Sk, D), lengths,
         kv_tile(num_heads, Sk, D, k_cache.dtype, page_size),
-        minor=rows_minor(D, k_cache.dtype, min(page_size, Sk)), scale=scale,
-        group=group, q_len=Sq // group, interpret=interpret,
-        whole=whole_chunk)
-    return o8[:, :Sq, :]
+        minor=minor, scale=scale, group=group, q_len=Sq // group,
+        interpret=interpret, whole=whole_chunk, append=append)
+    if append is None:
+        return out[:, :Sq, :]
+    return (out[0][:, :Sq, :],
+            *(c.reshape(k_cache.shape) for c in out[1:]))

@@ -159,11 +159,19 @@ def _fused_decode_attention(ctx, ins, attrs):
     # the append works in the view the kernel reads (kernels.rows_minor:
     # [B, H, D, S_max] where the runtime stores the cache so), or a layout
     # conversion of every cache lands between the two, inside the scan;
-    # the swaps themselves are bitcasts. There a row is a column, and its
-    # one writer is a kernel that updates the cache in place
+    # the swaps themselves are bitcasts. There a row is a column, whose
+    # writer fetches and rewrites the block around it: for a step of one
+    # row that block is the decode kernel's last live one, and the kernel
+    # writes the row itself; a chunk of rows (it may cross a block's edge)
+    # and a ring (its new row is not its last) go through `kv_append` first
     minor = route != "primitive" and rows_minor(D, ck.dtype, min(page, S))
-    if minor:
+    in_kernel = minor and q_len == 1 and not whole and not window
+    if in_kernel:
+        note_kernel_route(ctx, "fused_decode_attention.append_in_kernel",
+                          route)
+    elif minor:
         note_kernel_route(ctx, "kv_append", route)
+    interpret = route == "pallas-interpret"
 
     def append(cache, new):
         if whole and not minor:
@@ -176,11 +184,10 @@ def _fused_decode_attention(ctx, ins, attrs):
             return paged_kv_append_rows(cache, new, pos_b, smask,
                                         ring=bool(window))
         return kv_append(cache.swapaxes(2, 3), new, pos_b, smask,
-                         ring=bool(window),
-                         interpret=(route == "pallas-interpret")
+                         ring=bool(window), interpret=interpret
                          ).swapaxes(2, 3)
 
-    ck2, cv2 = append(ck, kn), append(cv, vn)
+    ck2, cv2 = (ck, cv) if in_kernel else (append(ck, kn), append(cv, vn))
     lengths = jnp.minimum(pos_b + 1, S)
 
     # the G query heads of one key/value head beside each other, position-
@@ -196,8 +203,10 @@ def _fused_decode_attention(ctx, ins, attrs):
     else:
         o = flash_attention_decode(
             q3, k3, v3, lengths, scale=scale, num_heads=H,
-            page_size=page, group=G,
-            interpret=(route == "pallas-interpret"), whole_chunk=whole)
+            page_size=page, group=G, interpret=interpret, whole_chunk=whole,
+            append=(kn, vn, smask) if in_kernel else None)
+        if in_kernel:
+            o, ck2, cv2 = o[0], o[1].reshape(ck.shape), o[2].reshape(cv.shape)
     o = o.reshape(B * H, q_len, G, D).swapaxes(1, 2)
     return {"Out": [o.reshape(B, Hq, q_len, D)],
             "CacheKOut": [ck2], "CacheVOut": [cv2]}

@@ -24,7 +24,8 @@ from paddle_tpu.kernels import (decode_attention_reference,
                                 decode_walk_blocks, flash_attention_decode,
                                 kv_append, paged_kv_append,
                                 paged_kv_append_rows, rows_minor)
-from paddle_tpu.kernels.decode_attention import (_kv_index_map, kv_tile,
+from paddle_tpu.kernels.decode_attention import (_decode_call,
+                                                 _kv_index_map, kv_tile,
                                                  last_live_block)
 from paddle_tpu.models.gpt import GptConfig, build_gpt_generative
 
@@ -269,12 +270,194 @@ def test_kv_append_kernel_refuses_a_cache_of_broken_lane_tiles():
                   jnp.zeros((1,), jnp.int32))
 
 
-def _append_routes():
-    """``kernel_route_total{op="kv_append"}`` as {route: lowerings}."""
+def _append_then_attend(q, ck, cv, kn, vn, pos, mask, H, G, tile=None):
+    """The unfused route on caches [B, H, S, D]: ``kv_append`` of each
+    cache, then the decode kernel on the results (``tile``: through
+    ``_decode_call`` on that tile and not on ``kv_tile``'s)."""
+    B, _, S, D = ck.shape
+    ck, cv = (kv_append(c.swapaxes(2, 3), n, pos, mask,
+                        interpret=True).swapaxes(2, 3)
+              for c, n in ((ck, kn), (cv, vn)))
+    return _attend(q, ck, cv, jnp.minimum(pos + 1, S), H, G, tile), ck, cv
+
+
+def _attend(q, ck, cv, lengths, H, G, tile, append=None):
+    B, _, S, D = ck.shape
+    if tile is None:
+        return flash_attention_decode(
+            q, ck.reshape(B * H, S, D), cv.reshape(B * H, S, D), lengths,
+            num_heads=H, page_size=PAGE, group=G, interpret=True,
+            append=append)
+    if append is not None:
+        append = (*append[:2], (append[2].reshape(B) > 0).astype(jnp.int32))
+    R = 8 * (4 // q.dtype.itemsize)
+    q8 = jnp.concatenate([q, jnp.broadcast_to(
+        q[:, -1:], (B * H, R - q.shape[1], D))], axis=1)
+    out = _decode_call(q8, ck, cv, lengths, tile, minor=True,
+                       scale=D ** -0.5, group=G, q_len=1, interpret=True,
+                       append=append)
+    return out[:, :G] if append is None else (out[0][:, :G], *out[1:])
+
+
+# name: dtype, key/value heads, query heads a group, cache rows, the tile
+# (a number of rows: `kv_tile`'s own, all heads and so many rows)
+IN_KERNEL_APPENDS = {
+    "f32-h12": (jnp.float32, 12, 1, 384, 128),           # GPT-2's tile
+    "bf16-h12-g2-three-pages": (jnp.bfloat16, 12, 2, 384, 384),
+    "f32-four-pages": (jnp.float32, 3, 1, 1024, 512),
+    "f32-head-tile-under-heads": (jnp.float32, 4, 1, 384, (2, 128)),
+    "bf16-two-pages-two-head-groups": (jnp.bfloat16, 4, 1, 512, (2, 256)),
+}
+IN_KERNEL_MASKS = {"mixed": (1, 0, 1, 1, 0, 1, 1), "all-on": (1,) * 7,
+                   "all-off": (0,) * 7, "none": None}
+
+
+@pytest.mark.parametrize("mask", sorted(IN_KERNEL_MASKS))
+@pytest.mark.parametrize("case", sorted(IN_KERNEL_APPENDS))
+def test_decode_kernel_appends_what_append_then_attend_does(case, mask):
+    """``flash_attention_decode(append=...)`` against ``kv_append`` and
+    then the kernel: seven sequences at positions 0, 127, 128, one inside a
+    later block, ``S - 2``, ``S - 1`` and a saturated one past the end
+    (its row clamps onto the last, which is its last live block's); the
+    attention and both caches the same BITS, a sequence whose mask is 0
+    with its caches untouched, a tile of fewer heads than the cache has
+    (two groups of heads a sequence, each writing its own block) and tiles
+    of two and four pages (the column lands in one lane tile of several)
+    among the shapes."""
+    dt, H, G, S, tile = IN_KERNEL_APPENDS[case]
+    B, D = 7, 64
+    if isinstance(tile, int):
+        assert kv_tile(H, S, D, dt, PAGE) == (H, tile)
+        tile = None
+    rng = np.random.default_rng(zlib.crc32(f"{case}/{mask}".encode()))
+    q = jnp.asarray(rng.normal(size=(B * H, G, D)), dt)
+    ck, cv = (jnp.asarray(rng.normal(size=(B, H, S, D)), dt)
+              for _ in range(2))
+    kn, vn = (jnp.asarray(rng.normal(size=(B, H, 1, D)), dt)
+              for _ in range(2))
+    pos = jnp.asarray([0, 127, 128, 300, S - 2, S - 1, S + 40], jnp.int32)
+    keep = IN_KERNEL_MASKS[mask]
+    m = None if keep is None else jnp.asarray(keep, jnp.float32)[:, None]
+    m1 = jnp.ones((B, 1), jnp.float32) if m is None else m
+    want = jax.jit(lambda *a: _append_then_attend(
+        *a, jnp.minimum(pos, S - 1), m, H, G, tile))(q, ck, cv, kn, vn)
+    got = jax.jit(lambda *a: _attend(
+        *a[:3], jnp.minimum(pos + 1, S), H, G, tile,
+        append=(*a[3:], m if tile is None else m1)))(q, ck, cv, kn, vn)
+    for w, g, name in zip(want, got, ("Out", "CacheK", "CacheV")):
+        g = g.reshape(w.shape)
+        assert g.dtype == w.dtype and _bits(g) == _bits(w), name
+    for cache, old, new in ((got[1], ck, kn), (got[2], cv, vn)):
+        cache = cache.reshape(old.shape)
+        for b in range(B):
+            untouched = _bits(cache[b]) == _bits(old[b])
+            assert untouched == (keep is not None and not keep[b])
+            if not untouched:
+                at = min(int(pos[b]), S - 1)
+                np.testing.assert_array_equal(
+                    np.asarray(cache[b, :, at], np.float32),
+                    np.asarray(new[b, :, 0], np.float32))
+
+
+def test_decode_kernel_appends_one_row_to_a_rows_minor_cache_only():
+    """A chunk of rows may cross a block's edge, a whole chunk sees past
+    its first row and a cache of whole lane tiles a head has no column to
+    merge: each is refused by name, so a caller appends first."""
+    f32 = jnp.float32
+    q, c = jnp.zeros((2, 1, 64), f32), jnp.zeros((2, 256, 64), f32)
+    new = jnp.zeros((1, 2, 1, 64), f32)
+    n = jnp.ones((1,), jnp.int32)
+    for kw, qq, cc in [({}, jnp.zeros((2, 2, 64), f32), c),
+                       ({"whole_chunk": True}, q, c),
+                       ({}, jnp.zeros((2, 1, 128), f32),
+                        jnp.zeros((2, 256, 128), f32))]:
+        with pytest.raises(ValueError, match="appends one row a step"):
+            flash_attention_decode(qq, cc, cc, n, num_heads=2,
+                                   append=(new, new, None), **kw)
+
+
+# name: dtype, q_len, query heads a group, head dim, window
+ROUTED_STEPS = {
+    "f32-step": (jnp.float32, 1, 1, 64, 0),
+    "bf16-step-g2": (jnp.bfloat16, 1, 2, 64, 0),
+    "f32-verify-chunk": (jnp.float32, 4, 1, 64, 0),
+    "f32-two-rows": (jnp.float32, 2, 1, 64, 0),
+    "f32-eight-rows-g2": (jnp.float32, 8, 2, 64, 0),
+    "f32-ring": (jnp.float32, 1, 1, 64, 384),
+    "bf16-heads-of-128": (jnp.bfloat16, 1, 1, 128, 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ROUTED_STEPS))
+def test_op_takes_the_in_kernel_append_for_a_step_of_one_row_only(case):
+    """``fused_decode_attention`` on its kernel route picks the writer from
+    what it sees in the shapes, and the counters say which: a step of one
+    row on a rows-minor cache is written by the decode kernel
+    (``IN_KERNEL``), chunks of 2 to 8 rows and a ring there by
+    ``kv_append``, a cache of whole lane tiles a head by neither. Whatever
+    the writer, the op returns what append-then-attend returns, bit for
+    bit: positions 0, 127, 128, inside a later block, ``S - 1`` and a
+    saturated one, the second slot masked out."""
+    from paddle_tpu.core.registry import get_op_def
+    from paddle_tpu.lowering import LowerCtx
+
+    dt, q_len, G, D, window = ROUTED_STEPS[case]
+    B, H, S = 6, 3, 384
+    rng = np.random.default_rng(zlib.crc32(case.encode()))
+    arr = lambda *shape: jnp.asarray(rng.normal(size=shape), dt)
+    q, kn, vn = arr(B, H * G, q_len, D), arr(B, H, q_len, D), arr(
+        B, H, q_len, D)
+    ck, cv = arr(B, H, S, D), arr(B, H, S, D)
+    pos = jnp.asarray([0, 127, 128, 300, S - 1, S + 40], jnp.int32)
+    mask = jnp.asarray([1.0, 0.0, 1.0, 1.0, 1.0, 1.0])[:, None]
+    monitor.reset()
+    fluid.set_flags({"FLAGS_use_flash_attention": "always"})
+    try:
+        got = get_op_def("fused_decode_attention").lower(
+            LowerCtx(platform="cpu"),
+            {"Q": [q], "KNew": [kn], "VNew": [vn], "CacheK": [ck],
+             "CacheV": [cv], "Positions": [pos[:, None]],
+             "SlotMask": [mask]},
+            {"scale": 0.0, "page_size": PAGE, "window": window})
+    finally:
+        fluid.set_flags({"FLAGS_use_flash_attention": "auto"})
+    minor = rows_minor(D, dt, PAGE)
+    in_kernel = minor and q_len == 1 and not window
+    assert _append_routes(IN_KERNEL) == (
+        {"pallas-interpret": 1} if in_kernel else {})
+    assert _append_routes() == (
+        {"pallas-interpret": 1} if minor and not in_kernel else {})
+    # append-then-attend, written out
+    if minor:
+        ck2, cv2 = (_kernel_append(c, n, pos, mask, bool(window))
+                    for c, n in ((ck, kn), (cv, vn)))
+    else:
+        ck2, cv2 = (paged_kv_append_rows(c, n, pos, mask)
+                    for c, n in ((ck, kn), (cv, vn)))
+    q3 = q.reshape(B * H, G, q_len, D).swapaxes(1, 2).reshape(
+        B * H, q_len * G, D)
+    o = flash_attention_decode(
+        q3, ck2.reshape(B * H, S, D), cv2.reshape(B * H, S, D),
+        jnp.minimum(pos + 1, S), num_heads=H, page_size=PAGE, group=G,
+        interpret=True)
+    o = o.reshape(B * H, q_len, G, D).swapaxes(1, 2).reshape(q.shape)
+    assert _bits(got["Out"][0]) == _bits(o)
+    assert _bits(got["CacheKOut"][0]) == _bits(ck2)
+    assert _bits(got["CacheVOut"][0]) == _bits(cv2)
+    assert _bits(got["CacheKOut"][0][1]) == _bits(ck[1])
+
+
+IN_KERNEL = "fused_decode_attention.append_in_kernel"
+
+
+def _append_routes(op="kv_append"):
+    """``kernel_route_total{op=...}`` as {route: lowerings}: ``kv_append``
+    where the append kernel writes a chunk's rows, ``IN_KERNEL`` where the
+    decode kernel writes a step's one row itself."""
     fam = monitor.get_registry().get("kernel_route_total")
     out = {}
     for labels, ctr in (fam.children() if fam is not None else ()):
-        if labels["op"] == "kv_append":
+        if labels["op"] == op:
             out[labels["route"]] = out.get(labels["route"], 0) + int(
                 ctr.value)
     return out
@@ -325,8 +508,13 @@ def test_op_appends_and_attends_in_one_view(case):
             fluid.set_flags({"FLAGS_use_flash_attention": "auto"})
     assert sorted(got) == ["pallas-interpret", "primitive"]
     assert rows_minor(D, dt, PAGE)
-    # the rows-minor append is the kernel's, and counted where it engages
-    assert _append_routes() == {"pallas-interpret": 1}
+    # the rows-minor append is a kernel's, and counted where it engages:
+    # the decode kernel's own for a step of one row, `kv_append` for a
+    # chunk of rows and for a ring
+    in_kernel = q_len == 1 and not window
+    assert _append_routes() == ({} if in_kernel else {"pallas-interpret": 1})
+    assert _append_routes(IN_KERNEL) == (
+        {"pallas-interpret": 1} if in_kernel else {})
     for name in ("CacheKOut", "CacheVOut"):
         a, b = (np.asarray(got[r][name][0], np.float32) for r in sorted(got))
         assert a.tobytes() == b.tobytes()
@@ -360,39 +548,69 @@ def _trace_for_tpu(program, fetch):
         "feed_order", "donated", "ro")), jax.random.key(0))
 
 
-def _decoder(name):
-    from paddle_tpu.models import cohere_moe, glm4_moe_lite, qwen3_next
+def _decoder(name, **kw):
+    from paddle_tpu.models import (cohere_moe, glm4_moe_lite,
+                                   granite_moe_hybrid, qwen3_next, sdar_moe)
 
     if name == "gpt-heads-of-64":
         cfg = GptConfig(vocab_size=64, hidden_size=128, num_layers=3,
                         num_heads=2, intermediate_size=64, max_position=256)
         return build_gpt_generative(cfg, batch_slots=2, max_seq=256,
-                                    page_size=128, prompt_buckets=(128,))
+                                    page_size=128, prompt_buckets=(128,),
+                                    **kw)
     if name == "gpt-tiny":
         return build_gpt_generative()
     return {"cohere-moe": cohere_moe.build_cohere_moe_generative,
             "qwen3-next": qwen3_next.build_qwen3_next_generative,
-            "glm4-moe-lite": glm4_moe_lite.build_glm4_moe_lite_generative}[
+            "glm4-moe-lite": glm4_moe_lite.build_glm4_moe_lite_generative,
+            "sdar-moe": sdar_moe.build_sdar_moe_generative,
+            "granite-moe-hybrid":
+                granite_moe_hybrid.build_granite_moe_hybrid_generative}[
                 name]()
 
 
 @pytest.mark.parametrize("name,appends", [
     ("gpt-heads-of-64", 3), ("gpt-tiny", 0), ("cohere-moe", 0),
-    ("qwen3-next", 0), ("glm4-moe-lite", 0)])
+    ("qwen3-next", 0), ("glm4-moe-lite", 0), ("sdar-moe", 0),
+    ("granite-moe-hybrid", 0)])
 def test_kv_append_route_counts_the_layers_that_take_the_kernel(name,
                                                                 appends):
-    """``kernel_route_total{op="kv_append"}`` for a decode program lowered
-    for a TPU: one a layer where the caches are worked on rows-minor
-    (heads of 64 in pages of 128), none for the other decoders' caches
-    (the tiny ones here, heads of 128 and 256 or a latent cache at the
-    published widths) though their decode attention rides its kernel."""
+    """The rows-minor append's two counters for a decode program lowered
+    for a TPU: where the caches are worked on rows-minor (heads of 64 in
+    pages of 128) the decode kernel writes the step's row itself, one
+    ``IN_KERNEL`` a layer and no ``kv_append``; neither for the other
+    decoders' caches (the tiny ones here, heads of 128 and 256 or a latent
+    cache at the published widths) though their decode attention rides
+    its kernel."""
     with un.guard():
         net = _decoder(name)
     monitor.reset()
-    _trace_for_tpu(net["decode"]["main"], net["decode"]["next_token"])
-    assert _append_routes() == ({"pallas": appends} if appends else {})
+    dec = net["decode"]     # a decoder by blocks yields tokens, not one
+    _trace_for_tpu(dec["main"], dec["next_token"] if "next_token" in dec
+                   else dec["yield"]["tokens"])
+    assert _append_routes() == {}
+    assert _append_routes(IN_KERNEL) == ({"pallas": appends} if appends
+                                         else {})
     fam = monitor.get_registry().get("kernel_route_total")
     assert any(labels["route"] == "pallas" for labels, _ in fam.children())
+
+
+@pytest.mark.parametrize("program,fetch,slice_rows,appends", [
+    ("verify", "sampled", 128, 3), ("chunk", "first_token", 8, 3),
+    ("chunk", "first_token", 128, 0)])
+def test_chunk_programs_keep_the_append_kernel(program, fetch, slice_rows,
+                                               appends):
+    """GPT-2's verify chunk (4 rows a slot) and a chunked-prefill slice of
+    8 rows on rows-minor caches: a chunk may cross a block's edge, so its
+    rows go through ``kv_append`` before the decode kernel, which appends
+    nothing. A slice of a page of rows takes the primitive route, and
+    neither counter."""
+    with un.guard():
+        net = _decoder("gpt-heads-of-64", prefill_chunk=slice_rows)
+    monitor.reset()
+    _trace_for_tpu(net[program]["main"], net[program][fetch])
+    assert _append_routes(IN_KERNEL) == {}
+    assert _append_routes() == ({"pallas": appends} if appends else {})
 
 
 def test_tile_is_whole_pages_of_whole_heads_inside_its_budget():

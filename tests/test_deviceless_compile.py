@@ -195,37 +195,80 @@ def test_masked_append_keeps_the_scan_carry_in_place(v5e, form):
     assert (found == []) if form == "op" else len(found) >= 2
 
 
-def test_gpt2_chained_decode_appends_by_one_aliased_call_a_cache(v5e):
+def test_gpt2_chained_decode_appends_inside_the_decode_kernel(v5e):
     """The serving cell's geometry (64 slots x 12 heads x 1,024 rows x 64,
     f32; two layers of the twelve, a chunk of 4 steps): the runtime stores
     such a cache rows in lanes, the step appends and attends in that view,
     so no ``copy`` of a cache stands at the program's entry, at its exit or
     in the loop (two conversions a cache and 9.86 GB of scratch at twelve
-    layers before PR 32). The append is one ``kv_append`` call a layer and
-    cache, its cache operand aliased to its result: the scan is the one
-    ``while`` left (a loop over the sequences a cache before PR 34),
-    nothing else produces a whole cache, and the compiler holds no
-    scratch for one."""
+    layers before PR 32). The step's row is written by the decode kernel
+    itself (PR 45): no ``kv_append`` call (one a layer and cache since PR
+    34, a loop over the sequences a cache before), one ``decode_attention``
+    call a layer whose K and V cache operands are aliased to its second
+    and third results (they stay in HBM, and the kernel copies the block
+    it merged into them itself); the scan is the one ``while``, nothing
+    else produces a whole cache, and the compiler holds no scratch for
+    one."""
     compiled = _compiled_chunk(v5e, 64, 12, 1024, 64, "op", layers=2)
     text = compiled.as_text()
     shape = (64, 12, 1024, 64)
     assert _whole_cache_work(text, shape) == []
     assert _whole_cache_work(text, shape, "entry") == []
     assert len(re.findall(r" while\(", text)) == 1
-    assert len(re.findall(r"%decode_attention[.\d]* = ", text)) == 2
-    cache = r"f32\[64,12,(?:1024,64|64,1024)\]\S* "
-    appends = re.findall(
-        r"%kv_append[.\d]* = " + cache + r"custom-call\(([^)]*)\)"
-        r"[^\n]*output_to_operand_aliasing=\{\{\}: \((\d+), \{\}\)\}", text)
-    assert len(appends) == 4
-    for operands, aliased in appends:
-        assert int(aliased) == len(operands.split(", ")) - 1 == 3
-    made = re.findall(r"%([a-zA-Z_\-]+)[.\w]* = " + cache + r"([a-z\-]+)\(",
+    assert not re.search(r"%kv_append[.\d]* = ", text)
+    cache = r"f32\[64,12,(?:1024,64|64,1024)\]\S*"
+    calls = re.findall(
+        r"%decode_attention[.\d]* = \((\S+), " + cache + ", " + cache
+        + r"\) custom-call\(([^)]*)\)[^\n]*output_to_operand_aliasing="
+        r"\{\{1\}: \((\d+), \{\}\), \{2\}: \((\d+), \{\}\)\}", text)
+    assert len(calls) == 2
+    for out, operands, k_at, v_at in calls:
+        # lengths, keep, q, K cache, V cache, K columns, V columns
+        assert out.startswith("f32[768,8,64]")
+        assert len(operands.split(", ")) == 7
+        assert (int(k_at), int(v_at)) == (3, 4)
+    made = re.findall(r"%([a-zA-Z_\-]+)[.\w]* = " + cache + r" ([a-z\-]+)\(",
                       text)
     assert {op for _, op in made} <= {"parameter", "get-tuple-element",
-                                      "bitcast", "custom-call"}
-    assert [n for n, op in made if op == "custom-call"] == ["kv_append"] * 4
+                                      "bitcast"}
     assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
+
+
+def test_gpt2_verify_chunk_keeps_one_append_call_a_row_and_cache(v5e):
+    """A chunk of rows on the same caches (the speculative-verify chunk, 4
+    rows a slot; one layer) may cross a block's edge: each row of each
+    cache is one ``kv_append`` call whose cache operand is aliased to its
+    result, the decode kernel after them appends nothing, and nothing
+    else produces a whole cache."""
+    from paddle_tpu.core.registry import get_op_def
+    from paddle_tpu.lowering import LowerCtx
+
+    B, H, S, D, C = 64, 12, 1024, 64, 4
+
+    def step(q, kn, vn, ck, cv, pos, mask):
+        got = get_op_def("fused_decode_attention").lower(
+            LowerCtx(platform="tpu"),
+            {"Q": [q], "KNew": [kn], "VNew": [vn], "CacheK": [ck],
+             "CacheV": [cv], "Positions": [pos], "SlotMask": [mask]},
+            {"scale": 0.0, "page_size": 128})
+        return got["Out"][0], got["CacheKOut"][0], got["CacheVOut"][0]
+
+    rows, cache = v5e((B, H, C, D), jnp.float32), v5e((B, H, S, D),
+                                                      jnp.float32)
+    text = jax.jit(step, donate_argnums=(3, 4)).lower(
+        rows, rows, rows, cache, cache, v5e((B, 1), jnp.int32),
+        v5e((B, 1), jnp.float32)).compile().as_text()
+    assert _whole_cache_work(text, (B, H, S, D), "entry") == []
+    shape = r"f32\[64,12,(?:1024,64|64,1024)\]\S* "
+    appends = re.findall(
+        r"%kv_append[.\d]* = " + shape + r"custom-call\(([^)]*)\)"
+        r"[^\n]*output_to_operand_aliasing=\{\{\}: \((\d+), \{\}\)\}", text)
+    assert len(appends) == 2 * C
+    for operands, aliased in appends:
+        assert int(aliased) == len(operands.split(", ")) - 1 == 3
+    attends = re.findall(r"%decode_attention[.\d]* = (\S+) custom-call\(",
+                         text)
+    assert len(attends) == 1 and attends[0].startswith("f32[768,8,64]")
 
 
 # name: query heads, key/value heads, cache rows, head dim, window

@@ -673,6 +673,70 @@ def test_more_newcomers_than_rows_take_several_dispatches_same_tokens():
         assert dispatches == sum(-(-n // rows) for n in turns), (rows, turns)
 
 
+def _serve_heads_of_64(mode, prompts, budgets):
+    """The streamed tokens of ``prompts`` from an engine of 3 slots over a
+    GPT of two layers whose caches are worked on rows in lanes (heads of
+    64 in pages of 128), every kernel-routed op on the route that
+    ``FLAGS_use_flash_attention=mode`` gives it on the CPU, and the
+    lowerings ``kernel_route_total`` counted, as {(op, route): n}."""
+    cfg = GptConfig(vocab_size=96, hidden_size=128, num_layers=2,
+                    num_heads=2, intermediate_size=64, max_position=256)
+    fluid.set_flags({"FLAGS_use_flash_attention": mode})
+    monitor.reset()
+    try:
+        with un.guard():
+            net = build_gpt_generative(cfg, batch_slots=3, max_seq=256,
+                                       page_size=128, prompt_buckets=(128,),
+                                       spec_k=0)
+        exe, scope = fluid.Executor(fluid.CPUPlace()), fluid.Scope()
+        exe.run(net["startup"], scope=scope)
+        _seed_weights(net, scope)
+        eng = serving.GenerativeEngine(
+            net, scope=scope, executor=exe,
+            config=serving.ServingConfig(max_batch=3, queue_depth=64,
+                                         deadline_s=0),
+            gen_config=serving.GenerationConfig(
+                decode_chunk=4, prefix_cache=False, chunked_prefill=False))
+        eng.warm_up()
+        with eng:
+            futs = [eng.submit(p, max_new_tokens=m)
+                    for p, m in zip(prompts, budgets)]
+            out = [list(f.result(timeout=600)[0]) for f in futs]
+        assert eng.accounting()["exact"] and eng.decode_recompiles == 0
+    finally:
+        fluid.set_flags({"FLAGS_use_flash_attention": "auto"})
+    routes = {}
+    for labels, ctr in monitor.get_registry().get(
+            "kernel_route_total").children():
+        key = labels["op"], labels["route"]
+        routes[key] = routes.get(key, 0) + int(ctr.value)
+    return out, routes
+
+
+def test_engine_streams_the_same_tokens_with_the_row_written_in_kernel():
+    """Four requests on three slots through the chained decode dispatch,
+    their contexts crossing from the first block of 128 rows into the
+    second while they decode, one of them seated in a slot another left
+    (its neighbours' rows masked meanwhile): with the decode kernel
+    writing each step's K/V row itself (under the interpreter; two
+    layers a lowering of the decode program, no ``kv_append``) the engine
+    streams what it streams on
+    the primitive route, where the rows are appended on the declared
+    shape, token for token."""
+    rng = np.random.default_rng(45)
+    sizes = [(120, 20), (126, 9), (90, 50), (127, 12)]
+    prompts = [rng.integers(1, 96, n) for n, _ in sizes]
+    budgets = [m for _, m in sizes]
+    want, plain = _serve_heads_of_64("never", prompts, budgets)
+    got, routes = _serve_heads_of_64("always", prompts, budgets)
+    assert got == want and [len(o) for o in got] == budgets
+    in_kernel = "fused_decode_attention.append_in_kernel"
+    n = routes[(in_kernel, "pallas-interpret")]
+    assert n >= 2 and n % 2 == 0
+    assert not any(op == "kv_append" for op, _ in routes)
+    assert not any(op in (in_kernel, "kv_append") for op, _ in plain)
+
+
 def test_recompile_guard_counts_warm_bucket_growth(serving_net):
     """Regression: a NEW executable appearing for an already-compiled
     (phase, bucket)'s program is a counted recompile — KV growth must

@@ -1,6 +1,6 @@
 #!/usr/bin/env python3
 """What the decode step's K/V append costs at GPT-2's geometry, by the form
-that writes it (PERF.md section 6, PRs 32, 33 and 34).
+that writes it (PERF.md section 6, PRs 32, 33, 34 and 45).
 
     chiprun -- python3 tools/probe_kv_append.py [--layers 12] [--attend]
     python3 tools/probe_kv_append.py --deviceless        # compiles only
@@ -15,7 +15,11 @@ appending one row a sequence to each. The forms:
   rows-minor view (the library's form until PR 34; kept here, where the
   library has dropped it, so the comparison can be repeated);
 * ``kernel``: ``kernels.kv_append``, one Pallas call a cache that aliases
-  it, a grid step a sequence.
+  it, a grid step a sequence (the library's form for a chunk of rows);
+* ``fused`` (with ``--attend``): no append call at all, the decode kernel
+  merges the column into the last live block it fetches and copies that
+  block back itself (``flash_attention_decode(append=...)``: the library's
+  form for a step of one row since PR 45).
 
 ``--attend`` runs the decode kernel on the appended caches too, a whole
 attention layer of a decode step. A step is timed as the wall time of a
@@ -62,6 +66,7 @@ def column_loop(cache, new, positions, mask):
 FORMS = {
     "loop": column_loop,
     "kernel": kv_append,
+    "fused": None,          # the decode kernel appends: --attend only
 }
 
 
@@ -69,16 +74,22 @@ def chunk_of(form: str, steps: int, attend: bool):
     """``steps`` decode steps over every layer's K and V cache (logical
     shape, as the program declares them), positions advancing."""
     append = FORMS[form]
+    fused = append is None
 
     def layer(ck, cv, q, new, pos, mask):
         at = jnp.minimum(pos, S - 1)
-        ck, cv = (append(c.swapaxes(2, 3), new, at, mask).swapaxes(2, 3)
-                  for c in (ck, cv))
+        if not fused:
+            ck, cv = (append(c.swapaxes(2, 3), new, at, mask).swapaxes(2, 3)
+                      for c in (ck, cv))
         if attend:
             o = flash_attention_decode(
                 q.reshape(B * H, 1, D), ck.reshape(B * H, S, D),
                 cv.reshape(B * H, S, D), jnp.minimum(pos + 1, S),
-                num_heads=H, page_size=PAGE)
+                num_heads=H, page_size=PAGE,
+                append=(new, new, mask) if fused else None)
+            if fused:
+                o, ck, cv = o[0], o[1].reshape(ck.shape), o[2].reshape(
+                    cv.shape)
             q = q + (o * 0).reshape(q.shape)
         return ck, cv, q
 
@@ -132,6 +143,8 @@ def main(argv=None) -> int:
     n = 2 * args.layers
     results = []
     for form in args.form or sorted(FORMS):
+        if FORMS[form] is None and not args.attend:
+            continue        # nothing appends where nothing attends
         line = {"form": form, "layers": args.layers, "attend": args.attend,
                 "appends_a_step": n * B}
         if args.deviceless:
