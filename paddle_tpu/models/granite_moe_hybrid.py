@@ -29,34 +29,33 @@ Layer ``i`` is what ``layer_types[i]`` says: ``mamba`` (nine in ten) or
           normalised over them; the held experts' part of the routed sum
           plus one shared expert of its own width, added.
 
-What is held here is what ``models/cohere_moe.py`` holds of its model:
-``experts_held`` routed experts from ``expert_offset``, the mixers and the
-shared expert whole, a slice of the vocabulary; bf16 storage, bf16 matmul
-operands with f32 accumulation; norms, router, ``dt``, decay, the scan and
-the residual stream f32. The feed-forward, the embedding, the head's
-product, the state table's maker and the phase's commits are that module's,
-by import.
+What is held here is ONE chip's share: ``experts_held`` routed experts
+from ``expert_offset``, the mixers and the shared expert whole, a slice of
+the vocabulary; bf16 storage, bf16 matmul operands with f32 accumulation;
+norms, router, ``dt``, decay, the scan and the residual stream f32.
 
-The block is written once (:func:`_block`) for both phases; a phase hands
-it a ``mix`` handle with ``attend`` and ``recur``, as ``qwen3_next`` does.
+The block is written once (:func:`_block`) for both phases, which are
+``models/decoder.py``'s; a phase hands it a ``mix`` handle with ``attend``
+(a K/V cache pair's) and ``recur`` (the scan).
 The state table holds two kinds of state: ``full`` (a K/V pair of
 ``max_seq`` rows) and ``recurrent``.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Optional, Tuple
 
 from .. import layers
-from ..framework import Program, program_guard
+from ..framework import default_main_program
 from ..initializer import Constant, Uniform
 from ..layer_helper import LayerHelper
-from .cohere_moe import (PREFILL_FEEDS, _attr, _commit_decode,
-                         _commit_prefill, _embed, _ffn, _generative, _logits,
-                         _prefill_feeds, _proj, _proj_out, _split_heads,
-                         _state_table)
-from .qwen3_next import _f32_param, _Mix
+from ..ops.gdn import count_rule_stats
+from ..ops.moe import expert_counter
+from . import decoder
+from .decoder import (Mix, attr, f32_param, ffn, proj, proj_out,
+                      split_heads)
 
 __all__ = ["GraniteMoeHybridConfig", "build_granite_moe_hybrid_generative"]
 
@@ -151,39 +150,37 @@ class GraniteMoeHybridConfig:
 
 
 def _norm(x, name: str, cfg: GraniteMoeHybridConfig, dim: int):
-    return layers.rms_norm(x, _f32_param(f"{name}_scale", [dim],
-                                         Constant(1.0)),
-                           epsilon=cfg.rms_norm_eps)
+    return decoder.norm(x, name, cfg, dim, zero_centered=False)
 
 
 def _attention(hb, p: str, S: int, cfg: GraniteMoeHybridConfig, attend,
                i: int):
     nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    q = _split_heads(_proj(hb, nh * hd, f"{p}_q", cfg), S, nh, hd)
-    k = _split_heads(_proj(hb, nkv * hd, f"{p}_k", cfg), S, nkv, hd)
-    v = _split_heads(_proj(hb, nkv * hd, f"{p}_v", cfg), S, nkv, hd)
+    q = split_heads(proj(hb, nh * hd, f"{p}_q", cfg), S, nh, hd)
+    k = split_heads(proj(hb, nkv * hd, f"{p}_k", cfg), S, nkv, hd)
+    v = split_heads(proj(hb, nkv * hd, f"{p}_v", cfg), S, nkv, hd)
     ctx = attend(i, q, k, v)                                  # [B, nh, S, hd]
     ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
                          [0, S, nh * hd])
-    return _proj_out(ctx, cfg.hidden_size, f"{p}_out", cfg)
+    return proj_out(ctx, cfg.hidden_size, f"{p}_out", cfg)
 
 
 def _mamba(hb, p: str, S: int, cfg: GraniteMoeHybridConfig, recur, i: int):
     H, C, di = cfg.mamba_n_heads, cfg.conv_channels, cfg.d_inner
-    z, xbc, dt = layers.split(_proj_out(hb, di + C + H, f"{p}_in", cfg),
+    z, xbc, dt = layers.split(proj_out(hb, di + C + H, f"{p}_in", cfg),
                               [di, C, H], dim=2)
     conv_w = LayerHelper("granite_moe_hybrid").create_parameter(
-        _attr(f"{p}_conv_w", cfg), [C, cfg.mamba_d_conv], cfg.dtype)
-    conv_b = _f32_param(f"{p}_conv_b", [C], Constant(0.0))
+        attr(f"{p}_conv_w", cfg), [C, cfg.mamba_d_conv], cfg.dtype)
+    conv_b = f32_param(f"{p}_conv_b", [C], Constant(0.0))
     # Mamba-2's own start: A from 1 to 16, dt from 0.001 to 0.1 (the
     # softplus of dt_bias), the skip at 1
-    a_log = _f32_param(f"{p}_a_log", [H], Uniform(0.0, math.log(16.0)))
-    dt_bias = _f32_param(f"{p}_dt_bias", [H], Uniform(-6.9, -2.25))
-    skip = _f32_param(f"{p}_d", [H], Constant(1.0))
+    a_log = f32_param(f"{p}_a_log", [H], Uniform(0.0, math.log(16.0)))
+    dt_bias = f32_param(f"{p}_dt_bias", [H], Uniform(-6.9, -2.25))
+    skip = f32_param(f"{p}_d", [H], Constant(1.0))
     y, stats = recur(i, xbc, conv_w, conv_b, dt, a_log, dt_bias, skip)
     y = _norm(layers.elementwise_mul(y, layers.swish(z)), f"{p}_gnorm", cfg,
               di)
-    return _proj_out(layers.cast(y, cfg.dtype), cfg.hidden_size,
+    return proj_out(layers.cast(y, cfg.dtype), cfg.hidden_size,
                      f"{p}_out", cfg), stats
 
 
@@ -204,38 +201,45 @@ def _block(x, i: int, cfg: GraniteMoeHybridConfig, real, mix):
         mixed, scan = _mamba(hb, p, S, cfg, mix.recur, i)
     x = layers.elementwise_add(x, layers.scale(mixed, scale=r))
     h = _norm(x, f"{p}_ln_post", cfg, H)
-    routed, shared, stats = _ffn(h, layers.cast(h, cfg.dtype), p, cfg, real,
+    routed, shared, stats = ffn(h, layers.cast(h, cfg.dtype), p, cfg, real,
                                  join="sum")
     x = layers.elementwise_add(
         x, layers.scale(layers.elementwise_add(routed, shared), scale=r))
     return x, stats, scan
 
 
-def _embedded(ids, cfg: GraniteMoeHybridConfig):
+def _embed(ids, cfg: GraniteMoeHybridConfig):
     """``embedding_multiplier`` times the embedding's rows. The embedding is
     the head too, so it is drawn at a range of its own: at the matrices'
     range the multiplier makes every token's best successor itself."""
     own = dataclasses.replace(cfg, initializer_range=cfg.embedding_range)
-    return layers.scale(_embed(ids, own, f"{_P}_word_emb"),
+    return layers.scale(decoder.embed(ids, own, f"{_P}_word_emb"),
                         scale=float(cfg.embedding_multiplier))
 
 
-def _stack_layers(x, cfg: GraniteMoeHybridConfig, real, mix):
-    stats, scans = [], []
+def _stack_layers(x, cfg: GraniteMoeHybridConfig, positions, real, mix):
+    """``positions`` go unread: neither kind of layer has a positional
+    signal."""
+    stats, scans, mamba = [], [], []
     for i in range(cfg.num_layers):
         x, s, r = _block(x, i, cfg, real, mix)
         stats.append(s)
         if r is not None:
             scans.append(r)
-    return (_norm(x, f"{_P}_lnf", cfg, cfg.hidden_size),
-            layers.stack(stats, axis=0),
-            layers.stack(scans, axis=0) if scans else None)
+            mamba.append(i)
+    h = _norm(x, f"{_P}_lnf", cfg, cfg.hidden_size)
+    experts = layers.stack(stats, axis=0)
+    return h, [
+        ("expert_stats", experts, expert_counter(experts)),
+        ("rule_stats", layers.stack(scans, axis=0) if scans else None,
+         functools.partial(count_rule_stats, layers=mamba, family="ssm"))]
 
 
-def _head(h2d, cfg: GraniteMoeHybridConfig, block):
+def _head(h2d, cfg: GraniteMoeHybridConfig):
     """The tied head: the embedding's held rows, logits over the slice."""
-    return _logits(h2d, cfg, block.var(f"{_P}_word_emb"),
-                   1.0 / cfg.logits_scaling)
+    return decoder.logits(
+        h2d, cfg, default_main_program().global_block.var(f"{_P}_word_emb"),
+        1.0 / cfg.logits_scaling)
 
 
 def _state_vars(block, cfg: GraniteMoeHybridConfig, batch_slots: int,
@@ -245,7 +249,7 @@ def _state_vars(block, cfg: GraniteMoeHybridConfig, batch_slots: int,
     head_dim]`` in ``cfg.dtype``; ``recurrent`` the scan's ``[slots, heads,
     head dim, state dim]`` and the convolution's tail ``[slots, taps - 1,
     channels]``, both f32."""
-    mk, sv, tok, pos, active = _state_table(block, _P, batch_slots)
+    mk, sv, tok, pos, active = decoder.state_table(block, _P, batch_slots)
     kinds, layer_state = {}, []
     for i in range(cfg.num_layers):
         if cfg.layer_types[i] == ATTENTION:
@@ -275,71 +279,20 @@ def _scan(cfg: GraniteMoeHybridConfig, state, mask, mode, **slots):
     return recur
 
 
-def _build_prefill(cfg, B, R, S, max_seq, sample, startup):
-    """The full-sequence phase for one prompt bucket: ``R`` sequences a
-    dispatch, each naming its slot (``cohere_moe._prefill_feeds``). An
-    attention layer writes the bucket into the slot's cache at row 0; a
-    Mamba-2 layer scans the prompt from a zero state and overwrites the
+def _prefill_handle(cfg, state, pmask, plen, smask, slots, page_size):
+    """An attention layer writes the bucket into the slot's cache at row 0;
+    a Mamba-2 layer scans the prompt from a zero state and overwrites the
     slot's."""
-    main = Program()
-    with program_guard(main, startup):
-        ids, _, pmask, plen, smask, slots = _prefill_feeds(R, S)
-        tok, pos, active, state, sv, _ = _state_vars(
-            main.global_block, cfg, B, max_seq)
-        bias = layers.unsqueeze(
-            layers.scale(pmask, scale=10000.0, bias=-10000.0), [1, 2])
-        zero_pos = layers.fill_constant([R, 1], "int64", 0)
-
-        def attend(i, q, k, v):
-            for cache, new in zip(state[i], (k, v)):
-                layers.kv_cache_append(cache, new, zero_pos, slot_mask=smask,
-                                       slots=slots)
-            return layers.fused_multihead_attention(
-                q, k, v, bias_qk=bias, causal=True,
-                scale=cfg.attention_multiplier, is_test=True)
-
-        mix = _Mix(attend, _scan(cfg, state, pmask, "scan", slots=slots,
-                                 slot_mask=smask))
-        real = layers.elementwise_mul(pmask, smask, axis=0)
-        h, stats, scans = _stack_layers(_embedded(ids, cfg), cfg, real, mix)
-        one = layers.fill_constant([R, 1], "int64", 1)
-        last_h = layers.sequence_gather(h, layers.elementwise_sub(plen, one))
-        logits = _head(last_h, cfg, main.global_block)
-        first_tok = layers.sample_token(logits, **sample)
-        _commit_prefill(tok, pos, active, slots, first_tok, plen, smask)
-    return {"main": main, "first_token": first_tok, "state_vars": sv,
-            "last_logits": logits, "expert_stats": stats,
-            "rule_stats": scans, "rows": R, "feeds": PREFILL_FEEDS}
+    return Mix(decoder.bulk_attend(state, pmask, smask, slots,
+                                   cfg.attention_multiplier),
+               _scan(cfg, state, pmask, "scan", slots=slots,
+                     slot_mask=smask))
 
 
-def _build_decode(cfg, B, max_seq, page_size, sample):
-    """The per-token phase: no feeds, everything is persistable state."""
-    main = Program()
-    with program_guard(main, Program()):
-        tok, pos, active, state, sv, kinds = _state_vars(
-            main.global_block, cfg, B, max_seq)
-
-        def attend(i, q, k, v):
-            ck, cv = state[i]
-            return layers.fused_decode_attention(
-                q, k, v, ck, cv, pos, scale=cfg.attention_multiplier,
-                page_size=page_size, slot_mask=active)
-
-        mix = _Mix(attend, _scan(cfg, state, active, "step"))
-        x = layers.unsqueeze(_embedded(tok, cfg), [1])
-        h, stats, scans = _stack_layers(x, cfg, active, mix)
-        logits = _head(layers.reshape(h, [0, cfg.hidden_size]), cfg,
-                       main.global_block)
-        next_tok = layers.sample_token(logits, **sample)
-        _commit_decode(tok, pos, active, next_tok, max_seq)
-    return {"main": main, "next_token": next_tok, "state_vars": sv,
-            "logits": logits, "expert_stats": stats, "rule_stats": scans,
-            "rule_layers": [i for i in range(cfg.num_layers)
-                            if cfg.layer_types[i] == MAMBA],
-            "rule_family": "ssm",
-            "cache_kinds": kinds,
-            "cache_vars": [tuple(v.name for v in pair) for pair in state],
-            "active_var": active.name}
+def _decode_handle(cfg, state, pos, active, page_size):
+    return Mix(decoder.step_attend(state, pos, active,
+                                   cfg.attention_multiplier, page_size),
+               _scan(cfg, state, active, "step"))
 
 
 def build_granite_moe_hybrid_generative(
@@ -347,26 +300,13 @@ def build_granite_moe_hybrid_generative(
         max_seq: int = 64, page_size: int = 8, prompt_buckets=(16,),
         strategy: str = "greedy", temperature: float = 1.0, top_k: int = 0,
         prefill_rows: int = None):
-    """What ``serving.GenerativeEngine`` needs, as
-    ``build_cohere_moe_generative`` returns it. ``prefill_rows``: the
-    sequences a prefill dispatch carries, each naming its slot (default:
-    one per slot). No chunk or verify program: a prompt has to fit a
-    bucket, and a bucket the cache."""
+    """What ``serving.GenerativeEngine`` needs
+    (``decoder.build_generative``). ``prefill_rows``: the sequences a
+    prefill dispatch carries, each naming its slot (default: one per
+    slot)."""
     cfg = cfg or GraniteMoeHybridConfig.tiny()
-    prompt_buckets = tuple(sorted(set(int(b) for b in prompt_buckets)))
-    if not prompt_buckets or prompt_buckets[-1] > max_seq:
-        raise ValueError(f"prompt buckets {prompt_buckets} for a cache of "
-                         f"{max_seq} rows")
-    if max_seq % page_size:
-        raise ValueError(f"max_seq {max_seq} must be a whole number of "
-                         f"pages of page_size {page_size}")
-    rows = int(prefill_rows or batch_slots)
-    if not 1 <= rows <= batch_slots:
-        raise ValueError(f"prefill_rows {rows} for {batch_slots} slots")
-    sample = dict(strategy=strategy, temperature=temperature, top_k=top_k)
-    startup = Program()
-    prefill = {S: _build_prefill(cfg, batch_slots, rows, S, max_seq, sample,
-                                 startup) for S in prompt_buckets}
-    decode = _build_decode(cfg, batch_slots, max_seq, page_size, sample)
-    return _generative(cfg, startup, prefill, decode, batch_slots, max_seq,
-                       page_size, strategy)
+    parts = decoder.Parts(cfg, _state_vars, _embed, _stack_layers, _head,
+                          _prefill_handle, _decode_handle)
+    return decoder.build_from_parts(parts, batch_slots, max_seq, page_size,
+                                    prompt_buckets, prefill_rows, strategy,
+                                    temperature, top_k)

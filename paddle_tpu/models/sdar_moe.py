@@ -34,27 +34,26 @@ rows its forward wrote are the K/V of its final tokens, its tokens go out,
 and the slot moves on L rows. So a forward yields 0 to L tokens a slot,
 and ``denoising_steps + 1`` forwards make a block.
 
-What is held here is what ``models/cohere_moe.py`` holds of its model
-(``experts_held`` routed experts from ``expert_offset``; bf16 storage, bf16
-matmul operands with f32 accumulation; norms, router, softmax, confidence
-and the residual stream f32); the feed-forward, the embedding, the head,
-the state table's maker and the prefill's feeds are that module's, the
-norm ``models/qwen3_next.py``'s, by import. The block is written once
-(:func:`_block`) over an ``attend`` handle, as there.
+What is held here is ONE chip's share (``experts_held`` routed experts from
+``expert_offset``; bf16 storage, bf16 matmul operands with f32 accumulation;
+norms, router, softmax, confidence and the residual stream f32). The block
+is written once (:func:`_block`) over an ``attend`` handle. The two phases
+are this module's own over the parts of ``models/decoder.py``: the prefill
+samples nothing and seeds the slot's block state, the decode forward carries
+a block and yields.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Optional
 
 from .. import layers
 from ..framework import Program, program_guard
-from ..layer_helper import LayerHelper
-from .cohere_moe import (PREFILL_FEEDS, _attr, _embed, _ffn, _generative,
-                         _logits, _prefill_feeds, _proj, _proj_out,
-                         _split_heads, _state_table)
-from .qwen3_next import _norm
+from ..ops.moe import expert_counter
+from . import decoder
+from .decoder import ffn, proj, proj_out, split_heads
 
 __all__ = ["SdarMoeConfig", "build_sdar_moe_generative"]
 
@@ -82,7 +81,7 @@ class SdarMoeConfig:
     initializer_range: float = 0.02
     dtype: str = "bfloat16"
     score_fn: str = "softmax"            # the router's, as ``moe_experts``
-    num_shared_experts: int = 0          # what ``cohere_moe._ffn`` reads
+    num_shared_experts: int = 0          # what ``decoder.ffn`` reads
 
     def __post_init__(self):
         if self.experts_held is None:
@@ -117,23 +116,23 @@ def _block(x, i: int, cfg: SdarMoeConfig, positions, real, attend):
     p = f"{_P}_l{i}"
     S, H = x.shape[1], cfg.hidden_size
     nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    norm = lambda t, name, dim: _norm(t, f"{p}_{name}", cfg, dim,
-                                      zero_centered=False)
+    norm = lambda t, name, dim: decoder.norm(t, f"{p}_{name}", cfg, dim,
+                                             zero_centered=False)
     hb = layers.cast(norm(x, "ln_in", H), cfg.dtype)
     # q and k keep the f32 accumulator on their way into a norm
-    q = layers.reshape(_proj_out(hb, nh * hd, f"{p}_q", cfg), [0, S, nh, hd])
-    k = layers.reshape(_proj_out(hb, nkv * hd, f"{p}_k", cfg),
+    q = layers.reshape(proj_out(hb, nh * hd, f"{p}_q", cfg), [0, S, nh, hd])
+    k = layers.reshape(proj_out(hb, nkv * hd, f"{p}_k", cfg),
                        [0, S, nkv, hd])
-    v = _split_heads(_proj(hb, nkv * hd, f"{p}_v", cfg), S, nkv, hd)
+    v = split_heads(proj(hb, nkv * hd, f"{p}_v", cfg), S, nkv, hd)
     rot = lambda t: layers.cast(layers.rotary_embedding(
         layers.transpose(t, [0, 2, 1, 3]), positions, theta=cfg.rope_theta,
         pairing="half"), cfg.dtype)
     ctx = attend(i, rot(norm(q, "qnorm", hd)), rot(norm(k, "knorm", hd)), v)
     ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
                          [0, S, nh * hd])
-    x = layers.elementwise_add(x, _proj_out(ctx, H, f"{p}_out", cfg))
+    x = layers.elementwise_add(x, proj_out(ctx, H, f"{p}_out", cfg))
     h = norm(x, "ln_post", H)
-    routed, _, stats = _ffn(h, layers.cast(h, cfg.dtype), p, cfg, real)
+    routed, _, stats = ffn(h, layers.cast(h, cfg.dtype), p, cfg, real)
     return layers.elementwise_add(x, routed), stats
 
 
@@ -142,15 +141,10 @@ def _stack_layers(x, cfg: SdarMoeConfig, positions, real, attend):
     for i in range(cfg.num_layers):
         x, s = _block(x, i, cfg, positions, real, attend)
         stats.append(s)
-    return (_norm(x, f"{_P}_lnf", cfg, cfg.hidden_size, zero_centered=False),
-            layers.stack(stats, axis=0))
-
-
-def _head(h2d, cfg: SdarMoeConfig):
-    w = LayerHelper("sdar_moe").create_parameter(
-        _attr(f"{_P}_lm_head", cfg), [cfg.vocab_size, cfg.hidden_size],
-        cfg.dtype)
-    return _logits(h2d, cfg, w)
+    h = decoder.norm(x, f"{_P}_lnf", cfg, cfg.hidden_size,
+                     zero_centered=False)
+    experts = layers.stack(stats, axis=0)
+    return h, [("expert_stats", experts, expert_counter(experts))]
 
 
 def _state_vars(block, cfg: SdarMoeConfig, batch_slots: int, max_seq: int):
@@ -160,7 +154,8 @@ def _state_vars(block, cfg: SdarMoeConfig, batch_slots: int, max_seq: int):
     block and the decode gate; and one K/V cache pair per layer,
     ``[slots, kv_heads, max_seq, head_dim]`` in ``cfg.dtype``."""
     L = cfg.block_length
-    mk, sv, tok, pos, active = _state_table(block, _P, batch_slots, tokens=L)
+    mk, sv, tok, pos, active = decoder.state_table(block, _P, batch_slots,
+                                                   tokens=L)
     at = mk(f"{_P}_gen_revealed_at", (batch_slots, L), "int64")
     step = mk(f"{_P}_gen_step", (batch_slots, 1), "int64")
     shape = (batch_slots, cfg.num_kv_heads, max_seq, cfg.head_dim)
@@ -169,9 +164,9 @@ def _state_vars(block, cfg: SdarMoeConfig, batch_slots: int, max_seq: int):
     return tok, at, pos, step, active, caches, sv
 
 
-def _build_prefill(cfg, B, R, S, max_seq, startup):
+def _build_prefill(cfg, B, R, S, max_seq, page_size, startup):
     """The full-sequence phase for one prompt bucket: ``R`` sequences a
-    dispatch, each naming its slot (``cohere_moe._prefill_feeds``), under
+    dispatch, each naming its slot (``decoder.prefill_feeds``), under
     the block-causal mask. The bucket's K/V go to the slot's cache at row
     0, of which the rows of whole prompt blocks are kept (the decode phase
     writes every later row before it reads it); the slot's block state is
@@ -179,7 +174,7 @@ def _build_prefill(cfg, B, R, S, max_seq, startup):
     main = Program()
     L = cfg.block_length
     with program_guard(main, startup):
-        ids, pos_ids, pmask, plen, smask, slots = _prefill_feeds(R, S)
+        ids, pos_ids, pmask, plen, smask, slots = decoder.prefill_feeds(R, S)
         tok, at, pos, step, active, caches, sv = _state_vars(
             main.global_block, cfg, B, max_seq)
         bias = layers.unsqueeze(
@@ -198,15 +193,16 @@ def _build_prefill(cfg, B, R, S, max_seq, startup):
         first, first_at, start, seated = layers.block_seed(
             ids, plen, L, cfg.mask_token_id)
         real = layers.elementwise_mul(seated, smask, axis=0)
-        _, stats = _stack_layers(_embed(ids, cfg, f"{_P}_word_emb"), cfg,
-                                 pos_ids, real, attend)
+        _, stats = _stack_layers(
+            decoder.embed(ids, cfg, f"{_P}_word_emb"), cfg, pos_ids, real,
+            attend)
         for var, new in ((tok, first), (at, first_at), (pos, start),
                          (step, zero_pos),
                          (active, layers.fill_constant([R, 1], "float32",
                                                        1.0))):
             layers.slot_assign(var, slots, new, smask)
-    return {"main": main, "state_vars": sv, "expert_stats": stats,
-            "rows": R, "feeds": PREFILL_FEEDS}
+    return decoder.counted({"main": main, "state_vars": sv, "rows": R,
+                            "feeds": decoder.PREFILL_FEEDS}, stats)
 
 
 def _build_decode(cfg, B, max_seq, page_size, startup):
@@ -229,52 +225,43 @@ def _build_decode(cfg, B, max_seq, page_size, startup):
 
         rows = layers.block_positions(pos, L)
         real = layers.expand(active, [1, L])
-        h, stats = _stack_layers(_embed(tok, cfg, f"{_P}_word_emb"), cfg,
-                                 rows, real, attend)
-        logits = _head(layers.reshape(h, [-1, cfg.hidden_size]), cfg)
+        h, stats = _stack_layers(
+            decoder.embed(tok, cfg, f"{_P}_word_emb"), cfg, rows, real,
+            attend)
+        logits = decoder.untied_head(
+            layers.reshape(h, [-1, cfg.hidden_size]), cfg, f"{_P}_lm_head")
         emitted, emitted_at, count = layers.block_reveal(
             logits, tok, at, pos, step, active, cfg.mask_token_id,
             cfg.denoising_steps, max_seq)
-    return {"main": main, "state_vars": sv, "logits": logits,
-            "expert_stats": stats,
-            "yield": {"tokens": emitted, "count": count,
-                      "revealed_at": emitted_at},
-            "cache_kinds": {c.name: "full" for pair in caches for c in pair},
-            "cache_vars": [(k.name, v.name) for k, v in caches],
-            "active_var": active.name}
+    return decoder.counted(decoder.decode_net(
+        main, caches, sv, {c.name: "full" for pair in caches for c in pair},
+        active, logits=logits,
+        **{"yield": {"tokens": emitted, "count": count,
+                     "revealed_at": emitted_at}}), stats)
 
 
 def build_sdar_moe_generative(cfg: SdarMoeConfig = None,
                               batch_slots: int = 4, max_seq: int = 64,
                               page_size: int = 8, prompt_buckets=(16,),
                               prefill_rows: int = None):
-    """What ``serving.GenerativeEngine`` needs, as
-    ``build_cohere_moe_generative`` returns it, with ``block_length`` and
+    """What ``serving.GenerativeEngine`` needs
+    (``decoder.build_generative``), with ``block_length`` and
     the decode net's ``yield`` (how its tokens come out: ``tokens`` [slots,
     L], ``count`` [slots, 1] and ``revealed_at`` [slots, L] a forward)
     beside: the engine reads a dispatch's tokens from those, and a prefill
     streams none. Greedy only. ``prefill_rows``: the sequences a prefill
-    dispatch carries, each naming its slot (default: one per slot). No
-    chunk or verify program: a prompt has to fit a bucket."""
+    dispatch carries, each naming its slot (default: one per slot)."""
     cfg = cfg or SdarMoeConfig.tiny()
     L = cfg.block_length
-    prompt_buckets = tuple(sorted(set(int(b) for b in prompt_buckets)))
-    if not prompt_buckets or prompt_buckets[-1] > max_seq:
-        raise ValueError(f"prompt buckets {prompt_buckets} for a cache of "
-                         f"{max_seq} rows")
-    if max_seq % page_size or max_seq % L or any(b % L
+    if max_seq % page_size or max_seq % L or any(int(b) % L
                                                  for b in prompt_buckets):
         raise ValueError(
             f"max_seq {max_seq} must be whole pages of {page_size} and, as "
-            f"every prompt bucket of {prompt_buckets}, whole blocks of {L}")
-    rows = int(prefill_rows or batch_slots)
-    if not 1 <= rows <= batch_slots:
-        raise ValueError(f"prefill_rows {rows} for {batch_slots} slots")
-    startup = Program()
-    prefill = {S: _build_prefill(cfg, batch_slots, rows, S, max_seq, startup)
-               for S in prompt_buckets}
-    decode = _build_decode(cfg, batch_slots, max_seq, page_size, startup)
-    net = _generative(cfg, startup, prefill, decode, batch_slots, max_seq,
-                      page_size, "greedy")
+            f"every prompt bucket of {tuple(prompt_buckets)}, whole blocks "
+            f"of {L}")
+    net = decoder.build_generative(
+        cfg, functools.partial(_build_prefill, cfg),
+        functools.partial(_build_decode, cfg), batch_slots, max_seq,
+        page_size, prompt_buckets, prefill_rows)
     net["block_length"] = L
     return net

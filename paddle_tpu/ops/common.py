@@ -2,10 +2,12 @@
 from __future__ import annotations
 
 import jax.numpy as jnp
+import numpy as np
 
 from ..core.registry import IOSpec, register_op  # re-export for op modules
 
-__all__ = ["register_op", "IOSpec", "x", "out", "broadcast_to_x", "unary"]
+__all__ = ["register_op", "IOSpec", "x", "out", "broadcast_to_x", "unary",
+           "count_by_layer"]
 
 
 def x(ins, slot="X", i=0):
@@ -18,6 +20,19 @@ def x(ins, slot="X", i=0):
 
 def out(val, slot="Out"):
     return {slot: [val]}
+
+
+def count_by_layer(phase: str, stats, layers, rows, calls) -> None:
+    """What a serving dispatch fetched of an op that counts one number an
+    execution (``Stats`` [..., n, 1]: one count from each of ``n`` ops of a
+    kind, in layer order; a chained decode stacks its steps in front;
+    ``layers`` names their layers where they are not all of them). Sums go
+    on the counter ``rows``, executions on ``calls``, by layer and phase."""
+    stats = stats.reshape(-1, stats.shape[-2]).astype(np.int64)
+    for j in range(stats.shape[1]):
+        lab = dict(layer=str(layers[j] if layers else j), phase=phase)
+        rows.labels(**lab).inc(float(stats[:, j].sum()))
+        calls.labels(**lab).inc(float(stats.shape[0]))
 
 
 def broadcast_to_x(xv, yv, axis: int):

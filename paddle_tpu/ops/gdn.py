@@ -23,11 +23,30 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .common import IOSpec, register_op, x
+from .common import IOSpec, count_by_layer, register_op, x
 from .. import flags
 from ..lowering import lowering_platform, note_kernel_route
 
 F32 = jnp.float32
+
+
+def count_rule_stats(phase: str, stats, sums, layers, family: str) -> None:
+    """What a serving dispatch's recurrent layers counted (the ``Stats`` of
+    ``gated_delta_rule`` or, from ``ops/ssd.py``, ``mamba2_scan``: [...,
+    layers, 1]), onto the monitor: the real rows each layer's rule
+    advanced, an execution at a time, under the counters' ``family``
+    (``gdn``, ``ssm``). ``layers`` names the recurrent layers."""
+    from .. import monitor
+
+    count_by_layer(
+        phase, stats, layers,
+        monitor.counter(
+            f"{family}_tokens_total",
+            f"rows of real tokens the recurrent layers' rule ({family}) "
+            f"advanced, by layer and phase of the dispatch"),
+        monitor.counter(
+            f"{family}_calls_total",
+            f"executions of the recurrent layers' op ({family})"))
 
 
 def _route_gdn(Dk: int, Dv: int, platform) -> str:

@@ -28,10 +28,28 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .common import IOSpec, register_op, x
+from .common import IOSpec, count_by_layer, register_op, x
 from .fused_attention import _route as _route_prefill
 from .generation import _route_decode
 from ..lowering import lowering_platform, note_kernel_route
+
+
+def count_latent_stats(phase: str, stats, sums) -> None:
+    """What a serving dispatch's latent-attention layers counted
+    (``latent_attention`` ``Stats``, [..., layers, 1]), onto the monitor:
+    the cache rows each layer's attention read, an execution at a time."""
+    from .. import monitor
+
+    count_by_layer(
+        phase, stats, (),
+        monitor.counter(
+            "latent_attention_rows_total",
+            "latent-cache rows the attention read, by layer and phase "
+            "of the dispatch: in decode whole blocks up to each "
+            "sequence's last live one, in prefill the bucket's rows"),
+        monitor.counter(
+            "latent_attention_calls_total",
+            "executions of the latent attention op"))
 
 
 @register_op(

@@ -14,8 +14,11 @@ No token is dropped for capacity: the row buffer holds the worst case.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .common import IOSpec, register_op, x
 from .. import flags
@@ -46,6 +49,99 @@ def expert_tile_rows(tokens: int, top_k: int, num_experts: int) -> int:
     the grouped route tiles by, and what the serving layer counts an
     execution's live tiles by (``ceil(assignments / rows)`` a hit expert)."""
     return _tile_rows(tokens * top_k // num_experts)
+
+
+def program_tile_rows(stats_var) -> list:
+    """Rows of a grouped-matmul tile in each ``moe_experts`` op of the
+    program that stacks their ``Stats`` into ``stats_var``, in program order
+    (which is the order they are stacked in): the op's own rule over its
+    static shapes."""
+    block = stats_var.block
+    rows = [expert_tile_rows(
+        int(np.prod(block.var(op.input("X")[0]).shape[:-1])),
+        int(op.attr("top_k")), int(op.attr("num_experts")))
+        for op in block.ops if op.type == "moe_experts"]
+    if len(rows) != stats_var.shape[0]:
+        raise ValueError(
+            f"{stats_var.name} stacks {stats_var.shape[0]} layers' counts, "
+            f"the program has {len(rows)} moe_experts ops")
+    return rows
+
+
+def expert_counter(stats_var, layers=()):
+    """What counts the ``Stats`` a program stacks into ``stats_var``, for a
+    net's ``counted``: :func:`count_expert_stats` with the ops' layers
+    (where they are not all of them) and the program's tile rows bound."""
+    return functools.partial(count_expert_stats, layers=layers,
+                             tile_rows=program_tile_rows(stats_var))
+
+
+def count_expert_stats(phase: str, stats, sums, layers, tile_rows) -> None:
+    """What a serving dispatch's expert ops counted (``moe_experts``
+    ``Stats``, [..., layers, experts_held + 2]; a chained decode stacks its
+    steps in front), onto the monitor: per layer and execution the
+    assignments each held expert received, all assignments made, and local
+    assignments that found no row. ``layers`` names the ops' layers where
+    they are not all of them; ``tile_rows`` is a layer's rows of a
+    grouped-matmul tile (:func:`program_tile_rows`), by which its live
+    tiles are counted. ``sums`` (a ``collections.Counter`` the engine was
+    built with) holds the two running sums behind
+    ``moe_local_assignment_share``."""
+    from .. import monitor
+
+    stats = stats.reshape((-1,) + stats.shape[-2:]).astype(np.int64)
+    load, made, dropped = stats[..., :-2], stats[..., -2], stats[..., -1]
+    tokens = monitor.counter(
+        "moe_expert_tokens_total",
+        "token assignments the held experts received, by layer and "
+        "phase of the dispatch")
+    hit = monitor.counter(
+        "moe_experts_hit_total",
+        "held experts that received at least one token, summed over "
+        "the expert op's executions")
+    calls = monitor.counter(
+        "moe_expert_calls_total", "executions of the expert op")
+    tiles = monitor.counter(
+        "moe_expert_tiles_total",
+        "row tiles of the grouped expert matmul that held rows "
+        "(ceil(assignments / tile rows) a hit expert), summed over the "
+        "expert op's executions: over moe_experts_hit_total, the tiles "
+        "that rode one fetch of an expert's weights")
+    for j in range(stats.shape[1]):
+        lab = dict(layer=str(layers[j] if layers else j), phase=phase)
+        tokens.labels(**lab).inc(float(load[:, j].sum()))
+        hit.labels(**lab).inc(float((load[:, j] > 0).sum()))
+        tiles.labels(**lab).inc(float(
+            (-(-load[:, j] // tile_rows[j])).sum()))
+        calls.labels(**lab).inc(float(stats.shape[0]))
+    mean = load.mean(axis=-1)
+    skew = monitor.histogram(
+        "moe_expert_load_max_over_mean",
+        "per execution of the expert op, the busiest held expert's "
+        "assignments over the mean of the held experts' (1 = even)")
+    for v in (load.max(axis=-1)[mean > 0] / mean[mean > 0]).ravel():
+        skew.observe(float(v))
+    held = monitor.histogram(
+        "moe_held_assignments_per_step",
+        "per execution of the expert op (one step of one layer), the "
+        "assignments that fell on the experts held here: the load a "
+        "seed's router deals this share of the deployment").labels(
+        phase=phase)
+    for v in load.sum(axis=-1).ravel():
+        held.observe(float(v))
+    sums["moe_local"] += int(load.sum())
+    sums["moe_made"] += int(made.sum())
+    monitor.gauge(
+        "moe_local_assignment_share",
+        "share of all token-to-expert assignments that fell on experts "
+        "held here, since the engine was built (experts_held / "
+        "num_experts under even routing)"
+    ).set(sums["moe_local"] / max(sums["moe_made"], 1))
+    monitor.counter(
+        "moe_dropped_assignments_total",
+        "local assignments the expert op found no buffer row for; the "
+        "buffer holds the worst case, so anything but 0 is a bug"
+    ).inc(float(dropped.sum()))
 
 
 def _route_moe(T: int, H: int, platform) -> str:

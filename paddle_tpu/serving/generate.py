@@ -1,10 +1,11 @@
-"""Generative serving: prefill/decode split scheduling over a GPT model.
+"""Generative serving: prefill/decode split scheduling over a decoder.
 
 ``GenerativeEngine`` extends :class:`~paddle_tpu.serving.engine.ServingEngine`
-with the autoregressive workload class (ROADMAP item 1): requests are token
-prompts, responses are token streams. The engine owns a fixed set of
-**batch slots** — one shared KV-page bucket per slot batch — and splits
-work into the two phases of ``models/gpt.py``:
+with the autoregressive workload class: requests are token prompts,
+responses are token streams. The engine owns a fixed set of **batch
+slots** — one shared KV-page bucket per slot batch — and splits work into
+the two phases a builder's dict holds (``models/decoder.py``; the keys are
+tabled in docs/SERVING.md "What a builder hands the engine"):
 
 * **prefill** — queued requests are admitted into free slots at decode-
   chunk boundaries and prefilled as one slot-masked batch per prompt
@@ -136,23 +137,6 @@ def _var_names(var) -> List[str]:
     return [] if var is None else [var.name]
 
 
-def _expert_tile_rows(net) -> List[int]:
-    """Rows of a grouped-matmul tile in each ``moe_experts`` op of a net's
-    program, in program order (which is the order the net stacks their
-    ``Stats``): the op's own rule over its static shapes."""
-    from ..ops.moe import expert_tile_rows
-    block = net["main"].global_block
-    rows = [expert_tile_rows(
-        int(np.prod(block.var(op.input("X")[0]).shape[:-1])),
-        int(op.attr("top_k")), int(op.attr("num_experts")))
-        for op in block.ops if op.type == "moe_experts"]
-    if len(rows) != net["expert_stats"].shape[0]:
-        raise ValueError(
-            f"serving: expert_stats stacks {net['expert_stats'].shape[0]} "
-            f"layers' counts, the program has {len(rows)} moe_experts ops")
-    return rows
-
-
 def _loop_phase(name: str, parent=None):
     """One phase of the dispatch thread's loop: the span ``serving.<name>``
     (``FLAGS_trace``) and an observation on
@@ -281,8 +265,9 @@ class _Launched:
 
 
 class GenerativeEngine(ServingEngine):
-    """See module docstring. ``model`` is a ``build_gpt_generative`` dict;
-    parameters must already be initialized in ``scope`` (run the model's
+    """See module docstring. ``model`` is a builder's dict
+    (``build_gpt_generative`` and the like); parameters must already be
+    initialized in ``scope`` (run the model's
     startup program first). Generation state (tokens/positions/KV pages)
     is planted and reset by the engine itself."""
 
@@ -316,26 +301,35 @@ class GenerativeEngine(ServingEngine):
         # exists; any LATER cache growth on the same key is a recompile
         self._compiled_buckets: Dict[tuple, bool] = {}
         self.decode_recompiles = 0
-        # chunked prefill + speculative verify programs (absent on model
-        # dicts from before ISSUE 20 — every new path degrades to the
-        # bucket-prefill / plain-decode behaviour)
+        # chunked prefill + speculative verify programs, where the builder
+        # has them (without, a prompt has to fit a bucket and decode is
+        # plain)
         self._chunk = model.get("chunk")
         self._verify = model.get("verify")
         self._prefill_chunk = int(model.get("prefill_chunk") or
                                   self._page_size)
         self._spec_k = int(model.get("spec_k") or 0)
+        missing = [f"prefill[{b}]['rows']"
+                   for b, net in model["prefill"].items()
+                   if "rows" not in net] + [
+            f"cache_kinds[{n!r}]" for names in model["cache_vars"]
+            for n in names if n not in model.get("cache_kinds", ())]
+        if missing:
+            raise ValueError(
+                "serving: a model names the sequences a dispatch of each "
+                "prefill net carries (rows) and the kind of every layer's "
+                "state (cache_kinds); missing: " + ", ".join(missing))
         # the model names its own state, a layer at a time, and says of
-        # what kind each layer's is (``cache_kinds``; a model that says
-        # nothing holds ``full`` pairs): a (K, V) cache pair whose rows
-        # follow the sequence (``full``: every position, ``window``: a ring
-        # of the last ones), with row counts and type of its own; a
+        # what kind each layer's is (``cache_kinds``): a (K, V) cache pair
+        # whose rows follow the sequence (``full``: every position,
+        # ``window``: a ring of the last ones), with row counts and type of
+        # its own; a
         # ``latent`` layer's cache, whose rows follow the sequence as a
         # ``full`` layer's do but are one "head" and no K/V pair (one array
         # of compressed rows with their rotary keys); or a ``recurrent``
         # layer's state, which has no rows at all. And the per-slot decode
         # gate.
-        kinds = model.get("cache_kinds", {})
-        self._state_kinds = {n: kinds.get(n, "full")
+        self._state_kinds = {n: model["cache_kinds"][n]
                              for names in model["cache_vars"] for n in names}
         of_kind = lambda *ks: [tuple(names) for names in model["cache_vars"]
                                if self._state_kinds[names[0]] in ks]
@@ -352,36 +346,12 @@ class GenerativeEngine(ServingEngine):
         for shapes in (self._cache_shapes, self._latent_shapes):
             for (shape, _), n in shapes.items():
                 self._cache_rows[int(shape[2])] += n
-        # what a dispatch counted on the device, fetched beside its tokens:
-        # a model with routed experts hands back the assignments each held
-        # expert received (``layers.moe_experts``), one with recurrent
-        # layers the rows each layer's rule advanced
-        # (``layers.gated_delta_rule``, ``layers.mamba2_scan``: the net
-        # names the counters' family), one with latent attention the
-        # cache rows each layer's attention read
-        # (``layers.latent_attention``)
-        stats = lambda net: {k: net[f"{k}_stats"].name
-                             for k in ("expert", "rule", "latent")
-                             if net.get(f"{k}_stats") is not None}
-        self._stats_fetch = {
-            "decode": stats(decode),
-            **{("prefill", b): stats(net)
-               for b, net in model["prefill"].items()}}
-        self._rule_layers = list(decode.get("rule_layers", ()))
-        self._rule_family = decode.get("rule_family")
-        if "rule" in self._stats_fetch["decode"] and not self._rule_family:
-            raise ValueError(
-                "serving: a decode net with rule_stats names the counters' "
-                "family (rule_family: 'gdn', 'ssm')")
-        self._expert_layers = list(decode.get("expert_layers", ()))
-        # rows of a grouped-matmul tile in each expert op of a program, in
-        # the order the ops' Stats are stacked: the key of a stats fetch ->
-        # one number a layer with experts
-        self._expert_tile_rows = {
-            key: _expert_tile_rows(net) for key, net in (
-                ("decode", decode),
-                *((("prefill", b), n) for b, n in model["prefill"].items()))
-            if "expert" in self._stats_fetch[key]}
+        # what a dispatch counted on the device, fetched beside its tokens
+        # and handed to what counts it (a net's ``counted``: pairs of a
+        # variable and ``count(phase, array, sums)``, the op's own reading
+        # of its layout; ``sums`` is this engine's, for what such a
+        # function adds up since the engine was built)
+        self._sums = Counter()
         gc = self.gen_config
         self._prefix_cache = None
         if gc.prefix_cache and self._chunk is not None:
@@ -400,7 +370,6 @@ class GenerativeEngine(ServingEngine):
         # Default: prompt-lookup n-gram (see _ngram_draft). Swappable for
         # tests and for a real draft model.
         self.draft_fn = None
-        self._moe_local = self._moe_made = 0
         # -- one dispatch ahead (module docstring) -------------------------
         # launched and not fetched, oldest first (dispatch thread only; a
         # list so that another thread may copy it)
@@ -1132,16 +1101,14 @@ class GenerativeEngine(ServingEngine):
             tokens.labels(kind="prompt").inc(float(prompt))
             tokens.labels(kind="run").inc(float(run))
 
-    def _prefill_rows(self, bucket: int) -> Optional[int]:
-        """Sequences one dispatch of this bucket's program carries, where
-        its rows name their slots (``slot_ids``); None where row ``i`` IS
-        slot ``i`` and every dispatch carries the whole slot batch."""
-        return self._model["prefill"][bucket].get("rows")
+    def _prefill_rows(self, bucket: int) -> int:
+        """Sequences one dispatch of this bucket's program carries, each
+        row naming its slot (``slot_ids``)."""
+        return self._model["prefill"][bucket]["rows"]
 
     def _prefill_feed(self, bucket: int,
                       reqs: Sequence[_GenRequest]) -> dict:
-        rows = self._prefill_rows(bucket)
-        B = rows or len(self._slots)
+        B = self._prefill_rows(bucket)
         feed = {
             "prompt_ids": np.zeros((B, bucket), np.int64),
             "prompt_pos": np.tile(np.arange(bucket, dtype=np.int64),
@@ -1149,30 +1116,27 @@ class GenerativeEngine(ServingEngine):
             "prompt_mask": np.zeros((B, bucket), np.float32),
             "prompt_len": np.ones((B, 1), np.int64),
             "slot_mask": np.zeros((B, 1), np.float32),
+            "slot_ids": np.zeros((B, 1), np.int64),
         }
-        if rows:
-            feed["slot_ids"] = np.zeros((B, 1), np.int64)
-        for i, r in enumerate(reqs):
-            row = i if rows else r.slot
+        for row, r in enumerate(reqs):
             L = len(r.prompt)
             feed["prompt_ids"][row, :L] = r.prompt
             feed["prompt_mask"][row, :L] = 1.0
             feed["prompt_len"][row, 0] = L
             feed["slot_mask"][row, 0] = 1.0
-            if rows:
-                feed["slot_ids"][row, 0] = r.slot
+            feed["slot_ids"][row, 0] = r.slot
         return feed
 
     def _run_prefill(self, newcomers: List[_GenRequest]) -> None:
         by_bucket = defaultdict(list)
         for r in newcomers:
             by_bucket[r.bucket].append(r)
-        # one dispatch per bucket, or per `rows` requests of it where the
-        # bucket's program carries that many sequences a dispatch
+        # one dispatch per `rows` requests of a bucket: its program carries
+        # that many sequences a dispatch
         groups = []
         for bucket in sorted(by_bucket):
             reqs = by_bucket[bucket]
-            n = self._prefill_rows(bucket) or len(reqs)
+            n = self._prefill_rows(bucket)
             groups += [(bucket, reqs[i:i + n])
                        for i in range(0, len(reqs), n)]
         for bucket, reqs in groups:
@@ -1223,20 +1187,17 @@ class GenerativeEngine(ServingEngine):
 
     def _settle_prefill(self, e: _Launched, outs, dt: float) -> None:
         bucket, reqs = e.bucket, e.reqs
-        by_row = self._prefill_rows(bucket) is not None
+        net = self._model["prefill"][bucket]
         self._publish(reqs)
         with _loop_phase("settle") as ph:
-            self._note_compiles("prefill", bucket,
-                                self._model["prefill"][bucket]["main"])
-            self._observe_stats("prefill", ("prefill", bucket),
-                                outs[0 if self._block else 1:])
+            self._note_compiles("prefill", bucket, net["main"])
+            self._count("prefill", net, outs[0 if self._block else 1:])
             # a prefill by blocks seats a prompt's whole blocks; what
             # is left over opens the slot's first decode block
             whole = self._block or 1
             self._count_prefill_tokens(
                 sum(len(r.prompt) // whole * whole for r in reqs),
-                (self._prefill_rows(bucket) or len(self._slots))
-                * bucket)
+                net["rows"] * bucket)
             if _monitor.enabled():
                 _monitor.histogram(
                     "serving_prefill_seconds",
@@ -1252,8 +1213,7 @@ class GenerativeEngine(ServingEngine):
                 # the first token's cost is the FIRST-TOKEN histogram's
                 # story — it must not pollute the inter-token latency
                 tokens += 1
-                self._emit(r, [int(first[i if by_row else r.slot])], dt,
-                           record_intertoken=False)
+                self._emit(r, [int(first[i])], dt, record_intertoken=False)
             self._settled(ph, reqs, tokens)
 
     # -- one dispatch ahead ------------------------------------------------
@@ -1374,7 +1334,7 @@ class GenerativeEngine(ServingEngine):
         with _loop_phase("settle") as ph:
             self._note_compiles("decode", len(self._slots), self._program)
             n = len(self._fetch_names)
-            self._observe_stats("decode", "decode", outs[n:])
+            self._count("decode", self._model["decode"], outs[n:])
             out = _Yield(outs[:n], steps, len(self._slots), self._block)
             # a request that ended before this dispatch was settled (a
             # stop only its tokens showed, a deadline) ran it for nothing:
@@ -1720,129 +1680,16 @@ class GenerativeEngine(ServingEngine):
     def _prefill_fetches(self, bucket: int) -> List[str]:
         net = self._model["prefill"][bucket]
         first = [] if self._block else [net["first_token"].name]
-        return first + list(self._stats_fetch["prefill", bucket].values())
+        return first + [v.name for v, _ in net.get("counted", ())]
 
     def _decode_fetches(self) -> List[str]:
-        return self._fetch_names + list(self._stats_fetch["decode"].values())
+        return self._fetch_names + [
+            v.name for v, _ in self._model["decode"].get("counted", ())]
 
-    def _observe_stats(self, phase: str, key, fetched) -> None:
-        if not _monitor.enabled():
-            return
-        got = dict(zip(self._stats_fetch[key], fetched))
-        if "expert" in got:
-            self._observe_expert_stats(phase, np.asarray(got["expert"]),
-                                       self._expert_tile_rows[key])
-        if "rule" in got:
-            self._observe_rule_stats(phase, np.asarray(got["rule"]))
-        if "latent" in got:
-            self._observe_latent_stats(phase, np.asarray(got["latent"]))
-
-    def _observe_latent_stats(self, phase: str, stats) -> None:
-        """What a dispatch's latent-attention layers counted
-        (``layers.latent_attention`` ``Stats``, [..., layers, 1]; a chained
-        decode stacks its steps in front): the cache rows each layer's
-        attention read, an execution at a time."""
-        self._count_by_layer(
-            phase, stats, (),
-            _monitor.counter(
-                "latent_attention_rows_total",
-                "latent-cache rows the attention read, by layer and phase "
-                "of the dispatch: in decode whole blocks up to each "
-                "sequence's last live one, in prefill the bucket's rows"),
-            _monitor.counter(
-                "latent_attention_calls_total",
-                "executions of the latent attention op"))
-
-    def _observe_rule_stats(self, phase: str, stats) -> None:
-        """What a dispatch's recurrent layers counted (the ``Stats`` of
-        ``layers.gated_delta_rule`` or ``layers.mamba2_scan``, [..., layers,
-        1]; a chained decode stacks its steps in front): the real rows each
-        layer's rule advanced, an execution at a time, under the family the
-        decode net names (``rule_family``: ``gdn``, ``ssm``)."""
-        fam = self._rule_family
-        self._count_by_layer(
-            phase, stats, self._rule_layers,
-            _monitor.counter(
-                f"{fam}_tokens_total",
-                f"rows of real tokens the recurrent layers' rule ({fam}) "
-                f"advanced, by layer and phase of the dispatch"),
-            _monitor.counter(
-                f"{fam}_calls_total",
-                f"executions of the recurrent layers' op ({fam})"))
-
-    @staticmethod
-    def _count_by_layer(phase: str, stats, layers, rows, calls) -> None:
-        """``stats`` [..., n, 1]: one count an execution from each of ``n``
-        ops of a kind, in layer order (``layers`` names their layers where
-        they are not all of them). Sums go on ``rows``, executions on
-        ``calls``, by layer and phase."""
-        stats = stats.reshape(-1, stats.shape[-2]).astype(np.int64)
-        for j in range(stats.shape[1]):
-            lab = dict(layer=str(layers[j] if layers else j), phase=phase)
-            rows.labels(**lab).inc(float(stats[:, j].sum()))
-            calls.labels(**lab).inc(float(stats.shape[0]))
-
-    def _observe_expert_stats(self, phase: str, stats, tile_rows) -> None:
-        """What a dispatch's expert ops counted (``layers.moe_experts``
-        ``Stats``, [..., layers, experts_held + 2]; a chained decode stacks
-        its steps in front): per layer and execution the assignments each
-        held expert received, all assignments made, and local assignments
-        that found no row. ``tile_rows``: a layer's rows of a
-        grouped-matmul tile, by which its live tiles are counted."""
-        stats = stats.reshape((-1,) + stats.shape[-2:]).astype(np.int64)
-        load, made, dropped = stats[..., :-2], stats[..., -2], stats[..., -1]
-        tokens = _monitor.counter(
-            "moe_expert_tokens_total",
-            "token assignments the held experts received, by layer and "
-            "phase of the dispatch")
-        hit = _monitor.counter(
-            "moe_experts_hit_total",
-            "held experts that received at least one token, summed over "
-            "the expert op's executions")
-        calls = _monitor.counter(
-            "moe_expert_calls_total", "executions of the expert op")
-        tiles = _monitor.counter(
-            "moe_expert_tiles_total",
-            "row tiles of the grouped expert matmul that held rows "
-            "(ceil(assignments / tile rows) a hit expert), summed over the "
-            "expert op's executions: over moe_experts_hit_total, the tiles "
-            "that rode one fetch of an expert's weights")
-        for j in range(stats.shape[1]):
-            layer = self._expert_layers[j] if self._expert_layers else j
-            lab = dict(layer=str(layer), phase=phase)
-            tokens.labels(**lab).inc(float(load[:, j].sum()))
-            hit.labels(**lab).inc(float((load[:, j] > 0).sum()))
-            tiles.labels(**lab).inc(float(
-                (-(-load[:, j] // tile_rows[j])).sum()))
-            calls.labels(**lab).inc(float(stats.shape[0]))
-        mean = load.mean(axis=-1)
-        skew = _monitor.histogram(
-            "moe_expert_load_max_over_mean",
-            "per execution of the expert op, the busiest held expert's "
-            "assignments over the mean of the held experts' (1 = even)")
-        for v in (load.max(axis=-1)[mean > 0] / mean[mean > 0]).ravel():
-            skew.observe(float(v))
-        held = _monitor.histogram(
-            "moe_held_assignments_per_step",
-            "per execution of the expert op (one step of one layer), the "
-            "assignments that fell on the experts held here: the load a "
-            "seed's router deals this share of the deployment").labels(
-            phase=phase)
-        for v in load.sum(axis=-1).ravel():
-            held.observe(float(v))
-        self._moe_local += int(load.sum())
-        self._moe_made += int(made.sum())
-        _monitor.gauge(
-            "moe_local_assignment_share",
-            "share of all token-to-expert assignments that fell on experts "
-            "held here, since the engine was built (experts_held / "
-            "num_experts under even routing)"
-        ).set(self._moe_local / max(self._moe_made, 1))
-        _monitor.counter(
-            "moe_dropped_assignments_total",
-            "local assignments the expert op found no buffer row for; the "
-            "buffer holds the worst case, so anything but 0 is a bug"
-        ).inc(float(dropped.sum()))
+    def _count(self, phase: str, net: dict, fetched) -> None:
+        if _monitor.enabled():
+            for (_, count), stats in zip(net.get("counted", ()), fetched):
+                count(phase, np.asarray(stats), self._sums)
 
     def generation_stats(self) -> dict:
         """Decode-side snapshot for reports: resident slots, compiled

@@ -15,6 +15,14 @@ STEPS = 10
 DIM = 16
 
 
+def _say(line: str) -> None:
+    """One whole line in ONE write: the ranks share the launcher's stdout,
+    and ``print`` under ``-u`` writes a line and its end separately, which
+    lets the other rank's line land between them."""
+    sys.stdout.flush()
+    os.write(sys.stdout.fileno(), (line + "\n").encode())
+
+
 def main():
     nranks = int(os.getenv("PADDLE_TRAINERS_NUM", "1"))
     rank = int(os.getenv("PADDLE_TRAINER_ID", "0"))
@@ -81,7 +89,7 @@ def main():
             losses.append(float(np.asarray(lv).reshape(-1)[0]))
             sync.step(scope)
         w = np.asarray(scope.find_var("d_fc1.w_0")).ravel()[:6].tolist()
-        print(f"PARAMS{rank} " + json.dumps(w), flush=True)
+        _say(f"PARAMS{rank} " + json.dumps(w))
     else:
         bs = fluid.BuildStrategy()
         if os.getenv("DIST_REDUCE") == "1":
@@ -95,7 +103,7 @@ def main():
                          fetch_list=[loss])[0]
             losses.append(float(np.asarray(lv).reshape(-1)[0]))
     if rank == 0:
-        print("LOSSES " + json.dumps(losses), flush=True)
+        _say("LOSSES " + json.dumps(losses))
     if nranks > 1:
         # hard-exit teardown: this jax build's gloo transport double-frees
         # nondeterministically when interpreter teardown (or even

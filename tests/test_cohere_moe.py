@@ -30,8 +30,9 @@ import paddle_tpu as fluid
 import paddle_tpu.unique_name as un
 from paddle_tpu import layers, monitor, serving
 from paddle_tpu.core.types import np_dtype
-from paddle_tpu.models.cohere_moe import (CohereMoeConfig, _ffn,
+from paddle_tpu.models.cohere_moe import (CohereMoeConfig,
                                           build_cohere_moe_generative)
+from paddle_tpu.models.decoder import ffn as _ffn
 
 # the benchmark's directory is on the path only while its reference is
 # imported: it has a ``tools`` package of its own, which would shadow the

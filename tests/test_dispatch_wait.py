@@ -9,6 +9,7 @@
   ``moe_held_assignments_per_step{phase}``;
 * ``tools/timeline.py --xplane`` as the join's first caller.
 """
+import collections
 import json
 import os
 import time
@@ -543,13 +544,13 @@ def test_prefill_tokens_prompt_against_run(rows):
 
 
 def test_held_assignments_per_step_from_the_ops_counts():
-    eng = serving.GenerativeEngine.__new__(serving.GenerativeEngine)
-    eng._expert_layers, eng._moe_local, eng._moe_made = (), 0, 0
+    from paddle_tpu.ops.moe import count_expert_stats
+
     monitor.reset()
     # two steps x two layers x (3 held experts + made + dropped)
     stats = np.array([[[4, 0, 2, 16, 0], [1, 1, 1, 16, 0]],
                       [[0, 0, 0, 16, 0], [5, 2, 0, 16, 0]]])
-    eng._observe_expert_stats("decode", stats, [16, 16])
+    count_expert_stats("decode", stats, collections.Counter(), (), [16, 16])
     snap = monitor.metric_value("moe_held_assignments_per_step",
                                 phase="decode")
     assert snap["count"] == 4 and snap["sum"] == 6 + 3 + 0 + 7
@@ -562,14 +563,15 @@ def test_live_tiles_from_the_ops_counts_and_its_tile_rule():
     over the held experts of every execution, by layer: over
     ``moe_experts_hit_total`` it is the tiles that rode one fetch of an
     expert's weights (1.0 under even routing, SDAR's skew about 1.7)."""
-    eng = serving.GenerativeEngine.__new__(serving.GenerativeEngine)
-    eng._expert_layers, eng._moe_local, eng._moe_made = (3, 5), 0, 0
+    from paddle_tpu.ops.moe import count_expert_stats
+
     monitor.reset()
     # two forwards x two layers x (4 held experts + made + dropped): the
     # first layer tiles by 16 rows, the second by 32
     stats = np.array([[[85, 0, 16, 17, 128, 0], [85, 0, 32, 33, 128, 0]],
                       [[1, 1, 1, 1, 128, 0], [0, 0, 0, 0, 128, 0]]])
-    eng._observe_expert_stats("decode", stats, [16, 32])
+    count_expert_stats("decode", stats, collections.Counter(), (3, 5),
+                       [16, 32])
     value = lambda fam, layer: monitor.metric_value(fam, layer=layer,
                                                     phase="decode")
     ceil = lambda counts, tm: sum(-(-c // tm) for c in counts)
@@ -581,15 +583,14 @@ def test_live_tiles_from_the_ops_counts_and_its_tile_rule():
     assert value("moe_experts_hit_total", "5") == 3
 
 
-def test_the_engine_reads_each_expert_ops_tile_rows_from_its_program():
+def test_each_expert_ops_tile_rows_are_read_from_its_program():
     """The tile rule is the op's own (``ops.moe.expert_tile_rows``) over
     the static shapes of each phase's program: a decode forward of 4 slots
     x 4 rows and prefills of 2 x 16 and 2 x 32 rows, top 2 of 8 experts."""
     import paddle_tpu.unique_name as un
     from paddle_tpu.models.sdar_moe import (SdarMoeConfig,
                                             build_sdar_moe_generative)
-    from paddle_tpu.ops.moe import expert_tile_rows
-    from paddle_tpu.serving.generate import _expert_tile_rows
+    from paddle_tpu.ops.moe import expert_tile_rows, program_tile_rows
 
     cfg = SdarMoeConfig.tiny(dtype="float32")
     with un.guard():
@@ -597,8 +598,9 @@ def test_the_engine_reads_each_expert_ops_tile_rows_from_its_program():
                                         page_size=8, prompt_buckets=(16, 32),
                                         prefill_rows=2)
     layers = cfg.num_layers
-    assert _expert_tile_rows(net["decode"]) == [16] * layers
-    assert _expert_tile_rows(net["prefill"][32]) == [16] * layers
+    assert program_tile_rows(net["decode"]["expert_stats"]) == [16] * layers
+    assert program_tile_rows(
+        net["prefill"][32]["expert_stats"]) == [16] * layers
     assert expert_tile_rows(1024, 8, 128) == 64       # SDAR's longest prefill
     assert expert_tile_rows(256, 8, 128) == 16        # its decode forward
     assert expert_tile_rows(64 * 128, 8, 128) == 256

@@ -36,8 +36,9 @@ import paddle_tpu as fluid
 import paddle_tpu.unique_name as un
 from paddle_tpu import layers, monitor, serving
 from paddle_tpu.core.types import np_dtype
+from paddle_tpu.models.decoder import Mix as _Mix
 from paddle_tpu.models.granite_moe_hybrid import (
-    ATTENTION, MAMBA, GraniteMoeHybridConfig, _block, _Mix,
+    ATTENTION, MAMBA, GraniteMoeHybridConfig, _block,
     build_granite_moe_hybrid_generative)
 
 _BENCHMARK = os.path.join(os.path.dirname(os.path.dirname(
@@ -340,7 +341,7 @@ def test_the_two_shares_add_up_to_the_uncut_layer(i):
 
 
 def test_a_shared_expert_of_its_own_width():
-    """``cohere_moe._ffn`` builds the shared expert at
+    """``decoder.ffn`` builds the shared expert at
     ``shared_intermediate_size`` where the configuration has one, and at
     ``num_shared_experts`` routed widths where it has not."""
     cfg = GraniteMoeHybridConfig.tiny()

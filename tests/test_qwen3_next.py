@@ -32,7 +32,8 @@ import paddle_tpu as fluid
 import paddle_tpu.unique_name as un
 from paddle_tpu import layers, monitor, serving
 from paddle_tpu.core.types import np_dtype
-from paddle_tpu.models.qwen3_next import (Qwen3NextConfig, _block, _Mix,
+from paddle_tpu.models.decoder import Mix as _Mix
+from paddle_tpu.models.qwen3_next import (Qwen3NextConfig, _block,
                                           build_qwen3_next_generative)
 
 _BENCHMARK = os.path.join(os.path.dirname(os.path.dirname(
