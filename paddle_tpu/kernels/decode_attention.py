@@ -89,7 +89,8 @@ from .flash_attention import _PALLAS_SCOPE, NEG_INF, _out_sds
 
 __all__ = ["flash_attention_decode", "kv_append", "paged_kv_append",
            "paged_kv_append_rows", "decode_attention_reference",
-           "decode_walk_blocks", "rows_minor", "KERNEL_ROWS"]
+           "decode_walk_blocks", "rows_minor", "KERNEL_ROWS", "fold_rows",
+           "window_fold"]
 
 # query rows one kernel call serves: the chunk rides ONE f32 sublane tile
 KERNEL_ROWS = 8
@@ -217,8 +218,46 @@ def paged_kv_append_rows(cache, new, positions, mask=None, ring=False):
     return jax.vmap(upd)(cache, new, idx)
 
 
+def fold_rows(lengths, window: int):
+    """``[R, window]``: the prompt position each row of a ring of
+    ``window`` rows takes from a sequence of ``lengths`` [R] tokens: row
+    ``r`` the LAST position ``p < length`` with ``p % window == r``, so
+    that a decode step, which writes position ``p`` at row ``p % window``,
+    goes on where the prompt stopped. A row no position of a short
+    sequence falls on (``r >= length``) takes position ``r``, a padding
+    row the ring's length mask hides, as a bucket written at row 0 leaves
+    it. The rows come from two consecutive blocks of ``window`` positions:
+    the one the sequence ends in, up to its last token, and the one
+    before."""
+    last = jnp.maximum(lengths.astype(jnp.int32), 1) - 1
+    block, rem = last // window, last % window
+    r = jnp.arange(window, dtype=jnp.int32)[None, :]
+    ends_here = r <= rem[:, None]
+    return jnp.where(ends_here, block[:, None],
+                     jnp.maximum(block[:, None] - 1, 0)) * window + r
+
+
+@jax.named_scope("window_fold")
+def window_fold(cache, new, lengths, mask=None, slots=None):
+    """A prompt past a window layer's ring, folded into it: ``cache``
+    [B, H, W, D] is a ring of the last ``W`` positions, ``new`` [R, H, S,
+    D] the keys or values of ``R`` whole prompts of ``lengths`` [R] tokens
+    in a bucket of ``S > W`` rows. ONE gather takes each sequence's rows
+    (:func:`fold_rows`) and ONE write a sequence puts them in the slot it
+    names (``slots`` [R], default its own index) where its ``mask`` is set;
+    every other slot stays bit-untouched (:func:`paged_kv_append`). Plain
+    XLA on every device, under the scope ``window_fold`` (a device trace
+    shows it in the operations' ``op_name``)."""
+    R, W = new.shape[0], cache.shape[-2]
+    idx = fold_rows(lengths.reshape(R), W)
+    folded = jnp.take_along_axis(new, idx[:, None, :, None], axis=2)
+    return paged_kv_append(cache, folded, jnp.zeros((R,), jnp.int32), mask,
+                           slots)
+
+
 def decode_attention_reference(q, k_cache, v_cache, lengths, scale,
-                               group: int = 1, whole_chunk: bool = False):
+                               group: int = 1, whole_chunk: bool = False,
+                               sink=None):
     """Primitive oracle: masked softmax attention of a chunk of query rows
     per sequence against its cache. q: [BH, Sq, D]; caches: [BH, S, D];
     lengths: [BH] (already expanded per head) — the number of keys visible
@@ -229,8 +268,10 @@ def decode_attention_reference(q, k_cache, v_cache, lengths, scale,
     chunk position ``i // group``. With ``whole_chunk`` every row sees
     what the chunk's last row sees, ``lengths + Sq // group - 1`` keys: the
     chunk's rows see one another in both directions (a block-diffusion
-    block). Matches the kernel semantics exactly; also the op's off-TPU
-    lowering."""
+    block). ``v_cache`` may be [BH, S, Dv] with another width than the
+    keys'. ``sink`` ([BH, group] f32, a scalar a query head): one more
+    column of the softmax that carries no value. Matches the kernel
+    semantics exactly; also the op's off-TPU lowering."""
     prec = "highest" if q.dtype == jnp.float32 else "default"
     s = jnp.einsum("bqd,bkd->bqk", q.astype(jnp.float32),
                    k_cache.astype(jnp.float32), precision=prec) * scale
@@ -239,7 +280,10 @@ def decode_attention_reference(q, k_cache, v_cache, lengths, scale,
     if whole_chunk:
         row = q.shape[1] // group - 1
     s = jnp.where(k_pos < lengths[:, None, None] + row, s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
+    if sink is not None:        # row i is query head i % group
+        col = jnp.tile(sink.astype(jnp.float32), (1, q.shape[1] // group))
+        s = jnp.concatenate([s, col[:, :, None]], axis=-1)
+    p = jax.nn.softmax(s, axis=-1)[..., :k_cache.shape[1]]
     o = jnp.einsum("bqk,bkd->bqd", p, v_cache.astype(jnp.float32),
                    precision=prec)
     return o.astype(q.dtype)
@@ -357,7 +401,7 @@ def kv_append(cache, new, positions, mask=None, ring=False, *,
 
 
 def kv_tile(num_heads: int, s_max: int, head_dim: int, dtype,
-            page_size: int, row_bytes: int = None):
+            page_size: int, row_bytes: int = None, v_dim: int = None):
     """``(heads, rows)`` of a cache that ONE grid step of the decode kernel
     carries, from what the kernel sees when it is traced. ``page_size`` is
     the cache's page, the unit of everything outside this module; the tile
@@ -376,14 +420,20 @@ def kv_tile(num_heads: int, s_max: int, head_dim: int, dtype,
     call on the saturated mix against 0.236 and 0.229 against 0.197 on
     sequences of one key: PR 32's sweep). ``row_bytes``: what a step
     fetches of one row of one head where that is not a K and a V row of
-    ``head_dim`` (a latent cache's row is fetched once)."""
+    ``head_dim`` (a latent cache's row is fetched once). ``v_dim``: a
+    value row's width where it is not the key's (both then lie as
+    declared, each padded to whole lane tiles)."""
     page = min(page_size, s_max)
     # K and V of one row of one head in VMEM: the head dimension padded to
     # whole 128-lane vregs, or, rows in lanes, as it is
-    lanes = (head_dim if rows_minor(head_dim, dtype, page)
-             else -(-head_dim // 128) * 128)
+    padded = lambda d: -(-d // 128) * 128
+    if v_dim not in (None, head_dim):
+        lanes2 = padded(head_dim) + padded(v_dim)
+    else:
+        lanes2 = 2 * (head_dim if rows_minor(head_dim, dtype, page)
+                      else padded(head_dim))
     if row_bytes is None:
-        row_bytes = 2 * lanes * jnp.dtype(dtype).itemsize
+        row_bytes = lanes2 * jnp.dtype(dtype).itemsize
     heads = max(h for h in range(1, num_heads + 1) if num_heads % h == 0
                 and (h == 1 or h * page * row_bytes <= _STEP_BYTES))
     pages, step = s_max // page, heads * page * row_bytes
@@ -406,26 +456,30 @@ def last_live_block(lengths, q_len: int, block_k: int, num_k: int):
 
 
 def decode_walk_blocks(lengths, cache_shape, dtype, page_size: int,
-                       q_len: int = 1, rows: int = None):
+                       q_len: int = 1, rows: int = None, v_dim: int = None):
     """``(fetched, capacity)``: the k-blocks one call of the kernel fetches
     for sequences of ``lengths`` (host integers, visible keys of query row
     0) out of those their caches ``[B, H, S_max, D]`` hold. Pure host
     arithmetic on the kernel's own tile and walk (``rows``: the rows of a
-    step of another kernel that walks as this one does)."""
+    step of another kernel that walks as this one does; ``v_dim``: the
+    values' width where it is not the keys')."""
     _, H, S, D = cache_shape
     if rows is None:
-        _, rows = kv_tile(H, S, D, dtype, page_size)
+        _, rows = kv_tile(H, S, D, dtype, page_size, v_dim=v_dim)
     num_k = S // rows
     live = last_live_block(np.asarray(lengths, np.int64), q_len, rows,
                            num_k) + 1
     return int(live.sum()), int(live.size * num_k)
 
 
-def _decode_kernel(scale, group, q_len, minor, whole, append, len_ref,
-                   *refs):
+def _decode_kernel(scale, group, q_len, minor, whole, append, sink,
+                   len_ref, *refs):
+    sink_ref = None
     if append:      # the step's new K/V row rides the last live block
         (keep_ref, q_ref, k_ref, v_ref, kn_ref, vn_ref, o_ref, ko_hbm,
          vo_hbm, m_scr, l_scr, acc, k_buf, v_buf, sems) = refs
+    elif sink:      # a scalar a query row: one more column, no value
+        q_ref, k_ref, v_ref, sink_ref, o_ref, m_scr, l_scr, acc = refs
     else:
         q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc = refs
     b, ik = pl.program_id(0), pl.program_id(2)
@@ -438,8 +492,12 @@ def _decode_kernel(scale, group, q_len, minor, whole, append, len_ref,
 
     @pl.when(ik == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
+        if sink:    # its score the running maximum, its exp(0) the sum
+            m_scr[:] = sink_ref[...]
+            l_scr[:] = jnp.ones_like(l_scr)
+        else:
+            m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+            l_scr[:] = jnp.zeros_like(l_scr)
         acc[:] = jnp.zeros_like(acc)
 
     last = last_live_block(length, q_len, block_k, num_k)
@@ -548,7 +606,7 @@ def _kv_index_map(q_len: int, block_k: int, num_k: int,
 
 
 def _decode_call(q, k_cache, v_cache, lengths, tile, *, minor, scale, group,
-                 q_len, interpret, whole=False, append=None):
+                 q_len, interpret, whole=False, append=None, sink=None):
     """The Pallas call on ``q`` [B * H, R, D] and caches [B, H, S_max, D]
     with ``tile = (heads, rows)`` of a cache a grid step
     (:func:`kv_tile`'s choice; ``tools/probe_decode_walk.py`` sweeps it).
@@ -559,23 +617,33 @@ def _decode_call(q, k_cache, v_cache, lengths, tile, *, minor, scale, group,
     too (``input_output_aliases``, so a donated cache is updated in place).
     As results they stay in HBM, and the kernel itself copies one block a
     sequence and group of heads into them, the last live one with the
-    column merged in, from two VMEM buffers a cache."""
+    column merged in, from two VMEM buffers a cache. ``sink`` [H, R, 128]
+    f32: a query row's scalar in every lane, what the running maximum
+    starts from. The values may be narrower or wider than the keys."""
     B, H = k_cache.shape[:2]
     _, R, D = q.shape
+    Dv = v_cache.shape[3]
     hb, bk = tile
     nk = k_cache.shape[2] // bk
     if minor:
         k_cache, v_cache = k_cache.swapaxes(2, 3), v_cache.swapaxes(2, 3)
-    q_spec = pl.BlockSpec((hb, R, D),
-                          lambda b, hg, ik, *_: (b * (H // hb) + hg, 0, 0))
+    rows_of = lambda b, hg, ik, *_: (b * (H // hb) + hg, 0, 0)
+    q_spec = pl.BlockSpec((hb, R, D), rows_of)
+    o_spec = pl.BlockSpec((hb, R, Dv), rows_of)
     kv_block = (1, hb, D, bk) if minor else (1, hb, bk, D)
-    kv_spec = pl.BlockSpec(kv_block, _kv_index_map(q_len, bk, nk, minor))
+    kv_map = _kv_index_map(q_len, bk, nk, minor)
+    kv_spec = pl.BlockSpec(kv_block, kv_map)
+    v_spec = kv_spec if Dv == D else pl.BlockSpec((1, hb, bk, Dv), kv_map)
     scalars, operands = [lengths], [q, k_cache, v_cache]
-    in_specs, out_specs = [q_spec, kv_spec, kv_spec], [q_spec]
-    out_shape = [_out_sds((B * H, R, D), q.dtype, q, k_cache, v_cache)]
+    in_specs, out_specs = [q_spec, kv_spec, v_spec], [o_spec]
+    out_shape = [_out_sds((B * H, R, Dv), q.dtype, q, k_cache, v_cache)]
     scratch = [pltpu.VMEM((hb, R, 128), jnp.float32),     # running max
                pltpu.VMEM((hb, R, 128), jnp.float32),     # running denom
-               pltpu.VMEM((hb, R, D), jnp.float32)]       # numerator acc
+               pltpu.VMEM((hb, R, Dv), jnp.float32)]      # numerator acc
+    if sink is not None:
+        operands.append(sink)
+        in_specs.append(pl.BlockSpec((hb, R, 128),
+                                     lambda b, hg, ik, *_: (hg, 0, 0)))
     aliases, order = {}, ("parallel", "parallel", "arbitrary")
     if append is not None:
         k_new, v_new, keep = append
@@ -607,7 +675,8 @@ def _decode_call(q, k_cache, v_cache, lengths, tile, *, minor, scale, group,
     )
     o, *caches = pl.pallas_call(
         functools.partial(_decode_kernel, scale, int(group), int(q_len),
-                          minor, bool(whole), append is not None),
+                          minor, bool(whole), append is not None,
+                          sink is not None),
         grid_spec=grid_spec,
         out_shape=out_shape,
         input_output_aliases=aliases,
@@ -625,9 +694,17 @@ def flash_attention_decode(q, k_cache, v_cache, lengths, *,
                            scale=None, num_heads: int = 1,
                            page_size: int = 128, group: int = 1,
                            interpret: bool = False,
-                           whole_chunk: bool = False, append=None):
+                           whole_chunk: bool = False, append=None,
+                           sink=None):
     """One decode/verify chunk: q [BH, Sq, D] (1 <= Sq <= 8) against paged
-    caches [BH, S_max, D].
+    caches [BH, S_max, D] (the values' may be [BH, S_max, Dv], another
+    width than the keys': the output is then [BH, Sq, Dv], and both caches
+    are read as declared).
+
+    ``sink`` ([num_heads * group] f32, a scalar a query head, or None): the
+    head's scalar joins its softmax as one more column that carries no
+    value, ``p_j = exp(s_j - m) / (sum_j' exp(s_j' - m) + exp(sink -
+    m))``: it seeds the running maximum and denominator.
 
     ``lengths`` is per-BATCH ([B] int, B = BH // num_heads): the number of
     valid key rows visible to query row 0; row ``i`` sees ``lengths + i``
@@ -670,7 +747,7 @@ def flash_attention_decode(q, k_cache, v_cache, lengths, *,
     are that route's bit for bit.
     """
     BH, Sq, D = q.shape
-    Sk = k_cache.shape[1]
+    Sk, Dv = k_cache.shape[1], v_cache.shape[2]
     if Sq % group or not 1 <= Sq // group <= KERNEL_ROWS:
         raise ValueError(
             f"flash_attention_decode is the q_len<={KERNEL_ROWS} chunk "
@@ -688,9 +765,9 @@ def flash_attention_decode(q, k_cache, v_cache, lengths, *,
         raise ValueError(
             f"lengths has {B} rows but q has BH={BH} with "
             f"num_heads={num_heads} (expected {BH // num_heads})")
-    minor = rows_minor(D, k_cache.dtype, min(page_size, Sk))
+    minor = Dv == D and rows_minor(D, k_cache.dtype, min(page_size, Sk))
     if append is not None:
-        if not minor or Sq != group or whole_chunk:
+        if not minor or Sq != group or whole_chunk or sink is not None:
             raise ValueError(
                 f"flash_attention_decode appends one row a step to a "
                 f"rows-minor cache, got q_len={Sq // group}, "
@@ -707,14 +784,21 @@ def flash_attention_decode(q, k_cache, v_cache, lengths, *,
     else:
         q8 = jnp.concatenate(
             [q, jnp.broadcast_to(q[:, -1:, :], (BH, R - Sq, D))], axis=1)
+    if sink is not None:
+        # row i of a key/value head's tile is its query head i % group
+        # (padding rows take any head's); the scalar in every lane, as the
+        # running maximum is kept
+        per_row = jnp.asarray(sink, jnp.float32).reshape(num_heads, group)[
+            :, jnp.arange(R) % group]
+        sink = jnp.broadcast_to(per_row[:, :, None], (num_heads, R, 128))
     # a sequence's heads beside each other: they share its length, so one
     # grid step can carry a page of each
     out = _decode_call(
         q8, k_cache.reshape(B, num_heads, Sk, D),
-        v_cache.reshape(B, num_heads, Sk, D), lengths,
-        kv_tile(num_heads, Sk, D, k_cache.dtype, page_size),
+        v_cache.reshape(B, num_heads, Sk, Dv), lengths,
+        kv_tile(num_heads, Sk, D, k_cache.dtype, page_size, v_dim=Dv),
         minor=minor, scale=scale, group=group, q_len=Sq // group,
-        interpret=interpret, whole=whole_chunk, append=append)
+        interpret=interpret, whole=whole_chunk, append=append, sink=sink)
     if append is None:
         return out[:, :Sq, :]
     return (out[0][:, :Sq, :],

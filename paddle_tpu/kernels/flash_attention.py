@@ -46,7 +46,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention", "flash_attention_with_lse",
-           "flash_attention_bwd", "supports_shapes", "classify_shapes"]
+           "flash_attention_bwd", "supports_shapes", "classify_shapes",
+           "window_block_visits"]
 
 NEG_INF = -1e30          # finite sentinel: (-inf) - (-inf) would NaN
 
@@ -82,6 +83,16 @@ class _Cfg:
     # sees every key of its own block, in both directions, and of the
     # blocks before it. Rides the causal comparison.
     causal_block: int = 0
+    # a sink: one f32 scalar a query head joins the softmax as a column
+    # with no value (it seeds the running maximum and denominator).
+    # Forward only.
+    has_sink: bool = False
+    # > 0 (with a window): the k axis of the forward grid has only the
+    # `k_steps` k-blocks a q-block's window can touch, from the first one
+    # it does (:func:`_k_range`); blocks the window hides from a whole
+    # q-block are neither fetched nor scored. 0: every k-block is visited.
+    k_steps: int = 0
+    num_k_blocks: int = 0    # with `k_steps`: the k-blocks there are
 
 
 def classify_shapes(sq: int, sk: int, block_q: int = 128,
@@ -178,11 +189,68 @@ def _visible(cfg: "_Cfg", q_pos, k_pos):
     return seen
 
 
+def _window_steps(window: int, block_q: int, block_k: int, nk: int,
+                  aligned: bool) -> int:
+    """The k-blocks one q-block of a windowed layer can touch, at most:
+    its rows see ``block_q + window - 1`` consecutive key positions. Where
+    the q-blocks start on k-block edges (``aligned``: equal static
+    offsets, ``block_q`` whole k-blocks) the count is exact; else the
+    interval may straddle one block more. 0 where that is every block
+    (nothing to skip)."""
+    span = block_q + window - 1
+    if aligned:
+        n = ((1 - window) % block_k + span - 1) // block_k + 1
+    else:
+        n = (span - 2) // block_k + 2
+    return n if n < nk else 0
+
+
+def _k_range(cfg: "_Cfg", q_off, k_off, iq, nk: int):
+    """``(first, last)`` k-block any row of q-block ``iq`` sees under the
+    window and the causal mask (``last < first``: none). On traced scalars
+    (the kernel and its index maps) and on host integers (the count of
+    :func:`window_block_visits`) alike."""
+    q_lo = q_off + iq * cfg.block_q
+    first = q_lo - cfg.window + 1 - k_off
+    last = q_lo + cfg.block_q - 1 - k_off
+    if not isinstance(last, jax.Array):
+        return (max(first, 0) // cfg.block_k,
+                min(last // cfg.block_k, nk - 1) if last >= 0 else -1)
+    return (jnp.maximum(first, 0) // cfg.block_k,
+            jnp.where(last >= 0,
+                      jnp.minimum(jnp.maximum(last, 0) // cfg.block_k,
+                                  nk - 1), -1))
+
+
+def window_block_visits(sq: int, sk: int, window: int, block_q: int = 128,
+                        block_k: int = 128):
+    """``(visited, grid)``: the (q-block, k-block) pairs one head of the
+    forward kernel scores for ``sq`` query rows over ``sk`` keys from
+    position 0, and the pairs of the whole grid. Host arithmetic on the
+    kernel's own range (:func:`_k_range`): with a window (and a k axis
+    worth shortening) only the blocks the window touches, else all."""
+    bq, bk = min(block_q, sq), min(block_k, sk)
+    nq, nk = sq // bq, sk // bk
+    if not window or not _window_steps(window, bq, bk, nk, bq % bk == 0):
+        return nq * nk, nq * nk
+    cfg = _Cfg(causal=True, scale=1.0, dropout=0.0, block_q=bq, block_k=bk,
+               num_heads=1, has_bias=False, interpret=False,
+               precision="default", window=int(window))
+    seen = 0
+    for iq in range(nq):
+        first, last = _k_range(cfg, 0, 0, iq, nk)
+        seen += max(0, last - first + 1)
+    return seen, nq * nk
+
+
 # --------------------------------------------------------------------------
 # forward
 # --------------------------------------------------------------------------
 
 def _fwd_kernel(cfg: _Cfg, scal_ref, *refs):
+    sink_ref = None
+    if cfg.has_sink:
+        sink_ref, refs = refs[0], refs[1:]
     if cfg.has_bias:
         q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref, m_scr, l_scr, acc = refs
     else:
@@ -193,43 +261,64 @@ def _fwd_kernel(cfg: _Cfg, scal_ref, *refs):
 
     @pl.when(ik == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
+        if cfg.has_sink:
+            # the sink's column: its score is the running maximum, its
+            # exp(0) = 1 the denominator, and it adds nothing to the sum
+            m_scr[:] = jnp.full_like(m_scr, sink_ref[bh % cfg.num_heads])
+            l_scr[:] = jnp.ones_like(l_scr)
+        else:
+            m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+            l_scr[:] = jnp.zeros_like(l_scr)
         acc[:] = jnp.zeros_like(acc)
 
-    q = q_ref[0]                                   # [bq, D]
-    k = k_ref[0]                                   # [bk, D]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32,
-                            precision=cfg.precision)
-    s = s * cfg.scale                              # [bq, bk] f32
-    if cfg.has_bias:
-        s = s + b_ref[0, 0].astype(jnp.float32)[None, :]
-    if cfg.causal:
-        q_pos = (scal_ref[0] + iq * cfg.block_q
-                 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
-        k_pos = (scal_ref[1] + ik * cfg.block_k
-                 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
-        s = jnp.where(_visible(cfg, q_pos, k_pos), s, NEG_INF)
+    kb = ik                     # the k-block this step holds
+    if cfg.k_steps:
+        first, last = _k_range(cfg, scal_ref[0], scal_ref[1], iq,
+                               cfg.num_k_blocks)
+        kb = first + ik
 
-    m_prev = m_scr[:, :1]                          # [bq, 1]
-    m_cur = jnp.max(s, axis=1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    alive = m_new > NEG_INF * 0.5
-    m_safe = jnp.where(alive, m_new, 0.0)
-    corr = jnp.exp(m_prev - m_safe)                # underflows to 0 if dead
-    p = jnp.exp(s - m_safe)                        # masked s -> exp(-1e30)=0
-    l_new = corr * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True)
-    if cfg.dropout > 0.0:
-        keep = _dropout_keep(scal_ref[2], bh, iq, ik, s.shape, cfg.dropout)
-        p = jnp.where(keep, p / (1.0 - cfg.dropout), 0.0)
-    pv = jax.lax.dot_general(p.astype(v_ref.dtype), v_ref[0],
-                             (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32,
-                            precision=cfg.precision)
-    acc[:] = acc[:] * corr + pv
-    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-    l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+    def _step():
+        q = q_ref[0]                                   # [bq, D]
+        k = k_ref[0]                                   # [bk, D]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32,
+                                precision=cfg.precision)
+        s = s * cfg.scale                              # [bq, bk] f32
+        if cfg.has_bias:
+            s = s + b_ref[0, 0].astype(jnp.float32)[None, :]
+        if cfg.causal:
+            q_pos = (scal_ref[0] + iq * cfg.block_q
+                     + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
+            k_pos = (scal_ref[1] + kb * cfg.block_k
+                     + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
+            s = jnp.where(_visible(cfg, q_pos, k_pos), s, NEG_INF)
+
+        m_prev = m_scr[:, :1]                          # [bq, 1]
+        m_cur = jnp.max(s, axis=1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        alive = m_new > NEG_INF * 0.5
+        m_safe = jnp.where(alive, m_new, 0.0)
+        corr = jnp.exp(m_prev - m_safe)            # underflows to 0 if dead
+        p = jnp.exp(s - m_safe)                    # masked s -> exp(-1e30)=0
+        l_new = corr * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True)
+        if cfg.dropout > 0.0:
+            keep = _dropout_keep(scal_ref[2], bh, iq, kb, s.shape,
+                                 cfg.dropout)
+            p = jnp.where(keep, p / (1.0 - cfg.dropout), 0.0)
+        pv = jax.lax.dot_general(p.astype(v_ref.dtype), v_ref[0],
+                                 (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32,
+                                 precision=cfg.precision)
+        acc[:] = acc[:] * corr + pv
+        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+
+    if cfg.k_steps:
+        # a step past the window's last block holds that block again (its
+        # index map repeats it: no DMA) and scores nothing
+        pl.when(kb <= last)(_step)
+    else:
+        _step()
 
     @pl.when(ik == num_k - 1)
     def _finish():
@@ -252,53 +341,67 @@ _PALLAS_SCOPE = "pallas"
 
 
 @jax.named_scope(_PALLAS_SCOPE)
-def _fwd(cfg: _Cfg, q, k, v, bias, scalars):
+def _fwd(cfg: _Cfg, q, k, v, bias, scalars, sink=None):
     BH, Sq, D = q.shape
-    Sk = k.shape[1]
+    Sk, Dv = k.shape[1], v.shape[2]
     nq, nk = Sq // cfg.block_q, Sk // cfg.block_k
+
+    def k_block(iq, ik, s):
+        """The k-block grid step ``ik`` of q-block ``iq`` holds: itself,
+        or, on a k axis cut to the window's reach, the window's first
+        block and on, the last one again past it (no DMA for an index
+        that repeats)."""
+        if not cfg.k_steps:
+            return ik
+        first, last = _k_range(cfg, s[0], s[1], iq, nk)
+        return jnp.minimum(first + ik, jnp.maximum(last, 0))
+
     if cfg.kv_group == 1:
-        kv_map = lambda bh, iq, ik, s: (bh, ik, 0)
+        kv_map = lambda bh, iq, ik, s, *_: (bh, k_block(iq, ik, s), 0)
     else:       # bh = b * Hq + h reads b * Hkv + h // G = bh // G
-        kv_map = lambda bh, iq, ik, s: (bh // cfg.kv_group, ik, 0)
+        kv_map = lambda bh, iq, ik, s, *_: (bh // cfg.kv_group,
+                                            k_block(iq, ik, s), 0)
+    q_map = lambda bh, iq, ik, *_: (bh, iq, 0)
     in_specs = [
-        pl.BlockSpec((1, cfg.block_q, D), lambda bh, iq, ik, s: (bh, iq, 0)),
+        pl.BlockSpec((1, cfg.block_q, D), q_map),
         pl.BlockSpec((1, cfg.block_k, D), kv_map),
-        pl.BlockSpec((1, cfg.block_k, D), kv_map),
+        pl.BlockSpec((1, cfg.block_k, Dv), kv_map),
     ]
     args = [q, k, v]
     if cfg.has_bias:
         H = cfg.num_heads
-        in_specs.append(pl.BlockSpec((1, 8, cfg.block_k),
-                                     lambda bh, iq, ik, s: (bh // H, 0, ik)))
+        in_specs.append(pl.BlockSpec(
+            (1, 8, cfg.block_k),
+            lambda bh, iq, ik, s, *_: (bh // H, 0, k_block(iq, ik, s))))
         args.append(_rows8(bias))
+    prefetch = [scalars] if sink is None else [scalars, sink]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(BH, nq, nk),
+        num_scalar_prefetch=len(prefetch),
+        grid=(BH, nq, cfg.k_steps or nk),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, cfg.block_q, D),
-                         lambda bh, iq, ik, s: (bh, iq, 0)),
+            pl.BlockSpec((1, cfg.block_q, Dv), q_map),
             pl.BlockSpec((1, 8, cfg.block_q),
-                         lambda bh, iq, ik, s: (bh, 0, iq)),
+                         lambda bh, iq, ik, *_: (bh, 0, iq)),
         ],
         scratch_shapes=[
             pltpu.VMEM((cfg.block_q, 128), jnp.float32),   # running max
             pltpu.VMEM((cfg.block_q, 128), jnp.float32),   # running denom
-            pltpu.VMEM((cfg.block_q, D), jnp.float32),     # numerator acc
+            pltpu.VMEM((cfg.block_q, Dv), jnp.float32),    # numerator acc
         ],
     )
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, cfg),
         grid_spec=grid_spec,
         out_shape=[
-            _out_sds((BH, Sq, D), q.dtype, q, k, v),
+            _out_sds((BH, Sq, Dv), q.dtype, q, k, v),
             _out_sds((BH, 8, Sq), jnp.float32, q, k, v),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=cfg.interpret,
         name="flash_attention_fwd",
-    )(scalars, *args)
+    )(*prefetch, *args)
     return o, lse[:, 0, :]
 
 
@@ -482,6 +585,19 @@ def _flash(cfg: _Cfg, q, k, v, bias, scalars):
     return _fwd(cfg, q, k, v, bias, scalars)
 
 
+def _forward_only(cfg: _Cfg, v_dim: int, head_dim: int) -> Optional[str]:
+    """Why this call has no backward kernels, or None where it has."""
+    if cfg.kv_group != 1:
+        return "grouped-query heads: the dK/dV kernel does not sum over " \
+               "a group's query heads"
+    if cfg.has_sink:
+        return "a sink column: the backward kernels do not carry it"
+    if v_dim != head_dim:
+        return f"values of {v_dim} beside keys of {head_dim}: the " \
+               f"backward kernels take one width"
+    return None
+
+
 def _flash_fwd_rule(cfg, q, k, v, bias, scalars):
     o, lse = _fwd(cfg, q, k, v, bias, scalars)
     return (o, lse), (q, k, v, bias, scalars, o, lse)
@@ -498,10 +614,9 @@ def _flash_bwd_rule(cfg, res, cts):
 def _bwd_from_residuals(cfg, q, k, v, bias, scalars, o, lse, do, dlse=None):
     """(dQ, dK, dV) from what the forward kernel left: its output and its
     log-sum-exp. No forward call."""
-    if cfg.kv_group != 1:
-        raise NotImplementedError(
-            "flash attention backward with grouped-query heads: the dK/dV "
-            "kernel does not sum over a group's query heads")
+    why = _forward_only(cfg, v.shape[-1], q.shape[-1])
+    if why:
+        raise NotImplementedError(f"flash attention backward with {why}")
     # delta_i = sum_d dO_id * O_id  = rowsum(P_dropped * dP); the lse
     # cotangent enters the same P-weighted term (d lse/dS = P), so it folds
     # in by subtraction.
@@ -548,6 +663,14 @@ def _prepare(q, k, bias, causal, scale, dropout_rate, seed, q_offset,
                           else "default"),
                window=int(window), kv_group=BH // k.shape[0],
                causal_block=int(causal_block))
+    if window:
+        # the forward's k axis holds only the blocks a window can touch
+        static = isinstance(q_offset, int) and isinstance(k_offset, int)
+        steps = _window_steps(
+            cfg.window, bq, bk, Sk // bk,
+            static and (q_offset - k_offset) % bk == 0 and bq % bk == 0)
+        cfg = dataclasses.replace(cfg, k_steps=steps,
+                                  num_k_blocks=Sk // bk)
     scalars = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                          jnp.asarray(k_offset, jnp.int32),
                          jnp.asarray(seed, jnp.int32)])
@@ -563,7 +686,7 @@ def flash_attention_with_lse(q, k, v, bias: Optional[jax.Array] = None,
                              num_heads: int = 1,
                              block_q: int = 128, block_k: int = 128,
                              interpret: bool = False, window: int = 0,
-                             causal_block: int = 0):
+                             causal_block: int = 0, sink=None):
     """Flash attention over [B*H, S, D] tensors; returns (O, lse).
 
     ``bias`` is an additive [B, Sk] key bias (the padding-mask encoding —
@@ -582,12 +705,24 @@ def flash_attention_with_lse(q, k, v, bias: Optional[jax.Array] = None,
     causal by blocks of L positions: key ``j`` is visible to query ``i``
     iff ``j // L <= i // L`` (block-diffusion prefill). Only the tiles on
     the diagonal differ from the row-causal ones.
+
+    ``v`` may be [.., Sk, Dv] with ``Dv`` other than ``D`` (the output is
+    then [B*H, Sq, Dv]). ``sink`` ([num_heads] f32): a head's scalar joins
+    its softmax as one more column that carries no value, ``p_ij =
+    exp(s_ij - m) / (sum_j' exp(s_ij' - m) + exp(sink_h - m))``; ``lse``
+    counts it. Both forward only. With a window the kernel visits only
+    the k-blocks some row of a q-block sees (:func:`window_block_visits`).
     """
     cfg, bias, scalars = _prepare(q, k, bias, causal, scale, dropout_rate,
                                   seed, q_offset, k_offset, num_heads,
                                   block_q, block_k, interpret, window,
                                   causal_block)
-    return _flash(cfg, q, k, v, bias, scalars)
+    if sink is None and v.shape[-1] == q.shape[-1]:
+        return _flash(cfg, q, k, v, bias, scalars)
+    if sink is not None:            # forward only, like the other width
+        cfg = dataclasses.replace(cfg, has_sink=True)
+        sink = jnp.asarray(sink, jnp.float32).reshape(cfg.num_heads)
+    return _fwd(cfg, q, k, v, bias, scalars, sink)
 
 
 def flash_attention_bwd(q, k, v, o, lse, do,
@@ -613,11 +748,11 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
                     dropout_rate: float = 0.0, seed=0,
                     num_heads: int = 1, block_q: int = 128,
                     block_k: int = 128, interpret: bool = False,
-                    window: int = 0, causal_block: int = 0):
+                    window: int = 0, causal_block: int = 0, sink=None):
     """Like :func:`flash_attention_with_lse` but returns only O."""
     o, _ = flash_attention_with_lse(
         q, k, v, bias=bias, causal=causal, scale=scale,
         dropout_rate=dropout_rate, seed=seed, num_heads=num_heads,
         block_q=block_q, block_k=block_k, interpret=interpret,
-        window=window, causal_block=causal_block)
+        window=window, causal_block=causal_block, sink=sink)
     return o

@@ -390,7 +390,7 @@ def matmul(x, y, transpose_x=False, transpose_y=False, alpha=1.0, name=None,
 def fused_multihead_attention(q, k, v, bias_qk=None, causal=False,
                               scale=0.0, attn_dropout=0.0, is_test=False,
                               sequence_parallel=False, name=None, window=0,
-                              causal_block=0):
+                              causal_block=0, sink=None):
     """Fused multi-head attention (the reference `operators/fused/` role,
     here a Pallas flash kernel on TPU — ops/fused_attention.py).
 
@@ -407,7 +407,10 @@ def fused_multihead_attention(q, k, v, bias_qk=None, causal=False,
     k/v may carry a whole fraction of q's heads (grouped-query attention,
     inference only); window > 0 with causal is a sliding window;
     causal_block = L > 0 with causal makes the mask causal by blocks of L
-    rows (key j visible to query i iff j // L <= i // L).
+    rows (key j visible to query i iff j // L <= i // L). v may be
+    [B, heads, S, Dv] with another width than the keys' (the result is
+    then [B, num_heads, S, Dv]); sink [num_heads] float32 joins each
+    head's softmax as one more column with no value (both inference only).
 
     The op also keeps the softmax's log-sum-exp ([B, num_heads, S],
     float32) for its gradient op, as layer_norm keeps Mean/Variance; a
@@ -419,6 +422,8 @@ def fused_multihead_attention(q, k, v, bias_qk=None, causal=False,
     inputs = {"Q": q, "K": k, "V": v}
     if bias_qk is not None:
         inputs["BiasQK"] = bias_qk
+    if sink is not None:
+        inputs["Sink"] = sink
     attrs = {"causal": causal, "scale": scale, "attn_dropout": attn_dropout,
              "is_test": is_test, "sequence_parallel": sequence_parallel}
     if window:

@@ -35,7 +35,8 @@ __all__ = [
     "sampled_softmax_with_cross_entropy", "py_func", "resize_trilinear",
     "lstm_unit", "autoincreased_step_counter", "adaptive_pool3d",
     "beam_search", "beam_search_decode", "filter_by_instag",
-    "fused_decode_attention", "kv_cache_append", "sequence_gather",
+    "fused_decode_attention", "kv_cache_append", "kv_cache_fold",
+    "sequence_gather",
     "rotary_embedding", "moe_experts", "slot_assign", "gated_delta_rule",
     "mamba2_scan", "rms_norm", "latent_attention",
     "sample_token", "spec_accept",
@@ -925,7 +926,8 @@ def filter_by_instag(ins, ins_tag, filter_tag, is_lod=True):
 
 def fused_decode_attention(q, k_new, v_new, cache_k, cache_v, positions,
                            scale=0.0, page_size=128, slot_mask=None,
-                           window=0, name=None, whole_chunk=False):
+                           window=0, name=None, whole_chunk=False,
+                           sink=None):
     """One decode/verify chunk with the KV append fused in
     (ops/generation.py). q/k_new/v_new: [B, H, C, D] (C == 1 is the
     classic decode step; C <= 8 rides the chunk kernel); cache_k/cache_v:
@@ -942,7 +944,10 @@ def fused_decode_attention(q, k_new, v_new, cache_k, cache_v, positions,
     context [B, H, C, D] is returned. scale=0.0 means 1/sqrt(D).
     ``q`` may carry a whole multiple of the caches' heads (grouped-query
     attention). ``window`` > 0: the caches are a ring of
-    ``min(window, max_seq)`` rows holding the last positions (C == 1)."""
+    ``min(window, max_seq)`` rows holding the last positions (C == 1).
+    ``v_new``/``cache_v`` may be ``Dv`` wide beside keys of ``D`` (the
+    context is then [B, H, C, Dv]); ``sink`` [H] float32 joins each query
+    head's softmax as one more column with no value."""
     helper = LayerHelper("fused_decode_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
     inputs = {"Q": q, "KNew": k_new, "VNew": v_new,
@@ -950,6 +955,8 @@ def fused_decode_attention(q, k_new, v_new, cache_k, cache_v, positions,
               "Positions": positions}
     if slot_mask is not None:
         inputs["SlotMask"] = slot_mask
+    if sink is not None:
+        inputs["Sink"] = sink
     attrs = {"scale": float(scale), "page_size": int(page_size)}
     if window:
         attrs["window"] = int(window)
@@ -1141,6 +1148,28 @@ def kv_cache_append(cache, new, positions, slot_mask=None, slots=None,
     helper.append_op("kv_cache_append", inputs=inputs,
                      outputs={"Out": cache})
     return cache
+
+
+def kv_cache_fold(cache, new, lengths, slot_mask=None, slots=None,
+                  name=None):
+    """A whole prompt past a window layer's ring, folded into it
+    (ops/generation.py): ``new`` [R, H, S, D] of ``lengths`` [R, 1] tokens
+    into ``cache`` [B, H, W, D] with ``W < S``; ring row ``r`` takes the
+    last position ``p < length`` with ``p % W == r``, in the slot
+    ``slots[i]`` names, where ``slot_mask[i]`` > 0. Writes in place into
+    ``cache``; returns ``(cache, stats [2] int32: rows kept, rows
+    dropped)``."""
+    helper = LayerHelper("kv_cache_fold", name=name)
+    stats = helper.create_variable_for_type_inference("int32",
+                                                      stop_gradient=True)
+    inputs = {"Cache": cache, "New": new, "Lengths": lengths}
+    if slot_mask is not None:
+        inputs["SlotMask"] = slot_mask
+    if slots is not None:
+        inputs["Slots"] = slots
+    helper.append_op("kv_cache_fold", inputs=inputs,
+                     outputs={"Out": cache, "Stats": stats})
+    return cache, stats
 
 
 def slot_assign(x, slots, updates, mask=None, name=None):

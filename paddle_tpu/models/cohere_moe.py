@@ -186,7 +186,7 @@ def _head(h2d, cfg: CohereMoeConfig):
 
 def _prefill_handle(cfg, caches, pmask, plen, smask, slots, page_size):
     return decoder.bulk_attend(caches, pmask, smask, slots,
-                               1.0 / math.sqrt(cfg.head_dim))
+                               1.0 / math.sqrt(cfg.head_dim), plen=plen)
 
 
 def _decode_handle(cfg, caches, pos, active, page_size):
@@ -203,16 +203,12 @@ def build_cohere_moe_generative(cfg: CohereMoeConfig = None,
     """What ``serving.GenerativeEngine`` needs
     (``decoder.build_generative``). A prefill dispatch carries
     ``prefill_rows`` sequences (default: one per slot), each naming its
-    slot. A bucket has to fit a sliding layer's cache."""
+    slot. A bucket past a sliding layer's window is folded into its ring
+    (``decoder.bulk_attend``)."""
     cfg = cfg or CohereMoeConfig.tiny()
     rows = min(cfg.cache_rows(i, max_seq) for i in range(cfg.num_layers))
-    longest = max((int(b) for b in prompt_buckets), default=0)
-    if longest > rows or rows % page_size:
-        raise ValueError(
-            f"prompt bucket {longest} against caches of {rows} "
-            f"rows in pages of {page_size}: prefill writes a whole bucket "
-            f"into every layer's cache at row 0, so a prompt past a "
-            f"sliding layer's window cannot be admitted yet")
+    if rows % page_size:
+        raise ValueError(f"caches of {rows} rows in pages of {page_size}")
     parts = decoder.Parts(cfg, _state_vars, _embed, _stack_layers, _head,
                           _prefill_handle, _decode_handle)
     return decoder.build_from_parts(parts, batch_slots, max_seq, page_size,

@@ -280,39 +280,72 @@ class Mix:
     recur: object
 
 
-def bulk_attend(caches, pmask, smask, slots, scale: float):
-    """A prefill's handle ``attend(i, q, k, v, window=0)``: each row's whole
-    prompt ``k``/``v`` ([R, kv_heads, S, D]) goes to row 0 of layer ``i``'s
-    cache pair in the slot the row names (slots that no row in use names
-    keep their pages), and the context is full-sequence causal attention
-    under the key-padding bias of ``pmask``."""
+def _as_wide_as(t, cache):
+    """``t`` [.., D] with zeros after its last axis up to the cache's row
+    width: a key cache may keep its rows in whole lane tiles (192 numbers
+    in 256), and a zero adds nothing to a query's product with a key."""
+    extra = cache.shape[-1] - t.shape[-1]
+    if not extra:
+        return t
+    return layers.pad(t, [0, 0] * (len(t.shape) - 1) + [0, extra])
+
+
+def bulk_attend(caches, pmask, smask, slots, scale: float, plen=None,
+                folded=None):
+    """A prefill's handle ``attend(i, q, k, v, window=0, sink=None)``: each
+    row's whole prompt ``k``/``v`` ([R, kv_heads, S, D]; the values may be
+    another width) goes to layer ``i``'s cache pair in the slot the row
+    names (slots that no row in use names keep their pages), and the
+    context is full-sequence causal attention under the key-padding bias of
+    ``pmask``, with the layer's ``sink`` [heads] where it has one. A bucket
+    that fits the cache's rows is written at row 0. A window layer's cache
+    is a ring of ``window`` rows: a bucket longer than that is folded into
+    it by each sequence's own length ``plen`` [R, 1]
+    (``layers.kv_cache_fold``), so that the decode step's wrap goes on
+    from it, and the attention runs over the whole bucket under the window
+    mask. ``folded``: a list that takes each fold's statistics."""
     # additive key-padding bias [R,1,1,S]: (mask-1)*10000, bert idiom
     bias = layers.unsqueeze(
         layers.scale(pmask, scale=10000.0, bias=-10000.0), [1, 2])
     zero_pos = layers.fill_constant([pmask.shape[0], 1], "int64", 0)
     S = pmask.shape[1]
 
-    def attend(i, q, k, v, window=0):
+    def attend(i, q, k, v, window=0, sink=None):
         for cache, new in zip(caches[i], (k, v)):
-            layers.kv_cache_append(cache, new, zero_pos, slot_mask=smask,
-                                   slots=slots)
+            new = _as_wide_as(new, cache)
+            if S <= cache.shape[2]:
+                layers.kv_cache_append(cache, new, zero_pos, slot_mask=smask,
+                                       slots=slots)
+                continue
+            if not window or plen is None:
+                raise ValueError(
+                    f"a bucket of {S} rows for layer {i}'s cache of "
+                    f"{cache.shape[2]}: only a window layer's ring takes a "
+                    f"longer prompt, by its length")
+            _, stats = layers.kv_cache_fold(cache, new, plen,
+                                            slot_mask=smask, slots=slots)
+            if folded is not None:
+                folded.append(stats)
         return layers.fused_multihead_attention(
             q, k, v, bias_qk=bias, causal=True, scale=scale, is_test=True,
-            window=window if window < S else 0)
+            window=window if window < S else 0, sink=sink)
 
     return attend
 
 
 def step_attend(caches, at, mask, scale: float, page_size: int):
-    """A step's handle ``attend(i, q, k, v, window=0)``: append and attend
-    in ONE op, the caches' only read and write site, which is what keeps
-    them donation-provable; the rows go in at ``at`` [B, 1] and ``mask``
-    [B, 1] keeps every other slot's pages bit-untouched."""
-    def attend(i, q, k, v, window=0):
+    """A step's handle ``attend(i, q, k, v, window=0, sink=None)``: append
+    and attend in ONE op, the caches' only read and write site, which is
+    what keeps them donation-provable; the rows go in at ``at`` [B, 1] and
+    ``mask`` [B, 1] keeps every other slot's pages bit-untouched. Where the
+    key cache's rows are wider than a key, query and key ride zero-padded
+    (``scale`` is the model's, not the padded width's)."""
+    def attend(i, q, k, v, window=0, sink=None):
         ck, cv = caches[i]
         return layers.fused_decode_attention(
-            q, k, v, ck, cv, at, scale=scale, page_size=page_size,
-            slot_mask=mask, window=window)
+            _as_wide_as(q, ck), _as_wide_as(k, ck), v, ck, cv, at,
+            scale=scale, page_size=page_size, slot_mask=mask, window=window,
+            sink=sink)
 
     return attend
 

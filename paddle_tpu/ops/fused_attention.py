@@ -61,8 +61,10 @@ def _route(sq: int, sk: int, dropout: float, platform=None) -> str:
 
 
 def _primitive_attention(ctx, q, k, v, bias, causal, scale, dropout,
-                         is_test, window=0, causal_block=0):
-    """[BH, S, D] oracle path; matches the kernel semantics exactly."""
+                         is_test, window=0, causal_block=0, sink=None):
+    """[BH, S, D] oracle path; matches the kernel semantics exactly.
+    ``sink`` [heads]: a head's scalar as one more column of its softmax,
+    which carries no value."""
     prec = ("highest" if q.dtype == jnp.float32 else "default")
     if k.shape[0] != q.shape[0]:            # grouped-query heads
         G = q.shape[0] // k.shape[0]
@@ -79,7 +81,12 @@ def _primitive_attention(ctx, q, k, v, bias, causal, scale, dropout,
         d = qi - kj
         m = (d >= 0) & (d < window) if window else d >= 0
         s = jnp.where(m[None], s, jnp.asarray(-1e30, s.dtype))
-    p = jax.nn.softmax(s, axis=-1)
+    if sink is not None:
+        col = jnp.tile(sink.astype(s.dtype), q.shape[0] // sink.shape[0])
+        s = jnp.concatenate(
+            [s, jnp.broadcast_to(col[:, None, None], s.shape[:2] + (1,))],
+            axis=-1)
+    p = jax.nn.softmax(s, axis=-1)[..., :k.shape[1]]
     if dropout > 0.0 and not is_test:
         keep = jax.random.bernoulli(ctx.rng(), 1.0 - dropout, p.shape)
         p = jnp.where(keep, p / (1.0 - dropout), 0.0)
@@ -94,6 +101,7 @@ class _Plan:
         q, k = x(ins, "Q"), x(ins, "K")
         self.B, self.H, self.Sq, self.D = q.shape
         self.Hkv, self.Sk = k.shape[1], k.shape[2]
+        self.Dv = x(ins, "V").shape[3]
         self.scale = attrs["scale"] or float(self.D) ** -0.5
         self.is_test = bool(attrs.get("is_test"))
         self.dropout = 0.0 if self.is_test else float(attrs["attn_dropout"])
@@ -196,7 +204,8 @@ def _fused_mha_grad(ctx, ins, attrs):
 
 @register_op("fused_multihead_attention",
              inputs=[IOSpec("Q"), IOSpec("K"), IOSpec("V"),
-                     IOSpec("BiasQK", optional=True, no_grad=True)],
+                     IOSpec("BiasQK", optional=True, no_grad=True),
+                     IOSpec("Sink", optional=True, no_grad=True)],
              outputs=["Out",
                       IOSpec("SoftmaxLse", optional=True, no_grad=True)],
              attrs={"causal": False, "scale": 0.0, "attn_dropout": 0.0,
@@ -230,10 +239,21 @@ def _fused_mha(ctx, ins, attrs):
     key ``j`` is visible to query ``i`` iff ``0 <= i - j < window``.
     ``causal_block`` = L > 0 (with ``causal``, no window) is the mask of a
     block-diffusion prefill, causal by blocks of L rows: key ``j`` is
-    visible to query ``i`` iff ``j // L <= i // L``."""
+    visible to query ``i`` iff ``j // L <= i // L``.
+
+    ``V`` may be [B, heads, S, Dv] with another width than the keys'
+    (``Out`` is then [B, num_heads, S, Dv]). ``Sink`` [num_heads] float32
+    (optional): a head's scalar joins its softmax as one more column that
+    carries no value. Both inference only, like grouped-query heads."""
     q, k, v = x(ins, "Q"), x(ins, "K"), x(ins, "V")
     plan = _Plan(ins, attrs)
     B, H, Sq, D = q.shape
+    sink = x(ins, "Sink")
+    if plan.rides_the_ring(ctx.mesh) and (sink is not None
+                                          or plan.Dv != D):
+        raise NotImplementedError(
+            "sequence_parallel attention with a sink or values of another "
+            "width than the keys: the ring path carries neither")
 
     if plan.rides_the_ring(ctx.mesh):
         if x(ins, "BiasQK") is not None:
@@ -263,33 +283,33 @@ def _fused_mha(ctx, ins, attrs):
     if route == "primitive":
         o = _primitive_attention(ctx, q.reshape(B * H, Sq, D),
                                  k.reshape(B * plan.Hkv, plan.Sk, D),
-                                 v.reshape(B * plan.Hkv, plan.Sk, D),
+                                 v.reshape(B * plan.Hkv, plan.Sk, plan.Dv),
                                  _key_bias(ins), plan.causal, plan.scale,
                                  plan.dropout, plan.is_test, plan.window,
-                                 plan.causal_block)
-        return {"Out": [o.reshape(B, H, Sq, D)]}
+                                 plan.causal_block, sink)
+        return {"Out": [o.reshape(B, H, Sq, plan.Dv)]}
 
     kernel = functools.partial(_kernel_attention,
                                **plan.kernel_options(route))
-    o, lse = _run_kernel(ctx, kernel, [q, k, v, _key_bias(ins)],
+    o, lse = _run_kernel(ctx, kernel, [q, k, v, _key_bias(ins), sink],
                          out_ranks=(4, 3))
     return {"Out": [o], "SoftmaxLse": [lse]}
 
 
-def _kernel_attention(seed, q, k, v, bias=None, *, causal, scale, dropout,
-                      interpret, window=0, causal_block=0):
-    """The flash kernel over one [B, H, S, D] block (bias [B, Sk]):
-    the output and its log-sum-exp [B, H, Sq]."""
+def _kernel_attention(seed, q, k, v, bias=None, sink=None, *, causal, scale,
+                      dropout, interpret, window=0, causal_block=0):
+    """The flash kernel over one [B, H, S, D] block (bias [B, Sk], sink
+    [H]): the output and its log-sum-exp [B, H, Sq]."""
     from ..kernels import flash_attention_with_lse
 
     B, H, Sq, D = q.shape
-    Hkv, Sk = k.shape[1], k.shape[2]
+    Hkv, Sk, Dv = k.shape[1], k.shape[2], v.shape[3]
     o, lse = flash_attention_with_lse(
         q.reshape(B * H, Sq, D), k.reshape(B * Hkv, Sk, D),
-        v.reshape(B * Hkv, Sk, D), bias=bias, causal=causal, scale=scale,
+        v.reshape(B * Hkv, Sk, Dv), bias=bias, causal=causal, scale=scale,
         dropout_rate=dropout, seed=seed, num_heads=H, interpret=interpret,
-        window=window, causal_block=causal_block)
-    return o.reshape(B, H, Sq, D), lse.reshape(B, H, Sq)
+        window=window, causal_block=causal_block, sink=sink)
+    return o.reshape(B, H, Sq, Dv), lse.reshape(B, H, Sq)
 
 
 def _kernel_attention_bwd(seed, q, k, v, bias, o, lse, do, *, causal, scale,
@@ -322,7 +342,7 @@ def _kernel_on_mesh(kernel, mesh, seed, blocks, out_ranks):
     ``blocks`` are ``kernel``'s operands after the seed, ``None`` where an
     optional one is absent; ``out_ranks`` the ranks of its results. A
     [B, H, ...] operand follows the batch and the heads, a [B, S] bias the
-    batch."""
+    batch, an [H] sink the heads."""
     B, H = blocks[0].shape[:2]
 
     def axis(name, dim):
@@ -332,6 +352,8 @@ def _kernel_on_mesh(kernel, mesh, seed, blocks, out_ranks):
     b_ax, h_ax = axis("dp", B), axis("tp", H)
 
     def spec(rank):
+        if rank == 1:
+            return P(h_ax)
         return P(b_ax, None) if rank == 2 \
             else P(b_ax, h_ax, *([None] * (rank - 2)))
 

@@ -69,7 +69,8 @@ def _route_decode(s_max: int, page_size: int, q_len: int = 1,
     inputs=[IOSpec("Q"), IOSpec("KNew"), IOSpec("VNew"),
             IOSpec("CacheK"), IOSpec("CacheV"),
             IOSpec("Positions", no_grad=True),
-            IOSpec("SlotMask", optional=True, no_grad=True)],
+            IOSpec("SlotMask", optional=True, no_grad=True),
+            IOSpec("Sink", optional=True, no_grad=True)],
     outputs=["Out", "CacheKOut", "CacheVOut"],
     attrs={"scale": 0.0, "page_size": 128, "window": 0,
            "whole_chunk": False},
@@ -122,6 +123,13 @@ def _fused_decode_attention(ctx, ins, attrs):
     window. Keys carry their positions in themselves (rotary) or not at
     all, so the order of the ring's rows does not matter to the softmax.
     Only single-row steps wrap (a chunk's causal order is its row order).
+    A prefill whose prompt is longer than the ring leaves it so
+    (``kv_cache_fold``), and the steps go on from ``pos % S_max``.
+
+    ``VNew``/``CacheV`` may be ``Dv`` wide where the keys are ``D`` (``Out``
+    is then [B, Hq, C, Dv]); both caches are then read and appended to as
+    declared. ``Sink`` [Hq] float32 (optional): a query head's scalar
+    joins its softmax as one more column that carries no value.
     """
     from ..kernels import (decode_attention_reference, flash_attention_decode,
                            kv_append, paged_kv_append, paged_kv_append_rows,
@@ -131,7 +139,9 @@ def _fused_decode_attention(ctx, ins, attrs):
     ck, cv = x(ins, "CacheK"), x(ins, "CacheV")
     pos = x(ins, "Positions")
     smask = x(ins, "SlotMask")
+    sink = x(ins, "Sink")
     B, Hq, q_len, D = q.shape
+    Dv = cv.shape[3]
     if q_len < 1:
         raise ValueError(
             f"fused_decode_attention: q_len must be >= 1, got {q_len}")
@@ -164,8 +174,10 @@ def _fused_decode_attention(ctx, ins, attrs):
     # row that block is the decode kernel's last live one, and the kernel
     # writes the row itself; a chunk of rows (it may cross a block's edge)
     # and a ring (its new row is not its last) go through `kv_append` first
-    minor = route != "primitive" and rows_minor(D, ck.dtype, min(page, S))
-    in_kernel = minor and q_len == 1 and not whole and not window
+    minor = (route != "primitive" and Dv == D
+             and rows_minor(D, ck.dtype, min(page, S)))
+    in_kernel = (minor and q_len == 1 and not whole and not window
+                 and sink is None)
     if in_kernel:
         note_kernel_route(ctx, "fused_decode_attention.append_in_kernel",
                           route)
@@ -195,20 +207,22 @@ def _fused_decode_attention(ctx, ins, attrs):
     q3 = q.reshape(B * H, G, q_len, D).swapaxes(1, 2).reshape(
         B * H, q_len * G, D)
     k3 = ck2.reshape(B * H, S, D)
-    v3 = cv2.reshape(B * H, S, D)
+    v3 = cv2.reshape(B * H, S, Dv)
     if route == "primitive":
-        o = decode_attention_reference(q3, k3, v3,
-                                       jnp.repeat(lengths, H, axis=0), scale,
-                                       group=G, whole_chunk=whole)
+        o = decode_attention_reference(
+            q3, k3, v3, jnp.repeat(lengths, H, axis=0), scale, group=G,
+            whole_chunk=whole,
+            sink=None if sink is None else jnp.tile(sink.reshape(H, G),
+                                                    (B, 1)))
     else:
         o = flash_attention_decode(
             q3, k3, v3, lengths, scale=scale, num_heads=H,
             page_size=page, group=G, interpret=interpret, whole_chunk=whole,
-            append=(kn, vn, smask) if in_kernel else None)
+            append=(kn, vn, smask) if in_kernel else None, sink=sink)
         if in_kernel:
             o, ck2, cv2 = o[0], o[1].reshape(ck.shape), o[2].reshape(cv.shape)
-    o = o.reshape(B * H, q_len, G, D).swapaxes(1, 2)
-    return {"Out": [o.reshape(B, Hq, q_len, D)],
+    o = o.reshape(B * H, q_len, G, Dv).swapaxes(1, 2)
+    return {"Out": [o.reshape(B, Hq, q_len, Dv)],
             "CacheKOut": [ck2], "CacheVOut": [cv2]}
 
 
@@ -240,6 +254,49 @@ def _kv_cache_append(ctx, ins, attrs):
     cache, new, pos = x(ins, "Cache"), x(ins, "New"), x(ins, "Positions")
     return {"Out": [paged_kv_append(cache, new, pos, x(ins, "SlotMask"),
                                     x(ins, "Slots"))]}
+
+
+@register_op(
+    "kv_cache_fold",
+    inputs=[IOSpec("Cache"), IOSpec("New"),
+            IOSpec("Lengths", no_grad=True),
+            IOSpec("SlotMask", optional=True, no_grad=True),
+            IOSpec("Slots", optional=True, no_grad=True)],
+    outputs=["Out", "Stats"],
+    attrs={},
+    grad=None)
+def _kv_cache_fold(ctx, ins, attrs):
+    """A prefill past a window layer's ring: ``New`` [R, H, S, D], the keys
+    or values of ``R`` whole prompts of ``Lengths`` [R, 1] tokens in a
+    bucket of ``S`` rows, into ``Cache`` [B, H, W, D], rings of the last
+    ``W < S`` positions. Row ``r`` of sequence ``i``'s ring, in the slot
+    ``Slots[i]`` (default ``i``), takes the last position ``p <
+    Lengths[i]`` with ``p % W == r`` (``kernels.fold_rows``), which is
+    where ``fused_decode_attention`` with a ``window`` writes position
+    ``p``: the decode step goes on from ``Lengths[i] % W`` with no special
+    case. A sequence shorter than the ring lies in it from row 0, as
+    ``kv_cache_append`` leaves it. One gather and one write a cache; a
+    sequence whose ``SlotMask`` is 0 writes nothing, and every slot no
+    sequence in use names stays bit-untouched. ``Out`` goes back to the
+    cache var. ``Stats`` [2] int32: the prompt rows the sequences in use
+    kept in their rings, and those they dropped (the window had passed
+    them). Plain XLA on every device (``kernels.window_fold``: its
+    operations carry the scope ``window_fold`` in a device trace)."""
+    from ..kernels import window_fold
+
+    cache, new = x(ins, "Cache"), x(ins, "New")
+    lengths, smask = x(ins, "Lengths"), x(ins, "SlotMask")
+    R, W, S = new.shape[0], cache.shape[2], new.shape[2]
+    if S <= W:
+        raise ValueError(f"kv_cache_fold: a bucket of {S} rows fits a ring "
+                         f"of {W}; kv_cache_append writes it at row 0")
+    out = window_fold(cache, new, lengths, smask, x(ins, "Slots"))
+    n = lengths.reshape(R).astype(jnp.int32)
+    if smask is not None:
+        n = jnp.where(smask.reshape(R) > 0, n, 0)
+    kept = jnp.minimum(n, W)
+    return {"Out": [out],
+            "Stats": [jnp.stack([kept.sum(), (n - kept).sum()])]}
 
 
 @register_op(

@@ -76,7 +76,7 @@ def expert_counter(stats_var, layers=()):
                              tile_rows=program_tile_rows(stats_var))
 
 
-def count_expert_stats(phase: str, stats, sums, layers, tile_rows) -> None:
+def count_expert_stats(phase: str, stats, sums, layers, tile_rows) -> dict:
     """What a serving dispatch's expert ops counted (``moe_experts``
     ``Stats``, [..., layers, experts_held + 2]; a chained decode stacks its
     steps in front), onto the monitor: per layer and execution the
@@ -84,7 +84,10 @@ def count_expert_stats(phase: str, stats, sums, layers, tile_rows) -> None:
     assignments that found no row. ``layers`` names the ops' layers where
     they are not all of them; ``tile_rows`` is a layer's rows of a
     grouped-matmul tile (:func:`program_tile_rows`), by which its live
-    tiles are counted. ``sums`` (a ``collections.Counter`` the engine was
+    tiles are counted. Returns what this one dispatch added to
+    ``moe_expert_tokens_total``, ``moe_experts_hit_total`` and
+    ``moe_expert_calls_total`` (the engine puts it on the dispatch's settle
+    span). ``sums`` (a ``collections.Counter`` the engine was
     built with) holds the two running sums behind
     ``moe_local_assignment_share``."""
     from .. import monitor
@@ -142,6 +145,10 @@ def count_expert_stats(phase: str, stats, sums, layers, tile_rows) -> None:
         "local assignments the expert op found no buffer row for; the "
         "buffer holds the worst case, so anything but 0 is a bug"
     ).inc(float(dropped.sum()))
+    # this ONE dispatch's share of the three counters above, for its span
+    return {"moe_expert_tokens": int(load.sum()),
+            "moe_experts_hit": int((load > 0).sum()),
+            "moe_expert_calls": int(stats.shape[0] * stats.shape[1])}
 
 
 def _route_moe(T: int, H: int, platform) -> str:

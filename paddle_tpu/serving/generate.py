@@ -340,6 +340,11 @@ class GenerativeEngine(ServingEngine):
         sd = lambda n: (tuple(model["state_vars"][n][0]),
                         model["state_vars"][n][1])
         self._cache_shapes = Counter(sd(nk) for nk, _ in self._cache_names)
+        # the same by the kind of the layer's cache and its values' width
+        # (a value row may be narrower than a key row)
+        self._cache_walks = Counter(
+            (*sd(nk), int(model["state_vars"][nv][0][3]),
+             self._state_kinds[nk]) for nk, nv in self._cache_names)
         self._latent_shapes = Counter(sd(n) for n, in of_kind("latent"))
         # rows a layer's cache holds -> how many layers hold that many
         self._cache_rows = Counter()
@@ -1191,7 +1196,10 @@ class GenerativeEngine(ServingEngine):
         self._publish(reqs)
         with _loop_phase("settle") as ph:
             self._note_compiles("prefill", bucket, net["main"])
-            self._count("prefill", net, outs[0 if self._block else 1:])
+            noted = self._count("prefill", net,
+                                outs[0 if self._block else 1:])
+            if ph.traced:
+                ph.set_attributes(launch_t0=e.t0, **noted)
             # a prefill by blocks seats a prompt's whole blocks; what
             # is left over opens the slot's first decode block
             whole = self._block or 1
@@ -1334,7 +1342,7 @@ class GenerativeEngine(ServingEngine):
         with _loop_phase("settle") as ph:
             self._note_compiles("decode", len(self._slots), self._program)
             n = len(self._fetch_names)
-            self._count("decode", self._model["decode"], outs[n:])
+            noted = self._count("decode", self._model["decode"], outs[n:])
             out = _Yield(outs[:n], steps, len(self._slots), self._block)
             # a request that ended before this dispatch was settled (a
             # stop only its tokens showed, a deadline) ran it for nothing:
@@ -1343,7 +1351,12 @@ class GenerativeEngine(ServingEngine):
             active = [r for r in e.reqs if not r.future.done()]
             late = sum(int(out.counts[:, r.slot].sum())
                        for r in e.reqs if r.future.done())
-            self._observe_walk(active, steps, out)
+            rows = self._observe_walk(active, steps, out)
+            if ph.traced:
+                # what ties this settle to its dispatch (the launch's host
+                # time) and what that one dispatch's attention fetched
+                ph.set_attributes(launch_t0=e.t0, **noted, **{
+                    f"attn_rows_{kind}": int(n) for kind, n in rows.items()})
             if _monitor.enabled():
                 _monitor.histogram(
                     "serving_decode_chunk_seconds",
@@ -1426,9 +1439,11 @@ class GenerativeEngine(ServingEngine):
         attention kernels walk: k-blocks fetched over k-blocks held, from
         the lengths this thread holds and the kernel module's own count
         (on the CPU too, where the primitive route scores every row).
-        ``out``: what the dispatch yielded (without it, a row a forward)."""
+        ``out``: what the dispatch yielded (without it, a row a forward).
+        Returns the rows the kernel fetched by kind of cache (a key/value
+        head's counted once), for the dispatch's span."""
         if not active or not _monitor.enabled():
-            return
+            return {}
         from ..kernels import decode_walk_blocks
         from ..kernels.latent_attention import latent_walk_blocks
 
@@ -1443,12 +1458,33 @@ class GenerativeEngine(ServingEngine):
                            - (0 if self._block else 1) for r in active])
         lengths = before + 1 + moved
         fetched = held = keys = 0
-        for (shape, dt), n in self._cache_shapes.items():
+        from ..kernels.decode_attention import kv_tile
+        rows_by_kind, calls_by_kind = Counter(), Counter()
+        for (shape, dt, v_dim, kind), n in self._cache_walks.items():
+            tile = kv_tile(shape[1], shape[2], shape[3], np_dtype(dt),
+                           self._page_size, v_dim=v_dim)[1]
             f, h = decode_walk_blocks(np.minimum(lengths, shape[2]), shape,
                                       np_dtype(dt), self._page_size,
-                                      q_len=q_len)
+                                      q_len=q_len, rows=tile)
             fetched, held = fetched + n * f, held + n * h
             keys += n * int(np.minimum(lengths + q_len - 1, shape[2]).sum())
+            rows_by_kind[kind] += n * f * tile
+            calls_by_kind[kind] += n * steps
+        for kind, rows in rows_by_kind.items():
+            _monitor.counter(
+                "decode_attention_rows_total",
+                "cache rows the decode attention kernel fetched, a "
+                "key/value head's counted once: per forward, resident "
+                "sequence and layer with a K/V cache, whole k-blocks up to "
+                "the sequence's last live one (kernels.decode_walk_blocks "
+                "on the host's lengths), by the kind of the layer's cache "
+                "(window: a ring, all of it once the window is passed)"
+            ).labels(kind=kind).inc(rows)
+            _monitor.counter(
+                "decode_attention_calls_by_kind_total",
+                "calls of the decode attention over a K/V cache, a forward "
+                "and layer, by the kind of the layer's cache").labels(
+                kind=kind).inc(calls_by_kind[kind])
         if keys:
             _monitor.counter(
                 "decode_attention_keys_total",
@@ -1470,6 +1506,7 @@ class GenerativeEngine(ServingEngine):
             "fetches over k-blocks the resident sequences' caches hold "
             "(kernels.decode_walk_blocks on the host's lengths)"
         ).observe(fetched / held)
+        return rows_by_kind
 
     # -- shared settle paths ---------------------------------------------
     def _expired(self, r: _GenRequest) -> bool:
@@ -1686,10 +1723,17 @@ class GenerativeEngine(ServingEngine):
         return self._fetch_names + [
             v.name for v, _ in self._model["decode"].get("counted", ())]
 
-    def _count(self, phase: str, net: dict, fetched) -> None:
+    def _count(self, phase: str, net: dict, fetched) -> dict:
+        """Hands what a dispatch counted to what counts it. A counting
+        function may return numbers of this ONE dispatch (a dict): they go
+        on the dispatch's settle span, where a reader ties them to the
+        dispatch's device time."""
+        noted = {}
         if _monitor.enabled():
             for (_, count), stats in zip(net.get("counted", ()), fetched):
-                count(phase, np.asarray(stats), self._sums)
+                noted.update(count(phase, np.asarray(stats), self._sums)
+                             or {})
+        return noted
 
     def generation_stats(self) -> dict:
         """Decode-side snapshot for reports: resident slots, compiled

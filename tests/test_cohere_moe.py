@@ -457,8 +457,15 @@ def test_engine_serves_the_tiny_model(window, max_seq, rows):
             fams["serving_kv_cache_bytes"]["values"]} >= {"window", "full"}
 
 
-def test_a_prompt_bucket_past_the_window_is_refused_by_mechanism():
-    with pytest.raises(ValueError, match="sliding layer's window"):
-        build_cohere_moe_generative(
-            CohereMoeConfig.tiny(sliding_window=8), batch_slots=2,
-            max_seq=32, page_size=8, prompt_buckets=(16,))
+def test_a_prompt_of_three_windows_is_folded_into_the_ring():
+    """A window of 8 under a bucket of 32: prompts of three windows and
+    more (and one inside the window) are served, the sliding layers' rings
+    taking each sequence's last 8 positions at ``position % 8``; 20 decode
+    steps wrap every ring twice more. Logits against the reference's full
+    pass, as for a bucket inside the window."""
+    cfg = CohereMoeConfig.tiny(sliding_window=8, dtype="float32",
+                               initializer_range=0.15)
+    rows = _against_reference(
+        cfg, dict(batch_slots=4, max_seq=64, page_size=8,
+                  prompt_buckets=(32,)), 32, (24, 5, 32, 27), 20)["program"]
+    assert len(rows) == 4 * 21 and rows[-1] < F32_TOL

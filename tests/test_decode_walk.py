@@ -658,3 +658,200 @@ def test_walk_share_histogram_counts_what_the_kernel_helper_counts():
     assert (fetched, held) == (4 * 1 + (2 * 1 + 2 * 2) + 4 * 3 + 4 * 4, 64)
     assert got["count"] == 1
     assert got["sum"] == pytest.approx(fetched / held)
+
+
+# -- a sink column, values narrower than keys, the fold into a ring ----------
+
+def _written_out(q, kc, vc, lengths, sink, group):
+    """The decode softmax with the sink's column written out."""
+    s = jnp.einsum("bqd,bkd->bqk", q, kc) * q.shape[-1] ** -0.5
+    live = jnp.arange(kc.shape[1])[None, None] < lengths[:, None, None]
+    s = jnp.where(live, s, -jnp.inf)
+    if sink is not None:
+        s = jnp.concatenate([s, sink[:, :, None]], axis=-1)
+    p = jax.nn.softmax(s, axis=-1)[..., :kc.shape[1]]
+    return jnp.einsum("bqk,bkd->bqd", p, vc)
+
+
+@pytest.mark.parametrize("with_sink", [False, True])
+@pytest.mark.parametrize("group", [1, 4])
+def test_decode_kernel_takes_a_sink_and_values_narrower_than_keys(with_sink,
+                                                                  group):
+    """Keys of 24 beside values of 16 (192 / 128 at an eighth), the group's
+    query heads in the sublane rows each with its own sink, against the
+    softmax with the extra column written out; the reference route too."""
+    rng = np.random.default_rng(0)
+    B, H, S, Dk, Dv = 3, 2, 64, 24, 16
+    mk = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
+    q, kc, vc = mk(B * H, group, Dk), mk(B * H, S, Dk), mk(B * H, S, Dv)
+    lens = jnp.asarray([5, 64, 17])
+    sink = mk(H * group) if with_sink else None
+    rows = None if sink is None else jnp.tile(sink.reshape(H, group), (B, 1))
+    want = _written_out(q, kc, vc, jnp.repeat(lens, H), rows, group)
+    got = flash_attention_decode(q, kc, vc, lens, num_heads=H, page_size=8,
+                                 group=group, interpret=True, sink=sink)
+    assert got.shape == (B * H, group, Dv)
+    np.testing.assert_allclose(got, want, atol=2e-6)
+    ref = decode_attention_reference(q, kc, vc, jnp.repeat(lens, H),
+                                     Dk ** -0.5, group=group, sink=rows)
+    np.testing.assert_allclose(ref, want, atol=2e-6)
+
+
+FOLDS = [
+    # lengths, mask, slots
+    ([3, 8, 29], [1, 1, 1], [4, 0, 2]),
+    ([32, 17, 9], [0, 1, 1], [0, 3, 1]),
+    ([5, 5, 5], [0, 0, 0], [0, 0, 0]),
+    ([16, 24, 1], [1, 0, 1], [2, 2, 0]),
+]
+
+
+@pytest.mark.parametrize("lengths,mask,slots", FOLDS)
+def test_window_fold_takes_each_sequences_last_window_by_position(
+        lengths, mask, slots):
+    """Ring row ``r`` takes the last position ``p < length`` with ``p %
+    window == r``, in the slot the row names; a masked row writes nothing
+    (whatever slot it names, another row's too), and every other slot keeps
+    every bit: the gather-and-write form against a loop over rows."""
+    from paddle_tpu.kernels import window_fold
+
+    rng = np.random.default_rng(1)
+    B, H, W, D, R, S = 5, 2, 8, 16, 3, 32
+    cache = jnp.asarray(rng.normal(size=(B, H, W, D)), jnp.float32)
+    new = jnp.asarray(rng.normal(size=(R, H, S, D)), jnp.float32)
+    want = np.array(cache)
+    for i in range(R):
+        if mask[i]:
+            for r in range(W):
+                seen = [p for p in range(lengths[i]) if p % W == r]
+                want[slots[i], :, r] = np.asarray(new)[
+                    i, :, seen[-1] if seen else r]
+    args = (jnp.asarray(lengths), jnp.asarray(mask, jnp.float32)[:, None],
+            jnp.asarray(slots)[:, None])
+    np.testing.assert_array_equal(
+        np.asarray(window_fold(cache, new, *args)), want)
+
+
+def test_fold_rows_end_where_the_decode_step_goes_on():
+    """Ring row ``r`` holds the last position under the length that is
+    ``r`` modulo the window: a whole number of windows leaves the last
+    window in order, one token more puts it at row 0, and a sequence
+    shorter than the window lies from row 0 with its padding rows after
+    it, where a bucket written at row 0 leaves them."""
+    from paddle_tpu.kernels import fold_rows
+
+    got = np.asarray(fold_rows(jnp.asarray([3, 4, 8, 9, 11]), 4))
+    assert got.tolist() == [[0, 1, 2, 3], [0, 1, 2, 3], [4, 5, 6, 7],
+                            [8, 5, 6, 7], [8, 9, 10, 7]]
+    # the next position, ``length``, is the oldest row's: ``length % 4``
+    for n, rows in zip((8, 9, 11), got[2:]):
+        assert rows[n % 4] == n - 4
+
+
+def test_fold_op_refuses_a_bucket_that_fits_the_ring():
+    """A bucket no longer than the ring is ``kv_cache_append``'s at row 0;
+    the fold says so when its program is lowered."""
+    main, startup = fluid.Program(), fluid.Program()
+    with un.guard(), fluid.program_guard(main, startup):
+        block = main.global_block
+        block.create_var(name="ring", shape=(2, 1, 8, 4), dtype="float32",
+                         persistable=True)
+        new = fluid.layers.data("new", shape=[2, 1, 8, 4], dtype="float32",
+                                append_batch_size=False)
+        ln = fluid.layers.data("len", shape=[2, 1], dtype="int64",
+                               append_batch_size=False)
+        out, _ = fluid.layers.kv_cache_fold(block.var("ring"), new, ln)
+    exe, scope = fluid.Executor(fluid.CPUPlace()), fluid.Scope()
+    scope.set_var("ring", np.zeros((2, 1, 8, 4), np.float32))
+    with pytest.raises(RuntimeError, match="fits a ring"):
+        exe.run(main, scope=scope, fetch_list=[out], feed={
+            "new": np.zeros((2, 1, 8, 4), np.float32),
+            "len": np.full((2, 1), 5, np.int64)})
+
+
+@pytest.mark.parametrize("flash", ["never", "always"])
+def test_decode_op_wraps_a_folded_ring_with_a_sink(flash):
+    """``kv_cache_fold`` then ``fused_decode_attention`` steps with a
+    window, a sink and a narrower ``V``: after a prompt of three windows
+    and a bit, each step's output is full attention over the last
+    ``window`` positions, on the primitive route and through the kernels."""
+    rng = np.random.default_rng(2)
+    B, Hq, H, W, S, Dk, Dv, L, steps = 2, 4, 2, 8, 32, 24, 16, (27, 8), 18
+    mk = lambda *s: rng.normal(size=s).astype(np.float32)
+    k_all, v_all = mk(B, H, S + steps, Dk), mk(B, H, S + steps, Dv)
+    q_all, sink = mk(B, Hq, S + steps, Dk), mk(Hq)
+    fluid.set_flags({"FLAGS_use_flash_attention": flash})
+    try:
+        main, startup = fluid.Program(), fluid.Program()
+        with un.guard(), fluid.program_guard(main, startup):
+            d = lambda n, a: fluid.layers.data(
+                n, shape=list(a.shape), dtype=str(a.dtype),
+                append_batch_size=False)
+            block = main.global_block
+            caches = []
+            for n, width in (("ck", Dk), ("cv", Dv)):
+                block.create_var(name=n, shape=(B, H, W, width),
+                                 dtype="float32", persistable=True)
+                caches.append(block.var(n))
+        exe, scope = fluid.Executor(fluid.CPUPlace()), fluid.Scope()
+        for c, width in zip(caches, (Dk, Dv)):
+            scope.set_var(c.name, mk(B, H, W, width))
+        lens = np.asarray(L, np.int64)[:, None]
+        fold, fs = fluid.Program(), fluid.Program()
+        with un.guard(), fluid.program_guard(fold, fs):
+            for c, (n, a) in zip(caches, (("k", k_all), ("v", v_all))):
+                fb = fold.global_block
+                fb.create_var(name=c.name, shape=c.shape, dtype="float32",
+                              persistable=True)
+                new = fluid.layers.data(n, shape=[B, H, S, a.shape[-1]],
+                                        dtype="float32",
+                                        append_batch_size=False)
+                ln = fluid.layers.data("len", shape=[B, 1], dtype="int64",
+                                       append_batch_size=False) \
+                    if n == "k" else ln
+                _, stats = fluid.layers.kv_cache_fold(fb.var(c.name), new,
+                                                      ln)
+        got_stats = exe.run(fold, scope=scope, fetch_list=[stats], feed={
+            "k": k_all[:, :, :S], "v": v_all[:, :, :S], "len": lens})[0]
+        assert list(got_stats) == [sum(min(n, W) for n in L),
+                                   sum(max(n - W, 0) for n in L)]
+        step, ss = fluid.Program(), fluid.Program()
+        with un.guard(), fluid.program_guard(step, ss):
+            sb = step.global_block
+            cvars = []
+            for c in caches:
+                sb.create_var(name=c.name, shape=c.shape, dtype="float32",
+                              persistable=True)
+                cvars.append(sb.var(c.name))
+            feeds = {n: fluid.layers.data(n, shape=list(s), dtype=t,
+                                          append_batch_size=False)
+                     for n, s, t in (("q", (B, Hq, 1, Dk), "float32"),
+                                     ("kn", (B, H, 1, Dk), "float32"),
+                                     ("vn", (B, H, 1, Dv), "float32"),
+                                     ("pos", (B, 1), "int64"),
+                                     ("sink", (Hq,), "float32"))}
+            out = fluid.layers.fused_decode_attention(
+                feeds["q"], feeds["kn"], feeds["vn"], *cvars, feeds["pos"],
+                page_size=8, window=W, sink=feeds["sink"])
+        # the prompts' tokens sit at positions 0..L-1 of each sequence's
+        # own stream; the steps append positions L, L+1, ...
+        for t in range(steps):
+            pos = lens + t
+            take = lambda a: np.stack([a[b, :, pos[b, 0]][:, None]
+                                       for b in range(B)])
+            got = exe.run(step, scope=scope, fetch_list=[out], feed={
+                "q": take(q_all), "kn": take(k_all), "vn": take(v_all),
+                "pos": pos, "sink": sink})[0]
+            for b in range(B):
+                p = int(pos[b, 0])
+                lo = max(0, p - W + 1)
+                want = _written_out(
+                    jnp.asarray(take(q_all)[b].reshape(H, Hq // H, Dk)),
+                    jnp.asarray(k_all[b, :, lo:p + 1]),
+                    jnp.asarray(v_all[b, :, lo:p + 1]),
+                    jnp.full((H,), p + 1 - lo),
+                    jnp.asarray(sink.reshape(H, Hq // H)), Hq // H)
+                np.testing.assert_allclose(
+                    got[b].reshape(H, Hq // H, Dv), want, atol=3e-6)
+    finally:
+        fluid.set_flags({"FLAGS_use_flash_attention": "auto"})

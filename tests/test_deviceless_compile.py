@@ -541,3 +541,93 @@ def test_block_masks_compile_at_the_published_widths(v5e):
                                            num_heads=32, causal_block=4),
         v5e((32, 1024, 128), bf), kv, kv, v5e((1, 1024), jnp.float32))
     assert re.search(r"%flash_attention_fwd[.\d]* = ", text)
+
+
+# -- MiMo-V2-Flash's attention at its published widths (PR 47) ----------------
+
+# name: query heads, key/value heads, cache rows, window
+MIMO_LAYERS = {"full": (64, 4, 4096, 0), "window": (64, 8, 128, 128)}
+
+
+def _copies_of(text, B, H, S, D):
+    """The ``copy`` instructions that produce a whole bf16 cache of this
+    shape, in either order of its last two dimensions."""
+    shapes = "|".join(f"{B},{H},{a},{b}" for a, b in ((S, D), (D, S)))
+    return re.findall(r"(copy[.\w]*) = bf16\[(?:" + shapes + r")\]", text)
+
+
+@pytest.mark.parametrize("kind", sorted(MIMO_LAYERS))
+def test_sink_and_two_widths_decode_step_compiles_in_place(v5e, kind):
+    """128 slots, bf16, a key cache of 256 lanes (a key's 192 numbers)
+    beside a value cache of 128, a sink a query head on the window layer's
+    ring: one ``decode_attention`` call, the row append in place (no copy
+    of a whole cache), no ``kv_append`` call."""
+    from paddle_tpu.core.registry import get_op_def
+    from paddle_tpu.lowering import LowerCtx
+
+    Hq, H, S, window = MIMO_LAYERS[kind]
+    B, bf = 128, jnp.bfloat16
+
+    def step(q, kn, vn, ck, cv, pos, mask, sink):
+        ins = {"Q": [q], "KNew": [kn], "VNew": [vn], "CacheK": [ck],
+               "CacheV": [cv], "Positions": [pos], "SlotMask": [mask]}
+        if window:
+            ins["Sink"] = [sink]
+        got = get_op_def("fused_decode_attention").lower(
+            LowerCtx(platform="tpu"), ins,
+            {"scale": 192 ** -0.5, "page_size": 128, "window": window})
+        return got["Out"][0], got["CacheKOut"][0], got["CacheVOut"][0]
+
+    text = jax.jit(step, donate_argnums=(3, 4)).lower(
+        v5e((B, Hq, 1, 256), bf), v5e((B, H, 1, 256), bf),
+        v5e((B, H, 1, 128), bf), v5e((B, H, S, 256), bf),
+        v5e((B, H, S, 128), bf), v5e((B, 1), jnp.int32),
+        v5e((B, 1), jnp.float32), v5e((Hq,), jnp.float32)
+    ).compile().as_text()
+    assert len(re.findall(r"%decode_attention[.\d]* = ", text)) == 1
+    assert not re.search(r"%kv_append[.\d]* = ", text)
+    assert not _copies_of(text, B, H, S, 256)
+    assert not _copies_of(text, B, H, S, 128)
+
+
+@pytest.mark.parametrize("kind,bucket", [("full", 3584), ("window", 3584),
+                                         ("window", 256)])
+def test_flash_forward_with_keys_of_192_and_a_sink_compiles(v5e, kind,
+                                                            bucket):
+    """One prompt of the longest bucket: 64 query heads of 192 over 4 or 8
+    key/value heads, values of 128, and on a window layer the sink and the
+    k axis cut to the two blocks a q-block's window touches."""
+    Hq, H, _, window = MIMO_LAYERS[kind]
+    bf = jnp.bfloat16
+
+    def attend(q, k, v, sink):
+        return flash_attention(q, k, v, causal=True, num_heads=Hq,
+                               scale=192 ** -0.5, window=window,
+                               sink=sink if window else None)
+
+    text = _compiles_with_mosaic(
+        attend, v5e((Hq, bucket, 192), bf), v5e((H, bucket, 192), bf),
+        v5e((H, bucket, 128), bf), v5e((Hq,), jnp.float32))
+    assert re.search(r"%flash_attention_fwd[.\d]* = ", text)
+
+
+def test_window_fold_compiles_and_updates_the_rings_in_place(v5e):
+    """A prompt of 3,584 rows into 128 slots' rings of 128 rows, keys in
+    256 lanes and values in 128: plain XLA under the scope
+    ``window_fold``, the rings its own results, no copy of them."""
+    from paddle_tpu.kernels import window_fold
+
+    bf = jnp.bfloat16
+
+    def fold(ck, cv, k, v, n, mask, slots):
+        return (window_fold(ck, k, n, mask, slots),
+                window_fold(cv, v, n, mask, slots))
+
+    text = jax.jit(fold, donate_argnums=(0, 1)).lower(
+        v5e((128, 8, 128, 256), bf), v5e((128, 8, 128, 128), bf),
+        v5e((1, 8, 3584, 256), bf), v5e((1, 8, 3584, 128), bf),
+        v5e((1, 1), jnp.int32), v5e((1, 1), jnp.float32),
+        v5e((1, 1), jnp.int32)).compile().as_text()
+    assert re.search(r'op_name="[^"]*window_fold/', text)
+    assert not _copies_of(text, 128, 8, 128, 256)
+    assert not _copies_of(text, 128, 8, 128, 128)

@@ -467,3 +467,134 @@ def test_tpu_compiled_kernel_and_dropout():
     g = jax.grad(lambda q: jnp.sum(flash_attention(
         q, k, v, dropout_rate=0.1, seed=3, num_heads=1)))(q)
     assert bool(jnp.all(jnp.isfinite(g)))
+
+
+# -- a sink column, values narrower than keys, blocks a window hides ----------
+
+def _ref_sink(q, k, v, sink, window, num_heads):
+    """The softmax with the extra column written out: a head's scalar
+    joins the scores of every query row and carries no value."""
+    G = q.shape[0] // k.shape[0]
+    k, v = jnp.repeat(k, G, 0), jnp.repeat(v, G, 0)
+    s = jnp.einsum("bqd,bkd->bqk", q, k, precision=HP) * q.shape[-1] ** -0.5
+    S = q.shape[1]
+    d = jnp.arange(S)[:, None] - jnp.arange(S)[None, :]
+    seen = (d >= 0) & ((d < window) if window else True)
+    s = jnp.where(seen[None], s, -jnp.inf)
+    if sink is not None:
+        col = jnp.tile(sink, q.shape[0] // num_heads)[:, None, None]
+        s = jnp.concatenate([s, jnp.broadcast_to(col, (q.shape[0], S, 1))],
+                            axis=-1)
+    p = jax.nn.softmax(s, axis=-1)[..., :S]
+    return jnp.einsum("bqk,bkd->bqd", p, v, precision=HP)
+
+
+def _wide_keys(B=2, H=4, Hkv=2, S=64, Dk=24, Dv=16):
+    mk = lambda *s: jnp.asarray(RNG.randn(*s).astype(np.float32))
+    return (mk(B * H, S, Dk), mk(B * Hkv, S, Dk), mk(B * Hkv, S, Dv),
+            mk(H))
+
+
+@pytest.mark.parametrize("window", [0, 8, 20, 40])
+@pytest.mark.parametrize("with_sink", [False, True])
+def test_kernel_takes_a_sink_and_values_narrower_than_keys(window,
+                                                           with_sink):
+    """Keys of 24 beside values of 16 (the published 192 / 128 at an eighth),
+    grouped-query heads, a window and a sink column, against the softmax
+    with the extra column written out."""
+    q, k, v, sink = _wide_keys()
+    sink = sink if with_sink else None
+    got = flash_attention(q, k, v, causal=True, num_heads=4, block_q=8,
+                          block_k=8, interpret=True, window=window,
+                          sink=sink)
+    assert got.shape == (8, 64, 16)
+    np.testing.assert_allclose(got, _ref_sink(q, k, v, sink, window, 4),
+                               atol=2e-6)
+
+
+@pytest.mark.parametrize("bq,bk,window,visited", [
+    (8, 8, 8, 15),      # the diagonal block and the one before it
+    (8, 8, 1, 8), (8, 8, 20, 26), (16, 8, 12, 14), (8, 16, 12, 14)])
+def test_blocks_a_window_hides_are_skipped_and_change_nothing(bq, bk, window,
+                                                              visited):
+    """The k axis of a windowed forward holds only the blocks a q-block's
+    window can touch: the grid is shorter, the count says what it visits,
+    and the output is the full grid's bit for bit."""
+    import sys
+
+    from paddle_tpu.kernels import window_block_visits
+
+    fa = sys.modules["paddle_tpu.kernels.flash_attention"]
+    q, k, v, sink = _wide_keys()
+    kw = dict(causal=True, num_heads=4, block_q=bq, block_k=bk,
+              interpret=True, window=window, sink=sink)
+    cut = flash_attention(q, k, v, **kw)
+    seen, grid = window_block_visits(64, 64, window, bq, bk)
+    assert (seen, grid) == (visited, (64 // bq) * (64 // bk))
+    # the same call with the skip switched off: every block visited
+    real = fa._window_steps
+    fa._window_steps = lambda *a: 0
+    try:
+        whole = flash_attention(q, k, v, **kw)
+    finally:
+        fa._window_steps = real
+    np.testing.assert_array_equal(np.asarray(cut), np.asarray(whole))
+    # and the cut grid really is shorter
+    cfg, _, _ = fa._prepare(q, k, None, True, None, 0.0, 0, 0, 0, 4, bq, bk,
+                            True, window)
+    assert 0 < cfg.k_steps < 64 // bk
+
+
+def test_windowed_forward_with_traced_offsets_visits_one_block_more():
+    """Ring attention hands the kernel traced offsets: the blocks a q-block
+    touches may then straddle one more, and the result is the same."""
+    q, k, v, _ = _wide_keys()
+    v = k[..., :24]
+    fn = jax.jit(lambda qo, ko: flash_attention_with_lse(
+        q, k, v, causal=True, num_heads=4, block_q=8, block_k=8,
+        interpret=True, window=12, q_offset=qo, k_offset=ko)[0])
+    got = fn(jnp.int32(0), jnp.int32(0))
+    np.testing.assert_allclose(got, _ref_sink(q, k, v, None, 12, 4),
+                               atol=2e-6)
+
+
+def test_backward_refuses_a_sink_and_unequal_widths():
+    q, k, v, sink = _wide_keys(H=2, Hkv=2)
+    with pytest.raises(Exception):
+        jax.grad(lambda q: flash_attention(
+            q, k, v, causal=True, num_heads=2, block_q=8, block_k=8,
+            interpret=True, sink=sink).sum())(q)
+
+
+@pytest.mark.parametrize("flash", ["never", "always"])
+def test_op_computes_the_sink_and_widths_on_both_routes(flash):
+    """``fused_multihead_attention`` with ``Sink`` and a narrower ``V``:
+    the primitive route and the kernel (interpret) give the written-out
+    softmax."""
+    B, H, Hkv, S, Dk, Dv = 2, 4, 2, 32, 24, 16
+    mk = lambda *s: RNG.randn(*s).astype(np.float32)
+    q, k, v, sink = mk(B, H, S, Dk), mk(B, Hkv, S, Dk), mk(B, Hkv, S, Dv), \
+        mk(H)
+    fluid.set_flags({"FLAGS_use_flash_attention": flash})
+    try:
+        main, startup = fluid.Program(), fluid.Program()
+        with un.guard(), fluid.program_guard(main, startup):
+            feeds = [fluid.layers.data(n, shape=list(a.shape),
+                                       dtype="float32",
+                                       append_batch_size=False)
+                     for n, a in (("q", q), ("k", k), ("v", v),
+                                  ("sink", sink))]
+            out = fluid.layers.fused_multihead_attention(
+                *feeds[:3], causal=True, is_test=True, window=8,
+                sink=feeds[3])
+        got = fluid.Executor(fluid.CPUPlace()).run(
+            main, feed={"q": q, "k": k, "v": v, "sink": sink},
+            fetch_list=[out])[0]
+    finally:
+        fluid.set_flags({"FLAGS_use_flash_attention": "auto"})
+    want = _ref_sink(jnp.asarray(q.reshape(B * H, S, Dk)),
+                     jnp.asarray(k.reshape(B * Hkv, S, Dk)),
+                     jnp.asarray(v.reshape(B * Hkv, S, Dv)),
+                     jnp.asarray(sink), 8, H)
+    assert got.shape == (B, H, S, Dv)
+    np.testing.assert_allclose(got.reshape(B * H, S, Dv), want, atol=3e-6)
