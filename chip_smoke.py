@@ -300,8 +300,8 @@ def leg_kernels() -> dict:
     #    heads, D, rows] in place against the row append on the declared
     #    shape, a chunk of 8 rows, one slot masked out, one across a block
     #    edge (row 127 on) and two clamped onto the last row
-    from paddle_tpu.kernels import (kv_append, paged_kv_append_rows,
-                                    rows_minor)
+    from paddle_tpu.kernels import (kv_append, paged_kv_append,
+                                    paged_kv_append_rows, rows_minor)
     check(rows_minor(D, jnp.float32, page),
           "a cache of 64-wide heads in pages of 128 is read rows-minor")
     c4 = kc.reshape(Bg, H, S_max, D)
@@ -315,6 +315,28 @@ def leg_kernels() -> dict:
           and not bool(jnp.array_equal(by_cols[0], c4[0])),
           "kv_append writes what the row append writes, bit for bit, and "
           "leaves a masked-out slot's cache as it was")
+    # -- rows that are whole lane tiles (heads of 128, bf16) lie as declared
+    #    and go in by ONE scatter a cache (PR 48), against the loop over the
+    #    slots that wrote them a row at a time: 4 rows a slot, slot 2
+    #    masked out, the last slot's tail clamped onto the last row
+    c128 = jnp.asarray(rng.randn(Bg, 4, S_max, 128), jnp.bfloat16)
+    n128 = jnp.asarray(rng.randn(Bg, 4, 4, 128), jnp.bfloat16)
+
+    @jax.jit
+    def row_by_row(c, n, p, m):
+        for i in range(n.shape[2]):
+            c = paged_kv_append(c, n[:, :, i:i + 1],
+                                jnp.minimum(p + i, S_max - 1), m)
+        return c
+
+    scattered = jax.jit(paged_kv_append_rows)(c128, n128, lengths + 6, keep)
+    check(bool(jnp.array_equal(scattered,
+                               row_by_row(c128, n128, lengths + 6, keep)))
+          and bool(jnp.array_equal(scattered[2], c128[2]))
+          and not bool(jnp.array_equal(scattered[0], c128[0])),
+          "paged_kv_append_rows' one scatter writes what the loop over the "
+          "slots writes, bit for bit, and leaves a masked-out slot's cache "
+          "as it was")
     # -- a step of one row: the decode kernel merges the column into the
     #    last live block it fetches and writes that block back (PR 45),
     #    against `kv_append` and then the kernel: the attention and both

@@ -45,8 +45,10 @@ is a column, whoever writes it fetches and rewrites the block around it,
 and that block is the walk's last live one, so the kernel merges the
 column in, scores the block as merged and copies it back itself, the
 caches its aliased results. The ``paged_``
-append helpers below are plain XLA updates of the donated buffer, one
-sequence at a time, for rows that are whole lane tiles; :func:`kv_append`
+append helpers below are plain XLA updates of the donated buffer, for rows
+that are whole lane tiles: one scatter a cache for a step's rows of every
+sequence (:func:`paged_kv_append_rows`), one update a sequence for a
+prefill's bucket (:func:`paged_kv_append`); :func:`kv_append`
 is a Pallas call that aliases the cache, for rows that are columns (a
 chunk of rows, a ring). All
 take the slot mask: a mask gates the rows that are written, never the
@@ -111,8 +113,9 @@ def paged_kv_append(cache, new, positions, mask=None, slots=None):
     """Write ``new`` rows into ``cache`` at per-sequence ``positions``.
 
     cache: [B, ..., S_max, D]; new: [B, ..., L, D]; positions: [B] int —
-    the start row per sequence (L == prompt bucket is the prefill bulk
-    write; L == 1 is one row of the decode append). One
+    the start row per sequence (L == prompt bucket: the prefill's bulk
+    write, a few sequences of a whole bucket each; the decode step's rows
+    go through :func:`paged_kv_append_rows`). One
     ``dynamic_update_slice`` per sequence, which XLA applies to a donated
     cache in place. Out-of-range starts clamp (XLA semantics), so a
     retired sequence whose position saturates keeps overwriting the last
@@ -140,8 +143,15 @@ def paged_kv_append(cache, new, positions, mask=None, slots=None):
         slots = slots.reshape(B).astype(jnp.int32)
 
     # one sequence at a time, with scalar starts: XLA updates the carried
-    # buffer in place, where the batched forms are a gather and a scatter
-    # for which the TPU compiler re-lays the whole cache
+    # buffer in place, and a sequence's whole bucket is one operation. Of
+    # the batched forms the TPU compiler applies ONE in place: a scatter
+    # whose every index names a (sequence, head, row) and whose window is
+    # the row (`paged_kv_append_rows`, the decode step's). With the heads
+    # inside the update window (one index a sequence, or a `vmap` over the
+    # sequences) it moves them next to the lanes and re-lays the whole
+    # cache into the loop and out of it; with several rows a window it
+    # expands the scatter into a loop of its own, a slower one than this
+    # (tools/probe_kv_append.py; PERF.md section 6, PR 48)
     def one(b, c):
         start = [jnp.int32(0)] * c.ndim
         start[0] = b if slots is None else slots[b]
@@ -155,8 +165,9 @@ def paged_kv_append(cache, new, positions, mask=None, slots=None):
     return jax.lax.fori_loop(0, B, one, cache)
 
 
-def _row_positions(positions, i: int, s_max: int, ring: bool):
-    """Where row ``i`` of a chunk that starts at ``positions`` lands."""
+def _row_positions(positions, i, s_max: int, ring: bool):
+    """Where row ``i`` (or rows ``i``, an array that broadcasts) of a chunk
+    that starts at ``positions`` lands."""
     return ((positions + i) % s_max if ring
             else jnp.minimum(positions + i, s_max - 1))
 
@@ -176,46 +187,38 @@ def paged_kv_append_rows(cache, new, positions, mask=None, ring=False):
     of a sequence whose mask is 0 bit-identical, at the cost of the rows
     and not of the cache (see :func:`paged_kv_append`).
 
-    Two lowerings of the same result, chosen by the row count. Up to
-    ``KERNEL_ROWS`` rows — the decode step and the verify chunk, the
-    shapes the Pallas kernel serves — one ``dynamic_update_slice`` per
-    row (a masked-out sequence writes its old row back): XLA updates the
-    donated cache in place next to the kernel's custom call. Past that —
-    chunked-prefill slices, which ride the primitive path anyway — ONE
-    scatter (a masked-out sequence's indices go out of range, where
-    ``mode="drop"`` discards them): unrolled, a 128-row chunk was 3,072
-    update ops over 12 layers and its compile took minutes where its
-    siblings take seconds."""
+    ONE scatter a cache for every row count — the decode step, the verify
+    chunk, the chunked-prefill slice — with one index a (sequence, head,
+    row), which XLA applies to the donated cache in place next to the
+    kernel's custom call (PERF.md section 6, PR 48: a step's 128 x 8 rows
+    in one operation where a loop over the sequences was three operations
+    a sequence and row). What must not be written goes out of range, where
+    the scatter drops it: a sequence whose mask is 0, and a row that a
+    later row of the chunk overwrites (every row at or past ``S_max - 1``
+    clamps onto the last cache row, where the chunk's LAST row wins), so
+    the indices in range are unique and the result does not depend on the
+    order a backend applies a scatter in."""
     S = cache.shape[-2]
     C = new.shape[-2]
     B = cache.shape[0]
-    positions = positions.reshape(B).astype(jnp.int32)
-    if C <= KERNEL_ROWS:
-        for i in range(C):
-            cache = paged_kv_append(
-                cache, jax.lax.slice_in_dim(new, i, i + 1, axis=-2),
-                _row_positions(positions, i, S, ring), mask)
-        return cache
-    if ring:
+    if ring and C > KERNEL_ROWS:
         raise NotImplementedError(
             f"a {C}-row chunk into a ring cache: only steps of up to "
             f"{KERNEL_ROWS} rows, the kernel's, wrap")
-    rows = positions[:, None] + jnp.arange(C, dtype=jnp.int32)    # [B, C]
-    # every row at or past S-1 clamps onto the last cache row, where the
-    # chunk's LAST row wins (what the row-by-row form does). The rows it
-    # shadows go out of range, where mode="drop" discards them: indices
-    # stay unique, so the result does not depend on the order a backend
-    # applies a scatter in
-    shadowed = (rows >= S - 1) & (jnp.arange(C) < C - 1)
-    idx = jnp.where(shadowed, S, jnp.minimum(rows, S - 1))
+    i = jnp.arange(C, dtype=jnp.int32)
+    at = _row_positions(positions.reshape(B, 1).astype(jnp.int32), i, S,
+                        ring)                                     # [B, C]
+    shadowed = i + S < C if ring else (at == S - 1) & (i < C - 1)
     keep = _keep(mask, B)
     if keep is not None:
-        idx = jnp.where(keep[:, None], idx, S)
-
-    def upd(c, n, r):
-        return c.at[..., r, :].set(n.astype(c.dtype), mode="drop")
-
-    return jax.vmap(upd)(cache, new, idx)
+        shadowed = shadowed | ~keep[:, None]
+    # [N, S, D], N = B x heads: every head's row named by an index of its
+    # own is the form XLA applies in place (see `paged_kv_append`)
+    D, N = cache.shape[-1], cache.size // (S * cache.shape[-1])
+    at = jnp.repeat(jnp.where(shadowed, S + i, at), N // B, axis=0)
+    return cache.reshape(N, S, D).at[jnp.arange(N)[:, None], at].set(
+        new.astype(cache.dtype).reshape(N, C, D), mode="drop",
+        unique_indices=True).reshape(cache.shape)
 
 
 def fold_rows(lengths, window: int):

@@ -81,21 +81,24 @@ def _fused_decode_attention(ctx, ins, attrs):
     1. append this chunk's K/V rows (``KNew``/``VNew`` [B, H, C, D],
        C = q_len; C == 1 is the classic decode step) into the paged
        caches ([B, H, S_max, D]) at per-sequence ``Positions`` ([B, 1]
-       int — the sequence length BEFORE this chunk), one row at a time
-       with per-row clamping onto the last cache row;
+       int — the sequence length BEFORE this chunk), with per-row
+       clamping onto the last cache row: one scatter a cache for all
+       sequences where the cache lies as declared
+       (``kernels.paged_kv_append_rows``), the decode kernel itself or
+       ``kernels.kv_append`` where it lies rows-minor, and
+       ``kernel_route_total`` says which (``...append_scatter``,
+       ``...append_in_kernel``, ``kv_append``);
     2. attend the C query rows against the updated cache with a
        per-sequence, per-row causal length mask (query row i sees keys
        at positions < pos + i + 1 — its own K row and everything before,
        never a later chunk row). With ``whole_chunk`` the chunk is a block
        whose rows see one another in both directions (a block-diffusion
        decode forward: C = block_length rows a sequence, which yield 0 to C
-       tokens): every row sees the keys at positions < pos + C. Such a
-       block is appended as one slice a sequence where the cache lies as
-       declared, so it has to lie inside the cache (no per-row clamp).
+       tokens): every row sees the keys at positions < pos + C.
 
     ``SlotMask`` [B, 1] (optional) gates the ROWS that step 1 writes: a
-    sequence whose mask is 0 writes its own old rows back (or, past
-    ``KERNEL_ROWS`` rows, nothing), so its caches stay bit-untouched —
+    sequence whose mask is 0 writes nothing (rows-minor: its own old rows
+    back), so its caches stay bit-untouched —
     the chunked-prefill and speculative-verify dispatches run a subset of
     slots while their neighbours keep decoding, and the decode chunk runs
     under the ``active`` gate. The mask never selects between an old and
@@ -132,8 +135,7 @@ def _fused_decode_attention(ctx, ins, attrs):
     joins its softmax as one more column that carries no value.
     """
     from ..kernels import (decode_attention_reference, flash_attention_decode,
-                           kv_append, paged_kv_append, paged_kv_append_rows,
-                           rows_minor)
+                           kv_append, paged_kv_append_rows, rows_minor)
 
     q, kn, vn = x(ins, "Q"), x(ins, "KNew"), x(ins, "VNew")
     ck, cv = x(ins, "CacheK"), x(ins, "CacheV")
@@ -183,15 +185,12 @@ def _fused_decode_attention(ctx, ins, attrs):
                           route)
     elif minor:
         note_kernel_route(ctx, "kv_append", route)
+    else:
+        note_kernel_route(ctx, "fused_decode_attention.append_scatter",
+                          route)
     interpret = route == "pallas-interpret"
 
     def append(cache, new):
-        if whole and not minor:
-            # a block goes in as one slice a sequence, a quarter of the
-            # row-by-row form's updates at 4 rows: its start clamps as a
-            # whole, so a block has to lie inside the cache (a start on a
-            # whole block in a cache of whole blocks does)
-            return paged_kv_append(cache, new, pos_b, smask)
         if not minor:
             return paged_kv_append_rows(cache, new, pos_b, smask,
                                         ring=bool(window))

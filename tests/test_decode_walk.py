@@ -251,6 +251,66 @@ def test_kv_append_kernel_is_the_row_form_bit_for_bit(shape, mask, rows):
             np.asarray(new[1, :, last], np.float32))
 
 
+def _rows_written_one_by_one(cache, new, positions, keep, ring):
+    """NumPy: row ``i`` of sequence ``b`` to row ``min(p + i, S - 1)`` of
+    its cache, or ``(p + i) % S`` of its ring, in the chunk's order (a
+    later row overwrites an earlier one); nothing where ``keep[b]`` is 0."""
+    out = np.array(cache)
+    S = out.shape[-2]
+    for b, p in enumerate(positions):
+        for i in range(new.shape[-2]):
+            if keep is None or keep[b]:
+                at = (p + i) % S if ring else min(p + i, S - 1)
+                out[b, ..., at, :] = np.asarray(new)[b, ..., i, :]
+    return out
+
+
+# name: dtype, head dimension (MiMo-V2-Flash's keys lie in 256 lanes beside
+# values of 128; 16: the tiny test models'), cache rows, ring
+SCATTER_SHAPES = {
+    "f32-d128": (jnp.float32, 128, 32, False),
+    "bf16-d128": (jnp.bfloat16, 128, 32, False),
+    "bf16-d256": (jnp.bfloat16, 256, 32, False),
+    "f32-d16": (jnp.float32, 16, 24, False),
+    "bf16-d128-ring": (jnp.bfloat16, 128, 16, True),
+    "f32-d256-ring": (jnp.float32, 256, 16, True),
+}
+
+
+@pytest.mark.parametrize("rows", [1, 4, 8, 16])
+@pytest.mark.parametrize("mask", sorted(APPEND_MASKS))
+@pytest.mark.parametrize("shape", sorted(SCATTER_SHAPES))
+def test_row_scatter_is_the_row_by_row_write_bit_for_bit(shape, mask, rows):
+    """``paged_kv_append_rows``, one scatter a cache for every row count,
+    against the rows written one by one in NumPy: six sequences at row 0,
+    inside the cache, with the chunk's last row on ``S - 1``, on ``S - 2``
+    (the rows a later one shadows must not win), on ``S - 1`` and past the
+    end (a ring wraps them all); a sequence whose mask is 0 keeps its
+    cache to the bit, and so does every row the chunk does not name."""
+    dt, D, S, ring = SCATTER_SHAPES[shape]
+    if ring and rows > 8:
+        with pytest.raises(NotImplementedError, match="ring"):
+            paged_kv_append_rows(jnp.zeros((1, 1, S, D)),
+                                 jnp.zeros((1, 1, rows, D)),
+                                 jnp.zeros((1,), jnp.int32), ring=True)
+        return
+    B, H = 6, 3
+    rng = np.random.default_rng(zlib.crc32(f"{shape}/{mask}/{rows}".encode()))
+    cache = jnp.asarray(rng.normal(size=(B, H, S, D)), dt)
+    new = jnp.asarray(rng.normal(size=(B, H, rows, D)), dt)
+    positions = [0, 5, max(S - rows, 0), S - 2, S - 1, 3 * S + 7]
+    keep = APPEND_MASKS[mask]
+    m = None if keep is None else jnp.asarray(keep, jnp.float32)[:, None]
+    got = jax.jit(paged_kv_append_rows, static_argnames="ring")(
+        cache, new, jnp.asarray(positions, jnp.int32), m, ring=ring)
+    assert got.dtype == cache.dtype and got.shape == cache.shape
+    want = _rows_written_one_by_one(cache, new, positions, keep, ring)
+    assert np.asarray(got).tobytes() == want.tobytes()
+    for b in range(B):
+        same = _bits(got[b]) == _bits(cache[b])
+        assert same == (keep is not None and not keep[b])
+
+
 def test_kv_append_kernel_walks_more_sequences_than_a_lane_tile():
     """The sequences' columns ride in lanes, 128 a block of ``new``: 130
     sequences take a second block."""
@@ -527,6 +587,55 @@ def test_op_appends_and_attends_in_one_view(case):
     np.testing.assert_allclose(a[live], b[live], **tol)
 
 
+SCATTER = "fused_decode_attention.append_scatter"
+# name: dtype, q_len, key width, value width, window, whole_chunk
+SCATTER_STEPS = {
+    "f32-step": (jnp.float32, 1, 16, 16, 0, False),
+    "bf16-step-keys-wider": (jnp.bfloat16, 1, 24, 16, 0, False),
+    "bf16-ring-keys-wider": (jnp.bfloat16, 1, 24, 16, 32, False),
+    "f32-verify-chunk": (jnp.float32, 4, 16, 16, 0, False),
+    "bf16-block-of-4": (jnp.bfloat16, 4, 16, 16, 0, True),
+    "f32-block-of-4-keys-wider": (jnp.float32, 4, 24, 16, 0, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SCATTER_STEPS))
+def test_op_scatters_a_steps_rows_into_caches_that_lie_as_declared(case):
+    """``fused_decode_attention`` where the caches are not worked on
+    rows-minor: the step's rows of every slot go in by one scatter a cache
+    (counted as ``append_scatter``, and neither kernel append), keys wider
+    than values and a block of 4 among the cases; both caches are the rows
+    written one by one in NumPy, a masked-out slot's untouched, one slot
+    clamped onto the last row (wrapped, on a ring)."""
+    from paddle_tpu.core.registry import get_op_def
+    from paddle_tpu.lowering import LowerCtx
+
+    dt, q_len, D, Dv, window, whole = SCATTER_STEPS[case]
+    B, H, G, S = 4, 2, 2, 32
+    rng = np.random.default_rng(zlib.crc32(case.encode()))
+    mk = lambda *shape: jnp.asarray(rng.normal(size=shape), dt)
+    positions = [4, 9, S - 4, 70 if window else S + 9]
+    keep = [1, 0, 1, 1]
+    ins = {"Q": [mk(B, H * G, q_len, D)], "KNew": [mk(B, H, q_len, D)],
+           "VNew": [mk(B, H, q_len, Dv)], "CacheK": [mk(B, H, S, D)],
+           "CacheV": [mk(B, H, S, Dv)],
+           "Positions": [jnp.asarray(positions)[:, None]],
+           "SlotMask": [jnp.asarray(keep, jnp.float32)[:, None]]}
+    monitor.reset()
+    got = get_op_def("fused_decode_attention").lower(
+        LowerCtx(platform="cpu"), ins,
+        {"scale": 0.0, "page_size": 8, "window": window,
+         "whole_chunk": whole})
+    assert _append_routes(SCATTER) == {"primitive": 1}
+    assert _append_routes() == {} and _append_routes(IN_KERNEL) == {}
+    assert got["Out"][0].shape == (B, H * G, q_len, Dv)
+    for out, cache, new in (("CacheKOut", "CacheK", "KNew"),
+                            ("CacheVOut", "CacheV", "VNew")):
+        want = _rows_written_one_by_one(ins[cache][0], ins[new][0],
+                                        positions, keep, bool(window))
+        assert np.asarray(got[out][0]).tobytes() == want.tobytes()
+
+
 def _trace_for_tpu(program, fetch):
     """Trace ``program``'s step as the executor would lower it for a TPU
     (nothing compiles or runs): routes are counted at trace time."""
@@ -550,7 +659,8 @@ def _trace_for_tpu(program, fetch):
 
 def _decoder(name, **kw):
     from paddle_tpu.models import (cohere_moe, glm4_moe_lite,
-                                   granite_moe_hybrid, qwen3_next, sdar_moe)
+                                   granite_moe_hybrid, mimo_v2_flash,
+                                   qwen3_next, sdar_moe)
 
     if name == "gpt-heads-of-64":
         cfg = GptConfig(vocab_size=64, hidden_size=128, num_layers=3,
@@ -565,23 +675,26 @@ def _decoder(name, **kw):
             "glm4-moe-lite": glm4_moe_lite.build_glm4_moe_lite_generative,
             "sdar-moe": sdar_moe.build_sdar_moe_generative,
             "granite-moe-hybrid":
-                granite_moe_hybrid.build_granite_moe_hybrid_generative}[
-                name]()
+                granite_moe_hybrid.build_granite_moe_hybrid_generative,
+            "mimo-v2-flash":
+                mimo_v2_flash.build_mimo_v2_flash_generative}[name]()
 
 
-@pytest.mark.parametrize("name,appends", [
-    ("gpt-heads-of-64", 3), ("gpt-tiny", 0), ("cohere-moe", 0),
-    ("qwen3-next", 0), ("glm4-moe-lite", 0), ("sdar-moe", 0),
-    ("granite-moe-hybrid", 0)])
+@pytest.mark.parametrize("name,appends,scatters", [
+    ("gpt-heads-of-64", 3, 0), ("gpt-tiny", 0, 2), ("cohere-moe", 0, 4),
+    ("qwen3-next", 0, 1), ("glm4-moe-lite", 0, 0), ("sdar-moe", 0, 2),
+    ("granite-moe-hybrid", 0, 1), ("mimo-v2-flash", 0, 4)])
 def test_kv_append_route_counts_the_layers_that_take_the_kernel(name,
-                                                                appends):
-    """The rows-minor append's two counters for a decode program lowered
-    for a TPU: where the caches are worked on rows-minor (heads of 64 in
-    pages of 128) the decode kernel writes the step's row itself, one
-    ``IN_KERNEL`` a layer and no ``kv_append``; neither for the other
-    decoders' caches (the tiny ones here, heads of 128 and 256 or a latent
-    cache at the published widths) though their decode attention rides
-    its kernel."""
+                                                                appends,
+                                                                scatters):
+    """The three appends' counters for a decode program lowered for a TPU:
+    where the caches are worked on rows-minor (heads of 64 in pages of
+    128) the decode kernel writes the step's row itself, one ``IN_KERNEL``
+    a layer, no ``kv_append`` and no scatter; the other decoders' caches
+    (the tiny ones here, heads of 128 and 256 at the published widths) lie
+    as declared and take one ``SCATTER`` an attention layer and neither
+    kernel append, though their decode attention rides its kernel; a
+    latent cache is its own op's and takes none of the three."""
     with un.guard():
         net = _decoder(name)
     monitor.reset()
@@ -591,6 +704,8 @@ def test_kv_append_route_counts_the_layers_that_take_the_kernel(name,
     assert _append_routes() == {}
     assert _append_routes(IN_KERNEL) == ({"pallas": appends} if appends
                                          else {})
+    assert _append_routes(SCATTER) == ({"pallas": scatters} if scatters
+                                       else {})
     fam = monitor.get_registry().get("kernel_route_total")
     assert any(labels["route"] == "pallas" for labels, _ in fam.children())
 

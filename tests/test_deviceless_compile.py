@@ -101,15 +101,17 @@ def test_kernels_keep_their_names_in_the_compiled_hlo(v5e):
                      "flash_attention_fwd"]
 
 
-def _whole_cache_work(text, cache_shape, where="loops"):
-    """Names of the instructions that PRODUCE a whole cache, as it is
-    declared or in the rows-minor view (the last two dimensions swapped):
-    a ``copy`` or a select of that shape. In-place updates
-    (``dynamic-update-slice``, alone or as a fusion's root) and tuple
-    plumbing are not. ``where``: outside the entry computation (so inside
-    a ``while`` body), or ``"entry"``, around the loop."""
+def _whole_cache_work(text, cache_shape, where="loops", dtype="f32"):
+    """Names of the instructions that PRODUCE a whole cache of ``dtype``,
+    as it is declared, in the rows-minor view (the last two dimensions
+    swapped) or re-laid with its heads next to its lanes: a ``copy`` or a
+    select of that shape. In-place updates (``dynamic-update-slice``,
+    alone or as a fusion's root; a ``scatter``) and tuple plumbing are
+    not. ``where``: outside the entry computation (so inside a ``while``
+    body), or ``"entry"``, around the loop."""
     B, H, S, D = cache_shape
-    shape = "f32\\[%d,%d,(?:%d,%d|%d,%d)\\]" % (B, H, S, D, D, S)
+    shape = dtype + "\\[%d,(?:%d,%d,%d|%d,%d,%d|%d,%d,%d)\\]" % (
+        B, H, S, D, H, D, S, S, H, D)
     made = re.compile(r"^\s+(?:ROOT )?%?((?:copy|[\w\-]*select[\w\-]*)"
                       r"[.\w]*) = \(?" + shape)
     found, entry = [], False
@@ -123,12 +125,16 @@ def _whole_cache_work(text, cache_shape, where="loops"):
     return found
 
 
-def _decode_chunk(B, H, S, D, form, layers=1, steps=4):
+def _decode_chunk(B, H, S, D, form, layers=1, steps=4, *, rows=1,
+                  sink=False, **attrs):
     """The decode chunk as ``run_chained`` runs it: a scan whose carry is
     the donated caches of ``layers`` layers, each step a masked append and
     the decode kernel a layer. ``form`` says how the step is made:
     ``"op"`` is ``fused_decode_attention``'s own rule, which appends in the
-    view the kernel reads; ``"logical_rows"`` appends on the declared shape
+    view the kernel reads (``rows`` rows a step, a sink a query head and
+    the op's ``attrs`` where given; the caches and rows it is called with
+    say how many query heads and how wide the values); ``"logical_rows"``
+    appends on the declared shape
     whatever the kernel reads (the rule before PR 32);
     ``"where_over_the_cache"`` selects between an appended cache and the
     old one (the rule before PR 26)."""
@@ -144,14 +150,20 @@ def _decode_chunk(B, H, S, D, form, layers=1, steps=4):
         m = (mask.reshape(B) > 0).reshape(B, 1, 1, 1)
         return jnp.where(m, appended, cache)
 
-    def layer(ck, cv, q, kn, vn, pos, mask):
+    def layer(ck, cv, q, kn, vn, pos, mask, sinks):
         if form == "op":
+            ins = {"Q": [q], "KNew": [kn], "VNew": [vn], "CacheK": [ck],
+                   "CacheV": [cv], "Positions": [pos.reshape(B, 1)],
+                   "SlotMask": [mask]}
+            if sink:
+                ins["Sink"] = [sinks]
             got = get_op_def("fused_decode_attention").lower(
-                LowerCtx(platform="tpu"),
-                {"Q": [q], "KNew": [kn], "VNew": [vn], "CacheK": [ck],
-                 "CacheV": [cv], "Positions": [pos.reshape(B, 1)],
-                 "SlotMask": [mask]}, {"scale": 0.0, "page_size": 128})
-            return got["CacheKOut"][0], got["CacheVOut"][0], got["Out"][0]
+                LowerCtx(platform="tpu"), ins,
+                {"scale": 0.0, "page_size": 128, **attrs})
+            o = got["Out"][0]
+            # the next layer's query depends on this one's attention
+            return (got["CacheKOut"][0], got["CacheVOut"][0],
+                    o if o.shape == q.shape else q + 0 * o[..., :1])
         ck, cv = append(ck, kn, pos, mask), append(cv, vn, pos, mask)
         o = flash_attention_decode(
             q.reshape(B * H, 1, D), ck.reshape(B * H, S, D),
@@ -159,25 +171,29 @@ def _decode_chunk(B, H, S, D, form, layers=1, steps=4):
             page_size=128)
         return ck, cv, o.reshape(B, H, 1, D)
 
-    def chunk(caches, q, kn, vn, pos, mask):
+    def chunk(caches, q, kn, vn, pos, mask, sinks):
         def body(carry, _):
             caches, pos, q = carry
             out = []
             for ck, cv in zip(caches[::2], caches[1::2]):
-                ck, cv, q = layer(ck, cv, q, kn, vn, pos, mask)
+                ck, cv, q = layer(ck, cv, q, kn, vn, pos, mask, sinks)
                 out += [ck, cv]
-            return (out, pos + mask.reshape(B).astype(pos.dtype), q), None
+            step = rows * mask.reshape(B).astype(pos.dtype)
+            return (out, pos + step, q), None
         return jax.lax.scan(body, (caches, pos, q), None, length=steps)[0]
 
-    return jax.jit(chunk, donate_argnums=(0,)), 2 * layers
+    return jax.jit(chunk, donate_argnums=(0,)), layers
 
 
-def _compiled_chunk(v5e, B, H, S, D, form, layers=1):
-    chunk, n = _decode_chunk(B, H, S, D, form, layers)
-    row = v5e((B, H, 1, D), jnp.float32)
+def _compiled_chunk(v5e, B, H, S, D, form, layers=1, dtype=jnp.float32,
+                    Hq=None, Dv=None, **op):
+    chunk, n = _decode_chunk(B, H, S, D, form, layers, **op)
+    rows, Hq, Dv = op.get("rows", 1), Hq or H, Dv or D
     return chunk.lower(
-        [v5e((B, H, S, D), jnp.float32)] * n, row, row, row,
-        v5e((B,), jnp.int32), v5e((B, 1), jnp.float32)).compile()
+        [v5e((B, H, S, D), dtype), v5e((B, H, S, Dv), dtype)] * n,
+        v5e((B, Hq, rows, D), dtype), v5e((B, H, rows, D), dtype),
+        v5e((B, H, rows, Dv), dtype), v5e((B,), jnp.int32),
+        v5e((B, 1), jnp.float32), v5e((Hq,), jnp.float32)).compile()
 
 
 @pytest.mark.parametrize("form", ["op", "logical_rows",
@@ -280,11 +296,12 @@ OTHER_DECODERS = {
 
 
 @pytest.mark.parametrize("decoder", sorted(OTHER_DECODERS))
-def test_heads_of_whole_lane_tiles_keep_the_row_append(v5e, decoder):
+def test_heads_of_whole_lane_tiles_take_one_scatter_a_cache(v5e, decoder):
     """The other decoders' decode steps (64 slots, bf16, heads of 128 and
     256: ``rows_minor`` is false) hold no ``kv_append`` call: their rows
-    are whole lane tiles and the loop form writes them as before, in
-    place."""
+    are whole lane tiles, and ONE scatter a cache writes every slot's (PR
+    48), where a loop over the slots wrote them one
+    ``dynamic-update-slice`` a slot."""
     from paddle_tpu.core.registry import get_op_def
     from paddle_tpu.lowering import LowerCtx
 
@@ -305,7 +322,46 @@ def test_heads_of_whole_lane_tiles_keep_the_row_append(v5e, decoder):
         v5e((B, 1), jnp.int32), v5e((B, 1), jnp.float32)).compile().as_text()
     assert re.search(r"%decode_attention[.\d]* = ", text)
     assert not re.search(r"%kv_append[.\d]* = ", text)
-    assert len(re.findall(r"dynamic-update-slice\(", text)) >= 2
+    assert len(re.findall(r" scatter\(", text)) == 2
+    assert not re.findall(r"dynamic-update-slice\(| while\(", text)
+    assert not _copies_of(text, B, H, S, D)
+
+
+# name: slots, key/value heads, cache rows, key width, then the op's own:
+# query heads, value width, rows a step, sink, attrs
+SCATTER_CHUNKS = {
+    "mimo-v2-flash-full": (128, 4, 4096, 256, dict(Hq=64, Dv=128)),
+    "mimo-v2-flash-ring": (128, 8, 128, 256,
+                           dict(Hq=64, Dv=128, sink=True, window=128)),
+    "sdar-block-of-4": (64, 4, 2048, 128,
+                        dict(Hq=32, rows=4, whole_chunk=True)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SCATTER_CHUNKS))
+def test_row_scatter_keeps_the_scan_carry_in_place(v5e, case):
+    """The decode chunk at the cache shapes whose append was a loop over
+    the slots until PR 48 (bf16; MiMo-V2-Flash's full layer and its ring,
+    keys in 256 lanes beside values in 128; SDAR's block of 4 rows): the
+    donated caches go through the scan with one ``scatter`` a cache a
+    step, the scan is the one ``while``, nothing in it or around it
+    produces a whole cache, and the compiler holds under a hundredth of a
+    cache of scratch. A scatter with the heads inside its window holds a
+    whole cache and copies it into the loop and out (``tools/
+    probe_kv_append.py --deviceless``): this is where that fails."""
+    B, H, S, D, op = SCATTER_CHUNKS[case]
+    compiled = _compiled_chunk(v5e, B, H, S, D, "op", dtype=jnp.bfloat16,
+                               **op)
+    text = compiled.as_text()
+    assert len(re.findall(r"%decode_attention[.\d]* = ", text)) == 1
+    assert len(re.findall(r" scatter\(", text)) == 2
+    assert len(re.findall(r" while\(", text)) == 1
+    for width in (D, op.get("Dv") or D):
+        shape = (B, H, S, width)
+        assert _whole_cache_work(text, shape, dtype="bf16") == []
+        assert _whole_cache_work(text, shape, "entry", dtype="bf16") == []
+    values = B * H * S * (op.get("Dv") or D) * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < values / 100
 
 
 # -- the sparse-expert decoder's kernels at its published widths (PR 27) ------

@@ -54,22 +54,33 @@ def test_chunk_kernel_matches_reference(q_len):
                                atol=2e-5, rtol=1e-4)
 
 
-def test_paged_kv_append_rows_clamps_per_row():
+@pytest.mark.parametrize("masked", [(), (1,), (0, 1)])
+@pytest.mark.parametrize("C", [4, 16])
+def test_paged_kv_append_rows_clamps_per_row(C, masked):
     """A chunk whose tail crosses the cache end collapses the overflow
     onto the LAST row (never shifts back over real rows the way a
-    whole-block dynamic_update_slice start-clamp would)."""
-    B, S, D, C = 2, 8, 4, 4
+    whole-block dynamic_update_slice start-clamp would): the verify chunk's
+    4 rows and a chunked-prefill slice's 16 through the same one scatter,
+    on a cache with no heads dimension; a masked-out sequence writes
+    nothing."""
+    B, S, D = 2, 24, 4
     cache = jnp.zeros((B, S, D), np.float32)
     new = jnp.asarray(
         np.arange(1, B * C * D + 1, dtype=np.float32).reshape(B, C, D))
-    # row 0 starts in-range, rows 2..3 overflow for batch 1
-    out = np.asarray(paged_kv_append_rows(cache, new, np.array([2, 6])))
-    np.testing.assert_array_equal(out[0, 2:6], np.asarray(new)[0])
-    # batch 1: rows 6, 7 get chunk rows 0, 1; overflow rows 2 and 3 both
-    # clamp onto row 7 — LAST writer wins, earlier rows intact
-    np.testing.assert_array_equal(out[1, 6], np.asarray(new)[1, 0])
-    np.testing.assert_array_equal(out[1, 7], np.asarray(new)[1, 3])
-    np.testing.assert_array_equal(out[1, :6], np.zeros((6, D)))
+    mask = None if not masked else jnp.asarray(
+        [[float(b not in masked)] for b in range(B)])
+    # batch 0 lies in range; batch 1 starts two rows before the end
+    out = np.asarray(paged_kv_append_rows(cache, new, np.array([2, S - 2]),
+                                          mask))
+    want = np.zeros((B, S, D), np.float32)
+    if 0 not in masked:
+        want[0, 2:2 + C] = np.asarray(new)[0]
+    if 1 not in masked:
+        # rows S-2, S-1 get chunk rows 0, 1; the overflow rows all clamp
+        # onto row S-1 — LAST writer wins, earlier rows intact
+        want[1, S - 2] = np.asarray(new)[1, 0]
+        want[1, S - 1] = np.asarray(new)[1, C - 1]
+    np.testing.assert_array_equal(out, want)
 
 
 def test_spec_accept_longest_agreeing_prefix():
