@@ -242,18 +242,21 @@ def count_fold_stats(phase: str, stats, sums) -> None:
 
 def _flash_counter(cfg: MimoV2FlashConfig, rows: int, bucket: int):
     """What counts a prefill dispatch's flash-forward blocks, by the kind
-    of its layers: the k-blocks the kernel scored and those a window let it
-    pass over, from the kernel's own arithmetic
-    (``kernels.window_block_visits``) for ``rows`` sequences of ``bucket``
+    of its layers: the (q-block, k-block) pairs the kernel fetched and
+    scored and those the diagonal or a window let it pass over, in tiles of
+    128 x 128 whatever height its q-blocks take, from the kernel's own walk
+    (``kernels.flash_block_visits``) for ``rows`` sequences of ``bucket``
     positions."""
-    from ..kernels import window_block_visits
+    from ..kernels import flash_block_visits
 
     per_call = {}
     for kind, name in ((FULL, "full"), (WINDOW, "window")):
         a = cfg.attention(kind)
         n = sum(1 for t in cfg.layer_pattern if t == kind)
         window = a.window if a.window < bucket else 0
-        seen, grid = window_block_visits(bucket, bucket, window)
+        seen, grid = flash_block_visits(
+            bucket, bucket, window=window, head_dim=a.qk, v_dim=a.v,
+            itemsize=4 if cfg.dtype == "float32" else 2)
         per_call[name] = (n, rows * a.heads * seen,
                           rows * a.heads * (grid - seen))
 
@@ -265,7 +268,8 @@ def _flash_counter(cfg: MimoV2FlashConfig, rows: int, bucket: int):
             "(q-block, k-block) pairs of the flash forward's grid over a "
             "prefill's sequences and heads, by the kind of the layer's "
             "cache: what=visited the pairs it fetched and scored, "
-            "what=skipped those a window hid from a whole q-block")
+            "what=skipped those the diagonal or a window hid from a whole "
+            "q-block")
         calls = monitor.counter(
             "flash_attention_calls_total",
             "calls of the flash forward in prefill dispatches, by the kind "

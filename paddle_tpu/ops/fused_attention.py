@@ -289,6 +289,15 @@ def _fused_mha(ctx, ins, attrs):
                                  plan.causal_block, sink)
         return {"Out": [o.reshape(B, H, Sq, plan.Dv)]}
 
+    from ..kernels import flash_forward_grid
+
+    # the grid the forward builds for this shape and mask: its q-block's
+    # height and how it walks the (q-block, k-block) pairs
+    note_kernel_route(
+        ctx, "fused_multihead_attention.grid", flash_forward_grid(
+            Sq, plan.Sk, D, plan.Dv, q.dtype.itemsize, causal=plan.causal,
+            window=plan.window, causal_block=plan.causal_block,
+            dropout=plan.dropout > 0.0))
     kernel = functools.partial(_kernel_attention,
                                **plan.kernel_options(route))
     o, lse = _run_kernel(ctx, kernel, [q, k, v, _key_bias(ins), sink],

@@ -84,7 +84,8 @@ def _latent_attention(ctx, ins, attrs):
     cache rows the kernel's walk fetches (whole blocks up to each
     sequence's last live one), in prefill the rows the flash kernel reads
     keys of (``B x S``)."""
-    from ..kernels import flash_attention, paged_kv_append
+    from ..kernels import (flash_attention, flash_forward_grid,
+                           paged_kv_append)
     from ..kernels.decode_attention import last_live_block
     from ..kernels.latent_attention import (
         latent_block_rows, mla_decode_attention,
@@ -137,6 +138,9 @@ def _latent_attention(ctx, ins, attrs):
             o = _primitive_attention(ctx, flat(q), flat(k), flat(v), None,
                                      True, scale, 0.0, True)
         else:
+            note_kernel_route(
+                ctx, "latent_attention.grid", flash_forward_grid(
+                    S, S, dq, dv, q.dtype.itemsize, causal=True))
             o = flash_attention(flat(q), flat(k), flat(v), causal=True,
                                 scale=scale, num_heads=nh,
                                 interpret=(route == "pallas-interpret"))

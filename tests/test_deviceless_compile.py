@@ -84,7 +84,9 @@ def test_kernels_keep_their_names_in_the_compiled_hlo(v5e):
         with jax.named_scope("fused_attention"):
             fwd = flash_attention(q, k, v, num_heads=12)
         with jax.named_scope("fused_attention_grad"):
-            grads = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+            # other operands than the forward's: two calls of the one
+            # traced forward (PR 50) on the same operands are one call
+            grads = jax.grad(loss, argnums=(0, 1, 2))(k, q, v)
         return (fwd, grads,
                 flash_attention_decode(qd, kc, vc, n, num_heads=12,
                                        page_size=128))
@@ -687,3 +689,180 @@ def test_window_fold_compiles_and_updates_the_rings_in_place(v5e):
     assert re.search(r'op_name="[^"]*window_fold/', text)
     assert not _copies_of(text, 128, 8, 128, 256)
     assert not _copies_of(text, 128, 8, 128, 128)
+
+
+# -- the flash forward's grid: forms, heights, layouts (PR 50) ----------------
+
+# name: (query heads, key/value heads, rows, key width, value width, dtype,
+# window, sink, sequences that bring a key bias (0: none), the heights forced
+# beside 128)
+FORWARD_SHAPES = {
+    "mimo_full_256": (64, 4, 256, 192, 128, "bfloat16", 0, False, 1, (256,)),
+    "mimo_full_3584": (64, 4, 3584, 192, 128, "bfloat16", 0, False, 1,
+                       (256, 512)),
+    "mimo_window_256": (64, 8, 256, 192, 128, "bfloat16", 128, True, 1,
+                        (256,)),
+    "mimo_window_3584": (64, 8, 3584, 192, 128, "bfloat16", 128, True, 1,
+                         (256, 512)),
+    "glm_latent_1024": (20, 20, 1024, 256, 256, "bfloat16", 0, False, 0,
+                        (256, 512)),
+    "gpt2_f32_biased_512": (96, 96, 512, 64, 64, "float32", 0, False, 8,
+                            (256, 512)),
+}
+
+
+def _forward_cases():
+    for name, shape in FORWARD_SHAPES.items():
+        for form in ("dense", "guarded", "flat"):
+            for layout in ("rows", "lanes"):
+                if layout == "lanes" and shape[4] % 128:
+                    continue            # values of no whole lane tile
+                yield pytest.param(name, form, layout,
+                                   id=f"{name}-{form}-{layout}")
+
+
+@pytest.mark.parametrize("name,form,layout", list(_forward_cases()))
+def test_every_form_of_the_flash_forward_compiles(v5e, name, form, layout):
+    """MiMo-V2-Flash's two layers at its shortest and longest bucket (with
+    the prompts' key bias, as its prefill calls them), GLM-4.7-Flash's
+    latent prefill and GPT-2's f32 biased prefill: every
+    form of the grid, at 128 rows and at the taller blocks, in both tile
+    layouts, compiles for the v5e (one program a case, a call a height)."""
+    import dataclasses
+    import sys
+
+    fa = sys.modules["paddle_tpu.kernels.flash_attention"]
+    Hq, H, S, D, Dv, dtype, window, with_sink, seqs, tall = \
+        FORWARD_SHAPES[name]
+
+    def attend(q, k, v, bias, sink):
+        outs = []
+        for h in (128,) + tall:
+            cfg, b, scalars = fa._prepare(
+                q, k, bias if seqs else None, True, None, 0.0, 0, 0, 0,
+                Hq // (seqs or 1), h, 128, False, window)
+            if with_sink:
+                cfg = dataclasses.replace(cfg, has_sink=True)
+            outs.append(fa._fwd(cfg, q, k, v, b, scalars,
+                                sink if with_sink else None, form=form,
+                                lanes=layout == "lanes"))
+        return outs
+
+    text = _compiles_with_mosaic(
+        attend, v5e((Hq, S, D), dtype), v5e((H, S, D), dtype),
+        v5e((H, S, Dv), dtype), v5e((seqs or 1, S), jnp.float32),
+        v5e((Hq // (seqs or 1),), jnp.float32))
+    assert len(re.findall(r"%flash_attention_fwd[.\d]* = ", text)) \
+        == 1 + len(tall)
+
+
+@pytest.mark.parametrize("name", sorted(FORWARD_SHAPES))
+def test_the_chosen_flash_forward_compiles(v5e, name):
+    """The same shapes as the library itself calls them."""
+    Hq, H, S, D, Dv, dtype, window, with_sink, seqs, _ = \
+        FORWARD_SHAPES[name]
+
+    def attend(q, k, v, bias, sink):
+        return flash_attention(
+            q, k, v, bias=bias if seqs else None, causal=True,
+            num_heads=Hq // (seqs or 1), window=window,
+            sink=sink if with_sink else None)
+
+    _compiles_with_mosaic(
+        attend, v5e((Hq, S, D), dtype), v5e((H, S, D), dtype),
+        v5e((H, S, Dv), dtype), v5e((seqs or 1, S), jnp.float32),
+        v5e((Hq // (seqs or 1),), jnp.float32))
+
+
+@pytest.mark.parametrize("shape,dtype,bias", [
+    ((96, 512, 64), "float32", True),       # GPT-2's 512 bucket: flat, split
+    ((64, 3584, 128), "bfloat16", False),   # a tall causal grid, in lanes
+])
+def test_a_stack_of_layers_traces_and_lowers_one_forward_kernel(v5e, shape,
+                                                                 dtype, bias):
+    """The setup budget (PERF.md, PR 50), held without a clock: the twelve
+    layers of a program call the forward with one configuration and one set
+    of shapes, so ``_fwd_kernel``'s body runs once for all of them (twice
+    at most; twelve times before PR 50) and the lowered module holds ONE
+    Mosaic body, called from twelve sites."""
+    import sys
+
+    fa = sys.modules["paddle_tpu.kernels.flash_attention"]
+    runs, real = [0], fa._fwd_kernel
+
+    def counted(*a, **kw):
+        runs[0] += 1
+        return real(*a, **kw)
+
+    def stack(q, k, v, b):
+        for _ in range(12):
+            q = flash_attention(q, k, v, bias=b if bias else None,
+                                causal=True, num_heads=12)
+        return q
+
+    qkv = v5e(shape, dtype)
+    jax.clear_caches()
+    fa._fwd_kernel = counted
+    try:
+        text = jax.jit(stack).lower(
+            qkv, qkv, qkv, v5e((shape[0] // 12 or 1, shape[1]),
+                               jnp.float32)).as_text()
+    finally:
+        fa._fwd_kernel = real
+    assert 1 <= runs[0] <= 2
+    assert text.count("stablehlo.custom_call @tpu_custom_call") == 1
+    assert len(re.findall(r"call @\w*_fwd\w*\(", text)) == 12
+
+
+@pytest.mark.parametrize("case,digest", [
+    ("gpt2_128", "e1fb2e0b531ac55a"), ("command_a_plus_128",
+                                       "c2e2335b9fed4f94"),
+    ("bert_512", "8442b240d069834b")])
+def test_a_call_with_nothing_to_cut_builds_the_kernel_it_always_had(case,
+                                                                    digest):
+    """A 1 x 1 grid (GPT-2's 128 bucket with its key bias, Command A+'s
+    grouped heads) and a call that is not causal (BERT's forward, dropout
+    in the kernel) have no pair to skip and take no taller block: the
+    kernel's jaxpr is the parent form's to the letter (one step, no table,
+    no second branch), so such a program pays nothing for the grids the
+    other calls take. ``digest``: the first 16 hex digits of the SHA-256 of
+    the jaxpr's text as commit a55dee3 (the parent of PR 50) built it with
+    this installation's JAX (``/root/scratch`` copy, ``str(jaxpr)``)."""
+    import hashlib
+
+    sds = jax.ShapeDtypeStruct
+    f32, bf = jnp.float32, jnp.bfloat16
+    fn, args, grid = {
+        "gpt2_128": (
+            lambda q, k, v, b: flash_attention(q, k, v, bias=b, causal=True,
+                                               num_heads=12),
+            (sds((96, 128, 64), f32),) * 3 + (sds((8, 128), f32),),
+            (96, 1, 1)),
+        "command_a_plus_128": (
+            lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                            num_heads=128),
+            (sds((128, 128, 128), bf),) + (sds((8, 128, 128), bf),) * 2,
+            (128, 1, 1)),
+        "bert_512": (
+            lambda q, k, v, b: flash_attention(q, k, v, bias=b, seed=3,
+                                               dropout_rate=0.1,
+                                               num_heads=12),
+            (sds((24, 512, 64), bf),) * 3 + (sds((2, 512), f32),),
+            (24, 4, 4)),
+    }[case]
+
+    def pallas_calls(jaxpr, out):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                out.append(e.params)
+            for v in e.params.values():
+                inner = getattr(v, "jaxpr", v)
+                if hasattr(inner, "eqns"):
+                    pallas_calls(inner, out)
+        return out
+
+    (call,) = pallas_calls(jax.make_jaxpr(fn)(*args).jaxpr, [])
+    assert call["grid_mapping"].grid == grid
+    assert call["grid_mapping"].num_index_operands == 1     # no table
+    text = str(call["jaxpr"])
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest, text

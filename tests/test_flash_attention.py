@@ -5,6 +5,10 @@ the TPU PRNG has no interpret lowering); the `tpu` marker cases cover the
 compiled Mosaic path including in-kernel dropout. Oracle: the primitive
 softmax composition (which is also the op's off-TPU lowering), matching
 reference semantics of fused attention (operators/fused/ role)."""
+import dataclasses
+import functools
+import sys
+
 import numpy as np
 import pytest
 
@@ -247,14 +251,17 @@ def _attention_grads(mode, use_bias=False, causal=False, window=0,
         flags.set_flags({"FLAGS_use_flash_attention": "auto"})
 
 
-def _routes(program):
-    """{(op, route): lowerings} of one program from kernel_route_total."""
+def _routes(program, grid=False):
+    """{(op, route): lowerings} of one program from kernel_route_total: the
+    paths its ops took, or (``grid``) the grids their flash forwards built
+    (``op="<op>.grid"``, PR 50)."""
     from paddle_tpu import monitor
 
     fam = monitor.get_registry().get("kernel_route_total")
     return {(lab["op"], lab["route"]): int(n.value)
             for lab, n in (fam.children() if fam else ())
-            if lab["program"] == str(program._serial)}
+            if lab["program"] == str(program._serial)
+            and lab["op"].endswith(".grid") == grid}
 
 
 @pytest.mark.parametrize("use_bias,causal,window", [
@@ -517,9 +524,9 @@ def test_kernel_takes_a_sink_and_values_narrower_than_keys(window,
     (8, 8, 1, 8), (8, 8, 20, 26), (16, 8, 12, 14), (8, 16, 12, 14)])
 def test_blocks_a_window_hides_are_skipped_and_change_nothing(bq, bk, window,
                                                               visited):
-    """The k axis of a windowed forward holds only the blocks a q-block's
-    window can touch: the grid is shorter, the count says what it visits,
-    and the output is the full grid's bit for bit."""
+    """A windowed forward walks only the blocks a q-block's window can
+    touch: the grid is shorter, the count says what it visits, and the
+    output is the dense grid's."""
     import sys
 
     from paddle_tpu.kernels import window_block_visits
@@ -531,18 +538,16 @@ def test_blocks_a_window_hides_are_skipped_and_change_nothing(bq, bk, window,
     cut = flash_attention(q, k, v, **kw)
     seen, grid = window_block_visits(64, 64, window, bq, bk)
     assert (seen, grid) == (visited, (64 // bq) * (64 // bk))
-    # the same call with the skip switched off: every block visited
-    real = fa._window_steps
-    fa._window_steps = lambda *a: 0
-    try:
-        whole = flash_attention(q, k, v, **kw)
-    finally:
-        fa._window_steps = real
-    np.testing.assert_array_equal(np.asarray(cut), np.asarray(whole))
-    # and the cut grid really is shorter
-    cfg, _, _ = fa._prepare(q, k, None, True, None, 0.0, 0, 0, 0, 4, bq, bk,
-                            True, window)
-    assert 0 < cfg.k_steps < 64 // bk
+    # the same call on the dense grid: every block visited
+    cfg, _, scalars = fa._prepare(q, k, None, True, None, 0.0, 0, 0, 0, 4,
+                                  bq, bk, True, window)
+    cfg = dataclasses.replace(cfg, has_sink=True)
+    whole, _ = fa._fwd(cfg, q, k, v, None, scalars, sink, form="dense")
+    np.testing.assert_allclose(np.asarray(cut), np.asarray(whole), atol=1e-6,
+                               rtol=0)
+    # and the cut grid really is shorter: the visible pairs alone
+    _, walk = fa._forward_grid(cfg, 64, 64, 24, 16, 4)
+    assert (walk.form, walk.steps) == ("flat", visited)
 
 
 def test_windowed_forward_with_traced_offsets_visits_one_block_more():
@@ -598,3 +603,225 @@ def test_op_computes_the_sink_and_widths_on_both_routes(flash):
                      jnp.asarray(sink), 8, H)
     assert got.shape == (B, H, S, Dv)
     np.testing.assert_allclose(got.reshape(B * H, S, Dv), want, atol=3e-6)
+
+
+# -- the forward's grid: forms, heights, layouts (PR 50) ----------------------
+
+_FORMS = ("dense", "guarded", "flat")
+_HEIGHTS = (128, 256, 384, 512)
+# name -> (options of the call, D, Dv, query heads, key/value heads, traced)
+_MASKS = {
+    "causal": (dict(), 16, 16, 2, 2),
+    "window200": (dict(window=200), 16, 16, 2, 2),
+    "window128_sink": (dict(window=128, sink=True), 16, 16, 2, 2),
+    "kv_group2": (dict(), 16, 16, 4, 2),
+    "causal_block4": (dict(causal_block=4), 16, 16, 2, 2),
+    "key_bias": (dict(bias=True), 16, 16, 2, 2),
+    "wide_keys": (dict(), 24, 16, 2, 2),
+    "offsets": (dict(q_offset=256, k_offset=128), 16, 16, 2, 2),
+    "traced_offsets": (dict(q_offset=256, k_offset=128, traced=True), 16,
+                       16, 2, 2),
+    # keys from position 640 on: the q-blocks before it see none
+    "no_key_seen": (dict(k_offset=640), 16, 16, 2, 2),
+}
+_S = 1536           # whole blocks of 128, 256, 384 and 512 rows
+
+
+def _fa():
+    return sys.modules["paddle_tpu.kernels.flash_attention"]
+
+
+@functools.lru_cache(maxsize=None)
+def _mask_case(name):
+    opts, D, Dv, H, Hkv = _MASKS[name]
+    rng = np.random.RandomState(len(name))
+    mk = lambda *s: jnp.asarray(rng.randn(*s).astype(np.float32))
+    q, k, v = mk(H, _S, D), mk(Hkv, _S, D), mk(Hkv, _S, Dv)
+    bias = (jnp.asarray(np.where(rng.rand(1, _S) < 0.2, -1e4, 0.0)
+                        .astype(np.float32)) if opts.get("bias") else None)
+    sink = mk(H) if opts.get("sink") else None
+    return q, k, v, bias, sink
+
+
+def _forward_as(name, form, height, lanes):
+    """The forward alone of mask ``name`` at a forced form, height and
+    layout: (o, lse)."""
+    fa = _fa()
+    opts = _MASKS[name][0]
+    q, k, v, bias, sink = _mask_case(name)
+
+    def call(q_off, k_off):
+        cfg, b, scalars = fa._prepare(
+            q, k, bias, True, None, 0.0, 0, q_off, k_off, q.shape[0],
+            height, 128, True, opts.get("window", 0),
+            opts.get("causal_block", 0))
+        if sink is not None:
+            cfg = dataclasses.replace(cfg, has_sink=True)
+        return fa._fwd(cfg, q, k, v, b, scalars, sink, form=form,
+                       lanes=lanes)
+
+    offs = opts.get("q_offset", 0), opts.get("k_offset", 0)
+    if opts.get("traced"):
+        return jax.jit(call)(*map(jnp.int32, offs))
+    return call(*offs)
+
+
+@functools.lru_cache(maxsize=None)
+def _dense_128(name):
+    return _forward_as(name, "dense", 128, False)
+
+
+def _grid_cases():
+    for name, (opts, *_rest) in _MASKS.items():
+        for form in _FORMS:
+            if form == "flat" and opts.get("traced"):
+                continue        # a table needs offsets known on the host
+            for h in _HEIGHTS:
+                for layout in ("rows", "lanes"):
+                    yield pytest.param(name, form, h, layout,
+                                       id=f"{name}-{form}-q{h}-{layout}")
+
+
+@pytest.mark.parametrize("name,form,height,layout", list(_grid_cases()))
+def test_forward_is_the_dense_128_row_grids_at_every_form_and_height(
+        name, form, height, layout):
+    """``o`` and ``lse`` do not depend on the grid: every form (dense,
+    guarded, flat), q-block height and tile layout gives what the dense
+    128 x 128 grid in the [queries, keys] layout gives, on the interpreter
+    (to the last bits of f32: its programs differ, the chip's bits are
+    ``tools/probe_flash_forward.py --check``'s)."""
+    o, lse = _forward_as(name, form, height, layout == "lanes")
+    want_o, want_lse = _dense_128(name)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(want_o), atol=2e-6,
+                               rtol=0)
+    dead = np.isneginf(np.asarray(want_lse))
+    assert np.array_equal(np.isneginf(np.asarray(lse)), dead)
+    np.testing.assert_allclose(np.asarray(lse)[~dead],
+                               np.asarray(want_lse)[~dead], atol=5e-6,
+                               rtol=0)
+    if name == "no_key_seen":       # rows before the first key: zeros
+        assert dead[:, :640].all() and not dead[:, 640:].any()
+        assert not np.asarray(o)[:, :640].any()
+
+
+@pytest.mark.parametrize("name", sorted(
+    n for n, m in _MASKS.items()        # the composition takes no offsets
+    if not (m[0].get("q_offset") or m[0].get("k_offset"))))
+def test_dense_128_row_grid_is_the_written_out_softmax(name):
+    """The yardstick of the test above against the primitive composition."""
+    from paddle_tpu.ops.fused_attention import _primitive_attention
+
+    opts = _MASKS[name][0]
+    q, k, v, bias, sink = _mask_case(name)
+    want = _primitive_attention(
+        None, q, k, v, bias, True, q.shape[-1] ** -0.5, 0.0, True,
+        opts.get("window", 0), opts.get("causal_block", 0), sink)
+    np.testing.assert_allclose(np.asarray(_dense_128(name)[0]),
+                               np.asarray(want), atol=3e-6, rtol=0)
+
+
+@pytest.mark.parametrize("kw,label,visits", [
+    # MiMo-V2-Flash's full layers (keys 192, values 128) and window layers
+    (dict(sq=3584, head_dim=192, v_dim=128), "q512xk128/flat", (448, 784)),
+    (dict(sq=1024, head_dim=192, v_dim=128), "q512xk128/flat", (48, 64)),
+    (dict(sq=256, head_dim=192, v_dim=128), "q256xk128/dense", (4, 4)),
+    (dict(sq=3584, head_dim=192, v_dim=128, window=128), "q128xk128/flat",
+     (55, 784)),
+    (dict(sq=256, head_dim=192, v_dim=128, window=128), "q128xk128/flat",
+     (3, 4)),
+    # GLM-4.7-Flash's latent prefill; SDAR's causal by blocks of 4
+    (dict(sq=768, head_dim=256), "q384xk128/flat", (27, 36)),
+    (dict(sq=1024, head_dim=128, causal_block=4), "q512xk128/flat",
+     (48, 64)),
+    # GPT-2: f32, heads of 64 (no whole lane tile): the rows layout at 128
+    (dict(sq=512, head_dim=64, itemsize=4), "q128xk128/flat", (10, 16)),
+    (dict(sq=128, head_dim=64, itemsize=4), "q128xk128/dense", (1, 1)),
+    # Command A+: one block. A bucket no tall block divides: 128 rows
+    (dict(sq=128, head_dim=128), "q128xk128/dense", (1, 1)),
+    (dict(sq=640, head_dim=128), "q128xk128/flat", (15, 25)),
+])
+def test_the_grid_is_chosen_from_the_shape_and_the_mask(kw, label, visits):
+    from paddle_tpu.kernels import flash_block_visits, flash_forward_grid
+
+    sq = kw.pop("sq")
+    assert flash_forward_grid(sq, sq, causal=True, **kw) == label
+    assert flash_block_visits(sq, sq, **kw) == visits
+
+
+@pytest.mark.parametrize("kw,label", [
+    (dict(causal=False), "q128xk128/dense"),            # BERT's forward
+    (dict(causal=True, dropout=True), "q128xk128/flat"),
+    (dict(causal=True, static_offsets=False), "q512xk128/guarded"),
+    (dict(causal=True, window=256), "q256xk128/flat"),
+    (dict(causal=True, v_dim=64), "q128xk128/flat"),    # no whole lane tile
+])
+def test_what_keeps_the_forward_at_128_rows(kw, label):
+    from paddle_tpu.kernels import flash_forward_grid
+
+    assert flash_forward_grid(1024, 1024, 128, **kw) == label
+
+
+def test_gradient_is_the_same_whatever_grid_the_forward_takes():
+    """The backward kernels keep 128 x 128 and are handed the forward's
+    residuals: the gradient of a call whose forward takes 512-row blocks
+    with queries in lanes is that of the call held to 128 rows."""
+    rng = np.random.RandomState(11)
+    q, k, v = (jnp.asarray(rng.randn(2, 512, 128).astype(np.float32))
+               for _ in range(3))
+    fa = _fa()
+    cfg, _, _ = fa._prepare(q, k, None, True, None, 0.0, 0, 0, 0, 1, None,
+                            128, True, 0)
+    tall, walk = fa._forward_grid(cfg, 512, 512, 128, 128, 4)
+    assert (tall.block_q, walk.lanes, cfg.block_q) == (512, True, 128)
+
+    def loss(block_q):
+        def f(q, k, v):
+            o, lse = flash_attention_with_lse(
+                q, k, v, causal=True, interpret=True, block_q=block_q)
+            return jnp.sum(o * o) + jnp.sum(jnp.sin(lse))
+        return jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+
+    for got, want in zip(loss(None), loss(128)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=5e-5, rtol=1e-5)
+
+
+def test_a_table_too_long_for_smem_walks_the_guarded_grid():
+    fa = _fa()
+    cfg = fa._shape_cfg(128 * 200, 128 * 200, True, 0, 0, 128, 128)
+    _, walk = fa._forward_grid(cfg, 128 * 200, 128 * 200, 128, 128, 2)
+    assert walk.form == "guarded" and walk.steps == 200
+
+
+@pytest.mark.parametrize("S,window,use_bias,label", [
+    (1024, 0, False, "q512xk128/flat"), (256, 128, False, "q128xk128/flat"),
+    (1024, 0, True, "q512xk128/flat"), (128, 0, True, "q128xk128/dense")])
+def test_op_notes_the_grid_its_forward_takes(S, window, use_bias, label):
+    """``kernel_route_total{op="fused_multihead_attention.grid"}``: the
+    q-block's height and the form of the walk, a lowering."""
+    B, H, D = 1, 2, 128
+    mk = lambda *s: RNG.randn(*s).astype(np.float32)
+    feed = {"q": mk(B, H, S, D), "k": mk(B, H, S, D), "v": mk(B, H, S, D)}
+    if use_bias:
+        feed["bias"] = np.zeros((B, S), np.float32)
+    fluid.set_flags({"FLAGS_use_flash_attention": "always"})
+    try:
+        main, startup = fluid.Program(), fluid.Program()
+        with un.guard(), fluid.program_guard(main, startup):
+            data = {n: fluid.layers.data(n, shape=list(a.shape),
+                                         dtype="float32",
+                                         append_batch_size=False)
+                    for n, a in feed.items()}
+            out = fluid.layers.fused_multihead_attention(
+                data["q"], data["k"], data["v"], bias_qk=data.get("bias"),
+                causal=True, is_test=True, window=window)
+        got = fluid.Executor(fluid.CPUPlace()).run(main, feed=feed,
+                                                   fetch_list=[out])[0]
+    finally:
+        fluid.set_flags({"FLAGS_use_flash_attention": "auto"})
+    assert _routes(main, grid=True) == {
+        ("fused_multihead_attention.grid", label): 1}
+    flat = lambda t: jnp.asarray(t.reshape(B * H, S, D))
+    want = _ref_sink(flat(feed["q"]), flat(feed["k"]), flat(feed["v"]),
+                     None, window, H)
+    np.testing.assert_allclose(got.reshape(B * H, S, D), want, atol=3e-6)
