@@ -36,14 +36,6 @@ def expert_matmul_cost(assignments: float, experts_hit: float, H: int,
             experts_hit * 3.0 * H * F * w_bytes)
 
 
-def router_cost(tokens: float, H: int, E: int):
-    """(operations, bytes) of the router's products over ``tokens`` rows in
-    all. No byte of it has to come from HBM: rows and scores are other
-    operations' results, and the weights (2 MB in f32) are converted once
-    outside the decode loop and stay where the compiler put them."""
-    return 2.0 * tokens * H * E, 0.0
-
-
 def _least_seconds(ops: float, moved: float, peaks: dict) -> float:
     return max(ops / peaks["bf16_flops_per_s"],
                moved / peaks["hbm_bytes_per_s"])
@@ -66,19 +58,18 @@ def moe_expert_matmul_seconds(config: dict, counters: dict, peaks: dict):
     return (total, calls) if total else None
 
 
-def moe_router_seconds(config: dict, counters: dict, peaks: dict):
-    """The router scores every row of a dispatch: ``slots`` rows a decode
-    step, ``prefill_rows x bucket`` rows a prefill (one bucket in this
-    configuration)."""
-    s = config["serving"]
-    rows = {"decode": s["slots"],
-            "prefill": s.get("prefill_rows", s["slots"])
-            * max(s["prompt_buckets"])}
-    H, E = config["hidden_size"], config["deployment"]["num_experts_total"]
-    total = 0.0
-    for phase in PHASES:
-        calls = sum_matching(counters, "moe_expert_calls_total", phase=phase)
-        total += _least_seconds(*router_cost(calls * rows[phase], H, E),
-                                peaks)
-    calls = sum_matching(counters, "moe_expert_calls_total")
-    return (total, calls) if total else None
+def moe_expert_matmul_slice_seconds(config: dict, dispatches, peaks: dict):
+    """The same over the traced slice's own dispatches
+    (``readers.kernel_roofline_slice``): ``dispatches`` holds what the
+    engine noted of each on its ``serving.settle`` span, among it what the
+    dispatch's expert ops counted (``moe_expert_tokens``,
+    ``moe_experts_hit``: assignments that reached the held experts and held
+    experts hit, summed over the dispatch's steps and layers). A dispatch
+    at a time: a decode chunk is bound by the hit experts' bytes, a prefill
+    by the MXU. Least seconds for all of them, or None where no span
+    carries the counts (the parent commit, a model without experts)."""
+    H, F = config["hidden_size"], config["intermediate_size"]
+    total = sum(_least_seconds(*expert_matmul_cost(
+        float(d["moe_expert_tokens"]), float(d["moe_experts_hit"]), H, F),
+        peaks) for d in dispatches if "moe_experts_hit" in d)
+    return total or None

@@ -37,6 +37,14 @@ def moe_expert_matmul_seconds(config: dict, counters: dict, peaks: dict):
         counters, peaks)
 
 
+def moe_expert_matmul_slice_seconds(config: dict, dispatches, peaks: dict):
+    """``kernel_costs.moe_expert_matmul_slice_seconds`` itself, handed an
+    expert's width under the key it reads."""
+    return kernel_costs.moe_expert_matmul_slice_seconds(
+        dict(config, intermediate_size=config["moe_intermediate_size"]),
+        dispatches, peaks)
+
+
 def gdn_step_cost(tokens: float, Hv: int, Dk: int, Dv: int):
     """(operations, bytes) of ``tokens`` single steps of the rule: three
     Dk x Dv products a head; the head's state read and written."""

@@ -8,7 +8,13 @@ calls)``, or None where the program has no such counter.
 
 * The expert matmul: ``kernel_costs.expert_matmul_cost`` with an expert's
   width read from ``moe_intermediate_size`` (this model's
-  ``intermediate_size`` is the dense first layer's).
+  ``intermediate_size`` is the dense first layer's). No entry reads it
+  since PR 51: in this configuration's decode program the compiler stages
+  the gate stack of six of the seven expert layers (8 x 2048 x 1536 bf16,
+  50 MB: it fits the fast memory) ahead of the kernel through asynchronous
+  slices, so the kernel's own time leaves out two sevenths of the bytes
+  this function counts, and both readers read 104-109% (PERF.md section 6,
+  PR 51). ``tools/roofline_readers.py`` still reads both.
 * The latent decode kernel: a cache row ``[c (dc) | k_rope (dr)]`` is
   fetched once for all heads (``(dc + dr)`` numbers of the cache's type:
   128 slots x 1,750 rows x 1,152 B is 258 MB a layer, it cannot sit on

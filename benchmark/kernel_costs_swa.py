@@ -40,7 +40,10 @@ seconds for all of them, or None.
 """
 from __future__ import annotations
 
-from kernel_costs import _least_seconds, expert_matmul_cost
+from kernel_costs import _least_seconds
+# ``kernel_costs.moe_expert_matmul_slice_seconds`` handed an expert's width
+# under the key it reads
+from kernel_costs_hybrid import moe_expert_matmul_slice_seconds  # noqa: F401
 from kernel_costs_latent import _ITEMSIZE
 
 KINDS = (("full", ""), ("window", "swa_"))
@@ -67,14 +70,6 @@ def decode_attention_slice_seconds(config: dict, dispatches, peaks: dict):
                 config[pre + "head_dim"], config[pre + "v_head_dim"], size)
             ops, moved = ops + o, moved + b
     return _least_seconds(ops, moved, peaks) or None
-
-
-def moe_expert_matmul_slice_seconds(config: dict, dispatches, peaks: dict):
-    H, F = config["hidden_size"], config["moe_intermediate_size"]
-    total = sum(_least_seconds(*expert_matmul_cost(
-        float(d["moe_expert_tokens"]), float(d["moe_experts_hit"]), H, F),
-        peaks) for d in dispatches if "moe_experts_hit" in d)
-    return total or None
 
 
 def flash_fwd_cost(lengths, layers: int, heads: int, qk: int, vd: int,

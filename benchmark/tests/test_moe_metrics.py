@@ -16,10 +16,8 @@ CFG = harness.load_json(harness.HERE, "configs",
 PEAKS = harness.load_json(harness.HERE, "peaks.json")["devices"][
     "TPU v5 lite"]
 FILES = {n: harness.load_json(harness.HERE, "layer_metrics", n + ".json")
-         for n in ("expert_time_pct.moe", "router_time_pct.moe",
-                   "decode_kernel_time_pct.moe",
-                   "expert_matmul_roofline_pct.moe",
-                   "router_roofline_pct.moe")}
+         for n in ("expert_time_pct.moe", "decode_kernel_time_pct.moe",
+                   "expert_matmul_roofline_pct.moe")}
 
 # left-hand sides and targets of the Mosaic calls in the compiled decode and
 # prefill programs of a described v5e, with a fusion that reads one
@@ -46,8 +44,6 @@ HLO = {
 @pytest.mark.parametrize("metric,hits", [
     ("expert_time_pct.moe", {"gate_up", "down"}),
     ("expert_matmul_roofline_pct.moe", {"gate_up", "down"}),
-    ("router_time_pct.moe", {"router"}),
-    ("router_roofline_pct.moe", {"router"}),
     ("decode_kernel_time_pct.moe", {"decode"}),
 ])
 def test_kernel_name_patterns(metric, hits):
@@ -67,12 +63,6 @@ def test_expert_matmul_cost_matches_the_hand_count():
     # the bytes bound it: 1.97 ms against 0.03 ms of multiplies
     assert moved / PEAKS["hbm_bytes_per_s"] > 50 * ops / PEAKS[
         "bf16_flops_per_s"]
-
-
-def test_router_cost_matches_the_hand_count():
-    ops, moved = kernel_costs.router_cost(tokens=64, H=4096, E=128)
-    assert ops == 2 * 64 * 4096 * 128
-    assert moved == 0       # nothing of it has to come from HBM
 
 
 def _counters(decode_calls, prefill_calls, layers=4):
@@ -109,9 +99,6 @@ def test_roofline_reader_compares_a_call_with_a_call(monkeypatch):
     args = FILES["expert_matmul_roofline_pct.moe"]["args"]
     assert kernel_roofline.read(ctx, **args) == pytest.approx(
         100.0 * (least / calls) / 2e-3)
-    router = kernel_roofline.read(ctx, **FILES[
-        "router_roofline_pct.moe"]["args"])
-    assert 0 < router < 100
     # a program without the counters (the parent), or no trace: nothing
     assert kernel_roofline.read(dict(ctx, counters={}), **args) is None
     assert kernel_roofline.read(dict(ctx, trace=None), **args) is None
@@ -149,10 +136,6 @@ def test_the_cost_never_passes_what_the_kernel_itself_does(tokens, tm,
         moved += tiles * tm * N * out_bytes
     ops, need = kernel_costs.expert_matmul_cost(
         sum(counts), sum(1 for c in counts if c), H, F)
-    # the router: its products are what the algorithm needs, and the
-    # kernel makes them in true f32, several passes each
-    assert kernel_costs.router_cost(tokens, H, 128) == (
-        2.0 * tokens * H * 128, 0.0)
     assert ops <= done_ops and need <= moved
     least = max(ops / PEAKS["bf16_flops_per_s"],
                 need / PEAKS["hbm_bytes_per_s"])

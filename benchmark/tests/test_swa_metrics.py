@@ -58,24 +58,33 @@ HLO = {
 }
 
 
-def test_the_cell_has_its_swa_metrics_and_only_they_list_it():
-    assert len(NAMES) == 14
+def cell_invariants(bench: dict) -> None:
+    """What this file holds of ``BENCHMARK.json``, on the tree's or on one
+    with further cells appended (``test_layer_metric_files.py``
+    ``test_a_cell_can_be_appended``): no count of anything."""
+    names = {m["name"] for m in bench["per_layer"]
+             if m["name"].endswith(".swa")}
     # every file of the cell has its entry, and every entry its file
-    files = {n[:-5] for n in os.listdir(
-        os.path.join(harness.HERE, "layer_metrics")) if n.endswith(".swa.json")}
-    assert files == set(NAMES)
-    for m in BENCH["per_layer"]:
+    files = {n[:-len(".json")] for n in os.listdir(
+        os.path.join(harness.HERE, "layer_metrics"))
+        if n.endswith(".swa.json")}
+    assert names and names == files
+    for m in bench["per_layer"]:
         if m["name"].endswith(".swa"):
             assert m["workloads"] == [CELL]
         else:
             assert CELL not in m.get("workloads", [])
-    assert len(BENCH["per_layer"]) <= 128          # the contract's room
-    e2e = {m["name"]: m for m in BENCH["end_to_end"]}
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
     assert CELL in e2e["decode_tokens_per_s"]["workloads"]
-    cell = next(w for w in BENCH["workloads"] if w["name"] == CELL)
+    cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "mimo-v2-flash-ep16-serve", "decode-mixed-lengths", 1)
-    assert sum(w["chips"] == 4 for w in BENCH["workloads"]) == 0
+
+
+def test_the_cell_has_its_swa_metrics_and_only_they_list_it():
+    cell_invariants(BENCH)
+    # the one place that holds the contract's room
+    assert len(BENCH["per_layer"]) <= 128
     assert all(len(e["why"]) <= 200 for e in BENCH["workloads"]
                + BENCH["configs"])
 
@@ -83,7 +92,6 @@ def test_the_cell_has_its_swa_metrics_and_only_they_list_it():
 @pytest.mark.parametrize("metric,hits", [
     ("decode_kernel_time_pct.swa", {"decode"}),
     ("decode_attention_roofline_pct.swa", {"decode"}),
-    ("router_time_pct.swa", {"router"}),
     ("expert_time_pct.swa", {"gate_up", "down"}),
     ("expert_matmul_roofline_pct.swa", {"gate_up", "down"}),
 ])
@@ -400,6 +408,49 @@ def test_expert_roofline_and_counter_shares_read_their_families(monkeypatch):
     assert counter_share.read(
         {"counters": {}},
         **FILES["window_rows_share_pct.swa"]["args"]) is None
+
+
+def test_a_light_slice_reads_its_own_calls_not_the_windows_mean(monkeypatch):
+    """Why the expert share of this cell is read over the slice's own
+    dispatches. Two decode chunks in the slice whose executions hit 12 of
+    the 16 held experts, in a window whose mean execution (prefills and
+    other contexts in it) hits all 16: the window's counters over the
+    slice's mean time (``kernel_roofline_in``) read a third too high, past
+    100, which no kernel does; the joined chunks' own counts over the time
+    of the operations inside their modules read what the kernel did."""
+    from readers import kernel_roofline_in
+
+    H, F = CFG["hidden_size"], CFG["moe_intermediate_size"]
+    expert = 3 * H * F * 2 / PEAKS["hbm_bytes_per_s"]   # one's bytes: 61 us
+    # a chunk: 16 steps x 6 layers; a call pair takes 1.25 x its floor
+    chunk = {"moe_expert_tokens": 96 * 48, "moe_experts_hit": 96 * 12,
+             "moe_expert_calls": 96}
+    pair_ns = 1.25 * 12 * expert * 1e9
+    joined = [{"path": "chained", "launch_t": 10.001 + i, "module_start_ns":
+               i * 1e9, "module_end_ns": (i + 1) * 1e9} for i in range(2)]
+    spans = [_span("serving.settle", 10.5 + i, 10.6 + i, launch_t0=10.0 + i,
+                   **chunk) for i in range(2)]
+    ops = [(HLO["gate_up" if k % 2 == 0 else "down"],
+            i * 1e9 + k * 2e6, i * 1e9 + k * 2e6 + pair_ns / 2)
+           for i in range(2) for k in range(192)]
+    counters = {}
+    for layer in range(1, 7):
+        lab = f"{{layer={layer},phase=decode}}"
+        counters["moe_expert_calls_total" + lab] = 4000.0
+        counters["moe_expert_tokens_total" + lab] = 4000 * 64.0
+        counters["moe_experts_hit_total" + lab] = 4000 * 16.0
+    monkeypatch.setattr(dispatch_join, "_joined", lambda ctx: joined)
+    monkeypatch.setattr(kernel_roofline, "_newest_profile", lambda: "p")
+    monkeypatch.setattr(kernel_roofline_slice.xplane, "load",
+                        lambda path: {"devices": {"d": {"ops": ops}}})
+    ctx = {"trace": {"window_s": 4.0}, "peaks": PEAKS, "config": CFG,
+           "counters": counters, "spans": spans}
+    args = FILES["expert_matmul_roofline_pct.swa"]["args"]
+    assert kernel_roofline_slice.read(ctx, **args) == pytest.approx(80.0)
+    window = kernel_roofline_in.read(
+        ctx, pattern=args["pattern"], module="kernel_costs_hybrid",
+        cost="moe_expert_matmul_seconds")
+    assert window == pytest.approx(80.0 * 16 / 12) and window > 105
 
 
 def test_expert_load_reads_the_ops_own_histogram():

@@ -1,24 +1,32 @@
 """The readers of PR 39's metrics of the device's wait, on hand-made
 windows: ``counter_share`` (``device_starved_pct.*``,
-``prefill_useful_tokens_pct.*``) and ``dispatch_join``
-(``dispatch_overhead_ms.*``, ``device_idle_window_pct.*``), the second also
-through the program's own join on the fixture the program's tests keep as
-data. A program without the families or the join (the parent commit), a
-run without a profile: None, no raise."""
+``prefill_useful_tokens_pct.*``), ``histogram_mean``
+(``held_assignments_per_step.*``), and the join of the run's dispatches to
+their device modules (``readers.dispatch_join``), which
+``readers.kernel_roofline_slice`` reads through, also through the program's
+own join on the fixture the program's tests keep as data. A program without
+the families or the join (the parent commit), a run without a profile:
+None, no raise. (The two metrics PR 39 built on the join's walls were
+retired by PR 51: under PR 42's overlap a wall holds the chunk ahead.)"""
 import json
 import os
 
 import pytest
 
 import harness
-from readers import counter_share, dispatch_join, kernel_roofline
+from readers import (counter_share, dispatch_join, kernel_roofline,
+                     kernel_roofline_slice)
 
 FILES = {n: harness.load_json(harness.HERE, "layer_metrics", n + ".json")
          for n in ("device_starved_pct.saturated",
+                   "device_starved_pct.hybrid",
+                   "device_starved_pct.latent",
                    "prefill_useful_tokens_pct.saturated",
-                   "dispatch_overhead_ms.saturated",
-                   "device_idle_window_pct.saturated",
-                   "held_assignments_per_step.moe")}
+                   "held_assignments_per_step.moe",
+                   "held_assignments_per_step.hybrid",
+                   "held_assignments_per_step.latent")}
+PEAKS = harness.load_json(harness.HERE, "peaks.json")["devices"][
+    "TPU v5 lite"]
 WINDOW = {
     "executor_starved_seconds{path=chained}_sum": 0.5,
     "executor_starved_seconds{path=chained}_count": 100.0,
@@ -34,8 +42,11 @@ WINDOW = {
 }
 
 
-def test_starved_share_is_starved_over_starved_plus_inflight():
-    args = FILES["device_starved_pct.saturated"]["args"]
+@pytest.mark.parametrize("name", ["device_starved_pct.saturated",
+                                  "device_starved_pct.hybrid",
+                                  "device_starved_pct.latent"])
+def test_starved_share_is_starved_over_starved_plus_inflight(name):
+    args = FILES[name]["args"]
     assert counter_share.read({"counters": WINDOW}, **args) \
         == pytest.approx(100 * 0.8 / 10.0)
     # an executor that never waited reads 0, not nothing
@@ -61,11 +72,11 @@ def test_counter_share_on_a_program_without_the_families(name):
 
 
 def _rows():
-    mk = lambda path, wall, device: {"path": path, "launch_t": 10.0,
-                                     "ready_t": 10.0 + wall,
-                                     "device_s": device}
-    return [mk("chained", 0.092, 0.090), mk("chained", 0.095, 0.091),
-            mk("run", 0.036, 0.035)]
+    mk = lambda path, t, start: {"path": path, "launch_t": t,
+                                 "module_start_ns": start,
+                                 "module_end_ns": start + 90_000_000}
+    return [mk("chained", 10.001, 0), mk("chained", 10.101, 100_000_000),
+            mk("run", 10.201, 200_000_000)]
 
 
 @pytest.fixture()
@@ -84,37 +95,66 @@ def joined(monkeypatch):
     return said
 
 
-def test_overhead_is_the_mean_wall_less_device_time(joined):
-    ctx = {"trace": {"busy_s": 1.0}, "spans": [], "counters": WINDOW}
-    args = FILES["dispatch_overhead_ms.saturated"]["args"]
-    assert dispatch_join.read(ctx, **args) == pytest.approx(
-        (2.0 + 4.0 + 1.0) / 3)
+# a kernel's operations, one inside each joined module and one outside all
+OPS = [("%k.1 = f32[8]{0} custom-call(%a)", 1_000_000, 21_000_000),
+       ("%k.1 = f32[8]{0} custom-call(%a)", 101_000_000, 131_000_000),
+       ("%k.2 = f32[8]{0} custom-call(%a)", 201_000_000, 211_000_000),
+       ("%k.1 = f32[8]{0} custom-call(%a)", 400_000_000, 900_000_000)]
+
+
+def _slice_ctx(monkeypatch, spans=None):
+    """A traced window whose settle spans carry ``work`` seconds each, and
+    a cost module that adds them up."""
+    import types
+
+    monkeypatch.setattr(kernel_roofline_slice.xplane, "load",
+                        lambda path: {"devices": {"d": {"ops": OPS}}})
+    monkeypatch.setitem(
+        __import__("sys").modules, "made_up_costs", types.SimpleNamespace(
+            work=lambda config, did, peaks: sum(d["work"] for d in did)
+            or None))
+    if spans is None:
+        spans = [{"name": "serving.settle", "t0": t + 0.05, "t1": t + 0.06,
+                  "attrs": {"launch_t0": t, "work": w}}
+                 for t, w in ((10.0, 0.010), (10.1, 0.015), (10.2, 0.005))]
+    return {"trace": {"busy_s": 1.0}, "spans": spans, "counters": WINDOW,
+            "peaks": PEAKS, "config": {}}
+
+
+ARGS = {"pattern": r"^%k[.\d]* = ", "module": "made_up_costs", "cost": "work"}
+
+
+def test_the_join_is_made_once_a_run_and_says_what_it_missed(joined,
+                                                             monkeypatch):
+    ctx = _slice_ctx(monkeypatch)
+    # every path: 30 ms of work over 60 ms of operations inside the modules
+    assert kernel_roofline_slice.read(ctx, **ARGS) == pytest.approx(50.0)
     # the second metric of the run reads the same join, and what was not
     # matched is said once
-    assert dispatch_join.read(
-        ctx, **FILES["device_idle_window_pct.saturated"]["args"]
-    ) == pytest.approx(100 * (0.8 + 100 * 0.003 + 50 * 0.001) / 10.0)
+    assert kernel_roofline_slice.read(ctx, path="chained", **ARGS) \
+        == pytest.approx(100 * 0.025 / 0.050)
+    assert kernel_roofline_slice.read(ctx, path="run", **ARGS) \
+        == pytest.approx(100 * 0.005 / 0.010)
     assert len(joined) == 1
     assert "3 of 5" in joined[0] and "no module 1" in joined[0] \
         and "cut by the slice's edge 2" in joined[0]
 
 
-def test_idle_window_without_the_families_or_with_nothing_joined(joined,
-                                                                 monkeypatch):
-    args = FILES["device_idle_window_pct.saturated"]["args"]
-    ctx = {"trace": {"busy_s": 1.0}, "spans": [], "counters": {}}
-    assert dispatch_join.read(ctx, **args) is None
+def test_nothing_joined_or_nothing_noted_reads_nothing(joined, monkeypatch):
     import paddle_tpu.trace as program_trace
 
+    # settle spans without the launch's time (the parent commit)
+    bare = [{"name": "serving.settle", "t0": 10.05, "t1": 10.06,
+             "attrs": {}}]
+    assert kernel_roofline_slice.read(_slice_ctx(monkeypatch, bare),
+                                      **ARGS) is None
     monkeypatch.setattr(
         program_trace, "join_dispatches",
         lambda path, spans: {"joined": [], "inside": 0, "no_module": 0,
                              "claimed_twice": 0, "cut": 0,
                              "modules_unclaimed": 0})
-    ctx = {"trace": {"busy_s": 1.0}, "spans": [], "counters": WINDOW}
-    for name in ("dispatch_overhead_ms.saturated",
-                 "device_idle_window_pct.saturated"):
-        assert dispatch_join.read(ctx, **FILES[name]["args"]) is None
+    assert kernel_roofline_slice.read(_slice_ctx(monkeypatch), **ARGS) \
+        is None
 
 
 @pytest.mark.parametrize("ctx", [
@@ -122,27 +162,31 @@ def test_idle_window_without_the_families_or_with_nothing_joined(joined,
     {"spans": [], "counters": WINDOW},
 ])
 def test_dispatch_join_without_a_trace(ctx):
-    assert dispatch_join.read(ctx, value="overhead_ms") is None
-    assert dispatch_join.read(ctx, value="idle_window_pct") is None
+    assert dispatch_join._joined(dict(ctx)) is None
+    assert kernel_roofline_slice.read(dict(ctx, peaks=PEAKS), **ARGS) is None
 
 
 def test_dispatch_join_without_a_profile_or_without_the_join(monkeypatch):
     monkeypatch.setattr(kernel_roofline, "_newest_profile", lambda: None)
     ctx = {"trace": {"busy_s": 1.0}, "spans": [], "counters": WINDOW}
-    assert dispatch_join.read(ctx, value="overhead_ms") is None
+    assert dispatch_join._joined(ctx) is None
     # the parent commit's trace package has no join
     import paddle_tpu.trace as program_trace
 
     monkeypatch.setattr(kernel_roofline, "_newest_profile", lambda: "x.pb")
     monkeypatch.delattr(program_trace, "join_dispatches")
     ctx = {"trace": {"busy_s": 1.0}, "spans": [], "counters": WINDOW}
-    assert dispatch_join.read(ctx, value="overhead_ms") is None
+    assert dispatch_join._joined(ctx) is None
 
 
-def test_dispatch_join_refuses_an_unknown_value(joined):
-    ctx = {"trace": {"busy_s": 1.0}, "spans": [], "counters": WINDOW}
-    with pytest.raises(ValueError, match="unknown value"):
-        dispatch_join.read(ctx, value="median_ms")
+def test_a_dispatch_takes_the_last_launch_before_it(joined, monkeypatch):
+    """A joined dispatch's work is what the settle span of the last launch
+    the dispatch thread began before it noted: a span whose launch comes
+    after every joined dispatch moves nothing."""
+    ctx = _slice_ctx(monkeypatch)
+    ctx["spans"].append({"name": "serving.settle", "t0": 11.0, "t1": 11.1,
+                         "attrs": {"launch_t0": 10.9, "work": 10.0}})
+    assert kernel_roofline_slice.read(ctx, **ARGS) == pytest.approx(50.0)
 
 
 def test_through_the_programs_join_on_its_recorded_fixture(monkeypatch):
@@ -158,17 +202,21 @@ def test_through_the_programs_join_on_its_recorded_fixture(monkeypatch):
     monkeypatch.setattr(program_join, "load_profile", lambda path: profile)
     ctx = {"trace": {"busy_s": 1.0}, "spans": rec["spans"],
            "counters": WINDOW}
-    # dispatch 11: 31.2 ms on the wall, 27.5 on the device; 12: 22 and 18
-    assert dispatch_join.read(ctx, value="overhead_ms") == pytest.approx(
-        (3.7 + 4.0) / 2)
-    assert dispatch_join.read(ctx, value="idle_window_pct") \
-        == pytest.approx(100 * (0.8 + 100 * 0.0037 + 50 * 0.004) / 10.0)
+    rows = dispatch_join._joined(ctx)
+    # dispatch 11: 27.5 ms on the device; 12: 18
+    assert [round(d["device_s"], 4) for d in rows] == [0.0275, 0.018]
+    assert all(d["module_start_ns"] < d["module_end_ns"]
+               and d["path"] in ("run", "chained") for d in rows)
+    assert dispatch_join._joined(ctx) is rows        # kept for the run
 
 
-def test_held_assignments_reads_the_decode_executions():
+@pytest.mark.parametrize("name", ["held_assignments_per_step.moe",
+                                  "held_assignments_per_step.hybrid",
+                                  "held_assignments_per_step.latent"])
+def test_held_assignments_reads_the_decode_executions(name):
     from readers import histogram_mean
 
-    args = FILES["held_assignments_per_step.moe"]["args"]
+    args = FILES[name]["args"]
     window = {"moe_held_assignments_per_step{phase=decode}_sum": 3840.0,
               "moe_held_assignments_per_step{phase=decode}_count": 64.0,
               "moe_held_assignments_per_step{phase=prefill}_sum": 9000.0,
