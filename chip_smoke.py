@@ -455,6 +455,32 @@ def leg_kernels() -> dict:
     check(e <= 2e-2 and not bool(jnp.any(u_k[4])),
           "mla_decode_attention agrees with its reference (<= 2e-2, bf16 "
           "probabilities against f32), and a slot that sees no key gives 0")
+    # -- hyper-connections (kernels/hyper_connection.py): the read and the
+    #    write of four f32 streams of 3,584 over a decode step's 256 rows,
+    #    each one pass over a tile of 128 rows, against the equations
+    from paddle_tpu.kernels.hyper_connection import (
+        hc_read, hc_read_reference, hc_write, hc_write_reference)
+
+    nh_, Ch, Rh = 4, 3584, 256
+    f32 = lambda a: jnp.asarray(a, jnp.float32)
+    bias_h = rng.uniform(-1, 1, nh_ * (nh_ + 2))
+    bias_h[2 * nh_:] += 4 * np.eye(nh_).ravel()
+    xh, yh = f32(rng.randn(Rh, nh_ * Ch)), f32(rng.randn(Rh, Ch))
+    ph = f32(rng.randn(nh_ * (nh_ + 2), nh_ * Ch) * 0.02)
+    ah, bh = f32(rng.uniform(0.5, 1.5, 3)), f32(bias_h)
+    u_k, c_k, _ = jax.jit(lambda *a: hc_read(*a, n=nh_))(xh, ph, ah, bh)
+    u_r, c_r, _ = jax.jit(lambda *a: hc_read_reference(*a, n=nh_))(
+        xh, ph, ah, bh)
+    post, res = c_r[:, nh_:2 * nh_], c_r[:, 2 * nh_:]
+    o_k = jax.jit(lambda *a: hc_write(*a, n=nh_))(xh, yh, post, res)
+    o_r = jax.jit(lambda *a: hc_write_reference(*a, n=nh_))(xh, yh, post,
+                                                            res)
+    e = max(maxerr(u_k, u_r), maxerr(c_k[:, :c_r.shape[1]], c_r),
+            maxerr(o_k, o_r))
+    say(leg, f"hc_read / hc_write {Rh}x{nh_}x{Ch} f32: max|err| {e:.2e}")
+    check(e <= 1e-4, "the hyper-connection kernels agree with the "
+                     "equations (<= 1e-4: true f32 products in another "
+                     "order)")
     hbm(leg)
     return {}
 

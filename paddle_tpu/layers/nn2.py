@@ -39,6 +39,7 @@ __all__ = [
     "sequence_gather",
     "rotary_embedding", "moe_experts", "slot_assign", "gated_delta_rule",
     "mamba2_scan", "rms_norm", "latent_attention",
+    "hyper_connection_read", "hyper_connection_write",
     "sample_token", "spec_accept",
     "block_seed", "block_positions", "block_reveal",
 ]
@@ -971,11 +972,15 @@ def fused_decode_attention(q, k_new, v_new, cache_k, cache_v, positions,
 
 
 def rotary_embedding(x, positions, theta=10000.0, rotary_dim=0,
-                     pairing="interleaved", name=None):
+                     pairing="interleaved", yarn=None, name=None):
     """Rotary position embedding (ops/moe.py): ``x`` [B, heads, S, D],
     ``positions`` [B, S] int; same shape and type out. The first
     ``rotary_dim`` dims of a head turn (0: all of them), as ``interleaved``
-    pairs ``(2i, 2i+1)`` or rotate-``half`` pairs ``(i, i + rotary_dim/2)``."""
+    pairs ``(2i, 2i+1)`` or rotate-``half`` pairs ``(i, i + rotary_dim/2)``.
+    ``yarn``: a model's ``rope_scaling`` of type ``yarn`` (``factor``,
+    ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
+    ``mscale``, ``mscale_all_dim``) makes the frequencies YaRN's at every
+    position; None leaves the plain ones."""
     helper = LayerHelper("rotary_embedding", name=name)
     out = helper.create_variable_for_type_inference(x.dtype)
     attrs = {"theta": float(theta)}
@@ -983,6 +988,15 @@ def rotary_embedding(x, positions, theta=10000.0, rotary_dim=0,
         attrs["rotary_dim"] = int(rotary_dim)
     if pairing != "interleaved":
         attrs["pairing"] = str(pairing)
+    if yarn:
+        attrs.update(
+            yarn_factor=float(yarn["factor"]),
+            yarn_original_max_position=int(
+                yarn["original_max_position_embeddings"]),
+            yarn_beta_fast=float(yarn.get("beta_fast", 32.0)),
+            yarn_beta_slow=float(yarn.get("beta_slow", 1.0)),
+            yarn_mscale=float(yarn.get("mscale", 1.0)),
+            yarn_mscale_all_dim=float(yarn.get("mscale_all_dim", 0.0)))
     helper.append_op("rotary_embedding",
                      inputs={"X": x, "Positions": positions},
                      outputs={"Out": out}, attrs=attrs)
@@ -1067,7 +1081,7 @@ def mamba2_scan(x, conv_w, conv_b, dt, a_log, dt_bias, d, state, conv_state,
 
 def latent_attention(q, c, k_rope, kv_b_w, cache, positions, nope_dim,
                      mode="decode", page_size=128, slot_mask=None,
-                     slots=None, name=None):
+                     slots=None, scale=None, name=None):
     """Multi-head latent attention over a latent cache
     (ops/latent_attention.py). ``q`` [B, heads, S, dn + dr] (``nope_dim`` =
     dn; the rotary part turned), ``c`` [B, S, dc] and ``k_rope`` [B, S, dr]
@@ -1078,8 +1092,9 @@ def latent_attention(q, c, k_rope, kv_b_w, cache, positions, nope_dim,
     ``slot_mask`` > 0), keys and values expanded, the flash kernel.
     ``mode="decode"``: one row a slot at ``positions`` under the gate
     ``slot_mask``, the up-projection absorbed, attention over the latent
-    rows. Returns ``(out [B, heads, S, dv], stats [1] int32: cache rows
-    the attention read)``."""
+    rows. ``scale``: the softmax scale (None: ``(dn + dr)^-1/2``).
+    Returns ``(out [B, heads, S, dv], stats [1] int32: cache rows the
+    attention read)``."""
     helper = LayerHelper("latent_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
     stats = helper.create_variable_for_type_inference("int32",
@@ -1090,12 +1105,54 @@ def latent_attention(q, c, k_rope, kv_b_w, cache, positions, nope_dim,
         inputs["SlotMask"] = slot_mask
     if slots is not None:
         inputs["Slots"] = slots
+    attrs = {"mode": str(mode), "nope_dim": int(nope_dim),
+             "page_size": int(page_size)}
+    if scale:
+        attrs["scale"] = float(scale)
     helper.append_op(
         "latent_attention", inputs=inputs,
         outputs={"Out": out, "CacheOut": cache, "Stats": stats},
-        attrs={"mode": str(mode), "nope_dim": int(nope_dim),
-               "page_size": int(page_size)})
+        attrs=attrs)
     return out, stats
+
+
+def hyper_connection_read(x, proj, alpha, bias, sinkhorn_iters=20, eps=1e-6,
+                          norm_eps=1e-6, clamp_min=-30.0, clamp_max=30.0,
+                          name=None):
+    """What a sublayer reads of an ``n``-stream residual path, and the
+    coefficients its write needs (ops/hyper_connection.py: manifold-
+    constrained hyper-connections). ``x`` [B, S, n, C] f32; ``proj``
+    [n (n + 2), n C] (rows ``[P_pre^T | P_post^T | P_res^T]``), ``alpha``
+    [3] and ``bias`` [n (n + 2)] the sublayer's parameters, f32. Returns
+    ``(u [B, S, C], h_post [B, S, n], h_res [B, S, n, n], stats [2] f32:
+    token rows mixed, the largest |row or column sum - 1| of an h_res)``:
+    ``u = sigmoid(..) X``, ``h_post = 2 sigmoid(..)``, ``h_res`` the
+    ``sinkhorn_iters`` Sinkhorn rounds (denominators ``sum + eps``) of
+    ``exp(clamp(..))``, all from the RMS-normed flattened ``x``
+    (``norm_eps``), per token."""
+    helper = LayerHelper("hyper_connection_read", name=name)
+    mk = lambda: helper.create_variable_for_type_inference("float32")
+    u, post, res = mk(), mk(), mk()
+    stats = helper.create_variable_for_type_inference("float32",
+                                                      stop_gradient=True)
+    helper.append_op(
+        "hyper_connection_read",
+        inputs={"X": x, "Proj": proj, "Alpha": alpha, "Bias": bias},
+        outputs={"Out": u, "HPost": post, "HRes": res, "Stats": stats},
+        attrs={"sinkhorn_iters": int(sinkhorn_iters), "eps": float(eps),
+               "norm_eps": float(norm_eps), "clamp_min": float(clamp_min),
+               "clamp_max": float(clamp_max)})
+    return u, post, res, stats
+
+
+def hyper_connection_write(x, y, h_post, h_res, name=None):
+    """What a sublayer writes back into an ``n``-stream residual path
+    (ops/hyper_connection.py): ``h_res X + h_post^T y`` [B, S, n, C] from
+    the stream ``x`` [B, S, n, C], the sublayer's output ``y`` [B, S, C]
+    and the coefficients ``hyper_connection_read`` gave, all f32."""
+    return _one("hyper_connection_write",
+                {"X": x, "Y": y, "HPost": h_post, "HRes": h_res},
+                dtype="float32", name=name)
 
 
 def moe_experts(x, router_w, gate_w, up_w, down_w, num_experts, top_k,

@@ -3,12 +3,12 @@
 Training and inference programs that mirror the reference's book/ test
 models and benchmark configs (reference: python/paddle/fluid/tests/book/,
 BASELINE.md): MNIST MLP, ResNet-50, BERT, Transformer NMT, DeepFM CTR. And
-six decoders for ``serving.GenerativeEngine``: ``gpt``, ``cohere_moe``,
+eight decoders for ``serving.GenerativeEngine``: ``gpt``, ``cohere_moe``,
 ``qwen3_next``, ``glm4_moe_lite``, ``sdar_moe``, ``granite_moe_hybrid``,
-each its configuration, its block, its state table and its cache handles
-over ``decoder`` (what they share, once; none imports another). The names
-below are the package's exports; the later decoders are imported from
-their modules.
+``mimo_v2_flash``, ``xing4``, each its configuration, its block, its state
+table and its cache handles over ``decoder`` (what they share, once; none
+imports another). The names below are the package's exports; the later
+decoders are imported from their modules.
 """
 from .mlp import build_mnist_mlp  # noqa: F401
 from .resnet import build_resnet  # noqa: F401

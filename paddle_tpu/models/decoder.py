@@ -1,7 +1,8 @@
 """What a decoder builder for ``serving.GenerativeEngine`` is made of, once.
 
 A builder (``models/gpt.py``, ``cohere_moe.py``, ``qwen3_next.py``,
-``glm4_moe_lite.py``, ``sdar_moe.py``, ``granite_moe_hybrid.py``) writes its
+``glm4_moe_lite.py``, ``sdar_moe.py``, ``granite_moe_hybrid.py``,
+``mimo_v2_flash.py``, ``xing4.py``) writes its
 configuration, its block, its state table and the cache handles of its two
 phases; the rest is here:
 
@@ -10,6 +11,10 @@ phases; the rest is here:
 * the state table's maker, the prefill's feeds and the two commits;
 * the handles of a layer with a K/V cache pair (:func:`bulk_attend`,
   :func:`step_attend`);
+* a latent-attention layer, its state and its two handles
+  (:func:`latent_attention`, :func:`latent_state`,
+  :func:`latent_prefill_handle`, :func:`latent_decode_handle`), which
+  ``glm4_moe_lite.py`` and ``xing4.py`` share;
 * the two phases as functions of what differs (:class:`Parts`,
   :func:`prefill_phase`, :func:`decode_phase`) and :func:`build_generative`
   (:func:`build_from_parts` over those two phases): validation, the shared
@@ -43,6 +48,8 @@ __all__ = ["PREFILL_FEEDS", "Mix", "Parts", "attr", "build_from_parts",
            "bulk_attend", "check_pages", "commit_decode", "commit_prefill",
            "counted", "decode_net", "decode_phase", "embed",
            "expert_weights", "f32_param", "ffn", "gated_mlp", "generative",
+           "latent_attention", "latent_decode_handle",
+           "latent_prefill_handle", "latent_softmax_scale", "latent_state",
            "logits", "merge_state", "norm", "prefill_feeds", "prefill_phase",
            "prefill_rows", "proj", "proj_out", "split_heads", "state_table",
            "step_attend", "untied_head"]
@@ -347,6 +354,98 @@ def step_attend(caches, at, mask, scale: float, page_size: int):
             scale=scale, page_size=page_size, slot_mask=mask, window=window,
             sink=sink)
 
+    return attend
+
+
+# -- a latent-attention layer ---------------------------------------------------
+def latent_softmax_scale(cfg):
+    """The softmax scale of ``cfg``'s latent attention where it is not the
+    op's default ``(dn + dr)^-1/2``: under YaRN positions
+    (``cfg.rope_scaling``) that times ``m(mscale_all_dim)^2``. None: the
+    default."""
+    yarn = getattr(cfg, "rope_scaling", None)
+    if not yarn or not yarn.get("mscale_all_dim"):
+        return None
+    from ..ops.moe import yarn_softmax_scale
+
+    return yarn_softmax_scale(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim,
+                              float(yarn["factor"]),
+                              float(yarn["mscale_all_dim"]))
+
+
+def latent_attention(hb, p: str, S: int, cfg, positions, attend, i: int):
+    """Multi-head latent attention of layer ``i`` on the normed rows ``hb``
+    [B, S, H] (``cfg.dtype``), parameters under ``p``: the query through
+    its low-rank bottleneck and norm, a head's ``[q_nope | q_rope]``;
+    ``[c_raw | k_r] = hb W_kva``, ``c = N(c_raw)``; rotary positions
+    (``cfg.rope_theta``; YaRN's where ``cfg.rope_scaling`` says so) on
+    ``q_rope`` and on the one rotary key; ``attend(i, q, c, k_rope,
+    w_kvb)`` appends the rows to the layer's latent cache and attends;
+    ``o_proj`` over the joined heads. Returns the f32 rows for the
+    residual path and the attention op's statistics. What it reads of
+    ``cfg``: ``num_heads``, ``q_lora_rank``, ``kv_lora_rank``,
+    ``qk_nope_head_dim``, ``qk_rope_head_dim``, ``v_head_dim``."""
+    nh, dn, dr = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    dc = cfg.kv_lora_rank
+    plain = functools.partial(norm, cfg=cfg, zero_centered=False)
+    cq = plain(proj_out(hb, cfg.q_lora_rank, f"{p}_q_a", cfg),
+               f"{p}_q_a_norm", dim=cfg.q_lora_rank)
+    q = layers.reshape(
+        proj(layers.cast(cq, cfg.dtype), nh * (dn + dr), f"{p}_q_b", cfg),
+        [0, S, nh, dn + dr])
+    q_nope, q_rope = layers.split(layers.transpose(q, [0, 2, 1, 3]),
+                                  [dn, dr], dim=3)
+    rot = lambda t: layers.rotary_embedding(
+        t, positions, theta=cfg.rope_theta,
+        yarn=getattr(cfg, "rope_scaling", None))
+    q = layers.concat([q_nope, rot(q_rope)], axis=3)      # [B, nh, S, dn+dr]
+    c_raw, k_r = layers.split(proj_out(hb, dc + dr, f"{p}_kv_a", cfg),
+                              [dc, dr], dim=2)
+    c = layers.cast(plain(c_raw, f"{p}_kv_a_norm", dim=dc), cfg.dtype)
+    k_rope = layers.squeeze(
+        rot(layers.unsqueeze(layers.cast(k_r, cfg.dtype), [1])), [1])
+    w_kvb = LayerHelper("decoder").create_parameter(
+        attr(f"{p}_kv_b_w", cfg), [dc, nh * (dn + cfg.v_head_dim)],
+        cfg.dtype)
+    ctx, stats = attend(i, q, c, k_rope, w_kvb)          # [B, nh, S, dv]
+    ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
+                         [0, S, nh * cfg.v_head_dim])
+    return proj_out(ctx, cfg.hidden_size, f"{p}_out", cfg), stats
+
+
+def latent_state(block, cfg, prefix: str, batch_slots: int, max_seq: int):
+    """A builder's ``Parts.state`` for latent-attention layers alone:
+    current token, position and decode gate per slot, and each layer's
+    latent cache ``[slots, 1, max_seq, W]`` in ``cfg.dtype``, kind
+    ``latent``: a row is ``[c (kv_lora_rank) | k_rope (qk_rope_head_dim) |
+    0]``, ``W`` whole lane tiles (``kernels.latent_row_width``)."""
+    from ..kernels.latent_attention import latent_row_width
+
+    mk, sv, tok, pos, active = state_table(block, prefix, batch_slots)
+    width = latent_row_width(cfg.kv_lora_rank, cfg.qk_rope_head_dim)
+    caches = [(mk(f"{prefix}_lat_{i}", (batch_slots, 1, max_seq, width),
+                  cfg.dtype),) for i in range(cfg.num_layers)]
+    return (tok, pos, active, caches, sv,
+            {c.name: "latent" for c, in caches})
+
+
+def latent_prefill_handle(cfg, caches, pmask, plen, smask, slots, page_size):
+    """A layer writes the bucket's latent rows at row 0 of the slot's cache
+    and attends over keys and values expanded from them."""
+    def attend(i, q, c, k_rope, w_kvb):
+        return layers.latent_attention(
+            q, c, k_rope, w_kvb, *caches[i], plen, cfg.qk_nope_head_dim,
+            mode="prefill", page_size=page_size, slot_mask=smask,
+            slots=slots, scale=latent_softmax_scale(cfg))
+    return attend
+
+
+def latent_decode_handle(cfg, caches, pos, active, page_size):
+    def attend(i, q, c, k_rope, w_kvb):
+        return layers.latent_attention(
+            q, c, k_rope, w_kvb, *caches[i], pos, cfg.qk_nope_head_dim,
+            page_size=page_size, slot_mask=active,
+            scale=latent_softmax_scale(cfg))
     return attend
 
 

@@ -30,11 +30,12 @@ What is held here is ONE chip's share: ``experts_held`` routed experts
 from ``expert_offset``, attention, the dense layers and the shared expert
 whole, a slice of the vocabulary; bf16 storage, bf16 matmul operands with
 f32 accumulation; norms, router, softmax and the residual stream f32. The
-block is written once (:func:`_block`); the two phases are
-``models/decoder.py``'s. The state table holds one kind of state,
-``latent``: per layer ONE array of ``max_seq`` rows ``[c | k_rope | 0]`` of
-one "head", which follow the sequence as a ``full`` layer's do. The
-multi-token prediction module is not built.
+block is written once (:func:`_block`); the two phases, the attention
+(``decoder.latent_attention``, shared with ``models/xing4.py``), its state
+and its two cache handles are ``models/decoder.py``'s. The state table
+holds one kind of state, ``latent``: per layer ONE array of ``max_seq``
+rows ``[c | k_rope | 0]`` of one "head", which follow the sequence as a
+``full`` layer's do. The multi-token prediction module is not built.
 """
 from __future__ import annotations
 
@@ -42,11 +43,10 @@ import dataclasses
 from typing import Optional
 
 from .. import layers
-from ..layer_helper import LayerHelper
 from ..ops.latent_attention import count_latent_stats
 from ..ops.moe import expert_counter
 from . import decoder
-from .decoder import attr, ffn, gated_mlp, proj, proj_out
+from .decoder import ffn, gated_mlp
 
 __all__ = ["Glm4MoeLiteConfig", "build_glm4_moe_lite_generative"]
 
@@ -104,34 +104,6 @@ def _norm(x, name: str, cfg: Glm4MoeLiteConfig, dim: int):
     return decoder.norm(x, name, cfg, dim, zero_centered=False)
 
 
-def _attention(hb, p: str, S: int, cfg: Glm4MoeLiteConfig, positions,
-               attend, i: int):
-    nh, dn, dr = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-    dc = cfg.kv_lora_rank
-    cq = _norm(proj_out(hb, cfg.q_lora_rank, f"{p}_q_a", cfg),
-               f"{p}_q_a_norm", cfg, cfg.q_lora_rank)
-    q = layers.reshape(
-        proj(layers.cast(cq, cfg.dtype), nh * (dn + dr), f"{p}_q_b", cfg),
-        [0, S, nh, dn + dr])
-    q_nope, q_rope = layers.split(layers.transpose(q, [0, 2, 1, 3]),
-                                  [dn, dr], dim=3)
-    rot = lambda t: layers.rotary_embedding(t, positions,
-                                            theta=cfg.rope_theta)
-    q = layers.concat([q_nope, rot(q_rope)], axis=3)      # [B, nh, S, dn+dr]
-    c_raw, k_r = layers.split(proj_out(hb, dc + dr, f"{p}_kv_a", cfg),
-                              [dc, dr], dim=2)
-    c = layers.cast(_norm(c_raw, f"{p}_kv_a_norm", cfg, dc), cfg.dtype)
-    k_rope = layers.squeeze(
-        rot(layers.unsqueeze(layers.cast(k_r, cfg.dtype), [1])), [1])
-    w_kvb = LayerHelper("glm4_moe_lite").create_parameter(
-        attr(f"{p}_kv_b_w", cfg), [dc, nh * (dn + cfg.v_head_dim)],
-        cfg.dtype)
-    ctx, stats = attend(i, q, c, k_rope, w_kvb)          # [B, nh, S, dv]
-    ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
-                         [0, S, nh * cfg.v_head_dim])
-    return proj_out(ctx, cfg.hidden_size, f"{p}_out", cfg), stats
-
-
 def _block(x, i: int, cfg: Glm4MoeLiteConfig, positions, real, attend):
     """One layer on the residual stream ``x`` [B, S, H] (f32). ``real``
     [B, S] is 1 on the tokens of the sequences this dispatch serves.
@@ -142,7 +114,8 @@ def _block(x, i: int, cfg: Glm4MoeLiteConfig, positions, real, attend):
     p = f"{_P}_l{i}"
     S, H = x.shape[1], cfg.hidden_size
     hb = layers.cast(_norm(x, f"{p}_ln_in", cfg, H), cfg.dtype)
-    att, walked = _attention(hb, p, S, cfg, positions, attend, i)
+    att, walked = decoder.latent_attention(hb, p, S, cfg, positions, attend,
+                                           i)
     x = layers.elementwise_add(x, att)
     h = _norm(x, f"{p}_ln_post", cfg, H)
     hb = layers.cast(h, cfg.dtype)
@@ -182,37 +155,7 @@ def _head(h2d, cfg: Glm4MoeLiteConfig):
 
 def _state_vars(block, cfg: Glm4MoeLiteConfig, batch_slots: int,
                 max_seq: int):
-    """Current token, position and decode gate per slot, and each layer's
-    latent cache ``[slots, 1, max_seq, W]`` in ``cfg.dtype``, kind
-    ``latent``: a row is ``[c (kv_lora_rank) | k_rope (qk_rope_head_dim) |
-    0]``, ``W`` whole lane tiles (``kernels.latent_row_width``)."""
-    from ..kernels.latent_attention import latent_row_width
-
-    mk, sv, tok, pos, active = decoder.state_table(block, _P, batch_slots)
-    width = latent_row_width(cfg.kv_lora_rank, cfg.qk_rope_head_dim)
-    caches = [(mk(f"{_P}_lat_{i}", (batch_slots, 1, max_seq, width),
-                  cfg.dtype),) for i in range(cfg.num_layers)]
-    return (tok, pos, active, caches, sv,
-            {c.name: "latent" for c, in caches})
-
-
-def _prefill_handle(cfg, caches, pmask, plen, smask, slots, page_size):
-    """A layer writes the bucket's latent rows at row 0 of the slot's cache
-    and attends over keys and values expanded from them."""
-    def attend(i, q, c, k_rope, w_kvb):
-        return layers.latent_attention(
-            q, c, k_rope, w_kvb, *caches[i], plen, cfg.qk_nope_head_dim,
-            mode="prefill", page_size=page_size, slot_mask=smask,
-            slots=slots)
-    return attend
-
-
-def _decode_handle(cfg, caches, pos, active, page_size):
-    def attend(i, q, c, k_rope, w_kvb):
-        return layers.latent_attention(
-            q, c, k_rope, w_kvb, *caches[i], pos, cfg.qk_nope_head_dim,
-            page_size=page_size, slot_mask=active)
-    return attend
+    return decoder.latent_state(block, cfg, _P, batch_slots, max_seq)
 
 
 def build_glm4_moe_lite_generative(cfg: Glm4MoeLiteConfig = None,
@@ -227,7 +170,8 @@ def build_glm4_moe_lite_generative(cfg: Glm4MoeLiteConfig = None,
     slot)."""
     cfg = cfg or Glm4MoeLiteConfig.tiny()
     parts = decoder.Parts(cfg, _state_vars, _embed, _stack_layers, _head,
-                          _prefill_handle, _decode_handle)
+                          decoder.latent_prefill_handle,
+                          decoder.latent_decode_handle)
     return decoder.build_from_parts(parts, batch_slots, max_seq, page_size,
                                     prompt_buckets, prefill_rows, strategy,
                                     temperature, top_k)

@@ -14,6 +14,7 @@ from . import moe  # noqa: F401
 from . import gdn  # noqa: F401
 from . import ssd  # noqa: F401
 from . import latent_attention  # noqa: F401
+from . import hyper_connection  # noqa: F401
 from . import block_diffusion  # noqa: F401
 from . import detection  # noqa: F401
 from . import quant_ops  # noqa: F401

@@ -59,13 +59,16 @@ def count_latent_stats(phase: str, stats, sums) -> None:
             IOSpec("SlotMask", optional=True, no_grad=True),
             IOSpec("Slots", optional=True, no_grad=True)],
     outputs=["Out", "CacheOut", "Stats"],
-    attrs={"mode": "decode", "nope_dim": 0, "page_size": 128},
+    attrs={"mode": "decode", "nope_dim": 0, "page_size": 128, "scale": 0.0},
     grad=None)
 def _latent_attention(ctx, ins, attrs):
     """``Q`` [B, heads, S, dn + dr]: a head's ``[q_nope | q_rope]``, the
     rotary part turned; ``C`` [B, S, dc] and ``KRope`` [B, S, dr] (turned):
-    the rows this call appends; ``nope_dim`` = dn. The scale is
-    ``(dn + dr)^-1/2``. ``Out`` [B, heads, S, dv] in ``Q``'s type.
+    the rows this call appends; ``nope_dim`` = dn. The softmax scale is
+    the attribute ``scale``, or ``(dn + dr)^-1/2`` where it is 0 (a model
+    whose positions are YaRN's multiplies that by ``m^2``,
+    ``ops.moe.yarn_softmax_scale``). ``Out`` [B, heads, S, dv] in ``Q``'s
+    type.
 
     ``mode="prefill"``: ``B`` whole prompts of up to ``S`` rows; sequence
     ``i`` writes its rows at row 0 of slot ``Slots[i]`` (default ``i``)
@@ -105,7 +108,7 @@ def _latent_attention(ctx, ins, attrs):
             f"{dn}), C {c.shape}, KRope {kr.shape}, KVBW {w.shape}, cache "
             f"{cache.shape}")
     page = int(attrs.get("page_size") or 128)
-    scale = float(dq) ** -0.5
+    scale = float(attrs.get("scale") or 0.0) or float(dq) ** -0.5
     platform = lowering_platform(ctx)
     prec = "highest" if w.dtype == jnp.float32 else "default"
     wh = w.reshape(dc, nh, dn + dv)
@@ -116,9 +119,7 @@ def _latent_attention(ctx, ins, attrs):
     rows = lanes([c.astype(cache.dtype), kr.astype(cache.dtype)])
 
     if not decode:
-        # the flash kernel takes keys and values of one width
-        route = ("primitive" if dv != dq
-                 else _route_prefill(S, S, 0.0, platform))
+        route = _route_prefill(S, S, 0.0, platform)
         note_kernel_route(ctx, "latent_attention", route)
         cache2 = paged_kv_append(cache, rows[:, None],
                                  jnp.zeros((B,), jnp.int32), smask,

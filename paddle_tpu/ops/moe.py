@@ -317,11 +317,67 @@ def _moe_experts(ctx, ins, attrs):
     return {"Out": [out.reshape(lead + (H,))], "Stats": [stats]}
 
 
+_YARN = ("factor", "original_max_position", "beta_fast", "beta_slow",
+         "mscale", "mscale_all_dim")
+
+
+def yarn_attrs(attrs) -> dict:
+    """The YaRN attribute set of a ``rotary_embedding`` op (its
+    ``yarn_*`` attributes without the prefix), or {} where the op has none
+    (``yarn_factor`` 0)."""
+    if not float(attrs.get("yarn_factor", 0.0) or 0.0):
+        return {}
+    return {k: float(attrs[f"yarn_{k}"]) for k in _YARN}
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """``m(s) = 0.1 s ln(factor) + 1`` (1 at a factor of 1 or below)."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * np.log(factor) + 1.0
+
+
+def yarn_cos_scale(factor, mscale, mscale_all_dim, **_) -> float:
+    """What YaRN multiplies cos and sin by: ``m(mscale) /
+    m(mscale_all_dim)``."""
+    return float(yarn_mscale(factor, mscale)
+                 / yarn_mscale(factor, mscale_all_dim))
+
+
+def yarn_softmax_scale(head_dim: int, factor: float,
+                       mscale_all_dim: float) -> float:
+    """The attention's softmax scale under YaRN: ``head_dim^-1/2 x
+    m(mscale_all_dim)^2`` (the plain one where ``mscale_all_dim`` is 0)."""
+    m = yarn_mscale(factor, mscale_all_dim) if mscale_all_dim else 1.0
+    return float(head_dim ** -0.5 * m * m)
+
+
+def yarn_inv_freq(theta: float, rot: int, factor, original_max_position,
+                  beta_fast, beta_slow, **_) -> np.ndarray:
+    """YaRN's ``rot / 2`` frequencies (f32): pair ``j`` turns by ``pos x
+    ((1 - r_j) f_j / factor + r_j f_j)`` with ``f_j = theta^(-2j/rot)``
+    and ``r_j = 1 - clip((j - lo) / (hi - lo), 0, 1)``, ``lo`` / ``hi`` the
+    pairs whose wavelength turns ``beta_fast`` / ``beta_slow`` times in
+    ``original_max_position`` positions (floor / ceil, clipped to 0 ..
+    ``rot - 1`` as the latent-attention family's code clips them)."""
+    def pair(turns):
+        return rot * np.log(original_max_position / (turns * 2 * np.pi)) \
+            / (2 * np.log(theta))
+
+    lo = max(int(np.floor(pair(beta_fast))), 0)
+    hi = min(int(np.ceil(pair(beta_slow))), rot - 1)
+    j = np.arange(rot // 2, dtype=np.float64)
+    keep = 1.0 - np.clip((j - lo) / ((hi - lo) or 0.001), 0.0, 1.0)
+    plain = theta ** (-2.0 * j / rot)
+    return ((1.0 - keep) * plain / factor + keep * plain).astype(np.float32)
+
+
 @register_op(
     "rotary_embedding",
     inputs=[IOSpec("X"), IOSpec("Positions", no_grad=True)],
     outputs=["Out"],
-    attrs={"theta": 10000.0, "rotary_dim": 0, "pairing": "interleaved"},
+    attrs={"theta": 10000.0, "rotary_dim": 0, "pairing": "interleaved",
+           "yarn_factor": 0.0, "yarn_original_max_position": 0,
+           "yarn_beta_fast": 32.0, "yarn_beta_slow": 1.0,
+           "yarn_mscale": 1.0, "yarn_mscale_all_dim": 0.0},
     grad=None)
 def _rotary_embedding(ctx, ins, attrs):
     """Rotary positions: ``X`` [B, heads, S, D], ``Positions`` [B, S] int.
@@ -331,7 +387,13 @@ def _rotary_embedding(ctx, ins, attrs):
     layout): pair ``i`` = dims ``(i, i + rotary_dim/2)``. Pair ``i`` turns
     by ``pos * theta^(-2i/rotary_dim)``. The pair swap is a product with a
     constant signed permutation (exact in any float type) and not a lane
-    shuffle; angles, sines and the blend are f32."""
+    shuffle; angles, sines and the blend are f32.
+
+    ``yarn_factor`` > 0 (with ``yarn_original_max_position``, the two
+    betas and the two ``mscale``) makes the frequencies YaRN's, at every
+    position (:func:`yarn_inv_freq`), and scales cos and sin by
+    :func:`yarn_cos_scale`; 0 (the default) leaves the plain frequencies
+    and the op's output bit for bit what it was."""
     xv, pos = x(ins, "X"), x(ins, "Positions")
     B, _, S, D = xv.shape
     rot = int(attrs.get("rotary_dim", 0)) or D
@@ -339,8 +401,13 @@ def _rotary_embedding(ctx, ins, attrs):
     if rot % 2 or rot > D:
         raise ValueError(f"rotary_embedding: rotary_dim {rot} of {D} dims")
     with jax.named_scope("rotary"):
-        inv = float(attrs["theta"]) ** (
-            -jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
+        yarn = yarn_attrs(attrs)
+        if yarn:
+            inv = jnp.asarray(yarn_inv_freq(float(attrs["theta"]), rot,
+                                            **yarn), jnp.float32)
+        else:
+            inv = float(attrs["theta"]) ** (
+                -jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
         ang = pos.reshape(B, 1, S, 1).astype(jnp.float32) * inv
         if half:
             spread = lambda t: jnp.concatenate([t, t], axis=-1)
@@ -351,6 +418,9 @@ def _rotary_embedding(ctx, ins, attrs):
             first = jnp.arange(0, rot, 2)
             second = first + 1
         cos, sin = spread(jnp.cos(ang)), spread(jnp.sin(ang))
+        m = yarn_cos_scale(**yarn) if yarn else 1.0
+        if m != 1.0:
+            cos, sin = cos * np.float32(m), sin * np.float32(m)
         if rot < D:
             still = [(0, 0)] * 3 + [(0, D - rot)]
             cos = jnp.pad(cos, still, constant_values=1.0)

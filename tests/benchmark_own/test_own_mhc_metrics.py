@@ -1,0 +1,4 @@
+"""``benchmark/tests/test_mhc_metrics.py`` under the tier-1 gate (see ``_own.py``)."""
+from _own import load
+
+globals().update(load("test_mhc_metrics.py"))

@@ -1,17 +1,4 @@
 """``benchmark/tests/test_ssm_metrics.py`` under the tier-1 gate (see ``_own.py``)."""
-import pytest
 from _own import load
 
 globals().update(load("test_ssm_metrics.py"))
-
-# The loaded file's own test, run as it is written. Its line 72 pins
-# Granite's cell as the LAST of ``decode_tokens_per_s``' workloads; PR 47
-# appended the next cell (a new cell goes at the end of a list, and the PR
-# that adds it may edit no file the benchmark has), so the test fails at
-# that line until a ``benchmark`` PR unpins it there (PERF.md section 7).
-# Strict: the day it passes again this mark fails and has to go.
-test_the_cell_has_its_ssm_metrics_and_only_lists_itself = pytest.mark.xfail(
-    strict=True, raises=AssertionError,
-    reason="benchmark/tests/test_ssm_metrics.py:72 pins its cell as the "
-           "last of decode_tokens_per_s.workloads; PR 47 appended a cell")(
-    test_the_cell_has_its_ssm_metrics_and_only_lists_itself)  # noqa: F821

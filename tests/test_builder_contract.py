@@ -1,5 +1,5 @@
 """What a builder hands ``serving.GenerativeEngine`` (docs/SERVING.md "What
-a builder hands the engine"): the seven builders' dicts satisfy the contract
+a builder hands the engine"): the eight builders' dicts satisfy the contract
 the engine reads, the engine hands what a dispatch counted to the function
 the net lists beside it and knows no more of it, a net outside the contract
 is refused, and no model's module leans on another's."""
@@ -25,6 +25,7 @@ BUILDERS = {
     "granite_moe_hybrid": ("GraniteMoeHybridConfig",
                            "build_granite_moe_hybrid_generative"),
     "mimo_v2_flash": ("MimoV2FlashConfig", "build_mimo_v2_flash_generative"),
+    "xing4": ("Xing4Config", "build_xing4_generative"),
 }
 KINDS = {"full", "window", "latent", "recurrent"}
 
@@ -73,7 +74,8 @@ def test_a_builders_dict_satisfies_what_the_engine_reads(name):
         for var, count in listed:
             assert block.var(var.name) is var and callable(count)
         stats = [phase[k] for k in ("expert_stats", "rule_stats",
-                                    "latent_stats", "fold_stats")
+                                    "latent_stats", "fold_stats",
+                                    "hc_stats")
                  if k in phase]
         assert [v.name for v in stats] == [v.name for v, _ in listed]
         assert not {"expert_layers", "rule_layers", "rule_family"} & set(

@@ -866,3 +866,68 @@ def test_a_call_with_nothing_to_cut_builds_the_kernel_it_always_had(case,
     assert call["grid_mapping"].num_index_operands == 1     # no table
     text = str(call["jaxpr"])
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest, text
+
+
+# -- the four-stream decoder's kernels at its published widths (PR 52) --------
+
+@pytest.mark.parametrize("rows", [256, 1536])
+def test_hyper_connection_kernels_compile_at_the_published_widths(v5e, rows):
+    """A decode step's 256 slots and the largest prefill bucket: four f32
+    streams of 3,584, a tile of 128 token rows a grid step (7.3 MB, two of
+    them in flight beside the projection and the outputs: the kernels ask
+    for 48 MiB of VMEM), the projection a true f32 product with the tokens
+    in the lanes; both keep their names."""
+    from paddle_tpu.kernels.hyper_connection import hc_read, hc_write
+
+    f32 = jnp.float32
+    text = _compiles_with_mosaic(
+        lambda x, p, a, b: hc_read(x, p, a, b, n=4),
+        v5e((rows, 14336), f32), v5e((24, 14336), f32), v5e((3,), f32),
+        v5e((24,), f32))
+    assert re.search(r"%hc_read[.\d]* = ", text)
+    text = _compiles_with_mosaic(
+        lambda x, y, p, r: hc_write(x, y, p, r, n=4),
+        v5e((rows, 14336), f32), v5e((rows, 3584), f32), v5e((rows, 4), f32),
+        v5e((rows, 16), f32))
+    assert re.search(r"%hc_write[.\d]* = ", text)
+    assert not re.search(rf"%copy[.\d]* = f32\[{rows},14336\]", text)
+
+
+def test_latent_prefill_with_yarns_scale_takes_the_flash_forward(v5e):
+    """The latent op's prefill form at Xing4.0's widths: 32 heads of 128 +
+    64 beside values of 128 (the flash forward with keys wider than
+    values, which until PR 52 the op sent down its primitive route), the
+    softmax scale an attribute; the decode form at 32 heads x 576."""
+    import paddle_tpu  # noqa: F401  (registers the ops)
+    from paddle_tpu.core.registry import get_op_def
+    from paddle_tpu.lowering import LowerCtx
+
+    bf = jnp.bfloat16
+    rule = get_op_def("latent_attention").lower
+
+    def fn(mode):
+        def run(q, c, kr, w, cache, pos, smask, slots):
+            ins = {"Q": [q], "C": [c], "KRope": [kr], "KVBW": [w],
+                   "Cache": [cache], "Positions": [pos], "SlotMask": [smask]}
+            if mode == "prefill":
+                ins["Slots"] = [slots]
+            out = rule(LowerCtx(platform="tpu"), ins,
+                       {"mode": mode, "nope_dim": 128, "page_size": 128,
+                        "scale": 0.14468})
+            return out["Out"][0], out["CacheOut"][0]
+        return run
+
+    def lower(mode, B, S):
+        col = lambda dt: v5e((B, 1), dt)
+        return jax.jit(fn(mode), donate_argnums=(4,)).lower(
+            v5e((B, 32, S, 192), bf), v5e((B, S, 512), bf),
+            v5e((B, S, 64), bf), v5e((512, 32 * 256), bf),
+            v5e((256, 1, 2048, 640), bf), col(jnp.int32), col(jnp.float32),
+            col(jnp.int32)).compile().as_text()
+
+    text = lower("prefill", 1, 1024)
+    assert re.search(r"%flash_attention_fwd[.\d]* = ", text)
+    assert not re.search(r"%copy[.\d]* = bf16\[256,1,2048,640\]", text)
+    text = lower("decode", 256, 1)
+    assert re.search(r"%mla_decode_attention[.\d]* = ", text)
+    assert not re.search(r"%copy[.\d]* = bf16\[256,1,2048,640\]", text)
