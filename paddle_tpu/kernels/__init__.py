@@ -7,7 +7,8 @@ from .flash_attention import (classify_shapes, flash_attention,
                               flash_block_visits, flash_forward_grid,
                               supports_shapes, window_block_visits)
 from .decode_attention import (KERNEL_ROWS, decode_attention_reference,
-                               decode_walk_blocks, flash_attention_decode,
+                               decode_grid_steps, decode_walk_blocks,
+                               flash_attention_decode,
                                fold_rows, kv_append, paged_kv_append,
                                paged_kv_append_rows, rows_minor, window_fold)
 from .latent_attention import (mla_decode_attention,
@@ -16,6 +17,7 @@ from .latent_attention import (mla_decode_attention,
 __all__ = ["flash_attention", "flash_attention_with_lse",
            "flash_attention_bwd", "supports_shapes", "classify_shapes",
            "flash_attention_decode", "kv_append", "paged_kv_append", "paged_kv_append_rows", "KERNEL_ROWS", "decode_walk_blocks",
+           "decode_grid_steps",
            "decode_attention_reference", "rows_minor", "fold_rows",
            "window_fold", "window_block_visits", "flash_block_visits",
            "flash_forward_grid",

@@ -18,12 +18,19 @@ is the kernel's own (:func:`kv_tile`): whole pages of as many of a
 sequence's heads as make the step worth its fixed cost, since a sequence's
 heads share its length. The kernel walks a sequence's cache k-block by
 k-block with the same online-softmax recurrence as the prefill kernel, up
-to the sequence's LAST LIVE block: past it the K and V index maps repeat
-that block's index, for which the pipeline issues no DMA, and the body is
-skipped. Inside the last live block key positions ``>= length + row`` are
-masked per sequence and query row (``>= length + q_len - 1`` for every row
-of a whole chunk: the walk is the same, it ends at the last row's block).
-Pages past a sequence's length hold stale/garbage rows by design (they are
+to the sequence's LAST LIVE block, and its grid has a step only where a
+block is fetched (PR 53): the steps are listed in a table built from the
+lengths beside the call (:func:`walk_steps`: the live ``(sequence, group
+of heads, k-block)`` triples, a sequence's blocks in ascending order),
+the table is scalar-prefetched and its count is the grid's bound, a traced
+value. (A grid step costs a third of a microsecond even where it fetches
+nothing and skips its body, so a grid sized by the cache, ``num_k`` steps
+a sequence, paid more for its dead steps than for its live ones wherever
+the caches are mostly empty: four steps in five on GPT-2's saturated mix.)
+Inside the last live block key positions ``>= length + row`` are masked
+per sequence and query row (``>= length + q_len - 1`` for every row of a
+whole chunk: the walk is the same, it ends at the last row's block). Pages
+past a sequence's length hold stale/garbage rows by design (they are
 overwritten when the sequence reaches them): they are never fetched, and
 the length mask keeps the tail of the last live block out of the softmax,
 so cache capacity can be provisioned once and reused across requests at
@@ -70,10 +77,18 @@ Design notes
   one walk, one mask: a block's two axes in the other order, chosen from
   the shape as the call is traced. Heads of 128 and 256 lie as declared
   and are read so.
-- per-sequence lengths arrive as scalar-prefetch values: the index maps
-  read them to end a sequence's walk (:func:`last_live_block`) and the
-  kernel's mask needs no extra VMEM traffic. The grid is ``(sequence,
-  group of heads, k-block)``.
+- per-sequence lengths arrive as scalar-prefetch values beside the table
+  of steps made from them (:func:`walk_steps`, from
+  :func:`last_live_block`): the index maps read a step's ``(sequence,
+  group of heads, k-block)`` from the table, the body reads the length for
+  its mask and to know a visit's last step, and neither costs VMEM
+  traffic. The grid is ONE axis of as many steps as blocks are fetched;
+  the ``BlockSpec`` pipeline prefetches from one step to the next, across
+  sequences as inside one. The running softmax starts on a visit's block 0
+  and is written out on its last live block.
+- the call is traced once a configuration and set of shapes
+  (``_decode_call`` is a ``jax.jit``): a program's layers share one traced
+  kernel and one Mosaic body in the lowered module.
 - inference-only: no custom VJP (decode never differentiates).
 - interpret=True runs the same kernel on CPU for tests/CI parity.
 """
@@ -91,7 +106,8 @@ from .flash_attention import _PALLAS_SCOPE, NEG_INF, _out_sds
 
 __all__ = ["flash_attention_decode", "kv_append", "paged_kv_append",
            "paged_kv_append_rows", "decode_attention_reference",
-           "decode_walk_blocks", "rows_minor", "KERNEL_ROWS", "fold_rows",
+           "decode_walk_blocks", "decode_grid_steps", "rows_minor",
+           "KERNEL_ROWS", "fold_rows",
            "window_fold"]
 
 # query rows one kernel call serves: the chunk rides ONE f32 sublane tile
@@ -298,7 +314,9 @@ def decode_attention_reference(q, k_cache, v_cache, lengths, scale,
 # 290 KB, so a step of 0.75-1.5 MB is bound by its bytes (740-750 GB/s on
 # a full cache at either end of that range) and more rows than that only
 # fetch more rows past the length: tools/probe_decode_walk.py, PERF.md
-# section 6, PRs 28 and 32
+# section 6, PRs 28 and 32. Since PR 53 every step of the grid fetches a
+# block, so that fixed cost is paid once a block that is read and never
+# for one that is not
 _STEP_BYTES = 3 << 19
 
 
@@ -475,8 +493,44 @@ def decode_walk_blocks(lengths, cache_shape, dtype, page_size: int,
     return int(live.sum()), int(live.size * num_k)
 
 
-def _decode_kernel(scale, group, q_len, minor, whole, append, sink,
-                   len_ref, *refs):
+def decode_grid_steps(lengths, cache_shape, dtype, page_size: int,
+                      q_len: int = 1, v_dim: int = None):
+    """The grid steps one call of the kernel runs for sequences of
+    ``lengths`` (host integers, as :func:`decode_walk_blocks` takes them):
+    a step a k-block that is fetched, once a group of heads where a step
+    carries fewer heads than the cache has (:func:`walk_steps` counts the
+    same on the traced lengths, and its count is the grid's bound)."""
+    _, H, S, D = cache_shape
+    heads, rows = kv_tile(H, S, D, dtype, page_size, v_dim=v_dim)
+    return (H // heads) * decode_walk_blocks(
+        lengths, cache_shape, dtype, page_size, q_len, rows)[0]
+
+
+def walk_steps(lengths, q_len: int, block_k: int, num_k: int, groups: int):
+    """``(table, steps)``: the grid of one decode call, a step a k-block
+    that is fetched, from the traced ``lengths`` [B] by ``jnp`` beside the
+    call. A *visit* is a sequence's group of heads, ``groups`` of them a
+    sequence, in the order ``(sequence, group)``; visit ``v`` walks the
+    blocks ``0..last_live_block`` of its sequence in ascending order, and
+    the steps of the call are the visits' walks one after another.
+    ``table[s]`` is ``v * num_k + ik`` of step ``s`` (past the call's
+    ``steps`` the last step again: a grid never gets there); ``steps`` is
+    their number, the grid's bound."""
+    live = jnp.repeat(last_live_block(lengths, q_len, block_k, num_k) + 1,
+                      groups)                               # [V] blocks
+    ends = jnp.cumsum(live)
+    steps = ends[-1]
+    s = jnp.minimum(jnp.arange(live.shape[0] * num_k), steps - 1)
+    # the visit a step belongs to: how many walks end at or before it,
+    # and its block: the step less the blocks of those walks
+    ended = ends[None, :] <= s[:, None]
+    table = (jnp.sum(ended, axis=1) * num_k + s
+             - jnp.sum(jnp.where(ended, live[None, :], 0), axis=1))
+    return table.astype(jnp.int32), steps.astype(jnp.int32)
+
+
+def _decode_kernel(scale, group, q_len, minor, whole, append, sink, num_k,
+                   groups, step_ref, len_ref, *refs):
     sink_ref = None
     if append:      # the step's new K/V row rides the last live block
         (keep_ref, q_ref, k_ref, v_ref, kn_ref, vn_ref, o_ref, ko_hbm,
@@ -485,8 +539,10 @@ def _decode_kernel(scale, group, q_len, minor, whole, append, sink,
         q_ref, k_ref, v_ref, sink_ref, o_ref, m_scr, l_scr, acc = refs
     else:
         q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc = refs
-    b, ik = pl.program_id(0), pl.program_id(2)
-    num_k = pl.num_programs(2)
+    # a grid step is a block that is fetched (`walk_steps`): block `ik` of
+    # visit `visit`, a sequence's group of heads
+    visit, ik = _visit_and_block(step_ref[pl.program_id(0)], num_k)
+    b, hg = visit // groups, visit % groups
     # a K or V block is [heads, block_k, D] or, rows-minor, [heads, D,
     # block_k]: the same products, the block's axes in the other order
     rows_at, d_at = (2, 1) if minor else (1, 2)
@@ -540,13 +596,10 @@ def _decode_kernel(scale, group, q_len, minor, whole, append, sink,
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
     if not append:
-        # a block past the last live one was not fetched (its index map
-        # repeats the last live block) and is not scored
-        pl.when(ik <= last)(lambda: walk(k_ref, v_ref))
+        walk(k_ref, v_ref)      # every step of the grid is a live block
     else:
         pl.when(ik < last)(lambda: walk(k_ref, v_ref))
-        hg, heads = pl.program_id(1), k_ref.shape[1]
-        visit = b * pl.num_programs(1) + hg     # a sequence's group of heads
+        heads = k_ref.shape[1]
         slot = visit % 2
 
         def flushes(slot, b=0, hg=0, block=0):
@@ -582,34 +635,27 @@ def _decode_kernel(scale, group, q_len, minor, whole, append, sink,
             for copy in flushes(slot, b, hg, last):
                 copy.start()
 
-    @pl.when(ik == num_k - 1)
+    @pl.when(ik == last)        # the visit's last step
     def _finish():
         l = l_scr[:, :, :1]
         o_ref[...] = (acc[:] / jnp.where(l == 0.0, 1.0, l)).astype(
             o_ref.dtype)
         if append:      # the call's last step: both buffers' flushes land
-            @pl.when(visit == pl.num_programs(0) * pl.num_programs(1) - 1)
+            @pl.when(visit == len_ref.shape[0] * groups - 1)
             def _drain_all():
                 drain(slot)
                 pl.when(visit >= 1)(lambda: drain(1 - slot))
 
 
-def _kv_index_map(q_len: int, block_k: int, num_k: int,
-                  minor: bool = False):
-    """Index map of the K and V tiles: k-block ``ik`` while it is live,
-    the last live one after it (on the last axis where the cache comes
-    rows-minor). The pipeline issues no DMA for a block index that repeats
-    (``kernels/moe.py`` ``frozen``), so nothing past a sequence's length
-    is fetched."""
-    def index(b, hg, ik, lens, *_):
-        blk = jnp.minimum(ik, last_live_block(lens[b], q_len, block_k,
-                                              num_k))
-        return (b, hg, 0, blk) if minor else (b, hg, blk, 0)
-    return index
+def _visit_and_block(step, num_k: int):
+    """A :func:`walk_steps` entry as ``(visit, k-block)``."""
+    return step // num_k, step % num_k
 
 
-def _decode_call(q, k_cache, v_cache, lengths, tile, *, minor, scale, group,
-                 q_len, interpret, whole=False, append=None, sink=None):
+@jax.named_scope(_PALLAS_SCOPE)
+def _decode_call_traced(q, k_cache, v_cache, lengths, tile, *, minor, scale,
+                        group, q_len, interpret, whole=False, append=None,
+                        sink=None):
     """The Pallas call on ``q`` [B * H, R, D] and caches [B, H, S_max, D]
     with ``tile = (heads, rows)`` of a cache a grid step
     (:func:`kv_tile`'s choice; ``tools/probe_decode_walk.py`` sweeps it).
@@ -627,17 +673,28 @@ def _decode_call(q, k_cache, v_cache, lengths, tile, *, minor, scale, group,
     _, R, D = q.shape
     Dv = v_cache.shape[3]
     hb, bk = tile
-    nk = k_cache.shape[2] // bk
+    nk, groups = k_cache.shape[2] // bk, H // hb
     if minor:
         k_cache, v_cache = k_cache.swapaxes(2, 3), v_cache.swapaxes(2, 3)
-    rows_of = lambda b, hg, ik, *_: (b * (H // hb) + hg, 0, 0)
+    table, steps = walk_steps(lengths, q_len, bk, nk, groups)
+
+    def at(index):
+        """An index map from ``index(sequence, group of heads, k-block)``
+        of the grid step's entry of the table."""
+        def index_map(s, table, *_):
+            visit, ik = _visit_and_block(table[s], nk)
+            return index(visit // groups, visit % groups, ik)
+        return index_map
+
+    rows_of = at(lambda b, hg, ik: (b * groups + hg, 0, 0))
     q_spec = pl.BlockSpec((hb, R, D), rows_of)
     o_spec = pl.BlockSpec((hb, R, Dv), rows_of)
     kv_block = (1, hb, D, bk) if minor else (1, hb, bk, D)
-    kv_map = _kv_index_map(q_len, bk, nk, minor)
+    kv_map = at(lambda b, hg, ik: (b, hg, 0, ik) if minor
+                else (b, hg, ik, 0))
     kv_spec = pl.BlockSpec(kv_block, kv_map)
     v_spec = kv_spec if Dv == D else pl.BlockSpec((1, hb, bk, Dv), kv_map)
-    scalars, operands = [lengths], [q, k_cache, v_cache]
+    scalars, operands = [table, lengths], [q, k_cache, v_cache]
     in_specs, out_specs = [q_spec, kv_spec, v_spec], [o_spec]
     out_shape = [_out_sds((B * H, R, Dv), q.dtype, q, k_cache, v_cache)]
     scratch = [pltpu.VMEM((hb, R, 128), jnp.float32),     # running max
@@ -646,12 +703,12 @@ def _decode_call(q, k_cache, v_cache, lengths, tile, *, minor, scale, group,
     if sink is not None:
         operands.append(sink)
         in_specs.append(pl.BlockSpec((hb, R, 128),
-                                     lambda b, hg, ik, *_: (hg, 0, 0)))
-    aliases, order = {}, ("parallel", "parallel", "arbitrary")
+                                     at(lambda b, hg, ik: (hg, 0, 0))))
+    aliases = {}
     if append is not None:
         k_new, v_new, keep = append
         new_spec = pl.BlockSpec((hb, D, _LANES),
-                                lambda b, hg, ik, *_: (hg, 0, b // _LANES))
+                                at(lambda b, hg, ik: (hg, 0, b // _LANES)))
         scalars.append(keep)
         operands += [_columns(k_new, k_cache.dtype)[0],
                      _columns(v_new, v_cache.dtype)[0]]
@@ -667,11 +724,9 @@ def _decode_call(q, k_cache, v_cache, lengths, tile, *, minor, scale, group,
                     pltpu.SemaphoreType.DMA((2, 2))]
         # the caches follow the scalars and q among the call's operands
         aliases = {len(scalars) + 1: 1, len(scalars) + 2: 2}
-        # a buffer is handed from one visit to the one after the next
-        order = ("arbitrary",) * 3
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
-        grid=(B, H // hb, nk),
+        grid=(steps,),      # as many steps as blocks are fetched
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=scratch,
@@ -679,11 +734,14 @@ def _decode_call(q, k_cache, v_cache, lengths, tile, *, minor, scale, group,
     o, *caches = pl.pallas_call(
         functools.partial(_decode_kernel, scale, int(group), int(q_len),
                           minor, bool(whole), append is not None,
-                          sink is not None),
+                          sink is not None, nk, groups),
         grid_spec=grid_spec,
         out_shape=out_shape,
         input_output_aliases=aliases,
-        compiler_params=pltpu.CompilerParams(dimension_semantics=order),
+        # the running softmax, and on the append path a flush's buffer,
+        # are handed from one step to the next
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="decode_attention",
     )(*scalars, *operands)
@@ -692,7 +750,20 @@ def _decode_call(q, k_cache, v_cache, lengths, tile, *, minor, scale, group,
     return (o, *(c.swapaxes(2, 3) if minor else c for c in caches))
 
 
-@jax.named_scope(_PALLAS_SCOPE)
+# One trace a configuration and set of shapes: the layers of a program call
+# the kernel alike, the first traces it (the table, the index maps, the
+# body) and the jit's cache answers the others, and the lowered module
+# holds one function with the Mosaic call that every layer calls, as the
+# flash forward's does (`flash_attention._fwd`, PR 50). XLA inlines the
+# call sites, so the caches' aliases hold as before. The `pallas` scope
+# stands inside the traced function, right around the call, where the
+# kernel's one name in the HLO and the profiler wants it.
+_decode_call = jax.jit(
+    _decode_call_traced, static_argnums=(4,),
+    static_argnames=("minor", "scale", "group", "q_len", "interpret",
+                     "whole"))
+
+
 def flash_attention_decode(q, k_cache, v_cache, lengths, *,
                            scale=None, num_heads: int = 1,
                            page_size: int = 128, group: int = 1,

@@ -1444,7 +1444,7 @@ class GenerativeEngine(ServingEngine):
         head's counted once), for the dispatch's span."""
         if not active or not _monitor.enabled():
             return {}
-        from ..kernels import decode_walk_blocks
+        from ..kernels import decode_grid_steps, decode_walk_blocks
         from ..kernels.latent_attention import latent_walk_blocks
 
         # a forward's first row sees the keys so far and its own: a
@@ -1460,16 +1460,21 @@ class GenerativeEngine(ServingEngine):
         fetched = held = keys = 0
         from ..kernels.decode_attention import kv_tile
         rows_by_kind, calls_by_kind = Counter(), Counter()
+        steps_by_kind = Counter()
         for (shape, dt, v_dim, kind), n in self._cache_walks.items():
             tile = kv_tile(shape[1], shape[2], shape[3], np_dtype(dt),
                            self._page_size, v_dim=v_dim)[1]
-            f, h = decode_walk_blocks(np.minimum(lengths, shape[2]), shape,
-                                      np_dtype(dt), self._page_size,
-                                      q_len=q_len, rows=tile)
+            seen = np.minimum(lengths, shape[2])
+            f, h = decode_walk_blocks(seen, shape, np_dtype(dt),
+                                      self._page_size, q_len=q_len,
+                                      rows=tile)
             fetched, held = fetched + n * f, held + n * h
             keys += n * int(np.minimum(lengths + q_len - 1, shape[2]).sum())
             rows_by_kind[kind] += n * f * tile
             calls_by_kind[kind] += n * steps
+            steps_by_kind[kind] += n * decode_grid_steps(
+                seen, shape, np_dtype(dt), self._page_size, q_len=q_len,
+                v_dim=v_dim)
         for kind, rows in rows_by_kind.items():
             _monitor.counter(
                 "decode_attention_rows_total",
@@ -1480,6 +1485,16 @@ class GenerativeEngine(ServingEngine):
                 "on the host's lengths), by the kind of the layer's cache "
                 "(window: a ring, all of it once the window is passed)"
             ).labels(kind=kind).inc(rows)
+            _monitor.counter(
+                "decode_attention_grid_steps_total",
+                "grid steps the decode attention kernel's calls ran for "
+                "the resident sequences, a step a k-block of a group of "
+                "heads (kernels.decode_grid_steps on the host's lengths): "
+                "over decode_attention_rows_total / the tile's rows, the "
+                "groups of heads a sequence's heads make (1 where a step "
+                "carries all of them) where a step runs only for a block "
+                "that is fetched, and more where a grid is sized by the "
+                "cache").labels(kind=kind).inc(steps_by_kind[kind])
             _monitor.counter(
                 "decode_attention_calls_by_kind_total",
                 "calls of the decode attention over a K/V cache, a forward "

@@ -21,12 +21,12 @@ import paddle_tpu as fluid
 import paddle_tpu.unique_name as un
 from paddle_tpu import monitor, serving
 from paddle_tpu.kernels import (decode_attention_reference,
-                                decode_walk_blocks, flash_attention_decode,
+                                decode_grid_steps, decode_walk_blocks,
+                                flash_attention_decode,
                                 kv_append, paged_kv_append,
                                 paged_kv_append_rows, rows_minor)
-from paddle_tpu.kernels.decode_attention import (_decode_call,
-                                                 _kv_index_map, kv_tile,
-                                                 last_live_block)
+from paddle_tpu.kernels.decode_attention import (_decode_call, kv_tile,
+                                                 last_live_block, walk_steps)
 from paddle_tpu.models.gpt import GptConfig, build_gpt_generative
 
 PAGE = 128
@@ -101,23 +101,30 @@ def test_kernel_scores_nothing_past_a_sequences_length(shape, walk):
                                    **tol)
 
 
-@pytest.mark.parametrize("minor", [False, True])
+@pytest.mark.parametrize("groups", [1, 3])
 @pytest.mark.parametrize("q_len", [1, 8])
-def test_index_map_repeats_the_last_live_block(q_len, minor):
-    """Past a sequence's last live block the K and V block index repeats,
-    which is what makes the pipeline issue no DMA there: on the rows' axis,
-    the last one where the cache comes rows-minor."""
+def test_walk_table_lists_each_visits_live_blocks_in_order(q_len, groups):
+    """The grid of a call (PR 53): a step a k-block that is fetched. Visit
+    ``v`` (a sequence's group of heads, in the order sequence, group) walks
+    blocks 0 to its sequence's last live one in ascending order, the visits
+    one after another, and the grid's bound is their number; past it the
+    table repeats its last step. From traced lengths, as the call has them."""
     block, num_k = 128, 8
     lens = np.array([0, 1, 127, 128, 129, 300, 1017, 1024], np.int32)
-    index = _kv_index_map(q_len, block, num_k, minor)
+    table, steps = jax.jit(
+        lambda n: walk_steps(n, q_len, block, num_k, groups))(lens)
+    want = []
     for b, n in enumerate(lens):
         last = min(max(int(n) + q_len - 2, 0) // block, num_k - 1)
         assert int(last_live_block(n, q_len, block, num_k)) == last
-        walked = [tuple(int(i) for i in index(b, 3, ik, lens))
-                  for ik in range(num_k)]
-        assert walked == [
-            (b, 3, 0, min(ik, last)) if minor else (b, 3, min(ik, last), 0)
-            for ik in range(num_k)]
+        for hg in range(groups):
+            want += [(b * groups + hg) * num_k + ik
+                     for ik in range(last + 1)]
+    assert int(steps) == len(want)
+    assert table.shape == (len(lens) * groups * num_k,)
+    assert table.dtype == jnp.int32 and steps.dtype == jnp.int32
+    assert list(np.asarray(table)[:len(want)]) == want
+    assert set(np.asarray(table)[len(want):]) <= {want[-1]}
 
 
 def test_view_is_read_from_the_cache_shape():
@@ -748,10 +755,11 @@ def test_tile_is_whole_pages_of_whole_heads_inside_its_budget():
                 dt).itemsize <= _STEP_BYTES
 
 
-def test_walk_share_histogram_counts_what_the_kernel_helper_counts():
-    """``decode_attention_walk_share`` is the kernel module's own count on
-    the lengths the dispatch thread holds: k-blocks fetched over k-blocks
-    held, over every step of the chunk and every layer."""
+def _observed_walk():
+    """A tiny GPT-2 engine's ``_observe_walk`` on four residents over a
+    chunk of 4 steps, the monitor reset before it: lengths 31.., 127..
+    (crosses into block 1 at its third step), 260.., 511.. (the cache's
+    end), blocks of 128 rows, 4 a cache, two layers."""
     cfg = GptConfig(vocab_size=64, hidden_size=48, num_layers=2,
                     num_heads=12, intermediate_size=48, max_position=512)
     with un.guard():
@@ -764,15 +772,156 @@ def test_walk_share_histogram_counts_what_the_kernel_helper_counts():
               for p, e in [(30, 1), (120, 7), (100, 160), (128, 383)]]
     monitor.reset()
     eng._observe_walk(active, 4)
+
+
+def test_walk_share_histogram_counts_what_the_kernel_helper_counts():
+    """``decode_attention_walk_share`` is the kernel module's own count on
+    the lengths the dispatch thread holds: k-blocks fetched over k-blocks
+    held, over every step of the chunk and every layer."""
+    _observed_walk()
     got = monitor.metric_value("decode_attention_walk_share", default=None)
-    # lengths 31.., 127.. (crosses into block 1 at its third step), 260..,
-    # 511.. (the cache's end): blocks of 128 rows, 4 a cache
     lengths = np.array([31, 127, 260, 511]) + np.arange(4)[:, None]
     fetched, held = decode_walk_blocks(np.minimum(lengths, 512),
                                        (4, 12, 512, 4), "float32", 128)
     assert (fetched, held) == (4 * 1 + (2 * 1 + 2 * 2) + 4 * 3 + 4 * 4, 64)
     assert got["count"] == 1
     assert got["sum"] == pytest.approx(fetched / held)
+
+
+# -- the live walk: a grid step only where a block is fetched (PR 53) --------
+
+# name: dtype, key/value heads, query heads a group, key dim, value dim,
+# q_len, whole_chunk, sink, append
+LIVE_WALKS = {
+    "append": (jnp.float32, 12, 1, 64, 64, 1, False, False, True),
+    "sink": (jnp.float32, 8, 4, 128, 128, 1, False, True, False),
+    "whole-chunk-q4": (jnp.bfloat16, 16, 1, 128, 128, 4, True, False, False),
+    "whole-chunk-q8": (jnp.bfloat16, 16, 1, 128, 128, 8, True, False, False),
+    "group16": (jnp.bfloat16, 16, 16, 128, 128, 1, False, False, False),
+    "keys192-values128": (jnp.float32, 4, 4, 192, 128, 1, False, True,
+                          False),
+}
+# name: lengths as (k-blocks, rows) of the chunk's LAST row's keys (what
+# ends the walk), and the slot mask of the append
+LIVE_LENGTHS = {
+    "ones": ([(0, 1)] * 3, None),
+    "block-edge": ([(1, 0), (2, 0), (3, 0), (1, 1)], None),
+    "full": ([(4, 0)] * 3, None),
+    "mixed": ([(0, 1), (0, 127), (1, 1), (2, 77), (4, 0), (0, 9)], None),
+    "masked": ([(0, 5), (1, 0), (1, 1), (2, 100), (4, 0)], (1, 0, 1, 0, 1)),
+}
+
+
+@pytest.mark.parametrize("variant,lengths", [
+    (v, n) for v in sorted(LIVE_WALKS) for n in sorted(LIVE_LENGTHS)
+    if LIVE_LENGTHS[n][1] is None or LIVE_WALKS[v][-1]])
+def test_live_walk_is_the_reference(variant, lengths):
+    """The kernel whose grid is the table of live blocks against the
+    primitive oracle, on caches whose dead blocks hold NaN: every variant
+    the six decoders use, on sequences of one key, lengths that end exactly
+    on a block's edge, full caches and a mix; on the append path the caches
+    come back with the step's row where the slot's mask is set, and
+    bit-identical where it is 0."""
+    dt, H, G, D, Dv, q_len, whole, with_sink, append = LIVE_WALKS[variant]
+    spec, mask = LIVE_LENGTHS[lengths]
+    S = 512
+    _, block = kv_tile(H, S, D, dt, PAGE, v_dim=Dv)
+    assert S // block == 4, "the cases count in a cache of four k-blocks"
+    # the chunk's first row sees q_len - 1 keys fewer than its last
+    n = np.array([max(b * block + r - (q_len - 1), 1) for b, r in spec],
+                 np.int32)
+    B = len(n)
+    rng = np.random.default_rng(zlib.crc32(f"{variant}/{lengths}".encode()))
+    mk = lambda *shape: rng.normal(size=shape).astype(np.float32)
+    q = jnp.asarray(mk(B * H, q_len * G, D), dt)
+    k, v = mk(B, H, S, D), mk(B, H, S, Dv)
+    sink = jnp.asarray(mk(H * G)) if with_sink else None
+    kn = vn = keep = None
+    clean_k, clean_v = jnp.asarray(k, dt), jnp.asarray(v, dt)
+    if append:      # the step's row is the last visible key, row n - 1
+        kn, vn = (jnp.asarray(mk(B, H, 1, D), dt) for _ in range(2))
+        keep = None if mask is None else jnp.asarray(mask, jnp.float32)
+        clean_k, clean_v = (paged_kv_append_rows(c, new, jnp.asarray(n - 1),
+                                                 keep)
+                            for c, new in ((clean_k, kn), (clean_v, vn)))
+    ref = decode_attention_reference(
+        q, clean_k.reshape(B * H, S, D), clean_v.reshape(B * H, S, Dv),
+        jnp.asarray(np.repeat(n, H)), D ** -0.5, group=G, whole_chunk=whole,
+        sink=None if sink is None else jnp.tile(sink.reshape(H, G), (B, 1)))
+    live = (last_live_block(n, q_len, block, 4) + 1) * block
+    for b in range(B):      # a block past the walk is never fetched
+        k[b, :, live[b]:] = np.nan
+        v[b, :, live[b]:] = np.nan
+    out = flash_attention_decode(
+        q, jnp.asarray(k.reshape(B * H, S, D), dt),
+        jnp.asarray(v.reshape(B * H, S, Dv), dt), n, num_heads=H,
+        page_size=PAGE, group=G, interpret=True, whole_chunk=whole,
+        sink=sink, append=(kn, vn, keep) if append else None)
+    if append:
+        out, ck, cv = out
+        for got, want, dirty in ((ck, clean_k, k), (cv, clean_v, v)):
+            got = np.asarray(got).reshape(want.shape)
+            for b in range(B):
+                # the live blocks as the row-form append leaves them, and
+                # nothing written past them; a masked slot bit-identical
+                np.testing.assert_array_equal(got[b, :, :live[b]],
+                                              np.asarray(want)[b, :, :live[b]])
+                assert np.isnan(got[b, :, live[b]:]).all()
+                if mask is not None and not mask[b]:
+                    assert _bits(got[b]) == _bits(jnp.asarray(dirty[b], dt))
+    assert out.shape == (B * H, q_len * G, Dv)
+    tol = dict(atol=2e-5, rtol=1e-4) if dt == jnp.float32 else dict(
+        atol=3e-2, rtol=3e-2)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), **tol)
+
+
+# name: cache [B, H, S, D], dtype, value dim, q_len
+GRID_SHAPES = {
+    "gpt2": ((64, 12, 1024, 64), jnp.float32, None, 1),
+    "command-a-plus": ((64, 8, 1024, 128), jnp.bfloat16, None, 1),
+    "sdar-block-of-4": ((64, 4, 2048, 128), jnp.bfloat16, None, 4),
+    "mimo-v2-flash-full": ((128, 4, 4096, 256), jnp.bfloat16, 128, 1),
+    "mimo-v2-flash-ring": ((128, 8, 128, 256), jnp.bfloat16, 128, 1),
+    "heads-in-groups": ((16, 128, 2048, 128), jnp.bfloat16, None, 1),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(GRID_SHAPES))
+def test_host_counts_the_steps_the_kernel_is_given(shape):
+    """``decode_grid_steps`` and ``decode_walk_blocks`` on the host's
+    lengths against the grid bound the kernel's own table comes with on the
+    same lengths traced: a step a fetched block and group of heads, at the
+    stored cells' cache shapes and at one whose heads a step cannot carry
+    at once."""
+    (B, H, S, D), dt, v_dim, q_len = GRID_SHAPES[shape]
+    rng = np.random.default_rng(zlib.crc32(shape.encode()))
+    lengths = np.concatenate([[1, S, S - q_len + 1], rng.integers(
+        1, S + 1, B - 3)]).astype(np.int32)
+    heads, rows = kv_tile(H, S, D, dt, PAGE, v_dim=v_dim)
+    groups = H // heads
+    assert (groups > 1) == (shape == "heads-in-groups")
+    _, steps = jax.jit(lambda n: walk_steps(
+        n, q_len, rows, S // rows, groups))(lengths)
+    fetched, held = decode_walk_blocks(lengths, (B, H, S, D), dt, PAGE,
+                                       q_len=q_len, v_dim=v_dim)
+    assert held == B * (S // rows)
+    assert int(steps) == groups * fetched
+    assert int(steps) == decode_grid_steps(lengths, (B, H, S, D), dt, PAGE,
+                                           q_len=q_len, v_dim=v_dim)
+
+
+def test_grid_steps_counter_reads_a_step_a_fetched_block():
+    """``decode_attention_grid_steps_total{kind}`` beside
+    ``decode_attention_rows_total{kind}``: over the rows a tile holds, one
+    step a fetched block where a step carries all of a sequence's heads."""
+    _observed_walk()
+    steps = monitor.metric_value("decode_attention_grid_steps_total",
+                                 kind="full")
+    rows = monitor.metric_value("decode_attention_rows_total", kind="full")
+    # two layers' blocks of 128 rows: 4 + 6 + 12 + 16 a layer (the walk
+    # share's test counts them)
+    assert steps == 2 * 38 and rows == steps * 128
 
 
 # -- a sink column, values narrower than keys, the fold into a ring ----------

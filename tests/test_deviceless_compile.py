@@ -241,10 +241,11 @@ def test_gpt2_chained_decode_appends_inside_the_decode_kernel(v5e):
         r"\{\{1\}: \((\d+), \{\}\), \{2\}: \((\d+), \{\}\)\}", text)
     assert len(calls) == 2
     for out, operands, k_at, v_at in calls:
+        # the grid's bound (the walk's live steps: PR 53), its table,
         # lengths, keep, q, K cache, V cache, K columns, V columns
         assert out.startswith("f32[768,8,64]")
-        assert len(operands.split(", ")) == 7
-        assert (int(k_at), int(v_at)) == (3, 4)
+        assert len(operands.split(", ")) == 9
+        assert (int(k_at), int(v_at)) == (5, 6)
     made = re.findall(r"%([a-zA-Z_\-]+)[.\w]* = " + cache + r" ([a-z\-]+)\(",
                       text)
     assert {op for _, op in made} <= {"parameter", "get-tuple-element",
